@@ -110,7 +110,7 @@ def measure_path(name, X, y, k, c, trace_dir):
         with count_host_syncs() as sc, count_dispatches() as dc:
             w_off, _ = run_stream(X, y, k, c)
     finally:
-        _monitoring._unregister_event_duration_listener_by_callback(
+        _monitoring.unregister_event_duration_listener(
             _listener)
     off = {"dispatches": dc["n"], "host_syncs": sc["n"],
            "compiles": compiles_off[0]}

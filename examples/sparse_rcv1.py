@@ -26,10 +26,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from tpu_sgd.utils.platform import honor_cpu_env
-
-honor_cpu_env()
-
 import numpy as np  # noqa: E402
 
 from tpu_sgd import L1Updater, SVMWithSGD, data_mesh  # noqa: E402

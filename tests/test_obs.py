@@ -414,7 +414,7 @@ def test_enabled_obs_compressed_wire_zero_added_runtime_events(rng):
         with count_host_syncs() as sc, count_dispatches() as dc:
             mk().optimize_with_history((X, y), w0)
     finally:
-        _monitoring._unregister_event_duration_listener_by_callback(
+        _monitoring.unregister_event_duration_listener(
             _listener)
     base_dispatch, base_sync = dc["n"], sc["n"]
 

@@ -78,13 +78,15 @@ def test_pallas_gradient_drop_in_optimizer():
     np.testing.assert_allclose(w_pal, w_true, atol=0.05)
 
 
-def test_pallas_gradient_falls_back_off_tpu():
-    """Default (interpret=None) on CPU: silently uses the XLA path."""
+def test_pallas_gradient_raises_off_tpu():
+    """Default (interpret=None) on CPU: an error that says what to do, on
+    both entry points — never a silent hand-over to the XLA path."""
     g = PallasGradient(LogisticGradient())
     X, y, w = _data(classify=True)
-    gs, ls, c = g.batch_sums(X, y, w)
-    gs_ref, ls_ref, c_ref = LogisticGradient().batch_sums(X, y, w)
-    np.testing.assert_allclose(np.asarray(gs), np.asarray(gs_ref), rtol=1e-5)
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        g.batch_sums(X, y, w)
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        g.window_sums(X, y, w, 0, X.shape[0])
 
 
 def test_pallas_gradient_weight_dim_delegates():

@@ -6,9 +6,9 @@ device, and hand it to a compiled consumer.  Before this package each
 path hand-rolled that loop — synchronous full-width ``device_put`` with
 zero transfer/compute overlap, and a differently-shaped tail chunk that
 recompiled the per-chunk kernels (the eager-op shape-compile trap).
-``BENCH_LAST_TPU.json`` puts the cost on the record: the streamed
-statistics build is feed-bound at ``build_s=248.2 s`` while the compute
-side idles at 0.024 ms/iter.
+The round-5 hardware capture put the cost on the record: the streamed
+statistics build was feed-bound at ``build_s=248.2 s`` while the compute
+side idled at 0.024 ms/iter.
 
 Three pieces, composed by the streaming consumers (``ops/gram.py``
 builders, ``parallel/gram_parallel.py`` meshed builders,

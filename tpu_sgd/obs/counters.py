@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import weakref
 from typing import Dict, Optional
 
 from tpu_sgd.obs import spans as _spans
@@ -255,10 +256,18 @@ def enable() -> None:
         _GLOBAL.inc(_tagged("dispatch"))
         return orig_call(self, *args)
 
+    # arrays already materialized while enabled, by id with a weakref
+    # keeping the id honest: a zero-copy fetch (the CPU backend's) caches
+    # no ``_npy_value``, and re-reading the same ready buffer is as free
+    # there as a cache hit is elsewhere (analysis/runtime.py's twin rule)
+    seen: dict = {}
+
     def _tick_sync(arr):
         if getattr(depth, "d", 0) > 0:
             return  # inner funnel of an already-counted materialization
-        if arr._npy_value is None:  # an actual copy, not a cache hit
+        if arr._npy_value is None and id(arr) not in seen:
+            key = id(arr)
+            seen[key] = weakref.ref(arr, lambda _, k=key: seen.pop(k, None))
             _GLOBAL.inc(_tagged("host_sync"),
                         nbytes=int(getattr(arr, "nbytes", 0) or 0))
 
@@ -349,7 +358,7 @@ def _restore(saved: dict) -> None:
     listener = saved.get("compile_listener")
     if listener is not None:
         try:
-            _monitoring._unregister_event_duration_listener_by_callback(
+            _monitoring.unregister_event_duration_listener(
                 listener)
         except Exception:
             logger.warning("could not unregister the compile listener",

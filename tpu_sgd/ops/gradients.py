@@ -225,9 +225,9 @@ class ChunkedGradient(Gradient):
     ONE HBM read of X per iteration — the same traffic shape the Pallas
     fused kernel targets (SURVEY.md §2 #11), expressed at the XLA level
     where the MXU mapping stays the compiler's problem.  Whether the
-    read actually collapses is an empirical, per-backend question; bench.py
-    measures it against the stock path on hardware and only a
-    trajectory-clean winner may take the headline.
+    read actually collapses is an empirical, per-backend question: measure
+    it against the stock path on hardware, and only a trajectory-clean
+    winner may take a headline.
 
     Wraps any pointwise family (least-squares / logistic / hinge);
     delegates everything except the window schedule.

@@ -42,12 +42,9 @@ import numpy as np
 
 def is_sparse(X) -> bool:
     """True when ``X`` is a sparse (BCOO) feature matrix."""
-    try:
-        from jax.experimental.sparse import BCOO
+    from jax.experimental.sparse import BCOO
 
-        return isinstance(X, BCOO)
-    except ImportError:  # pragma: no cover - sparse always ships with jax
-        return False
+    return isinstance(X, BCOO)
 
 
 def row_matrix_bcoo(x):

@@ -1,7 +1,7 @@
 """Chunked-gather driver for block-ALIGNED sufficient-statistics SGD.
 
-Round-4's decomposition experiment (``scripts/gram_scan_experiment.py``)
-showed the 0.024 ms aligned-gram iteration spends roughly half its time
+Round-4's decomposition experiment (its script was deleted in PR 23; the
+record ``GRAM_SCAN_EXPERIMENT.json`` stays) showed the 0.024 ms aligned-gram iteration spends roughly half its time
 OUTSIDE the two (d, d) prefix reads — per-iteration loop bookkeeping and
 dispatch.  This driver amortizes that: an outer ``while_loop`` advances
 ``chunk_iters`` iterations at a time, gathering ALL of the chunk's window
@@ -28,12 +28,12 @@ per-iteration driver's two dynamic slices stay fused; the bookkeeping
 it amortizes measured only ~0.0036 ms/iter (14%).  The driver stays
 OPT-IN via ``GradientDescent.set_gram_options(chunk_iters=K)`` — it
 still wins ~1.4–2.6× on CPU hosts — and the planner default remains
-the per-iteration contract (see BASELINE.md, round-5 decision).
+the per-iteration contract (the round-5 decision).
 
 FOLLOW-UP CLOSED (PR 5): the weights_agree-gated product_chunked vs
-full_contract comparison the JSON asked for is now computed by
-``scripts/gram_scan_experiment.py`` itself (``product_chunked_wins`` +
-``verdict`` fields) and the recorded verdict keeps the per-iteration
+full_contract comparison the JSON asked for was computed by the
+experiment script itself (``product_chunked_wins`` + ``verdict``
+fields) and the recorded verdict keeps the per-iteration
 default.  The dispatch-tax half of the original motivation — the
 ~44–65 ms fixed cost plus per-iteration host slop — is attacked from
 the other side by the superstep executor

@@ -89,14 +89,7 @@ def as_data_mesh(mesh):
 
 
 def shard_map_fn(mesh, fn, in_specs, out_specs, check_vma=False):
-    """Version-tolerant shard_map wrapper (jax.shard_map vs experimental)."""
-    try:
-        from jax import shard_map as _sm  # jax >= 0.6
-
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check_vma)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
+    """``jax.shard_map`` with this package's default (no replication
+    check: the psums inside ``make_step`` are what replicate)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
