@@ -1,0 +1,267 @@
+"""``bench/spans.py`` and the nine readers built on it, on traces written by
+hand as text and read back through the same path as a run's: two fits, a
+hand-off split across ``train.h2d`` and ``train.fetch``, stretches no span
+covers, a ``while`` with scoped and unscoped bodies."""
+
+import pytest
+
+from bench import cells, spans, trace
+
+MS = 1e6  # nanoseconds
+WORKLOAD = "dense1000-logistic.from-host"
+NINE = {
+    "validate_ms": [WORKLOAD], "plan_ms": [WORKLOAD], "h2d_ms": [WORKLOAD],
+    "dispatch_ms": None, "fetch_ms": None, "idle_unspanned_share": None,
+    "margins_ms": None, "gradient_ms": None, "step_unscoped_share": None}
+
+#: fit 0 is [0, 100) ms, fit 1 [100, 200) ms; (name, start ms, length ms,
+#: stats).  ``fit.run`` and ``train.run`` hold spans, so they are no leaves.
+HOST = [
+    ("bench.fit", 0, 100, {}),
+    ("fit.run", 1, 98, {"rows": 64}),
+    ("fit.validate", 1, 4, {"rows": 64}),
+    ("fit.plan", 5, 1, {"cached": 1, "schedule": "resident_stock"}),
+    ("train.run", 6, 92, {"path": "fused"}),
+    ("train.h2d", 6, 10, {"bytes": 4096}),
+    ("train.dispatch", 18, 2, {"built": 0}),
+    ("train.fetch", 20, 77, {"recorded": 10}),
+    ("shard_args", 7, 1, {}),  # the runtime's own: not a span
+    ("bench.fit", 100, 100, {}),
+    ("fit.run", 100, 100, {"rows": 64}),
+    ("fit.validate", 100, 2, {"rows": 64}),
+    ("fit.plan", 102, 3, {"cached": 0, "schedule": "resident_stock"}),
+    ("train.run", 105, 95, {"path": "fused"}),
+    ("train.h2d", 105, 2, {"bytes": 4096}),
+    ("train.dispatch", 107, 1, {"built": 1}),
+    ("train.fetch", 108, 90, {"recorded": 10}),
+]
+M, G = "%fusion.13 = fusion(X, w)", "%fusion.16 = fusion(c, X)"
+WHILE, COPY = "%while.4 = while(...)", "%copy-done = copy-done(...)"
+OPS = [  # fit 0: one while of 60 ms holding 40 + 15 ms; fit 1: bare ops
+    (WHILE, 30, 60), (M, 31, 40), (G, 72, 15),
+    (M, 110, 20), (COPY, 140, 20)]
+TF_OPS = {M: "jit(sgd_run)/while/body/sgd.margins/dot_general:",
+          G: "jit(sgd_run)/while/body/sgd.pointwise/sgd.gradient/dot:",
+          WHILE: "jit(sgd_run)/while:"}
+
+
+def _text(host=HOST, ops=OPS, tf_ops=TF_OPS, device="/device:TPU:0"):
+    """An XSpace as text: one host thread, one chip."""
+    stat_ids, event_ids = {"tf_op": 1}, {}
+
+    def stat(key, value):
+        sid = stat_ids.setdefault(key, len(stat_ids) + 1)
+        kind = "str_value" if isinstance(value, str) else "int64_value"
+        shown = f'"{value}"' if isinstance(value, str) else value
+        return f"stats {{ metadata_id: {sid} {kind}: {shown} }}"
+
+    def events(rows, ids):
+        out = []
+        for name, start, length, *rest in rows:
+            mid = ids.setdefault(name, len(ids) + 1)
+            stats = " ".join(stat(k, v) for k, v in (rest[0] if rest
+                                                     else {}).items())
+            out.append(f"events {{ metadata_id: {mid} offset_ps: "
+                       f"{int(start * 1e9)} duration_ps: "
+                       f"{int(length * 1e9)} {stats} }}")
+        return "\n".join(out)
+
+    host_events = events(host, event_ids)
+    host_meta = "\n".join(
+        f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}" }} }}'
+        for n, i in event_ids.items())
+    op_ids = {}
+    op_events = events(ops, op_ids)
+    # the last operation names its op_name by reference, as a string that a
+    # plane holds once may be
+    refs = list(tf_ops)[-1:] if tf_ops else []
+    op_meta = "\n".join(
+        f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}" '
+        + (f"stats {{ metadata_id: 1 ref_value: {100 + i} }}" if n in refs
+           else stat("tf_op", tf_ops[n]) if n in tf_ops else "") + " } }"
+        for n, i in op_ids.items())
+    ref_meta = "\n".join(
+        f'stat_metadata {{ key: {100 + i} value {{ id: {100 + i} '
+        f'name: "{tf_ops[n]}" }} }}' for n, i in op_ids.items() if n in refs)
+    stat_meta = "\n".join(
+        f'stat_metadata {{ key: {i} value {{ id: {i} name: "{k}" }} }}'
+        for k, i in stat_ids.items())
+    planes = f"""
+    planes {{ name: "/host:CPU"
+      lines {{ name: "python3" timestamp_ns: 0
+        {host_events} }}
+      {host_meta}
+      {stat_meta} }}"""
+    if device:
+        planes += f"""
+    planes {{ name: "{device}"
+      lines {{ name: "XLA Ops" timestamp_ns: 0
+        {op_events} }}
+      lines {{ name: "Async XLA Ops" timestamp_ns: 0
+        events {{ metadata_id: 1 offset_ps: 0 duration_ps: 5 }} }}
+      {op_meta}
+      {stat_meta}
+      {ref_meta} }}"""
+    return planes
+
+
+@pytest.fixture
+def checkout(tmp_path, monkeypatch):
+    """``write(text) -> (trace, run)``: the text as the one ``.xplane.pb``
+    of a run of ``WORKLOAD`` in a checkout of its own, reduced by
+    ``bench/trace.py`` as ``bench/harness.py`` does."""
+    from jax.profiler import ProfileData
+
+    monkeypatch.setattr(cells, "REPO", str(tmp_path))
+    run = {"workload": WORKLOAD, "iterations": 10}
+
+    def write(text):
+        folder = tmp_path / ".bench_trace" / WORKLOAD / "plugins" \
+            / "profile" / "2026_09_27"
+        folder.mkdir(parents=True, exist_ok=True)
+        path = folder / "host.xplane.pb"
+        path.write_bytes(ProfileData.text_proto_to_serialized_xspace(text))
+        spans._reduced.cache_clear()
+        return trace.reduce(trace.load(str(path))), run
+
+    return write
+
+
+def _read(metric, reduced, run):
+    return cells.load_module("layers", metric).read(reduced, run)
+
+
+# -- the file and the reduction ------------------------------------------------
+
+def test_load_keeps_the_programs_spans_and_the_operations_op_names(checkout):
+    checkout(_text())
+    host, device = spans.load(spans.find({"workload": WORKLOAD}))
+    names = [e[0] for e in host["lines"][0]["events"]]
+    assert "shard_args" not in names and names.count("bench.fit") == 2
+    assert ("train.h2d", 6 * MS, 10 * MS, {"bytes": 4096}) \
+        in host["lines"][0]["events"]
+    assert [line["name"] for line in device["lines"]] == ["XLA Ops"]
+    assert device["op_names"] == TF_OPS  # by value and by reference
+    assert spans.scope_of(TF_OPS[G]) == "sgd.gradient"  # the innermost
+    assert spans.scope_of(TF_OPS[WHILE]) == spans.scope_of(None) \
+        == spans.UNSCOPED
+
+
+def test_spans_are_a_tree_by_containment(checkout):
+    fit0, fit1 = spans.of(*checkout(_text()))["fits"]
+    by_name = {s["name"]: i for i, s in enumerate(fit0["spans"])}
+    parent = {s["name"]: s["parent"] for s in fit0["spans"]}
+    assert parent["fit.run"] is None
+    assert parent["fit.validate"] == parent["fit.plan"] \
+        == parent["train.run"] == by_name["fit.run"]
+    assert parent["train.h2d"] == parent["train.dispatch"] \
+        == parent["train.fetch"] == by_name["train.run"]
+    assert fit0["spans"][by_name["train.h2d"]]["stats"] == {"bytes": 4096}
+    assert [s["stats"].get("built") for s in fit1["spans"]
+            if s["name"] == "train.dispatch"] == [1]
+
+
+def test_the_hand_off_is_cut_by_the_leaf_span_that_covers_it(checkout):
+    reduced, run = checkout(_text())
+    fit0, fit1 = spans.of(reduced, run)["fits"]
+    # [0, 30) ms before the first operation
+    assert fit0["before_first_op"] == {
+        "fit.validate": 4 * MS, "fit.plan": 1 * MS, "train.h2d": 10 * MS,
+        "train.dispatch": 2 * MS, "train.fetch": 10 * MS,
+        spans.UNSPANNED: 3 * MS}  # [0, 1) and [16, 18): fit.run is no leaf
+    assert sum(fit0["before_first_op"].values()) == pytest.approx(
+        reduced["fits"][0]["first_op_ns"] - reduced["fits"][0]["start_ns"])
+    # and [90, 100) after the last: fetch to 97, then nobody
+    assert fit0["idle"]["train.fetch"] == 17 * MS
+    assert fit0["idle"][spans.UNSPANNED] == 6 * MS
+    assert fit0["last_op_end_ns"] == 90 * MS
+    # fit 1: [100, 110), [130, 140), [160, 200)
+    assert fit1["before_first_op"] == {
+        "fit.validate": 2 * MS, "fit.plan": 3 * MS, "train.h2d": 2 * MS,
+        "train.dispatch": 1 * MS, "train.fetch": 2 * MS}
+    assert fit1["idle"]["train.fetch"] == (2 + 10 + 38) * MS
+    assert fit1["idle"][spans.UNSPANNED] == 2 * MS
+
+
+def test_operations_own_time_is_keyed_by_scope(checkout):
+    scopes = spans.of(*checkout(_text()))["scopes"]
+    assert scopes == {"sgd.margins": 60 * MS, "sgd.gradient": 15 * MS,
+                      spans.UNSCOPED: (5 + 20) * MS}  # the while's own, copy
+
+
+def test_overlapping_leaves_of_two_threads_share_a_gap_once():
+    leaves = [{"name": "a.x", "start_ns": 0.0, "end_ns": 6.0},
+              {"name": "b.y", "start_ns": 4.0, "end_ns": 8.0}]
+    assert spans._cut([(0.0, 10.0)], leaves) == {
+        "a.x": 6.0, "b.y": 2.0, spans.UNSPANNED: 2.0}
+
+
+# -- the nine readers ------------------------------------------------------------
+
+@pytest.mark.parametrize("metric,expected", [
+    ("validate_ms", (4 + 2) / 2), ("plan_ms", (1 + 3) / 2),
+    ("h2d_ms", (10 + 2) / 2), ("dispatch_ms", (2 + 1) / 2),
+    ("fetch_ms", ((97 - 90) + (198 - 160)) / 2),
+    ("idle_unspanned_share", 100 * (6 + 2) / (40 + 60)),
+    ("margins_ms", 60 / 2 / 10), ("gradient_ms", 15 / 2 / 10),
+    ("step_unscoped_share", 100 * 25 / 100)])
+def test_reader(checkout, metric, expected):
+    assert _read(metric, *checkout(_text())) == pytest.approx(expected)
+
+
+@pytest.mark.parametrize("metric", sorted(NINE))
+def test_a_reader_with_nothing_to_read_returns_nothing(checkout, metric,
+                                                       tmp_path):
+    reduced, run = checkout(_text())
+    # no file: the run traced elsewhere (the CPU rehearsal)
+    assert _read(metric, reduced, dict(run, workload="some.other")) is None
+    # the file's bench.fit events do not start where the trace's do
+    moved = {**reduced, "fits": [dict(f, start_ns=f["start_ns"] + 5e3)
+                                 for f in reduced["fits"]]}
+    assert _read(metric, moved, run) is None
+    assert _read(metric, {**reduced, "fits": reduced["fits"][:1]}, run) is None
+    # no device plane
+    assert _read(metric, *checkout(_text(device=None))) is None
+    # two files: which one is the run's cannot be said
+    extra = tmp_path / ".bench_trace" / WORKLOAD / "plugins" / "profile" \
+        / "older"
+    extra.mkdir()
+    (extra / "host.xplane.pb").write_bytes(b"")
+    assert _read(metric, reduced, run) is None
+
+
+@pytest.mark.parametrize("metric", sorted(NINE))
+def test_a_program_without_spans_or_scopes_gives_nothing(checkout, metric):
+    """The parent of the PR that added them: ``bench.fit`` and the device's
+    lines are there, no span, no ``sgd.*`` in any ``op_name``."""
+    bare = [e for e in HOST if e[0] == "bench.fit"]
+    old = {name: "jit(run)/while/body/dot_general:" for name in TF_OPS}
+    assert _read(metric, *checkout(_text(host=bare, tf_ops=old))) is None
+    assert _read(metric, *checkout(_text(host=bare, tf_ops={}))) is None
+
+
+def test_the_reduction_is_read_once_a_process(checkout, monkeypatch):
+    reduced, run = checkout(_text())
+    first = spans.of(reduced, run)
+    monkeypatch.setattr(spans, "load", None)  # a second read would raise
+    assert spans.of(reduced, run) is first
+
+
+# -- BENCHMARK.json ------------------------------------------------------------
+
+def test_the_nine_metrics_are_entries_with_readers():
+    bench = cells.benchmark()
+    both = [w["name"] for w in bench["workloads"]]
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    for name, workloads in NINE.items():
+        entry = entries[name]
+        assert entry["workloads"] == (workloads or both), name
+        assert entry["moves"] == "rows_per_s"
+        assert entry["source"] == ("program_span" if name in (
+            "validate_ms", "plan_ms", "h2d_ms", "dispatch_ms")
+            else "device_trace")
+    assert list(entries)[-9:] == list(NINE)  # appended, in the issue's order
+    for cell in both:
+        readers = cells.Cell(cell).readers
+        assert {n for n, w in NINE.items() if not w or cell in w} \
+            <= set(readers)

@@ -132,6 +132,23 @@ def test_whole_run_program_compiles(S):
     assert "while" in compiled.as_text()
 
 
+def test_whole_run_fusions_keep_the_step_scopes(S):
+    """After the chip's compiler has fused the step, one fusion still
+    carries ``sgd.margins`` in its ``op_name`` and one ``sgd.gradient``
+    (the two reads of all of X, 98.9% of the step on the chip): what
+    ``bench/spans.py`` keys the device's time by."""
+    import re
+
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    run = make_run(LogisticGradient(), SquaredL2Updater(), _cfg())
+    text = jax.jit(run).lower(
+        S((D,), F32), S((N, D), BF16), S((N,), F32)).compile().as_text()
+    scopes = {m.group(1) for m in re.finditer(
+        r' fusion\(.*op_name="[^"]*(sgd\.[a-z]+)', text)}
+    assert {"sgd.margins", "sgd.gradient"} <= scopes, scopes
+
+
 def test_superstep_k8_compiles(S):
     """The host-streamed feed's fused program: K=8 per-step batches of
     one bf16-wire superchunk (frac 0.1 of 2**19 host rows)."""

@@ -551,3 +551,55 @@ def test_chunk_iters_warning_on_meshed_streamed_stats(rng):
         _w.simplefilter("always")
         opt.optimize_with_history((X, y), np.zeros(8, np.float32))
     assert any("chunk_iters applies" in str(r.message) for r in rec)
+
+
+# -- the step's named scopes (what the profiler's trace names kernels by) -----
+
+def _scopes_in(lowered):
+    import re
+
+    return set(re.findall(r"sgd\.[a-z]+", lowered.as_text(debug_info=True)))
+
+
+STEP_SCOPES = {"sgd.sample", "sgd.margins", "sgd.pointwise", "sgd.gradient",
+               "sgd.update"}
+
+
+@pytest.mark.parametrize("case", ["dense_logistic", "bcoo_hinge",
+                                  "dp4_step"])
+def test_lowered_step_carries_the_named_scopes(case):
+    """``jax.named_scope`` at trace time, in the one place each piece is
+    defined: every driver inherits the names, ``sgd.allreduce`` exists only
+    on a mesh and ``sgd.converge`` only in the whole-run loop."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_sgd.ops.gradients import HingeGradient
+    from tpu_sgd.ops.sparse import sparse_data
+    from tpu_sgd.ops.updaters import L1Updater
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    cfg = SGDConfig(step_size=1.0, num_iterations=3, mini_batch_fraction=0.5,
+                    convergence_tol=0.001)
+    w = jnp.zeros(8, jnp.float32)
+    if case == "dense_logistic":
+        X, y = jnp.ones((64, 8), jnp.bfloat16), jnp.ones(64, jnp.float32)
+        fn = jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg))
+        assert fn.__name__ == "sgd_run"  # part of the compile cache's key
+        found = _scopes_in(fn.lower(w, X, y))
+        assert found == STEP_SCOPES | {"sgd.converge"}
+    elif case == "bcoo_hinge":
+        X, y, _ = sparse_data(64, 8, nnz_per_row=3, kind="svm")
+        fn = jax.jit(make_run(HingeGradient(), L1Updater(), cfg))
+        found = _scopes_in(fn.lower(w, X, jnp.asarray(y)))
+        assert found == STEP_SCOPES | {"sgd.converge"}
+    else:
+        from tpu_sgd.parallel.data_parallel import dp_step_fn
+        from tpu_sgd.parallel.mesh import data_mesh
+
+        X, y = jnp.ones((64, 8), jnp.float32), jnp.ones(64, jnp.float32)
+        fn = dp_step_fn(LeastSquaresGradient(), SimpleUpdater(), cfg,
+                        data_mesh(jax.devices()[:4]), with_valid=False)
+        found = _scopes_in(fn.lower(w, X, y, jnp.asarray(1, jnp.int32),
+                                    jnp.asarray(0.0, jnp.float32)))
+        assert found == STEP_SCOPES | {"sgd.allreduce"}

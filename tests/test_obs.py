@@ -177,6 +177,136 @@ def test_raising_sink_never_kills_the_hot_path():
         disable_tracing()
 
 
+# -- the second consumer: the profiler's own trace ---------------------------
+
+@pytest.fixture
+def profiler_session(tmp_path):
+    """``stop() -> {span name: [(start_ns, end_ns, stats)]}`` of a
+    ``jax.profiler`` session started here, read back from its
+    ``.xplane.pb`` with nothing but JAX."""
+    import glob
+
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    stopped = []
+
+    def stop():
+        jax.profiler.stop_trace()
+        stopped.append(True)
+        path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                              / "*.xplane.pb"))
+        found = {}
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(("fit.", "train.", "demo.")):
+                        found.setdefault(e.name, []).append(
+                            (e.start_ns, e.start_ns + e.duration_ns,
+                             dict(e.stats)))
+        return {name: sorted(spans, key=lambda s: s[0])
+                for name, spans in found.items()}
+
+    yield stop
+    if not stopped:
+        jax.profiler.stop_trace()
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_session_with_the_gate_closed_puts_the_span_in_the_trace(
+        profiler_session):
+    assert not obs_spans.is_enabled()
+    with obs_spans.span("demo.outer", rows=7, path="fused") as sp:
+        assert sp is not obs_spans._NOOP
+        assert sp.set(cached=True) is sp  # set() chains, as on _Span
+    found = profiler_session()
+    (_, _, stats), = found["demo.outer"]
+    assert stats == {"rows": 7, "path": "fused", "cached": 1}
+    # the session over, the gate still closed: the singleton again
+    assert obs_spans.span("demo.outer") is obs_spans._NOOP
+
+
+def test_gate_open_and_session_give_one_record_and_one_event(
+        profiler_session):
+    sink = ListSink()
+    enable_tracing(sink)
+    try:
+        with obs_spans.span("demo.both", i=3) as sp:
+            sp.set(steps=4)
+    finally:
+        disable_tracing()
+    found = profiler_session()
+    rec, = sink.spans("demo.both")
+    assert rec["i"] == 3 and rec["steps"] == 4
+    (_, _, stats), = found["demo.both"]
+    assert stats == {"i": 3, "steps": 4}
+
+
+def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
+                                                        rng):
+    """A tiny ``LogisticRegressionWithSGD.run`` on host arrays, twice, and
+    a device-array fit at the Optimizer boundary, under one session with
+    no ``obs.enable``: the fit path's spans nest as the program runs and
+    carry their counts."""
+    import jax.numpy as jnp
+
+    import tpu_sgd
+
+    X = rng.normal(size=(256, 8)).astype(np.float32)
+    y = (rng.random(256) > 0.5).astype(np.float32)
+    alg = tpu_sgd.LogisticRegressionWithSGD(1.0, 4, reg_param=0.0,
+                                            mini_batch_fraction=0.5)
+    alg.run((X, y))
+    alg.run((X, y))
+    opt = tpu_sgd.GradientDescent(
+        tpu_sgd.LogisticGradient(),
+        tpu_sgd.SquaredL2Updater()).set_num_iterations(3)
+    opt.optimize_with_history((jnp.asarray(X), jnp.asarray(y)),
+                              np.zeros(8, np.float32))
+    found = profiler_session()
+
+    assert len(found["fit.run"]) == 2 and len(found["train.run"]) == 3
+    assert "fit.prepare" not in found  # no scaling, no intercept: no span
+    for i, fit in enumerate(found["fit.run"]):
+        assert fit[2] == {"rows": 256, "features": 8, "sparse": 0}
+        for name in ("fit.validate", "fit.plan", "train.run"):
+            assert _inside(found[name][i], fit), name
+        run = found["train.run"][i]
+        assert run[2] == {"iterations": 4, "rows": 256, "path": "fused"}
+        for name in ("train.h2d", "train.dispatch", "train.fetch"):
+            assert _inside(found[name][i], run), name
+        assert found["train.h2d"][i][2]["bytes"] == X.nbytes + y.nbytes
+        assert found["train.fetch"][i][2]["recorded"] == 4
+        assert found["fit.validate"][i][2] == {"rows": 256}
+    assert [p[2]["cached"] for p in found["fit.plan"]] == [0, 1]
+    assert found["fit.plan"][0][2]["schedule"] == "resident_stock"
+    # the fit that builds its runner names itself
+    assert [d[2]["built"] for d in found["train.dispatch"]] == [1, 0, 1]
+    # device arrays at the Optimizer boundary: nothing to copy
+    assert found["train.h2d"][2][2]["bytes"] == 0
+    assert found["train.run"][2][2]["iterations"] == 3
+
+
+def test_fit_prepare_span_only_when_scaling_or_intercept_runs(
+        profiler_session, rng):
+    import tpu_sgd
+
+    X = rng.normal(size=(64, 4)).astype(np.float32)
+    y = X @ np.ones(4, np.float32)
+    alg = tpu_sgd.LinearRegressionWithSGD(0.1, 2)
+    alg.set_intercept(True)
+    alg.run((X, y))
+    found = profiler_session()
+    (prepare,), (fit,) = found["fit.prepare"], found["fit.run"]
+    assert _inside(prepare, fit) and prepare[2] == {"rows": 64}
+    assert prepare[1] <= found["fit.plan"][0][0]  # planned on the final X
+
+
 # -- counters ----------------------------------------------------------------
 
 def test_counters_inc_snapshot_deltas_reset():

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_sgd.models.labeled_point import LabeledPoint, to_arrays
+from tpu_sgd.obs.spans import span
 from tpu_sgd.ops.sparse import append_bias_auto, is_sparse, row_matrix_bcoo
 from tpu_sgd.optimize.optimizer import Optimizer
 
@@ -194,6 +195,15 @@ class GeneralizedLinearAlgorithm:
         and intercept append)."""
         if self.schedule == "off":
             return
+        with span("fit.plan") as sp:
+            cached = self._apply_plan(X, y)
+            plan = getattr(self.optimizer, "last_plan", None)
+            sp.set(cached=cached,
+                   schedule="unplanned" if plan is None else plan.schedule)
+
+    def _apply_plan(self, X, y) -> bool:
+        """``_auto_plan``'s work; True when the repeat-run ``_plan_key``
+        hit skipped the probe and the plan."""
         opt = self.optimizer
         manual = bool(
             getattr(opt, "host_streaming", False)
@@ -206,7 +216,7 @@ class GeneralizedLinearAlgorithm:
         # set, including after an auto-planned run — always win.
         if (self.schedule == "auto" and manual
                 and getattr(opt, "last_plan", None) is None):
-            return  # explicit optimizer flags win
+            return False  # explicit optimizer flags win
         import numpy as np
 
         from tpu_sgd.plan import logger, plan_for, plan_quasi_newton
@@ -220,7 +230,7 @@ class GeneralizedLinearAlgorithm:
                getattr(opt, "max_num_iterations", None))
         if (getattr(opt, "last_plan", None) is not None
                 and getattr(opt, "_plan_key", None) == key):
-            return
+            return True
         if isinstance(opt, _LBFGS):
             # quasi-Newton optimizers plan a narrower menu: stock
             # full-batch passes, the sufficient-stats substitution, or
@@ -254,6 +264,7 @@ class GeneralizedLinearAlgorithm:
                 "schedules) — configure it directly with the optimizer "
                 "setters instead"
             )
+        return False
 
     # -- hooks -------------------------------------------------------------
     def create_model(self, weights, intercept) -> GeneralizedLinearModel:
@@ -269,60 +280,75 @@ class GeneralizedLinearAlgorithm:
         initial_weights=None,
         initial_intercept: float = 0.0,
     ) -> GeneralizedLinearModel:
-        X, y = _as_arrays(data)
-        if X.shape[0] == 0:
-            raise ValueError("empty input")
-        if self.num_features < 0:
-            self.num_features = X.shape[1]
-        if self.validate_data:
-            self.validators(X, y)
-        if initial_weights is None:
-            initial_weights = np.zeros((self._weight_dim(),), np.float32)
-        w0 = np.asarray(initial_weights, np.float32)
-        scaler = None
-        if self.use_feature_scaling:
-            # Fit BEFORE the bias column exists (the reference scales raw
-            # features, then appends the bias to the scaled matrix); user
-            # initial weights arrive in ORIGINAL space, so they move into
-            # scaled space by the inverse map (w * std) — an improvement on
-            # the reference, whose warm starts silently stay unscaled.
-            # Flat stacked weights (the multinomial (K-1)*d layout) rescale
-            # per d-sized block.
-            from tpu_sgd.feature import StandardScaler
-
-            scaler = StandardScaler(with_mean=False, with_std=True).fit(X)
-            # host numpy input stays on host inside transform (the
-            # device round-trip would triple the transfer); device and
-            # sparse inputs keep their layout
-            X = scaler.transform(X)
-            d = int(np.asarray(scaler.std).shape[0])
-            w0 = np.asarray(
-                (w0.reshape(-1, d) * np.asarray(scaler.std)[None, :])
-                .reshape(w0.shape),
-                np.float32,
-            )
-        if self.add_intercept:
-            # Bias appended as the LAST column ([U] MLUtils.appendBias;
-            # SURVEY.md §3.1 intercept prepend/split).
-            Xb = append_bias_auto(X)
-            w0 = np.concatenate([w0, np.asarray([initial_intercept], np.float32)])
-            self._auto_plan(Xb, y)
-            weights = self.optimizer.optimize((Xb, y), w0)
-            intercept = float(weights[-1])
-            weights = weights[:-1]
-        else:
+        with span("fit.run") as run_span:
+            with span("fit.validate") as sp:
+                X, y = _as_arrays(data)
+                sp.set(rows=X.shape[0])
+                if X.shape[0] == 0:
+                    raise ValueError("empty input")
+                if self.num_features < 0:
+                    self.num_features = X.shape[1]
+                if self.validate_data:
+                    self.validators(X, y)
+            run_span.set(rows=X.shape[0], features=X.shape[1],
+                         sparse=is_sparse(X))
+            if initial_weights is None:
+                initial_weights = np.zeros((self._weight_dim(),), np.float32)
+            w0 = np.asarray(initial_weights, np.float32)
+            scaler = None
+            if self.use_feature_scaling or self.add_intercept:
+                with span("fit.prepare", rows=X.shape[0]):
+                    if self.use_feature_scaling:
+                        X, w0, scaler = self._scale_features(X, w0)
+                    if self.add_intercept:
+                        # Bias appended as the LAST column ([U]
+                        # MLUtils.appendBias; SURVEY.md §3.1 intercept
+                        # prepend/split).
+                        X = append_bias_auto(X)
+                        w0 = np.concatenate(
+                            [w0, np.asarray([initial_intercept], np.float32)])
             self._auto_plan(X, y)
             weights = self.optimizer.optimize((X, y), w0)
             intercept = 0.0
-        if scaler is not None:
-            # Same trick as the reference: transform() maps trained weights
-            # back to original space (margin w'.(x/std) == (w'/std).x);
-            # flat stacked (multinomial) weights go block-wise.
-            d = int(np.asarray(scaler.std).shape[0])
-            weights = scaler.transform(
-                jnp.asarray(weights).reshape(-1, d)
-            ).reshape(jnp.asarray(weights).shape)
-        return self.create_model(weights, intercept)
+            if self.add_intercept:
+                intercept = float(weights[-1])
+                weights = weights[:-1]
+            if scaler is not None:
+                # Same trick as the reference: transform() maps trained
+                # weights back to original space (margin w'.(x/std) ==
+                # (w'/std).x); flat stacked (multinomial) weights go
+                # block-wise.
+                d = int(np.asarray(scaler.std).shape[0])
+                weights = scaler.transform(
+                    jnp.asarray(weights).reshape(-1, d)
+                ).reshape(jnp.asarray(weights).shape)
+            return self.create_model(weights, intercept)
+
+    @staticmethod
+    def _scale_features(X, w0):
+        """``(scaled X, w0 in scaled space, the fitted scaler)``.
+
+        Fit BEFORE the bias column exists (the reference scales raw
+        features, then appends the bias to the scaled matrix); user
+        initial weights arrive in ORIGINAL space, so they move into
+        scaled space by the inverse map (w * std) — an improvement on
+        the reference, whose warm starts silently stay unscaled.  Flat
+        stacked weights (the multinomial (K-1)*d layout) rescale per
+        d-sized block."""
+        from tpu_sgd.feature import StandardScaler
+
+        scaler = StandardScaler(with_mean=False, with_std=True).fit(X)
+        # host numpy input stays on host inside transform (the device
+        # round-trip would triple the transfer); device and sparse
+        # inputs keep their layout
+        X = scaler.transform(X)
+        d = int(np.asarray(scaler.std).shape[0])
+        w0 = np.asarray(
+            (w0.reshape(-1, d) * np.asarray(scaler.std)[None, :])
+            .reshape(w0.shape),
+            np.float32,
+        )
+        return X, w0, scaler
 
     def _weight_dim(self) -> int:
         return self.num_features

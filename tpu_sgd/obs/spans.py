@@ -20,7 +20,8 @@ verdicts)::
 Cost contract (the failpoints discipline, measured in
 ``tests/test_obs.py``): DISABLED — the only state a production process
 runs in unless an operator opts in — is ONE module-global load and a
-falsy branch; ``span(...)`` returns a shared no-op singleton, allocates
+falsy branch (for ``span`` also one read of the profiler's session
+flag); ``span(...)`` returns a shared no-op singleton, allocates
 nothing, and formats nothing.  Enabling (``tpu_sgd.obs.enable``) routes
 records to a sink; a raising sink drops the record and never kills the
 observed hot path.
@@ -43,10 +44,17 @@ in ``tests/test_resident.py`` enforce).  Counts and bytes
 (``obs.counters``) are the truth on this harness; span durations
 attribute where host wall clock went.
 
-A ``jax.profiler`` capture rides the span API: ``span("train.run",
-profile_dir="/tmp/jaxtrace")`` brackets the region with
-``jax.profiler.start_trace``/``stop_trace`` (TensorBoard/Perfetto),
-so a deep-dive capture attaches to exactly one traced region.
+Two consumers, one API.  While a ``jax.profiler`` session is active
+(``jax.profiler.start_trace``, the profiler server — the switch is the
+profiler's own ``TraceAnnotation.is_enabled()``, which the program can
+observe) every span is ALSO entered as a
+``jax.profiler.TraceAnnotation(name, **attrs)`` on the entering thread,
+so it lands on the host plane of the same ``.xplane.pb`` as the device's
+lines, on the same clock, and an idle gap of the device can be put down
+to the span that covers it.  No ``obs.enable`` is needed for that: with
+the JSONL gate closed and a session active ``span()`` returns the bare
+annotation; with the gate open ``_Span`` enters the annotation too, so
+both records carry the same name.
 """
 
 from __future__ import annotations
@@ -55,6 +63,8 @@ import itertools
 import logging
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["span", "event", "enable_tracing", "disable_tracing",
            "is_enabled", "current_subsystem"]
@@ -124,19 +134,32 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+#: the second consumer's switch: true while a ``jax.profiler`` session
+#: is active in this process (one C++ flag read, ~40-150 ns)
+_profiling = TraceAnnotation.is_enabled
+
+
+class _Annotation(TraceAnnotation):
+    """A span with the JSONL gate closed and a profiler session active:
+    the profiler's own annotation, plus the span API's ``set``."""
+
+    def set(self, **attrs):
+        self.set_metadata(**attrs)
+        return self
+
 
 class _Span:
     __slots__ = ("name", "attrs", "span_id", "parent_id", "ts", "t0",
-                 "_profile_dir")
+                 "_annotation")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
-        self._profile_dir = attrs.pop("profile_dir", None)
         self.attrs = attrs
         self.span_id = next(_IDS)
         self.parent_id = 0
         self.ts = 0.0
         self.t0 = 0.0
+        self._annotation = None
 
     def set(self, **attrs):
         """Attach host-scalar attributes after entry (e.g. a batch size
@@ -144,6 +167,8 @@ class _Span:
         one forces a device->host sync (graftlint's obs-discipline
         check flags that statically)."""
         self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
         return self
 
     def __enter__(self):
@@ -154,30 +179,16 @@ class _Span:
         # epoch ts for cross-record joins (staleness SLOs), monotonic
         # t0 for durations and the Chrome trace timeline
         self.ts = time.time()
-        if self._profile_dir is not None:
-            try:
-                import jax
-
-                jax.profiler.start_trace(self._profile_dir)
-            except Exception:
-                logger.warning("jax.profiler.start_trace failed; span "
-                               "continues untraced", exc_info=True)
-                self._profile_dir = None
+        if _profiling():
+            self._annotation = TraceAnnotation(self.name, **self.attrs)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        # duration FIRST: the profiler stop below is not part of the
-        # traced region's cost
         dur = time.perf_counter() - self.t0
-        if self._profile_dir is not None:
-            try:
-                import jax
-
-                jax.profiler.stop_trace()
-            except Exception:
-                logger.warning("jax.profiler.stop_trace failed",
-                               exc_info=True)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -213,16 +224,17 @@ class _Span:
 
 
 def span(name: str, **attrs):
-    """Open a trace span.  No-op singleton when tracing is disabled
-    (one global load + branch); otherwise a context manager that emits
-    one ``trace_span`` record on exit.
+    """Open a trace span.  No-op singleton when tracing is disabled and
+    no profiler session is active (one global load + branch, one flag
+    read); the profiler's annotation when only a session is active;
+    otherwise a context manager that emits one ``trace_span`` record on
+    exit (and enters the annotation too while a session is active).
 
     ``attrs`` must be HOST scalars/strings — a device value here forces
-    a sync when the record serializes (statically flagged by graftlint).
-    ``profile_dir=<dir>`` additionally brackets the region with
-    ``jax.profiler`` start/stop for a TensorBoard/Perfetto deep dive."""
+    a sync when the record serializes (statically flagged by
+    graftlint)."""
     if not _ENABLED:
-        return _NOOP
+        return _Annotation(name, **attrs) if _profiling() else _NOOP
     return _Span(name, attrs)
 
 

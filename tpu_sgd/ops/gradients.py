@@ -68,28 +68,30 @@ def margins_of(X, weights):
     accumulation dtype (>= f32; int one-hot data promotes instead of
     truncating the weights)."""
     rhs = weights.T if weights.ndim == 2 else weights
-    if _is_sparse(X):
-        cd = acc_dtype(matmul_dtype(X))
-        return X.astype(cd) @ rhs.astype(cd)
-    mm_dtype = matmul_dtype(X)
-    return jnp.dot(
-        X.astype(mm_dtype), rhs.astype(mm_dtype),
-        preferred_element_type=acc_dtype(mm_dtype),
-    )
+    with jax.named_scope("sgd.margins"):
+        if _is_sparse(X):
+            cd = acc_dtype(matmul_dtype(X))
+            return X.astype(cd) @ rhs.astype(cd)
+        mm_dtype = matmul_dtype(X)
+        return jnp.dot(
+            X.astype(mm_dtype), rhs.astype(mm_dtype),
+            preferred_element_type=acc_dtype(mm_dtype),
+        )
 
 
 def grad_sum_of(coeff, X):
     """``coeffᵀ @ X`` (the gradient-sum matvec / matmul), sparse-aware; the
     dense path is written ``coeff @ X`` so it stays row-major friendly."""
     lhs = coeff.T if coeff.ndim == 2 else coeff
-    if _is_sparse(X):
-        cd = acc_dtype(matmul_dtype(X))
-        return lhs.astype(cd) @ X.astype(cd)
-    mm_dtype = matmul_dtype(X)
-    return jnp.dot(
-        lhs.astype(mm_dtype), X.astype(mm_dtype),
-        preferred_element_type=acc_dtype(mm_dtype),
-    )
+    with jax.named_scope("sgd.gradient"):
+        if _is_sparse(X):
+            cd = acc_dtype(matmul_dtype(X))
+            return lhs.astype(cd) @ X.astype(cd)
+        mm_dtype = matmul_dtype(X)
+        return jnp.dot(
+            lhs.astype(mm_dtype), X.astype(mm_dtype),
+            preferred_element_type=acc_dtype(mm_dtype),
+        )
 
 
 class Gradient:
@@ -137,17 +139,20 @@ class Gradient:
         """
         margins = margins_of(X, weights)
         if margin_axis_name is not None:
-            margins = jax.lax.psum(margins, margin_axis_name)
-        coeff, losses = self.pointwise(margins, y)
-        if mask is not None:
-            m = mask.astype(margins.dtype)
-            coeff = coeff * m
-            losses = losses * m
-            count = jnp.sum(m)
-        else:
-            count = jnp.asarray(X.shape[0], margins.dtype)
+            with jax.named_scope("sgd.margins"):
+                margins = jax.lax.psum(margins, margin_axis_name)
+        with jax.named_scope("sgd.pointwise"):
+            coeff, losses = self.pointwise(margins, y)
+            if mask is not None:
+                m = mask.astype(margins.dtype)
+                coeff = coeff * m
+                losses = losses * m
+                count = jnp.sum(m)
+            else:
+                count = jnp.asarray(X.shape[0], margins.dtype)
         grad_sum = grad_sum_of(coeff, X)  # == X.T @ coeff
-        loss_sum = jnp.sum(losses)
+        with jax.named_scope("sgd.pointwise"):
+            loss_sum = jnp.sum(losses)
         return grad_sum, loss_sum, count
 
     def loss_sweep(
@@ -374,25 +379,30 @@ class MultinomialLogisticGradient:
         # (n, K-1); partial if features are sharded
         margins = margins_of(X, W)
         if margin_axis_name is not None:
-            margins = jax.lax.psum(margins, margin_axis_name)
-        logits = jnp.concatenate(
-            [jnp.zeros((X.shape[0], 1), margins.dtype), margins], axis=-1
-        )  # (n, K) with pivot logit 0
-        log_probs = jax.nn.log_softmax(logits, axis=-1)
-        y_int = y.astype(jnp.int32)
-        losses = -jnp.take_along_axis(log_probs, y_int[:, None], axis=-1)[:, 0]
-        probs = jnp.exp(log_probs)[:, 1:]  # (n, K-1)
-        onehot = jax.nn.one_hot(y_int - 1, K - 1, dtype=margins.dtype)
-        coeff = probs - onehot  # (n, K-1)
-        if mask is not None:
-            m = mask.astype(margins.dtype)
-            coeff = coeff * m[:, None]
-            losses = losses * m
-            count = jnp.sum(m)
-        else:
-            count = jnp.asarray(X.shape[0], margins.dtype)
+            with jax.named_scope("sgd.margins"):
+                margins = jax.lax.psum(margins, margin_axis_name)
+        with jax.named_scope("sgd.pointwise"):
+            logits = jnp.concatenate(
+                [jnp.zeros((X.shape[0], 1), margins.dtype), margins], axis=-1
+            )  # (n, K) with pivot logit 0
+            log_probs = jax.nn.log_softmax(logits, axis=-1)
+            y_int = y.astype(jnp.int32)
+            losses = -jnp.take_along_axis(
+                log_probs, y_int[:, None], axis=-1)[:, 0]
+            probs = jnp.exp(log_probs)[:, 1:]  # (n, K-1)
+            onehot = jax.nn.one_hot(y_int - 1, K - 1, dtype=margins.dtype)
+            coeff = probs - onehot  # (n, K-1)
+            if mask is not None:
+                m = mask.astype(margins.dtype)
+                coeff = coeff * m[:, None]
+                losses = losses * m
+                count = jnp.sum(m)
+            else:
+                count = jnp.asarray(X.shape[0], margins.dtype)
         grad_sum = grad_sum_of(coeff, X).reshape(-1)  # flattened (K-1)*D
-        return grad_sum, jnp.sum(losses), count
+        with jax.named_scope("sgd.pointwise"):
+            loss_sum = jnp.sum(losses)
+        return grad_sum, loss_sum, count
 
     def loss_sweep(
         self,

@@ -107,9 +107,11 @@ def _make_mask(cfg: SGDConfig, key, i, n_local, valid, axis_name,
                shard_index=None):
     """Per-iteration Bernoulli mini-batch mask (None = take everything)."""
     if cfg.mini_batch_fraction < 1.0:
-        k = _sample_key(key, i, axis_name, shard_index)
-        mask = jax.random.bernoulli(k, cfg.mini_batch_fraction, (n_local,))
-        return mask if valid is None else mask & valid
+        with jax.named_scope("sgd.sample"):
+            k = _sample_key(key, i, axis_name, shard_index)
+            mask = jax.random.bernoulli(k, cfg.mini_batch_fraction,
+                                        (n_local,))
+            return mask if valid is None else mask & valid
     return valid
 
 
@@ -129,13 +131,15 @@ def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
     def local_sums(weights, X, y, i, valid):
         if sliced or indexed:
             m = max(1, round(cfg.mini_batch_fraction * X.shape[0]))
-            k = _sample_key(key, i, axis_name, shard_index)
         if sliced:
             # HBM-optimal path: a contiguous row window at a random offset —
             # one sequential DMA (zero-copy under PallasGradient) instead of
             # a random gather.  Assumes exchangeable row order (see
             # SGDConfig.sampling docs).
-            start = jax.random.randint(k, (), 0, max(1, X.shape[0] - m + 1))
+            with jax.named_scope("sgd.sample"):
+                k = _sample_key(key, i, axis_name, shard_index)
+                start = jax.random.randint(
+                    k, (), 0, max(1, X.shape[0] - m + 1))
             return gradient.window_sums(
                 X, y, weights, start, m, valid=valid,
                 margin_axis_name=model_axis_name,
@@ -144,9 +148,11 @@ def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
             # TPU fast path: gather a fixed-size batch (with replacement)
             # instead of masking the whole dataset — touches only ``frac``
             # of HBM per iteration.
-            idx = jax.random.randint(k, (m,), 0, X.shape[0])
-            Xb, yb = X[idx], y[idx]
-            mask = None if valid is None else valid[idx]
+            with jax.named_scope("sgd.sample"):
+                k = _sample_key(key, i, axis_name, shard_index)
+                idx = jax.random.randint(k, (m,), 0, X.shape[0])
+                Xb, yb = X[idx], y[idx]
+                mask = None if valid is None else valid[idx]
         else:
             Xb, yb = X, y
             mask = _make_mask(cfg, key, i, X.shape[0], valid, axis_name,
@@ -187,19 +193,23 @@ def make_step(
     def step(weights, X, y, i, reg_val, valid=None):
         g, l, c = local_sums(weights, X, y, i, valid)
         if axis_name is not None:
-            g, l, c = jax.lax.psum((g, l, c), axis_name)
-        has_batch = c > 0
-        safe_c = jnp.maximum(c, 1.0)
-        loss_i = l / safe_c + reg_val
-        new_w, new_reg = updater.compute(
-            weights, g / safe_c, cfg.step_size, i, cfg.reg_param
-        )
-        if model_axis_name is not None:
-            # reg value is a sum over features -> combine the local blocks
-            new_reg = jax.lax.psum(new_reg, model_axis_name)
-        # Reference behavior on an empty sampled batch: warn, skip the update.
-        new_w = jnp.where(has_batch, new_w, weights)
-        new_reg = jnp.where(has_batch, new_reg, reg_val)
+            with jax.named_scope("sgd.allreduce"):
+                g, l, c = jax.lax.psum((g, l, c), axis_name)
+        with jax.named_scope("sgd.update"):
+            has_batch = c > 0
+            safe_c = jnp.maximum(c, 1.0)
+            loss_i = l / safe_c + reg_val
+            new_w, new_reg = updater.compute(
+                weights, g / safe_c, cfg.step_size, i, cfg.reg_param
+            )
+            if model_axis_name is not None:
+                # reg value is a sum over features -> combine the local
+                # blocks
+                new_reg = jax.lax.psum(new_reg, model_axis_name)
+            # Reference behavior on an empty sampled batch: warn, skip the
+            # update.
+            new_w = jnp.where(has_batch, new_w, weights)
+            new_reg = jnp.where(has_batch, new_reg, reg_val)
         return new_w, loss_i, new_reg, c
 
     return step
@@ -231,7 +241,12 @@ def make_run(
             diff_sq, w_sq = jax.lax.psum((diff_sq, w_sq), model_axis_name)
         return jnp.sqrt(diff_sq), jnp.sqrt(w_sq)
 
-    def run(initial_weights, X, y, valid=None):
+    # ``sgd_run``, not ``run``: the jitted function's name is part of the
+    # persistent compile cache's key and the scopes above are not (JAX
+    # leaves metadata out of it), so under the old name a cache warmed
+    # before the scopes existed hands back an executable without them —
+    # and the profiler names operations from the executable it runs.
+    def sgd_run(initial_weights, X, y, valid=None):
         w0 = initial_weights
         # Initial regVal from a zero-gradient probe update, exactly as the
         # reference initializes it before the loop (SURVEY.md §5.5).
@@ -247,7 +262,8 @@ def make_run(
 
         def cond(carry):
             i, _, _, _, _, converged = carry
-            return (i <= cfg.num_iterations) & jnp.logical_not(converged)
+            with jax.named_scope("sgd.converge"):
+                return (i <= cfg.num_iterations) & jnp.logical_not(converged)
 
         def body(carry):
             i, w, reg_val, losses, n_rec, _ = carry
@@ -258,12 +274,14 @@ def make_run(
             )
             n_rec = n_rec + has_batch.astype(n_rec.dtype)
             if check_conv:
-                diff, w_norm = _global_norms(new_w, w)
-                conv = (
-                    has_batch
-                    & (i > 1)
-                    & (diff < cfg.convergence_tol * jnp.maximum(w_norm, 1.0))
-                )
+                with jax.named_scope("sgd.converge"):
+                    diff, w_norm = _global_norms(new_w, w)
+                    conv = (
+                        has_batch
+                        & (i > 1)
+                        & (diff
+                           < cfg.convergence_tol * jnp.maximum(w_norm, 1.0))
+                    )
             else:
                 conv = jnp.asarray(False)
             return (i + 1, new_w, new_reg, losses, n_rec, conv)
@@ -279,7 +297,7 @@ def make_run(
         _, w, _, losses, n_rec, _ = jax.lax.while_loop(cond, body, carry)
         return w, losses, n_rec
 
-    return run
+    return sgd_run
 
 
 def pack_step_ys(prev_w, new_w, loss_i, new_rv, count, f32: bool = False):
@@ -1273,6 +1291,15 @@ class GradientDescent(Optimizer):
     def optimize_with_history(self, data: Dataset, initial_weights: Array):
         import numpy as np
 
+        with span("train.run", iterations=self.config.num_iterations,
+                  rows=np.shape(data[0])[0]) as run_span:
+            return self._optimize(data, initial_weights, run_span)
+
+    def _optimize(self, data: Dataset, initial_weights: Array, run_span):
+        """``optimize_with_history`` under its ``train.run`` span, whose
+        ``path`` is set where the route is decided."""
+        import numpy as np
+
         X, y = data
         from tpu_sgd.ops.gram import GramData, GramLeastSquaresGradient
 
@@ -1311,13 +1338,13 @@ class GradientDescent(Optimizer):
                     f"sliced windows at frac={cfg.mini_batch_fraction} "
                     "degenerate to FULL-BATCH iterations; rebuild with "
                     "a smaller block_rows for true mini-batch sampling",
-                    RuntimeWarning, stacklevel=3,
+                    RuntimeWarning, stacklevel=4,
                 )
             y = jnp.asarray(y)
             if not jnp.issubdtype(y.dtype, jnp.inexact):
                 y = y.astype(jnp.float32)
             w0 = _coerce_w0(self.gradient, initial_weights, X.shape[1])
-            return self._optimize_routed(X, y, w0, sparse_X=False)
+            return self._optimize_routed(X, y, w0, False, run_span)
         sparse_X = is_sparse(X)
         if sparse_X:
             # BCOO feature path (VERDICT r1 missing #2; [U] SparseVector
@@ -1346,8 +1373,9 @@ class GradientDescent(Optimizer):
                         "wire_dtype applies to dense row chunks; the "
                         "sparse feed ships BCOO components at the data "
                         "dtype (its compression is the sparsity itself)",
-                        RuntimeWarning, stacklevel=2,
+                        RuntimeWarning, stacklevel=3,
                     )
+                run_span.set(path="streamed")
                 w0 = _coerce_w0(self.gradient, initial_weights,
                                 X.shape[1])
                 w, hist = optimize_host_streamed_sparse(
@@ -1394,7 +1422,8 @@ class GradientDescent(Optimizer):
                 # this route returns before _optimize_routed's warning
                 # would fire — the user's explicit chunk_iters request is
                 # being dropped and must not go silent
-                self._warn_chunk_iters_with_mesh(stacklevel=3)
+                self._warn_chunk_iters_with_mesh(stacklevel=4)
+                run_span.set(path="gram")
                 return self._optimize_streamed_stats_mesh(
                     X, y, initial_weights
                 )
@@ -1402,8 +1431,9 @@ class GradientDescent(Optimizer):
             orig, self.gradient = self.gradient, gram
             try:
                 n_logical = gram.data.shape[0]
-                return self.optimize_with_history(
-                    (gram.data, np.asarray(y)[:n_logical]), initial_weights
+                return self._optimize(
+                    (gram.data, np.asarray(y)[:n_logical]), initial_weights,
+                    run_span
                 )
             finally:
                 self.gradient = orig
@@ -1417,6 +1447,7 @@ class GradientDescent(Optimizer):
                     "host streaming supports 1-D data meshes; feature-axis "
                     "('model') sharding needs the resident path"
                 )
+            run_span.set(path="streamed")
             Xh = np.asarray(X)
             # same weight validation/coercion as the resident paths — a
             # wrong-length w0 must raise the clear ValueError here, not
@@ -1449,14 +1480,18 @@ class GradientDescent(Optimizer):
             if self.check_numerics:
                 _raise_if_nonfinite(hist)
             return w, hist
-        if not sparse_X:
-            X = jnp.asarray(X)
-            if not jnp.issubdtype(X.dtype, jnp.inexact):
-                X = X.astype(jnp.float32)  # int/bool features (one-hot etc.)
-        y = jnp.asarray(y)
-        if not jnp.issubdtype(y.dtype, jnp.inexact):
-            y = y.astype(jnp.float32)
-        w0 = _coerce_w0(self.gradient, initial_weights, X.shape[1])
+        # host time in the calls only: the copy may still drain after them
+        with span("train.h2d", bytes=sum(
+                a.nbytes for a in (X, y) if isinstance(a, np.ndarray))):
+            if not sparse_X:
+                X = jnp.asarray(X)
+                if not jnp.issubdtype(X.dtype, jnp.inexact):
+                    # int/bool features (one-hot etc.)
+                    X = X.astype(jnp.float32)
+            y = jnp.asarray(y)
+            if not jnp.issubdtype(y.dtype, jnp.inexact):
+                y = y.astype(jnp.float32)
+            w0 = _coerce_w0(self.gradient, initial_weights, X.shape[1])
         n = X.shape[0]
         if n == 0:
             self._loss_history = np.zeros((0,), np.float32)
@@ -1465,7 +1500,7 @@ class GradientDescent(Optimizer):
             import warnings
 
             warnings.warn(
-                "The miniBatchFraction is too small", RuntimeWarning, stacklevel=2
+                "The miniBatchFraction is too small", RuntimeWarning, stacklevel=3
             )
         gram = self._maybe_gram(X, y, sparse_X)
         if gram is not None:
@@ -1473,18 +1508,21 @@ class GradientDescent(Optimizer):
             # enter the jit program as buffers, not closure constants.
             orig, self.gradient = self.gradient, gram
             try:
-                return self._optimize_routed(gram.data, y, w0, sparse_X)
+                return self._optimize_routed(gram.data, y, w0, sparse_X,
+                                             run_span)
             finally:
                 self.gradient = orig
-        return self._optimize_routed(X, y, w0, sparse_X)
+        return self._optimize_routed(X, y, w0, sparse_X, run_span)
 
-    def _optimize_routed(self, X, y, w0, sparse_X):
+    def _optimize_routed(self, X, y, w0, sparse_X, run_span):
         """Resident-data path routing (single-device / mesh / sparse /
         stepwise), after input coercion and the optional sufficient-stats
         substitution."""
         import numpy as np
 
-        self._warn_chunk_iters_with_mesh(stacklevel=4)
+        from tpu_sgd.ops.gram import GramData
+
+        self._warn_chunk_iters_with_mesh(stacklevel=5)
 
         if self.listener is not None or self.checkpoint_manager is not None:
             if self.gram_chunk_iters:
@@ -1496,7 +1534,7 @@ class GradientDescent(Optimizer):
                     "per-iteration host hop that listeners exist to "
                     "provide; detach the listener to use the chunked "
                     "driver",
-                    RuntimeWarning, stacklevel=3,
+                    RuntimeWarning, stacklevel=4,
                 )
             if (self.sufficient_stats and self.mesh is not None
                     and not sparse_X):
@@ -1508,9 +1546,14 @@ class GradientDescent(Optimizer):
                     "stepper uses the stock DP step); detach the listener "
                     "or run single-device to combine them",
                     RuntimeWarning,
-                    stacklevel=3,
+                    stacklevel=4,
                 )
+            run_span.set(path="stepwise")
             return self._optimize_stepwise(X, y, w0)
+        # each route names its compiled runner and its arguments; ONE call
+        # below dispatches it.  A runner that had to be built (a new
+        # _run_cache entry) traces, lowers and compiles inside that call.
+        cached = len(self._run_cache)
         if sparse_X and self.mesh is not None:
             # Distributed sparse: equal-nse BCOO blocks per shard, same
             # make_run body, psum over ICI (the treeAggregate-over-sparse-
@@ -1530,10 +1573,9 @@ class GradientDescent(Optimizer):
                                       self.config, self.mesh, rows_local, d,
                                       with_valid)
                 self._run_cache[key] = fn
+            path, args = "sparse_mesh", (w0, data, idx, yd)
             if with_valid:
-                w, losses, n_rec = fn(w0, data, idx, yd, valid)
-            else:
-                w, losses, n_rec = fn(w0, data, idx, yd)
+                args += (valid,)
         elif self.mesh is not None and self._mesh_kind() == "dp_mp":
             from tpu_sgd.parallel.model_parallel import dp_mp_optimize
 
@@ -1543,9 +1585,9 @@ class GradientDescent(Optimizer):
                     "gradients only; matrix-weight gradients (multinomial) "
                     "need a 1-D 'data' mesh"
                 )
-            w, losses, n_rec = dp_mp_optimize(
-                self.gradient, self.updater, self.config, self.mesh, w0, X, y
-            )
+            path, fn = "mesh", dp_mp_optimize
+            args = (self.gradient, self.updater, self.config, self.mesh,
+                    w0, X, y)
         elif self.mesh is not None:
             from tpu_sgd.parallel.data_parallel import shard_dataset
 
@@ -1565,21 +1607,24 @@ class GradientDescent(Optimizer):
                                         self.mesh, block_rows,
                                         aligned=self.gram_aligned)
                     self._run_cache[key] = fn
-                w, losses, n_rec = fn(w0, Xd, yd, *stats_leaves)
+                args = (w0, Xd, yd, *stats_leaves)
             else:
                 fn = self._runner(with_valid=valid is not None)
-                if valid is not None:
-                    w, losses, n_rec = fn(w0, Xd, yd, valid)
-                else:
-                    w, losses, n_rec = fn(w0, Xd, yd)
+                args = (w0, Xd, yd) if valid is None else (w0, Xd, yd, valid)
+            path = "mesh"
         else:
-            fn = self._maybe_chunked_gram_run(X)
-            if fn is not None:
-                w, losses, n_rec = fn(w0, X, y)
-            else:
-                w, losses, n_rec = self._runner(with_valid=False)(w0, X, y)
-        n_rec = int(n_rec)
-        self._loss_history = np.asarray(losses)[:n_rec]
+            fn = (self._maybe_chunked_gram_run(X)
+                  or self._runner(with_valid=False))
+            path = "gram" if isinstance(X, GramData) else "fused"
+            args = (w0, X, y)
+        run_span.set(path=path)
+        with span("train.dispatch",
+                  built=int(len(self._run_cache) > cached)):
+            w, losses, n_rec = fn(*args)
+        with span("train.fetch") as sp:
+            recorded = int(n_rec)
+            self._loss_history = np.asarray(losses)[:recorded]
+            sp.set(recorded=recorded)
         if self.check_numerics:
             _raise_if_nonfinite(self._loss_history)
         return w, self._loss_history
@@ -1695,7 +1740,7 @@ class GradientDescent(Optimizer):
                 "virtual loop has no per-iteration host hop); detach "
                 "them or run single-device to combine",
                 RuntimeWarning,
-                stacklevel=3,
+                stacklevel=4,
             )
         Xh = np.asarray(X)
         d = Xh.shape[1]
@@ -1905,7 +1950,7 @@ class GradientDescent(Optimizer):
                         "checkpoint config differs from current config; "
                         "resuming anyway",
                         RuntimeWarning,
-                        stacklevel=3,
+                        stacklevel=4,
                     )
                 w0 = jnp.asarray(state["weights"])
                 reg_val = state["reg_val"]
@@ -1922,7 +1967,7 @@ class GradientDescent(Optimizer):
                 "set_superstep applies to dense data on the meshed "
                 "observed path; the sparse meshed stepper stays "
                 "per-iteration",
-                RuntimeWarning, stacklevel=4,
+                RuntimeWarning, stacklevel=5,
             )
             fused_k = 1
         resident_c = int(self.resident_cadence or 0)
@@ -1933,7 +1978,7 @@ class GradientDescent(Optimizer):
                 "set_residency is single-device (io_callback cadence "
                 "hooks do not ride shard_map); the meshed observed "
                 "path runs the fused superstep driver",
-                RuntimeWarning, stacklevel=4,
+                RuntimeWarning, stacklevel=5,
             )
             resident_c = 0
         if resident_c >= 2 and fused_k <= 1:
@@ -1943,7 +1988,7 @@ class GradientDescent(Optimizer):
                 "set_residency rides the fused superstep executor; "
                 "call set_superstep(K >= 2) (or let the planner pick "
                 "K) to engage the device-resident driver",
-                RuntimeWarning, stacklevel=4,
+                RuntimeWarning, stacklevel=5,
             )
             resident_c = 0
 
