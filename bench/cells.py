@@ -42,8 +42,10 @@ def load_module(kind: str, name: str):
 
 def benchmark(with_prepared: bool = False) -> dict:
     """``BENCHMARK.json``; ``with_prepared`` adds the cells under
-    ``bench/prepared/`` (entries a later PR pastes into ``BENCHMARK.json``),
-    for the tests and ``bench/limits.py`` — never for a run."""
+    ``bench/prepared/`` (entries a later PR pastes into ``BENCHMARK.json``:
+    a cell, its configuration, the ``per_layer`` metrics that are its alone;
+    one that has been pasted is there already and is not added twice), for
+    the tests and ``bench/limits.py`` — never for a run."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     if with_prepared:
@@ -51,8 +53,11 @@ def benchmark(with_prepared: bool = False) -> dict:
         for name in sorted(os.listdir(folder)):
             with open(os.path.join(folder, name)) as f:
                 more = json.load(f)
-            for kind in ("configs", "workloads"):
-                bench[kind] = bench[kind] + more[kind]
+            for kind in ("configs", "workloads", "per_layer"):
+                have = {entry["name"] for entry in bench[kind]}
+                bench[kind] = bench[kind] + [
+                    entry for entry in more.get(kind, [])
+                    if entry["name"] not in have]
     return bench
 
 

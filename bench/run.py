@@ -60,7 +60,7 @@ def main(argv=None) -> int:
     import tpu_sgd  # noqa: F401  (the system under test; absent -> no run)
     import jax
 
-    from bench import cells, harness
+    from bench import cells, harness, spans
 
     cell = cells.Cell(args.workload)
     cache_dir = configure_compile_cache()
@@ -89,8 +89,7 @@ def main(argv=None) -> int:
         reduced = run.pop("trace")
         device["busy_s"] = reduced["busy_ns"] / 1e9
         device["window_s"] = reduced["window_ns"] / 1e9
-        line["breakdown"] = {"device_ops": reduced["device_ops"],
-                             "idle_gaps": reduced["idle_gaps"]}
+        line["breakdown"] = spans.breakdown(reduced, run)
         run["traced_fits"] = reduced["fits"]
     line["run"] = run
     print(json.dumps(line), flush=True)

@@ -40,8 +40,8 @@ import os
 import re
 
 from bench import cells
-from bench.trace import (DEVICE_PLANE, FIT, OPS_LINE, _clip, _events,
-                         _self_times, _union)
+from bench.trace import (DEVICE_PLANE, FIT, NAME_CHARS, OPS_LINE, _clip,
+                         _events, _self_times, _union)
 
 UNSPANNED, UNSCOPED = "(unspanned)", "(unscoped)"
 #: a span of the program: dotted lower-case (``fit.run``, ``train.h2d``);
@@ -205,14 +205,15 @@ def _cut(intervals, leaves):
 
 
 def reduce(planes: list) -> dict:
-    """``{"fits": [...], "scopes": {scope: ns}}``.  A fit has ``start_ns``,
-    ``end_ns``, ``spans`` (its threads' trees, one list; ``parent`` indexes
-    it), ``last_op_end_ns``, ``idle`` (``{leaf: ns}`` of the device's idle
-    time inside the fit) and ``before_first_op`` (the same for the stretch up
-    to the fit's first operation, what ``handoff_ms`` measures).  ``scopes``
-    is the operations' own time between the first fit's start and the last
-    one's end, over the chips.  ``fits`` is empty without a ``bench.fit``;
-    ``scopes`` without a chip."""
+    """``{"fits": [...], "scopes": {scope: ns}, "op_scopes": {operation:
+    scope}}``.  A fit has ``start_ns``, ``end_ns``, ``spans`` (its threads'
+    trees, one list; ``parent`` indexes it), ``leaves`` (the spans that hold
+    no span), ``last_op_end_ns``, ``idle`` (``{leaf: ns}`` of the device's
+    idle time inside the fit) and ``before_first_op`` (the same for the
+    stretch up to the fit's first operation, what ``handoff_ms`` measures).
+    ``scopes`` is the operations' own time between the first fit's start and
+    the last one's end, over the chips.  ``fits`` is empty without a
+    ``bench.fit``; ``scopes`` without a chip."""
     host = [line["events"] for p in planes if p["name"].startswith("/host:")
             for line in p["lines"]]
     windows = sorted((e[1], e[1] + e[2]) for events in host for e in events
@@ -238,21 +239,22 @@ def reduce(planes: list) -> dict:
         idle = [(a, b) for a, b in zip([fs] + [e for _, e in busy],
                                        [s for s, _ in busy] + [fe]) if b > a]
         fits.append({
-            "start_ns": fs, "end_ns": fe, "spans": spans,
+            "start_ns": fs, "end_ns": fe, "spans": spans, "leaves": leaves,
             "last_op_end_ns": busy[-1][1] if busy else None,
             "idle": _cut(idle, leaves),
             "before_first_op": _cut(
                 idle[:1] if busy and busy[0][0] > fs else [], leaves)})
-    scopes = {}
+    scopes, op_scopes = {}, {}
     if windows and devices:
         lo, hi = windows[0][0], windows[-1][1]
         for plane in devices:
             ops = [e for e in _events(plane, OPS_LINE)
                    if e[1] + e[2] > lo and e[1] < hi]
             for name, ns in _self_times(ops).items():
-                scope = scope_of(plane["op_names"].get(name))
+                scope = op_scopes[name] = scope_of(
+                    plane["op_names"].get(name))
                 scopes[scope] = scopes.get(scope, 0.0) + ns / len(devices)
-    return {"fits": fits, "scopes": scopes}
+    return {"fits": fits, "scopes": scopes, "op_scopes": op_scopes}
 
 
 @functools.lru_cache(maxsize=None)
@@ -273,6 +275,28 @@ def of(trace: dict, run: dict):
             abs(a - b) > MATCH_NS for a, b in zip(starts, wanted)):
         return None
     return reduced
+
+
+def breakdown(trace: dict, run: dict) -> dict:
+    """The last line's ``breakdown`` from ``bench/trace.py``'s two lists:
+    each operation named ``<sgd.* scope>: <HLO text>`` and each gap ``<leaf
+    span that covers most of it>: <its place in the fit>``; the lists as they
+    are where the run's file resolves neither (``of`` is None)."""
+    reduced = of(trace, run)
+    ops, gaps = trace["device_ops"], trace["idle_gaps"]
+    if reduced is not None:
+        ops = [[f"{reduced['op_scopes'].get(name, UNSCOPED)}: {name}", s]
+               for name, s in ops]
+        named = []
+        for (name, s), (lo, hi) in zip(gaps, trace["idle_gap_ns"]):
+            fit = next((f for f in reduced["fits"]
+                        if f["start_ns"] <= lo and hi <= f["end_ns"]), None)
+            cut = _cut([(lo, hi)], fit["leaves"]) if fit else {}
+            named.append([f"{max(cut, key=cut.get, default=UNSPANNED)}: "
+                          f"{name}", s])
+        gaps = named
+    return {"device_ops": [[name[:NAME_CHARS], s] for name, s in ops],
+            "idle_gaps": gaps}
 
 
 # -- what the readers under bench/layers/ share ---------------------------------

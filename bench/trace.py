@@ -2,7 +2,8 @@
 read: per traced fit its span, the device's busy time inside it, the programs
 launched and the first operation's start; over all traced fits the busy time,
 the operations that took most time and the longest idle gaps, each named by
-the part of the fit the host was in.
+its place in the fit (``bench/spans.py``'s ``breakdown`` puts the step's scope
+in front of an operation and the program's span in front of a gap).
 
 ``load`` turns the file into plain dicts with nothing but JAX
 (``jax.profiler.ProfileData``); ``reduce`` works on those dicts, so a test
@@ -21,7 +22,7 @@ FIT = "bench.fit"
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 TOP = 10
-NAME_CHARS = 160  # an operation's name is its whole HLO instruction
+NAME_CHARS = 160  # of a breakdown's name: an operation's is its whole HLO
 
 
 def load(path: str) -> list:
@@ -88,15 +89,18 @@ def _events(plane, line_name):
 
 def reduce(planes: list) -> dict:
     """See the module's docstring; times in nanoseconds except the two
-    ``breakdown`` lists, which are in seconds.  ``fits`` is empty where the
-    trace has no ``bench.fit``; ``devices`` is 0 where it has no chip."""
+    ``breakdown`` lists, which are in seconds (``idle_gap_ns`` holds each
+    listed gap's ``[start, end]``, in the list's order).  ``fits`` is empty
+    where the trace has no ``bench.fit``; ``devices`` is 0 where it has no
+    chip."""
     fits = sorted((start, start + dur)
                   for p in planes if p["name"].startswith("/host:")
                   for line in p["lines"]
                   for name, start, dur in line["events"] if name == FIT)
     devices = [p for p in planes if DEVICE_PLANE.match(p["name"])]
     out = {"devices": len(devices), "fits": [], "window_ns": 0.0,
-           "busy_ns": 0.0, "device_ops": [], "idle_gaps": []}
+           "busy_ns": 0.0, "device_ops": [], "idle_gaps": [],
+           "idle_gap_ns": []}
     if not devices:
         return out
     if fits:
@@ -129,24 +133,25 @@ def reduce(planes: list) -> dict:
             per_fit[i]["programs"] += sum(
                 fs <= s + d / 2 < fe for _, s, d in modules) / len(devices)
             if not inside:
-                gaps.append((f"{tag}fit {i}: no operation", fe - fs))
+                gaps.append((f"{tag}fit {i}: no operation", fs, fe))
                 continue
             first = inside[0][0]
             if per_fit[i]["first_op_ns"] is None \
                     or first < per_fit[i]["first_op_ns"]:
                 per_fit[i]["first_op_ns"] = first
-            gaps.append((f"{tag}fit {i}: before first operation",
-                         first - fs))
+            gaps.append((f"{tag}fit {i}: before first operation", fs, first))
             gaps.append((f"{tag}fit {i}: after last operation",
-                         fe - inside[-1][1]))
+                         inside[-1][1], fe))
             for (_, e0), (s1, _) in zip(inside, inside[1:]):
                 in_program = any(ls <= e0 and s1 <= le for ls, le in launches)
                 gaps.append((f"{tag}fit {i}: " + ("inside a program"
-                             if in_program else "between programs"), s1 - e0))
+                             if in_program else "between programs"), e0, s1))
     out["fits"] = per_fit
     out["busy_ns"] = busy_total / len(devices)
-    out["device_ops"] = [[n[:NAME_CHARS], ns / 1e9] for n, ns in sorted(
+    out["device_ops"] = [[n, ns / 1e9] for n, ns in sorted(
         own.items(), key=lambda kv: -kv[1])[:TOP]]
-    out["idle_gaps"] = [[n, ns / 1e9] for n, ns in sorted(
-        gaps, key=lambda kv: -kv[1])[:TOP] if ns > 0]
+    longest = [g for g in sorted(gaps, key=lambda g: g[1] - g[2])[:TOP]
+               if g[2] > g[1]]
+    out["idle_gaps"] = [[n, (e - s) / 1e9] for n, s, e in longest]
+    out["idle_gap_ns"] = [[s, e] for _, s, e in longest]
     return out

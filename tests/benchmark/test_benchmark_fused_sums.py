@@ -2,8 +2,7 @@
 the helpers of ``test_benchmark_spans.py``: it reads the one-read kernel's
 scope where the step took it (``margins_ms`` and ``gradient_ms`` then read
 the little each half keeps outside the kernel), and nothing where the step
-took the two matvecs — the parent's program, which the driver runs with this
-reader."""
+took the two matvecs: the windowed cell's step, and PR 26's parent."""
 
 import importlib.util
 import os
@@ -40,9 +39,9 @@ def test_fused_sums_ms_reads_the_kernels_scope(checkout):
     reduced, run = checkout(H._text(ops=OPS, tf_ops=TF_OPS))
     # (50 + 5 + 20) ms over 2 fits of 10 iterations
     assert H._read("fused_sums_ms", reduced, run) == pytest.approx(3.75)
-    # the two halves' own metrics read what each leaves outside the kernel
-    # (the innermost scope names an operation), so a traced line of either
-    # cell still holds them
+    # the two halves' own readers find what each leaves outside the kernel
+    # (the innermost scope names an operation): microseconds, which is why
+    # their entries list the windowed cell alone since PR 27
     assert H._read("margins_ms", reduced, run) == pytest.approx(1 / 20)
     assert H._read("gradient_ms", reduced, run) == pytest.approx(2 / 20)
     # the while's own 2 ms are the only time under no scope

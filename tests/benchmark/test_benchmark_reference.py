@@ -7,7 +7,7 @@ import pytest
 from jax.experimental.sparse import BCOO
 
 from bench import cells, correct
-from bench.reference import glm_dense, glm_sparse, rules
+from bench.reference import glm_dense, glm_dense_window, glm_sparse, rules
 
 GRADIENTS = ("LeastSquaresGradient", "LogisticGradient", "HingeGradient")
 UPDATERS = ("SimpleUpdater", "SquaredL2Updater", "L1Updater")
@@ -178,6 +178,92 @@ def test_lower_operand_precision_moves_the_dense_reference(operands,
                                                           worse_than):
     cell = cells.Cell("dense1000-logistic.resident",
                       overrides={"rows": 8192, "features": 64})
+    X, y = cell.generator.make(cell.config, cell.rows, 2)
+    w0 = np.zeros(64, np.float32)
+    ref = cell.reference.fit(cell.config, X, y, w0, 42)
+    low = cell.reference.fit(cell.config, jnp.array(X), y, w0, 42,
+                             operands=operands)
+    assert correct.readings(*low, *ref, w0)["w_rel_gap"] > worse_than
+
+
+# -- the windowed reference (sampling="sliced") -------------------------------
+
+SLICED = "dense1000-logistic-sliced.resident"
+ALL = cells.benchmark(with_prepared=True)  # the cell may wait prepared
+
+
+def test_window_reference_follows_two_windowed_steps_by_hand():
+    """Five rows, a window of two (fraction 0.4): each step trains the rows
+    from the offset the seed gives, normalised by the window's two rows."""
+    import jax
+
+    X = np.array([[1.0, 2.0], [0.5, -1.0], [-1.5, 0.25], [2.0, 0.5],
+                  [-0.5, -0.75]], np.float32)
+    y = np.array([1.0, 0.0, 1.0, 0.0, 1.0], np.float32)
+    key = jax.random.PRNGKey(42)
+    starts = [int(jax.random.randint(jax.random.fold_in(key, t), (), 0, 4))
+              for t in (1, 2)]
+    assert starts == [int(glm_dense_window.offset(key, t, 5, 2))
+                      for t in (1, 2)]
+    assert all(0 <= o <= 3 for o in starts)
+    assert glm_dense_window.window_rows(5, 0.4) == 2
+    w, losses = np.zeros(2), []
+    for t, o in zip((1, 2), starts):
+        Xw, yw = X[o:o + 2], y[o:o + 2]
+        m = Xw @ w
+        losses.append(np.mean(np.where(yw > 0, np.log1p(np.exp(-m)),
+                                       np.log1p(np.exp(m))))
+                      + 0.005 * np.sum(w * w))
+        g = Xw.T @ (1 / (1 + np.exp(-m)) - yw) / 2
+        eta = 0.5 / np.sqrt(t)
+        w = w * (1 - eta * 0.01) - eta * g
+    got_w, got_l = glm_dense_window.fit(
+        _config("LogisticGradient", "SquaredL2Updater",
+                mini_batch_fraction=0.4), X, y, np.zeros(2), seed=42)
+    np.testing.assert_allclose(got_w, w, rtol=1e-5)
+    np.testing.assert_allclose(got_l, losses, rtol=1e-5)
+
+
+def test_a_window_of_everything_is_the_full_batch_fit():
+    rng = np.random.default_rng(7)
+    X = rng.normal(size=(24, 5)).astype(np.float32)
+    y = (rng.random(24) < 0.5).astype(np.float32)
+    config = _config("LogisticGradient", "SquaredL2Updater", num_iterations=4)
+    a = glm_dense_window.fit(config, X, y, np.zeros(5), seed=3)
+    b = glm_dense.fit(config, X, y, np.zeros(5), seed=3)
+    np.testing.assert_allclose(a[0], b[0], rtol=1e-6)
+    np.testing.assert_allclose(a[1], b[1], rtol=1e-6)
+
+
+def test_window_reference_draws_the_programs_offsets():
+    """The program's sliced fit and the reference's agree step by step only
+    if both train the same windows: the gaps are under the committed limits
+    with the program's seed, and far over with another."""
+    cell = cells.Cell(SLICED, ALL, overrides={"rows": 4096, "features": 32,
+                                              "num_iterations": 6})
+    assert cell.config["sampling"] == "sliced"
+    assert cell.reference.__file__.endswith("glm_dense_window.py")
+    X, y = cell.generator.make(cell.config, cell.rows, 5)
+    w, losses = cell.entry.prepare(cell.config, X, y, 42)()
+    w0 = np.zeros(32, np.float32)
+    ref = cell.reference.fit(cell.config, X, y, w0, 42)
+    got = correct.readings(np.asarray(w), losses, *ref, w0)
+    limits = cell.config["limits"]
+    assert all(got[n] <= limits[n] for n in correct.NUMBERS), got
+    other = cell.reference.fit(cell.config, X, y, w0, 43)
+    assert correct.readings(np.asarray(w), losses, *other, w0)[
+        "loss_max_gap"] > 10 * max(got["loss_max_gap"], limits["loss_max_gap"])
+    # and not the Bernoulli draws of the sibling's reference
+    masked = glm_dense.fit(cell.config, X, y, w0, 42)
+    assert correct.readings(np.asarray(w), losses, *masked, w0)[
+        "loss_max_gap"] > limits["loss_max_gap"]
+
+
+@pytest.mark.parametrize("operands,worse_than", [("bfloat16", 1e-5),
+                                                 ("float8_e4m3fn", 3e-3)])
+def test_lower_operand_precision_moves_the_window_reference(operands,
+                                                           worse_than):
+    cell = cells.Cell(SLICED, ALL, overrides={"rows": 8192, "features": 64})
     X, y = cell.generator.make(cell.config, cell.rows, 2)
     w0 = np.zeros(64, np.float32)
     ref = cell.reference.fit(cell.config, X, y, w0, 42)

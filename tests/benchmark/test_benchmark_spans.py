@@ -1,7 +1,13 @@
-"""``bench/spans.py`` and the nine readers built on it, on traces written by
-hand as text and read back through the same path as a run's: two fits, a
-hand-off split across ``train.h2d`` and ``train.fetch``, stretches no span
-covers, a ``while`` with scoped and unscoped bodies."""
+"""``bench/spans.py`` and the readers built on it, on traces written by hand
+as text and read back through the same path as a run's: two fits, a hand-off
+split across ``train.h2d`` and ``train.fetch``, stretches no span covers, a
+``while`` with scoped and unscoped bodies; then the same on four chips.
+
+Which metrics are "span metrics" is read from ``bench/layers/``: every reader
+that imports ``bench.spans``.  A PR that adds one adds its file and its entry,
+and the tests below that take ``SPAN_METRICS`` hold it to the same rules."""
+
+import os
 
 import pytest
 
@@ -9,10 +15,17 @@ from bench import cells, spans, trace
 
 MS = 1e6  # nanoseconds
 WORKLOAD = "dense1000-logistic.from-host"
-NINE = {
-    "validate_ms": [WORKLOAD], "plan_ms": [WORKLOAD], "h2d_ms": [WORKLOAD],
-    "dispatch_ms": None, "fetch_ms": None, "idle_unspanned_share": None,
-    "margins_ms": None, "gradient_ms": None, "step_unscoped_share": None}
+SPAN_METRICS = sorted(
+    name for name in (f[:-3] for f in os.listdir(
+        os.path.join(cells.BENCH, "layers")) if f.endswith(".py"))
+    if getattr(cells.load_module("layers", name), "spans", None) is spans)
+#: what the entries of PRs 25 to 27 state: host time inside a span alone, or
+#: the device's lines too.  A later metric states either and is not listed
+SOURCE = {**dict.fromkeys(("validate_ms", "plan_ms", "h2d_ms", "dispatch_ms"),
+                          "program_span"),
+          **dict.fromkeys(("fetch_ms", "idle_unspanned_share", "margins_ms",
+                           "gradient_ms", "step_unscoped_share",
+                           "fused_sums_ms"), "device_trace")}
 
 #: fit 0 is [0, 100) ms, fit 1 [100, 200) ms; (name, start ms, length ms,
 #: stats).  ``fit.run`` and ``train.run`` hold spans, so they are no leaves.
@@ -45,8 +58,11 @@ TF_OPS = {M: "jit(sgd_run)/while/body/sgd.margins/dot_general:",
           WHILE: "jit(sgd_run)/while:"}
 
 
-def _text(host=HOST, ops=OPS, tf_ops=TF_OPS, device="/device:TPU:0"):
-    """An XSpace as text: one host thread, one chip."""
+def _text(host=HOST, ops=OPS, tf_ops=TF_OPS, device="/device:TPU:0",
+          modules=()):
+    """An XSpace as text: one host thread and one chip, or with ``ops`` a
+    ``{device plane's name: its operations}`` one chip for each; ``modules``
+    are the launches on every chip's ``XLA Modules`` line."""
     stat_ids, event_ids = {"tf_op": 1}, {}
 
     def stat(key, value):
@@ -70,39 +86,48 @@ def _text(host=HOST, ops=OPS, tf_ops=TF_OPS, device="/device:TPU:0"):
     host_meta = "\n".join(
         f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}" }} }}'
         for n, i in event_ids.items())
-    op_ids = {}
-    op_events = events(ops, op_ids)
-    # the last operation names its op_name by reference, as a string that a
-    # plane holds once may be
-    refs = list(tf_ops)[-1:] if tf_ops else []
-    op_meta = "\n".join(
-        f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}" '
-        + (f"stats {{ metadata_id: 1 ref_value: {100 + i} }}" if n in refs
-           else stat("tf_op", tf_ops[n]) if n in tf_ops else "") + " } }"
-        for n, i in op_ids.items())
-    ref_meta = "\n".join(
-        f'stat_metadata {{ key: {100 + i} value {{ id: {100 + i} '
-        f'name: "{tf_ops[n]}" }} }}' for n, i in op_ids.items() if n in refs)
-    stat_meta = "\n".join(
-        f'stat_metadata {{ key: {i} value {{ id: {i} name: "{k}" }} }}'
-        for k, i in stat_ids.items())
-    planes = f"""
-    planes {{ name: "/host:CPU"
-      lines {{ name: "python3" timestamp_ns: 0
-        {host_events} }}
-      {host_meta}
-      {stat_meta} }}"""
-    if device:
-        planes += f"""
-    planes {{ name: "{device}"
+
+    def stat_meta():
+        return "\n".join(
+            f'stat_metadata {{ key: {i} value {{ id: {i} name: "{k}" }} }}'
+            for k, i in stat_ids.items())
+
+    def chip(name, ops):
+        op_ids = {}
+        op_events = events(ops, op_ids)
+        launches = events(modules, op_ids)
+        # the last operation names its op_name by reference, as a string
+        # that a plane holds once may be
+        refs = list(tf_ops)[-1:] if tf_ops else []
+        op_meta = "\n".join(
+            f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}" '
+            + (f"stats {{ metadata_id: 1 ref_value: {100 + i} }}" if n in refs
+               else stat("tf_op", tf_ops[n]) if n in tf_ops else "") + " } }"
+            for n, i in op_ids.items())
+        ref_meta = "\n".join(
+            f'stat_metadata {{ key: {100 + i} value {{ id: {100 + i} '
+            f'name: "{tf_ops[n]}" }} }}'
+            for n, i in op_ids.items() if n in refs)
+        return f"""
+    planes {{ name: "{name}"
+      lines {{ name: "XLA Modules" timestamp_ns: 0
+        {launches} }}
       lines {{ name: "XLA Ops" timestamp_ns: 0
         {op_events} }}
       lines {{ name: "Async XLA Ops" timestamp_ns: 0
         events {{ metadata_id: 1 offset_ps: 0 duration_ps: 5 }} }}
       {op_meta}
-      {stat_meta}
+      {stat_meta()}
       {ref_meta} }}"""
-    return planes
+
+    planes = f"""
+    planes {{ name: "/host:CPU"
+      lines {{ name: "python3" timestamp_ns: 0
+        {host_events} }}
+      {host_meta}
+      {stat_meta()} }}"""
+    chips = ops if isinstance(ops, dict) else {device: ops} if device else {}
+    return planes + "".join(chip(name, o) for name, o in chips.items())
 
 
 @pytest.fixture
@@ -196,7 +221,7 @@ def test_overlapping_leaves_of_two_threads_share_a_gap_once():
         "a.x": 6.0, "b.y": 2.0, spans.UNSPANNED: 2.0}
 
 
-# -- the nine readers ------------------------------------------------------------
+# -- the readers -----------------------------------------------------------------
 
 @pytest.mark.parametrize("metric,expected", [
     ("validate_ms", (4 + 2) / 2), ("plan_ms", (1 + 3) / 2),
@@ -209,7 +234,7 @@ def test_reader(checkout, metric, expected):
     assert _read(metric, *checkout(_text())) == pytest.approx(expected)
 
 
-@pytest.mark.parametrize("metric", sorted(NINE))
+@pytest.mark.parametrize("metric", SPAN_METRICS)
 def test_a_reader_with_nothing_to_read_returns_nothing(checkout, metric,
                                                        tmp_path):
     reduced, run = checkout(_text())
@@ -230,7 +255,7 @@ def test_a_reader_with_nothing_to_read_returns_nothing(checkout, metric,
     assert _read(metric, reduced, run) is None
 
 
-@pytest.mark.parametrize("metric", sorted(NINE))
+@pytest.mark.parametrize("metric", SPAN_METRICS)
 def test_a_program_without_spans_or_scopes_gives_nothing(checkout, metric):
     """The parent of the PR that added them: ``bench.fit`` and the device's
     lines are there, no span, no ``sgd.*`` in any ``op_name``."""
@@ -247,21 +272,122 @@ def test_the_reduction_is_read_once_a_process(checkout, monkeypatch):
     assert spans.of(reduced, run) is first
 
 
+# -- the breakdown --------------------------------------------------------------
+
+def test_the_breakdown_names_operations_by_scope_and_gaps_by_span(checkout):
+    reduced, run = checkout(_text())
+    got = spans.breakdown(reduced, run)
+    assert [n for n, _ in got["device_ops"]] == [
+        f"sgd.margins: {M}", f"(unscoped): {COPY}", f"sgd.gradient: {G}",
+        f"(unscoped): {WHILE}"]
+    # fetch covers [20, 97) of fit 0 and [108, 198) of fit 1; nobody [0, 1)
+    gaps = dict((n, s) for n, s in got["idle_gaps"])
+    assert gaps["train.fetch: fit 1: after last operation"] \
+        == pytest.approx(0.040)
+    assert gaps["train.fetch: fit 0: after last operation"] \
+        == pytest.approx(0.010)
+    # [0, 30): h2d 10, fetch 10, validate 4, ... - the first of the longest
+    assert gaps["train.h2d: fit 0: before first operation"] \
+        == pytest.approx(0.030)
+    assert gaps["train.fetch: fit 1: between programs"] == pytest.approx(0.010)
+    # only the names change: the seconds and the order are the trace's
+    assert [s for _, s in got["device_ops"]] \
+        == [s for _, s in reduced["device_ops"]]
+    assert [[n.split(": ", 1)[1], s] for n, s in got["idle_gaps"]] \
+        == reduced["idle_gaps"]
+
+
+def test_a_breakdown_that_resolves_nothing_keeps_the_traces_names(checkout):
+    reduced, run = checkout(_text())
+    long = "%fusion.9 = " + "f32[4194304]{0:T(1024)} " * 20
+    other = dict(reduced, device_ops=[[long, 1.0]])
+    got = spans.breakdown(other, dict(run, workload="some.other"))
+    assert got["idle_gaps"] == reduced["idle_gaps"]
+    assert got["device_ops"] == [[long[:trace.NAME_CHARS], 1.0]]
+    # a gap that no leaf covers, and an operation the file does not name
+    bare = [e for e in HOST if e[0] == "bench.fit"]
+    reduced, run = checkout(_text(host=bare))
+    got = spans.breakdown(dict(reduced, device_ops=[[long, 1.0]]), run)
+    assert got["device_ops"][0][0] == f"(unscoped): {long}"[:trace.NAME_CHARS]
+    assert all(n.startswith("(unspanned): fit ") for n, _ in got["idle_gaps"])
+
+
+# -- four chips ------------------------------------------------------------------
+
+CHIPS = [f"/device:TPU:{n}" for n in range(4)]
+ALLREDUCE = "%all-reduce.3 = all-reduce(g)"
+#: every chip runs the margins' fusion from 30 ms (40, 36, 32 and 28 ms long)
+#: and then the all-reduce to 80 ms: the chip that is done first waits longest
+FOUR = {chip: [(WHILE, 30, 50), (M, 30, 40 - 4 * n),
+               (ALLREDUCE, 70 - 4 * n, 10 + 4 * n), (G, 110, 20 + n)]
+        for n, chip in enumerate(CHIPS)}
+FOUR_TF_OPS = {**TF_OPS,
+               ALLREDUCE: "jit(sgd_run)/while/body/sgd.allreduce/psum:"}
+LAUNCHES = [("jit_sgd_run(7)", 30, 50), ("jit_sgd_run(7)", 110, 25)]
+
+
+def test_four_device_planes_reduce_to_per_device_means(checkout):
+    reduced, run = checkout(_text(ops=FOUR, tf_ops=FOUR_TF_OPS,
+                                  modules=LAUNCHES))
+    assert reduced["devices"] == 4
+    # busy: 50 ms of fit 0 on every chip, 20 + n of fit 1
+    assert reduced["busy_ns"] == pytest.approx((50 + 21.5) * MS)
+    f0, f1 = reduced["fits"]
+    assert f0["busy_ns"] == pytest.approx(50 * MS) and f0["programs"] == 1
+    assert f1["busy_ns"] == pytest.approx(21.5 * MS) and f1["programs"] == 1
+    assert f0["first_op_ns"] == 30 * MS and f1["first_op_ns"] == 110 * MS
+    # own time, a chip: margins 34 + the mean of fit 1's 21.5 under
+    # sgd.gradient, the all-reduce's mean 16, nothing left to the while
+    resolved = spans.of(reduced, run)
+    assert resolved["scopes"] == pytest.approx({
+        "sgd.margins": 34 * MS, "sgd.allreduce": 16 * MS,
+        "sgd.gradient": 21.5 * MS, spans.UNSCOPED: 0.0})
+    assert resolved["op_scopes"][ALLREDUCE] == "sgd.allreduce"
+    # the device is idle only while no chip is busy: fit 1's last operation
+    # ends with chip 3's, at 133 ms
+    assert [f["last_op_end_ns"] for f in resolved["fits"]] \
+        == [80 * MS, 133 * MS]
+    assert _read("step_ms", reduced, run) == pytest.approx(71.5 / 2 / 10)
+    assert _read("programs_per_fit", reduced, run) == 1
+    assert _read("device_idle_share", reduced, run) \
+        == pytest.approx(100 * (1 - 71.5 / 200))
+    assert _read("handoff_ms", reduced, run) == pytest.approx((30 + 10) / 2)
+    assert _read("margins_ms", reduced, run) == pytest.approx(34 / 2 / 10)
+    assert _read("gradient_ms", reduced, run) == pytest.approx(21.5 / 2 / 10)
+    assert _read("step_unscoped_share", reduced, run) == 0.0
+    assert _read("fetch_ms", reduced, run) \
+        == pytest.approx(((97 - 80) + (198 - 133)) / 2)
+    # idle inside the fits: [0, 30) + [80, 100), [100, 110) + [133, 200); of
+    # it no leaf covers [0, 1), [16, 18), [97, 100) and [198, 200)
+    assert _read("idle_unspanned_share", reduced, run) \
+        == pytest.approx(100 * (1 + 2 + 3 + 2) / (50 + 77))
+    # each chip's gaps are its own, named by the chip
+    names = [n for n, _ in spans.breakdown(reduced, run)["idle_gaps"]]
+    assert any(n.startswith("train.fetch: /device:TPU:0 fit 1: after last")
+               for n in names)
+
+
 # -- BENCHMARK.json ------------------------------------------------------------
 
-def test_the_nine_metrics_are_entries_with_readers():
-    bench = cells.benchmark()
-    both = [w["name"] for w in bench["workloads"]]
-    entries = {m["name"]: m for m in bench["per_layer"]}
-    for name, workloads in NINE.items():
-        entry = entries[name]
-        assert entry["workloads"] == (workloads or both), name
-        assert entry["moves"] == "rows_per_s"
-        assert entry["source"] == ("program_span" if name in (
-            "validate_ms", "plan_ms", "h2d_ms", "dispatch_ms")
-            else "device_trace")
-    assert list(entries)[-9:] == list(NINE)  # appended, in the issue's order
-    for cell in both:
-        readers = cells.Cell(cell).readers
-        assert {n for n, w in NINE.items() if not w or cell in w} \
-            <= set(readers)
+@pytest.mark.parametrize("metric", SPAN_METRICS)
+def test_a_span_metric_is_an_entry_with_a_reader(metric):
+    """Every reader built on ``bench/spans.py`` has its entry; the entry
+    moves an end-to-end metric, reads a span or the trace, and the cells it
+    lists (all, where it lists none) exist and load its reader.  Nothing
+    here names a cell or a position in the list: a cell or a metric is added
+    with new files and new entries alone."""
+    bench = cells.benchmark(with_prepared=True)
+    assert metric in [m["name"] for m in bench["per_layer"]], \
+        f"bench/layers/{metric}.py has no entry in BENCHMARK.json " \
+        "or under bench/prepared/"
+    entry = next(m for m in bench["per_layer"] if m["name"] == metric)
+    assert entry["moves"] in [m["name"] for m in bench["end_to_end"]]
+    assert entry["source"] in ("program_span", "device_trace")
+    assert entry["source"] == SOURCE.get(metric, entry["source"])
+    named = [w["name"] for w in bench["workloads"]]
+    listed = entry.get("workloads", named)
+    assert listed and set(listed) <= set(named)
+    for cell in listed:
+        assert metric in cells.Cell(cell, bench).readers
+    for cell in set(named) - set(listed):
+        assert metric not in cells.Cell(cell, bench).readers
