@@ -316,6 +316,39 @@ def test_dp_whole_run_4_devices_has_all_reduce(mesh4, S):
     assert _moves_of(text, N // 4, D) == []
 
 
+def test_dp_whole_run_at_the_four_chip_cells_shape_trains_each_shard_in_place(
+        mesh4, S):
+    """The benchmark's four-chip fit (``dense1000-lsq-dp4.resident-sharded``:
+    10,000,000 x 1000 bf16, least squares, fraction 0.1) as ``set_mesh``
+    dispatches it: every chip holds its 2,500,000 rows (5.01 GB of
+    arguments), the one-read kernel takes the shard as it is stored (no
+    X-sized array is made, temporaries under 1% of the shard), and one
+    all-reduce a step carries the gradient, loss and count sums."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpu_sgd.parallel.data_parallel import dp_run_fn
+    from tpu_sgd.parallel.mesh import DATA_AXIS
+
+    n = 10_000_000
+    fn = dp_run_fn(LeastSquaresGradient(), SimpleUpdater(),
+                   _cfg(step_size=1.0, num_iterations=100, reg_param=0.0,
+                        convergence_tol=0.0), mesh4, with_valid=False)
+    compiled = fn.lower(
+        S((D,), F32, NamedSharding(mesh4, P())),
+        S((n, D), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
+        S((n,), F32, NamedSharding(mesh4, P(DATA_AXIS))),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert text.count(" all-reduce(") == 1 and "sgd.allreduce" in text
+    assert "all-gather" not in text
+    assert _moves_of(text, n // 4, D) == [] and _moves_of(text, n, D) == []
+    memory = compiled.memory_analysis()
+    shard = n // 4 * D * 2
+    assert shard <= memory.argument_size_in_bytes < shard * 1.01
+    assert memory.temp_size_in_bytes < shard // 100
+
+
 # -- sparse ------------------------------------------------------------------
 
 def test_sparse_hinge_l1_step_compiles(S):

@@ -268,9 +268,17 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
         tpu_sgd.SquaredL2Updater()).set_num_iterations(3)
     opt.optimize_with_history((jnp.asarray(X), jnp.asarray(y)),
                               np.zeros(8, np.float32))
+    # a meshed fit: from host arrays, then on what the placement returned
+    import jax
+
+    mesh = tpu_sgd.data_mesh(jax.devices()[:4])
+    opt.set_mesh(mesh)
+    opt.optimize_with_history((X, y), np.zeros(8, np.float32))
+    Xd, yd, _ = tpu_sgd.parallel.shard_dataset(mesh, X, y)
+    opt.optimize_with_history((Xd, yd), np.zeros(8, np.float32))
     found = profiler_session()
 
-    assert len(found["fit.run"]) == 2 and len(found["train.run"]) == 3
+    assert len(found["fit.run"]) == 2 and len(found["train.run"]) == 5
     assert "fit.prepare" not in found  # no scaling, no intercept: no span
     for i, fit in enumerate(found["fit.run"]):
         assert fit[2] == {"rows": 256, "features": 8, "sparse": 0}
@@ -278,7 +286,7 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
             assert _inside(found[name][i], fit), name
         run = found["train.run"][i]
         assert run[2] == {"iterations": 4, "rows": 256, "path": "fused",
-                          "sums": "two_read"}
+                          "sums": "two_read", "shards": 1}
         for name in ("train.h2d", "train.dispatch", "train.fetch"):
             assert _inside(found[name][i], run), name
         assert found["train.h2d"][i][2]["bytes"] == X.nbytes + y.nbytes
@@ -287,10 +295,23 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
     assert [p[2]["cached"] for p in found["fit.plan"]] == [0, 1]
     assert found["fit.plan"][0][2]["schedule"] == "resident_stock"
     # the fit that builds its runner names itself
-    assert [d[2]["built"] for d in found["train.dispatch"]] == [1, 0, 1]
+    assert [d[2]["built"] for d in found["train.dispatch"]] == [1, 0, 1, 1,
+                                                                0]
     # device arrays at the Optimizer boundary: nothing to copy
     assert found["train.h2d"][2][2]["bytes"] == 0
     assert found["train.run"][2][2]["iterations"] == 3
+    # the meshed fits: ``train.place`` is a leaf of ``train.run`` between
+    # the copy and the dispatch; the second trains the arrays where they lie
+    assert "train.place" in found and len(found["train.place"]) == 2
+    for place, run, h2d, dispatch in zip(
+            found["train.place"], found["train.run"][3:],
+            found["train.h2d"][3:], found["train.dispatch"][3:]):
+        assert _inside(place, run) and h2d[1] <= place[0]
+        assert place[1] <= dispatch[0]
+        assert run[2]["path"] == "mesh" and run[2]["shards"] == 4
+    assert [p[2] for p in found["train.place"]] == [
+        {"shards": 4, "in_place": 0, "bytes": X.nbytes + y.nbytes},
+        {"shards": 4, "in_place": 1, "bytes": 0}]
 
 
 def test_train_run_span_says_which_sums_the_fit_took(rng):

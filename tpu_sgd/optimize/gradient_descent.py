@@ -1592,9 +1592,7 @@ class GradientDescent(Optimizer):
             args = (self.gradient, self.updater, self.config, self.mesh,
                     w0, X, y)
         elif self.mesh is not None:
-            from tpu_sgd.parallel.data_parallel import shard_dataset
-
-            Xd, yd, valid = shard_dataset(self.mesh, X, y)
+            Xd, yd, valid = self._place(X, y)
             stats = self._maybe_gram_dp(X, y, Xd, yd, valid)
             if stats is not None:
                 stats_leaves, block_rows = stats
@@ -1624,7 +1622,8 @@ class GradientDescent(Optimizer):
             args = (w0, X, y)
             if path == "fused" and not sparse_X:
                 held = (X.shape[0], next(iter(X.devices())), False)
-        run_span.set(path=path, sums=self._sums_of(X, w0, held))
+        run_span.set(path=path, sums=self._sums_of(X, w0, held),
+                     shards=1 if self.mesh is None else self.mesh.devices.size)
         with span("train.dispatch",
                   built=int(len(self._run_cache) > cached)):
             w, losses, n_rec = fn(*args)
@@ -1635,6 +1634,21 @@ class GradientDescent(Optimizer):
         if self.check_numerics:
             _raise_if_nonfinite(self._loss_history)
         return w, self._loss_history
+
+    def _place(self, X, y):
+        """``shard_dataset`` for this fit's mesh under the ``train.place``
+        span: ``in_place`` 1 where the dataset already lay sharded for the
+        mesh and is trained where it lies; ``bytes`` is what the placement
+        moved to lay it out (from the host or between devices), 0 in
+        place."""
+        from tpu_sgd.parallel.data_parallel import shard_dataset
+
+        with span("train.place", shards=self.mesh.devices.size) as sp:
+            Xd, yd, valid = shard_dataset(self.mesh, X, y)
+            in_place = Xd is X and yd is y
+            sp.set(in_place=int(in_place),
+                   bytes=0 if in_place else X.nbytes + y.nbytes)
+        return Xd, yd, valid
 
     def _sums_of(self, X, w0, held) -> str:
         """The ``train.run`` span's ``sums``: ``fused`` where this fit's
@@ -1959,9 +1973,7 @@ class GradientDescent(Optimizer):
                 X = (data, idx)  # component tuple; the stepper rebuilds
                 sparse_shape = (rows_local, d_feat)
             else:
-                from tpu_sgd.parallel.data_parallel import shard_dataset
-
-                X, y, valid = shard_dataset(self.mesh, X, y)
+                X, y, valid = self._place(X, y)
         step = self._stepper(with_valid=valid is not None,
                              sparse_shape=sparse_shape)
 

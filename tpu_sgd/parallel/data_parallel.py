@@ -49,8 +49,19 @@ def shard_dataset(mesh: Mesh, X, y) -> Tuple[Array, Array, Optional[Array]]:
     """Place ``(X, y)`` sharded over the 'data' axis of ``mesh``.
 
     Returns device arrays plus a ``valid`` mask (None when no padding was
-    needed).  This is the one host->device transfer of the whole run — the
-    analogue of the reference's initial ``RDD.cache()`` materialization.
+    needed).  For host arrays this is the one host->device transfer of the
+    whole run — the analogue of the reference's initial ``RDD.cache()``
+    materialization.
+
+    Place once, fit many: what this returns is laid out for ``mesh``, and
+    handed such arrays (its own result, or any ``jax.Array`` whose sharding
+    is equivalent to rows over 'data', whatever ``Mesh`` object spells it)
+    it returns them AS THEY ARE — no fetch, no copy, ``valid`` None.  So a
+    dataset is cached across the chips by calling this once and handing
+    the result to every fit; each fit then trains it where it lies.  A
+    ``jax.Array`` laid out otherwise (one device, another sharding) is
+    re-laid on the devices, never through the host; rows that do not
+    divide by the shards are zero-padded there and masked.
 
     Multi-host jobs (``jax.process_count() > 1`` after
     ``initialize_distributed``): ``X``/``y`` are each process's LOCAL rows —
@@ -58,15 +69,32 @@ def shard_dataset(mesh: Mesh, X, y) -> Tuple[Array, Array, Optional[Array]]:
     (SURVEY.md §3.4) — and the global sharded arrays are assembled without
     any cross-host data movement; only gradient psums ride DCN.
     """
+    if jax.process_count() > 1:
+        return _shard_dataset_multihost(mesh, np.asarray(X), np.asarray(y))
+    n_shards = mesh.shape[DATA_AXIS]
+    x_sharding = NamedSharding(mesh, P(DATA_AXIS, None))
+    row_sharding = NamedSharding(mesh, P(DATA_AXIS))
+    if isinstance(X, jax.Array):
+        y = jnp.asarray(y)  # the labels follow the rows
+        n = X.shape[0]
+        rem = (-n) % n_shards
+        if (not rem and X.sharding.is_equivalent_to(x_sharding, X.ndim)
+                and y.sharding.is_equivalent_to(row_sharding, y.ndim)):
+            return X, y, None
+        valid = None
+        if rem:
+            # graftlint: disable=shape-trap -- once-per-dataset placement of rows that are on devices already: host numpy would fetch them
+            X = jnp.concatenate([X, jnp.zeros((rem,) + X.shape[1:], X.dtype)])
+            # graftlint: disable=shape-trap -- as above
+            y = jnp.concatenate([y, jnp.zeros((rem,), y.dtype)])
+            valid = jax.device_put(jnp.arange(n + rem) < n, row_sharding)
+        return (jax.device_put(X, x_sharding),
+                jax.device_put(y, row_sharding), valid)
     Xh = np.asarray(X)
     yh = np.asarray(y)
-    if jax.process_count() > 1:
-        return _shard_dataset_multihost(mesh, Xh, yh)
-    n_shards = mesh.shape[DATA_AXIS]
     n = Xh.shape[0]
     Xh, yh, validh = pad_to_multiple(Xh, yh, n_shards)
-    row_sharding = NamedSharding(mesh, P(DATA_AXIS))
-    Xd = jax.device_put(Xh, NamedSharding(mesh, P(DATA_AXIS, None)))
+    Xd = jax.device_put(Xh, x_sharding)
     yd = jax.device_put(yh, row_sharding)
     if n == Xh.shape[0]:
         return Xd, yd, None
