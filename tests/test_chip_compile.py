@@ -349,6 +349,37 @@ def test_dp_whole_run_at_the_four_chip_cells_shape_trains_each_shard_in_place(
     assert memory.temp_size_in_bytes < shard // 100
 
 
+# -- the hand-off -------------------------------------------------------------
+
+@pytest.mark.parametrize("rows", ["full", "remainder"])
+def test_the_hand_offs_writer_writes_its_destination_in_place(S, rows):
+    """``_stage_block`` at the from-host cell's shape (2,145,000 x 1000 bf16
+    in blocks of ``_STAGE_BLOCK_BYTES``: 130 full ones and a remainder): the
+    donated destination IS the result (all of it aliased, no temporary, no
+    copy of it to another layout, the one X-sized array made is the
+    ``dynamic-update-slice`` itself), the block arrives in the layout the
+    chip gives a host array of its shape (feature-major: a window of rows is
+    a window of lanes), and the write carries the ``sgd.stage`` scope."""
+    from tpu_sgd.optimize.gradient_descent import (_STAGE_BLOCK_BYTES,
+                                                   _STAGE_ROWS, _stage_block)
+
+    n = CELL_ROWS["from-host"]
+    full = _STAGE_BLOCK_BYTES // (2 * D) // _STAGE_ROWS * _STAGE_ROWS
+    block = full if rows == "full" else n % full
+    assert full == 16_384 and -(-n // full) == 131 and 0 < block <= full
+    compiled = _stage_block.lower(S((n, D), BF16), S((block, D), BF16),
+                                  S((), I32)).compile()
+    text = compiled.as_text()
+    assert _moves_of(text, n, D) == ["dynamic-update-slice"]
+    assert "sgd.stage/dynamic_update_slice" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes == 0
+    assert memory.alias_size_in_bytes >= n * D * 2  # rows pad to 128
+    by_features = (1, 0)
+    assert [f.layout.major_to_minor for f in compiled.input_formats[0][:2]] \
+        == [by_features, by_features]
+
+
 # -- sparse ------------------------------------------------------------------
 
 def test_sparse_hinge_l1_step_compiles(S):

@@ -1,0 +1,256 @@
+"""A dense host array reaches the device in row blocks written into ONE
+``(N, d)`` array in place (``gradient_descent._stage_dense``): the array is
+``jnp.asarray``'s value for value whatever the rows, the width, the type and
+the host array's order; what is no large numpy array takes the calls it took
+before; the ``train.h2d`` span says how many pieces went; a fit from blocks is
+the fit from one piece bit for bit.  Tiny, CPU, the block cut to a few rows'
+bytes."""
+
+import jax
+import jax.numpy as jnp
+import ml_dtypes
+import numpy as np
+import pytest
+
+import tpu_sgd
+from tpu_sgd.obs.spans import disable_tracing, enable_tracing
+from tpu_sgd.ops.sparse import sparse_data
+from tpu_sgd.optimize import gradient_descent as gd
+
+#: rows a block holds in these tests (the least the helper cuts: whole
+#: multiples of ``_STAGE_ROWS``) and the blocks in flight
+ROWS, IN_FLIGHT = gd._STAGE_ROWS, 2
+
+
+def _blocks_of(monkeypatch, row_bytes, rows=ROWS, in_flight=IN_FLIGHT):
+    monkeypatch.setattr(gd, "_STAGE_BLOCK_BYTES", rows * row_bytes)
+    monkeypatch.setattr(gd, "_STAGE_IN_FLIGHT", in_flight)
+
+
+def _host(n, d, dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    if np.dtype(dtype) == np.bool_:
+        return rng.random((n, d)) < 0.5
+    if np.issubdtype(np.dtype(dtype), np.integer):
+        return rng.integers(-3, 4, (n, d)).astype(dtype)
+    return rng.normal(size=(n, d)).astype(dtype)
+
+
+def _same(got, X):
+    want = jnp.asarray(X)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert got.sharding == want.sharding
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# -- the array ----------------------------------------------------------------------
+
+#: under one block; one block exactly; an exact multiple; a remainder of one
+#: row; a remainder of most of a block; more blocks than may be in flight
+ROW_CASES = {"under": ROWS - 1, "one_block": ROWS, "multiple": 3 * ROWS,
+             "one_row_over": 2 * ROWS + 1, "remainder": 2 * ROWS + 1000,
+             "beyond_in_flight": 5 * ROWS + 7}
+
+
+@pytest.mark.parametrize("d", [1000, 128, 7])
+@pytest.mark.parametrize("case", sorted(ROW_CASES))
+def test_the_staged_array_is_jnp_asarrays(monkeypatch, case, d):
+    n = ROW_CASES[case]
+    X = _host(n, d, np.float32)
+    _blocks_of(monkeypatch, X.strides[0])
+    got, blocks, block_bytes = gd._stage_dense(X)
+    _same(got, X)
+    assert blocks == max(1, -(-n // ROWS))
+    assert block_bytes == (X.nbytes if blocks == 1 else ROWS * d * 4)
+
+
+@pytest.mark.parametrize("dtype", [ml_dtypes.bfloat16, np.float32, np.float64,
+                                   np.int8, np.bool_],
+                         ids=lambda t: np.dtype(t).name)
+def test_every_type_arrives_as_the_single_copy_brings_it(monkeypatch, dtype):
+    """bf16 and f32 as they are, f64 as ``jnp.asarray`` narrows it, int8 and
+    bool in their own type: the cast to f32 is the caller's, after the copy."""
+    X = _host(2 * ROWS + 5, 16, dtype)
+    _blocks_of(monkeypatch, X.strides[0])
+    got, blocks, _ = gd._stage_dense(X)
+    assert blocks == 3
+    _same(got, X)
+
+
+@pytest.mark.parametrize("order", ["fortran", "strided_rows", "strided_columns",
+                                   "reversed"])
+def test_a_host_array_in_another_order_arrives_right(monkeypatch, order):
+    """``np.asarray`` of a device array stored feature-major comes back
+    Fortran-ordered (the benchmark's from-host array is one); a user slices."""
+    base = _host(4 * ROWS + 6, 24, np.float32)
+    X = {"fortran": np.asfortranarray(base), "strided_rows": base[::2],
+         "strided_columns": base[:, ::3], "reversed": base[::-1]}[order]
+    assert not X.flags.c_contiguous
+    _blocks_of(monkeypatch, X.shape[1] * 4)
+    got, blocks, _ = gd._stage_dense(X)
+    assert blocks == -(-X.shape[0] // ROWS) > 1
+    _same(got, X)
+
+
+def test_a_wide_array_of_few_rows_goes_in_one_piece(monkeypatch):
+    """Over one block's bytes and under one block's least rows."""
+    X = _host(ROWS - 24, 64, np.float32)
+    monkeypatch.setattr(gd, "_STAGE_BLOCK_BYTES", 4096)
+    before = gd._stage_block._cache_size()
+    got, blocks, block_bytes = gd._stage_dense(X)
+    _same(got, X)
+    assert (blocks, block_bytes) == (1, X.nbytes)
+    assert gd._stage_block._cache_size() == before
+
+
+def test_what_is_no_large_numpy_array_takes_the_calls_it_took(monkeypatch):
+    """A device array and a BCOO come back as the same object, a numpy array
+    under one block goes through one ``jnp.asarray`` and no writer."""
+    _blocks_of(monkeypatch, 64)
+    Xd = jnp.asarray(_host(4 * ROWS, 16, np.float32))
+    before = gd._stage_block._cache_size()
+    with jax.transfer_guard("disallow"):
+        got, blocks, block_bytes = gd._stage_dense(Xd)
+    assert got is Xd and (blocks, block_bytes) == (0, 0)
+    small = _host(ROWS - 1, 16, np.float32)
+    got, blocks, block_bytes = gd._stage_dense(small)
+    _same(got, small)
+    assert (blocks, block_bytes) == (1, small.nbytes)
+    assert gd._stage_block._cache_size() == before
+    # BCOO features never reach the helper: the fit hands them on untouched
+    Xs, ys, _ = sparse_data(256, 32, nnz_per_row=4, seed=1)
+    seen = []
+    monkeypatch.setattr(gd, "_stage_dense", lambda X: seen.append(X))
+    opt = (tpu_sgd.GradientDescent(tpu_sgd.LeastSquaresGradient(),
+                                   tpu_sgd.SimpleUpdater())
+           .set_num_iterations(2))
+    routed = []
+    monkeypatch.setattr(
+        opt, "_optimize_routed",
+        lambda X, *a: routed.append(X) or (np.zeros(32, np.float32), []))
+    opt.optimize_with_history((Xs, ys), np.zeros(32, np.float32))
+    assert seen == [] and routed[0] is Xs
+
+
+def test_the_blocks_held_are_bounded_by_the_blocks_in_flight(monkeypatch):
+    """The host waits for the oldest write before it issues a block beyond
+    the bound: at no call of the writer are more than ``_STAGE_IN_FLIGHT``
+    earlier writes not known to be done, and every block is deleted."""
+    X = _host(7 * ROWS, 8, np.float32)
+    _blocks_of(monkeypatch, 32, in_flight=3)
+    waited, blocks = [], []
+    real = gd._stage_block
+
+    class Written:
+        def __init__(self, token):
+            self.token = token
+
+        def block_until_ready(self):
+            waited.append(self)
+            return self.token.block_until_ready()
+
+    def write(dest, block, offset):
+        dest, token = real(dest, block, offset)
+        blocks.append(block)
+        pending = len(blocks) - len(waited)
+        assert pending <= 3
+        return dest, Written(token)
+
+    monkeypatch.setattr(gd, "_stage_block", write)
+    got, n_blocks, _ = gd._stage_dense(X)
+    _same(got, X)
+    assert n_blocks == len(blocks) == 7 and len(waited) == 7 - 3
+    assert all(b.is_deleted() for b in blocks)
+
+
+# -- the span ---------------------------------------------------------------------
+
+class Sink:
+    def __init__(self):
+        self.records = []
+
+    def emit(self, kind, payload):
+        self.records.append(dict(payload))
+
+    def h2d(self):
+        return [p for p in self.records if p["name"] == "train.h2d"]
+
+
+def _opt():
+    return (tpu_sgd.GradientDescent(tpu_sgd.LeastSquaresGradient(),
+                                    tpu_sgd.SimpleUpdater())
+            .set_step_size(0.1).set_num_iterations(6)
+            .set_mini_batch_fraction(0.5).set_convergence_tol(0.0))
+
+
+def test_train_h2d_says_how_many_pieces_went(monkeypatch):
+    X = _host(3 * ROWS + 9, 8, np.float32)
+    y = X @ np.arange(8, dtype=np.float32)
+    w0 = np.zeros(8, np.float32)
+    sink = Sink()
+    enable_tracing(sink)
+    try:
+        _opt().optimize_with_history((X, y), w0)  # under the real block
+        _blocks_of(monkeypatch, 32)
+        _opt().optimize_with_history((X, y), w0)
+        _opt().optimize_with_history((jnp.asarray(X), jnp.asarray(y)), w0)
+    finally:
+        disable_tracing()
+    one, many, device = sink.h2d()
+    assert (one["bytes"], one["blocks"], one["block_bytes"]) == (
+        X.nbytes + y.nbytes, 1, X.nbytes)
+    assert (many["bytes"], many["blocks"], many["block_bytes"]) == (
+        X.nbytes + y.nbytes, 4, ROWS * 32)
+    assert (device["bytes"], device["blocks"], device["block_bytes"]) == (
+        0, 0, 0)
+
+
+# -- the fit ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("model,dtype", [
+    ("LogisticRegressionWithSGD", np.float32),
+    ("LogisticRegressionWithSGD", ml_dtypes.bfloat16),
+    ("LinearRegressionWithSGD", np.float32),
+    ("LinearRegressionWithSGD", np.int8)],
+    ids=lambda v: v if isinstance(v, str) else np.dtype(v).name)
+def test_a_fit_from_blocks_is_the_fit_from_one_piece_bit_for_bit(
+        monkeypatch, model, dtype):
+    rng = np.random.default_rng(3)
+    X = _host(3 * ROWS + 100, 12, dtype, seed=3)
+    margin = X.astype(np.float32) @ rng.uniform(-1, 1, 12).astype(np.float32)
+    y = (margin > 0).astype(np.float32) if model.startswith("Logistic") \
+        else margin
+
+    def fit():
+        alg = getattr(tpu_sgd, model)(0.05, 10, mini_batch_fraction=0.5)
+        m = alg.run((X, y))
+        return np.asarray(m.weights), np.asarray(alg.optimizer.loss_history)
+
+    staged = []
+    real = gd._stage_dense
+    monkeypatch.setattr(
+        gd, "_stage_dense", lambda X: staged.append(real(X)) or staged[-1])
+    w_one, loss_one = fit()
+    _blocks_of(monkeypatch, X.strides[0])
+    w_blocks, loss_blocks = fit()
+    assert [s[1] for s in staged] == [1, 4]
+    assert len(loss_one) == 10 and np.isfinite(loss_one).all()
+    np.testing.assert_array_equal(w_blocks, w_one)
+    np.testing.assert_array_equal(loss_blocks, loss_one)
+
+
+def test_a_second_fit_of_the_same_shape_builds_no_program(monkeypatch):
+    X = _host(2 * ROWS + 300, 10, np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    _blocks_of(monkeypatch, X.strides[0])
+    alg = tpu_sgd.LogisticRegressionWithSGD(0.1, 4, mini_batch_fraction=0.5)
+    alg.run((X, y))
+    sizes = gd._stage_block._cache_size(), gd._stage_dest._cache_size()
+    runners = len(alg.optimizer._run_cache)
+    first = np.asarray(alg.optimizer.loss_history)
+    alg.run((X.copy(), y))
+    assert (gd._stage_block._cache_size(),
+            gd._stage_dest._cache_size()) == sizes
+    assert len(alg.optimizer._run_cache) == runners
+    np.testing.assert_array_equal(np.asarray(alg.optimizer.loss_history),
+                                  first)

@@ -30,6 +30,8 @@ stance):
 
 from __future__ import annotations
 
+import collections
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -79,6 +81,79 @@ def _coerce_w0(gradient, initial_weights, n_features):
             f"gradient needs {expect_dim} for {n_features}-feature data"
         )
     return w0
+
+
+#: How a dense host array goes to the device (``_stage_dense``): in row
+#: blocks of this many bytes, this many of them in flight, cut at whole
+#: multiples of ``_STAGE_ROWS`` rows (a window of rows is a window of lanes
+#: where the chip stores X feature-major: the writes stay tile-aligned).
+#: Set from the sweep on the v5e in PERF.md section 6 (PR 29's step 0,
+#: 2,145,000 x 1000 bf16): the runtime re-tiles one block on one thread at
+#: 10.6 GB/s, under the wire's 14.3, so the wire is kept busy only by
+#: several blocks re-tiling at once; 16 x 32 MiB reads 0.31 s against 0.69 s
+#: for the one piece and 0.60 s for 2 x 256 MiB, and holds 0.52 GB beside
+#: the dataset.  A block is far under 4 GiB, above which a host array is
+#: copied 24 times slower.
+_STAGE_BLOCK_BYTES = 32 << 20
+_STAGE_IN_FLIGHT = 16
+_STAGE_ROWS = 1024
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _stage_dest(shape, dtype):
+    """The array ``_stage_dense``'s blocks are written into."""
+    with jax.named_scope("sgd.stage"):
+        return jnp.zeros(shape, dtype)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _stage_block(dest, block, offset):
+    """``dest`` (donated: written in place) with ``block`` at rows
+    ``offset:``, and a scalar that is ready when the write is.  The offset
+    is an operand, so one program serves every full block and one more the
+    remainder."""
+    with jax.named_scope("sgd.stage"):
+        dest = jax.lax.dynamic_update_slice_in_dim(dest, block, offset,
+                                                   axis=0)
+        return dest, dest[offset, 0]
+
+
+def _stage_dense(X):
+    """Dense features on the device as ONE ``(N, d)`` array, and what the
+    ``train.h2d`` span says of the copy: ``(X, blocks, block_bytes)``.
+
+    A device array comes back as it is (0 blocks).  A numpy array of at
+    most one block goes in one ``jnp.asarray``.  A larger one goes in row
+    blocks (views: ``X[a:b]`` copies nothing on the host) issued back to
+    back, so that the runtime re-tiles the next blocks while one is on the
+    wire; each is written into the destination in place and deleted, and
+    the host waits for the oldest write before it issues a block beyond
+    ``_STAGE_IN_FLIGHT``, so the device holds the dataset plus the blocks
+    in flight, never the dataset twice.  The values are ``jnp.asarray``'s
+    (each block IS one), so the fit is the single copy's bit for bit."""
+    import numpy as np
+
+    if not isinstance(X, np.ndarray):
+        return jnp.asarray(X), 0, 0
+    n = rows = 0
+    if X.ndim == 2 and X.nbytes > _STAGE_BLOCK_BYTES:
+        n, row_bytes = X.shape[0], X.nbytes // X.shape[0]
+        rows = _STAGE_ROWS * max(
+            1, _STAGE_BLOCK_BYTES // row_bytes // _STAGE_ROWS)
+    if rows >= n:  # one block holds it all
+        return jnp.asarray(X), 1, X.nbytes
+    dest, writes = None, collections.deque()
+    for a in range(0, n, rows):
+        if len(writes) == _STAGE_IN_FLIGHT:
+            # flow control, not a fetch: bounds what the device holds
+            writes.popleft().block_until_ready()
+        block = jnp.asarray(X[a:a + rows])
+        if dest is None:
+            dest = _stage_dest(X.shape, block.dtype)
+        dest, written = _stage_block(dest, block, a)
+        block.delete()
+        writes.append(written)
+    return dest, -(-n // rows), rows * row_bytes
 
 
 def _sample_key(key, i, axis_name, shard_index=None):
@@ -1480,11 +1555,14 @@ class GradientDescent(Optimizer):
             if self.check_numerics:
                 _raise_if_nonfinite(hist)
             return w, hist
-        # host time in the calls only: the copy may still drain after them
+        # host time in the calls: a large X's blocks are issued in here,
+        # the last of them (any other copy whole) may still drain after it
         with span("train.h2d", bytes=sum(
-                a.nbytes for a in (X, y) if isinstance(a, np.ndarray))):
+                a.nbytes for a in (X, y)
+                if isinstance(a, np.ndarray))) as h2d:
             if not sparse_X:
-                X = jnp.asarray(X)
+                X, blocks, block_bytes = _stage_dense(X)
+                h2d.set(blocks=blocks, block_bytes=block_bytes)
                 if not jnp.issubdtype(X.dtype, jnp.inexact):
                     # int/bool features (one-hot etc.)
                     X = X.astype(jnp.float32)
