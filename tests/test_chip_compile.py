@@ -32,8 +32,8 @@ from tpu_sgd.ops.updaters import L1Updater, SimpleUpdater, SquaredL2Updater
 N, D = 2**20, 1000
 #: RCV1's published width and the smoke's sparse rows / nnz per row
 SPARSE_N, SPARSE_D, SPARSE_NNZ = 200_000, 47_236, 75
-#: the window kernels' rehearsal slab
-KERNEL_N, TILE_M, NUM_TILES = 262_144, 2048, 12
+#: the kernel's rehearsal slab
+KERNEL_N = 262_144
 #: the rows of the benchmark's two cells (bench/jobs/: 8.39 and 4.29 GB bf16)
 CELL_ROWS = {"resident": 4_194_304, "from-host": 2_145_000}
 
@@ -455,20 +455,7 @@ def test_serving_bucket_programs_compile(S, activation):
             S((rows, D), F32), S((D,), F32), S((), F32)).compile()
 
 
-# -- the Pallas kernels (opt-in path) ----------------------------------------
-
-def _window_args(S, dtype):
-    return (S((KERNEL_N, D), dtype), S((KERNEL_N,), F32), S((D,), F32),
-            S((), I32))
-
-
-def _lower_window(S, dtype, use_vpu, tile_m):
-    from tpu_sgd.ops.pallas_kernels import _fused_window_sums
-
-    return _fused_window_sums.lower(
-        LeastSquaresGradient().pointwise, *_window_args(S, dtype),
-        num_tiles=NUM_TILES, tile_m=tile_m, use_vpu=use_vpu)
-
+# -- the Pallas kernel at a tile of the caller's -------------------------------
 
 def _lower_masked(S, dtype, tile_m):
     from tpu_sgd.ops.pallas_kernels import _fused_gradient_sums
@@ -479,72 +466,42 @@ def _lower_masked(S, dtype, tile_m):
         tile_m=tile_m)
 
 
-@pytest.mark.parametrize("use_vpu", [False, True], ids=["mxu", "vpu"])
-def test_window_kernels_bf16_compile(S, use_vpu):
-    from tpu_sgd.ops.pallas_kernels import _check_tile_vmem
-
-    _check_tile_vmem(TILE_M, S((KERNEL_N, D), BF16), False)  # admits
-    compiled = _lower_window(S, BF16, use_vpu, TILE_M).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-#: what the chip's compiler refuses at d=1000: both window kernels on f32
-#: at the default tile 2048 (18.00M of scoped VMEM against its 16.00M
-#: limit), and the masked full scan over ``(d, tile)`` blocks of ``X.T``
-#: at the tiles whose two buffers alone pass the 32M it asks for (bf16:
-#: 16384 lanes, 65.5M; f32: 8192 lanes, 65.5M)
-REFUSED = {
-    "window_mxu_f32": ("window", F32, False, TILE_M),
-    "window_vpu_f32": ("window", F32, True, TILE_M),
-    "masked_bf16": ("masked", BF16, None, 16384),
-    "masked_f32": ("masked", F32, None, 8192),
-}
+#: what the chip's compiler refuses at d=1000: the masked full scan over
+#: ``(d, tile)`` blocks of ``X.T`` at the tiles whose two buffers alone pass
+#: the 32M it asks for (bf16: 16384 lanes, 65.5M; f32: 8192 lanes, 65.5M)
+REFUSED = {"masked_bf16": (BF16, 16384), "masked_f32": (F32, 8192)}
 
 
 @pytest.mark.parametrize("case", sorted(REFUSED))
 def test_vmem_estimate_refuses_what_the_compiler_refuses(S, case):
-    """``_check_tile_vmem`` raises its actionable ``ValueError`` BEFORE
+    """``_check_fm_vmem`` raises its actionable ``ValueError`` BEFORE
     compiling exactly where the chip's compiler would refuse the kernel —
     and the tile its hint names does compile."""
     import re
 
-    from tpu_sgd.ops.pallas_kernels import _check_fm_vmem, _check_tile_vmem
+    from tpu_sgd.ops.pallas_kernels import _check_fm_vmem
 
-    kind, dtype, use_vpu, refused_tile = REFUSED[case]
+    dtype, refused_tile = REFUSED[case]
     X = S((KERNEL_N, D), dtype)
-
-    def check(tile_m):
-        if kind == "masked":
-            return _check_fm_vmem(tile_m, X, True)
-        return _check_tile_vmem(tile_m, X, False)
-
-    def lower(tile_m):
-        if kind == "masked":
-            return _lower_masked(S, dtype, tile_m)
-        return _lower_window(S, dtype, use_vpu, tile_m)
-
     with pytest.raises(ValueError, match=r"tile_m <= \d+") as refused:
-        check(refused_tile)
+        _check_fm_vmem(refused_tile, X, True)
     with pytest.raises(Exception, match="(?i)vmem"):
-        lower(refused_tile).compile()  # the compiler's own verdict agrees
+        # the compiler's own verdict agrees
+        _lower_masked(S, dtype, refused_tile).compile()
     tile = int(re.search(r"tile_m <= (\d+)", str(refused.value)).group(1))
-    if kind == "window":
-        tile = 1 << (tile.bit_length() - 1)  # it needs n % tile == 0
-    check(tile)
-    assert "tpu_custom_call" in lower(tile).compile().as_text()
+    _check_fm_vmem(tile, X, True)
+    assert "tpu_custom_call" in _lower_masked(
+        S, dtype, tile).compile().as_text()
 
 
 def test_public_kernel_entry_points_refuse_before_compiling(S):
-    """The public wrappers run the check first: a tile that every
+    """The public wrapper runs the check first: a tile that every
     interpret-mode test passes is a ``ValueError`` here, not a Mosaic
     compile error on the chip."""
-    from tpu_sgd.ops.pallas_kernels import (fused_gradient_sums,
-                                            fused_window_sums)
+    from tpu_sgd.ops.pallas_kernels import fused_gradient_sums
 
     pw = LeastSquaresGradient().pointwise
     with pytest.raises(ValueError, match="scoped VMEM"):
         fused_gradient_sums(pw, S((KERNEL_N, D), BF16), S((KERNEL_N,), F32),
                             S((D,), F32), S((KERNEL_N,), jnp.bool_),
                             tile_m=16384)
-    with pytest.raises(ValueError, match="scoped VMEM"):
-        fused_window_sums(pw, *_window_args(S, F32), NUM_TILES)

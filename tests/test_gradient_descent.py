@@ -534,25 +534,6 @@ def test_host_streaming_validates_initial_weights(rng):
         opt.optimize_with_history((X, y), np.zeros(5, np.float32))
 
 
-def test_chunk_iters_warning_on_meshed_streamed_stats(rng):
-    """The meshed streamed-stats route returns before the resident
-    router; the dropped-chunk_iters warning must still fire there."""
-    import warnings as _w
-
-    from tpu_sgd import data_mesh
-
-    X = rng.normal(size=(1024, 8)).astype(np.float32)
-    y = (X @ rng.uniform(-1, 1, 8).astype(np.float32)).astype(np.float32)
-    opt = (GradientDescent(LeastSquaresGradient(), SimpleUpdater())
-           .set_num_iterations(3).set_mesh(data_mesh())
-           .set_streamed_stats(True, block_rows=64)
-           .set_gram_options(chunk_iters=4))
-    with _w.catch_warnings(record=True) as rec:
-        _w.simplefilter("always")
-        opt.optimize_with_history((X, y), np.zeros(8, np.float32))
-    assert any("chunk_iters applies" in str(r.message) for r in rec)
-
-
 # -- the step's named scopes (what the profiler's trace names kernels by) -----
 
 def _scopes_in(lowered):

@@ -158,3 +158,40 @@ class TestMultinomial:
         gs, ls, c = m.batch_sums(X, y, w)
         np.testing.assert_allclose(auto, gs, rtol=1e-3, atol=1e-4)
         np.testing.assert_allclose(float(total_loss(np.asarray(w))), float(ls), rtol=1e-4)
+
+
+#: ``Gradient.window_sums``' window of m = 100 of n = 333 rows (neither a
+#: multiple of anything): where it starts, and where it has to end up
+WINDOW_STARTS = {"first_row": 0, "unaligned_middle": 77, "last_window": 233,
+                 "past_the_end": 300}
+
+
+@pytest.mark.parametrize("with_valid", [False, True], ids=["all", "valid"])
+@pytest.mark.parametrize("start", sorted(WINDOW_STARTS))
+@pytest.mark.parametrize(
+    "g", [LeastSquaresGradient(), LogisticGradient(), HingeGradient()],
+    ids=lambda g: type(g).__name__)
+def test_window_sums_equal_batch_sums_on_the_sliced_rows(g, start, with_valid):
+    """The ``sampling="sliced"`` step: exactly the m rows from a traced
+    ``start``, the window pushed back in bounds where it would pass the
+    end (as ``lax.dynamic_slice`` clamps), ``valid`` cut to the same rows
+    and counted.  The contract a window kernel is held to."""
+    import jax
+    import jax.numpy as jnp
+
+    n, m = 333, 100
+    X, w = _rand(n, 16, seed=11)
+    r = np.random.default_rng(12)
+    y = (r.uniform(size=n) < 0.5).astype(np.float32)
+    valid = (r.uniform(size=n) < 0.7) if with_valid else None
+    at = WINDOW_STARTS[start]
+    gs, ls, c = jax.jit(g.window_sums, static_argnums=4)(
+        X, y, w, jnp.int32(at), m, valid)
+    lo = min(at, n - m)
+    rows = slice(lo, lo + m)
+    gs_ref, ls_ref, c_ref = g.batch_sums(
+        X[rows], y[rows], w, None if valid is None else valid[rows])
+    np.testing.assert_allclose(gs, gs_ref, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(ls, ls_ref, rtol=1e-5)
+    assert float(c) == float(c_ref) == (valid[rows].sum() if with_valid
+                                        else m)

@@ -1132,190 +1132,19 @@ def test_build_streamed_resume_rejects_different_dataset(rng, tmp_path):
             XB, y, block_rows=B, batch_rows=64, resume_dir=resume_dir)
 
 
-# ---- chunked-gather driver (round 5) ---------------------------------------
+def test_the_chunked_gather_option_is_gone_not_ignored():
+    """PR 30 took ``chunk_iters`` and its driver out (21x slower on the
+    chip, GRAM_SCAN_EXPERIMENT.json): the keyword is Python's own
+    ``TypeError`` and no plan carries such a field."""
+    import dataclasses
 
-def _chunked_setup(rng, n=4096, d=12, B=256):
-    X = rng.normal(size=(n, d)).astype(np.float32)
-    w = rng.uniform(-1, 1, d).astype(np.float32)
-    y = (X @ w + 0.05 * rng.normal(size=n)).astype(np.float32)
-    return X, y
-
-
-@pytest.mark.parametrize("chunk_iters", [1, 7, 16])
-def test_chunked_driver_matches_per_iteration_aligned(rng, chunk_iters):
-    """The chunked-gather driver must reproduce the per-iteration
-    aligned-gram trajectory exactly (same fold_in window stream, same
-    prefix-difference math) — including chunk sizes that do not divide
-    the iteration count."""
-    X, y = _chunked_setup(rng)
-
-    def make(chunked):
-        opt = (GradientDescent(LeastSquaresGradient(), SimpleUpdater())
-               .set_step_size(0.3).set_num_iterations(30)
-               .set_mini_batch_fraction(0.1).set_sampling("sliced")
-               .set_seed(11).set_convergence_tol(0.0)
-               .set_streamed_stats(True, block_rows=256))
-        if chunked:
-            opt.set_gram_options(chunk_iters=chunk_iters)
-        return opt
-
-    w0, h0 = make(False).optimize_with_history(
-        (X, y), np.zeros(12, np.float32))
-    w1, h1 = make(True).optimize_with_history(
-        (X, y), np.zeros(12, np.float32))
-    assert len(h0) == len(h1) == 30
-    np.testing.assert_allclose(np.asarray(h1), np.asarray(h0),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
-                               rtol=1e-5, atol=1e-6)
-
-
-def test_chunked_driver_convergence_contract(rng):
-    """With convergence_tol > 0 the chunked driver must record EXACTLY
-    as many losses as the per-iteration driver (post-convergence updates
-    inside a chunk are masked to no-ops)."""
-    X, y = _chunked_setup(rng)
-
-    def make(chunked):
-        opt = (GradientDescent(LeastSquaresGradient(), SimpleUpdater())
-               .set_step_size(0.5).set_num_iterations(200)
-               .set_mini_batch_fraction(0.1).set_sampling("sliced")
-               .set_seed(5).set_convergence_tol(1e-4)
-               .set_streamed_stats(True, block_rows=256))
-        if chunked:
-            opt.set_gram_options(chunk_iters=16)
-        return opt
-
-    w0, h0 = make(False).optimize_with_history(
-        (X, y), np.zeros(12, np.float32))
-    w1, h1 = make(True).optimize_with_history(
-        (X, y), np.zeros(12, np.float32))
-    assert 0 < len(h0) < 200  # converged early — the contract under test
-    assert len(h1) == len(h0)
-    np.testing.assert_allclose(np.asarray(h1), np.asarray(h0),
-                               rtol=1e-5, atol=1e-6)
-
-
-def test_chunked_driver_resident_aligned(rng):
-    """Resident statistics in ALIGNED mode route through the chunked
-    driver too; exact (unaligned) mode ignores the knob."""
-    X, y = _chunked_setup(rng, n=2048)
-
-    def make(aligned):
-        return (GradientDescent(LeastSquaresGradient(), SimpleUpdater())
-                .set_step_size(0.3).set_num_iterations(20)
-                .set_mini_batch_fraction(0.1).set_sampling("sliced")
-                .set_seed(3).set_convergence_tol(0.0)
-                .set_sufficient_stats(True)
-                .set_gram_options(block_rows=256, aligned=aligned,
-                                  chunk_iters=8))
-
-    opt_a = make(True)
-    w_a, h_a = opt_a.optimize_with_history((X, y), np.zeros(12, np.float32))
-    assert any(k[0] == "chunked_gram_run" for k in opt_a._run_cache)
-    ref = (GradientDescent(LeastSquaresGradient(), SimpleUpdater())
-           .set_step_size(0.3).set_num_iterations(20)
-           .set_mini_batch_fraction(0.1).set_sampling("sliced")
-           .set_seed(3).set_convergence_tol(0.0)
-           .set_sufficient_stats(True)
-           .set_gram_options(block_rows=256, aligned=True))
-    w_r, h_r = ref.optimize_with_history((X, y), np.zeros(12, np.float32))
-    np.testing.assert_allclose(np.asarray(h_a), np.asarray(h_r),
-                               rtol=1e-5, atol=1e-6)
-    # exact mode: the knob is ignored (edge corrections need rows)
-    opt_e = make(False)
-    opt_e.optimize_with_history((X, y), np.zeros(12, np.float32))
-    assert not any(k[0] == "chunked_gram_run" for k in opt_e._run_cache)
-
-
-def test_chunk_iters_knob_validation_and_plan_ownership():
-    from tpu_sgd import GradientDescent
     from tpu_sgd.plan import Plan
 
-    with pytest.raises(ValueError, match="chunk_iters must be positive"):
-        GradientDescent().set_gram_options(chunk_iters=0)
-    # plan-owned reset unless user-set
-    opt = GradientDescent()
-    Plan("streamed_virtual_gram", "t", block_rows=32, aligned=True,
-         chunk_iters=16).apply(opt)
-    assert opt.gram_chunk_iters == 16
-    Plan("resident_stock", "t").apply(opt)
-    assert opt.gram_chunk_iters is None
-    user = GradientDescent().set_gram_options(chunk_iters=8)
-    Plan("streamed_virtual_gram", "t", block_rows=32,
-         aligned=True).apply(user)
-    assert user.gram_chunk_iters == 8  # user knob survives
-
-
-def test_chunk_iters_meshed_warns_and_falls_back(rng):
-    """chunk_iters is single-device-only: meshed gram runs warn once and
-    keep the per-iteration driver rather than silently dropping the
-    expected speedup (code-review r5)."""
-    from tpu_sgd import data_mesh
-
-    n, d = 2048, 8
-    X = rng.normal(size=(n, d)).astype(np.float32)
-    y = rng.normal(size=(n,)).astype(np.float32)
-    opt = (GradientDescent(LeastSquaresGradient(), SimpleUpdater())
-           .set_step_size(0.2).set_num_iterations(5)
-           .set_mini_batch_fraction(0.25).set_sampling("sliced")
-           .set_convergence_tol(0.0)
-           .set_mesh(data_mesh())
-           .set_sufficient_stats(True)
-           .set_gram_options(block_rows=64, aligned=True, chunk_iters=8))
-    with pytest.warns(RuntimeWarning, match="single-device"):
-        w, h = opt.optimize_with_history((X, y), np.zeros(d, np.float32))
-    assert np.all(np.isfinite(np.asarray(w)))
-    assert not any(k[0] == "chunked_gram_run" for k in opt._run_cache)
-
-
-def test_chunk_iters_listener_warns(rng):
-    """The observed (listener) path warns that chunk_iters is ignored —
-    chunking amortizes exactly the per-iteration host hop listeners
-    provide."""
-    from tpu_sgd.utils.events import SGDListener
-
-    X, y = _chunked_setup(rng, n=512)
-    opt = (GradientDescent(LeastSquaresGradient(), SimpleUpdater())
-           .set_step_size(0.2).set_num_iterations(3)
-           .set_mini_batch_fraction(0.25).set_sampling("sliced")
-           .set_streamed_stats(True, block_rows=64)
-           .set_gram_options(chunk_iters=8))
-    opt.listener = SGDListener()
-    with pytest.warns(RuntimeWarning, match="observed"):
-        opt.optimize_with_history((X, y), np.zeros(12, np.float32))
-
-
-def test_chunked_driver_ignores_optimizer_aligned_on_prebuilt_exact(rng):
-    """A prebuilt EXACT (aligned=False) gram gradient runs exact windows
-    per-iteration; ``set_gram_options(aligned=True, chunk_iters=K)`` on
-    the OPTIMIZER configures future auto-builds and must NOT reroute the
-    prebuilt gradient through the aligned chunked driver — that would
-    switch the window math and silently change the trajectory the
-    chunk_iters contract promises to preserve."""
-    X, y = _chunked_setup(rng, n=2048)
-    gram = GramLeastSquaresGradient.build(X, y, block_rows=256)
-
-    def make(chunk):
-        opt = (GradientDescent(gram, SimpleUpdater())
-               .set_step_size(0.3).set_num_iterations(20)
-               .set_mini_batch_fraction(0.1).set_sampling("sliced")
-               .set_seed(7).set_convergence_tol(0.0))
-        opt.set_gram_options(aligned=True,
-                             chunk_iters=chunk if chunk else None)
-        return opt
-
-    opt_c = make(8)
-    w_c, h_c = opt_c.optimize_with_history(
-        (gram.data, y), np.zeros(12, np.float32))
-    assert not any(k[0] == "chunked_gram_run" for k in opt_c._run_cache)
-    opt_0 = make(None)
-    w_0, h_0 = opt_0.optimize_with_history(
-        (gram.data, y), np.zeros(12, np.float32))
-    np.testing.assert_allclose(np.asarray(h_c), np.asarray(h_0),
-                               rtol=1e-6, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(w_c), np.asarray(w_0),
-                               rtol=1e-6, atol=1e-7)
+    with pytest.raises(TypeError, match="chunk_iters"):
+        GradientDescent().set_gram_options(chunk_iters=4)
+    assert not [f.name for f in dataclasses.fields(Plan)
+                if "chunk_iters" in f.name]
+    assert not hasattr(GradientDescent(), "gram_chunk_iters")
 
 
 def test_statistics_evaluator_dots_run_highest_precision(rng):

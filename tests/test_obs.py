@@ -286,7 +286,7 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
             assert _inside(found[name][i], fit), name
         run = found["train.run"][i]
         assert run[2] == {"iterations": 4, "rows": 256, "path": "fused",
-                          "sums": "two_read", "shards": 1}
+                          "shards": 1}
         for name in ("train.h2d", "train.dispatch", "train.fetch"):
             assert _inside(found[name][i], run), name
         assert found["train.h2d"][i][2]["bytes"] == X.nbytes + y.nbytes
@@ -312,42 +312,6 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
     assert [p[2] for p in found["train.place"]] == [
         {"shards": 4, "in_place": 0, "bytes": X.nbytes + y.nbytes},
         {"shards": 4, "in_place": 1, "bytes": 0}]
-
-
-def test_train_run_span_says_which_sums_the_fit_took(rng):
-    """``sums`` on ``train.run``: ``two_read`` for every fit on the CPU; the
-    test behind it answers ``fused`` for the same fit on a TPU device."""
-    import types
-
-    import jax.numpy as jnp
-
-    import tpu_sgd
-    from tpu_sgd.ops.pallas_kernels import PallasGradient
-
-    X = rng.normal(size=(1024, 1000)).astype(np.float32)
-    y = (rng.random(1024) > 0.5).astype(np.float32)
-    Xd = jnp.asarray(X, jnp.bfloat16)
-    opt = tpu_sgd.GradientDescent(
-        tpu_sgd.LogisticGradient(), tpu_sgd.SquaredL2Updater()
-    ).set_num_iterations(2).set_mini_batch_fraction(0.5)
-    sink = ListSink()
-    enable_tracing(sink)
-    try:
-        opt.optimize_with_history((Xd, jnp.asarray(y)),
-                                  np.zeros(1000, np.float32))
-    finally:
-        disable_tracing()
-    rec, = sink.spans("train.run")
-    assert rec["path"] == "fused" and rec["sums"] == "two_read"
-    w0 = np.zeros(1000, np.float32)
-    tpu = types.SimpleNamespace(platform="tpu")
-    assert opt._sums_of(Xd, w0, (1024, tpu, False)) == "fused"
-    assert opt._sums_of(Xd, w0, None) == "two_read"  # sparse, gram, dp_mp
-    wide = jnp.zeros((1024, 1024), jnp.bfloat16)  # stored row-major
-    assert opt._sums_of(wide, np.zeros(1024), (1024, tpu, False)) \
-        == "two_read"
-    opt.gradient = PallasGradient(tpu_sgd.LogisticGradient())  # its own sums
-    assert opt._sums_of(Xd, w0, (1024, tpu, False)) == "two_read"
 
 
 def test_fit_prepare_span_only_when_scaling_or_intercept_runs(

@@ -1077,7 +1077,7 @@ def test_manual_schedule_after_plan_resets_plan_owned_knobs():
     opt.set_streamed_stats(True)  # user takes the wheel, new dataset
     assert opt.gram_block_rows == DEFAULT_BLOCK_ROWS
     assert opt.gram_batch_rows is None
-    assert opt.gram_aligned is False and opt.gram_chunk_iters is None
+    assert opt.gram_aligned is False
     # ...but a USER-set knob survives the reset
     opt2 = GradientDescent().set_gram_options(block_rows=128)
     Plan("streamed_virtual_gram", "t", block_rows=512,

@@ -407,20 +407,6 @@ def test_predict_margin_single_vector_shape(small_sparse):
     np.testing.assert_allclose(md, ms, rtol=1e-6)
 
 
-def test_pallas_gradient_falls_back_on_sparse(small_sparse):
-    """PallasGradient + BCOO routes to the base sparse lowering (the Mosaic
-    kernel needs dense rows) instead of crashing inside the kernel."""
-    from tpu_sgd.ops.pallas_kernels import PallasGradient
-
-    X, y, _ = small_sparse
-    g = PallasGradient(LeastSquaresGradient(), interpret=True)
-    w = jnp.ones((X.shape[1],), jnp.float32)
-    gs, ls, c = g.batch_sums(X, y, w)
-    gd, ld, cd = LeastSquaresGradient().batch_sums(X, y, w)
-    np.testing.assert_allclose(gs, gd, rtol=1e-6)
-    np.testing.assert_allclose(ls, ld, rtol=1e-6)
-
-
 def test_sparse_int_features_promote():
     """Integer one-hot BCOO data must not truncate f32 weights (compute
     promotes to >= f32)."""

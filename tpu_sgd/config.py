@@ -42,7 +42,7 @@ class SGDConfig:
         memory traffic.  ``"sliced"`` is the HBM-optimal fast path: a
         contiguous row window of ``round(frac * n)`` rows at a per-iteration
         random offset — sequential DMA instead of a random gather (several
-        times faster again), and zero-copy under ``PallasGradient``.  Sliced
+        times faster again), read in place by the two matvecs.  Sliced
         sampling is statistically sound when row order carries no signal
         (shuffled or i.i.d.-generated datasets); shuffle once beforehand if
         your rows are ordered.

@@ -7,7 +7,7 @@ from tpu_sgd.ops.gradients import (
     MultinomialLogisticGradient,
 )
 from tpu_sgd.ops.gram import GramData, GramLeastSquaresGradient
-from tpu_sgd.ops.pallas_kernels import PallasGradient, fused_gradient_sums
+from tpu_sgd.ops.pallas_kernels import fused_gradient_sums
 from tpu_sgd.ops.sparse import (
     append_bias_auto,
     append_bias_bcoo,
@@ -34,7 +34,6 @@ __all__ = [
     "MultinomialLogisticGradient",
     "GramData",
     "GramLeastSquaresGradient",
-    "PallasGradient",
     "fused_gradient_sums",
     "is_sparse",
     "csr_to_bcoo",

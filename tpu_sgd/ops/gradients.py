@@ -240,7 +240,6 @@ class Gradient:
         one-read kernel of :meth:`batch_sums` would have the window copied
         out first (compiled for the described chip at 4,194,304 x 1000:
         an 841.5 MB temporary a step), so it is not taken here.
-        PallasGradient overrides this with a zero-copy offset kernel.
         """
         return _window_sums(self._two_read_sums, X, y, weights, start, m,
                             valid, margin_axis_name)

@@ -108,36 +108,6 @@ def streamed_totals_chunking(n: int, block_rows: int,
     return B, min(chunk, n)
 
 
-def aligned_window_blocks(m: int, B: int, nbf: int) -> int:
-    """Whole-block window length of an m-row aligned window — THE
-    rounding shared by the per-iteration executor
-    (``_window_sums_aligned``) and the chunked-gather driver
-    (``optimize/gram_driver.py``), so their trajectories cannot drift."""
-    return max(1, min(nbf, round(m / B)))
-
-
-def aligned_window_k1(start, n: int, m: int, B: int, nbf: int, mb: int):
-    """First block index of the aligned window at row ``start`` — the
-    clamp-then-floor shared by both aligned drivers."""
-    start = jnp.clip(start, 0, max(n - m, 0))
-    return jnp.clip(start // B, 0, nbf - mb)
-
-
-def aligned_window_terms(PG_diff, Pb_diff, yy_diff, w_sd):
-    """``(g_sum, loss_sum)`` of an aligned window from its already-
-    differenced prefix stats — the quadratic-loss math shared by both
-    aligned drivers (stats dtype in, stats dtype out)."""
-    sd = PG_diff.dtype
-    Gw = _dot_hi(PG_diff, w_sd, sd)
-    g_sum = Gw - Pb_diff
-    # HIGHEST-precision dots: near convergence the loss is the near-zero
-    # difference of ~||y||^2-magnitude terms, and a default-precision
-    # (bf16-pass) dot's relative error dwarfs it (module docstring)
-    loss_sum = 0.5 * (_dot_hi(w_sd, g_sum, sd) - _dot_hi(w_sd, Pb_diff, sd)
-                      + yy_diff)
-    return g_sum, loss_sum
-
-
 def _running_sum(carry0, blocks):
     """Inclusive running sum over the leading axis via ``lax.scan`` —
     shared by the one-shot and the chunked-streaming prefix builders
@@ -586,8 +556,9 @@ class GramLeastSquaresGradient(LeastSquaresGradient):
         # and treats every plain array as unbound stock input.
         # aligned=True floors window starts to block boundaries even when
         # rows ARE resident — skipping the edge corrections (71% of the
-        # exact iteration, PROFILE_TPU.json) at the cost of the same
-        # floored-window sampling deviation the Pallas tiled kernel makes.
+        # exact iteration, PROFILE_TPU.json) at the cost of a floored
+        # window: a different, equally sized run of rows where the start
+        # is not a block boundary (sound on shuffled rows).
         # Virtual data (X=None) is always aligned.
         self.data = data
         self.aligned = bool(aligned)
@@ -1142,7 +1113,7 @@ class GramLeastSquaresGradient(LeastSquaresGradient):
         Gw = _dot_hi(st.G_tot, w, sd)
         b = st.b_tot
         g_sum = (Gw - b).astype(cd)
-        # cancellation-safe loss dots (see aligned_window_terms)
+        # cancellation-safe loss dots (see _window_sums_aligned)
         loss_sum = (0.5 * (_dot_hi(w, Gw, sd) - 2.0 * _dot_hi(w, b, sd)
                            + st.yy_tot)).astype(cd)
         return g_sum, loss_sum, jnp.asarray(X.shape[0], cd)
@@ -1195,15 +1166,16 @@ class GramLeastSquaresGradient(LeastSquaresGradient):
     def _window_sums_aligned(self, st, weights, start, m, cd):
         """Block-aligned window on virtual (stats-only) data: the start
         floors to a block boundary and the window length rounds to whole
-        blocks — the same floored-window sampling deviation the Pallas
-        tiled kernel makes (harmless on i.i.d. data).  Prefix difference
+        blocks — a different, equally sized run of rows where the start
+        is no block boundary (harmless on i.i.d. data).  Prefix difference
         only: ZERO row access, so a beyond-HBM dataset iterates entirely
         from its on-device statistics."""
         B = st.block_rows
         n = st.shape[0]
         nbf = n // B
-        mb = aligned_window_blocks(m, B, nbf)
-        k1 = aligned_window_k1(start, n, m, B, nbf, mb)
+        mb = max(1, min(nbf, round(m / B)))
+        start = jnp.clip(start, 0, max(n - m, 0))
+        k1 = jnp.clip(start // B, 0, nbf - mb)
         k2 = k1 + mb
         sd = st.PG.dtype
         PG1 = jax.lax.dynamic_slice_in_dim(st.PG, k1, 1, 0)[0]
@@ -1212,8 +1184,12 @@ class GramLeastSquaresGradient(LeastSquaresGradient):
         Pb2 = jax.lax.dynamic_slice_in_dim(st.Pb, k2, 1, 0)[0]
         yy = (jax.lax.dynamic_slice_in_dim(st.Pyy, k2, 1, 0)[0]
               - jax.lax.dynamic_slice_in_dim(st.Pyy, k1, 1, 0)[0])
-        g_sum, loss_sum = aligned_window_terms(
-            PG2 - PG1, Pb2 - Pb1, yy, weights.astype(sd))
+        PG, Pb, w = PG2 - PG1, Pb2 - Pb1, weights.astype(sd)
+        g_sum = _dot_hi(PG, w, sd) - Pb
+        # HIGHEST-precision dots: near convergence the loss is the near-zero
+        # difference of ~||y||^2-magnitude terms, and a default-precision
+        # (bf16-pass) dot's relative error dwarfs it (module docstring)
+        loss_sum = 0.5 * (_dot_hi(w, g_sum, sd) - _dot_hi(w, Pb, sd) + yy)
         count = jnp.asarray(mb * B, cd)
         return g_sum.astype(cd), loss_sum.astype(cd), count
 

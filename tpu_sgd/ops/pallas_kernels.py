@@ -1,4 +1,4 @@
-"""Pallas fused gradient kernels: the framework's hand-written TPU hot path.
+"""The Pallas fused gradient kernel: the framework's hand-written TPU hot path.
 
 Reference parity: SURVEY.md §2 native-component ledger — the reference's one
 native component is JNI BLAS under the per-example gradient loop; the
@@ -16,41 +16,31 @@ compiler on every call (seen in the compiled program for a described v5e,
 ``tests/test_chip_compile.py``; PERF.md, PR 26): one read and one write of X
 in front of the kernel's own read.
 
-Two families:
+One family, :func:`fused_gradient_sums`: the full scan with an optional
+sampling mask (reference parity with ``RDD.sample``), over ``(d, tile)``
+blocks of ``X.T``: features on sublanes, rows on lanes.  Where X is stored
+feature-major ``X.T`` is a bitcast and the program holds no copy and no
+temporary; both products are plain vector work (``w`` broadcast along the
+lanes and a sum over sublanes for the margins, the coefficient broadcast
+along the sublanes and lane-group adds for the gradient), X stays bf16 in
+HBM and VMEM, products and sums are f32.  On the chip it runs at one read of
+X (11.2 ms for 8.39 GB against 22.3 ms for the two matvecs, PERF.md PR 26).
+``Gradient.batch_sums`` selects it by what the operands look like
+(``ops/gradients.py:one_read_sums``) when the program is lowered for a TPU;
+nothing else routes here (``interpret=True`` is the CPU tests' way in).
 
-  * :func:`fused_gradient_sums` — the full scan with an optional sampling
-    mask (reference parity with ``RDD.sample``), over ``(d, tile)`` blocks of
-    ``X.T``: features on sublanes, rows on lanes.  Where X is stored
-    feature-major ``X.T`` is a bitcast and the program holds no copy and no
-    temporary; both products are plain vector work (``w`` broadcast along
-    the lanes and a sum over sublanes for the margins, the coefficient
-    broadcast along the sublanes and lane-group adds for the gradient), X
-    stays bf16 in HBM and VMEM, products and sums are f32.  On the chip it
-    runs at one read of X (11.2 ms for 8.39 GB against 22.3 ms for the two
-    matvecs, PERF.md PR 26).  ``Gradient.batch_sums`` selects it by what the
-    operands look like (``ops/gradients.py:one_read_sums``) when the program
-    is lowered for a TPU; nothing else routes here.
-  * :func:`fused_window_sums` / :func:`fused_window_sums_vpu` — a contiguous
-    window of rows starting at a *runtime* row offset
-    (``sampling="sliced"``), over ``(tile, d)`` ROW blocks through a
-    scalar-prefetched block index.  Opt-in through :class:`PallasGradient`.
-    They keep every tensor >= 2-D and MXU-shaped (``(tile, d) @ (d, 8)``
-    against a sublane-padded weight block, an iota lane mask for the 7
-    padding columns, a ``dot_general`` contracting the row axis), because
-    degenerate M=1/N=1 matmuls lower to relayouts Mosaic rejects.
-
-**What the old verdict on the row-blocked kernels rested on.**  They ran
-3.1-3.4 ms an iteration against XLA's 1.64 on a 3M x 1000 bf16 window
-(round 2, TPU v5 lite), and that was put down to the MXU: an M/N dimension
-of 8 on a 128 x 128 systolic array.  Nobody looked at the compiled program.
-At d = 1000 it shows ``copy(X)`` to ``{1,0}`` in front of the
-``tpu_custom_call`` (2,097,152 x 1000 bf16: 6.44 GB of temporaries for the
-old row-blocked masked kernel, since replaced): three passes over X against
-XLA's two, whatever the MXU did.  So the chip never voted on a one-read
-step, only on a copy; the window kernels' verdict is open again for the
-same reason (ROADMAP Design 2), and at a width stored by rows (a multiple
-of 128) their blocks do follow the layout.
+**What the old verdict rested on.**  A second family, window kernels over
+``(tile, d)`` ROW blocks at a scalar-prefetched row offset, ran 3.1-3.4 ms an
+iteration against XLA's 1.64 on a 3M x 1000 bf16 window (round 2, TPU v5
+lite), and that was put down to the MXU: an M/N dimension of 8 on a
+128 x 128 systolic array.  The compiled program shows ``copy(X)`` to
+``{1,0}`` in front of their ``tpu_custom_call`` at d = 1000: three passes
+over X against XLA's two, so the chip voted on a copy, never on a one-read
+window.  They went in PR 30; their successor is the lane-window kernel,
+``_fm_kernel`` with a scalar-prefetched LANE-block offset (ROADMAP Speed
+1b), and until it exists ``Gradient.window_sums`` keeps its two matvecs.
 """
+
 
 from __future__ import annotations
 
@@ -62,16 +52,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_sgd.ops.gradients import Gradient
-from tpu_sgd.ops.sparse import is_sparse
-
 Array = jax.Array
 
-SUBLANES = 8  # f32 sublane count: the weight/coefficient blocks' lane dim
-
-#: scoped-VMEM limit per kernel on TPU v5e: the chip's compiler refuses a
-#: kernel whose scoped allocation exceeds it
-_VMEM_LIMIT = 16 * 1024 * 1024
+SUBLANES = 8  # f32 sublane count
 
 #: lane width: the minor dimension of every VMEM block pads to a multiple
 LANES = 128
@@ -79,170 +62,6 @@ LANES = 128
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
-
-
-def _tile_vmem_bytes(tile: int, d: int, itemsize: int) -> int:
-    """Scoped VMEM one grid step of a window kernel needs, reckoned as the
-    chip's compiler does (checked against its refusals at d=1000 and
-    d=512, bf16 and f32, tiles 512..4096 — tests/test_chip_compile.py):
-    the feature dim pads to a multiple of 128 lanes; the X tile is
-    double-buffered; a sub-32-bit X adds one more tile-sized temporary in
-    the body; the ``(tile, 1)`` f32 labels pad to ``(tile, 128)`` and are
-    double-buffered too; one ``(tile, 128)`` f32 block covers the margin /
-    coefficient temporaries."""
-    x_tiles = 2 + (1 if itemsize < 4 else 0)
-    return (x_tiles * tile * _round_up(d, LANES) * itemsize
-            + 3 * tile * LANES * 4)
-
-
-def _refuse_tile(tile: int, X, need: int, limit: int, max_tile: int,
-                 least: int) -> None:
-    """The one ``ValueError`` for a tile whose scoped VMEM (``need``) the
-    chip's compiler would refuse under ``limit``: it names the largest
-    tile that fits, or says that none of ``least`` rows or more does."""
-    d = X.shape[1]
-    hint = (
-        f"use tile_m <= {max_tile}"
-        if max_tile >= least
-        else f"feature dim d={d} is too wide for this kernel at any "
-        "tile size; use the XLA path"
-    )
-    raise ValueError(
-        f"tile_m={tile} with d={d} {jnp.dtype(X.dtype).name} needs "
-        f"~{need / 2**20:.1f} MB of scoped VMEM, over the "
-        f"{limit / 2**20:.0f} MB the TPU compiler allows this kernel; {hint}"
-    )
-
-
-def _check_tile_vmem(tile: int, X, interpret: bool) -> None:
-    """Reject window-kernel tiles the chip's compiler would refuse
-    (measured: tile 2048 x d=1000 f32 = 18.00 MB scoped vs the 16 MB
-    limit) with an actionable error instead of a Mosaic compile-time OOM."""
-    if interpret:
-        return
-    itemsize = jnp.dtype(X.dtype).itemsize
-    need = _tile_vmem_bytes(tile, X.shape[1], itemsize)
-    if need > _VMEM_LIMIT:
-        per_row = _tile_vmem_bytes(1, X.shape[1], itemsize)
-        _refuse_tile(tile, X, need, _VMEM_LIMIT,
-                     _VMEM_LIMIT // per_row // 8 * 8, 8)
-
-
-def _coeff_losses(pointwise, Xt, yv, W):
-    """Shared tile prologue: one MXU margins pass + the pointwise rule.
-
-    ``Xt (tile, d)``, ``yv`` ``(tile, 1)``, ``W (d, SUBLANES)`` with
-    the weight vector in column 0.  The pointwise rule is evaluated on the
-    full ``(tile, SUBLANES)`` margin block — columns 1.. see the garbage
-    margins of the zero weight columns — and an iota lane mask zeroes their
-    coeff/loss, so no single-lane slice or concatenate is materialized.
-    Returns ``(coeff, losses, count)`` with coeff/losses ``(tile,
-    SUBLANES)`` and only column 0 live."""
-    margins = jnp.dot(
-        Xt, W.astype(Xt.dtype), preferred_element_type=jnp.float32
-    )  # (tile, SUBLANES); only column 0 is real
-    coeff, losses = pointwise(margins, yv)  # yv broadcasts over columns
-    col0 = (
-        jax.lax.broadcasted_iota(jnp.int32, (1, SUBLANES), 1) == 0
-    )
-    coeff = jnp.where(col0, coeff, 0.0)
-    losses = jnp.where(col0, losses, 0.0)
-    return coeff, losses, jnp.float32(Xt.shape[0])
-
-
-def _tile_contrib(pointwise, Xt, yv, W):
-    """One row tile's ``(grad_block, loss_sum, count)``: the MXU variant —
-    both reductions are matmuls (bf16 data runs both passes in bf16 with
-    f32 accumulation); the returned grad block is ``(SUBLANES, d)`` f32
-    with the gradient in row 0."""
-    coeff, losses, cnt = _coeff_losses(pointwise, Xt, yv, W)
-    G = jax.lax.dot_general(
-        coeff.astype(Xt.dtype),
-        Xt,
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    return G, jnp.sum(losses), cnt
-
-
-def _accumulate(i, grad_ref, loss_ref, cnt_ref, G, lt, ct):
-    @pl.when(i == 0)
-    def _():
-        grad_ref[:] = G
-        loss_ref[0, 0] = lt
-        cnt_ref[0, 0] = ct
-
-    @pl.when(i > 0)
-    def _():
-        grad_ref[:] = grad_ref[:] + G
-        loss_ref[0, 0] = loss_ref[0, 0] + lt
-        cnt_ref[0, 0] = cnt_ref[0, 0] + ct
-
-
-def _tile_contrib_vpu(pointwise, Xt, yv, W):
-    """One row tile's sums with the gradient reduction on the VPU.
-
-    Round-3 experiment against the round-2 finding that BOTH MXU matmuls
-    underutilize the systolic array 16x (M/N = 8): margins stay on the MXU
-    (one (tile, d) @ (d, 8) pass), but the gradient outer-product-sum is
-    recast as elementwise-multiply + sublane reduction —
-    ``sum(coeff_vec * Xt, axis=0)`` — which is VPU work at memory rate, so
-    the kernel's cost model becomes one DMA + one matmul + one
-    bandwidth-rate reduction instead of two underutilized matmuls.
-    Returns a ``(1, d)`` gradient row (accumulated into row 0 of the
-    ``(SUBLANES, d)`` output by the caller)."""
-    coeff, losses, cnt = _coeff_losses(pointwise, Xt, yv, W)
-    # (tile, 8) -> (tile, 1): an 8-lane reduction (cheap), keeping >= 2-D
-    coeff_vec = jnp.sum(coeff, axis=1, keepdims=True)
-    # Elementwise multiply in Xt's dtype with f32 SUM accumulation — the
-    # same precision contract as the MXU variant's bf16 dot_general, and
-    # no f32 (tile, d) temp blowing the VMEM limit (the chip's compiler
-    # charges this body no more than the MXU variant's).
-    contrib = coeff_vec.astype(Xt.dtype) * Xt
-    g1 = jnp.sum(contrib, axis=0, keepdims=True,
-                 dtype=jnp.float32)  # (1, d)
-    return g1, jnp.sum(losses), cnt
-
-
-def _accumulate_vpu(i, grad_ref, loss_ref, cnt_ref, g1, lt, ct):
-    """Accumulate a (1, d) gradient row into row 0 of the (SUBLANES, d)
-    output block (sublane-axis slice writes; the lane axis is untouched)."""
-    @pl.when(i == 0)
-    def _():
-        grad_ref[:] = jnp.zeros_like(grad_ref)
-        grad_ref[0:1] = g1
-        loss_ref[0, 0] = lt
-        cnt_ref[0, 0] = ct
-
-    @pl.when(i > 0)
-    def _():
-        grad_ref[0:1] = grad_ref[0:1] + g1
-        loss_ref[0, 0] = loss_ref[0, 0] + lt
-        cnt_ref[0, 0] = cnt_ref[0, 0] + ct
-
-
-def _window_kernel_vpu(pointwise, s_ref, x_ref, y_ref, w_ref,
-                       grad_ref, loss_ref, cnt_ref):
-    del s_ref  # consumed by the BlockSpec index maps
-    i = pl.program_id(0)
-    g1, lt, ct = _tile_contrib_vpu(
-        pointwise, x_ref[:], y_ref[:], w_ref[:]
-    )
-    _accumulate_vpu(i, grad_ref, loss_ref, cnt_ref, g1, lt, ct)
-
-
-def _window_kernel(pointwise, s_ref, x_ref, y_ref, w_ref,
-                   grad_ref, loss_ref, cnt_ref):
-    del s_ref  # consumed by the BlockSpec index maps
-    i = pl.program_id(0)
-    G, lt, ct = _tile_contrib(pointwise, x_ref[:], y_ref[:], w_ref[:])
-    _accumulate(i, grad_ref, loss_ref, cnt_ref, G, lt, ct)
-
-
-def _pad_w(w: Array) -> Array:
-    return jnp.zeros((w.shape[0], SUBLANES), jnp.float32).at[:, 0].set(
-        w.astype(jnp.float32)
-    )
 
 
 #: rows of X (lanes of ``X.T``) per grid step of the feature-major kernel
@@ -307,16 +126,28 @@ def fm_tile(n: int, d: int, itemsize: int, masked: bool = True
 
 def _check_fm_vmem(tile: int, X, masked: bool) -> None:
     """Reject a tile the chip's compiler would refuse with an error that
-    names one it admits, instead of a Mosaic compile-time OOM."""
+    names the largest one it admits (or says that not even one lane group
+    fits), instead of a Mosaic compile-time OOM."""
     d = X.shape[1]
     itemsize = jnp.dtype(X.dtype).itemsize
     need = _fm_vmem_bytes(tile, d, itemsize, masked)
-    if need > _FM_VMEM_LIMIT:
-        fixed = _fm_vmem_bytes(0, d, itemsize, masked)
-        per_lane = _fm_vmem_bytes(1, d, itemsize, masked) - fixed
-        _refuse_tile(tile, X, need, _FM_VMEM_LIMIT,
-                     (_FM_VMEM_LIMIT - fixed) // per_lane // LANES * LANES,
-                     LANES)
+    if need <= _FM_VMEM_LIMIT:
+        return
+    fixed = _fm_vmem_bytes(0, d, itemsize, masked)
+    per_lane = _fm_vmem_bytes(1, d, itemsize, masked) - fixed
+    max_tile = (_FM_VMEM_LIMIT - fixed) // per_lane // LANES * LANES
+    hint = (
+        f"use tile_m <= {max_tile}"
+        if max_tile >= LANES
+        else f"feature dim d={d} is too wide for this kernel at any "
+        "tile size; use the XLA path"
+    )
+    raise ValueError(
+        f"tile_m={tile} with d={d} {jnp.dtype(X.dtype).name} needs "
+        f"~{need / 2**20:.1f} MB of scoped VMEM, over the "
+        f"{_FM_VMEM_LIMIT / 2**20:.0f} MB the TPU compiler allows this "
+        f"kernel; {hint}"
+    )
 
 
 def _fm_lane_chunk(tile: int) -> int:
@@ -531,213 +362,3 @@ def _fused_gradient_sums(
     return grad_sum, jnp.sum(loss), count
 
 
-def fused_window_sums(
-    pointwise,
-    X: Array,
-    y: Array,
-    w: Array,
-    start_tile: Array,
-    num_tiles: int,
-    tile_m: int = 2048,
-    interpret: bool = False,
-) -> Tuple[Array, Array, Array]:
-    """Fused sums over ``num_tiles`` consecutive tiles starting at runtime
-    tile index ``start_tile`` — the zero-copy ``sampling="sliced"`` hot path.
-
-    The window is read straight from the full HBM-resident ``X`` through a
-    scalar-prefetched block offset; the mini-batch is never materialized.
-    ``X.shape[0]`` must be a multiple of ``tile_m`` and ``start_tile`` must
-    satisfy ``(start_tile + num_tiles) * tile_m <= X.shape[0]`` (callers
-    clamp).  Returns ``(grad_sum, loss_sum, count)`` with
-    ``count = num_tiles * tile_m``.
-    """
-    _check_tile_vmem(tile_m, X, interpret)
-    return _fused_window_sums(
-        pointwise, X, y, w, start_tile,
-        num_tiles=num_tiles, tile_m=tile_m, interpret=interpret,
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("pointwise", "num_tiles", "tile_m", "interpret",
-                     "use_vpu"),
-)
-def _fused_window_sums(
-    pointwise,
-    X: Array,
-    y: Array,
-    w: Array,
-    start_tile: Array,
-    num_tiles: int,
-    tile_m: int = 2048,
-    interpret: bool = False,
-    use_vpu: bool = False,
-) -> Tuple[Array, Array, Array]:
-    n, d = X.shape
-    if n % tile_m:
-        raise ValueError(
-            f"fused_window_sums needs rows ({n}) to be a multiple of the "
-            f"tile size ({tile_m}); pad the dataset or use a smaller tile"
-        )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec((tile_m, d), lambda i, s: (s[0] + i, 0)),
-            pl.BlockSpec((tile_m, 1), lambda i, s: (s[0] + i, 0)),
-            pl.BlockSpec((d, SUBLANES), lambda i, s: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((SUBLANES, d), lambda i, s: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-    )
-    kernel = _window_kernel_vpu if use_vpu else _window_kernel
-    grad, loss, cnt = pl.pallas_call(
-        functools.partial(kernel, pointwise),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((SUBLANES, d), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(
-        jnp.asarray(start_tile, jnp.int32).reshape(1),
-        X,
-        y.reshape(-1, 1).astype(jnp.float32),
-        _pad_w(w),
-    )
-    return grad[0], loss[0, 0], cnt[0, 0]
-
-
-def fused_window_sums_vpu(
-    pointwise,
-    X: Array,
-    y: Array,
-    w: Array,
-    start_tile: Array,
-    num_tiles: int,
-    tile_m: int = 2048,
-    interpret: bool = False,
-) -> Tuple[Array, Array, Array]:
-    """VPU-reduction variant of :func:`fused_window_sums` (round-3
-    experiment; see ``_tile_contrib_vpu``).  Same contract and constraints;
-    the gradient lands in row 0 of the block like the MXU variant."""
-    _check_tile_vmem(tile_m, X, interpret)
-    return _fused_window_sums(
-        pointwise, X, y, w, start_tile,
-        num_tiles=num_tiles, tile_m=tile_m, interpret=interpret,
-        use_vpu=True,
-    )
-
-
-class PallasGradient(Gradient):
-    """Wrap any pointwise Gradient with the fused Pallas hot path.
-
-    Drop-in for the optimizer boundary: ``PallasGradient(LeastSquaresGradient())``
-    computes the same sums (same pointwise rule, same contract) with
-    ``batch_sums`` in the fused kernel, and ``window_sums`` (the
-    ``sampling="sliced"`` path) in the zero-copy offset kernel.  Sparse
-    features and a sharded feature axis take the base XLA path (the
-    kernel needs dense rows and whole margins).  Off-TPU it raises: set
-    ``interpret=True`` to run the kernels in interpreter mode for CPU
-    testing.
-
-    Window-alignment caveat: on the kernel path ``window_sums`` floors
-    ``start`` to a ``tile_m`` boundary (and clamps so the window stays
-    in-bounds), so for non-tile-aligned starts it sums a *different,
-    equally-sized* row window than the base XLA implementation.  Under
-    ``sampling="sliced"`` the start is uniformly random and rows are
-    exchangeable, so the distribution of sampled windows is unchanged —
-    but bitwise reproducibility across the Pallas and XLA paths only holds
-    for tile-aligned starts.
-    """
-
-    def __init__(self, base: Gradient, tile_m: int = 2048,
-                 interpret: Optional[bool] = None, window_kernel: str = "mxu"):
-        if window_kernel not in ("mxu", "vpu"):
-            raise ValueError(
-                f"window_kernel must be 'mxu' or 'vpu', got {window_kernel!r}"
-            )
-        self.base = base
-        self.tile_m = tile_m
-        self.interpret = interpret
-        #: which fused window kernel serves window_sums: the round-2 MXU
-        #: variant (default) or the round-3 VPU-reduction experiment (one
-        #: underutilized matmul instead of two; see fused_window_sums_vpu)
-        self.window_kernel = window_kernel
-
-    def pointwise(self, margin, label):
-        return self.base.pointwise(margin, label)
-
-    def weight_dim(self, num_features: int) -> int:
-        return self.base.weight_dim(num_features)
-
-    def _require_kernel_platform(self) -> None:
-        """Raise off-TPU unless ``interpret=True``: a Mosaic kernel cannot
-        compile elsewhere, and quietly handing the work to the XLA base
-        would pass for working."""
-        if self.interpret is True:
-            return  # interpreter mode runs anywhere (CPU tests)
-        platform = jax.devices()[0].platform
-        if platform != "tpu":
-            raise RuntimeError(
-                f"PallasGradient compiles Mosaic kernels for a TPU, but the "
-                f"default device is {platform!r}; pass interpret=True to run "
-                "the kernels in interpreter mode, or use the base gradient"
-            )
-
-    def batch_sums(self, X, y, weights, mask=None, margin_axis_name=None):
-        if margin_axis_name is not None or is_sparse(X):
-            # BCOO features take the base path's sparse lowering — the
-            # Mosaic kernel needs a dense row layout.
-            return self.base.batch_sums(
-                X, y, weights, mask, margin_axis_name=margin_axis_name
-            )
-        self._require_kernel_platform()
-        grad, loss, cnt = fused_gradient_sums(
-            self.base.pointwise,
-            X,
-            y,
-            weights,
-            mask,
-            tile_m=self.tile_m,
-            interpret=bool(self.interpret),
-        )
-        return grad, loss, cnt
-
-    def window_sums(self, X, y, weights, start, m, valid=None,
-                    margin_axis_name=None):
-        n = X.shape[0]
-        dense_rows = not is_sparse(X) and margin_axis_name is None
-        if dense_rows:
-            self._require_kernel_platform()
-        if not (dense_rows and valid is None and m >= self.tile_m
-                and n % self.tile_m == 0):
-            return self.base.window_sums(
-                X, y, weights, start, m, valid=valid,
-                margin_axis_name=margin_axis_name,
-            )
-        # Kernel covers the tile-aligned bulk; any sub-tile remainder is
-        # sliced through the base path so exactly m rows are processed (the
-        # "behaves identically" contract with Gradient.window_sums).
-        num_tiles = m // self.tile_m
-        rem = m - num_tiles * self.tile_m
-        start_tile = jnp.minimum(
-            jnp.asarray(start, jnp.int32) // self.tile_m,
-            (n - m) // self.tile_m,
-        )
-        kernel = (fused_window_sums_vpu if self.window_kernel == "vpu"
-                  else fused_window_sums)
-        g, l, c = kernel(
-            self.base.pointwise, X, y, weights, start_tile, num_tiles,
-            tile_m=self.tile_m, interpret=bool(self.interpret),
-        )
-        if rem:
-            tail = (start_tile + num_tiles) * self.tile_m
-            g2, l2, c2 = self.base.window_sums(X, y, weights, tail, rem)
-            g, l, c = g + g2, l + l2, c + c2
-        return g, l, c

@@ -208,8 +208,8 @@ def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
             m = max(1, round(cfg.mini_batch_fraction * X.shape[0]))
         if sliced:
             # HBM-optimal path: a contiguous row window at a random offset —
-            # one sequential DMA (zero-copy under PallasGradient) instead of
-            # a random gather.  Assumes exchangeable row order (see
+            # one sequential DMA (read in place by the two matvecs) instead
+            # of a random gather.  Assumes exchangeable row order (see
             # SGDConfig.sampling docs).
             with jax.named_scope("sgd.sample"):
                 k = _sample_key(key, i, axis_name, shard_index)
@@ -905,8 +905,7 @@ GRAFTLINT_MEMO = {
         # gram-runner keys carry the data geometry and the gram/ingest
         # knobs the compiled prefix programs bake in
         "X", "y", "gram_aligned", "gram_batch_rows", "gram_block_rows",
-        "gram_chunk_iters", "ingest_pipeline", "ingest_prefetch_depth",
-        "ingest_wire_dtype",
+        "ingest_pipeline", "ingest_prefetch_depth", "ingest_wire_dtype",
     ),
 }
 
@@ -942,7 +941,6 @@ class GradientDescent(Optimizer):
         self.gram_block_rows = DEFAULT_BLOCK_ROWS
         self.gram_batch_rows = None
         self.gram_aligned = False
-        self.gram_chunk_iters = None
         #: ingest-pipeline knobs (tpu_sgd/io; set_ingest_options): wire
         #: dtype for the host→device hop (None = data dtype), prefetch
         #: lookahead (2 = double buffer, 0 = synchronous), and the
@@ -1130,32 +1128,25 @@ class GradientDescent(Optimizer):
         return self
 
     def set_gram_options(self, block_rows: int = None, aligned: bool = None,
-                         batch_rows: int = None, chunk_iters: int = None):
+                         batch_rows: int = None):
         """Tuning knobs for the sufficient-statistics schedules.
 
         ``block_rows`` trades prefix-stack memory (``n/B · d² · 4`` bytes)
         against per-iteration edge-read traffic (see ``ops/gram.py``).
         ``aligned=True`` floors window starts to block boundaries, skipping
         the edge corrections (~71% of the exact iteration) at the cost of
-        the same floored-window sampling deviation the Pallas tiled kernel
-        makes — fine on shuffled rows, not on sorted/grouped data.
+        a floored window (a different, equally sized run of rows where the
+        start is no block boundary) — fine on shuffled rows, not on
+        sorted/grouped data.
         ``batch_rows`` caps the streamed build's host→device chunk (the
         chunk is co-resident with the growing prefix stack, so a tight
         device budget needs a smaller chunk than the 64-block default).
-        ``chunk_iters=K`` switches block-aligned sliced execution to the
-        chunked-gather driver (``optimize/gram_driver.py``): K window
-        endpoints gathered from the prefix stacks per outer step, the
-        same per-iteration contract — opt-in until the hardware
-        decomposition capture settles its default.  SINGLE-DEVICE only:
-        the meshed gram runners keep the per-iteration driver (a warning
-        says so when both are set).
         The execution planner (``tpu_sgd/plan.py``) sets ``block_rows``/
         ``batch_rows`` automatically; ``aligned`` stays opt-in."""
         from tpu_sgd.plan import apply_user_gram_knobs
 
         apply_user_gram_knobs(self, block_rows=block_rows, aligned=aligned,
-                              batch_rows=batch_rows,
-                              chunk_iters=chunk_iters)
+                              batch_rows=batch_rows)
         return self
 
     def set_ingest_options(self, wire_dtype=None, prefetch_depth=None,
@@ -1494,10 +1485,6 @@ class GradientDescent(Optimizer):
             # device re-enters through the GramData branch above.
             self._check_streamed_stats_applies(sparse_X)
             if self.mesh is not None:
-                # this route returns before _optimize_routed's warning
-                # would fire — the user's explicit chunk_iters request is
-                # being dropped and must not go silent
-                self._warn_chunk_iters_with_mesh(stacklevel=4)
                 run_span.set(path="gram")
                 return self._optimize_streamed_stats_mesh(
                     X, y, initial_weights
@@ -1600,20 +1587,7 @@ class GradientDescent(Optimizer):
 
         from tpu_sgd.ops.gram import GramData
 
-        self._warn_chunk_iters_with_mesh(stacklevel=5)
-
         if self.listener is not None or self.checkpoint_manager is not None:
-            if self.gram_chunk_iters:
-                import warnings
-
-                warnings.warn(
-                    "chunk_iters is ignored on the observed "
-                    "(listener/checkpoint) path: chunking amortizes the "
-                    "per-iteration host hop that listeners exist to "
-                    "provide; detach the listener to use the chunked "
-                    "driver",
-                    RuntimeWarning, stacklevel=4,
-                )
             if (self.sufficient_stats and self.mesh is not None
                     and not sparse_X):
                 import warnings
@@ -1632,9 +1606,6 @@ class GradientDescent(Optimizer):
         # below dispatches it.  A runner that had to be built (a new
         # _run_cache entry) traces, lowers and compiles inside that call.
         cached = len(self._run_cache)
-        # (rows one device holds, such a device, whether a validity mask
-        # rides along) on the routes whose step sums dense rows of X
-        held = None
         if sparse_X and self.mesh is not None:
             # Distributed sparse: equal-nse BCOO blocks per shard, same
             # make_run body, psum over ICI (the treeAggregate-over-sparse-
@@ -1690,18 +1661,14 @@ class GradientDescent(Optimizer):
             else:
                 fn = self._runner(with_valid=valid is not None)
                 args = (w0, Xd, yd) if valid is None else (w0, Xd, yd, valid)
-                held = (Xd.shape[0] // self.mesh.devices.size,
-                        self.mesh.devices.flat[0], valid is not None)
             path = "mesh"
         else:
-            fn = (self._maybe_chunked_gram_run(X)
-                  or self._runner(with_valid=False))
+            fn = self._runner(with_valid=False)
             path = "gram" if isinstance(X, GramData) else "fused"
             args = (w0, X, y)
-            if path == "fused" and not sparse_X:
-                held = (X.shape[0], next(iter(X.devices())), False)
-        run_span.set(path=path, sums=self._sums_of(X, w0, held),
-                     shards=1 if self.mesh is None else self.mesh.devices.size)
+        run_span.set(
+            path=path,
+            shards=1 if self.mesh is None else self.mesh.devices.size)
         with span("train.dispatch",
                   built=int(len(self._run_cache) > cached)):
             w, losses, n_rec = fn(*args)
@@ -1727,85 +1694,6 @@ class GradientDescent(Optimizer):
             sp.set(in_place=int(in_place),
                    bytes=0 if in_place else X.nbytes + y.nbytes)
         return Xd, yd, valid
-
-    def _sums_of(self, X, w0, held) -> str:
-        """The ``train.run`` span's ``sums``: ``fused`` where this fit's
-        step takes the one-read kernel, ``two_read`` where it reads X twice
-        (or is no sum over dense rows at all).  It is the step's own test
-        (``ops.gradients.one_read_sums``) on the batch one device is
-        handed, and what that test cannot see from inside the program:
-        that the sums are ``Gradient.batch_sums``'s own (a wrapper or a
-        matrix-weight gradient brings its own) and that the devices
-        holding X are TPUs."""
-        from tpu_sgd.ops.gradients import one_read_sums
-
-        if (held is None or held[1].platform != "tpu"
-                or getattr(type(self.gradient), "batch_sums", None)
-                is not Gradient.batch_sums):
-            return "two_read"
-        rows, _, with_valid = held
-        cfg = self.config
-        sampled = cfg.mini_batch_fraction < 1.0
-        if sampled and cfg.sampling in ("sliced", "indexed"):
-            rows = max(1, round(cfg.mini_batch_fraction * rows))
-        shape = jax.ShapeDtypeStruct
-        mask = (shape((rows,), jnp.bool_)
-                if with_valid or (sampled and cfg.sampling == "bernoulli")
-                else None)
-        return "fused" if one_read_sums(
-            shape((rows, X.shape[1]), X.dtype), shape((rows,), jnp.float32),
-            w0, mask) else "two_read"
-
-    def _warn_chunk_iters_with_mesh(self, stacklevel: int = 3) -> None:
-        """One warning for every route that drops an explicit
-        ``chunk_iters`` because a mesh is set — the meshed gram runners
-        keep the per-iteration driver."""
-        if self.gram_chunk_iters and self.mesh is not None:
-            import warnings
-
-            warnings.warn(
-                "chunk_iters applies to the single-device aligned-gram "
-                "driver only; the meshed gram runners keep the "
-                "per-iteration driver (drop set_mesh to use the chunked "
-                "driver)",
-                RuntimeWarning, stacklevel=stacklevel,
-            )
-
-    def _maybe_chunked_gram_run(self, X):
-        """The chunked-gather driver (``optimize/gram_driver.py``) when
-        the ``chunk_iters`` knob is set and this execution is block-
-        ALIGNED statistics with sliced windows — virtual stats (X.X is
-        None) are aligned by construction; resident stats qualify in
-        aligned mode.  None otherwise (the per-iteration driver runs)."""
-        from tpu_sgd.ops.gram import GramData, GramLeastSquaresGradient
-
-        cfg = self.config
-        if (not self.gram_chunk_iters
-                or not isinstance(X, GramData)
-                or not isinstance(self.gradient, GramLeastSquaresGradient)
-                # engage ONLY where the per-iteration path itself runs
-                # aligned windows (window_sums' own dispatch): gating on
-                # the optimizer-level gram_aligned knob would switch a
-                # prebuilt non-aligned gradient to aligned math and
-                # silently change the trajectory chunk_iters promises to
-                # preserve
-                or not (X.X is None or self.gradient.aligned)
-                or cfg.sampling != "sliced"
-                or cfg.mini_batch_fraction >= 1.0):
-            return None
-        n = X.shape[0]
-        key = ("chunked_gram_run", self.updater, cfg, n, X.block_rows,
-               self.gram_chunk_iters)
-        fn = self._run_cache.get(key)
-        if fn is None:
-            from tpu_sgd.optimize.gram_driver import make_chunked_gram_run
-
-            fn = jax.jit(make_chunked_gram_run(
-                self.updater, cfg, n=n, block_rows=X.block_rows,
-                chunk_iters=self.gram_chunk_iters,
-            ))
-            self._run_cache[key] = fn
-        return fn
 
     def _check_streamed_stats_applies(self, sparse_X):
         """Shared guards for ``set_streamed_stats`` (single-device and

@@ -303,10 +303,6 @@ class Plan:
     batch_rows: Optional[int] = None
     aligned: bool = False
     resident_rows: int = 0
-    #: chunked-gather driver iterations per outer step (gram schedules;
-    #: None = the per-iteration driver — the default until the hardware
-    #: decomposition capture settles the win)
-    chunk_iters: Optional[int] = None
     #: ingest-pipeline knobs for the streaming schedules (tpu_sgd/io):
     #: wire_dtype stays None — the bf16 wire is a documented opt-in, the
     #: planner never silently rounds the user's inputs; prefetch_depth=2
@@ -424,9 +420,6 @@ def apply_gram_knobs(optimizer, p: "Plan") -> None:
             None if p.schedule == "host_streamed" else p.batch_rows or None)
     if "aligned" not in user and hasattr(optimizer, "gram_aligned"):
         optimizer.gram_aligned = bool(p.aligned)
-    if ("chunk_iters" not in user
-            and hasattr(optimizer, "gram_chunk_iters")):
-        optimizer.gram_chunk_iters = p.chunk_iters or None
     if ("wire_dtype" not in user
             and hasattr(optimizer, "ingest_wire_dtype")):
         optimizer.ingest_wire_dtype = p.wire_dtype
@@ -451,7 +444,6 @@ _GRAM_KNOBS = {
     "block_rows": ("gram_block_rows", True),
     "batch_rows": ("gram_batch_rows", True),
     "aligned": ("gram_aligned", False),
-    "chunk_iters": ("gram_chunk_iters", True),
 }
 
 
@@ -562,9 +554,6 @@ def reset_plan_owned_gram_knobs(optimizer) -> None:
         optimizer.gram_batch_rows = None
     if "aligned" not in user and hasattr(optimizer, "gram_aligned"):
         optimizer.gram_aligned = False
-    if ("chunk_iters" not in user
-            and hasattr(optimizer, "gram_chunk_iters")):
-        optimizer.gram_chunk_iters = None
     if ("stream_batch_rows" not in user
             and hasattr(optimizer, "stream_batch_rows")):
         optimizer.stream_batch_rows = None
