@@ -252,6 +252,53 @@ def test_sliced_run_at_the_cells_shape_reads_the_window_once_in_place(
     assert memory.argument_size_in_bytes < local * D * 2 * 1.01
 
 
+#: mnist8m's rows, width and classes (bench/configs/mnist8m-multinomial.json)
+MNIST8M = (8_100_000, 784, 10)
+
+
+def _classes_run(S):
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    n, d, K = MNIST8M
+    cfg = _cfg(step_size=1.0, num_iterations=100, mini_batch_fraction=1.0,
+               reg_param=0.001, convergence_tol=0.0)
+    return jax.jit(make_run(MultinomialLogisticGradient(K),
+                            SquaredL2Updater(), cfg)).lower(
+        S(((K - 1) * d,), F32), S((n, d), BF16), S((n,), F32)).compile()
+
+
+def test_classes_run_at_the_cells_shape_reads_x_once_in_place(S):
+    """``mnist8m-multinomial.resident-classes``: all 8,100,000 x 784 bf16
+    rows (12.70 GB of a chip's 15.75) under a ``(9, 784)`` matrix of
+    weights at fraction 1.0 (no mask).  ONE Mosaic call a step under
+    ``sgd.class_sums``, ``X.T`` handed to it as a bitcast of the parameter,
+    nothing of X's size made, NO array of a row's class count in HBM (the
+    two matmuls hold ``f32[8100000,9]`` margins and ``[8100000,10]``
+    logits between them) and temporaries under 1% of X."""
+    import re
+
+    n, d, K = MNIST8M
+    compiled = _classes_run(S)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "sgd.class_sums" in call and "_fused_class_sums" in call
+    assert "sgd.fused_sums" not in text
+    assert "bf16[%d,%d]{1,0:T(8,128)(2,1)} bitcast(" % (d, n) in text
+    assert _moves_of(text, n, d) == []
+    # X, its bitcast and the labels' row aside, no 2-D array has n rows
+    rest = text
+    for known in ("bf16[%d,%d]" % (n, d), "bf16[%d,%d]" % (d, n),
+                  "f32[1,%d]" % n):
+        rest = rest.replace(known, "")
+    assert not re.search(r"\[%d,\d+\]|\[\d+,%d\]" % (n, n), rest)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < n * d * 2 // 100
+    assert memory.argument_size_in_bytes < n * d * 2 * 1.01
+
+
 #: sha256 (16 hex digits) of the masked fit's program lowered for a TPU at
 #: the three masked cells' shapes: the StableHLO outside the Mosaic call,
 #: and the call's body parsed and printed WITHOUT locations (the serialized
@@ -314,14 +361,15 @@ def test_the_masked_cells_lowered_program_is_the_pinned_one(cell):
 
 #: (rows, features): what ``feature_major`` must say of each, and the
 #: chip's compiler does: the issue's three (f32 at d = 1000; bf16 at 1024
-#: and 128), the two cells, and the rule's edges (a tie, few rows, d not
-#: a multiple of 8, a narrow d)
+#: and 128), the two cells, the rule's edges (a tie, few rows, d not
+#: a multiple of 8, a narrow d), and mnist8m's (PR 32: 784 pads to 896
+#: columns by rows and to nothing by features)
 LAYOUTS = {
     "f32_d1000": (F32, N, D), "bf16_d1024": (BF16, N, 1024),
     "bf16_d128": (BF16, N, 128), "resident": (BF16, 4_194_304, D),
     "from_host": (BF16, 2_145_000, D), "square_tie": (BF16, 1000, 1000),
     "few_rows": (BF16, 1024, D), "d1001": (F32, N, 1001),
-    "narrow": (F32, 300, 24),
+    "narrow": (F32, 300, 24), "mnist8m": (BF16, 8_100_000, 784),
 }
 
 
@@ -613,3 +661,57 @@ def test_public_kernel_entry_points_refuse_before_compiling(S):
         fused_gradient_sums(pw, S((KERNEL_N, D), BF16), S((KERNEL_N,), F32),
                             S((D,), F32), S((KERNEL_N,), jnp.bool_),
                             tile_m=16384)
+
+
+
+# -- the class kernel: a (K-1, d) matrix of weights --------------------------
+
+def _lower_classes(S, dtype, classes, masked, tile_m):
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+    from tpu_sgd.ops.pallas_kernels import _fused_class_sums, class_rows_of
+
+    d = MNIST8M[1]
+    args = [S((KERNEL_N, d), dtype), S((KERNEL_N,), F32),
+            S((classes - 1, d), F32)]
+    if masked:
+        args.append(S((KERNEL_N,), jnp.bool_))
+    return _fused_class_sums.lower(
+        MultinomialLogisticGradient(classes).class_rule, *args,
+        rows=class_rows_of(classes - 1, dtype), tile_m=tile_m)
+
+
+#: (type, classes, masked): mnist8m's ten classes with and without a mask,
+#: float32 rows, and as many class rows as one pass takes (128)
+CLASS_CASES = {"mnist8m": (BF16, 10, False), "mnist8m_masked": (BF16, 10, True),
+               "f32": (F32, 10, True), "rows_128": (BF16, 129, True)}
+
+
+@pytest.mark.parametrize("case", sorted(CLASS_CASES))
+def test_the_class_kernels_vmem_count_admits_what_the_compiler_admits(
+        S, case):
+    """``_check_fm_vmem`` with the class rows counted: the kernel's own
+    tile compiles, a tile of 16384 lanes is refused by the count and by
+    the compiler alike, and the largest tile the count's hint names
+    compiles."""
+    import re
+
+    from tpu_sgd.ops.pallas_kernels import (_check_fm_vmem, class_rows_of,
+                                            fm_tile)
+
+    dtype, classes, masked = CLASS_CASES[case]
+    d = MNIST8M[1]
+    rows = class_rows_of(classes - 1, dtype)
+    X = S((KERNEL_N, d), dtype)
+    own = fm_tile(KERNEL_N, d, jnp.dtype(dtype).itemsize, masked, rows)
+    assert own is not None
+    assert "tpu_custom_call" in _lower_classes(
+        S, dtype, classes, masked, own).compile().as_text()
+    with pytest.raises(ValueError, match=r"tile_m <= \d+") as refused:
+        _check_fm_vmem(16384, X, masked, rows)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _lower_classes(S, dtype, classes, masked, 16384).compile()
+    tile = int(re.search(r"tile_m <= (\d+)", str(refused.value)).group(1))
+    assert tile >= own
+    _check_fm_vmem(tile, X, masked, rows)
+    assert "tpu_custom_call" in _lower_classes(
+        S, dtype, classes, masked, tile).compile().as_text()

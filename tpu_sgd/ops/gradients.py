@@ -95,15 +95,20 @@ def grad_sum_of(coeff, X):
         )
 
 
-def one_read_sums(X, y, weights, mask=None, margin_axis_name=None) -> bool:
+def one_read_sums(X, y, weights, mask=None, margin_axis_name=None,
+                  classes: Optional[int] = None) -> bool:
     """Whether ``batch_sums`` of these operands may take the fused one-read
     kernel where the program is lowered for a TPU — decided from what the
     operands look like, nothing else: dense 2-D bf16 or f32 rows that the
     chip stores feature-major (so ``X.T`` is a bitcast and no copy of X
-    stands in front of the kernel), a flat weight vector, one label (and
-    one mask entry) a row, whole margins on every core (a feature-sharded
-    run needs the ``psum`` between the two halves), and a block of
-    ``X.T`` that fits the kernel's VMEM."""
+    stands in front of the kernel), a flat weight vector (``classes``
+    None: one entry a feature; a class count: the row-major flattening of
+    a ``(classes - 1, d)`` matrix whose rows, padded to whole packed
+    registers, are no more than one pass of the matrix unit takes,
+    ``pallas_kernels.FM_CLASS_ROWS``), one label (and one mask entry) a
+    row, whole margins on every core (a feature-sharded run needs the
+    ``psum`` between the two halves), and a block of ``X.T`` that fits
+    the kernel's VMEM beside the class rows."""
     if (margin_axis_name is not None or _is_sparse(X)
             or getattr(X, "ndim", 0) != 2 or jnp.ndim(weights) != 1
             or X.dtype not in (jnp.bfloat16, jnp.float32)):
@@ -112,10 +117,16 @@ def one_read_sums(X, y, weights, mask=None, margin_axis_name=None) -> bool:
     if jnp.shape(y) != (n,) or (mask is not None
                                 and jnp.shape(mask) != (n,)):
         return False
-    from tpu_sgd.ops.pallas_kernels import feature_major, fm_tile
+    from tpu_sgd.ops.pallas_kernels import (FM_CLASS_ROWS, class_rows_of,
+                                            feature_major, fm_tile)
 
+    rows = 0
+    if classes is not None:
+        rows = class_rows_of(classes - 1, X.dtype)
+        if rows > FM_CLASS_ROWS or jnp.shape(weights) != ((classes - 1) * d,):
+            return False
     return feature_major(n, d) and fm_tile(
-        n, d, X.dtype.itemsize, mask is not None) is not None
+        n, d, X.dtype.itemsize, mask is not None, rows) is not None
 
 
 class Gradient:
@@ -422,15 +433,21 @@ class HingeGradient(Gradient):
         return coeff, loss
 
 
-class MultinomialLogisticGradient:
+class MultinomialLogisticGradient(Gradient):
     """K-class logistic gradient over a ``(K-1, D)`` weight matrix.
 
     Parity with the reference's multinomial branch of ``LogisticGradient``
     ([U] mllib/optimization/Gradient.scala, SURVEY.md §2 #3, "binary +
     multinomial"): the pivot class is class 0, weights hold K-1 rows, and the
     loss is the negative log-likelihood of the softmax with an implicit zero
-    logit for the pivot.  Kept as a separate class because its weight pytree is
-    a matrix, not a vector; the GLM harness reshapes accordingly.
+    logit for the pivot.  The weights travel as the flat vector of
+    ``weight_dim`` entries that is the matrix's row-major flattening
+    (MLlib's), so every driver, updater and checkpoint holds them as it
+    holds a vector's.  One of the ``Gradient`` family: in place of the
+    elementwise ``pointwise`` it supplies :meth:`class_rule`, the rule
+    between the two products for a whole column of class margins, and
+    :meth:`batch_sums` selects between one read of X and two as
+    ``Gradient.batch_sums`` does.
     """
 
     def __init__(self, num_classes: int):
@@ -441,6 +458,36 @@ class MultinomialLogisticGradient:
     def weight_dim(self, num_features: int) -> int:
         return (self.num_classes - 1) * num_features
 
+    def class_rule(self, margins: Array, labels: Array
+                   ) -> Tuple[Array, Array]:
+        """THE rule between the two products, for ``(rows, lanes)`` margins
+        (row ``r`` is class ``r + 1``; the pivot class 0 has no row and the
+        zero logit) and ``(1, lanes)`` labels: ``(dloss/dmargins (rows,
+        lanes), loss (1, lanes))``, the softmax written out so that both
+        paths trace the same operations.  Rows past ``num_classes - 1`` are
+        padding (the kernel holds the class rows at whole registers): they
+        take no part in the softmax and get a coefficient of zero."""
+        row = jax.lax.broadcasted_iota(jnp.int32, margins.shape, 0)
+        real = row < self.num_classes - 1
+        logits = jnp.where(real, margins, -jnp.inf)
+        top = jnp.maximum(jnp.max(logits, axis=0, keepdims=True), 0.0)
+        e = jnp.exp(logits - top)
+        total = jnp.exp(-top) + jnp.sum(e, axis=0, keepdims=True)
+        onehot = row + 1 == labels.astype(jnp.int32)
+        picked = jnp.sum(jnp.where(onehot, margins, 0.0), axis=0,
+                         keepdims=True)  # the label's logit; 0 the pivot's
+        losses = top + jnp.log(total) - picked
+        coeff = e * (1.0 / total) - onehot.astype(margins.dtype)
+        return coeff, losses
+
+    def compute(self, data: Array, label: Array, weights: Array
+                ) -> Tuple[Array, Array]:
+        """Single-example ``(gradient, loss)`` — Spark contract parity; the
+        gradient is flat like the weights."""
+        grad, loss, _ = self._two_read_sums(
+            data[None, :], jnp.reshape(label, (1,)), weights, None)
+        return grad, loss
+
     def batch_sums(
         self,
         X: Array,
@@ -449,32 +496,47 @@ class MultinomialLogisticGradient:
         mask: Optional[Array] = None,
         margin_axis_name: Optional[str] = None,
     ) -> Tuple[Array, Array, Array]:
-        K = self.num_classes
-        W = weights.reshape(K - 1, X.shape[-1])
+        """``Gradient.batch_sums`` for the flat ``(K-1) * D`` weights: the
+        same selection from the operands and the lowering platform, no
+        option.  One read: the class kernel, both products on the matrix
+        unit and :meth:`class_rule` between them in VMEM.  Two reads: two
+        matmuls with ``(n, K-1)`` margins and coefficients in HBM between
+        them (a CPU, X stored by rows, a feature-sharded run, BCOO)."""
+        with jax.named_scope("sgd.class_sums"):
+            if one_read_sums(X, y, weights, mask, margin_axis_name,
+                             classes=self.num_classes):
+                return jax.lax.platform_dependent(
+                    X, y, weights, mask,
+                    tpu=self._fused_sums, default=self._two_read_sums)
+            return self._two_read_sums(X, y, weights, mask, margin_axis_name)
+
+    def _fused_sums(self, X, y, weights, mask):
+        """One read of X (``ops/pallas_kernels.fused_class_sums``)."""
+        from tpu_sgd.ops.pallas_kernels import fused_class_sums
+
+        W = weights.reshape(self.num_classes - 1, X.shape[-1])
+        grad, loss_sum, count = fused_class_sums(self.class_rule, X, y, W,
+                                                 mask)
+        return grad.reshape(-1), loss_sum, count
+
+    def _two_read_sums(self, X, y, weights, mask, margin_axis_name=None):
+        """Two matmuls, each a pass over all of X (or the BCOO lowering)."""
+        W = weights.reshape(self.num_classes - 1, X.shape[-1])
         # (n, K-1); partial if features are sharded
         margins = margins_of(X, W)
         if margin_axis_name is not None:
             with jax.named_scope("sgd.margins"):
                 margins = jax.lax.psum(margins, margin_axis_name)
         with jax.named_scope("sgd.pointwise"):
-            logits = jnp.concatenate(
-                [jnp.zeros((X.shape[0], 1), margins.dtype), margins], axis=-1
-            )  # (n, K) with pivot logit 0
-            log_probs = jax.nn.log_softmax(logits, axis=-1)
-            y_int = y.astype(jnp.int32)
-            losses = -jnp.take_along_axis(
-                log_probs, y_int[:, None], axis=-1)[:, 0]
-            probs = jnp.exp(log_probs)[:, 1:]  # (n, K-1)
-            onehot = jax.nn.one_hot(y_int - 1, K - 1, dtype=margins.dtype)
-            coeff = probs - onehot  # (n, K-1)
+            coeff, losses = self.class_rule(margins.T, y[None, :])
             if mask is not None:
                 m = mask.astype(margins.dtype)
-                coeff = coeff * m[:, None]
-                losses = losses * m
+                coeff = coeff * m[None, :]
+                losses = losses * m[None, :]
                 count = jnp.sum(m)
             else:
                 count = jnp.asarray(X.shape[0], margins.dtype)
-        grad_sum = grad_sum_of(coeff, X).reshape(-1)  # flattened (K-1)*D
+        grad_sum = grad_sum_of(coeff.T, X).reshape(-1)  # flattened (K-1)*D
         with jax.named_scope("sgd.pointwise"):
             loss_sum = jnp.sum(losses)
         return grad_sum, loss_sum, count
@@ -536,9 +598,13 @@ class MultinomialLogisticGradient:
 
     def window_sums(self, X, y, weights, start, m, valid=None,
                     margin_axis_name=None):
-        """Same window contract as the vector-weight gradients."""
-        return _window_sums(self.batch_sums, X, y, weights, start, m,
-                            valid, margin_axis_name)
+        """Same window contract as the vector-weight gradients, on the
+        slice and two matmuls everywhere: the class kernel has no window
+        grid (``batch_sums`` of the sliced rows would have the window
+        copied out in front of the kernel, see ``Gradient.window_sums``)."""
+        with jax.named_scope("sgd.class_sums"):
+            return _window_sums(self._two_read_sums, X, y, weights, start,
+                                m, valid, margin_axis_name)
 
     def predict_class(self, X: Array, weights: Array) -> Array:
         K = self.num_classes

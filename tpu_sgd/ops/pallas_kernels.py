@@ -16,7 +16,9 @@ compiler on every call (seen in the compiled program for a described v5e,
 ``tests/test_chip_compile.py``; PERF.md, PR 26): one read and one write of X
 in front of the kernel's own read.
 
-One family, one body (``_fm_kernel``), two grids.
+One family over the ``(d, tile)`` blocks of ``X.T``: a body for a vector
+of weights (``_fm_kernel``) under two grids, and a body for a matrix of
+them (``_fm_class_kernel``) under the first.
 :func:`fused_gradient_sums`: the full scan with an optional
 sampling mask (reference parity with ``RDD.sample``), over ``(d, tile)``
 blocks of ``X.T``: features on sublanes, rows on lanes.  Where X is stored
@@ -33,6 +35,19 @@ nothing else routes here (``interpret=True`` is the CPU tests' way in).
 contiguous window of rows (``sampling="sliced"``), found through a
 scalar-prefetched LANE-block offset, so X is read where it lies and the
 window once; ``Gradient.window_sums`` selects it the same way.
+
+:func:`fused_class_sums` (PR 32): the full scan for a ``(C, d)`` MATRIX of
+weights, one row a class (``MultinomialLogisticGradient``).  C margins a
+lane as vector work would be C times the multiply-adds of a kernel that
+already needed a tight body to stay at the HBM bound, so both products go
+to the matrix unit, ``(C, d) @ (d, lanes)`` and ``(C, lanes) @ (lanes, d)``
+with operands in X's type and f32 sums, and the rule between them (the
+pivot softmax) runs on ``(C, lanes)`` arrays in VMEM.  On the chip the
+products hide under the block's copy: over 8,100,000 x 784 bf16 rows
+and nine class rows a call timed alone reads 18.29 ms with both
+products and 18.29 with neither (the two matmuls with their ``(n, C)``
+arrays in HBM: 38.9), and in a fit the kernel runs 16.78 ms a step, 759
+GB/s, the vector-weight kernel's pace (PERF.md, PR 32).
 
 **What the old verdict rested on.**  A second family, window kernels over
 ``(tile, d)`` ROW blocks at a scalar-prefetched row offset, ran 3.1-3.4 ms an
@@ -96,18 +111,30 @@ def feature_major(n: int, d: int) -> bool:
     return by_features < by_rows
 
 
-def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool) -> int:
+def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool,
+                   class_rows: int = 0) -> int:
     """Scoped VMEM one grid step of the feature-major kernel needs: per
     lane the ``(d, tile)`` block of ``X.T`` double-buffered (d pads to a
     packed vreg's rows) and the ``(1, tile)`` f32 rows of y (and the mask)
     double-buffered at 8 sublanes each; besides, the ``(d, 128)`` f32
     weights and gradient partials, two buffers each, and 1 MB for the
-    body's temporaries.  Above the compiler's own count at every shape
-    tried (tests/test_chip_compile.py), so a tile it admits compiles."""
+    body's temporaries.  The class kernel (``class_rows`` > 0) holds
+    instead the ``(class_rows, d)`` weights in X's type and the gradient
+    in f32 (d pads to whole lane groups), two buffers each, and beside
+    the 1 MB one lane chunk of the block in X's type (the cut block's
+    copy with the lanes outside replaced) and six ``(class_rows, chunk)``
+    f32 arrays of the rule between the products.  Above the compiler's
+    own count at every shape tried (tests/test_chip_compile.py), so a
+    tile it admits compiles."""
     per_lane = (2 * _round_up(d, 32 // itemsize) * itemsize
                 + 2 * (2 if masked else 1) * SUBLANES * 4)
-    return (per_lane * tile + 4 * _round_up(d, SUBLANES) * LANES * 4
-            + (1 << 20))
+    if class_rows:
+        fixed = (2 * class_rows * _round_up(d, LANES) * (itemsize + 4)
+                 + _FM_LANE_CHUNK * (_round_up(d, 32 // itemsize) * itemsize
+                                     + 6 * class_rows * 4))
+    else:
+        fixed = 4 * _round_up(d, SUBLANES) * LANES * 4
+    return per_lane * tile + fixed + (1 << 20)
 
 
 def _fm_round(tile_m: int, n: int) -> int:
@@ -116,30 +143,31 @@ def _fm_round(tile_m: int, n: int) -> int:
     return min(max(LANES, tile_m // LANES * LANES), _round_up(n, LANES))
 
 
-def fm_tile(n: int, d: int, itemsize: int, masked: bool = True
-            ) -> Optional[int]:
+def fm_tile(n: int, d: int, itemsize: int, masked: bool = True,
+            class_rows: int = 0) -> Optional[int]:
     """The feature-major kernel's own choice of row tile for an ``(n, d)``
     X: ``FM_TILE`` halved until its VMEM fits — None where not even one
     lane group does (a very wide d: the two-read path's case)."""
     tile = FM_TILE
-    while _fm_vmem_bytes(tile, d, itemsize, masked) > _FM_VMEM_LIMIT:
+    while _fm_vmem_bytes(tile, d, itemsize, masked,
+                         class_rows) > _FM_VMEM_LIMIT:
         if tile == LANES:
             return None
         tile //= 2
     return _fm_round(tile, n)
 
 
-def _check_fm_vmem(tile: int, X, masked: bool) -> None:
+def _check_fm_vmem(tile: int, X, masked: bool, class_rows: int = 0) -> None:
     """Reject a tile the chip's compiler would refuse with an error that
     names the largest one it admits (or says that not even one lane group
     fits), instead of a Mosaic compile-time OOM."""
     d = X.shape[1]
     itemsize = jnp.dtype(X.dtype).itemsize
-    need = _fm_vmem_bytes(tile, d, itemsize, masked)
+    need = _fm_vmem_bytes(tile, d, itemsize, masked, class_rows)
     if need <= _FM_VMEM_LIMIT:
         return
-    fixed = _fm_vmem_bytes(0, d, itemsize, masked)
-    per_lane = _fm_vmem_bytes(1, d, itemsize, masked) - fixed
+    fixed = _fm_vmem_bytes(0, d, itemsize, masked, class_rows)
+    per_lane = _fm_vmem_bytes(1, d, itemsize, masked, class_rows) - fixed
     max_tile = (_FM_VMEM_LIMIT - fixed) // per_lane // LANES * LANES
     hint = (
         f"use tile_m <= {max_tile}"
@@ -276,24 +304,34 @@ def _fm_kernel(pointwise, n, masked, window, *refs):
 
         sweep(gradient_step, None)
 
-    def block(tail):
-        if tile == lw:
-            lanes_of(0, tail)
-        else:
-            def body(c, carry):
-                lanes_of(c, tail)
-                return carry
-
-            jax.lax.fori_loop(0, tile // lw, body, None)
-
+    block = functools.partial(_fm_block, lanes_of, tile, lw)
     if window is not None:
         last = s_ref[2] - s_ref[1]  # the grid step of the window's last row
         pl.when((i == 0) | (i == last))(lambda: block(True))
         pl.when((i > 0) & (i < last))(lambda: block(False))
-    elif n % tile == 0:
+    else:
+        _fm_full_scan(block, n, tile)
+
+
+def _fm_block(lanes_of, tile, lw, tail):
+    """``lanes_of(chunk, tail)`` over a block's lane chunks of ``lw``."""
+    if tile == lw:
+        lanes_of(0, tail)
+    else:
+        def body(c, carry):
+            lanes_of(c, tail)
+            return carry
+
+        jax.lax.fori_loop(0, tile // lw, body, None)
+
+
+def _fm_full_scan(block, n, tile):
+    """The full scan's grid step: ``block(tail)`` with the grid's last
+    block cut at row ``n`` where the tile does not divide the rows."""
+    if n % tile == 0:
         block(False)
     else:
-        last_block = pl.num_programs(0) - 1
+        i, last_block = pl.program_id(0), pl.num_programs(0) - 1
         pl.when(i < last_block)(lambda: block(False))
         pl.when(i == last_block)(lambda: block(True))
 
@@ -323,20 +361,20 @@ def fused_gradient_sums(
     )
 
 
-def _fm_tile_of(X, tile_m: Optional[int], masked: bool, interpret: bool
-                ) -> int:
+def _fm_tile_of(X, tile_m: Optional[int], masked: bool, interpret: bool,
+                class_rows: int = 0) -> int:
     """The row tile of a call on ``X``: the kernel's own choice, or the
     caller's floored to whole lane groups; checked against the VMEM the
     chip's compiler allows unless the interpreter runs it."""
     n, d = X.shape
     if tile_m is None:
         # too wide for one lane group: the check below says so
-        tile = (fm_tile(n, d, jnp.dtype(X.dtype).itemsize, masked)
-                or _fm_round(LANES, n))
+        tile = (fm_tile(n, d, jnp.dtype(X.dtype).itemsize, masked,
+                        class_rows) or _fm_round(LANES, n))
     else:
         tile = _fm_round(tile_m, n)
     if not interpret:
-        _check_fm_vmem(tile, X, masked)
+        _check_fm_vmem(tile, X, masked, class_rows)
     return tile
 
 
@@ -408,6 +446,143 @@ def _fused_gradient_sums(
         interpret=interpret,
     )(*operands)
     return _fold_sums(grad, loss, cnt, None if masked else n)
+
+
+#: class rows one call of the class kernel takes at most: one pass of the
+#: matrix unit's 128 rows (more are the two-read path's; not measured)
+FM_CLASS_ROWS = 128
+
+
+def class_rows_of(C: int, dtype) -> int:
+    """Rows the class kernel holds ``C`` class rows of weights and
+    coefficients at: padded to whole packed registers of ``dtype``."""
+    return _round_up(C, 32 // jnp.dtype(dtype).itemsize)
+
+
+def _fm_class_kernel(rule, n, masked, xt_ref, y_ref, *refs):
+    """One ``(d, tile)`` block of ``X.T`` for a ``(rows, d)`` MATRIX of
+    weights, one row a class: both products go to the matrix unit with
+    operands in X's type and f32 sums, ``(rows, d) @ (d, lanes)`` for the
+    margins and ``(rows, lanes) @ (lanes, d)`` for the gradient, with
+    ``rule(margins, labels) -> (dloss/dmargins, loss)`` between them on
+    ``(rows, lanes)`` arrays in VMEM.  The block is read from VMEM twice
+    and from HBM once; the gradient is summed over the grid in f32, the
+    loss and the count as lane partials, as in ``_fm_kernel``, whose grid
+    and tail cut these are."""
+    m_ref = refs[0] if masked else None
+    w_ref, g_ref, loss_ref, cnt_ref = refs[-4:]
+    i = pl.program_id(0)
+    d, tile = xt_ref.shape
+    f32 = jnp.float32
+    lw = _fm_lane_chunk(tile)
+
+    @pl.when(i == 0)
+    def _():
+        g_ref[:] = jnp.zeros_like(g_ref)
+        loss_ref[:] = jnp.zeros_like(loss_ref)
+        cnt_ref[:] = jnp.zeros_like(cnt_ref)
+
+    def lanes_of(c, tail):
+        """Sums of the block's lanes ``[c * lw, (c + 1) * lw)``; in the
+        cut block every value read from a lane past row ``n`` is replaced
+        before any arithmetic, as in ``_fm_kernel``."""
+        c0 = c * lw if isinstance(c, int) else pl.multiple_of(c * lw, lw)
+        lanes = pl.ds(c0, lw)
+        x = xt_ref[:, lanes]
+        y = y_ref[:, lanes]
+        if tail:
+            inside = (i * tile + c0 + jax.lax.broadcasted_iota(
+                jnp.int32, (1, lw), 1)) < n
+            x = jnp.where(inside, x, jnp.zeros_like(x))
+            y = jnp.where(inside, y, 0.0)
+        margins = jnp.dot(w_ref[:], x, preferred_element_type=f32)
+        coeff, losses = rule(margins, y)
+        if masked:
+            m = m_ref[:, lanes]
+            if tail:
+                m = jnp.where(inside, m, 0.0)
+            coeff, losses = coeff * m, losses * m
+            cnt_ref[:] += _lane_fold(m)
+        elif tail:
+            coeff = jnp.where(inside, coeff, 0.0)
+            losses = jnp.where(inside, losses, 0.0)
+        loss_ref[:] += _lane_fold(losses)
+        g_ref[:] += jax.lax.dot_general(
+            coeff.astype(x.dtype), x, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32)
+
+    _fm_full_scan(functools.partial(_fm_block, lanes_of, tile, lw), n, tile)
+
+
+def fused_class_sums(
+    rule,
+    X: Array,
+    y: Array,
+    W: Array,
+    mask: Optional[Array] = None,
+    tile_m: Optional[int] = None,
+    interpret: bool = False,
+) -> Tuple[Array, Array, Array]:
+    """Fused ``(grad_sum (C, d), loss_sum, count)`` in ONE read of ``X``
+    for a ``(C, d)`` matrix of weights, one row a class.
+
+    ``rule(margins (rows, lanes), labels (1, lanes)) -> (dloss/dmargins,
+    loss (1, lanes))`` is traced into the kernel and is handed the class
+    rows PADDED to whole packed registers (``class_rows_of``): the rows of
+    ``W`` past ``C`` are zero, and the rule has to give them a coefficient
+    of zero.  Grid, tile and tail cut are :func:`fused_gradient_sums`';
+    the products run on the matrix unit in ``X``'s type (``W`` and the
+    coefficients are rounded to it) with f32 sums.
+    """
+    C, d = W.shape
+    rows = class_rows_of(C, X.dtype)
+    if rows > FM_CLASS_ROWS:
+        raise ValueError(f"{C} class rows: the class kernel takes at most "
+                         f"{FM_CLASS_ROWS}; use the XLA path")
+    tile = _fm_tile_of(X, tile_m, mask is not None, interpret, rows)
+    return _fused_class_sums(rule, X, y, W, mask, rows=rows, tile_m=tile,
+                             interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("rule", "rows", "tile_m", "interpret")
+)
+def _fused_class_sums(
+    rule,
+    X: Array,
+    y: Array,
+    W: Array,
+    mask: Optional[Array] = None,
+    rows: int = 16,
+    tile_m: int = FM_TILE,
+    interpret: bool = False,
+) -> Tuple[Array, Array, Array]:
+    n, d = X.shape
+    C = W.shape[0]
+    tile = tile_m
+    masked = mask is not None
+    f32 = jnp.float32
+    row = pl.BlockSpec((1, tile), lambda i: (0, i))
+    whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))  # noqa: E731
+    operands = [X.T, y.reshape(1, n).astype(f32)]
+    if masked:
+        # as in _fused_gradient_sums: the mask's fusion writes f32[n]
+        operands.append(
+            jax.lax.optimization_barrier(mask.astype(f32)).reshape(1, n))
+    operands.append(jnp.pad(W.astype(X.dtype), ((0, rows - C), (0, 0))))
+    grad, loss, cnt = pl.pallas_call(
+        functools.partial(_fm_class_kernel, rule, n, masked),
+        grid=(pl.cdiv(n, tile),),
+        in_specs=[pl.BlockSpec((d, tile), lambda i: (0, i))]
+        + [row] * (1 + masked) + [whole((rows, d))],
+        out_specs=[whole((rows, d)), whole((1, LANES)), whole((1, LANES))],
+        out_shape=[jax.ShapeDtypeStruct((rows, d), f32)]
+        + _fm_sums_shape(d)[1:],
+        compiler_params=_FM_COMPILER_PARAMS,
+        interpret=interpret,
+    )(*operands)
+    count = jnp.sum(cnt) if masked else jnp.asarray(n, f32)
+    return grad[:C], jnp.sum(loss), count
 
 
 def fused_window_sums(

@@ -1361,8 +1361,11 @@ class GradientDescent(Optimizer):
     def optimize_with_history(self, data: Dataset, initial_weights: Array):
         import numpy as np
 
+        # classes: K for a (K-1, d) matrix of weights, 2 for a vector
         with span("train.run", iterations=self.config.num_iterations,
-                  rows=np.shape(data[0])[0]) as run_span:
+                  rows=np.shape(data[0])[0],
+                  classes=getattr(self.gradient, "num_classes", 2)
+                  ) as run_span:
             return self._optimize(data, initial_weights, run_span)
 
     def _optimize(self, data: Dataset, initial_weights: Array, run_span):
