@@ -299,17 +299,160 @@ def test_classes_run_at_the_cells_shape_reads_x_once_in_place(S):
     assert memory.argument_size_in_bytes < n * d * 2 * 1.01
 
 
+def _computations(text):
+    """``{name: [instruction lines]}`` of a compiled program's text, the
+    entry computation also under ``"ENTRY"``."""
+    import re
+
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            if head.group(1):
+                comps["ENTRY"] = comps[name]
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and " = " in line:
+            comps[name].append(line.strip())
+    return comps
+
+
+def _reach(comps, root):
+    """``root`` and every computation it calls, however deep (fusions,
+    inner ``while`` bodies and conditions, reducers, branches)."""
+    import re
+
+    seen, todo = [], [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.append(name)
+        for line in comps[name]:
+            for called in re.findall(
+                    r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", line):
+                todo.append(called)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += re.findall(r"%?([\w.\-]+)", group)
+    return seen
+
+
+def _fit_loop_body(comps):
+    """The body of the fit's ``while``: the one in the entry computation
+    whose reach holds the Mosaic call."""
+    import re
+
+    for line in comps["ENTRY"]:
+        loop = re.search(r" while\(.*body=%?([\w.\-]+)", line)
+        if loop and any("tpu_custom_call" in inner
+                        for name in _reach(comps, loop.group(1))
+                        for inner in comps[name]):
+            return loop.group(1)
+    raise AssertionError("no while in ENTRY holds the kernel")
+
+
+def _made_with(comps, names, elements):
+    """``(opcode, shape)`` of every instruction in the computations
+    ``names`` that MAKES an array of ``elements`` entries (a hand-on,
+    ``_NO_MOVE``, makes none)."""
+    import re
+
+    made = []
+    for name in names:
+        for line in comps[name]:
+            inst = re.match(
+                r"(?:ROOT )?%?[\w.\-]+ = (\w+\[([\d,]+)\])\S* ([a-z\-]+)\(",
+                line)
+            if inst and inst.group(3) not in _NO_MOVE and np.prod(
+                    [int(k) for k in inst.group(2).split(",")]) == elements:
+                made.append((inst.group(3), inst.group(1)))
+    return made
+
+
+def test_the_class_fits_loop_holds_the_kernel_and_no_array_of_the_labels(S):
+    """PR 33.  8,100,000 is no multiple of the flat layout's 1024, so
+    ``f32[n] -> f32[1, n]`` moves every label, and at this size the chip's
+    compiler writes it as a copy, a fill and an inner ``while`` of
+    ``dynamic-slice`` + ``dynamic-update-slice`` which it left INSIDE the
+    fit's loop while the source had the reshape there (0.296 ms of every
+    17.08 ms step, PERF.md).  ``sgd_run`` lays the row out in front of the
+    loop: the loop's body, and everything it calls, makes NO array of
+    8,100,000 entries (the parent made six), the relayout stands in the
+    entry computation, once, and the kernel reads the row the loop
+    carries.  If a later compiler sinks it back, fence the row
+    (``optimization_barrier``) or pad it once to a multiple of 1024."""
+    n, d, K = MNIST8M
+    comps = _computations(_classes_run(S).as_text())
+    body = _reach(comps, _fit_loop_body(comps))
+    assert _made_with(comps, body, n) == []
+    outside = [name for name in _reach(comps, "ENTRY") if name not in body]
+    made = _made_with(comps, outside, n)
+    assert {"copy-done", "broadcast", "dynamic-update-slice"} <= {
+        op for op, _ in made}
+    assert all(shape in ("f32[%d]" % n, "f32[1,1,%d]" % n)
+               for _, shape in made)
+    call, = [line for name in body for line in comps[name]
+             if "tpu_custom_call" in line]
+    assert "f32[1,%d]" % n in call
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_ROWS))
+def test_the_masked_fits_loop_lays_out_the_mask_and_not_the_labels(S, cell):
+    """The same at the masked cells' shapes, where the compiler hoisted
+    the labels' reshape by itself before PR 33 moved it in the source:
+    pinned, not assumed.  One ``reshape`` to ``f32[1, n]`` stands outside
+    the fit's loop under ``sgd.prepare`` (2,145,000 is no multiple of
+    1024; at 4,194,304 it is a bitcast and nothing is made), and inside
+    the loop the one such reshape is the MASK's, drawn anew each step
+    (0.0136 ms of 6.136 from-host, PERF.md section 7)."""
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    n = CELL_ROWS[cell]
+    cfg = _cfg(step_size=5.0, num_iterations=100, reg_param=0.001,
+               convergence_tol=0.0)
+    text = jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg)
+                   ).lower(S((D,), F32), S((n, D), BF16),
+                           S((n,), F32)).compile().as_text()
+    comps = _computations(text)
+    loop = _fit_loop_body(comps)
+    # (a ``copy-done`` of the row in the loop is the compiler's prefetch of
+    # it into the nearer memory, layout unchanged; the parent's, of f32[n])
+    rows_made = [[made for made in _made_with(comps, [name], n)
+                  if made == ("reshape", "f32[1,%d]" % n)
+                  or made[0] in ("fusion", "copy", "dynamic-update-slice")
+                  and made[1] == "f32[1,%d]" % n]
+                 for name in ("ENTRY", loop)]
+    moved = n % 1024 != 0
+    assert rows_made == [[("reshape", "f32[1,%d]" % n)] * moved] * 2
+    if moved:
+        outside, = [line for line in comps["ENTRY"]
+                    if " reshape(" in line and "f32[1,%d]" % n in line]
+        assert "sgd.prepare" in outside and "%y" in outside
+        inside, = [line for line in comps[loop]
+                   if " reshape(" in line and "f32[1,%d]" % n in line]
+        assert "sgd.fused_sums" in inside and "%y" not in inside
+
+
 #: sha256 (16 hex digits) of the masked fit's program lowered for a TPU at
 #: the three masked cells' shapes: the StableHLO outside the Mosaic call,
 #: and the call's body parsed and printed WITHOUT locations (the serialized
-#: body carries file paths and line numbers).  They are PR 30's programs:
-#: PR 31 put the window's grid beside the masked one on the same kernel
-#: body and had to leave these as they were.  A PR that means to change
-#: the masked step changes them here, and says so.
+#: body carries file paths and line numbers).  The kernel bodies (the
+#: second of each pair) are PR 30's: PR 31 put the window's grid beside the
+#: masked one on the same kernel body and had to leave them as they were.
+#: The first of each pair is PR 33's, which moved ONE line of the StableHLO:
+#: the labels' ``reshape`` to ``tensor<1xNxf32>`` stands in front of the
+#: ``while`` (``sgd.prepare``) and the row rides in the loop's operands
+#: to ``@_fused_gradient_sums``, where PR 30's program had it inside the
+#: called function.  The COMPILED step is the parent's: its compiler hoisted
+#: that reshape out of the loop at these sizes by itself
+#: (``test_the_masked_fits_loop_lays_out_the_mask_and_not_the_labels``).
+#: A PR that means to change the masked step changes them here, and says so.
 MASKED_PROGRAMS = {
-    "resident": ("050e608478fe7f09", "f5c6595b5d61f671"),
-    "from-host": ("a8045ecd5b2d7fb0", "3d660c2b20cbfaf7"),
-    "resident-sharded": ("3a291e32cfaf6cbe", "aba2383d2357b17e"),
+    "resident": ("e8da7b203a71f46f", "f5c6595b5d61f671"),
+    "from-host": ("56bee9814e9c64a1", "3d660c2b20cbfaf7"),
+    "resident-sharded": ("000a06458a33c051", "aba2383d2357b17e"),
 }
 
 

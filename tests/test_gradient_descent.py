@@ -659,7 +659,8 @@ STEP_SCOPES = {"sgd.sample", "sgd.margins", "sgd.pointwise", "sgd.gradient",
 def test_lowered_step_carries_the_named_scopes(case):
     """``jax.named_scope`` at trace time, in the one place each piece is
     defined: every driver inherits the names, ``sgd.allreduce`` exists only
-    on a mesh and ``sgd.converge`` only in the whole-run loop."""
+    on a mesh and ``sgd.converge`` only in the whole-run loop, as does
+    ``sgd.prepare`` (PR 33: dense labels laid out once, in front of it)."""
     import jax
     import jax.numpy as jnp
 
@@ -676,7 +677,7 @@ def test_lowered_step_carries_the_named_scopes(case):
         fn = jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg))
         assert fn.__name__ == "sgd_run"  # part of the compile cache's key
         found = _scopes_in(fn.lower(w, X, y))
-        assert found == STEP_SCOPES | {"sgd.converge"}
+        assert found == STEP_SCOPES | {"sgd.converge", "sgd.prepare"}
     elif case == "bcoo_hinge":
         X, y, _ = sparse_data(64, 8, nnz_per_row=3, kind="svm")
         fn = jax.jit(make_run(HingeGradient(), L1Updater(), cfg))

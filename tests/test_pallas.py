@@ -435,3 +435,268 @@ def test_fused_bf16_inputs():
     assert gs.dtype == jnp.float32  # f32 accumulation
     np.testing.assert_allclose(np.asarray(gs), np.asarray(gs_ref), rtol=0.05,
                                atol=0.5)
+
+
+# -- row operands laid out once a fit (PR 33) ---------------------------------
+
+#: the three kernel entries and which masks they take: the labels (and a mask
+#: that is the same every step) reach them as ``(n,)`` or already as the
+#: ``(1, n)`` float32 rows ``Gradient.row_operands`` lays out before a loop
+ROW_ENTRIES = ["gradient_all", "gradient_masked", "window_all",
+               "window_valid", "classes_all", "classes_masked"]
+
+
+def _entry_sums(entry, labels, mask):
+    """The entry's sums over 333 rows (no multiple of 128: two blocks and
+    a cut one) in the interpreter, with the labels and the mask as handed."""
+    import jax.numpy as jnp
+
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+    from tpu_sgd.ops.pallas_kernels import fused_class_sums
+
+    n, d, K = 333, 24, 10
+    X, _, w = _data(n=n, d=d, seed=31)
+    kind = entry.split("_")[0]
+    if kind == "classes":
+        g = MultinomialLogisticGradient(K)
+        W = np.random.default_rng(32).normal(size=(K - 1, d)) * 0.1
+        return fused_class_sums(g.class_rule, jnp.asarray(X, jnp.bfloat16),
+                                labels, jnp.asarray(W, jnp.float32), mask,
+                                tile_m=128, interpret=True)
+    g = LogisticGradient()
+    if kind == "window":
+        return fused_window_sums(g.pointwise, X, labels, w, jnp.int32(57),
+                                 100, mask, tile_m=128, interpret=True)
+    return fused_gradient_sums(g.pointwise, X, labels, w, mask, tile_m=128,
+                               interpret=True)
+
+
+@pytest.mark.parametrize("entry", ROW_ENTRIES)
+def test_rows_laid_out_before_the_call_give_the_sums_bit_for_bit(entry):
+    """Labels (and the mask) handed as ``(1, n)`` float32 go to the kernel
+    as they are and give the sums of the ``(n,)`` ones, bit for bit: the
+    entries select on the SHAPE they are handed, no argument says which."""
+    import jax.numpy as jnp
+
+    from tpu_sgd.ops.pallas_kernels import row_operand
+
+    n = 333
+    r = np.random.default_rng(33)
+    y = jnp.asarray(r.integers(0, 10 if entry.startswith("classes") else 2,
+                               n), jnp.float32)
+    mask = None if entry.endswith("_all") else jnp.asarray(
+        r.uniform(size=n) < 0.6)
+    flat = _entry_sums(entry, y, mask)
+    y_row = row_operand(y, n)
+    assert y_row.shape == (1, n) and row_operand(y_row, n) is y_row
+    laid = _entry_sums(entry, y_row,
+                       None if mask is None else row_operand(mask, n))
+    for a, b in zip(laid, flat):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the labels alone laid out, the mask as the step draws it: (n,)
+    if mask is not None:
+        for a, b in zip(_entry_sums(entry, y_row, mask), flat):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _run_case(name):
+    """``(gradient, config, X, y, w0, valid)`` of a 333-row fit."""
+    from tpu_sgd.config import SGDConfig
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+
+    n, d, K = 333, 24, 10
+    X, y, _ = _data(n=n, d=d, seed=41, classify=True)
+    r = np.random.default_rng(42)
+    valid = (r.uniform(size=n) < 0.8) if name.endswith("_valid") else None
+    base = dict(step_size=0.5, num_iterations=4, reg_param=0.01,
+                convergence_tol=0.0)
+    if name.startswith("logistic_masked"):
+        g, cfg = LogisticGradient(), SGDConfig(mini_batch_fraction=0.4,
+                                               **base)
+    elif name.startswith("least_squares_sliced"):
+        g, cfg = LeastSquaresGradient(), SGDConfig(
+            mini_batch_fraction=0.4, sampling="sliced", **base)
+    else:
+        assert name.startswith("ten_classes_full_batch"), name
+        g, cfg = MultinomialLogisticGradient(K), SGDConfig(
+            mini_batch_fraction=1.0, **base)
+        y = r.integers(0, K, n).astype(np.float32)
+    return g, cfg, X, y, np.zeros(g.weight_dim(d), np.float32), valid
+
+
+RUN_CASES = ["logistic_masked", "logistic_masked_valid",
+             "least_squares_sliced", "least_squares_sliced_valid",
+             "ten_classes_full_batch", "ten_classes_full_batch_valid"]
+
+
+def _as_lowered_for_a_tpu(monkeypatch):
+    """``ops/gradients.py`` as a program lowered for a TPU has it, run here:
+    ``platform_dependent`` takes its ``tpu`` branch and the kernels run in
+    the interpreter.  Returns the list the entries' label shapes go to."""
+    import functools
+
+    import jax
+
+    from tpu_sgd.ops import gradients, pallas_kernels
+
+    class Lax:
+        def __getattr__(self, name):
+            return getattr(jax.lax, name)
+
+        @staticmethod
+        def platform_dependent(*args, tpu, default):
+            return tpu(*args)
+
+    class Jax:
+        lax = Lax()
+
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+    monkeypatch.setattr(gradients, "jax", Jax())
+    seen = []
+    for name in ("fused_gradient_sums", "fused_window_sums",
+                 "fused_class_sums"):
+        def entry(rule, X, y, *args, _kernel=getattr(pallas_kernels, name),
+                  **kw):
+            seen.append(tuple(y.shape))
+            return _kernel(rule, X, y, *args, tile_m=128, interpret=True,
+                           **kw)
+
+        monkeypatch.setattr(pallas_kernels, name,
+                            functools.wraps(getattr(pallas_kernels, name))(
+                                entry))
+    return seen
+
+
+@pytest.mark.parametrize("case", RUN_CASES)
+def test_a_fit_with_the_rows_prepared_is_the_fit_without_bit_for_bit(
+        case, monkeypatch):
+    """``make_run``'s fit on the path a TPU takes (the kernel, here in the
+    interpreter): with the labels laid out once before the loop the kernel
+    is handed the ``(1, n)`` row every step, without them the ``(n,)``
+    labels, and weights, loss history and count are the same bits.  So are
+    they on the two-read path this CPU takes, where the row rides unread."""
+    import jax
+
+    from tpu_sgd.ops.updaters import SquaredL2Updater
+    from tpu_sgd.optimize import gradient_descent as gd
+
+    g, cfg, X, y, w0, valid = _run_case(case)
+    n = X.shape[0]
+    assert gd.rows_prepared(g, cfg, X, y, w0, valid)
+
+    def fit(prepared):
+        with monkeypatch.context() as m:
+            if not prepared:
+                m.setattr(gd, "prepare_rows", lambda *a, **k: None)
+            run = jax.jit(gd.make_run(g, SquaredL2Updater(), cfg))
+            return [np.asarray(a) for a in run(w0, X, y, valid)]
+
+    here = fit(True)
+    for a, b in zip(here, fit(False)):
+        np.testing.assert_array_equal(a, b)
+    seen = _as_lowered_for_a_tpu(monkeypatch)
+    with_rows = fit(True)
+    assert seen and set(seen) == {(1, n)}
+    del seen[:]
+    without = fit(False)
+    assert seen and set(seen) == {(n,)}
+    for a, b in zip(with_rows, without):
+        np.testing.assert_array_equal(a, b)
+    assert int(with_rows[2]) == cfg.num_iterations
+    # and the kernel's fit is the two-read fit to rounding
+    np.testing.assert_allclose(with_rows[0], here[0], rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("case", ["indexed", "bcoo", "row_major_width",
+                                  "feature_sharded", "statistics",
+                                  "chunked_window", "classes_sliced"])
+def test_rows_prepared_is_false_where_no_kernel_reads_them(case):
+    """Where the step's sums are not the one-read kernel the step takes
+    ``y`` as it is: ``prepare_rows`` makes nothing and ``labels_prepared``
+    reads 0."""
+    import jax.numpy as jnp
+
+    from tpu_sgd.config import SGDConfig
+    from tpu_sgd.ops.gradients import (ChunkedGradient,
+                                       MultinomialLogisticGradient)
+    from tpu_sgd.ops.gram import GramLeastSquaresGradient
+    from tpu_sgd.optimize import gradient_descent as gd
+
+    n, d = 1024, 1000
+    X, y, w = jnp.zeros((n, d), jnp.bfloat16), jnp.zeros(n), jnp.zeros(d)
+    g, axis = LogisticGradient(), None
+    kw = dict(mini_batch_fraction=0.1)
+    if case == "indexed":
+        kw["sampling"] = "indexed"
+    elif case == "bcoo":
+        X, _, _, _, _ = _selection_case("bcoo")
+    elif case == "row_major_width":
+        X, w = jnp.zeros((n, 1024), jnp.bfloat16), jnp.zeros(1024)
+    elif case == "feature_sharded":
+        axis = "model"
+    elif case == "statistics":
+        g = GramLeastSquaresGradient()
+    elif case == "chunked_window":
+        g, kw["sampling"] = ChunkedGradient(g, 256), "sliced"
+    else:
+        g, kw["sampling"] = MultinomialLogisticGradient(3), "sliced"
+        w = jnp.zeros(2 * d)
+    cfg = SGDConfig(**kw)
+    assert not gd.rows_prepared(g, cfg, X, y, w, None, axis)
+    assert gd.prepare_rows(g, cfg, X, y, w, None, axis) is None
+    if case == "chunked_window":  # its batch sums are the base's: prepared
+        assert gd.rows_prepared(g, SGDConfig(mini_batch_fraction=0.1),
+                                X, y, w)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_train_run_says_whether_the_labels_were_prepared(backend,
+                                                         monkeypatch):
+    """``train.run``'s ``labels_prepared``: 1 where the fit's program lays
+    the labels out before its loop for the kernel (a TPU, the one-read
+    step; a shard's operands under a mesh), 0 where the step takes ``y``
+    as it is: every fit on a CPU, a gathered batch, rows stored by rows."""
+    import jax
+
+    import tpu_sgd
+    from tpu_sgd.obs.spans import disable_tracing, enable_tracing
+
+    class Sink:
+        def __init__(self):
+            self.records = []
+
+        def emit(self, kind, payload):
+            self.records.append((kind, dict(payload)))
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    X, y, _ = _data(n=512, d=24, seed=51, classify=True)
+    wide = _data(n=512, d=128, seed=52)[0]  # (512, 128): stored by rows
+
+    def fit(X, mesh=None, **kw):
+        opt = tpu_sgd.GradientDescent(
+            LogisticGradient(), tpu_sgd.SquaredL2Updater()
+        ).set_num_iterations(2).set_mini_batch_fraction(0.5)
+        if mesh is not None:
+            opt.set_mesh(mesh)
+        if "sampling" in kw:
+            opt.set_sampling(kw["sampling"])
+        opt.optimize_with_history((X, y), np.zeros(X.shape[1], np.float32))
+
+    sink = Sink()
+    enable_tracing(sink)
+    try:
+        fit(X)
+        fit(X, sampling="sliced")
+        fit(X, mesh=tpu_sgd.data_mesh(jax.devices()[:4]))
+        fit(X, sampling="indexed")
+        fit(wide)
+    finally:
+        disable_tracing()
+    runs = [p for k, p in sink.records
+            if k == "trace_span" and p["name"] == "train.run"]
+    assert [r["path"] for r in runs] == ["fused", "fused", "mesh", "fused",
+                                         "fused"]
+    assert [r["labels_prepared"] for r in runs] == (
+        [1, 1, 1, 0, 0] if backend == "tpu" else [0] * 5)

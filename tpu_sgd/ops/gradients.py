@@ -27,7 +27,6 @@ Closed forms mirrored exactly from the reference semantics (SURVEY.md §3.2):
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -157,6 +156,7 @@ class Gradient:
         weights: Array,
         mask: Optional[Array] = None,
         margin_axis_name: Optional[str] = None,
+        rows=None,
     ) -> Tuple[Array, Array, Array]:
         """Fused mini-batch ``(grad_sum, loss_sum, count)``.
 
@@ -173,21 +173,64 @@ class Gradient:
         mode), each core computes a partial margin from its column block;
         pass the mesh axis to all-reduce those partials into full margins.
         The returned grad_sum is then the local feature block's gradient.
+
+        ``rows``: what :meth:`row_operands` made of these ``X``, ``y`` and
+        (where it is the same every call) this ``mask``, once, in front of
+        the caller's loop; the one-read kernel then reads them as they lie.
+        They ride beside ``y`` and ``mask``, which the two-read path reads.
         """
         if one_read_sums(X, y, weights, mask, margin_axis_name):
             # both are traced; the platform the program is LOWERED for
             # picks one, so a CPU process compiling for the chip gets the
             # kernel and a CPU run the two matvecs it always had
             return jax.lax.platform_dependent(
-                X, y, weights, mask,
-                tpu=self._fused_sums, default=self._two_read_sums)
+                X, y, weights, mask, rows,
+                tpu=self._fused_sums, default=self._two_read_default)
         return self._two_read_sums(X, y, weights, mask, margin_axis_name)
 
-    def _fused_sums(self, X, y, weights, mask):
+    def _two_read_default(self, X, y, weights, mask, rows):
+        """``platform_dependent``'s other branch: the ``(n,)`` operands."""
+        return self._two_read_sums(X, y, weights, mask)
+
+    def prepares_rows(self, X, y, weights, valid=None,
+                      margin_axis_name=None, window: Optional[int] = None
+                      ) -> bool:
+        """Whether :meth:`row_operands` lays these operands out: where the
+        sums of every step (``batch_sums``; ``window_sums`` over a window
+        of ``window`` rows) will be the one-read kernel if the program is
+        lowered for a TPU.  Decided from shapes and types alone, so the
+        host can ask it of a fit it is about to dispatch."""
+        return ((window is None or 0 < window <= jnp.shape(X)[0])
+                and one_read_sums(X, y, weights, valid, margin_axis_name))
+
+    def row_operands(self, X, y, weights, valid=None,
+                     margin_axis_name=None, window: Optional[int] = None):
+        """The kernel's loop-invariant row operands, laid out ONCE: ``(labels,
+        valid or None)`` as the ``(1, n)`` float32 rows its block specs
+        read (``pallas_kernels.row_operand``), for the caller of a loop
+        to make in front of it and hand to every step's ``batch_sums`` /
+        ``window_sums`` as ``rows``; None where no kernel will read them
+        (:meth:`prepares_rows`) and the step takes ``y`` as it is.
+
+        ``valid`` is a mask that is the same every step (a padded shard's);
+        one drawn each step stays the step's.  Why the source and not the
+        compiler moves them: ``ops/pallas_kernels.py``, "What reaches the
+        kernels as a bitcast"."""
+        if not self.prepares_rows(X, y, weights, valid, margin_axis_name,
+                                  window):
+            return None
+        from tpu_sgd.ops.pallas_kernels import row_operand
+
+        n = X.shape[0]
+        return (row_operand(y, n),
+                None if valid is None else row_operand(valid, n))
+
+    def _fused_sums(self, X, y, weights, mask, rows=None):
         """One read of X: the Pallas kernel over the feature-major blocks
         the chip already stores (``ops/pallas_kernels.py``)."""
         from tpu_sgd.ops.pallas_kernels import fused_gradient_sums
 
+        y, mask = _kernel_rows(y, mask, rows)
         with jax.named_scope("sgd.fused_sums"):
             return fused_gradient_sums(self.pointwise, X, y, weights, mask)
 
@@ -243,6 +286,7 @@ class Gradient:
         m: int,
         valid: Optional[Array] = None,
         margin_axis_name: Optional[str] = None,
+        rows=None,
     ) -> Tuple[Array, Array, Array]:
         """Sums over the contiguous row window ``[start, start + m)`` — the
         ``sampling="sliced"`` mini-batch (SURVEY.md §7 hard parts: the HBM-
@@ -258,26 +302,38 @@ class Gradient:
         ``batch_sums`` of the sliced rows is NOT the way to one read: it
         would have the window copied out first (compiled for the described
         chip at 4,194,304 x 1000: an 841.5 MB temporary a step).
+        ``rows`` as in :meth:`batch_sums` (``row_operands(..., window=m)``).
         """
         if (0 < m <= jnp.shape(X)[0]
                 and one_read_sums(X, y, weights, valid, margin_axis_name)):
             return jax.lax.platform_dependent(
-                X, y, weights, start, valid,
-                tpu=functools.partial(self._fused_window_sums, m=m),
-                default=lambda X, y, weights, start, valid: _window_sums(
-                    self._two_read_sums, X, y, weights, start, m, valid,
-                    None))
+                X, y, weights, start, valid, rows,
+                tpu=lambda X, y, weights, start, valid, rows:
+                self._fused_window_sums(X, y, weights, start, valid, m, rows),
+                default=lambda X, y, weights, start, valid, rows:
+                _window_sums(self._two_read_sums, X, y, weights, start, m,
+                             valid, None))
         return _window_sums(self._two_read_sums, X, y, weights, start, m,
                             valid, margin_axis_name)
 
-    def _fused_window_sums(self, X, y, weights, start, valid, m):
+    def _fused_window_sums(self, X, y, weights, start, valid, m, rows=None):
         """One read of the window, X read in place
         (``ops/pallas_kernels.fused_window_sums``)."""
         from tpu_sgd.ops.pallas_kernels import fused_window_sums
 
+        y, valid = _kernel_rows(y, valid, rows)
         with jax.named_scope("sgd.fused_sums"):
             return fused_window_sums(self.pointwise, X, y, weights, start, m,
                                      valid)
+
+
+def _kernel_rows(y, mask, rows):
+    """The kernel's ``(labels, mask)``: the rows a fit laid out before its
+    loop (``Gradient.row_operands``) where it made them, else the ``(n,)``
+    operands, which the kernel's entry lays out itself."""
+    if rows is None:
+        return y, mask
+    return rows[0], mask if rows[1] is None else rows[1]
 
 
 def _window_sums(sums, X, y, weights, start, m, valid, margin_axis_name):
@@ -339,10 +395,17 @@ class ChunkedGradient(Gradient):
     def compute(self, data, label, weights):
         return self.base.compute(data, label, weights)
 
-    def batch_sums(self, X, y, weights, mask=None, margin_axis_name=None):
+    def batch_sums(self, X, y, weights, mask=None, margin_axis_name=None,
+                   rows=None):
         return self.base.batch_sums(
-            X, y, weights, mask, margin_axis_name=margin_axis_name
+            X, y, weights, mask, margin_axis_name=margin_axis_name, rows=rows
         )
+
+    def prepares_rows(self, X, y, weights, valid=None,
+                      margin_axis_name=None, window=None):
+        # the window schedule below slices its own blocks of the labels
+        return window is None and self.base.prepares_rows(
+            X, y, weights, valid, margin_axis_name)
 
     def loss_sweep(self, X, y, W, mask=None):
         return self.base.loss_sweep(X, y, W, mask)
@@ -495,6 +558,7 @@ class MultinomialLogisticGradient(Gradient):
         weights: Array,
         mask: Optional[Array] = None,
         margin_axis_name: Optional[str] = None,
+        rows=None,
     ) -> Tuple[Array, Array, Array]:
         """``Gradient.batch_sums`` for the flat ``(K-1) * D`` weights: the
         same selection from the operands and the lowering platform, no
@@ -506,14 +570,21 @@ class MultinomialLogisticGradient(Gradient):
             if one_read_sums(X, y, weights, mask, margin_axis_name,
                              classes=self.num_classes):
                 return jax.lax.platform_dependent(
-                    X, y, weights, mask,
-                    tpu=self._fused_sums, default=self._two_read_sums)
+                    X, y, weights, mask, rows,
+                    tpu=self._fused_sums, default=self._two_read_default)
             return self._two_read_sums(X, y, weights, mask, margin_axis_name)
 
-    def _fused_sums(self, X, y, weights, mask):
+    def prepares_rows(self, X, y, weights, valid=None,
+                      margin_axis_name=None, window=None):
+        # the class kernel has no window grid (window_sums below)
+        return window is None and one_read_sums(
+            X, y, weights, valid, margin_axis_name, classes=self.num_classes)
+
+    def _fused_sums(self, X, y, weights, mask, rows=None):
         """One read of X (``ops/pallas_kernels.fused_class_sums``)."""
         from tpu_sgd.ops.pallas_kernels import fused_class_sums
 
+        y, mask = _kernel_rows(y, mask, rows)
         W = weights.reshape(self.num_classes - 1, X.shape[-1])
         grad, loss_sum, count = fused_class_sums(self.class_rule, X, y, W,
                                                  mask)

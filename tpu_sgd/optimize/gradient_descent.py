@@ -192,6 +192,53 @@ def _make_mask(cfg: SGDConfig, key, i, n_local, valid, axis_name,
     return valid
 
 
+def _window_rows(cfg: SGDConfig, n_rows: int) -> int:
+    """Rows of a sliced or indexed mini-batch over ``n_rows`` rows."""
+    return max(1, round(cfg.mini_batch_fraction * n_rows))
+
+
+def _invariant_rows(cfg: SGDConfig, n_rows: int, valid):
+    """``(mask, window)``: what of a fit's row operands every step's sums
+    are handed as it stands besides the labels: ``valid``, unless the
+    Bernoulli draw folds it into a mask made anew each step, and, under
+    ``sampling="sliced"``, a window of ``window`` rows (else None).  None
+    where the step gathers its rows (``"indexed"``) and hands nothing on
+    as it stands."""
+    if cfg.mini_batch_fraction >= 1.0:
+        return valid, None
+    if cfg.sampling == "sliced":
+        return valid, _window_rows(cfg, n_rows)
+    if cfg.sampling == "indexed":
+        return None
+    return None, None
+
+
+def rows_prepared(gradient, cfg: SGDConfig, X, y, weights, valid=None,
+                  model_axis_name=None) -> bool:
+    """Whether ``make_run``'s fit over these operands (a shard's, under a
+    mesh) lays its labels out before its loop: from shapes and types alone,
+    so ``train.run``'s ``labels_prepared`` asks it on the host."""
+    plan = _invariant_rows(cfg, X.shape[0], valid)
+    return plan is not None and gradient.prepares_rows(
+        X, y, weights, plan[0], model_axis_name, plan[1])
+
+
+def prepare_rows(gradient, cfg: SGDConfig, X, y, weights, valid=None,
+                 model_axis_name=None):
+    """The step kernel's loop-invariant row operands, laid out once a fit
+    (``Gradient.row_operands``) under the scope ``sgd.prepare``; None where
+    the step takes ``y`` as it is.  For the caller of a loop over
+    ``make_step``'s step to call in FRONT of the loop and hand every step
+    as ``rows``: inside it the compiler may leave the relayout where the
+    source put it, every iteration (PERF.md, PR 33)."""
+    plan = _invariant_rows(cfg, X.shape[0], valid)
+    if plan is None:
+        return None
+    with jax.named_scope("sgd.prepare"):
+        return gradient.row_operands(X, y, weights, plan[0],
+                                     model_axis_name, plan[1])
+
+
 def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
                      shard_index=None):
     """THE per-iteration LOCAL ``(grad_sum, loss_sum, count)`` recipe —
@@ -205,9 +252,12 @@ def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
     indexed = cfg.sampling == "indexed" and cfg.mini_batch_fraction < 1.0
     sliced = cfg.sampling == "sliced" and cfg.mini_batch_fraction < 1.0
 
-    def local_sums(weights, X, y, i, valid):
+    def local_sums(weights, X, y, i, valid, rows=None):
+        # ``rows`` (prepare_rows) goes on only where a caller made it, so a
+        # gradient that prepares none is called as it always was
+        made = {} if rows is None else {"rows": rows}
         if sliced or indexed:
-            m = max(1, round(cfg.mini_batch_fraction * X.shape[0]))
+            m = _window_rows(cfg, X.shape[0])
         if sliced:
             # HBM-optimal path: a contiguous row window at a random offset,
             # read in place instead of a random gather: once, by the
@@ -221,7 +271,7 @@ def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
                     k, (), 0, max(1, X.shape[0] - m + 1))
             return gradient.window_sums(
                 X, y, weights, start, m, valid=valid,
-                margin_axis_name=model_axis_name,
+                margin_axis_name=model_axis_name, **made,
             )
         if indexed:
             # TPU fast path: gather a fixed-size batch (with replacement)
@@ -237,7 +287,7 @@ def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
             mask = _make_mask(cfg, key, i, X.shape[0], valid, axis_name,
                               shard_index)
         return gradient.batch_sums(
-            Xb, yb, weights, mask, margin_axis_name=model_axis_name
+            Xb, yb, weights, mask, margin_axis_name=model_axis_name, **made
         )
 
     return local_sums
@@ -256,6 +306,9 @@ def make_step(
     (new_weights, loss_i, new_reg_val, count)`` — the unit the streaming mode
     and the fused driver both build on.  ``loss_i`` already includes the
     previous iteration's ``reg_val`` per the reference's loss-history contract.
+    A seventh argument, ``rows``, is what :func:`prepare_rows` made of the
+    same ``X``, ``y`` and ``valid`` in front of the caller's loop (None: the
+    step lays its kernel's row operands out itself, every call).
 
     ``axis_name`` shards the example axis (data parallelism — the reference's
     only strategy); ``model_axis_name`` additionally shards the FEATURE axis
@@ -269,8 +322,8 @@ def make_step(
     local_sums = _make_local_sums(gradient, cfg, key, axis_name,
                                   model_axis_name)
 
-    def step(weights, X, y, i, reg_val, valid=None):
-        g, l, c = local_sums(weights, X, y, i, valid)
+    def step(weights, X, y, i, reg_val, valid=None, rows=None):
+        g, l, c = local_sums(weights, X, y, i, valid, rows)
         if axis_name is not None:
             with jax.named_scope("sgd.allreduce"):
                 g, l, c = jax.lax.psum((g, l, c), axis_name)
@@ -338,6 +391,11 @@ def make_run(
             # a warm-started 2-D run records a block-local iteration-1 loss
             reg_val0 = jax.lax.psum(reg_val0, model_axis_name)
         losses0 = jnp.full((cfg.num_iterations,), jnp.nan, jnp.float32)
+        # once a fit: what the step's kernel reads of the labels (and of a
+        # padded shard's ``valid``) in the layout it reads them in, so that
+        # the loop's body holds the kernel and no relayout of an operand
+        # that never changes (tests/test_chip_compile.py pins the body)
+        rows = prepare_rows(gradient, cfg, X, y, w0, valid, model_axis_name)
 
         def cond(carry):
             i, _, _, _, _, converged = carry
@@ -346,7 +404,8 @@ def make_run(
 
         def body(carry):
             i, w, reg_val, losses, n_rec, _ = carry
-            new_w, loss_i, new_reg, c = step(w, X, y, i, reg_val, valid)
+            new_w, loss_i, new_reg, c = step(w, X, y, i, reg_val, valid,
+                                             rows)
             has_batch = c > 0
             losses = jnp.where(
                 has_batch, losses.at[n_rec].set(loss_i.astype(jnp.float32)), losses
@@ -1613,6 +1672,7 @@ class GradientDescent(Optimizer):
         # below dispatches it.  A runner that had to be built (a new
         # _run_cache entry) traces, lowers and compiles inside that call.
         cached = len(self._run_cache)
+        prepared = 0  # only ``_runner``'s programs (make_run) prepare rows
         if sparse_X and self.mesh is not None:
             # Distributed sparse: equal-nse BCOO blocks per shard, same
             # make_run body, psum over ICI (the treeAggregate-over-sparse-
@@ -1668,14 +1728,17 @@ class GradientDescent(Optimizer):
             else:
                 fn = self._runner(with_valid=valid is not None)
                 args = (w0, Xd, yd) if valid is None else (w0, Xd, yd, valid)
+                prepared = self._labels_prepared(*args)
             path = "mesh"
         else:
             fn = self._runner(with_valid=False)
             path = "gram" if isinstance(X, GramData) else "fused"
             args = (w0, X, y)
+            prepared = self._labels_prepared(*args)
         run_span.set(
             path=path,
-            shards=1 if self.mesh is None else self.mesh.devices.size)
+            shards=1 if self.mesh is None else self.mesh.devices.size,
+            labels_prepared=prepared)
         with span("train.dispatch",
                   built=int(len(self._run_cache) > cached)):
             w, losses, n_rec = fn(*args)
@@ -1696,6 +1759,26 @@ class GradientDescent(Optimizer):
         if self.check_numerics:
             _raise_if_nonfinite(self._loss_history)
         return w, self._loss_history
+
+    def _labels_prepared(self, w0, X, y, valid=None) -> int:
+        """``train.run``'s ``labels_prepared`` for the fit ``_runner``'s
+        program is about to make of these arguments: 1 where it lays the
+        labels out once, before its loop, for the one-read kernel
+        (``rows_prepared`` of a shard's operands, on a TPU), 0 where the
+        step takes ``y`` as it is (two reads; statistics; a CPU, whose
+        program drops the row nothing reads)."""
+        if jax.default_backend() != "tpu":
+            return 0
+        if self.mesh is not None:
+            shards = self.mesh.devices.size
+
+            def shard(a):
+                return None if a is None else jax.ShapeDtypeStruct(
+                    (a.shape[0] // shards,) + tuple(a.shape[1:]), a.dtype)
+
+            X, y, valid = shard(X), shard(y), shard(valid)
+        return int(rows_prepared(self.gradient, self.config, X, y, w0,
+                                 valid))
 
     def _place(self, X, y):
         """``shard_dataset`` for this fit's mesh under the ``train.place``
