@@ -513,6 +513,7 @@ LAYOUTS = {
     "from_host": (BF16, 2_145_000, D), "square_tie": (BF16, 1000, 1000),
     "few_rows": (BF16, 1024, D), "d1001": (F32, N, 1001),
     "narrow": (F32, 300, 24), "mnist8m": (BF16, 8_100_000, 784),
+    "rcv1_dense": (BF16, 131_072, 47_236),
 }
 
 
@@ -805,6 +806,105 @@ def test_public_kernel_entry_points_refuse_before_compiling(S):
                             S((D,), F32), S((KERNEL_N,), jnp.bool_),
                             tile_m=16384)
 
+
+
+# -- the wide form: a vector of weights as rows, at RCV1's width ----------------
+
+#: the wide cell's rows and width (bench/configs/rcv1-dense-hinge-l1.json)
+RCV1_DENSE = (131_072, 47_236)
+
+
+def _lower_wide(S, n, dtype, masked, tile_m, limit=None):
+    from tpu_sgd.ops import pallas_kernels as PK
+
+    d = RCV1_DENSE[1]
+    limit = PK._FM_WIDE_VMEM_LIMIT if limit is None else limit
+    args = [S((n, d), dtype), S((n,), F32), S((d,), F32)]
+    if masked:
+        args.append(S((n,), jnp.bool_))
+    fblock = PK._fm_feature_block(d, tile_m, jnp.dtype(dtype).itemsize, limit)
+    return PK._fused_wide_sums.lower(
+        HingeGradient().pointwise, *args, tile_m=tile_m, fblock=fblock,
+        vmem_limit=limit)
+
+
+def test_wide_run_at_the_cells_shape_reads_x_once_in_place(S):
+    """``rcv1-dense-hinge-l1.resident-wide``: 131,072 x 47,236 bf16 rows
+    (12.38 GB of a chip's 15.75) under hinge and the L1 prox at fraction
+    1.0.  ONE Mosaic call a step under ``sgd.wide_sums`` in a jitted
+    function of its own name, ``X.T`` handed to it as a bitcast of the
+    parameter, nothing of X's size made, no ``(n,)`` margins between two
+    products, temporaries under 1% of X."""
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    n, d = RCV1_DENSE
+    cfg = _cfg(step_size=100.0, num_iterations=100, mini_batch_fraction=1.0,
+               reg_param=1e-5, convergence_tol=0.0)
+    compiled = jax.jit(make_run(HingeGradient(), L1Updater(), cfg)).lower(
+        S((d,), F32), S((n, d), BF16), S((n,), F32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "sgd.wide_sums" in call and "_fused_wide_sums" in call
+    assert "sgd.fused_sums" not in text
+    assert "bf16[%d,%d]{1,0:T(8,128)(2,1)} bitcast(" % (d, n) in text
+    assert _moves_of(text, n, d) == []
+    # the weights go in as 16 rows in X's type and the gradient comes out
+    # as 16 rows in f32: no (d, 128) lane-broadcast operand
+    assert "bf16[16,%d]" % d in call and "f32[16,%d]" % d in call
+    assert "f32[%d,128]" % d not in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < n * d * 2 // 100
+    assert memory.argument_size_in_bytes < n * d * 2 * 1.01
+
+
+#: (type, masked): the cell's bf16 rows; f32 rows under a mask
+WIDE_CASES = {"rcv1": (BF16, False), "f32": (F32, True)}
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_CASES))
+def test_the_wide_kernels_vmem_count_admits_what_the_compiler_admits(S, case):
+    """``_fm_vmem_bytes`` for the wide form stays above the compiler's own
+    count: the kernel's own tile at 47,236 features compiles when the
+    compiler is asked for exactly what the tile was counted at (so under
+    the limit the kernel asks for too); twice that tile is refused by the
+    count, and by the compiler under those same bytes; the largest tile
+    the count's hint names compiles."""
+    import re
+
+    from tpu_sgd.ops import pallas_kernels as PK
+
+    dtype, masked = WIDE_CASES[case]
+    n, d = 6144, RCV1_DENSE[1]  # whole tiles of 128, 256 and 384 rows
+    itemsize = jnp.dtype(dtype).itemsize
+    limit = PK._FM_WIDE_VMEM_LIMIT
+    own, fblock = PK.fm_wide(n, d, itemsize, masked)
+    assert (own, fblock) == PK._fm_wide_plan(n, d, itemsize, masked, limit)
+    _, rows = PK.wide_rows_of(dtype)
+    X = S((n, d), dtype)
+    with pytest.raises(ValueError, match=r"tile_m <= \d+") as refused:
+        PK._check_fm_vmem(2 * own, X, masked, rows, fblock, limit)
+    counted = PK._fm_vmem_bytes(own, d, itemsize, masked, rows, fblock)
+    assert counted <= limit
+    assert "tpu_custom_call" in _lower_wide(
+        S, n, dtype, masked, own, counted).compile().as_text()
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _lower_wide(S, n, dtype, masked, 2 * own, counted).compile()
+    tile = int(re.search(r"tile_m <= (\d+)", str(refused.value)).group(1))
+    assert own <= tile < 2 * own
+    PK._check_fm_vmem(tile, X, masked, rows, fblock, limit)
+    assert "tpu_custom_call" in _lower_wide(
+        S, n, dtype, masked, tile).compile().as_text()
+
+
+def test_the_wide_entry_refuses_a_width_no_tile_fits_before_compiling(S):
+    from tpu_sgd.ops.pallas_kernels import fused_wide_sums
+
+    n, d = 8192, 400_004
+    with pytest.raises(ValueError, match="too wide for this kernel"):
+        fused_wide_sums(HingeGradient().pointwise, S((n, d), BF16),
+                        S((n,), F32), S((d,), F32))
 
 
 # -- the class kernel: a (K-1, d) matrix of weights --------------------------

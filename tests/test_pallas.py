@@ -12,7 +12,7 @@ from tpu_sgd.ops.gradients import (
     LogisticGradient,
 )
 from tpu_sgd.ops.pallas_kernels import (fused_gradient_sums,
-                                        fused_window_sums)
+                                        fused_wide_sums, fused_window_sums)
 
 
 GRADS = [LeastSquaresGradient(), LogisticGradient(), HingeGradient()]
@@ -116,15 +116,25 @@ def test_interpreter_poisons_what_lies_past_the_end():
 
 def test_feature_major_tile_choice():
     """``fm_tile`` halves until the block fits the kernel's VMEM, never
-    passes the rows there are, and gives up on a width no lane group
-    fits; an explicit tile is floored to whole lane groups."""
+    passes the rows there are, takes the wide form's tile where the
+    ``(d, 128)`` operands do not fit (RCV1's width) and gives up on a
+    width no lane group fits in either form; an explicit tile is floored
+    to whole lane groups."""
     from tpu_sgd.ops import pallas_kernels as PK
 
     assert PK.fm_tile(4_194_304, 1000, 2) == PK.FM_TILE
     assert PK.fm_tile(2_145_000, 1000, 4) == PK.FM_TILE  # f32: 17.4 MB
     assert PK.fm_tile(1 << 20, 4000, 4) == PK.FM_TILE // 4
     assert PK.fm_tile(300, 24, 4) == 384  # one block over all 300 rows
-    assert PK.fm_tile(1 << 16, 47_236, 2) is None  # 128 lanes: 24 MB x 2
+    # RCV1's width: 96.7 MB of (d, 128) f32 operands, so the wide form:
+    # 256 rows a grid step (two blocks of 24.2 MB), the width in 8 blocks
+    assert PK.fm_tile(1 << 16, 47_236, 2) == 256
+    assert PK.fm_blocks(131_072, 47_236, 2, False) == (256, 8)
+    assert PK.fm_wide(131_072, 47_236, 2, False) == (256, 6400)
+    assert PK.fm_wide(4_194_304, 1000, 2) is None  # _fm_kernel takes it
+    assert PK.fm_blocks(4_194_304, 1000, 2) == (PK.FM_TILE, 1)
+    assert PK.fm_tile(1 << 16, 400_004, 2) is None  # 128 lanes: 102 MB x 2
+    assert PK.fm_tile(1 << 16, 47_236, 2, class_rows=16) is None
     assert PK._fm_round(200, 10_000) == 128
     assert PK._fm_round(64, 10_000) == 128
     assert [PK._fm_lane_chunk(t) for t in (128, 384, 1920, 2048, 4096)] \
@@ -132,6 +142,145 @@ def test_feature_major_tile_choice():
     X = np.zeros((1 << 16, 47_236), np.float32)
     with pytest.raises(ValueError, match="too wide for this kernel"):
         fused_gradient_sums(GRADS[0].pointwise, X, X[:, 0], X[0])
+    import jax
+
+    X = jax.ShapeDtypeStruct((1 << 10, 400_004), np.float32)
+    with pytest.raises(ValueError, match="too wide for this kernel"):
+        fused_wide_sums(GRADS[0].pointwise, X, X, X)
+
+
+# -- the wide form: a vector of weights as rows, the width in feature blocks ---
+
+#: scoped VMEM that puts a width of 600 into several feature blocks (the
+#: cut is the limit's: a thirty-second of it is one block of a lane chunk)
+WIDE_LIMIT = 5 << 19
+WIDE_N, WIDE_D = 700, 600
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("g", GRADS, ids=lambda g: type(g).__name__)
+def test_wide_kernel_in_feature_blocks_matches_two_matvecs_and_the_rules(
+        g, dtype, masked):
+    """The wide form in interpret mode at a small width that the limit
+    cuts into three feature blocks or more, rows no multiple of the row
+    tile (the last block is cut, and the interpreter fills what lies past
+    row n with NaN), against ``margins_of`` / ``grad_sum_of`` and against
+    the benchmark's own rules in float64.  The weights ride as parts in
+    X's type that add up to the f32 vector, so bf16 rows lose nothing
+    against f32 operands."""
+    import jax.numpy as jnp
+
+    from bench.reference import rules
+    from tpu_sgd.ops import pallas_kernels as PK
+
+    n, d = WIDE_N, WIDE_D
+    itemsize = jnp.dtype(dtype).itemsize
+    tile, fblock = PK._fm_wide_plan(n, d, itemsize, masked, WIDE_LIMIT)
+    assert n % tile and n > tile and -(-d // fblock) >= 3 and d % fblock
+    X, y, w = _data(n=n, d=d, seed=7 + masked,
+                    classify=not isinstance(g, LeastSquaresGradient))
+    X = np.asarray(jnp.asarray(0.1 * X, dtype).astype(jnp.float32))
+    mask = (np.random.default_rng(5).uniform(size=n) < 0.4) if masked \
+        else None
+    gs, ls, c = fused_wide_sums(g.pointwise, jnp.asarray(X, dtype), y, w,
+                                mask, vmem_limit=WIDE_LIMIT, interpret=True)
+    gs_ref, ls_ref, c_ref = g._two_read_sums(X, y, w, mask)
+    np.testing.assert_allclose(np.asarray(gs), np.asarray(gs_ref), rtol=2e-5,
+                               atol=2e-4)
+    np.testing.assert_allclose(float(ls), float(ls_ref), rtol=2e-5)
+    assert float(c) == float(c_ref) == (n if mask is None else mask.sum())
+    X64, keep = X.astype(np.float64), (1.0 if mask is None else mask)
+    coeff, loss = rules.pointwise(np, type(g).__name__, X64 @ w, y)
+    np.testing.assert_allclose(np.asarray(gs), (coeff * keep) @ X64,
+                               rtol=2e-5, atol=2e-4)
+    np.testing.assert_allclose(float(ls), np.sum(loss * keep), rtol=2e-5)
+
+
+def test_wide_kernel_takes_a_tile_of_the_callers_and_one_block():
+    """``tile_m`` is the caller's, as in ``fused_gradient_sums``; under the
+    kernel's own limit a width of 600 is ONE feature block, and the sums
+    are the same."""
+    from tpu_sgd.ops import pallas_kernels as PK
+
+    g = HingeGradient()
+    X, y, w = _data(n=WIDE_N, d=WIDE_D, seed=9, classify=True)
+    assert PK._fm_feature_block(WIDE_D, 128, 4, PK._FM_WIDE_VMEM_LIMIT) \
+        == WIDE_D
+    one = fused_wide_sums(g.pointwise, X, y, w, tile_m=128, interpret=True)
+    cut = fused_wide_sums(g.pointwise, X, y, w, vmem_limit=WIDE_LIMIT,
+                          interpret=True)
+    ref = g._two_read_sums(X, y, w, None)
+    for a, b, r in zip(one, cut, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=2e-5,
+                                   atol=2e-4)
+        np.testing.assert_allclose(np.asarray(b), np.asarray(r), rtol=2e-5,
+                                   atol=2e-4)
+
+
+def test_wide_parts_add_up_to_the_f32_vector():
+    """Three bf16 parts carry an f32's 24 bits; an f32 row is one part."""
+    import jax.numpy as jnp
+
+    from tpu_sgd.ops import pallas_kernels as PK
+
+    assert PK.wide_rows_of(jnp.bfloat16) == (3, 16)
+    assert PK.wide_rows_of(jnp.float32) == (1, 8)
+    w = np.random.default_rng(3).normal(size=4096).astype(np.float32) * 37.0
+    for in_kernel in (False, True):  # reduce_precision; a pair of casts
+        parts = PK._parts_of(jnp.asarray(w), jnp.bfloat16, 3, in_kernel)
+        assert all(p.dtype == jnp.bfloat16 for p in parts)
+        total = sum(np.asarray(p.astype(jnp.float32), np.float64)
+                    for p in parts)
+        np.testing.assert_array_equal(total.astype(np.float32), w)
+        assert float(jnp.max(jnp.abs(parts[1]))) > 0  # not all in the first
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("g", GRADS, ids=lambda g: type(g).__name__)
+def test_batch_sums_lowers_the_wide_kernel_for_a_tpu_at_rcv1s_width(
+        g, masked):
+    """At 47,236 features ``batch_sums`` lowered for a TPU is ONE Mosaic
+    call under ``sgd.wide_sums`` in a jitted function of its own name and
+    no matvec; lowered for the CPU the two ``dot_general`` it always
+    was."""
+    X, y, w, mask, _ = _selection_case("wide")
+    mask = mask if masked else None
+    tpu = _lowered_for("tpu", g.batch_sums, X, y, w, mask)
+    assert "tpu_custom_call" in tpu and "stablehlo.dot_general" not in tpu
+    assert "sgd.wide_sums/jit(_fused_wide_sums)" in tpu
+    assert "sgd.fused_sums" not in tpu
+    cpu = _lowered_for("cpu", g.batch_sums, X, y, w, mask)
+    assert cpu.count("stablehlo.dot_general") == 2
+    assert "tpu_custom_call" not in cpu and "sgd.wide_sums" not in cpu
+
+
+@pytest.mark.parametrize("case", ["full_batch", "bernoulli", "sliced",
+                                  "indexed", "narrow"])
+def test_step_blocks_names_the_kernels_row_tile_and_feature_blocks(case):
+    """``train.run``'s ``row_tile`` and ``feature_blocks``, from shapes
+    alone: RCV1's width under a full batch or a drawn mask is the wide
+    form (256 rows a grid step, the width in 8 blocks); the window's
+    kernel has no wide form and a gathered batch is no kernel (0 rows, one
+    block); the north star's width is one block of 2048 rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_sgd.config import SGDConfig
+    from tpu_sgd.optimize import gradient_descent as gd
+
+    n, d = (131_072, 47_236) if case != "narrow" else (4_194_304, 1000)
+    X = jax.ShapeDtypeStruct((n, d), jnp.bfloat16)
+    y = jax.ShapeDtypeStruct((n,), jnp.float32)
+    w = jax.ShapeDtypeStruct((d,), jnp.float32)
+    cfg = SGDConfig(
+        mini_batch_fraction=1.0 if case == "full_batch" else 0.1,
+        sampling=case if case in ("sliced", "indexed") else "bernoulli")
+    assert gd.step_blocks(HingeGradient(), cfg, X, y, w) == {
+        "full_batch": (256, 8), "bernoulli": (256, 8), "sliced": (0, 1),
+        "indexed": (0, 1), "narrow": (2048, 1)}[case]
+    assert gd.rows_prepared(HingeGradient(), cfg, X, y, w) == (
+        case in ("full_batch", "bernoulli", "narrow"))
 
 
 # -- the kernel over a window of rows -----------------------------------------
@@ -225,6 +374,7 @@ def test_lane_window_kernel_refuses_a_window_larger_than_x():
 def _selection_case(name):
     """``(X, y, w, mask, margin_axis_name)`` of (1024, 1000) bf16 rows —
     a shape the chip stores feature-major — with one observable changed."""
+    import jax
     import jax.numpy as jnp
 
     n, d = 1024, 1000
@@ -244,9 +394,11 @@ def _selection_case(name):
     elif name == "row_major_width":
         X = jnp.zeros((n, 1024), jnp.bfloat16)  # pads nothing by rows
         w = jnp.zeros((1024,))
-    elif name == "too_wide":
-        X = jnp.zeros((256, 47_236), jnp.bfloat16)
-        w = jnp.zeros((47_236,))
+    elif name in ("too_wide", "wide"):
+        # RCV1's width: the wide form; ten times it: no form's
+        d = {"wide": 47_236, "too_wide": 472_364}[name]
+        X = jax.ShapeDtypeStruct((256, d), jnp.bfloat16)
+        w = jax.ShapeDtypeStruct((d,), jnp.float32)
         y, mask = y[:256], mask[:256]
     elif name == "labels_per_class":
         y = jnp.zeros((n, 2))
@@ -259,13 +411,19 @@ OFF = ["bcoo", "matrix_weights", "margin_axis_name", "integer_rows",
        "row_major_width", "too_wide", "labels_per_class"]
 
 
-@pytest.mark.parametrize("case", ["feature_major"] + OFF)
+@pytest.mark.parametrize("case", ["feature_major", "wide"] + OFF)
 def test_one_read_sums_follows_what_the_operands_look_like(case):
-    from tpu_sgd.ops.gradients import one_read_sums
+    from tpu_sgd.ops.gradients import one_read_blocks, one_read_sums
 
     X, y, w, mask, axis = _selection_case(case)
-    assert one_read_sums(X, y, w, mask, axis) == (case == "feature_major")
-    assert one_read_sums(X, y, w, None, axis) == (case == "feature_major")
+    on = case in ("feature_major", "wide")
+    assert one_read_sums(X, y, w, mask, axis) == on
+    assert one_read_sums(X, y, w, None, axis) == on
+    # the window's kernel has no wide form
+    assert one_read_sums(X, y, w, None, axis, window=True) == (
+        case == "feature_major")
+    assert one_read_blocks(X, y, w, mask, axis) == {
+        "feature_major": (1024, 1), "wide": (256, 8)}.get(case)
 
 
 def _lowered_for(platform, fn, *args):
@@ -345,7 +503,7 @@ def test_window_sums_lowers_the_kernel_for_a_tpu_and_two_matvecs_here(
 
 
 @pytest.mark.parametrize("case", ["row_major_width", "integer_rows",
-                                  "too_wide", "margin_axis_name"])
+                                  "too_wide", "margin_axis_name", "wide"])
 def test_window_sums_keeps_two_matvecs_on_a_tpu_where_the_kernel_is_off(case):
     import jax
     import jax.numpy as jnp
@@ -700,3 +858,8 @@ def test_train_run_says_whether_the_labels_were_prepared(backend,
                                          "fused"]
     assert [r["labels_prepared"] for r in runs] == (
         [1, 1, 1, 0, 0] if backend == "tpu" else [0] * 5)
+    # the rows a grid step of the step's kernel takes (a shard's 128 under
+    # the mesh), 0 where the step is no kernel; the width is never cut
+    assert [r["row_tile"] for r in runs] == (
+        [512, 512, 128, 0, 0] if backend == "tpu" else [0] * 5)
+    assert [r["feature_blocks"] for r in runs] == [1] * 5

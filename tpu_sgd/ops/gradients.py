@@ -94,38 +94,54 @@ def grad_sum_of(coeff, X):
         )
 
 
-def one_read_sums(X, y, weights, mask=None, margin_axis_name=None,
-                  classes: Optional[int] = None) -> bool:
-    """Whether ``batch_sums`` of these operands may take the fused one-read
-    kernel where the program is lowered for a TPU — decided from what the
-    operands look like, nothing else: dense 2-D bf16 or f32 rows that the
-    chip stores feature-major (so ``X.T`` is a bitcast and no copy of X
-    stands in front of the kernel), a flat weight vector (``classes``
-    None: one entry a feature; a class count: the row-major flattening of
-    a ``(classes - 1, d)`` matrix whose rows, padded to whole packed
-    registers, are no more than one pass of the matrix unit takes,
-    ``pallas_kernels.FM_CLASS_ROWS``), one label (and one mask entry) a
-    row, whole margins on every core (a feature-sharded run needs the
-    ``psum`` between the two halves), and a block of ``X.T`` that fits
-    the kernel's VMEM beside the class rows."""
+def one_read_blocks(X, y, weights, mask=None, margin_axis_name=None,
+                    classes: Optional[int] = None, window: bool = False):
+    """``(row tile, feature blocks)`` of the fused one-read kernel that
+    ``batch_sums`` of these operands takes where the program is lowered
+    for a TPU (``window``: ``window_sums``), None where it takes two reads
+    — decided from what the operands look like, nothing else: dense 2-D
+    bf16 or f32 rows that the chip stores feature-major (so ``X.T`` is a
+    bitcast and no copy of X stands in front of the kernel), a flat
+    weight vector (``classes`` None: one entry a feature; a class count:
+    the row-major flattening of a ``(classes - 1, d)`` matrix whose rows,
+    padded to whole packed registers, are no more than one pass of the
+    matrix unit takes, ``pallas_kernels.FM_CLASS_ROWS``), one label (and
+    one mask entry) a row, whole margins on every core (a feature-sharded
+    run needs the ``psum`` between the two halves), and a block of
+    ``X.T`` that fits the kernel's VMEM beside the weights: all d along
+    128 lanes in f32 or beside the class rows (one feature block), or,
+    for a vector of weights too wide for that, in the wide form
+    (``pallas_kernels.fm_wide``: weights as rows, the width in feature
+    blocks), which the window's kernel does not have."""
     if (margin_axis_name is not None or _is_sparse(X)
             or getattr(X, "ndim", 0) != 2 or jnp.ndim(weights) != 1
             or X.dtype not in (jnp.bfloat16, jnp.float32)):
-        return False
+        return None
     n, d = X.shape
     if jnp.shape(y) != (n,) or (mask is not None
                                 and jnp.shape(mask) != (n,)):
-        return False
+        return None
     from tpu_sgd.ops.pallas_kernels import (FM_CLASS_ROWS, class_rows_of,
-                                            feature_major, fm_tile)
+                                            feature_major, fm_blocks)
 
     rows = 0
     if classes is not None:
         rows = class_rows_of(classes - 1, X.dtype)
         if rows > FM_CLASS_ROWS or jnp.shape(weights) != ((classes - 1) * d,):
-            return False
-    return feature_major(n, d) and fm_tile(
-        n, d, X.dtype.itemsize, mask is not None, rows) is not None
+            return None
+    if not feature_major(n, d):
+        return None
+    blocks = fm_blocks(n, d, X.dtype.itemsize, mask is not None, rows)
+    return None if blocks is None or (window and blocks[1] > 1) else blocks
+
+
+def one_read_sums(X, y, weights, mask=None, margin_axis_name=None,
+                  classes: Optional[int] = None, window: bool = False
+                  ) -> bool:
+    """Whether the sums of these operands may take a fused one-read
+    kernel (:func:`one_read_blocks`)."""
+    return one_read_blocks(X, y, weights, mask, margin_axis_name, classes,
+                           window) is not None
 
 
 class Gradient:
@@ -192,16 +208,28 @@ class Gradient:
         """``platform_dependent``'s other branch: the ``(n,)`` operands."""
         return self._two_read_sums(X, y, weights, mask)
 
+    def kernel_blocks(self, X, y, weights, mask=None, margin_axis_name=None,
+                      window: Optional[int] = None) -> Tuple[int, int]:
+        """``(row tile, feature blocks)`` of the one-read kernel that the
+        sums of every step take where the program is lowered for a TPU
+        (``batch_sums``; ``window_sums`` over a window of ``window``
+        rows): the rows a grid step reads and the blocks its body cuts
+        the width into (:func:`one_read_blocks`); ``(0, 1)`` where the
+        step takes two reads.  Decided from shapes and types alone, so
+        the host can ask it of a fit it is about to dispatch."""
+        if window is not None and not 0 < window <= jnp.shape(X)[0]:
+            return 0, 1
+        return one_read_blocks(X, y, weights, mask, margin_axis_name,
+                               window=window is not None) or (0, 1)
+
     def prepares_rows(self, X, y, weights, valid=None,
                       margin_axis_name=None, window: Optional[int] = None
                       ) -> bool:
         """Whether :meth:`row_operands` lays these operands out: where the
-        sums of every step (``batch_sums``; ``window_sums`` over a window
-        of ``window`` rows) will be the one-read kernel if the program is
-        lowered for a TPU.  Decided from shapes and types alone, so the
-        host can ask it of a fit it is about to dispatch."""
-        return ((window is None or 0 < window <= jnp.shape(X)[0])
-                and one_read_sums(X, y, weights, valid, margin_axis_name))
+        sums of every step will be the one-read kernel if the program is
+        lowered for a TPU (:meth:`kernel_blocks`)."""
+        return self.kernel_blocks(X, y, weights, valid, margin_axis_name,
+                                  window)[0] > 0
 
     def row_operands(self, X, y, weights, valid=None,
                      margin_axis_name=None, window: Optional[int] = None):
@@ -227,10 +255,17 @@ class Gradient:
 
     def _fused_sums(self, X, y, weights, mask, rows=None):
         """One read of X: the Pallas kernel over the feature-major blocks
-        the chip already stores (``ops/pallas_kernels.py``)."""
-        from tpu_sgd.ops.pallas_kernels import fused_gradient_sums
+        the chip already stores (``ops/pallas_kernels.py``), in the wide
+        form where the width asks for it (``fm_wide``), under a scope of
+        its own."""
+        from tpu_sgd.ops.pallas_kernels import (fm_wide, fused_gradient_sums,
+                                                fused_wide_sums)
 
+        wide = fm_wide(*X.shape, X.dtype.itemsize, mask is not None)
         y, mask = _kernel_rows(y, mask, rows)
+        if wide is not None:
+            with jax.named_scope("sgd.wide_sums"):
+                return fused_wide_sums(self.pointwise, X, y, weights, mask)
         with jax.named_scope("sgd.fused_sums"):
             return fused_gradient_sums(self.pointwise, X, y, weights, mask)
 
@@ -305,7 +340,8 @@ class Gradient:
         ``rows`` as in :meth:`batch_sums` (``row_operands(..., window=m)``).
         """
         if (0 < m <= jnp.shape(X)[0]
-                and one_read_sums(X, y, weights, valid, margin_axis_name)):
+                and one_read_sums(X, y, weights, valid, margin_axis_name,
+                                  window=True)):
             return jax.lax.platform_dependent(
                 X, y, weights, start, valid, rows,
                 tpu=lambda X, y, weights, start, valid, rows:
@@ -401,11 +437,12 @@ class ChunkedGradient(Gradient):
             X, y, weights, mask, margin_axis_name=margin_axis_name, rows=rows
         )
 
-    def prepares_rows(self, X, y, weights, valid=None,
-                      margin_axis_name=None, window=None):
+    def kernel_blocks(self, X, y, weights, mask=None, margin_axis_name=None,
+                      window=None):
         # the window schedule below slices its own blocks of the labels
-        return window is None and self.base.prepares_rows(
-            X, y, weights, valid, margin_axis_name)
+        if window is not None:
+            return 0, 1
+        return self.base.kernel_blocks(X, y, weights, mask, margin_axis_name)
 
     def loss_sweep(self, X, y, W, mask=None):
         return self.base.loss_sweep(X, y, W, mask)
@@ -574,11 +611,13 @@ class MultinomialLogisticGradient(Gradient):
                     tpu=self._fused_sums, default=self._two_read_default)
             return self._two_read_sums(X, y, weights, mask, margin_axis_name)
 
-    def prepares_rows(self, X, y, weights, valid=None,
-                      margin_axis_name=None, window=None):
+    def kernel_blocks(self, X, y, weights, mask=None, margin_axis_name=None,
+                      window=None):
         # the class kernel has no window grid (window_sums below)
-        return window is None and one_read_sums(
-            X, y, weights, valid, margin_axis_name, classes=self.num_classes)
+        if window is not None:
+            return 0, 1
+        return one_read_blocks(X, y, weights, mask, margin_axis_name,
+                               classes=self.num_classes) or (0, 1)
 
     def _fused_sums(self, X, y, weights, mask, rows=None):
         """One read of X (``ops/pallas_kernels.fused_class_sums``)."""

@@ -18,7 +18,8 @@ in front of the kernel's own read.
 
 One family over the ``(d, tile)`` blocks of ``X.T``: a body for a vector
 of weights (``_fm_kernel``) under two grids, and a body for a matrix of
-them (``_fm_class_kernel``) under the first.
+them (``_fm_class_kernel``) under the first, which a vector too wide for
+the first body rides as rows.
 :func:`fused_gradient_sums`: the full scan with an optional
 sampling mask (reference parity with ``RDD.sample``), over ``(d, tile)``
 blocks of ``X.T``: features on sublanes, rows on lanes.  Where X is stored
@@ -48,6 +49,27 @@ and nine class rows a call timed alone reads 18.29 ms with both
 products and 18.29 with neither (the two matmuls with their ``(n, C)``
 arrays in HBM: 38.9), and in a fit the kernel runs 16.78 ms a step, 759
 GB/s, the vector-weight kernel's pace (PERF.md, PR 32).
+
+:func:`fused_wide_sums` (PR 34): the full scan for a VECTOR of weights at
+a width ``_fm_kernel`` cannot hold.  Its weights and gradient partials lie
+along 128 lanes in f32, ``4 x d x 128 x 4`` bytes: 96.7 MB at RCV1's
+47,236 features, beside 24.2 MB for the two buffers of ONE lane group of
+``X.T``.  The class kernel's form needs neither: the vector rides as rows
+of a ``(16, d)`` matrix in X's type (an f32 in three bf16 parts, so the
+products lose nothing against f32 operands: :func:`wide_rows_of`), both
+products run on the matrix unit, the elementwise rule between them on
+``(1, lanes)`` margins, and the gradient comes back as rows: 9.1 MB of
+weights and gradient, so two blocks of 256 lanes (48.4 MB) fit the
+100 MB asked for (a v5e core has 128 MiB; the compiler admitted 127).
+The body takes the width in FEATURE BLOCKS (``_fm_feature_block``), the
+margins summed over them before the rule, so no operand of a product is
+a 24 MB row tile.  On the chip the copy sets the pace whatever the tile:
+131,072 x 47,236 bf16 rows read 16.48 to 16.51 ms a call at 128, 256,
+384 and 512 lanes (4 to 16 KB contiguous pieces), with both products,
+with one and with neither, and feature blocks of 1024, 4096 or all of d;
+in a fit 16.37 ms a step, 756 GB/s, against the two matvecs' 32.86
+(PERF.md, PR 34).  ``Gradient.batch_sums`` selects it where
+:func:`fm_wide` says so, from the operands, under ``sgd.wide_sums``.
 
 **What reaches the kernels as a bitcast.**  ``X.T`` where the chip stores X
 feature-major, and every ROW operand (one entry a row of X: the labels, a
@@ -111,6 +133,14 @@ _FM_LANE_CHUNK = 1024
 _FM_UNROLL = 4
 #: the scoped VMEM the kernel asks the compiler for (v5e's default is 16 MB)
 _FM_VMEM_LIMIT = 32 * 1024 * 1024
+#: what the WIDE form asks for (a v5e core has 128 MiB of VMEM and its
+#: compiler admits a kernel nearly all of it): room for two ``(d, tile)``
+#: blocks of ``X.T`` at a width whose one lane group is 12 MB
+_FM_WIDE_VMEM_LIMIT = 100 * 1024 * 1024
+#: the share of its limit the wide form's body gets for one feature block
+#: of the row tile in X's type (the operand of one product, and the cut
+#: block's copy with the lanes outside replaced)
+_FM_FEATURE_BLOCK_SHARE = 32
 
 
 def feature_major(n: int, d: int) -> bool:
@@ -129,7 +159,7 @@ def feature_major(n: int, d: int) -> bool:
 
 
 def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool,
-                   class_rows: int = 0) -> int:
+                   class_rows: int = 0, fblock: Optional[int] = None) -> int:
     """Scoped VMEM one grid step of the feature-major kernel needs: per
     lane the ``(d, tile)`` block of ``X.T`` double-buffered (d pads to a
     packed vreg's rows) and the ``(1, tile)`` f32 rows of y (and the mask)
@@ -140,15 +170,18 @@ def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool,
     in f32 (d pads to whole lane groups), two buffers each, and beside
     the 1 MB one lane chunk of the block in X's type (the cut block's
     copy with the lanes outside replaced) and six ``(class_rows, chunk)``
-    f32 arrays of the rule between the products.  Above the compiler's
+    f32 arrays of the rule between the products; where the body cuts the
+    width into blocks of ``fblock`` features (the wide form), one such
+    block of the lane chunk in place of all d.  Above the compiler's
     own count at every shape tried (tests/test_chip_compile.py), so a
     tile it admits compiles."""
     per_lane = (2 * _round_up(d, 32 // itemsize) * itemsize
                 + 2 * (2 if masked else 1) * SUBLANES * 4)
     if class_rows:
         fixed = (2 * class_rows * _round_up(d, LANES) * (itemsize + 4)
-                 + _FM_LANE_CHUNK * (_round_up(d, 32 // itemsize) * itemsize
-                                     + 6 * class_rows * 4))
+                 + _FM_LANE_CHUNK * (
+                     _round_up(min(fblock or d, d), 32 // itemsize) * itemsize
+                     + 6 * class_rows * 4))
     else:
         fixed = 4 * _round_up(d, SUBLANES) * LANES * 4
     return per_lane * tile + fixed + (1 << 20)
@@ -160,42 +193,125 @@ def _fm_round(tile_m: int, n: int) -> int:
     return min(max(LANES, tile_m // LANES * LANES), _round_up(n, LANES))
 
 
-def fm_tile(n: int, d: int, itemsize: int, masked: bool = True,
-            class_rows: int = 0) -> Optional[int]:
-    """The feature-major kernel's own choice of row tile for an ``(n, d)``
-    X: ``FM_TILE`` halved until its VMEM fits — None where not even one
-    lane group does (a very wide d: the two-read path's case)."""
+def _fm_halved(n: int, need, limit: int) -> Optional[int]:
+    """``FM_TILE`` halved until ``need(tile)`` bytes fit ``limit``, rounded
+    to the rows there are; None where not even one lane group fits."""
     tile = FM_TILE
-    while _fm_vmem_bytes(tile, d, itemsize, masked,
-                         class_rows) > _FM_VMEM_LIMIT:
+    while need(tile) > limit:
         if tile == LANES:
             return None
         tile //= 2
     return _fm_round(tile, n)
 
 
-def _check_fm_vmem(tile: int, X, masked: bool, class_rows: int = 0) -> None:
+def _fm_narrow_tile(n: int, d: int, itemsize: int, masked: bool,
+                    class_rows: int = 0) -> Optional[int]:
+    """The row tile of the forms that hold all d at once under
+    ``_FM_VMEM_LIMIT``: ``_fm_kernel``'s, or the class kernel's."""
+    return _fm_halved(
+        n, lambda t: _fm_vmem_bytes(t, d, itemsize, masked, class_rows),
+        _FM_VMEM_LIMIT)
+
+
+def wide_rows_of(dtype) -> Tuple[int, int]:
+    """``(parts, rows)``: how the wide form holds a VECTOR of f32 weights
+    (and the rows' coefficients) as rows of a matrix in X's type: as many
+    parts as carry an f32's 24 bits (three bf16 values: high, middle, low;
+    one f32), padded to whole packed registers as class rows are.  The
+    matrix unit takes 16 rows at the price of one, so the split is free
+    and the products lose nothing against f32 operands."""
+    parts = -(-24 // (jnp.finfo(dtype).nmant + 1))
+    return parts, class_rows_of(parts, dtype)
+
+
+def _fm_feature_block(d: int, tile: int, itemsize: int, limit: int) -> int:
+    """Features one product of the wide form's body takes: whole lane
+    groups of them (the weights' rows hold d along the lanes), as many as
+    keep one lane chunk of such a block within its share of ``limit``;
+    all d where that is all of them."""
+    lw = _fm_lane_chunk(tile)
+    fblock = limit // _FM_FEATURE_BLOCK_SHARE // (lw * itemsize)
+    fblock = max(LANES, fblock // LANES * LANES)
+    return d if fblock >= d else fblock
+
+
+def _fm_wide_plan(n: int, d: int, itemsize: int, masked: bool, limit: int
+                  ) -> Optional[Tuple[int, int]]:
+    """``(row tile, feature block)`` of the wide form under ``limit`` bytes
+    of scoped VMEM, None where not even one lane group of rows fits."""
+    rows = 32 // itemsize  # one packed register of weight rows
+
+    def need(tile):
+        return _fm_vmem_bytes(tile, d, itemsize, masked, rows,
+                              _fm_feature_block(d, tile, itemsize, limit))
+
+    tile = _fm_halved(n, need, limit)
+    return tile and (tile, _fm_feature_block(d, tile, itemsize, limit))
+
+
+def fm_wide(n: int, d: int, itemsize: int, masked: bool = True
+            ) -> Optional[Tuple[int, int]]:
+    """``(row tile, feature block)`` where the full scan over an ``(n, d)``
+    X under a VECTOR of weights takes the WIDE form
+    (:func:`fused_wide_sums`): where the ``(d, 128)`` f32 operands of
+    ``_fm_kernel`` do not fit its VMEM beside one lane group of ``X.T``
+    (RCV1's 47,236 features: 96.7 MB of weights and partials alone) and
+    the weights as ROWS do.  None where ``_fm_kernel`` takes the shape,
+    and where not even the wide form fits."""
+    if _fm_narrow_tile(n, d, itemsize, masked) is not None:
+        return None
+    return _fm_wide_plan(n, d, itemsize, masked, _FM_WIDE_VMEM_LIMIT)
+
+
+def fm_blocks(n: int, d: int, itemsize: int, masked: bool = True,
+              class_rows: int = 0) -> Optional[Tuple[int, int]]:
+    """``(row tile, feature blocks)`` of the full scan's kernel over an
+    ``(n, d)`` X: its own choice of row tile, ``FM_TILE`` halved until its
+    VMEM fits, and the blocks its body cuts the width into: one, or for a
+    vector of weights too wide for that (:func:`fm_wide`) the wide form's.
+    None where no form fits even one lane group of rows (a wider d
+    still: the two-read path's case)."""
+    tile = _fm_narrow_tile(n, d, itemsize, masked, class_rows)
+    if tile is not None:
+        return tile, 1
+    wide = None if class_rows else fm_wide(n, d, itemsize, masked)
+    return wide and (wide[0], pl.cdiv(d, wide[1]))
+
+
+def fm_tile(n: int, d: int, itemsize: int, masked: bool = True,
+            class_rows: int = 0) -> Optional[int]:
+    """The full scan's own choice of row tile for an ``(n, d)`` X
+    (:func:`fm_blocks`), None where it has none."""
+    blocks = fm_blocks(n, d, itemsize, masked, class_rows)
+    return blocks and blocks[0]
+
+
+def _check_fm_vmem(tile: int, X, masked: bool, class_rows: int = 0,
+                   fblock: Optional[int] = None,
+                   limit: int = _FM_VMEM_LIMIT) -> None:
     """Reject a tile the chip's compiler would refuse with an error that
     names the largest one it admits (or says that not even one lane group
     fits), instead of a Mosaic compile-time OOM."""
     d = X.shape[1]
     itemsize = jnp.dtype(X.dtype).itemsize
-    need = _fm_vmem_bytes(tile, d, itemsize, masked, class_rows)
-    if need <= _FM_VMEM_LIMIT:
+    need = _fm_vmem_bytes(tile, d, itemsize, masked, class_rows, fblock)
+    if need <= limit:
         return
-    fixed = _fm_vmem_bytes(0, d, itemsize, masked, class_rows)
-    per_lane = _fm_vmem_bytes(1, d, itemsize, masked, class_rows) - fixed
-    max_tile = (_FM_VMEM_LIMIT - fixed) // per_lane // LANES * LANES
+    fixed = _fm_vmem_bytes(0, d, itemsize, masked, class_rows, fblock)
+    per_lane = _fm_vmem_bytes(1, d, itemsize, masked, class_rows,
+                              fblock) - fixed
+    max_tile = (limit - fixed) // per_lane // LANES * LANES
     hint = (
         f"use tile_m <= {max_tile}"
         if max_tile >= LANES
         else f"feature dim d={d} is too wide for this kernel at any "
-        "tile size; use the XLA path"
+        "tile size; Gradient.batch_sums takes such rows in the wide form "
+        "(fused_wide_sums) or, wider still, in two reads"
     )
     raise ValueError(
         f"tile_m={tile} with d={d} {jnp.dtype(X.dtype).name} needs "
         f"~{need / 2**20:.1f} MB of scoped VMEM, over the "
-        f"{_FM_VMEM_LIMIT / 2**20:.0f} MB the TPU compiler allows this "
+        f"{limit / 2**20:.0f} MB the TPU compiler allows this "
         f"kernel; {hint}"
     )
 
@@ -386,8 +502,8 @@ def _fm_tile_of(X, tile_m: Optional[int], masked: bool, interpret: bool,
     n, d = X.shape
     if tile_m is None:
         # too wide for one lane group: the check below says so
-        tile = (fm_tile(n, d, jnp.dtype(X.dtype).itemsize, masked,
-                        class_rows) or _fm_round(LANES, n))
+        tile = (_fm_narrow_tile(n, d, jnp.dtype(X.dtype).itemsize, masked,
+                                class_rows) or _fm_round(LANES, n))
     else:
         tile = _fm_round(tile_m, n)
     if not interpret:
@@ -497,7 +613,7 @@ def class_rows_of(C: int, dtype) -> int:
     return _round_up(C, 32 // jnp.dtype(dtype).itemsize)
 
 
-def _fm_class_kernel(rule, n, masked, xt_ref, y_ref, *refs):
+def _fm_class_kernel(rule, n, masked, fblock, xt_ref, y_ref, *refs):
     """One ``(d, tile)`` block of ``X.T`` for a ``(rows, d)`` MATRIX of
     weights, one row a class: both products go to the matrix unit with
     operands in X's type and f32 sums, ``(rows, d) @ (d, lanes)`` for the
@@ -506,13 +622,19 @@ def _fm_class_kernel(rule, n, masked, xt_ref, y_ref, *refs):
     ``(rows, lanes)`` arrays in VMEM.  The block is read from VMEM twice
     and from HBM once; the gradient is summed over the grid in f32, the
     loss and the count as lane partials, as in ``_fm_kernel``, whose grid
-    and tail cut these are."""
+    and tail cut these are.
+
+    ``fblock`` features a product: all d, or (the wide form) the width cut
+    into blocks that each product takes in turn, the margins summed over
+    them before the rule and the gradient written block by block, so that
+    no operand of a product is larger than one block of the row tile."""
     m_ref = refs[0] if masked else None
     w_ref, g_ref, loss_ref, cnt_ref = refs[-4:]
     i = pl.program_id(0)
     d, tile = xt_ref.shape
     f32 = jnp.float32
     lw = _fm_lane_chunk(tile)
+    blocks = [(r0, min(fblock, d - r0)) for r0 in range(0, d, fblock)]
 
     @pl.when(i == 0)
     def _():
@@ -526,14 +648,27 @@ def _fm_class_kernel(rule, n, masked, xt_ref, y_ref, *refs):
         before any arithmetic, as in ``_fm_kernel``."""
         c0 = c * lw if isinstance(c, int) else pl.multiple_of(c * lw, lw)
         lanes = pl.ds(c0, lw)
-        x = xt_ref[:, lanes]
-        y = y_ref[:, lanes]
         if tail:
             inside = (i * tile + c0 + jax.lax.broadcasted_iota(
                 jnp.int32, (1, lw), 1)) < n
-            x = jnp.where(inside, x, jnp.zeros_like(x))
+
+        def x_of(r0, r):
+            if whole is not None:  # one block: both products read it
+                return whole
+            x = xt_ref[r0:r0 + r, lanes]
+            return jnp.where(inside, x, jnp.zeros_like(x)) if tail else x
+
+        whole = None
+        if len(blocks) == 1:
+            whole = x_of(0, d)
+        y = y_ref[:, lanes]
+        if tail:
             y = jnp.where(inside, y, 0.0)
-        margins = jnp.dot(w_ref[:], x, preferred_element_type=f32)
+        margins = None
+        for r0, r in blocks:
+            part = jnp.dot(w_ref[:, r0:r0 + r], x_of(r0, r),
+                           preferred_element_type=f32)
+            margins = part if margins is None else margins + part
         coeff, losses = rule(margins, y)
         if masked:
             m = m_ref[:, lanes]
@@ -545,9 +680,11 @@ def _fm_class_kernel(rule, n, masked, xt_ref, y_ref, *refs):
             coeff = jnp.where(inside, coeff, 0.0)
             losses = jnp.where(inside, losses, 0.0)
         loss_ref[:] += _lane_fold(losses)
-        g_ref[:] += jax.lax.dot_general(
-            coeff.astype(x.dtype), x, (((1,), (1,)), ((), ())),
-            preferred_element_type=f32)
+        coeff = coeff.astype(xt_ref.dtype)
+        for r0, r in blocks:
+            g_ref[:, r0:r0 + r] += jax.lax.dot_general(
+                coeff, x_of(r0, r), (((1,), (1,)), ((), ())),
+                preferred_element_type=f32)
 
     _fm_full_scan(functools.partial(_fm_block, lanes_of, tile, lw), n, tile)
 
@@ -582,6 +719,34 @@ def fused_class_sums(
                              interpret=interpret)
 
 
+def _class_call(rule, X, y, W, mask, tile: int, fblock: int, limit: int,
+                interpret: bool):
+    """The class kernel's call over ``X.T`` under the ``(rows, d)`` weights
+    ``W`` in X's type: ``(gradient (rows, d), loss and count lane
+    partials)``, all f32."""
+    n, d = X.shape
+    rows = W.shape[0]
+    masked = mask is not None
+    row = pl.BlockSpec((1, tile), lambda i: (0, i))
+    whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))  # noqa: E731
+    operands = [X.T, row_operand(y, n)]
+    if masked:
+        operands.append(_mask_operand(mask, n))
+    operands.append(W)
+    return pl.pallas_call(
+        functools.partial(_fm_class_kernel, rule, n, masked, fblock),
+        grid=(pl.cdiv(n, tile),),
+        in_specs=[pl.BlockSpec((d, tile), lambda i: (0, i))]
+        + [row] * (1 + masked) + [whole((rows, d))],
+        out_specs=[whole((rows, d)), whole((1, LANES)), whole((1, LANES))],
+        out_shape=[jax.ShapeDtypeStruct((rows, d), jnp.float32)]
+        + _fm_sums_shape(d)[1:],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=limit),
+        interpret=interpret,
+    )(*operands)
+
+
 @functools.partial(
     jax.jit, static_argnames=("rule", "rows", "tile_m", "interpret")
 )
@@ -597,28 +762,120 @@ def _fused_class_sums(
 ) -> Tuple[Array, Array, Array]:
     n, d = X.shape
     C = W.shape[0]
-    tile = tile_m
-    masked = mask is not None
-    f32 = jnp.float32
-    row = pl.BlockSpec((1, tile), lambda i: (0, i))
-    whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))  # noqa: E731
-    operands = [X.T, row_operand(y, n)]
-    if masked:
-        operands.append(_mask_operand(mask, n))
-    operands.append(jnp.pad(W.astype(X.dtype), ((0, rows - C), (0, 0))))
-    grad, loss, cnt = pl.pallas_call(
-        functools.partial(_fm_class_kernel, rule, n, masked),
-        grid=(pl.cdiv(n, tile),),
-        in_specs=[pl.BlockSpec((d, tile), lambda i: (0, i))]
-        + [row] * (1 + masked) + [whole((rows, d))],
-        out_specs=[whole((rows, d)), whole((1, LANES)), whole((1, LANES))],
-        out_shape=[jax.ShapeDtypeStruct((rows, d), f32)]
-        + _fm_sums_shape(d)[1:],
-        compiler_params=_FM_COMPILER_PARAMS,
-        interpret=interpret,
-    )(*operands)
-    count = jnp.sum(cnt) if masked else jnp.asarray(n, f32)
+    grad, loss, cnt = _class_call(
+        rule, X, y, jnp.pad(W.astype(X.dtype), ((0, rows - C), (0, 0))),
+        mask, tile_m, d, _FM_VMEM_LIMIT, interpret)
+    count = jnp.sum(cnt) if mask is not None else jnp.asarray(
+        n, jnp.float32)
     return grad[:C], jnp.sum(loss), count
+
+
+def _parts_of(a, dtype, parts: int, in_kernel: bool = False):
+    """An f32 array as ``parts`` values of ``dtype`` that add up to it:
+    each the rounding of what the ones before left (bf16: 8 bits a part,
+    three parts an f32's 24; the last part of an f32 split is exact).
+    Outside a kernel the rounding is ``reduce_precision`` and not a pair
+    of casts: the chip's compiler removes a cast down and up again as
+    excess precision, and every part after the first would be zero (seen
+    on the chip: the fit then read the bf16-operand reference's gaps to
+    five digits).  Mosaic compiles a kernel's casts as they are written
+    and has no ``reduce_precision``."""
+    info = jnp.finfo(dtype)
+    out, rest = [], a.astype(jnp.float32)
+    for _ in range(parts):
+        part = rest.astype(dtype) if in_kernel else jax.lax.reduce_precision(
+            rest, info.nexp, info.nmant).astype(dtype)
+        out.append(part)
+        rest = rest - part.astype(jnp.float32)
+    return out
+
+
+def _vector_rule(pointwise, parts, dtype, margins, labels):
+    """The class kernel's rule for a VECTOR of weights held as ``parts``
+    rows: the rows' margins add up to ``x . w`` (smallest first), the
+    elementwise ``pointwise`` takes it, and the coefficient goes back as
+    ``parts`` rows of ``dtype`` values, whose gradient rows the caller
+    adds up; the padding rows' coefficient is zero."""
+    m = margins[parts - 1:parts]
+    for p in range(parts - 2, -1, -1):
+        m = m + margins[p:p + 1]
+    coeff, losses = pointwise(m, labels)
+    row = jax.lax.broadcasted_iota(jnp.int32, margins.shape, 0)
+    rows = jnp.zeros(margins.shape, jnp.float32)
+    split = _parts_of(coeff, dtype, parts, in_kernel=True)
+    for p, part in enumerate(split):
+        rows = jnp.where(row == p, part.astype(jnp.float32), rows)
+    return rows, losses
+
+
+def fused_wide_sums(
+    pointwise,
+    X: Array,
+    y: Array,
+    w: Array,
+    mask: Optional[Array] = None,
+    tile_m: Optional[int] = None,
+    vmem_limit: Optional[int] = None,
+    interpret: bool = False,
+) -> Tuple[Array, Array, Array]:
+    """:func:`fused_gradient_sums` for a width whose ``(d, 128)`` f32
+    operands no VMEM holds (RCV1's 47,236 features): ONE read of ``X``
+    through the class kernel's form.  The vector of weights rides as
+    rows of a ``(16, d)`` matrix in X's type (:func:`wide_rows_of`: three
+    bf16 parts an f32), both products run on the matrix unit with f32
+    sums, the elementwise ``pointwise`` between them on ``(1, lanes)``
+    margins in VMEM, and the body takes the width in feature blocks
+    (``_fm_feature_block``) so that a row tile of 12 MB a lane group is
+    never one operand.  Two ``(d, tile)`` blocks of ``X.T`` have to fit
+    ``vmem_limit`` bytes of scoped VMEM (``_FM_WIDE_VMEM_LIMIT`` when
+    None) beside the weights and the gradient: that decides the row tile
+    when ``tile_m`` names none, and the feature block with it.
+    """
+    n, d = X.shape
+    itemsize = jnp.dtype(X.dtype).itemsize
+    masked = mask is not None
+    limit = _FM_WIDE_VMEM_LIMIT if vmem_limit is None else int(vmem_limit)
+    _, rows = wide_rows_of(X.dtype)
+    if tile_m is None:
+        plan = _fm_wide_plan(n, d, itemsize, masked, limit)
+        tile = plan[0] if plan else _fm_round(LANES, n)  # the check says so
+    else:
+        tile = _fm_round(tile_m, n)
+    fblock = _fm_feature_block(d, tile, itemsize, limit)
+    if not interpret:
+        _check_fm_vmem(tile, X, masked, rows, fblock, limit)
+    return _fused_wide_sums(pointwise, X, y, w, mask, tile_m=tile,
+                            fblock=fblock, vmem_limit=limit,
+                            interpret=interpret)
+
+
+# a name of its own, with its scope (``sgd.wide_sums``): the compile cache
+# keys on the jitted function's name, not on its scopes (PERF.md, PR 25)
+@functools.partial(
+    jax.jit, static_argnames=("pointwise", "tile_m", "fblock", "vmem_limit",
+                              "interpret")
+)
+def _fused_wide_sums(
+    pointwise,
+    X: Array,
+    y: Array,
+    w: Array,
+    mask: Optional[Array] = None,
+    tile_m: int = LANES,
+    fblock: int = LANES,
+    vmem_limit: int = _FM_WIDE_VMEM_LIMIT,
+    interpret: bool = False,
+) -> Tuple[Array, Array, Array]:
+    n, d = X.shape
+    parts, rows = wide_rows_of(X.dtype)
+    W = jnp.pad(jnp.stack(_parts_of(w, X.dtype, parts)),
+                ((0, rows - parts), (0, 0)))
+    grad, loss, cnt = _class_call(
+        functools.partial(_vector_rule, pointwise, parts, X.dtype),
+        X, y, W, mask, tile_m, fblock, vmem_limit, interpret)
+    count = jnp.sum(cnt) if mask is not None else jnp.asarray(
+        n, jnp.float32)
+    return jnp.sum(grad[:parts], axis=0), jnp.sum(loss), count
 
 
 def fused_window_sums(

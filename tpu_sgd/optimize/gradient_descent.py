@@ -223,6 +223,24 @@ def rows_prepared(gradient, cfg: SGDConfig, X, y, weights, valid=None,
         X, y, weights, plan[0], model_axis_name, plan[1])
 
 
+def step_blocks(gradient, cfg: SGDConfig, X, y, weights, valid=None,
+                model_axis_name=None):
+    """``(row tile, feature blocks)`` of the one-read kernel that every
+    step of ``make_run``'s fit over these operands takes on a TPU
+    (``Gradient.kernel_blocks`` of what the step hands its sums: the mask
+    it draws, the window it slices); ``(0, 1)`` where the step is no
+    kernel.  From shapes and types alone: ``train.run``'s ``row_tile``
+    and ``feature_blocks`` ask it on the host."""
+    plan = _invariant_rows(cfg, X.shape[0], valid)
+    if plan is None:
+        return 0, 1
+    mask, window = plan
+    if mask is None and window is None and cfg.mini_batch_fraction < 1.0:
+        mask = jax.ShapeDtypeStruct((X.shape[0],), bool)  # the step's draw
+    return gradient.kernel_blocks(X, y, weights, mask, model_axis_name,
+                                  window)
+
+
 def prepare_rows(gradient, cfg: SGDConfig, X, y, weights, valid=None,
                  model_axis_name=None):
     """The step kernel's loop-invariant row operands, laid out once a fit
@@ -1672,7 +1690,9 @@ class GradientDescent(Optimizer):
         # below dispatches it.  A runner that had to be built (a new
         # _run_cache entry) traces, lowers and compiles inside that call.
         cached = len(self._run_cache)
-        prepared = 0  # only ``_runner``'s programs (make_run) prepare rows
+        # only ``_runner``'s programs (make_run) prepare rows and are the
+        # kernel: (labels_prepared, row_tile, feature_blocks)
+        kernel = 0, 0, 1
         if sparse_X and self.mesh is not None:
             # Distributed sparse: equal-nse BCOO blocks per shard, same
             # make_run body, psum over ICI (the treeAggregate-over-sparse-
@@ -1728,17 +1748,18 @@ class GradientDescent(Optimizer):
             else:
                 fn = self._runner(with_valid=valid is not None)
                 args = (w0, Xd, yd) if valid is None else (w0, Xd, yd, valid)
-                prepared = self._labels_prepared(*args)
+                kernel = self._step_kernel(*args)
             path = "mesh"
         else:
             fn = self._runner(with_valid=False)
             path = "gram" if isinstance(X, GramData) else "fused"
             args = (w0, X, y)
-            prepared = self._labels_prepared(*args)
+            kernel = self._step_kernel(*args)
         run_span.set(
             path=path,
             shards=1 if self.mesh is None else self.mesh.devices.size,
-            labels_prepared=prepared)
+            labels_prepared=kernel[0], row_tile=kernel[1],
+            feature_blocks=kernel[2])
         with span("train.dispatch",
                   built=int(len(self._run_cache) > cached)):
             w, losses, n_rec = fn(*args)
@@ -1760,15 +1781,19 @@ class GradientDescent(Optimizer):
             _raise_if_nonfinite(self._loss_history)
         return w, self._loss_history
 
-    def _labels_prepared(self, w0, X, y, valid=None) -> int:
-        """``train.run``'s ``labels_prepared`` for the fit ``_runner``'s
-        program is about to make of these arguments: 1 where it lays the
-        labels out once, before its loop, for the one-read kernel
-        (``rows_prepared`` of a shard's operands, on a TPU), 0 where the
-        step takes ``y`` as it is (two reads; statistics; a CPU, whose
+    def _step_kernel(self, w0, X, y, valid=None):
+        """``train.run``'s ``(labels_prepared, row_tile, feature_blocks)``
+        for the fit ``_runner``'s program is about to make of these
+        arguments (a shard's operands under a mesh), on a TPU:
+        ``labels_prepared`` 1 where it lays the labels out once, before
+        its loop, for the one-read kernel (``rows_prepared``), ``row_tile``
+        the rows a grid step of the step's kernel takes and
+        ``feature_blocks`` the blocks its body cuts the width into
+        (``step_blocks``).  ``(0, 0, 1)`` where the step takes ``y`` as
+        it is and is no kernel (two reads; statistics; a CPU, whose
         program drops the row nothing reads)."""
         if jax.default_backend() != "tpu":
-            return 0
+            return 0, 0, 1
         if self.mesh is not None:
             shards = self.mesh.devices.size
 
@@ -1777,8 +1802,8 @@ class GradientDescent(Optimizer):
                     (a.shape[0] // shards,) + tuple(a.shape[1:]), a.dtype)
 
             X, y, valid = shard(X), shard(y), shard(valid)
-        return int(rows_prepared(self.gradient, self.config, X, y, w0,
-                                 valid))
+        args = (self.gradient, self.config, X, y, w0, valid)
+        return (int(rows_prepared(*args)),) + tuple(step_blocks(*args))
 
     def _place(self, X, y):
         """``shard_dataset`` for this fit's mesh under the ``train.place``
