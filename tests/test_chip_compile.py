@@ -398,61 +398,87 @@ def test_the_class_fits_loop_holds_the_kernel_and_no_array_of_the_labels(S):
     assert "f32[1,%d]" % n in call
 
 
-@pytest.mark.parametrize("cell", sorted(CELL_ROWS))
-def test_the_masked_fits_loop_lays_out_the_mask_and_not_the_labels(S, cell):
-    """The same at the masked cells' shapes, where the compiler hoisted
-    the labels' reshape by itself before PR 33 moved it in the source:
-    pinned, not assumed.  One ``reshape`` to ``f32[1, n]`` stands outside
-    the fit's loop under ``sgd.prepare`` (2,145,000 is no multiple of
-    1024; at 4,194,304 it is a bitcast and nothing is made), and inside
-    the loop the one such reshape is the MASK's, drawn anew each step
-    (0.0136 ms of 6.136 from-host, PERF.md section 7)."""
+#: a shard's rows in the three masked cells: the two one-chip cells' and the
+#: four-chip cell's 10,000,000 over four
+MASKED_ROWS = {**CELL_ROWS, "resident-sharded": 2_500_000}
+
+
+@pytest.mark.parametrize("cell", sorted(MASKED_ROWS))
+def test_the_masked_fits_loop_makes_no_array_of_the_masks_size(
+        S, mesh4, cell):
+    """PR 36, at the three masked cells' shapes.  The kernel draws each
+    row's Bernoulli bit itself from the step's key (two prefetched words)
+    and the row's index, so the fit's loop, and everything it calls, makes
+    NO array of n entries: no ``f32[n]`` draw, no ``f32[1, n]`` relayout
+    of one (the parent made the draw's fusion every step, 0.044 to 0.086
+    ms, and where n is no multiple of 1024 a ``reshape`` of it besides,
+    0.014 to 0.026; PERF.md, PR 36).  ONE Mosaic call a step, handed the
+    key as ``s32[2]``, X as a bitcast and the labels' row the loop carries;
+    what is left of ``sgd.sample`` is the key's folds.  Outside the loop
+    the one array of n entries is the labels' ``reshape`` under
+    ``sgd.prepare`` (PR 33; a bitcast at 4,194,304, where nothing is
+    made)."""
     from tpu_sgd.optimize.gradient_descent import make_run
 
-    n = CELL_ROWS[cell]
-    cfg = _cfg(step_size=5.0, num_iterations=100, reg_param=0.001,
-               convergence_tol=0.0)
-    text = jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg)
-                   ).lower(S((D,), F32), S((n, D), BF16),
-                           S((n,), F32)).compile().as_text()
+    n = MASKED_ROWS[cell]
+    if cell == "resident-sharded":
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from tpu_sgd.parallel.data_parallel import dp_run_fn
+        from tpu_sgd.parallel.mesh import DATA_AXIS
+
+        text = dp_run_fn(
+            LeastSquaresGradient(), SimpleUpdater(),
+            _cfg(step_size=1.0, num_iterations=100, reg_param=0.0,
+                 convergence_tol=0.0), mesh4, with_valid=False).lower(
+            S((D,), F32, NamedSharding(mesh4, P())),
+            S((4 * n, D), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
+            S((4 * n,), F32, NamedSharding(mesh4, P(DATA_AXIS))),
+        ).compile().as_text()
+    else:
+        cfg = _cfg(step_size=5.0, num_iterations=100, reg_param=0.001,
+                   convergence_tol=0.0)
+        text = jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg)
+                       ).lower(S((D,), F32), S((n, D), BF16),
+                               S((n,), F32)).compile().as_text()
     comps = _computations(text)
-    loop = _fit_loop_body(comps)
-    # (a ``copy-done`` of the row in the loop is the compiler's prefetch of
-    # it into the nearer memory, layout unchanged; the parent's, of f32[n])
-    rows_made = [[made for made in _made_with(comps, [name], n)
-                  if made == ("reshape", "f32[1,%d]" % n)
-                  or made[0] in ("fusion", "copy", "dynamic-update-slice")
-                  and made[1] == "f32[1,%d]" % n]
-                 for name in ("ENTRY", loop)]
+    body = _reach(comps, _fit_loop_body(comps))
+    assert _made_with(comps, body, n) == []
+    outside = [name for name in _reach(comps, "ENTRY") if name not in body]
     moved = n % 1024 != 0
-    assert rows_made == [[("reshape", "f32[1,%d]" % n)] * moved] * 2
+    assert _made_with(comps, outside, n) == [
+        ("reshape", "f32[1,%d]" % n)] * moved
     if moved:
-        outside, = [line for line in comps["ENTRY"]
-                    if " reshape(" in line and "f32[1,%d]" % n in line]
-        assert "sgd.prepare" in outside and "%y" in outside
-        inside, = [line for line in comps[loop]
-                   if " reshape(" in line and "f32[1,%d]" % n in line]
-        assert "sgd.fused_sums" in inside and "%y" not in inside
+        laid, = [line for name in outside for line in comps[name]
+                 if " reshape(" in line and "f32[1,%d]" % n in line]
+        assert "sgd.prepare" in laid
+    call, = [line for name in body for line in comps[name]
+             if "tpu_custom_call" in line]
+    assert "sgd.fused_sums" in call and "_fused_scan_sums" in call
+    assert ("operand_layout_constraints={s32[2]{0}, bf16[%d,%d]{1,0}, "
+            "f32[1,%d]{1,0}, f32[%d,128]{1,0}}" % (D, n, n, D)) in call
+    assert "bf16[%d,%d]{1,0:T(8,128)(2,1)} bitcast(" % (D, n) in text
+    assert _moves_of(text, n, D) == []
 
 
 #: sha256 (16 hex digits) of the masked fit's program lowered for a TPU at
 #: the three masked cells' shapes: the StableHLO outside the Mosaic call,
 #: and the call's body parsed and printed WITHOUT locations (the serialized
-#: body carries file paths and line numbers).  The kernel bodies (the
-#: second of each pair) are PR 30's: PR 31 put the window's grid beside the
-#: masked one on the same kernel body and had to leave them as they were.
-#: The first of each pair is PR 33's, which moved ONE line of the StableHLO:
-#: the labels' ``reshape`` to ``tensor<1xNxf32>`` stands in front of the
-#: ``while`` (``sgd.prepare``) and the row rides in the loop's operands
-#: to ``@_fused_gradient_sums``, where PR 30's program had it inside the
-#: called function.  The COMPILED step is the parent's: its compiler hoisted
-#: that reshape out of the loop at these sizes by itself
-#: (``test_the_masked_fits_loop_lays_out_the_mask_and_not_the_labels``).
+#: body carries file paths and line numbers).  Both of each pair are PR
+#: 36's, which meant to change the masked step: the StableHLO no longer
+#: draws ``bernoulli`` (no ``f32[n]`` mask, no third row operand; the step's
+#: key goes to ``@_fused_scan_sums`` as ``tensor<2xui32>`` and to the call
+#: as two prefetched int32 words), and the kernel's body draws the rows of
+#: its block itself (``_row_draw``: twenty rounds of threefry on the row's
+#: index, folded over the sublanes).  Before it the bodies were PR 30's (PR
+#: 31 put the window's grid beside the masked one on the same body and left
+#: them as they were) and the StableHLO PR 33's (the labels' ``reshape`` in
+#: front of the ``while``, ``sgd.prepare``).
 #: A PR that means to change the masked step changes them here, and says so.
 MASKED_PROGRAMS = {
-    "resident": ("e8da7b203a71f46f", "f5c6595b5d61f671"),
-    "from-host": ("56bee9814e9c64a1", "3d660c2b20cbfaf7"),
-    "resident-sharded": ("000a06458a33c051", "aba2383d2357b17e"),
+    "resident": ("b0d759422ecb7c46", "ebe1ae5e828b7d7c"),
+    "from-host": ("6b2dd818dd5e44d0", "a1e7fb6617a1c983"),
+    "resident-sharded": ("b39c1a803f76616e", "03e9de77b6558f08"),
 }
 
 
@@ -758,9 +784,9 @@ def test_serving_bucket_programs_compile(S, activation):
 # -- the Pallas kernel at a tile of the caller's -------------------------------
 
 def _lower_masked(S, dtype, tile_m):
-    from tpu_sgd.ops.pallas_kernels import _fused_gradient_sums
+    from tpu_sgd.ops.pallas_kernels import _fused_scan_sums
 
-    return _fused_gradient_sums.lower(
+    return _fused_scan_sums.lower(
         LeastSquaresGradient().pointwise, S((KERNEL_N, D), dtype),
         S((KERNEL_N,), F32), S((D,), F32), S((KERNEL_N,), jnp.bool_),
         tile_m=tile_m)

@@ -289,7 +289,9 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
                           "shards": 1, "classes": 2,
                           "labels_prepared": 0,  # 1 on a TPU alone (PR 33)
                           # the kernel's blocks, on a TPU alone (PR 34)
-                          "row_tile": 0, "feature_blocks": 1}
+                          "row_tile": 0, "feature_blocks": 1,
+                          # the draw in the kernel, on a TPU alone (PR 36)
+                          "mask_in_kernel": 0}
         for name in ("train.h2d", "train.dispatch", "train.fetch"):
             assert _inside(found[name][i], run), name
         assert found["train.h2d"][i][2]["bytes"] == X.nbytes + y.nbytes

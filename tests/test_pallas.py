@@ -444,7 +444,7 @@ def test_batch_sums_lowers_the_kernel_for_a_tpu_and_two_matvecs_here(g):
     assert "tpu_custom_call" in tpu and "stablehlo.dot_general" not in tpu
     cpu = _lowered_for("cpu", g.batch_sums, X, y, w, mask)
     assert cpu.count("stablehlo.dot_general") == 2 and "tpu_custom_call" not in cpu
-    assert "sgd.fused_sums/jit(_fused_gradient_sums)" in tpu
+    assert "sgd.fused_sums/jit(_fused_scan_sums)" in tpu
     # each half keeps its own scope on what it leaves outside the kernel
     # (w along the lanes, the fold of the lane partials): the benchmark's
     # margins_ms and gradient_ms still find an operation to read

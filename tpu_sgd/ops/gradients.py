@@ -27,6 +27,7 @@ Closed forms mirrored exactly from the reference semantics (SURVEY.md §3.2):
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Tuple
 
 import jax
@@ -94,6 +95,37 @@ def grad_sum_of(coeff, X):
         )
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class RowDraw:
+    """A Bernoulli mini-batch mask that is not drawn yet: what a step hands
+    ``Gradient.batch_sums`` in the mask's place where the one-read kernel
+    draws each row's bit itself (``Gradient.draws_rows``), so that no
+    array of the mask is made.  It stands for ``bernoulli(key, fraction,
+    (n,)) & valid``, the same rows wherever it is drawn: :meth:`mask` IS
+    that array, for every path that is no such kernel."""
+
+    key: Array
+    valid: Optional[Array]
+    fraction: float = dataclasses.field(metadata=dict(static=True))
+
+    def mask(self, n: int) -> Array:
+        with jax.named_scope("sgd.sample"):
+            drawn = jax.random.bernoulli(self.key, self.fraction, (n,))
+            return drawn if self.valid is None else drawn & self.valid
+
+
+def counter_draws() -> bool:
+    """Whether ``jax.random.bernoulli`` under a key that
+    ``jax.random.PRNGKey`` makes draws entry i from i alone: threefry2x32
+    run as a counter (``jax_threefry_partitionable``; JAX's default since
+    0.5), which a kernel can repeat for the rows of its block.  Under
+    another implementation (``rbg``) or with the flag off the bits depend
+    on the whole array's shape, and the mask stays an array."""
+    return bool(jax.config.jax_threefry_partitionable
+                and jax.config.jax_default_prng_impl == "threefry2x32")
+
+
 def one_read_blocks(X, y, weights, mask=None, margin_axis_name=None,
                     classes: Optional[int] = None, window: bool = False):
     """``(row tile, feature blocks)`` of the fused one-read kernel that
@@ -112,7 +144,10 @@ def one_read_blocks(X, y, weights, mask=None, margin_axis_name=None,
     128 lanes in f32 or beside the class rows (one feature block), or,
     for a vector of weights too wide for that, in the wide form
     (``pallas_kernels.fm_wide``: weights as rows, the width in feature
-    blocks), which the window's kernel does not have."""
+    blocks), which the window's kernel does not have.  A mask the kernel
+    draws itself (:class:`RowDraw`) is no operand: its ``valid`` is."""
+    if isinstance(mask, RowDraw):
+        mask = mask.valid
     if (margin_axis_name is not None or _is_sparse(X)
             or getattr(X, "ndim", 0) != 2 or jnp.ndim(weights) != 1
             or X.dtype not in (jnp.bfloat16, jnp.float32)):
@@ -194,6 +229,11 @@ class Gradient:
         (where it is the same every call) this ``mask``, once, in front of
         the caller's loop; the one-read kernel then reads them as they lie.
         They ride beside ``y`` and ``mask``, which the two-read path reads.
+
+        A :class:`RowDraw` in the mask's place (a step hands one on where
+        :meth:`draws_rows` holds, nowhere else) is drawn by the kernel,
+        row by row, and made the array it stands for where the program is
+        lowered for another platform.
         """
         if one_read_sums(X, y, weights, mask, margin_axis_name):
             # both are traced; the platform the program is LOWERED for
@@ -205,8 +245,27 @@ class Gradient:
         return self._two_read_sums(X, y, weights, mask, margin_axis_name)
 
     def _two_read_default(self, X, y, weights, mask, rows):
-        """``platform_dependent``'s other branch: the ``(n,)`` operands."""
+        """``platform_dependent``'s other branch: the ``(n,)`` operands,
+        the mask drawn as an array."""
+        if isinstance(mask, RowDraw):
+            mask = mask.mask(X.shape[0])
         return self._two_read_sums(X, y, weights, mask)
+
+    def draws_rows(self, X, y, weights, valid=None, margin_axis_name=None
+                   ) -> bool:
+        """Whether a Bernoulli-sampled step hands :meth:`batch_sums` its
+        mask as a :class:`RowDraw`, for the kernel to draw: where the sums
+        of these operands (``valid``: a padded shard's, or None) are the
+        one-read kernel's vector body with all d in one feature block if
+        the program is lowered for a TPU (:meth:`kernel_blocks`), and the
+        draw is a counter's (:func:`counter_draws`).  Everywhere else (two
+        reads, BCOO, a feature-sharded run, the wide form, another PRNG)
+        the step draws the array it always drew.  From shapes, types and
+        JAX's configuration alone, so the host can ask it too."""
+        tile, feature_blocks = self.kernel_blocks(
+            X, y, weights, valid, margin_axis_name)
+        return (tile > 0 and feature_blocks == 1 and counter_draws()
+                and jnp.shape(X)[0] < 2**31)
 
     def kernel_blocks(self, X, y, weights, mask=None, margin_axis_name=None,
                       window: Optional[int] = None) -> Tuple[int, int]:
@@ -261,13 +320,17 @@ class Gradient:
         from tpu_sgd.ops.pallas_kernels import (fm_wide, fused_gradient_sums,
                                                 fused_wide_sums)
 
+        draw = None
+        if isinstance(mask, RowDraw):  # by draws_rows never the wide form
+            draw, mask = (mask.key, mask.fraction), mask.valid
         wide = fm_wide(*X.shape, X.dtype.itemsize, mask is not None)
         y, mask = _kernel_rows(y, mask, rows)
         if wide is not None:
             with jax.named_scope("sgd.wide_sums"):
                 return fused_wide_sums(self.pointwise, X, y, weights, mask)
         with jax.named_scope("sgd.fused_sums"):
-            return fused_gradient_sums(self.pointwise, X, y, weights, mask)
+            return fused_gradient_sums(self.pointwise, X, y, weights, mask,
+                                       draw=draw)
 
     def _two_read_sums(self, X, y, weights, mask, margin_axis_name=None):
         """Two matvecs, each a pass over all of X (or the BCOO lowering)."""
@@ -444,6 +507,9 @@ class ChunkedGradient(Gradient):
             return 0, 1
         return self.base.kernel_blocks(X, y, weights, mask, margin_axis_name)
 
+    def draws_rows(self, X, y, weights, valid=None, margin_axis_name=None):
+        return self.base.draws_rows(X, y, weights, valid, margin_axis_name)
+
     def loss_sweep(self, X, y, W, mask=None):
         return self.base.loss_sweep(X, y, W, mask)
 
@@ -618,6 +684,11 @@ class MultinomialLogisticGradient(Gradient):
             return 0, 1
         return one_read_blocks(X, y, weights, mask, margin_axis_name,
                                classes=self.num_classes) or (0, 1)
+
+    def draws_rows(self, X, y, weights, valid=None, margin_axis_name=None):
+        # the class kernel reads a drawn mask as an array (no cell runs it
+        # masked; the draw rides the vector body alone)
+        return False
 
     def _fused_sums(self, X, y, weights, mask, rows=None):
         """One read of X (``ops/pallas_kernels.fused_class_sums``)."""

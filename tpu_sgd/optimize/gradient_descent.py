@@ -40,7 +40,8 @@ import jax.numpy as jnp
 from tpu_sgd.config import SGDConfig
 from tpu_sgd.obs.spans import span
 from tpu_sgd.obs.timeseries import observe_scalar
-from tpu_sgd.ops.gradients import Gradient, LeastSquaresGradient
+from tpu_sgd.ops.gradients import (Gradient, LeastSquaresGradient,
+                                   RowDraw)
 from tpu_sgd.ops.gram import DEFAULT_BLOCK_ROWS
 from tpu_sgd.ops.sparse import is_sparse
 from tpu_sgd.ops.updaters import SimpleUpdater, Updater
@@ -180,16 +181,20 @@ def _sample_key(key, i, axis_name, shard_index=None):
     return k
 
 
-def _make_mask(cfg: SGDConfig, key, i, n_local, valid, axis_name,
-               shard_index=None):
-    """Per-iteration Bernoulli mini-batch mask (None = take everything)."""
-    if cfg.mini_batch_fraction < 1.0:
-        with jax.named_scope("sgd.sample"):
-            k = _sample_key(key, i, axis_name, shard_index)
-            mask = jax.random.bernoulli(k, cfg.mini_batch_fraction,
-                                        (n_local,))
-            return mask if valid is None else mask & valid
-    return valid
+def _make_mask(gradient, cfg: SGDConfig, key, i, X, y, weights, valid,
+               axis_name, model_axis_name, shard_index=None):
+    """Per-iteration Bernoulli mini-batch mask (None = take everything):
+    the array, or where the gradient's kernel draws its rows itself
+    (``Gradient.draws_rows``) the ``RowDraw`` that stands for the same
+    array: the same rows, every step, every shard."""
+    if cfg.mini_batch_fraction >= 1.0:
+        return valid
+    with jax.named_scope("sgd.sample"):
+        k = _sample_key(key, i, axis_name, shard_index)
+    draw = RowDraw(k, valid, cfg.mini_batch_fraction)
+    if gradient.draws_rows(X, y, weights, valid, model_axis_name):
+        return draw
+    return draw.mask(X.shape[0])
 
 
 def _window_rows(cfg: SGDConfig, n_rows: int) -> int:
@@ -197,20 +202,34 @@ def _window_rows(cfg: SGDConfig, n_rows: int) -> int:
     return max(1, round(cfg.mini_batch_fraction * n_rows))
 
 
-def _invariant_rows(cfg: SGDConfig, n_rows: int, valid):
-    """``(mask, window)``: what of a fit's row operands every step's sums
-    are handed as it stands besides the labels: ``valid``, unless the
-    Bernoulli draw folds it into a mask made anew each step, and, under
-    ``sampling="sliced"``, a window of ``window`` rows (else None).  None
-    where the step gathers its rows (``"indexed"``) and hands nothing on
-    as it stands."""
+def mask_in_kernel(gradient, cfg: SGDConfig, X, y, weights, valid=None,
+                   model_axis_name=None) -> bool:
+    """Whether every step of a fit over these operands (a shard's, under a
+    mesh) has the one-read kernel draw its Bernoulli mask
+    (``_make_mask``): from shapes and types alone, so ``train.run``'s
+    ``mask_in_kernel`` asks it on the host."""
+    return (cfg.mini_batch_fraction < 1.0 and cfg.sampling == "bernoulli"
+            and gradient.draws_rows(X, y, weights, valid, model_axis_name))
+
+
+def _invariant_rows(gradient, cfg: SGDConfig, X, y, weights, valid,
+                    model_axis_name):
+    """``(mask, window, drawn)``: what of a fit's row operands every step's
+    sums are handed as it stands besides the labels: ``valid``, unless the
+    Bernoulli draw folds it into a mask made anew each step (``drawn``;
+    where the kernel draws, ``mask_in_kernel``, ``valid`` stays an operand
+    of its own and nothing is made), and, under ``sampling="sliced"``, a
+    window of ``window`` rows (else None).  None where the step gathers its
+    rows (``"indexed"``) and hands nothing on as it stands."""
     if cfg.mini_batch_fraction >= 1.0:
-        return valid, None
+        return valid, None, False
     if cfg.sampling == "sliced":
-        return valid, _window_rows(cfg, n_rows)
+        return valid, _window_rows(cfg, X.shape[0]), False
     if cfg.sampling == "indexed":
         return None
-    return None, None
+    if mask_in_kernel(gradient, cfg, X, y, weights, valid, model_axis_name):
+        return valid, None, False
+    return None, None, True
 
 
 def rows_prepared(gradient, cfg: SGDConfig, X, y, weights, valid=None,
@@ -218,7 +237,8 @@ def rows_prepared(gradient, cfg: SGDConfig, X, y, weights, valid=None,
     """Whether ``make_run``'s fit over these operands (a shard's, under a
     mesh) lays its labels out before its loop: from shapes and types alone,
     so ``train.run``'s ``labels_prepared`` asks it on the host."""
-    plan = _invariant_rows(cfg, X.shape[0], valid)
+    plan = _invariant_rows(gradient, cfg, X, y, weights, valid,
+                           model_axis_name)
     return plan is not None and gradient.prepares_rows(
         X, y, weights, plan[0], model_axis_name, plan[1])
 
@@ -228,14 +248,15 @@ def step_blocks(gradient, cfg: SGDConfig, X, y, weights, valid=None,
     """``(row tile, feature blocks)`` of the one-read kernel that every
     step of ``make_run``'s fit over these operands takes on a TPU
     (``Gradient.kernel_blocks`` of what the step hands its sums: the mask
-    it draws, the window it slices); ``(0, 1)`` where the step is no
-    kernel.  From shapes and types alone: ``train.run``'s ``row_tile``
-    and ``feature_blocks`` ask it on the host."""
-    plan = _invariant_rows(cfg, X.shape[0], valid)
+    it draws as an array, the window it slices); ``(0, 1)`` where the step
+    is no kernel.  From shapes and types alone: ``train.run``'s
+    ``row_tile`` and ``feature_blocks`` ask it on the host."""
+    plan = _invariant_rows(gradient, cfg, X, y, weights, valid,
+                           model_axis_name)
     if plan is None:
         return 0, 1
-    mask, window = plan
-    if mask is None and window is None and cfg.mini_batch_fraction < 1.0:
+    mask, window, drawn = plan
+    if drawn:
         mask = jax.ShapeDtypeStruct((X.shape[0],), bool)  # the step's draw
     return gradient.kernel_blocks(X, y, weights, mask, model_axis_name,
                                   window)
@@ -249,7 +270,8 @@ def prepare_rows(gradient, cfg: SGDConfig, X, y, weights, valid=None,
     ``make_step``'s step to call in FRONT of the loop and hand every step
     as ``rows``: inside it the compiler may leave the relayout where the
     source put it, every iteration (PERF.md, PR 33)."""
-    plan = _invariant_rows(cfg, X.shape[0], valid)
+    plan = _invariant_rows(gradient, cfg, X, y, weights, valid,
+                           model_axis_name)
     if plan is None:
         return None
     with jax.named_scope("sgd.prepare"):
@@ -302,8 +324,8 @@ def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
                 mask = None if valid is None else valid[idx]
         else:
             Xb, yb = X, y
-            mask = _make_mask(cfg, key, i, X.shape[0], valid, axis_name,
-                              shard_index)
+            mask = _make_mask(gradient, cfg, key, i, X, y, weights, valid,
+                              axis_name, model_axis_name, shard_index)
         return gradient.batch_sums(
             Xb, yb, weights, mask, margin_axis_name=model_axis_name, **made
         )
@@ -1691,8 +1713,8 @@ class GradientDescent(Optimizer):
         # _run_cache entry) traces, lowers and compiles inside that call.
         cached = len(self._run_cache)
         # only ``_runner``'s programs (make_run) prepare rows and are the
-        # kernel: (labels_prepared, row_tile, feature_blocks)
-        kernel = 0, 0, 1
+        # kernel: (labels_prepared, row_tile, feature_blocks, mask_in_kernel)
+        kernel = 0, 0, 1, 0
         if sparse_X and self.mesh is not None:
             # Distributed sparse: equal-nse BCOO blocks per shard, same
             # make_run body, psum over ICI (the treeAggregate-over-sparse-
@@ -1759,7 +1781,7 @@ class GradientDescent(Optimizer):
             path=path,
             shards=1 if self.mesh is None else self.mesh.devices.size,
             labels_prepared=kernel[0], row_tile=kernel[1],
-            feature_blocks=kernel[2])
+            feature_blocks=kernel[2], mask_in_kernel=kernel[3])
         with span("train.dispatch",
                   built=int(len(self._run_cache) > cached)):
             w, losses, n_rec = fn(*args)
@@ -1782,18 +1804,20 @@ class GradientDescent(Optimizer):
         return w, self._loss_history
 
     def _step_kernel(self, w0, X, y, valid=None):
-        """``train.run``'s ``(labels_prepared, row_tile, feature_blocks)``
-        for the fit ``_runner``'s program is about to make of these
-        arguments (a shard's operands under a mesh), on a TPU:
-        ``labels_prepared`` 1 where it lays the labels out once, before
-        its loop, for the one-read kernel (``rows_prepared``), ``row_tile``
-        the rows a grid step of the step's kernel takes and
+        """``train.run``'s ``(labels_prepared, row_tile, feature_blocks,
+        mask_in_kernel)`` for the fit ``_runner``'s program is about to
+        make of these arguments (a shard's operands under a mesh), on a
+        TPU: ``labels_prepared`` 1 where it lays the labels out once,
+        before its loop, for the one-read kernel (``rows_prepared``),
+        ``row_tile`` the rows a grid step of the step's kernel takes and
         ``feature_blocks`` the blocks its body cuts the width into
-        (``step_blocks``).  ``(0, 0, 1)`` where the step takes ``y`` as
-        it is and is no kernel (two reads; statistics; a CPU, whose
-        program drops the row nothing reads)."""
+        (``step_blocks``), ``mask_in_kernel`` 1 where that kernel draws
+        every step's Bernoulli mask itself (0 where the step is handed an
+        array or draws nothing).  ``(0, 0, 1, 0)`` where the step takes
+        ``y`` as it is and is no kernel (two reads; statistics; a CPU,
+        whose program drops the row nothing reads)."""
         if jax.default_backend() != "tpu":
-            return 0, 0, 1
+            return 0, 0, 1, 0
         if self.mesh is not None:
             shards = self.mesh.devices.size
 
@@ -1803,7 +1827,8 @@ class GradientDescent(Optimizer):
 
             X, y, valid = shard(X), shard(y), shard(valid)
         args = (self.gradient, self.config, X, y, w0, valid)
-        return (int(rows_prepared(*args)),) + tuple(step_blocks(*args))
+        return (int(rows_prepared(*args)), *step_blocks(*args),
+                int(mask_in_kernel(*args)))
 
     def _place(self, X, y):
         """``shard_dataset`` for this fit's mesh under the ``train.place``
