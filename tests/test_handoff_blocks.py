@@ -13,13 +13,15 @@ import numpy as np
 import pytest
 
 import tpu_sgd
-from tpu_sgd.obs.spans import disable_tracing, enable_tracing
+from tpu_sgd.obs.spans import disable_tracing, enable_tracing, span
 from tpu_sgd.ops.sparse import sparse_data
 from tpu_sgd.optimize import gradient_descent as gd
 
 #: rows a block holds in these tests (the least the helper cuts: whole
 #: multiples of ``_STAGE_ROWS``) and the blocks in flight
 ROWS, IN_FLIGHT = gd._STAGE_ROWS, 2
+#: what ``span("train.h2d")`` is with tracing off: the helper reads no clock
+NO_SPAN = span("train.h2d")
 
 
 def _blocks_of(monkeypatch, row_bytes, rows=ROWS, in_flight=IN_FLIGHT):
@@ -58,7 +60,7 @@ def test_the_staged_array_is_jnp_asarrays(monkeypatch, case, d):
     n = ROW_CASES[case]
     X = _host(n, d, np.float32)
     _blocks_of(monkeypatch, X.strides[0])
-    got, blocks, block_bytes = gd._stage_dense(X)
+    got, blocks, block_bytes = gd._stage_dense(X, NO_SPAN)
     _same(got, X)
     assert blocks == max(1, -(-n // ROWS))
     assert block_bytes == (X.nbytes if blocks == 1 else ROWS * d * 4)
@@ -72,7 +74,7 @@ def test_every_type_arrives_as_the_single_copy_brings_it(monkeypatch, dtype):
     bool in their own type: the cast to f32 is the caller's, after the copy."""
     X = _host(2 * ROWS + 5, 16, dtype)
     _blocks_of(monkeypatch, X.strides[0])
-    got, blocks, _ = gd._stage_dense(X)
+    got, blocks, _ = gd._stage_dense(X, NO_SPAN)
     assert blocks == 3
     _same(got, X)
 
@@ -87,7 +89,7 @@ def test_a_host_array_in_another_order_arrives_right(monkeypatch, order):
          "strided_columns": base[:, ::3], "reversed": base[::-1]}[order]
     assert not X.flags.c_contiguous
     _blocks_of(monkeypatch, X.shape[1] * 4)
-    got, blocks, _ = gd._stage_dense(X)
+    got, blocks, _ = gd._stage_dense(X, NO_SPAN)
     assert blocks == -(-X.shape[0] // ROWS) > 1
     _same(got, X)
 
@@ -97,7 +99,7 @@ def test_a_wide_array_of_few_rows_goes_in_one_piece(monkeypatch):
     X = _host(ROWS - 24, 64, np.float32)
     monkeypatch.setattr(gd, "_STAGE_BLOCK_BYTES", 4096)
     before = gd._stage_block._cache_size()
-    got, blocks, block_bytes = gd._stage_dense(X)
+    got, blocks, block_bytes = gd._stage_dense(X, NO_SPAN)
     _same(got, X)
     assert (blocks, block_bytes) == (1, X.nbytes)
     assert gd._stage_block._cache_size() == before
@@ -110,17 +112,17 @@ def test_what_is_no_large_numpy_array_takes_the_calls_it_took(monkeypatch):
     Xd = jnp.asarray(_host(4 * ROWS, 16, np.float32))
     before = gd._stage_block._cache_size()
     with jax.transfer_guard("disallow"):
-        got, blocks, block_bytes = gd._stage_dense(Xd)
+        got, blocks, block_bytes = gd._stage_dense(Xd, NO_SPAN)
     assert got is Xd and (blocks, block_bytes) == (0, 0)
     small = _host(ROWS - 1, 16, np.float32)
-    got, blocks, block_bytes = gd._stage_dense(small)
+    got, blocks, block_bytes = gd._stage_dense(small, NO_SPAN)
     _same(got, small)
     assert (blocks, block_bytes) == (1, small.nbytes)
     assert gd._stage_block._cache_size() == before
     # BCOO features never reach the helper: the fit hands them on untouched
     Xs, ys, _ = sparse_data(256, 32, nnz_per_row=4, seed=1)
     seen = []
-    monkeypatch.setattr(gd, "_stage_dense", lambda X: seen.append(X))
+    monkeypatch.setattr(gd, "_stage_dense", lambda X, h2d: seen.append(X))
     opt = (tpu_sgd.GradientDescent(tpu_sgd.LeastSquaresGradient(),
                                    tpu_sgd.SimpleUpdater())
            .set_num_iterations(2))
@@ -157,7 +159,7 @@ def test_the_blocks_held_are_bounded_by_the_blocks_in_flight(monkeypatch):
         return dest, Written(token)
 
     monkeypatch.setattr(gd, "_stage_block", write)
-    got, n_blocks, _ = gd._stage_dense(X)
+    got, n_blocks, _ = gd._stage_dense(X, NO_SPAN)
     _same(got, X)
     assert n_blocks == len(blocks) == 7 and len(waited) == 7 - 3
     assert all(b.is_deleted() for b in blocks)
@@ -203,6 +205,11 @@ def test_train_h2d_says_how_many_pieces_went(monkeypatch):
         X.nbytes + y.nbytes, 4, ROWS * 32)
     assert (device["bytes"], device["blocks"], device["block_bytes"]) == (
         0, 0, 0)
+    # the stall counter (PR 37): 4 blocks, 2 in flight: the host stood in
+    # the flow-control wait twice; one piece and a device array, never
+    assert many["stalls"] == 4 - IN_FLIGHT and many["stall_ms"] >= 0
+    for span in (one, device):
+        assert (span["stalls"], span["stall_ms"]) == (0, 0)
 
 
 # -- the fit ----------------------------------------------------------------------
@@ -229,7 +236,8 @@ def test_a_fit_from_blocks_is_the_fit_from_one_piece_bit_for_bit(
     staged = []
     real = gd._stage_dense
     monkeypatch.setattr(
-        gd, "_stage_dense", lambda X: staged.append(real(X)) or staged[-1])
+        gd, "_stage_dense",
+        lambda X, h2d: staged.append(real(X, h2d)) or staged[-1])
     w_one, loss_one = fit()
     _blocks_of(monkeypatch, X.strides[0])
     w_blocks, loss_blocks = fit()

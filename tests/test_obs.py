@@ -292,8 +292,18 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
                           "row_tile": 0, "feature_blocks": 1,
                           # the draw in the kernel, on a TPU alone (PR 36)
                           "mask_in_kernel": 0}
-        for name in ("train.h2d", "train.dispatch", "train.fetch"):
+        # the leaves tile the fit in this order (PR 37: train.select
+        # between the hand-off and the call, fit.finish after the optimizer)
+        leaves = [found[name][i] for name in (
+            "fit.validate", "fit.plan", "train.h2d", "train.select",
+            "train.dispatch", "train.fetch", "fit.finish")]
+        for earlier, later in zip(leaves, leaves[1:]):
+            assert earlier[1] <= later[0]
+        for name in ("train.h2d", "train.select", "train.dispatch",
+                     "train.fetch"):
             assert _inside(found[name][i], run), name
+        assert _inside(found["fit.finish"][i], fit)
+        assert run[1] <= found["fit.finish"][i][0]
         assert found["train.h2d"][i][2]["bytes"] == X.nbytes + y.nbytes
         assert found["train.fetch"][i][2]["recorded"] == 4
         assert found["fit.validate"][i][2] == {"rows": 256}
@@ -302,17 +312,21 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
     # the fit that builds its runner names itself
     assert [d[2]["built"] for d in found["train.dispatch"]] == [1, 0, 1, 1,
                                                                 0]
+    # the selection before it carries nothing: ``built`` says it all
+    assert [s[2] for s in found["train.select"]] == [{}] * 5
+    assert len(found["fit.finish"]) == 2  # run() alone has a model to make
     # device arrays at the Optimizer boundary: nothing to copy
     assert found["train.h2d"][2][2]["bytes"] == 0
     assert found["train.run"][2][2]["iterations"] == 3
     # the meshed fits: ``train.place`` is a leaf of ``train.run`` between
     # the copy and the dispatch; the second trains the arrays where they lie
     assert "train.place" in found and len(found["train.place"]) == 2
-    for place, run, h2d, dispatch in zip(
+    for place, run, h2d, select, dispatch in zip(
             found["train.place"], found["train.run"][3:],
-            found["train.h2d"][3:], found["train.dispatch"][3:]):
+            found["train.h2d"][3:], found["train.select"][3:],
+            found["train.dispatch"][3:]):
         assert _inside(place, run) and h2d[1] <= place[0]
-        assert place[1] <= dispatch[0]
+        assert place[1] <= select[0] and select[1] <= dispatch[0]
         assert run[2]["path"] == "mesh" and run[2]["shards"] == 4
     assert [p[2] for p in found["train.place"]] == [
         {"shards": 4, "in_place": 0, "bytes": X.nbytes + y.nbytes},

@@ -309,20 +309,21 @@ class GeneralizedLinearAlgorithm:
                             [w0, np.asarray([initial_intercept], np.float32)])
             self._auto_plan(X, y)
             weights = self.optimizer.optimize((X, y), w0)
-            intercept = 0.0
-            if self.add_intercept:
-                intercept = float(weights[-1])
-                weights = weights[:-1]
-            if scaler is not None:
-                # Same trick as the reference: transform() maps trained
-                # weights back to original space (margin w'.(x/std) ==
-                # (w'/std).x); flat stacked (multinomial) weights go
-                # block-wise.
-                d = int(np.asarray(scaler.std).shape[0])
-                weights = scaler.transform(
-                    jnp.asarray(weights).reshape(-1, d)
-                ).reshape(jnp.asarray(weights).shape)
-            return self.create_model(weights, intercept)
+            with span("fit.finish"):
+                intercept = 0.0
+                if self.add_intercept:
+                    intercept = float(weights[-1])
+                    weights = weights[:-1]
+                if scaler is not None:
+                    # Same trick as the reference: transform() maps trained
+                    # weights back to original space (margin w'.(x/std) ==
+                    # (w'/std).x); flat stacked (multinomial) weights go
+                    # block-wise.
+                    d = int(np.asarray(scaler.std).shape[0])
+                    weights = scaler.transform(
+                        jnp.asarray(weights).reshape(-1, d)
+                    ).reshape(jnp.asarray(weights).shape)
+                return self.create_model(weights, intercept)
 
     @staticmethod
     def _scale_features(X, w0):

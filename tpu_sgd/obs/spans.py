@@ -50,11 +50,22 @@ profiler's own ``TraceAnnotation.is_enabled()``, which the program can
 observe) every span is ALSO entered as a
 ``jax.profiler.TraceAnnotation(name, **attrs)`` on the entering thread,
 so it lands on the host plane of the same ``.xplane.pb`` as the device's
-lines, on the same clock, and an idle gap of the device can be put down
-to the span that covers it.  No ``obs.enable`` is needed for that: with
-the JSONL gate closed and a session active ``span()`` returns the bare
-annotation; with the gate open ``_Span`` enters the annotation too, so
-both records carry the same name.
+lines.  The same FILE, not the same clock: the host's clock and the
+device's differ by a constant of up to ~2 ms that changes from one
+profiler session to the next (PERF.md section 3, read off the ledger), so
+a consumer compares DURATIONS — a span's on the host's clock, an
+operation's or a launch's on the device's — and never a timestamp of one
+with a timestamp of the other.  No ``obs.enable`` is needed for the
+annotations: with the JSONL gate closed and a session active ``span()``
+returns the bare annotation; with the gate open ``_Span`` enters the
+annotation too, so both records carry the same name.
+
+``span()`` returns one of three types, and ``live`` says of each whether
+it keeps attributes: False on the no-op singleton, True on the
+annotation and on ``_Span``.  A caller whose attributes cost something
+to work out (``train.run``'s description of the step's kernel, the
+hand-off's stall clock) works them out only where ``live`` holds, so
+that the disabled path stays one global load and one flag read.
 """
 
 from __future__ import annotations
@@ -122,6 +133,9 @@ class _NoopSpan:
 
     __slots__ = ()
 
+    #: nothing keeps what ``set`` is given: do not work it out
+    live = False
+
     def __enter__(self):
         return self
 
@@ -143,6 +157,8 @@ class _Annotation(TraceAnnotation):
     """A span with the JSONL gate closed and a profiler session active:
     the profiler's own annotation, plus the span API's ``set``."""
 
+    live = True
+
     def set(self, **attrs):
         self.set_metadata(**attrs)
         return self
@@ -151,6 +167,8 @@ class _Annotation(TraceAnnotation):
 class _Span:
     __slots__ = ("name", "attrs", "span_id", "parent_id", "ts", "t0",
                  "_annotation")
+
+    live = True
 
     def __init__(self, name: str, attrs: dict):
         self.name = name

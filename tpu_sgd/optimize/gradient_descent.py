@@ -31,7 +31,9 @@ stance):
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
+import time
 from typing import Optional, Tuple
 
 import jax
@@ -95,8 +97,15 @@ def _coerce_w0(gradient, initial_weights, n_features):
 #: 10.6 GB/s, under the wire's 14.3, so the wire is kept busy only by
 #: several blocks re-tiling at once; 16 x 32 MiB reads 0.31 s against 0.69 s
 #: for the one piece and 0.60 s for 2 x 256 MiB, and holds 0.52 GB beside
-#: the dataset.  A block is far under 4 GiB, above which a host array is
-#: copied 24 times slower.
+#: the dataset.  At these sizes the WIRE bounds the copy, not the host's
+#: issue of the blocks (PR 37, ``train.h2d``'s ``stall_ms``): the fit's
+#: thread spends 1.5 to 1.8 ms in its own calls a block (``jnp.asarray`` of
+#: the row block, the write's dispatch, the delete) and then stands 0.5 to
+#: 0.8 ms in the flow-control wait, 56 to 92 ms of the 291 it is in
+#: ``_stage_dense``, because the wire takes 2.29 ms for the block; a faster
+#: issue would lengthen the wait, not shorten the copy (a host 1.3 times
+#: slower would make the issue the bound).  A block is far under 4 GiB,
+#: above which a host array is copied 24 times slower.
 _STAGE_BLOCK_BYTES = 32 << 20
 _STAGE_IN_FLIGHT = 16
 _STAGE_ROWS = 1024
@@ -121,7 +130,7 @@ def _stage_block(dest, block, offset):
         return dest, dest[offset, 0]
 
 
-def _stage_dense(X):
+def _stage_dense(X, h2d):
     """Dense features on the device as ONE ``(N, d)`` array, and what the
     ``train.h2d`` span says of the copy: ``(X, blocks, block_bytes)``.
 
@@ -133,9 +142,17 @@ def _stage_dense(X):
     the host waits for the oldest write before it issues a block beyond
     ``_STAGE_IN_FLIGHT``, so the device holds the dataset plus the blocks
     in flight, never the dataset twice.  The values are ``jnp.asarray``'s
-    (each block IS one), so the fit is the single copy's bit for bit."""
+    (each block IS one), so the fit is the single copy's bit for bit.
+
+    Where ``h2d`` (the ``train.h2d`` span) is ``live`` it is given
+    the hand-off's stall counter: ``stalls``, the times the host stood in
+    that flow-control wait, and ``stall_ms``, how long in all (0 and 0 for
+    one piece or a device array).  Otherwise the loop reads no clock."""
     import numpy as np
 
+    timed = h2d.live
+    if timed:
+        h2d.set(stalls=0, stall_ms=0.0)
     if not isinstance(X, np.ndarray):
         return jnp.asarray(X), 0, 0
     n = rows = 0
@@ -146,16 +163,23 @@ def _stage_dense(X):
     if rows >= n:  # one block holds it all
         return jnp.asarray(X), 1, X.nbytes
     dest, writes = None, collections.deque()
+    stalls, stall_s = 0, 0.0
     for a in range(0, n, rows):
         if len(writes) == _STAGE_IN_FLIGHT:
             # flow control, not a fetch: bounds what the device holds
+            t = time.perf_counter() if timed else 0.0
             writes.popleft().block_until_ready()
+            if timed:
+                stall_s += time.perf_counter() - t
+                stalls += 1
         block = jnp.asarray(X[a:a + rows])
         if dest is None:
             dest = _stage_dest(X.shape, block.dtype)
         dest, written = _stage_block(dest, block, a)
         block.delete()
         writes.append(written)
+    if timed:
+        h2d.set(stalls=stalls, stall_ms=round(stall_s * 1e3, 4))
     return dest, -(-n // rows), rows * row_bytes
 
 
@@ -1009,6 +1033,9 @@ GRAFTLINT_MEMO = {
         # knobs the compiled prefix programs bake in
         "X", "y", "gram_aligned", "gram_batch_rows", "gram_block_rows",
         "ingest_pipeline", "ingest_prefetch_depth", "ingest_wire_dtype",
+        # ``_select``'s view of (X, y) as ``_place`` laid them on the mesh:
+        # ``with_valid`` and the sharded statistics' block rows come from it
+        "placed",
     ),
 }
 
@@ -1654,7 +1681,7 @@ class GradientDescent(Optimizer):
                 a.nbytes for a in (X, y)
                 if isinstance(a, np.ndarray))) as h2d:
             if not sparse_X:
-                X, blocks, block_bytes = _stage_dense(X)
+                X, blocks, block_bytes = _stage_dense(X, h2d)
                 h2d.set(blocks=blocks, block_bytes=block_bytes)
                 if not jnp.issubdtype(X.dtype, jnp.inexact):
                     # int/bool features (one-hot etc.)
@@ -1673,48 +1700,91 @@ class GradientDescent(Optimizer):
             warnings.warn(
                 "The miniBatchFraction is too small", RuntimeWarning, stacklevel=3
             )
-        gram = self._maybe_gram(X, y, sparse_X)
-        if gram is not None:
-            # The stats ride as the X argument (GramData pytree) so they
-            # enter the jit program as buffers, not closure constants.
-            orig, self.gradient = self.gradient, gram
-            try:
-                return self._optimize_routed(gram.data, y, w0, sparse_X,
-                                             run_span)
-            finally:
-                self.gradient = orig
         return self._optimize_routed(X, y, w0, sparse_X, run_span)
 
     def _optimize_routed(self, X, y, w0, sparse_X, run_span):
         """Resident-data path routing (single-device / mesh / sparse /
-        stepwise), after input coercion and the optional sufficient-stats
-        substitution."""
+        stepwise), after input coercion.  The fused fit's leaves tile it:
+        ``train.place`` (a 1-D mesh alone), then ``train.select`` — the
+        sufficient-stats substitution, the route, the compiled runner's
+        lookup and what ``train.run`` says of the step — up to the start of
+        ``train.dispatch``, and ``train.fetch`` to the return."""
         import numpy as np
 
+        if self.listener is not None or self.checkpoint_manager is not None:
+            with self._substituted(X, y, sparse_X) as X:
+                if (self.sufficient_stats and self.mesh is not None
+                        and not sparse_X):
+                    import warnings
+
+                    warnings.warn(
+                        "sufficient_stats is not applied on the meshed "
+                        "listener/checkpoint path (the observed "
+                        "per-iteration stepper uses the stock DP step); "
+                        "detach the listener or run single-device to "
+                        "combine them",
+                        RuntimeWarning,
+                        stacklevel=4,
+                    )
+                run_span.set(path="stepwise")
+                return self._optimize_stepwise(X, y, w0)
+        placed = None
+        if (not sparse_X and self.mesh is not None
+                and self._mesh_kind() == "dp"):
+            placed = self._place(X, y)
+        with span("train.select"), self._substituted(X, y, sparse_X) as X:
+            fn, args, built = self._select(X, y, w0, sparse_X, placed,
+                                           run_span)
+        with span("train.dispatch", built=int(built)):
+            w, losses, n_rec = fn(*args)
+            # the copies to the host ride behind the program, so the fit
+            # ends in ONE wait: the count's read wakes the host once and
+            # the loss history has landed beside it (in series they were
+            # two round trips after the chip was done).  w is returned on
+            # the device as before; its copy is started too, so that the
+            # caller's first read of it finds it landed and is no third
+            # round trip (the fit itself never reads it)
+            n_rec.copy_to_host_async()
+            losses.copy_to_host_async()
+            w.copy_to_host_async()
+        with span("train.fetch") as sp:
+            recorded = int(n_rec)
+            self._loss_history = np.asarray(losses)[:recorded]
+            sp.set(recorded=recorded, waits=1)
+            if self.check_numerics:
+                _raise_if_nonfinite(self._loss_history)
+        return w, self._loss_history
+
+    @contextlib.contextmanager
+    def _substituted(self, X, y, sparse_X):
+        """The sufficient-stats substitution for the length of the block,
+        where it applies (``_maybe_gram``): yields the X to train on.  The
+        stats ride as the X argument (GramData pytree) so they enter the
+        jit program as buffers, not closure constants; a runner built in
+        the block closes over the substituted gradient."""
         from tpu_sgd.ops.gram import GramData
 
-        if self.listener is not None or self.checkpoint_manager is not None:
-            if (self.sufficient_stats and self.mesh is not None
-                    and not sparse_X):
-                import warnings
+        gram = None if isinstance(X, GramData) \
+            else self._maybe_gram(X, y, sparse_X)
+        if gram is None:
+            yield X
+            return
+        orig, self.gradient = self.gradient, gram
+        try:
+            yield gram.data
+        finally:
+            self.gradient = orig
 
-                warnings.warn(
-                    "sufficient_stats is not applied on the meshed "
-                    "listener/checkpoint path (the observed per-iteration "
-                    "stepper uses the stock DP step); detach the listener "
-                    "or run single-device to combine them",
-                    RuntimeWarning,
-                    stacklevel=4,
-                )
-            run_span.set(path="stepwise")
-            return self._optimize_stepwise(X, y, w0)
-        # each route names its compiled runner and its arguments; ONE call
-        # below dispatches it.  A runner that had to be built (a new
-        # _run_cache entry) traces, lowers and compiles inside that call.
+    def _select(self, X, y, w0, sparse_X, placed, run_span):
+        """``train.select``'s work for a fused fit: ``(fn, args, built)`` —
+        each route names its compiled runner and its arguments, ONE call in
+        ``train.dispatch`` runs them; ``built`` where the runner is a new
+        ``_run_cache`` entry, which traces, lowers and compiles inside that
+        call.  Sets ``train.run``'s attributes where the span keeps them."""
+        from tpu_sgd.ops.gram import GramData
+
         cached = len(self._run_cache)
-        # only ``_runner``'s programs (make_run) prepare rows and are the
-        # kernel: (labels_prepared, row_tile, feature_blocks, mask_in_kernel)
-        kernel = 0, 0, 1, 0
+        runner = False  # ``_runner``'s program (make_run): the step's kernel
         if sparse_X and self.mesh is not None:
             # Distributed sparse: equal-nse BCOO blocks per shard, same
             # make_run body, psum over ICI (the treeAggregate-over-sparse-
@@ -1737,7 +1807,8 @@ class GradientDescent(Optimizer):
             path, args = "sparse_mesh", (w0, data, idx, yd)
             if with_valid:
                 args += (valid,)
-        elif self.mesh is not None and self._mesh_kind() == "dp_mp":
+        elif self.mesh is not None and placed is None:
+            # a 2-D data x model mesh: the one dense mesh nothing places
             from tpu_sgd.parallel.model_parallel import dp_mp_optimize
 
             if self.gradient.weight_dim(X.shape[1]) != X.shape[1]:
@@ -1750,7 +1821,7 @@ class GradientDescent(Optimizer):
             args = (self.gradient, self.updater, self.config, self.mesh,
                     w0, X, y)
         elif self.mesh is not None:
-            Xd, yd, valid = self._place(X, y)
+            Xd, yd, valid = placed
             stats = self._maybe_gram_dp(X, y, Xd, yd, valid)
             if stats is not None:
                 stats_leaves, block_rows = stats
@@ -1768,40 +1839,23 @@ class GradientDescent(Optimizer):
                     self._run_cache[key] = fn
                 args = (w0, Xd, yd, *stats_leaves)
             else:
-                fn = self._runner(with_valid=valid is not None)
+                fn, runner = self._runner(with_valid=valid is not None), True
                 args = (w0, Xd, yd) if valid is None else (w0, Xd, yd, valid)
-                kernel = self._step_kernel(*args)
             path = "mesh"
         else:
-            fn = self._runner(with_valid=False)
+            fn, runner = self._runner(with_valid=False), True
             path = "gram" if isinstance(X, GramData) else "fused"
             args = (w0, X, y)
-            kernel = self._step_kernel(*args)
-        run_span.set(
-            path=path,
-            shards=1 if self.mesh is None else self.mesh.devices.size,
-            labels_prepared=kernel[0], row_tile=kernel[1],
-            feature_blocks=kernel[2], mask_in_kernel=kernel[3])
-        with span("train.dispatch",
-                  built=int(len(self._run_cache) > cached)):
-            w, losses, n_rec = fn(*args)
-            # the copies to the host ride behind the program, so the fit
-            # ends in ONE wait: the count's read wakes the host once and
-            # the loss history has landed beside it (in series they were
-            # two round trips after the chip was done).  w is returned on
-            # the device as before; its copy is started too, so that the
-            # caller's first read of it finds it landed and is no third
-            # round trip (the fit itself never reads it)
-            n_rec.copy_to_host_async()
-            losses.copy_to_host_async()
-            w.copy_to_host_async()
-        with span("train.fetch") as sp:
-            recorded = int(n_rec)
-            self._loss_history = np.asarray(losses)[:recorded]
-            sp.set(recorded=recorded, waits=1)
-        if self.check_numerics:
-            _raise_if_nonfinite(self._loss_history)
-        return w, self._loss_history
+        if run_span.live:
+            # (labels_prepared, row_tile, feature_blocks, mask_in_kernel):
+            # evaluated only where a span carries them
+            kernel = self._step_kernel(*args) if runner else (0, 0, 1, 0)
+            run_span.set(
+                path=path,
+                shards=1 if self.mesh is None else self.mesh.devices.size,
+                labels_prepared=kernel[0], row_tile=kernel[1],
+                feature_blocks=kernel[2], mask_in_kernel=kernel[3])
+        return fn, args, len(self._run_cache) > cached
 
     def _step_kernel(self, w0, X, y, valid=None):
         """``train.run``'s ``(labels_prepared, row_tile, feature_blocks,
