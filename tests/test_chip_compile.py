@@ -141,8 +141,10 @@ def test_whole_run_fusions_keep_the_step_scopes(S, d):
     (d = 1000) the one-read kernel is selected and its custom call carries
     ``sgd.fused_sums``, the weights' broadcast in front of it
     ``sgd.margins`` and the fold of its lane partials ``sgd.gradient``; where it stores X by rows (d = 1024 pads nothing
-    either way) one fusion still carries ``sgd.margins`` and one
-    ``sgd.gradient``, the two reads of all of X, and there is no kernel."""
+    either way) the step is the by-rows kernel since PR 39: its custom call
+    carries ``sgd.fused_sums`` in a jitted function of its own name, the
+    vector goes in as rows (no broadcast along the lanes) and no fusion
+    carries ``sgd.margins`` or ``sgd.gradient``: the two reads are gone."""
     import re
 
     from tpu_sgd.optimize.gradient_descent import make_run
@@ -163,8 +165,9 @@ def test_whole_run_fusions_keep_the_step_scopes(S, d):
         assert "sgd.margins" in scopes("broadcast")
         assert "sgd.gradient" in scopes("reduce")
     else:
-        assert "tpu_custom_call" not in text
-        assert {"sgd.margins", "sgd.gradient"} <= scopes("fusion")
+        assert scopes("custom-call") == {"sgd.fused_sums"}
+        assert "_fused_rows_sums" in text
+        assert not {"sgd.margins", "sgd.gradient"} & scopes("fusion")
 
 
 #: instructions that hand an array on without moving it
@@ -486,13 +489,6 @@ MASKED_PROGRAMS = {
 def test_the_masked_cells_lowered_program_is_the_pinned_one(cell):
     """Lowered from this CPU process for a TPU (nothing is compiled, so no
     described chip is needed)."""
-    import base64
-    import hashlib
-    import re
-
-    from jax._src.interpreters import mlir as jax_mlir
-    from jax._src.lib.mlir import ir
-
     from tpu_sgd.optimize.gradient_descent import make_run
 
     shape = jax.ShapeDtypeStruct
@@ -511,9 +507,22 @@ def test_the_masked_cells_lowered_program_is_the_pinned_one(cell):
             LogisticGradient(), SquaredL2Updater(),
             _cfg(step_size=5.0, num_iterations=100, reg_param=0.001,
                  convergence_tol=0.0)))
-    text = fn.trace(shape((D,), F32), shape((n, D), BF16),
-                    shape((n,), F32)).lower(
-                        lowering_platforms=("tpu",)).as_text()
+    assert _program_digests(fn, shape((D,), F32), shape((n, D), BF16),
+                            shape((n,), F32)) == MASKED_PROGRAMS[cell]
+
+
+def _program_digests(fn, *shapes):
+    """``(StableHLO outside the Mosaic call, the call's body without
+    locations)`` of ``fn`` lowered from this CPU process for a TPU, as 16
+    hex digits of sha256 each."""
+    import base64
+    import hashlib
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    text = fn.trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
     body = r"\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22"
     serialized, = re.findall(body, text)
     with jax_mlir.make_ir_context() as context:
@@ -524,8 +533,51 @@ def test_the_masked_cells_lowered_program_is_the_pinned_one(cell):
     def digest(s):
         return hashlib.sha256(s.encode()).hexdigest()[:16]
 
-    assert (digest(re.sub(body, '"body": ""', text)), digest(kernel)) \
-        == MASKED_PROGRAMS[cell]
+    return digest(re.sub(body, '"body": ""', text)), digest(kernel)
+
+
+#: the same pair for the cells whose step is no masked scan: the windowed
+#: cell's (PR 31's grid on PR 36's body), the class cell's and the wide
+#: cell's (PR 32's and PR 34's, the labels laid out by PR 33), all three as
+#: PR 37 left them and as PR 39 found and kept them while it gave the class
+#: body its by-rows orientation; and the CIFAR-5m cell's by-rows program,
+#: which is PR 39's.
+#: A PR that means to change one of these steps changes its pair here.
+OTHER_PROGRAMS = {
+    "sliced": ("e3280a2b1fc6b199", "40c8672db62c8475"),
+    "classes": ("13db878507830ea7", "29123780d775e1cd"),
+    "wide": ("093108cc0f17cb39", "e267abd75882cd0a"),
+    "cifar5m": ("222dd9de2b854bcb", "20c7220b35aa989c"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(OTHER_PROGRAMS))
+def test_the_other_cells_lowered_program_is_the_pinned_one(cell):
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    base = dict(num_iterations=100, convergence_tol=0.0)
+    grad, upd, cfg, n, d, wd = {
+        "sliced": (LogisticGradient(), SquaredL2Updater(),
+                   _cfg(step_size=5.0, reg_param=0.001, sampling="sliced",
+                        **base), 4_194_304, D, D),
+        "classes": (MultinomialLogisticGradient(10), SquaredL2Updater(),
+                    _cfg(step_size=1.0, reg_param=0.001,
+                         mini_batch_fraction=1.0, **base),
+                    8_100_000, 784, 9 * 784),
+        "wide": (HingeGradient(), L1Updater(),
+                 _cfg(step_size=100.0, reg_param=1e-5,
+                      mini_batch_fraction=1.0, **base),
+                 131_072, 47_236, 47_236),
+        "cifar5m": (MultinomialLogisticGradient(10), SquaredL2Updater(),
+                    _cfg(step_size=1.0, reg_param=0.001,
+                         mini_batch_fraction=1.0, **base),
+                    2_000_896, 3072, 9 * 3072),
+    }[cell]
+    shape = jax.ShapeDtypeStruct
+    assert _program_digests(
+        jax.jit(make_run(grad, upd, cfg)), shape((wd,), F32),
+        shape((n, d), BF16), shape((n,), F32)) == OTHER_PROGRAMS[cell]
 
 
 #: (rows, features): what ``feature_major`` must say of each, and the
@@ -540,6 +592,10 @@ LAYOUTS = {
     "few_rows": (BF16, 1024, D), "d1001": (F32, N, 1001),
     "narrow": (F32, 300, 24), "mnist8m": (BF16, 8_100_000, 784),
     "rcv1_dense": (BF16, 131_072, 47_236),
+    # PR 39: 3,072 pixels (the CIFAR-5m cell's rows; LIBSVM SVHN's, whose
+    # count is no multiple of 128) and an embedding's width: by rows
+    "cifar5m": (BF16, 2_000_896, 3072), "svhn": (BF16, 604_388, 3072),
+    "bf16_d768": (BF16, N, 768),
 }
 
 
@@ -562,10 +618,11 @@ def test_feature_major_is_the_layout_the_compiler_gives(S, case):
     assert layout_of(lambda X, w: jnp.dot(
         X, w.astype(X.dtype), preferred_element_type=F32),
         S((d,), F32)) == expect
-    if n * d <= N * D:  # a gather of rows copies X: keep the copy small
+    if n * d <= N * 1024:  # a gather of rows copies X: keep the copy small
         assert layout_of(lambda X, idx: X[idx], S((1000,), I32)) == expect
     assert feature_major(n, d) == (case not in (
-        "bf16_d1024", "bf16_d128", "square_tie"))
+        "bf16_d1024", "bf16_d128", "square_tie", "cifar5m", "svhn",
+        "bf16_d768"))
 
 
 def test_superstep_k8_compiles(S):
@@ -984,3 +1041,165 @@ def test_the_class_kernels_vmem_count_admits_what_the_compiler_admits(
     _check_fm_vmem(tile, X, masked, rows)
     assert "tpu_custom_call" in _lower_classes(
         S, dtype, classes, masked, tile).compile().as_text()
+
+
+# -- the by-rows form: row blocks of an X the chip stores by rows (PR 39) -------
+
+#: the cell's rows, width and classes (bench/configs/
+#: cifar5m-multinomial.json), and a vector's shape at a hashed space's width
+CIFAR5M = (2_000_896, 3072, 10)
+ROWS_VECTOR = (2_097_152, 1024)
+
+
+@pytest.mark.parametrize("case", ["cifar5m_classes", "vector_full_batch",
+                                  "vector_bernoulli"])
+def test_by_rows_run_at_the_cells_shape_reads_x_once_where_it_lies(S, case):
+    """``cifar5m-multinomial.resident-classes`` (2,000,896 x 3,072 bf16 rows, a
+    ``(9, 3072)`` matrix of weights, fraction 1.0) and a vector of weights
+    at 2,097,152 x 1,024 (a full batch, and a Bernoulli mask that the step
+    draws as an array): ONE Mosaic call a step in the by-rows form's own
+    jitted function, under the scope its feature-major sibling has; X goes
+    to it AS THE PARAMETER LIES, ``{1,0}``: no bitcast to ``X.T``, no copy
+    or transpose of X's size in front (the verdict PR 30 deleted the row
+    kernels on was a ``copy(X)`` at d = 1000), no ``(n, classes)`` array
+    between two products, temporaries under 1% of X."""
+    import re
+
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    if case == "cifar5m_classes":
+        n, d, K = CIFAR5M
+        grad, wd, scope, fn = (MultinomialLogisticGradient(K), (K - 1) * d,
+                               "sgd.class_sums", "_fused_rows_class_sums")
+    else:
+        n, d = ROWS_VECTOR
+        grad, wd, scope, fn = (LogisticGradient(), d, "sgd.fused_sums",
+                               "_fused_rows_sums")
+    cfg = _cfg(step_size=1.0, num_iterations=100, reg_param=0.001,
+               convergence_tol=0.0, mini_batch_fraction=0.1
+               if case == "vector_bernoulli" else 1.0)
+    compiled = jax.jit(make_run(grad, SquaredL2Updater(), cfg)).lower(
+        S((wd,), F32), S((n, d), BF16), S((n,), F32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert scope in call and fn in call
+    assert "operand_layout_constraints={bf16[%d,%d]{1,0}, " % (n, d) in call
+    assert compiled.input_formats[0][1].layout.major_to_minor == (0, 1)
+    assert _moves_of(text, n, d) == []
+    assert "bf16[%d,%d]" % (d, n) not in text  # no X.T, not even a bitcast
+    # the weights go in as 16 rows in X's type, the gradient comes out as
+    # 16 rows in f32; X and the row operands aside no 2-D array has n rows
+    assert "bf16[16,%d]" % d in call and "f32[16,%d]" % d in call
+    rest = re.sub(r"\w+\[1,%d\]" % n, "",  # the labels' row, a mask's
+                  text.replace("bf16[%d,%d]" % (n, d), ""))
+    assert not re.search(r"\[%d,\d+\]|\[\d+,%d\]" % (n, n), rest)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < n * d * 2 // 100
+    assert memory.argument_size_in_bytes < n * d * 2 * 1.01
+
+
+def _lower_rows(S, n, d, dtype, classes, masked, tile_m, limit=None):
+    """The by-rows call at a tile and under a compiler limit of the
+    test's (None: the one the kernel asks for)."""
+    from tpu_sgd.ops import pallas_kernels as PK
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+
+    limit = PK._FM_VMEM_LIMIT if limit is None else limit
+    args = [S((n, d), dtype), S((n,), F32)]
+    mask = [S((n,), jnp.bool_)] if masked else []
+    if classes is None:
+        return jax.jit(lambda X, y, w, m=None: PK._vector_sums(
+            HingeGradient().pointwise, X, y, w, m, tile_m, d, limit, False,
+            True)).lower(*args, S((d,), F32), *mask)
+    rows = PK.class_rows_of(classes - 1, dtype)
+    rule = MultinomialLogisticGradient(classes).class_rule
+    return jax.jit(lambda X, y, W, m=None: PK._class_call(
+        rule, X, y, W, m, tile_m, d, limit, False, True)).lower(
+            *args, S((rows, d), dtype), *mask)
+
+
+#: (type, d, classes or None for a vector, masked): the cell's ten classes, a
+#: vector at a hashed space's width under a mask, float32 embeddings, and
+#: a width whose own tile is one lane chunk's quarter
+ROWS_CASES = {"cifar5m": (BF16, 3072, 10, False),
+              "vector_1024_masked": (BF16, 1024, None, True),
+              "f32_768_classes": (F32, 768, 10, True),
+              "vector_4096_f32": (F32, 4096, None, False)}
+
+
+@pytest.mark.parametrize("case", sorted(ROWS_CASES))
+def test_the_by_rows_vmem_count_admits_what_the_compiler_admits(S, case):
+    """``_fm_vmem_bytes(by_rows=True)`` stays above the compiler's own
+    count: the form's own tile compiles when the compiler is asked for
+    exactly what the tile was counted at; a tile the count refuses under
+    the kernel's limit is refused by the compiler too; the largest tile
+    the count's hint names compiles."""
+    import re
+
+    from tpu_sgd.ops import pallas_kernels as PK
+
+    dtype, d, classes, masked = ROWS_CASES[case]
+    n = KERNEL_N
+    itemsize = jnp.dtype(dtype).itemsize
+    rows = (PK.class_rows_of(classes - 1, dtype) if classes
+            else PK.wide_rows_of(dtype)[1])
+    own = PK.fm_tile(n, d, itemsize, masked, rows if classes else 0)
+    assert own is not None and PK.by_rows_form(n, d)
+    counted = PK._fm_vmem_bytes(own, d, itemsize, masked, rows, by_rows=True)
+    assert counted <= PK._FM_VMEM_LIMIT
+    assert "tpu_custom_call" in _lower_rows(
+        S, n, d, dtype, classes, masked, own, counted).compile().as_text()
+    X = S((n, d), dtype)
+    with pytest.raises(ValueError, match=r"tile_m <= \d+") as refused:
+        PK._check_fm_vmem(16384, X, masked, rows, by_rows=True)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _lower_rows(S, n, d, dtype, classes, masked, 16384).compile()
+    tile = int(re.search(r"tile_m <= (\d+)", str(refused.value)).group(1))
+    assert own <= tile < 16384
+    PK._check_fm_vmem(tile, X, masked, rows, by_rows=True)
+    assert "tpu_custom_call" in _lower_rows(
+        S, n, d, dtype, classes, masked, tile).compile().as_text()
+
+
+def test_the_by_rows_entries_refuse_a_width_no_tile_fits_before_compiling(S):
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+    from tpu_sgd.ops.pallas_kernels import fused_class_sums, fused_rows_sums
+
+    n, d = 8192, 32_768
+    with pytest.raises(ValueError, match="too wide for this kernel"):
+        fused_rows_sums(HingeGradient().pointwise, S((n, d), BF16),
+                        S((n,), F32), S((d,), F32))
+    with pytest.raises(ValueError, match="too wide for this kernel"):
+        fused_class_sums(MultinomialLogisticGradient(10).class_rule,
+                         S((n, d), BF16), S((n,), F32), S((9, d), F32))
+
+
+def test_a_by_rows_shard_of_a_meshed_fit_takes_the_kernel_where_it_lies(
+        mesh4, S):
+    """A shard of a by-rows X is by rows: ``dp_run_fn`` at 4 x 524,288 x
+    1,024 trains every shard through the by-rows kernel in place (no copy
+    of a shard, one all-reduce a step)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpu_sgd.parallel.data_parallel import dp_run_fn
+    from tpu_sgd.parallel.mesh import DATA_AXIS
+
+    n, d = ROWS_VECTOR
+    fn = dp_run_fn(LeastSquaresGradient(), SimpleUpdater(),
+                   _cfg(step_size=1.0, num_iterations=100, reg_param=0.0,
+                        convergence_tol=0.0), mesh4, with_valid=False)
+    text = fn.lower(
+        S((d,), F32, NamedSharding(mesh4, P())),
+        S((n, d), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
+        S((n,), F32, NamedSharding(mesh4, P(DATA_AXIS))),
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "_fused_rows_sums" in call and "sgd.fused_sums" in call
+    assert "bf16[%d,%d]{1,0}" % (n // 4, d) in call
+    assert text.count(" all-reduce(") == 1
+    assert _moves_of(text, n // 4, d) == [] and _moves_of(text, n, d) == []

@@ -173,7 +173,10 @@ def _case(name):
     if name == "more_rows_than_one_pass":
         K = 200  # 199 rows pad to 208, over the matrix unit's 128
     elif name == "row_major_width":
-        d = 1024
+        d = 1024  # stored by rows: the by-rows form's (PR 39)
+        X = jnp.zeros((n, d), jnp.bfloat16)
+    elif name == "row_major_odd_width":
+        d = 1020  # by rows with padded lanes: no block of either form
         X = jnp.zeros((n, d), jnp.bfloat16)
     elif name == "two_classes":
         K = 2
@@ -182,8 +185,9 @@ def _case(name):
     return X, y, jnp.zeros(((K - 1) * d,)), mask, K
 
 
-ON = ["ten_classes", "two_classes"]
-OFF = ["a_vector_of_d_weights", "more_rows_than_one_pass", "row_major_width"]
+ON = ["ten_classes", "two_classes", "row_major_width"]
+OFF = ["a_vector_of_d_weights", "more_rows_than_one_pass",
+       "row_major_odd_width"]
 
 
 @pytest.mark.parametrize("case", ON + OFF)

@@ -93,9 +93,10 @@ def test_a_fit_through_run_emits_each_leaf_once_in_order(sink, data):
     _check_tiling(sink.records, RUN_LEAVES, {"fit.run", "train.run"})
     select, = sink.named("train.select")
     dispatch, = sink.named("train.dispatch")
-    # train.select carries no attribute: whether the cache held the
-    # runner is train.dispatch's ``built``
+    # train.select says which form the step's kernel is (PR 39), nothing
+    # else: whether the cache held the runner is train.dispatch's ``built``
     assert "cached" not in select and dispatch["built"] == 1
+    assert select["by_rows"] == 0
     # fit.finish is fit.run's own, after the optimizer has returned
     finish, = sink.named("fit.finish")
     run, = sink.named("train.run")

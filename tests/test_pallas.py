@@ -394,6 +394,11 @@ def _selection_case(name):
     elif name == "row_major_width":
         X = jnp.zeros((n, 1024), jnp.bfloat16)  # pads nothing by rows
         w = jnp.zeros((1024,))
+    elif name == "row_major_odd_width":
+        # stored by rows (1020 pads to 1024 either way) with padded lanes:
+        # no by-rows block, and a block of X.T would be handed a copy
+        X = jnp.zeros((n, 1020), jnp.bfloat16)
+        w = jnp.zeros((1020,))
     elif name in ("too_wide", "wide"):
         # RCV1's width: the wide form; ten times it: no form's
         d = {"wide": 47_236, "too_wide": 472_364}[name]
@@ -408,22 +413,24 @@ def _selection_case(name):
 
 
 OFF = ["bcoo", "matrix_weights", "margin_axis_name", "integer_rows",
-       "row_major_width", "too_wide", "labels_per_class"]
+       "row_major_odd_width", "too_wide", "labels_per_class"]
 
 
-@pytest.mark.parametrize("case", ["feature_major", "wide"] + OFF)
+@pytest.mark.parametrize("case", ["feature_major", "wide",
+                                  "row_major_width"] + OFF)
 def test_one_read_sums_follows_what_the_operands_look_like(case):
     from tpu_sgd.ops.gradients import one_read_blocks, one_read_sums
 
     X, y, w, mask, axis = _selection_case(case)
-    on = case in ("feature_major", "wide")
+    on = case in ("feature_major", "wide", "row_major_width")
     assert one_read_sums(X, y, w, mask, axis) == on
     assert one_read_sums(X, y, w, None, axis) == on
-    # the window's kernel has no wide form
+    # the window's kernel has no wide form and no by-rows form
     assert one_read_sums(X, y, w, None, axis, window=True) == (
         case == "feature_major")
     assert one_read_blocks(X, y, w, mask, axis) == {
-        "feature_major": (1024, 1), "wide": (256, 8)}.get(case)
+        "feature_major": (1024, 1), "wide": (256, 8),
+        "row_major_width": (1024, 1)}.get(case)
 
 
 def _lowered_for(platform, fn, *args):
@@ -453,7 +460,7 @@ def test_batch_sums_lowers_the_kernel_for_a_tpu_and_two_matvecs_here(g):
     assert "sgd.fused_sums" not in cpu and "sgd.margins" in cpu
 
 
-@pytest.mark.parametrize("case", ["row_major_width", "integer_rows",
+@pytest.mark.parametrize("case", ["row_major_odd_width", "integer_rows",
                                   "too_wide"])
 def test_batch_sums_keeps_two_matvecs_on_a_tpu_where_the_kernel_is_off(case):
     X, y, w, mask, _ = _selection_case(case)
@@ -502,8 +509,9 @@ def test_window_sums_lowers_the_kernel_for_a_tpu_and_two_matvecs_here(
     assert "stablehlo.dynamic_slice" in cpu
 
 
-@pytest.mark.parametrize("case", ["row_major_width", "integer_rows",
-                                  "too_wide", "margin_axis_name", "wide"])
+@pytest.mark.parametrize("case", ["row_major_width", "row_major_odd_width",
+                                  "integer_rows", "too_wide",
+                                  "margin_axis_name", "wide"])
 def test_window_sums_keeps_two_matvecs_on_a_tpu_where_the_kernel_is_off(case):
     import jax
     import jax.numpy as jnp
@@ -791,7 +799,8 @@ def test_rows_prepared_is_false_where_no_kernel_reads_them(case):
     elif case == "bcoo":
         X, _, _, _, _ = _selection_case("bcoo")
     elif case == "row_major_width":
-        X, w = jnp.zeros((n, 1024), jnp.bfloat16), jnp.zeros(1024)
+        # by rows with padded lanes: no by-rows block (1024 has one, PR 39)
+        X, w = jnp.zeros((n, 1020), jnp.bfloat16), jnp.zeros(1020)
     elif case == "feature_sharded":
         axis = "model"
     elif case == "statistics":
@@ -815,7 +824,9 @@ def test_train_run_says_whether_the_labels_were_prepared(backend,
     """``train.run``'s ``labels_prepared``: 1 where the fit's program lays
     the labels out before its loop for the kernel (a TPU, the one-read
     step; a shard's operands under a mesh), 0 where the step takes ``y``
-    as it is: every fit on a CPU, a gathered batch, rows stored by rows."""
+    as it is: every fit on a CPU, a gathered batch, rows stored by rows at
+    a width that is no multiple of 128 (at one that is, the by-rows kernel
+    reads the row: PR 39)."""
     import jax
 
     import tpu_sgd
@@ -831,6 +842,7 @@ def test_train_run_says_whether_the_labels_were_prepared(backend,
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     X, y, _ = _data(n=512, d=24, seed=51, classify=True)
     wide = _data(n=512, d=128, seed=52)[0]  # (512, 128): stored by rows
+    odd = _data(n=512, d=124, seed=53)[0]  # by rows too, lanes padded
 
     def fit(X, mesh=None, **kw):
         opt = tpu_sgd.GradientDescent(
@@ -849,17 +861,20 @@ def test_train_run_says_whether_the_labels_were_prepared(backend,
         fit(X, sampling="sliced")
         fit(X, mesh=tpu_sgd.data_mesh(jax.devices()[:4]))
         fit(X, sampling="indexed")
+        fit(odd)
         fit(wide)
     finally:
         disable_tracing()
     runs = [p for k, p in sink.records
             if k == "trace_span" and p["name"] == "train.run"]
     assert [r["path"] for r in runs] == ["fused", "fused", "mesh", "fused",
-                                         "fused"]
+                                         "fused", "fused"]
     assert [r["labels_prepared"] for r in runs] == (
-        [1, 1, 1, 0, 0] if backend == "tpu" else [0] * 5)
+        [1, 1, 1, 0, 0, 1] if backend == "tpu" else [0] * 6)
     # the rows a grid step of the step's kernel takes (a shard's 128 under
     # the mesh), 0 where the step is no kernel; the width is never cut
     assert [r["row_tile"] for r in runs] == (
-        [512, 512, 128, 0, 0] if backend == "tpu" else [0] * 5)
-    assert [r["feature_blocks"] for r in runs] == [1] * 5
+        [512, 512, 128, 0, 0, 512] if backend == "tpu" else [0] * 6)
+    assert [r["feature_blocks"] for r in runs] == [1] * 6
+    assert [r["by_rows"] for r in runs] == (
+        [0, 0, 0, 0, 0, 1] if backend == "tpu" else [0] * 6)

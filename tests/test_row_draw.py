@@ -413,5 +413,8 @@ def test_train_run_says_whether_the_kernel_draws_the_mask(backend, how,
         [1, 1, 0, 0, 0, 0] if (backend, how) == ("tpu", "counter")
         else [0] * 6)
     # the kernel is the step either way: the rule moves the draw alone
+    # (over rows stored by rows the class body, which reads an array: PR 39)
     assert [r["row_tile"] for r in runs] == (
-        [512, 128, 512, 0, 512, 0] if backend == "tpu" else [0] * 6)
+        [512, 128, 512, 0, 512, 512] if backend == "tpu" else [0] * 6)
+    assert [r["by_rows"] for r in runs] == (
+        [0, 0, 0, 0, 0, 1] if backend == "tpu" else [0] * 6)

@@ -132,8 +132,11 @@ def one_read_blocks(X, y, weights, mask=None, margin_axis_name=None,
     ``batch_sums`` of these operands takes where the program is lowered
     for a TPU (``window``: ``window_sums``), None where it takes two reads
     — decided from what the operands look like, nothing else: dense 2-D
-    bf16 or f32 rows that the chip stores feature-major (so ``X.T`` is a
-    bitcast and no copy of X stands in front of the kernel), a flat
+    bf16 or f32 rows whose blocks the kernel can take in the order the
+    chip stores them, so that no copy of X stands in front of it (stored
+    feature-major: ``X.T`` is a bitcast; stored by rows at a width that is
+    a multiple of 128: the class body's by-rows form over X itself,
+    :func:`by_rows`), a flat
     weight vector (``classes`` None: one entry a feature; a class count:
     the row-major flattening of a ``(classes - 1, d)`` matrix whose rows,
     padded to whole packed registers, are no more than one pass of the
@@ -144,7 +147,9 @@ def one_read_blocks(X, y, weights, mask=None, margin_axis_name=None,
     128 lanes in f32 or beside the class rows (one feature block), or,
     for a vector of weights too wide for that, in the wide form
     (``pallas_kernels.fm_wide``: weights as rows, the width in feature
-    blocks), which the window's kernel does not have.  A mask the kernel
+    blocks), which the window's kernel does not have; nor has it a
+    by-rows form, and the by-rows form none in feature blocks (such
+    windows and widths stay two reads).  A mask the kernel
     draws itself (:class:`RowDraw`) is no operand: its ``valid`` is."""
     if isinstance(mask, RowDraw):
         mask = mask.valid
@@ -157,17 +162,28 @@ def one_read_blocks(X, y, weights, mask=None, margin_axis_name=None,
                                 and jnp.shape(mask) != (n,)):
         return None
     from tpu_sgd.ops.pallas_kernels import (FM_CLASS_ROWS, class_rows_of,
-                                            feature_major, fm_blocks)
+                                            fm_blocks)
 
     rows = 0
     if classes is not None:
         rows = class_rows_of(classes - 1, X.dtype)
         if rows > FM_CLASS_ROWS or jnp.shape(weights) != ((classes - 1) * d,):
             return None
-    if not feature_major(n, d):
-        return None
     blocks = fm_blocks(n, d, X.dtype.itemsize, mask is not None, rows)
-    return None if blocks is None or (window and blocks[1] > 1) else blocks
+    if blocks is None or (window and (blocks[1] > 1 or by_rows(X))):
+        return None
+    return blocks
+
+
+def by_rows(X) -> bool:
+    """Whether the one-read kernel of a dense ``X`` (where
+    :func:`one_read_blocks` gives it one) is the by-rows form: the class
+    body over row blocks of an X the chip stores by rows
+    (``pallas_kernels.by_rows_form``), for a matrix of weights and for a
+    vector alike.  From the shape alone."""
+    from tpu_sgd.ops.pallas_kernels import by_rows_form
+
+    return by_rows_form(*jnp.shape(X))
 
 
 def one_read_sums(X, y, weights, mask=None, margin_axis_name=None,
@@ -259,13 +275,14 @@ class Gradient:
         one-read kernel's vector body with all d in one feature block if
         the program is lowered for a TPU (:meth:`kernel_blocks`), and the
         draw is a counter's (:func:`counter_draws`).  Everywhere else (two
-        reads, BCOO, a feature-sharded run, the wide form, another PRNG)
+        reads, BCOO, a feature-sharded run, the wide and by-rows forms,
+        which are the class body, another PRNG)
         the step draws the array it always drew.  From shapes, types and
         JAX's configuration alone, so the host can ask it too."""
         tile, feature_blocks = self.kernel_blocks(
             X, y, weights, valid, margin_axis_name)
-        return (tile > 0 and feature_blocks == 1 and counter_draws()
-                and jnp.shape(X)[0] < 2**31)
+        return (tile > 0 and feature_blocks == 1 and not by_rows(X)
+                and counter_draws() and jnp.shape(X)[0] < 2**31)
 
     def kernel_blocks(self, X, y, weights, mask=None, margin_axis_name=None,
                       window: Optional[int] = None) -> Tuple[int, int]:
@@ -313,19 +330,23 @@ class Gradient:
                 None if valid is None else row_operand(valid, n))
 
     def _fused_sums(self, X, y, weights, mask, rows=None):
-        """One read of X: the Pallas kernel over the feature-major blocks
-        the chip already stores (``ops/pallas_kernels.py``), in the wide
-        form where the width asks for it (``fm_wide``), under a scope of
-        its own."""
+        """One read of X: the Pallas kernel over the blocks the chip
+        already stores (``ops/pallas_kernels.py``): of ``X.T`` where it
+        stores X feature-major, in the wide form where the width asks for
+        it (``fm_wide``) under a scope of its own; of X itself where it
+        stores X by rows (:func:`by_rows`)."""
         from tpu_sgd.ops.pallas_kernels import (fm_wide, fused_gradient_sums,
+                                                fused_rows_sums,
                                                 fused_wide_sums)
 
         draw = None
-        if isinstance(mask, RowDraw):  # by draws_rows never the wide form
+        if isinstance(mask, RowDraw):  # by draws_rows the vector body's
             draw, mask = (mask.key, mask.fraction), mask.valid
-        wide = fm_wide(*X.shape, X.dtype.itemsize, mask is not None)
         y, mask = _kernel_rows(y, mask, rows)
-        if wide is not None:
+        if by_rows(X):
+            with jax.named_scope("sgd.fused_sums"):
+                return fused_rows_sums(self.pointwise, X, y, weights, mask)
+        if fm_wide(*X.shape, X.dtype.itemsize, mask is not None) is not None:
             with jax.named_scope("sgd.wide_sums"):
                 return fused_wide_sums(self.pointwise, X, y, weights, mask)
         with jax.named_scope("sgd.fused_sums"):
@@ -394,9 +415,10 @@ class Gradient:
         holds for ``(X, y, weights, valid)`` and the program is lowered for
         a TPU, the one-read kernel over the window's own blocks of ``X.T``
         at a scalar-prefetched block offset (X read where it lies, the
-        window once); everywhere else (a CPU, X stored by rows, a
-        feature-sharded run) the slice and two matvecs, which read the
-        window in place twice: the compiler fuses the slice into each.
+        window once); everywhere else (a CPU, X stored by rows: the by-rows
+        form has no window grid, a feature-sharded run) the slice and two
+        matvecs, which read the window in place twice: the compiler fuses
+        the slice into each.
         ``batch_sums`` of the sliced rows is NOT the way to one read: it
         would have the window copied out first (compiled for the described
         chip at 4,194,304 x 1000: an 841.5 MB temporary a step).
@@ -666,9 +688,14 @@ class MultinomialLogisticGradient(Gradient):
         """``Gradient.batch_sums`` for the flat ``(K-1) * D`` weights: the
         same selection from the operands and the lowering platform, no
         option.  One read: the class kernel, both products on the matrix
-        unit and :meth:`class_rule` between them in VMEM.  Two reads: two
-        matmuls with ``(n, K-1)`` margins and coefficients in HBM between
-        them (a CPU, X stored by rows, a feature-sharded run, BCOO)."""
+        unit and :meth:`class_rule` between them in VMEM, over blocks of
+        ``X.T`` where the chip stores X feature-major and over row blocks
+        of X itself where it stores X by rows at a multiple of 128
+        (embeddings, hashed spaces, 32 x 32 x 3 pixels: PERF.md, PR 39).
+        Two reads: two matmuls with ``(n, K-1)`` margins and coefficients
+        in HBM between them (a CPU, a feature-sharded run, BCOO, a by-rows
+        width that is no multiple of 128 or overflows the kernel's
+        VMEM)."""
         with jax.named_scope("sgd.class_sums"):
             if one_read_sums(X, y, weights, mask, margin_axis_name,
                              classes=self.num_classes):
@@ -691,7 +718,8 @@ class MultinomialLogisticGradient(Gradient):
         return False
 
     def _fused_sums(self, X, y, weights, mask, rows=None):
-        """One read of X (``ops/pallas_kernels.fused_class_sums``)."""
+        """One read of X (``ops/pallas_kernels.fused_class_sums``, which
+        takes the blocks in the order the chip stores X)."""
         from tpu_sgd.ops.pallas_kernels import fused_class_sums
 
         y, mask = _kernel_rows(y, mask, rows)

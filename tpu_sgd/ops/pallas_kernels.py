@@ -10,16 +10,20 @@ mini-batch gradient in ONE pass over X, where the XLA path reads X twice
 ``(n, d)`` array in whichever order of its two dimensions pads least under
 the ``(8, 128)`` tile (:func:`feature_major`): ``(n, 1000)`` is stored
 feature-major, ``{0,1:T(8,128)(2,1)}`` for bf16 — rows on the lanes, a row's
-1000 values ``n`` apart — and ``(n, 1024)`` by rows.  A kernel whose blocks
-do not follow the stored order is handed a relayout COPY of all of X by the
-compiler on every call (seen in the compiled program for a described v5e,
+1000 values ``n`` apart — and ``(n, 1024)`` by rows, as every width that is
+a multiple of 128 is (embeddings, hashed feature spaces, 3,072 pixels).  A
+kernel whose blocks do not follow the stored order is handed a relayout COPY
+of all of X by the compiler on every call (seen in the compiled program for a described v5e,
 ``tests/test_chip_compile.py``; PERF.md, PR 26): one read and one write of X
-in front of the kernel's own read.
+in front of the kernel's own read.  So the blocks follow the stored order.
 
 One family over the ``(d, tile)`` blocks of ``X.T``: a body for a vector
 of weights (``_fm_kernel``) under two grids, and a body for a matrix of
 them (``_fm_class_kernel``) under the first, which a vector too wide for
-the first body rides as rows.
+the first body rides as rows.  The second body has a BY-ROWS form too (PR
+38): over ``(tile, d)`` blocks of X itself where the chip stores X by rows,
+for a matrix of weights and for a vector as rows alike
+(:func:`by_rows_form`; "The by-rows form" below).
 :func:`fused_gradient_sums`: the full scan with an optional
 sampling mask (reference parity with ``RDD.sample``), read as a row or
 drawn in the kernel, over ``(d, tile)``
@@ -106,6 +110,26 @@ where a step hands the draw on in the mask's place; the class and wide
 bodies, and every explicit mask (a padded shard's ``valid``), read a
 ``(1, n)`` row as before.
 
+**The by-rows form (PR 39).**  Where the chip stores X by rows, d a multiple
+of 128, a ``(tile, d)`` block of X is ONE contiguous piece of ``tile * d``
+values and ``X.T`` would be the copy.  ``_fm_class_kernel(by_rows=True)``
+takes such blocks: the same two products with X as the right-hand operand in
+the same two orientations, swapped (the margins contract both operands on d,
+``(rows, d) . (lanes, d)^T``; the gradient is the plain ``(rows, lanes) @
+(lanes, d)``), while margins, labels, mask and coefficients stay ``(rows,
+lanes)`` / ``(1, lanes)`` arrays with X's rows on the lanes, so the rule, the
+folds, the row operands, the grid and the tail cut are the feature-major
+form's (in the cut block the rows past n are replaced along the block's
+first axis).  A matrix of weights goes in directly
+(:func:`fused_class_sums` picks the orientation from :func:`by_rows_form`);
+a vector as rows under ``_vector_rule`` (:func:`fused_rows_sums`), so binary
+logistic, hinge and least squares at 1,024 or 4,096 features take it too.
+All d is one feature block under ``_FM_VMEM_LIMIT``; there is no window grid
+and no draw in the kernel (a Bernoulli mask is a row operand), and a by-rows
+X whose d is no multiple of 128 (padded lanes) or too wide for one lane
+group of rows stays two reads.  ``one_read_blocks`` selects it from the
+operands as it selects the others.
+
 **What the old verdict rested on.**  A second family, window kernels over
 ``(tile, d)`` ROW blocks at a scalar-prefetched row offset, ran 3.1-3.4 ms an
 iteration against XLA's 1.64 on a 3M x 1000 bf16 window (round 2, TPU v5
@@ -115,7 +139,11 @@ lite), and that was put down to the MXU: an M/N dimension of 8 on a
 over X against XLA's two, so the chip voted on a copy, never on a one-read
 window.  They went in PR 30; their successor is :func:`fused_window_sums`
 (PR 31: 1.13 ms a step on a 419,430 x 1000 bf16 window against the two
-matvecs' 2.26, PERF.md).
+matvecs' 2.26, PERF.md).  Row blocks came back in PR 39 where neither half
+of that verdict holds: at a multiple of 128 X already lies ``{1,0}`` (no
+copy: pinned against the compiler at 2,000,896 x 3,072 and 2,097,152 x 1,024)
+and the products run with 16 rows on the matrix unit, not 8 on the vector
+unit.
 """
 
 
@@ -168,7 +196,11 @@ def feature_major(n: int, d: int) -> bool:
     The device's default layout for a 2-D shape is the order of its two
     dimensions that pads least under the ``(8, 128)`` tile, row-major on a
     tie: ``(n, 1000)`` pads 1000 to 1024 columns by rows and nothing by
-    features, ``(n, 1024)`` nothing either way.  It holds for 2- and 4-byte
+    features, ``(n, 1024)`` nothing either way.  So it fails, for n a
+    multiple of 8, exactly where d rounds to the same number under 8 and
+    under 128: every multiple of 128 (768, 1,024, 3,072, 4,096: those
+    widths take the by-rows form, :func:`by_rows_form`) and the seven
+    widths under each.  It holds for 2- and 4-byte
     elements alike; ``tests/test_chip_compile.py`` pins the rule against
     the layouts the chip's compiler gives entry parameters."""
     by_features = _round_up(d, SUBLANES) * _round_up(n, LANES)
@@ -177,7 +209,8 @@ def feature_major(n: int, d: int) -> bool:
 
 
 def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool,
-                   class_rows: int = 0, fblock: Optional[int] = None) -> int:
+                   class_rows: int = 0, fblock: Optional[int] = None,
+                   by_rows: bool = False) -> int:
     """Scoped VMEM one grid step of the feature-major kernel needs: per
     lane the ``(d, tile)`` block of ``X.T`` double-buffered (d pads to a
     packed vreg's rows) and the ``(1, tile)`` f32 rows of y (and the mask)
@@ -190,15 +223,18 @@ def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool,
     copy with the lanes outside replaced) and six ``(class_rows, chunk)``
     f32 arrays of the rule between the products; where the body cuts the
     width into blocks of ``fblock`` features (the wide form), one such
-    block of the lane chunk in place of all d.  Above the compiler's
-    own count at every shape tried (tests/test_chip_compile.py), so a
-    tile it admits compiles."""
-    per_lane = (2 * _round_up(d, 32 // itemsize) * itemsize
+    block of the lane chunk in place of all d.  ``by_rows``: the class
+    kernel over ``(tile, d)`` blocks of X itself, whose d lies along the
+    lanes and pads to whole lane groups, in the block and in the chunk's
+    copy.  Above the compiler's own count at every shape tried
+    (tests/test_chip_compile.py), so a tile it admits compiles."""
+    pad = LANES if by_rows else 32 // itemsize  # what d pads to in a block
+    per_lane = (2 * _round_up(d, pad) * itemsize
                 + 2 * (2 if masked else 1) * SUBLANES * 4)
     if class_rows:
         fixed = (2 * class_rows * _round_up(d, LANES) * (itemsize + 4)
                  + _FM_LANE_CHUNK * (
-                     _round_up(min(fblock or d, d), 32 // itemsize) * itemsize
+                     _round_up(min(fblock or d, d), pad) * itemsize
                      + 6 * class_rows * 4))
     else:
         fixed = 4 * _round_up(d, SUBLANES) * LANES * 4
@@ -223,12 +259,27 @@ def _fm_halved(n: int, need, limit: int) -> Optional[int]:
 
 
 def _fm_narrow_tile(n: int, d: int, itemsize: int, masked: bool,
-                    class_rows: int = 0) -> Optional[int]:
+                    class_rows: int = 0, by_rows: bool = False
+                    ) -> Optional[int]:
     """The row tile of the forms that hold all d at once under
-    ``_FM_VMEM_LIMIT``: ``_fm_kernel``'s, or the class kernel's."""
+    ``_FM_VMEM_LIMIT``: ``_fm_kernel``'s, or the class kernel's (over
+    blocks of ``X.T``, or ``by_rows`` of X itself)."""
     return _fm_halved(
-        n, lambda t: _fm_vmem_bytes(t, d, itemsize, masked, class_rows),
+        n, lambda t: _fm_vmem_bytes(t, d, itemsize, masked, class_rows,
+                                    by_rows=by_rows),
         _FM_VMEM_LIMIT)
+
+
+def by_rows_form(n: int, d: int) -> bool:
+    """Whether the full scan over a dense ``(n, d)`` X takes ``(tile, d)``
+    blocks of X ITSELF (the class kernel's by-rows form): where the chip
+    stores X by rows (not :func:`feature_major`) and a row fills whole
+    lane groups, ``d`` a multiple of 128 (embeddings, hashed spaces, 3,072
+    pixels), so that a block is one contiguous piece with no padded lane
+    in either product's contraction.  A by-rows X of another width (few
+    rows; ``d`` = 1020) has no one-read form: a block of ``X.T`` would be
+    handed a copy of all of X."""
+    return not feature_major(n, d) and d % LANES == 0
 
 
 def wide_rows_of(dtype) -> Tuple[int, int]:
@@ -287,8 +338,19 @@ def fm_blocks(n: int, d: int, itemsize: int, masked: bool = True,
     ``(n, d)`` X: its own choice of row tile, ``FM_TILE`` halved until its
     VMEM fits, and the blocks its body cuts the width into: one, or for a
     vector of weights too wide for that (:func:`fm_wide`) the wide form's.
-    None where no form fits even one lane group of rows (a wider d
-    still: the two-read path's case)."""
+    Where the chip stores X by rows the kernel is the class body over
+    ``(tile, d)`` blocks of X itself (:func:`by_rows_form`), the width in one
+    block.  None where no form fits even one lane group of rows (a wider
+    d still, or a by-rows width that is no multiple of 128: the two-read
+    path's cases)."""
+    if by_rows_form(n, d):
+        # a vector of weights rides the class body as one packed register
+        # of rows (wide_rows_of); the by-rows form has no feature blocks
+        tile = _fm_narrow_tile(n, d, itemsize, masked,
+                               class_rows or 32 // itemsize, True)
+        return tile and (tile, 1)
+    if not feature_major(n, d):
+        return None
     tile = _fm_narrow_tile(n, d, itemsize, masked, class_rows)
     if tile is not None:
         return tile, 1
@@ -306,18 +368,21 @@ def fm_tile(n: int, d: int, itemsize: int, masked: bool = True,
 
 def _check_fm_vmem(tile: int, X, masked: bool, class_rows: int = 0,
                    fblock: Optional[int] = None,
-                   limit: int = _FM_VMEM_LIMIT) -> None:
+                   limit: int = _FM_VMEM_LIMIT, by_rows: bool = False
+                   ) -> None:
     """Reject a tile the chip's compiler would refuse with an error that
     names the largest one it admits (or says that not even one lane group
     fits), instead of a Mosaic compile-time OOM."""
     d = X.shape[1]
     itemsize = jnp.dtype(X.dtype).itemsize
-    need = _fm_vmem_bytes(tile, d, itemsize, masked, class_rows, fblock)
+    count = functools.partial(_fm_vmem_bytes, d=d, itemsize=itemsize,
+                              masked=masked, class_rows=class_rows,
+                              fblock=fblock, by_rows=by_rows)
+    need = count(tile)
     if need <= limit:
         return
-    fixed = _fm_vmem_bytes(0, d, itemsize, masked, class_rows, fblock)
-    per_lane = _fm_vmem_bytes(1, d, itemsize, masked, class_rows,
-                              fblock) - fixed
+    fixed = count(0)
+    per_lane = count(1) - fixed
     max_tile = (limit - fixed) // per_lane // LANES * LANES
     hint = (
         f"use tile_m <= {max_tile}"
@@ -592,7 +657,7 @@ def fused_gradient_sums(
 
 
 def _fm_tile_of(X, tile_m: Optional[int], masked: bool, interpret: bool,
-                class_rows: int = 0) -> int:
+                class_rows: int = 0, by_rows: bool = False) -> int:
     """The row tile of a call on ``X``: the kernel's own choice, or the
     caller's floored to whole lane groups; checked against the VMEM the
     chip's compiler allows unless the interpreter runs it."""
@@ -600,11 +665,12 @@ def _fm_tile_of(X, tile_m: Optional[int], masked: bool, interpret: bool,
     if tile_m is None:
         # too wide for one lane group: the check below says so
         tile = (_fm_narrow_tile(n, d, jnp.dtype(X.dtype).itemsize, masked,
-                                class_rows) or _fm_round(LANES, n))
+                                class_rows, by_rows)
+                or _fm_round(LANES, n))
     else:
         tile = _fm_round(tile_m, n)
     if not interpret:
-        _check_fm_vmem(tile, X, masked, class_rows)
+        _check_fm_vmem(tile, X, masked, class_rows, by_rows=by_rows)
     return tile
 
 
@@ -713,16 +779,21 @@ def class_rows_of(C: int, dtype) -> int:
     return _round_up(C, 32 // jnp.dtype(dtype).itemsize)
 
 
-def _fm_class_kernel(rule, n, masked, fblock, xt_ref, y_ref, *refs):
-    """One ``(d, tile)`` block of ``X.T`` for a ``(rows, d)`` MATRIX of
-    weights, one row a class: both products go to the matrix unit with
-    operands in X's type and f32 sums, ``(rows, d) @ (d, lanes)`` for the
-    margins and ``(rows, lanes) @ (lanes, d)`` for the gradient, with
-    ``rule(margins, labels) -> (dloss/dmargins, loss)`` between them on
-    ``(rows, lanes)`` arrays in VMEM.  The block is read from VMEM twice
-    and from HBM once; the gradient is summed over the grid in f32, the
-    loss and the count as lane partials, as in ``_fm_kernel``, whose grid
-    and tail cut these are.
+def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs):
+    """One block of X for a ``(rows, d)`` MATRIX of weights, one row a
+    class: both products go to the matrix unit with operands in X's type
+    and f32 sums, with ``rule(margins, labels) -> (dloss/dmargins, loss)``
+    between them on ``(rows, lanes)`` arrays in VMEM.  The block is a
+    ``(d, tile)`` block of ``X.T`` (features on sublanes, rows on lanes:
+    ``(rows, d) @ (d, lanes)`` for the margins, ``(rows, lanes) @ (lanes,
+    d)`` for the gradient) or, ``by_rows``, a ``(tile, d)`` block of X
+    itself where the chip stores it so (one contiguous piece; the same two
+    orientations of X as the right-hand operand, swapped: the margins
+    contract both operands on d, the gradient is a plain product).
+    Margins, labels, mask and coefficients have X's rows on the lanes
+    either way.  The block is read from VMEM twice and from HBM once; the
+    gradient is summed over the grid in f32, the loss and the count as lane
+    partials, as in ``_fm_kernel``, whose grid and tail cut these are.
 
     ``fblock`` features a product: all d, or (the wide form) the width cut
     into blocks that each product takes in turn, the margins summed over
@@ -731,10 +802,11 @@ def _fm_class_kernel(rule, n, masked, fblock, xt_ref, y_ref, *refs):
     m_ref = refs[0] if masked else None
     w_ref, g_ref, loss_ref, cnt_ref = refs[-4:]
     i = pl.program_id(0)
-    d, tile = xt_ref.shape
+    tile, d = x_ref.shape if by_rows else x_ref.shape[::-1]
     f32 = jnp.float32
     lw = _fm_lane_chunk(tile)
     blocks = [(r0, min(fblock, d - r0)) for r0 in range(0, d, fblock)]
+    features = int(by_rows)  # the axis of a block that holds d
 
     @pl.when(i == 0)
     def _():
@@ -745,18 +817,23 @@ def _fm_class_kernel(rule, n, masked, fblock, xt_ref, y_ref, *refs):
     def lanes_of(c, tail):
         """Sums of the block's lanes ``[c * lw, (c + 1) * lw)``; in the
         cut block every value read from a lane past row ``n`` is replaced
-        before any arithmetic, as in ``_fm_kernel``."""
+        before any arithmetic, as in ``_fm_kernel`` (of a by-rows block:
+        the rows past ``n``, an iota along its first axis)."""
         c0 = c * lw if isinstance(c, int) else pl.multiple_of(c * lw, lw)
         lanes = pl.ds(c0, lw)
         if tail:
             inside = (i * tile + c0 + jax.lax.broadcasted_iota(
                 jnp.int32, (1, lw), 1)) < n
+            x_inside = inside if not by_rows else (
+                i * tile + c0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (lw, 1), 0)) < n
 
         def x_of(r0, r):
             if whole is not None:  # one block: both products read it
                 return whole
-            x = xt_ref[r0:r0 + r, lanes]
-            return jnp.where(inside, x, jnp.zeros_like(x)) if tail else x
+            x = (x_ref[lanes, r0:r0 + r] if by_rows
+                 else x_ref[r0:r0 + r, lanes])
+            return jnp.where(x_inside, x, jnp.zeros_like(x)) if tail else x
 
         whole = None
         if len(blocks) == 1:
@@ -766,8 +843,9 @@ def _fm_class_kernel(rule, n, masked, fblock, xt_ref, y_ref, *refs):
             y = jnp.where(inside, y, 0.0)
         margins = None
         for r0, r in blocks:
-            part = jnp.dot(w_ref[:, r0:r0 + r], x_of(r0, r),
-                           preferred_element_type=f32)
+            part = jax.lax.dot_general(
+                w_ref[:, r0:r0 + r], x_of(r0, r),
+                (((1,), (features,)), ((), ())), preferred_element_type=f32)
             margins = part if margins is None else margins + part
         coeff, losses = rule(margins, y)
         if masked:
@@ -780,10 +858,10 @@ def _fm_class_kernel(rule, n, masked, fblock, xt_ref, y_ref, *refs):
             coeff = jnp.where(inside, coeff, 0.0)
             losses = jnp.where(inside, losses, 0.0)
         loss_ref[:] += _lane_fold(losses)
-        coeff = coeff.astype(xt_ref.dtype)
+        coeff = coeff.astype(x_ref.dtype)
         for r0, r in blocks:
             g_ref[:, r0:r0 + r] += jax.lax.dot_general(
-                coeff, x_of(r0, r), (((1,), (1,)), ((), ())),
+                coeff, x_of(r0, r), (((1,), (1 - features,)), ((), ())),
                 preferred_element_type=f32)
 
     _fm_full_scan(functools.partial(_fm_block, lanes_of, tile, lw), n, tile)
@@ -797,6 +875,7 @@ def fused_class_sums(
     mask: Optional[Array] = None,
     tile_m: Optional[int] = None,
     interpret: bool = False,
+    by_rows: Optional[bool] = None,
 ) -> Tuple[Array, Array, Array]:
     """Fused ``(grad_sum (C, d), loss_sum, count)`` in ONE read of ``X``
     for a ``(C, d)`` matrix of weights, one row a class.
@@ -808,36 +887,45 @@ def fused_class_sums(
     of zero.  Grid, tile and tail cut are :func:`fused_gradient_sums`';
     the products run on the matrix unit in ``X``'s type (``W`` and the
     coefficients are rounded to it) with f32 sums.
+
+    ``by_rows``: the blocks are ``(tile_m, d)`` row blocks of X itself and
+    not ``(d, tile_m)`` blocks of ``X.T``; None: whichever follows the
+    order the chip stores X in (:func:`by_rows_form`), so that no copy of
+    X stands in front of the kernel.
     """
     C, d = W.shape
     rows = class_rows_of(C, X.dtype)
     if rows > FM_CLASS_ROWS:
         raise ValueError(f"{C} class rows: the class kernel takes at most "
                          f"{FM_CLASS_ROWS}; use the XLA path")
-    tile = _fm_tile_of(X, tile_m, mask is not None, interpret, rows)
-    return _fused_class_sums(rule, X, y, W, mask, rows=rows, tile_m=tile,
-                             interpret=interpret)
+    if by_rows is None:
+        by_rows = by_rows_form(*X.shape)
+    tile = _fm_tile_of(X, tile_m, mask is not None, interpret, rows, by_rows)
+    sums = _fused_rows_class_sums if by_rows else _fused_class_sums
+    return sums(rule, X, y, W, mask, rows=rows, tile_m=tile,
+                interpret=interpret)
 
 
 def _class_call(rule, X, y, W, mask, tile: int, fblock: int, limit: int,
-                interpret: bool):
-    """The class kernel's call over ``X.T`` under the ``(rows, d)`` weights
-    ``W`` in X's type: ``(gradient (rows, d), loss and count lane
-    partials)``, all f32."""
+                interpret: bool, by_rows: bool = False):
+    """The class kernel's call over ``X.T`` (``by_rows``: over X as it
+    lies) under the ``(rows, d)`` weights ``W`` in X's type: ``(gradient
+    (rows, d), loss and count lane partials)``, all f32."""
     n, d = X.shape
     rows = W.shape[0]
     masked = mask is not None
     row = pl.BlockSpec((1, tile), lambda i: (0, i))
     whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))  # noqa: E731
-    operands = [X.T, row_operand(y, n)]
+    block = (pl.BlockSpec((tile, d), lambda i: (i, 0)) if by_rows
+             else pl.BlockSpec((d, tile), lambda i: (0, i)))
+    operands = [X if by_rows else X.T, row_operand(y, n)]
     if masked:
         operands.append(row_operand(mask, n))
     operands.append(W)
     return pl.pallas_call(
-        functools.partial(_fm_class_kernel, rule, n, masked, fblock),
+        functools.partial(_fm_class_kernel, rule, n, masked, fblock, by_rows),
         grid=(pl.cdiv(n, tile),),
-        in_specs=[pl.BlockSpec((d, tile), lambda i: (0, i))]
-        + [row] * (1 + masked) + [whole((rows, d))],
+        in_specs=[block] + [row] * (1 + masked) + [whole((rows, d))],
         out_specs=[whole((rows, d)), whole((1, LANES)), whole((1, LANES))],
         out_shape=[jax.ShapeDtypeStruct((rows, d), jnp.float32)]
         + _fm_sums_shape(d)[1:],
@@ -845,6 +933,20 @@ def _class_call(rule, X, y, W, mask, tile: int, fblock: int, limit: int,
             dimension_semantics=("arbitrary",), vmem_limit_bytes=limit),
         interpret=interpret,
     )(*operands)
+
+
+def _class_sums(rule, X, y, W, mask, rows: int, tile: int, interpret: bool,
+                by_rows: bool):
+    """The class kernel's call with the ``(C, d)`` weights ``W`` cast to
+    X's type and padded to ``rows``, all d in one feature block."""
+    n, d = X.shape
+    C = W.shape[0]
+    grad, loss, cnt = _class_call(
+        rule, X, y, jnp.pad(W.astype(X.dtype), ((0, rows - C), (0, 0))),
+        mask, tile, d, _FM_VMEM_LIMIT, interpret, by_rows)
+    count = jnp.sum(cnt) if mask is not None else jnp.asarray(
+        n, jnp.float32)
+    return grad[:C], jnp.sum(loss), count
 
 
 @functools.partial(
@@ -860,14 +962,26 @@ def _fused_class_sums(
     tile_m: int = FM_TILE,
     interpret: bool = False,
 ) -> Tuple[Array, Array, Array]:
-    n, d = X.shape
-    C = W.shape[0]
-    grad, loss, cnt = _class_call(
-        rule, X, y, jnp.pad(W.astype(X.dtype), ((0, rows - C), (0, 0))),
-        mask, tile_m, d, _FM_VMEM_LIMIT, interpret)
-    count = jnp.sum(cnt) if mask is not None else jnp.asarray(
-        n, jnp.float32)
-    return grad[:C], jnp.sum(loss), count
+    return _class_sums(rule, X, y, W, mask, rows, tile_m, interpret, False)
+
+
+# the by-rows forms have names of their own, so that a trace says which
+# form a step took (the compile cache keys on the jitted function's name
+# too, not on its scopes: PERF.md, PR 25)
+@functools.partial(
+    jax.jit, static_argnames=("rule", "rows", "tile_m", "interpret")
+)
+def _fused_rows_class_sums(
+    rule,
+    X: Array,
+    y: Array,
+    W: Array,
+    mask: Optional[Array] = None,
+    rows: int = 16,
+    tile_m: int = FM_TILE,
+    interpret: bool = False,
+) -> Tuple[Array, Array, Array]:
+    return _class_sums(rule, X, y, W, mask, rows, tile_m, interpret, True)
 
 
 def _parts_of(a, dtype, parts: int, in_kernel: bool = False):
@@ -949,6 +1063,22 @@ def fused_wide_sums(
                             interpret=interpret)
 
 
+def _vector_sums(pointwise, X, y, w, mask, tile: int, fblock: int,
+                 limit: int, interpret: bool, by_rows: bool):
+    """The class kernel's call with the vector ``w`` as rows of a matrix
+    in X's type and ``_vector_rule`` between the products."""
+    n, d = X.shape
+    parts, rows = wide_rows_of(X.dtype)
+    W = jnp.pad(jnp.stack(_parts_of(w, X.dtype, parts)),
+                ((0, rows - parts), (0, 0)))
+    grad, loss, cnt = _class_call(
+        functools.partial(_vector_rule, pointwise, parts, X.dtype),
+        X, y, W, mask, tile, fblock, limit, interpret, by_rows)
+    count = jnp.sum(cnt) if mask is not None else jnp.asarray(
+        n, jnp.float32)
+    return jnp.sum(grad[:parts], axis=0), jnp.sum(loss), count
+
+
 # a name of its own, with its scope (``sgd.wide_sums``): the compile cache
 # keys on the jitted function's name, not on its scopes (PERF.md, PR 25)
 @functools.partial(
@@ -966,16 +1096,47 @@ def _fused_wide_sums(
     vmem_limit: int = _FM_WIDE_VMEM_LIMIT,
     interpret: bool = False,
 ) -> Tuple[Array, Array, Array]:
-    n, d = X.shape
-    parts, rows = wide_rows_of(X.dtype)
-    W = jnp.pad(jnp.stack(_parts_of(w, X.dtype, parts)),
-                ((0, rows - parts), (0, 0)))
-    grad, loss, cnt = _class_call(
-        functools.partial(_vector_rule, pointwise, parts, X.dtype),
-        X, y, W, mask, tile_m, fblock, vmem_limit, interpret)
-    count = jnp.sum(cnt) if mask is not None else jnp.asarray(
-        n, jnp.float32)
-    return jnp.sum(grad[:parts], axis=0), jnp.sum(loss), count
+    return _vector_sums(pointwise, X, y, w, mask, tile_m, fblock, vmem_limit,
+                        interpret, False)
+
+
+def fused_rows_sums(
+    pointwise,
+    X: Array,
+    y: Array,
+    w: Array,
+    mask: Optional[Array] = None,
+    tile_m: Optional[int] = None,
+    interpret: bool = False,
+) -> Tuple[Array, Array, Array]:
+    """:func:`fused_gradient_sums` for an X the chip stores BY ROWS
+    (:func:`by_rows_form`: d a multiple of 128): ONE read of ``X`` through
+    the class kernel's by-rows form over ``(tile_m, d)`` blocks of X
+    itself, each one contiguous piece.  The vector of weights rides as
+    rows of a matrix in X's type and the elementwise ``pointwise`` sits
+    between the two products, as in :func:`fused_wide_sums`; all d is one
+    feature block under ``_FM_VMEM_LIMIT``.  Over a feature-major X it
+    is right and slow: the compiler puts a copy of all of X in front."""
+    _, rows = wide_rows_of(X.dtype)
+    tile = _fm_tile_of(X, tile_m, mask is not None, interpret, rows, True)
+    return _fused_rows_sums(pointwise, X, y, w, mask, tile_m=tile,
+                            interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("pointwise", "tile_m", "interpret")
+)
+def _fused_rows_sums(
+    pointwise,
+    X: Array,
+    y: Array,
+    w: Array,
+    mask: Optional[Array] = None,
+    tile_m: int = LANES,
+    interpret: bool = False,
+) -> Tuple[Array, Array, Array]:
+    return _vector_sums(pointwise, X, y, w, mask, tile_m, X.shape[1],
+                        _FM_VMEM_LIMIT, interpret, True)
 
 
 def fused_window_sums(

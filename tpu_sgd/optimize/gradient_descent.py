@@ -43,7 +43,7 @@ from tpu_sgd.config import SGDConfig
 from tpu_sgd.obs.spans import span
 from tpu_sgd.obs.timeseries import observe_scalar
 from tpu_sgd.ops.gradients import (Gradient, LeastSquaresGradient,
-                                   RowDraw)
+                                   RowDraw, by_rows)
 from tpu_sgd.ops.gram import DEFAULT_BLOCK_ROWS
 from tpu_sgd.ops.sparse import is_sparse
 from tpu_sgd.ops.updaters import SimpleUpdater, Updater
@@ -1732,9 +1732,10 @@ class GradientDescent(Optimizer):
         if (not sparse_X and self.mesh is not None
                 and self._mesh_kind() == "dp"):
             placed = self._place(X, y)
-        with span("train.select"), self._substituted(X, y, sparse_X) as X:
+        with span("train.select") as select_span, \
+                self._substituted(X, y, sparse_X) as X:
             fn, args, built = self._select(X, y, w0, sparse_X, placed,
-                                           run_span)
+                                           run_span, select_span)
         with span("train.dispatch", built=int(built)):
             w, losses, n_rec = fn(*args)
             # the copies to the host ride behind the program, so the fit
@@ -1775,12 +1776,13 @@ class GradientDescent(Optimizer):
         finally:
             self.gradient = orig
 
-    def _select(self, X, y, w0, sparse_X, placed, run_span):
+    def _select(self, X, y, w0, sparse_X, placed, run_span, select_span):
         """``train.select``'s work for a fused fit: ``(fn, args, built)`` —
         each route names its compiled runner and its arguments, ONE call in
         ``train.dispatch`` runs them; ``built`` where the runner is a new
         ``_run_cache`` entry, which traces, lowers and compiles inside that
-        call.  Sets ``train.run``'s attributes where the span keeps them."""
+        call.  Sets ``train.run``'s attributes (and ``by_rows`` on
+        ``train.select`` too) where the spans keep them."""
         from tpu_sgd.ops.gram import GramData
 
         cached = len(self._run_cache)
@@ -1847,31 +1849,35 @@ class GradientDescent(Optimizer):
             path = "gram" if isinstance(X, GramData) else "fused"
             args = (w0, X, y)
         if run_span.live:
-            # (labels_prepared, row_tile, feature_blocks, mask_in_kernel):
-            # evaluated only where a span carries them
-            kernel = self._step_kernel(*args) if runner else (0, 0, 1, 0)
+            # (labels_prepared, row_tile, feature_blocks, mask_in_kernel,
+            # by_rows): evaluated only where a span carries them
+            kernel = self._step_kernel(*args) if runner else (0, 0, 1, 0, 0)
             run_span.set(
                 path=path,
                 shards=1 if self.mesh is None else self.mesh.devices.size,
                 labels_prepared=kernel[0], row_tile=kernel[1],
-                feature_blocks=kernel[2], mask_in_kernel=kernel[3])
+                feature_blocks=kernel[2], mask_in_kernel=kernel[3],
+                by_rows=kernel[4])
+            select_span.set(by_rows=kernel[4])
         return fn, args, len(self._run_cache) > cached
 
     def _step_kernel(self, w0, X, y, valid=None):
         """``train.run``'s ``(labels_prepared, row_tile, feature_blocks,
-        mask_in_kernel)`` for the fit ``_runner``'s program is about to
-        make of these arguments (a shard's operands under a mesh), on a
+        mask_in_kernel, by_rows)`` for the fit ``_runner``'s program is about
+        to make of these arguments (a shard's operands under a mesh), on a
         TPU: ``labels_prepared`` 1 where it lays the labels out once,
         before its loop, for the one-read kernel (``rows_prepared``),
         ``row_tile`` the rows a grid step of the step's kernel takes and
         ``feature_blocks`` the blocks its body cuts the width into
         (``step_blocks``), ``mask_in_kernel`` 1 where that kernel draws
         every step's Bernoulli mask itself (0 where the step is handed an
-        array or draws nothing).  ``(0, 0, 1, 0)`` where the step takes
-        ``y`` as it is and is no kernel (two reads; statistics; a CPU,
-        whose program drops the row nothing reads)."""
+        array or draws nothing), ``by_rows`` 1 where that kernel is the
+        by-rows form (row blocks of an X the chip stores by rows:
+        ``ops.gradients.by_rows``).  ``(0, 0, 1, 0, 0)`` where the step
+        takes ``y`` as it is and is no kernel (two reads; statistics; a
+        CPU, whose program drops the row nothing reads)."""
         if jax.default_backend() != "tpu":
-            return 0, 0, 1, 0
+            return 0, 0, 1, 0, 0
         if self.mesh is not None:
             shards = self.mesh.devices.size
 
@@ -1881,8 +1887,9 @@ class GradientDescent(Optimizer):
 
             X, y, valid = shard(X), shard(y), shard(valid)
         args = (self.gradient, self.config, X, y, w0, valid)
-        return (int(rows_prepared(*args)), *step_blocks(*args),
-                int(mask_in_kernel(*args)))
+        blocks = step_blocks(*args)
+        return (int(rows_prepared(*args)), *blocks,
+                int(mask_in_kernel(*args)), int(blocks[0] > 0 and by_rows(X)))
 
     def _place(self, X, y):
         """``shard_dataset`` for this fit's mesh under the ``train.place``
