@@ -763,6 +763,33 @@ def test_the_hand_offs_writer_writes_its_destination_in_place(S, rows):
         == [by_features, by_features]
 
 
+def test_a_batch_staged_ahead_is_made_whole_in_one_program(S):
+    """``_stage_join`` at the stream cell's shape (a micro-batch of
+    2,097,152 x 1000 bf16 as 128 blocks of 16,384 rows): one program whose
+    result is the one array in the layout the fit reads (feature-major, as
+    its blocks arrive), with no temporary of a block's size beside the
+    blocks and the result, one write a block and no fill of the array."""
+    from tpu_sgd.optimize.gradient_descent import _stage_join
+
+    n, block = 2_097_152, 16_384
+    compiled = _stage_join.lower(
+        *[S((block, D), BF16)] * (n // block)).compile()
+    # one write a block, the first one of block 0 alone: no fill
+    text = compiled.as_text()
+    writes = [line for line in text.split("\n")
+              if " fusion(" in line and "ENTRY" not in line]
+    assert len(writes) == n // block and " pad(" not in text
+    assert "sgd.stage/concatenate" in text
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == n * D * 2
+    assert memory.argument_size_in_bytes == n * D * 2
+    assert memory.temp_size_in_bytes < block * D * 2
+    by_features = (1, 0)
+    assert compiled.output_formats.layout.major_to_minor == by_features
+    assert {f.layout.major_to_minor for f in compiled.input_formats[0]} \
+        == {by_features}
+
+
 # -- sparse ------------------------------------------------------------------
 
 def test_sparse_hinge_l1_step_compiles(S):

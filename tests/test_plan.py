@@ -1099,3 +1099,43 @@ def test_set_gram_options_validates_before_applying():
             opt.set_gram_options(block_rows=4096, batch_rows=0)
         assert opt.gram_block_rows == DEFAULT_BLOCK_ROWS
         assert "block_rows" not in opt._user_gram_opts
+
+
+def test_a_batch_staged_on_the_device_keeps_the_plan(rng, monkeypatch):
+    """The streaming fold plans a pass's first micro-batch with the device
+    empty and every later one with another micro-batch staged beside it,
+    when the probe would read far less free memory: one shape keeps one plan
+    (the probe is not asked again), and rows that already lie on the device
+    are never sent to a streaming schedule whatever the probe reads."""
+    import tpu_sgd.plan as plan_mod
+    from tpu_sgd import LinearRegressionWithSGD
+
+    budgets = [1e9, 8e3, 8e3]  # then 8 KB "free": a host array would stream
+    asked = []
+
+    def budget(*a, **k):
+        asked.append(budgets[len(asked)])
+        return asked[-1], "test"
+
+    monkeypatch.setattr(plan_mod, "device_budget", budget)
+    X = rng.normal(size=(512, 8)).astype(np.float32)
+    y = rng.normal(size=(512,)).astype(np.float32)
+    alg = LinearRegressionWithSGD(0.2, 5)
+    alg.run((X, y))
+    first = alg.optimizer.last_plan
+    assert first.schedule == "resident_stock" and len(asked) == 1
+    w_host = np.asarray(alg.run((X, y)).weights)
+    assert alg._apply_plan(jnp.asarray(X), y) is True  # the key held
+    w_dev = np.asarray(alg.run((jnp.asarray(X), y)).weights)
+    assert alg.optimizer.last_plan is first and len(asked) == 1
+    assert not alg.optimizer.host_streaming
+    np.testing.assert_array_equal(w_dev, w_host)
+    # planned afresh under the small budget: a device array stays resident,
+    # the same rows on the host would be streamed
+    fresh = LinearRegressionWithSGD(0.2, 5)
+    fresh.run((jnp.asarray(X), y))
+    assert fresh.optimizer.last_plan.schedule == "resident_stock"
+    assert not fresh.optimizer.host_streaming and len(asked) == 2
+    hosted = LinearRegressionWithSGD(0.2, 5)
+    hosted._apply_plan(X, y)
+    assert hosted.optimizer.last_plan.schedule == "host_streamed"

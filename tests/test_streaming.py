@@ -301,3 +301,352 @@ def test_checkpoint_history_tail_bounds_persisted_history(tmp_path, rng):
     with pytest.raises(ValueError, match="history_tail"):
         StreamingLinearRegressionWithSGD().set_checkpoint(
             str(tmp_path / "ck2"), history_tail=0)
+
+
+# ---- one micro-batch ahead: the fold is the in-turn fold's ----------------
+
+def _blocked(monkeypatch, d, in_flight=2):
+    """Hand-off blocks of ``_STAGE_ROWS`` rows, ``in_flight`` at a time."""
+    import tpu_sgd.optimize.gradient_descent as gd
+
+    monkeypatch.setattr(gd, "_STAGE_BLOCK_BYTES", gd._STAGE_ROWS * d * 4)
+    monkeypatch.setattr(gd, "_STAGE_IN_FLIGHT", in_flight)
+    return gd
+
+
+def _mixed_stream(kind, d=6, batches=5):
+    """``batches`` micro-batches of 3 blocks and a bit, the third EMPTY;
+    ``kind`` says where the features lie."""
+    import jax.numpy as jnp
+
+    from tpu_sgd.ops.sparse import sparse_data
+
+    w_true = np.linspace(-1, 1, d).astype(np.float32)
+    out = []
+    for i in range(batches):
+        r = np.random.default_rng(40 + i)
+        rows = 0 if i == 2 else 3 * 1024 + 100
+        if kind == "bcoo" and rows:
+            X, y, _ = sparse_data(rows, d, nnz_per_row=3, seed=40 + i)
+            out.append((X, np.asarray(y, np.float32)))
+            continue
+        X = r.normal(size=(rows, d)).astype(np.float32)
+        y = (X @ w_true + 0.05 * r.normal(size=rows)).astype(np.float32)
+        out.append((jnp.asarray(X) if kind == "device" else X, y))
+    return out
+
+
+@pytest.mark.parametrize("kind", ["host", "device", "bcoo"])
+def test_train_on_ahead_is_the_in_turn_fold_bit_for_bit(kind, monkeypatch):
+    """``train_on`` (one micro-batch staged ahead) against ``train_on_batch``
+    called in turn: weights, loss history, stream position, and the
+    listeners' calls, with an empty micro-batch in the middle."""
+    _blocked(monkeypatch, d=6)
+    stream = _mixed_stream(kind)
+
+    def fold(how):
+        alg = StreamingLinearRegressionWithSGD(step_size=0.2,
+                                               num_iterations=8)
+        alg.set_initial_weights(np.zeros(6, np.float32))
+        calls = []
+        alg.add_model_update_listener(
+            lambda model, count: calls.append(
+                (count, np.asarray(model.weights).copy(),
+                 np.asarray(alg.algorithm.optimizer.loss_history).copy())))
+        if how == "ahead":
+            alg.train_on(iter(stream))
+        else:
+            for X, y in stream:
+                alg.train_on_batch(X, y)
+        return alg, calls
+
+    (ahead, calls_a), (turn, calls_t) = fold("ahead"), fold("in turn")
+    np.testing.assert_array_equal(np.asarray(ahead.latest_model().weights),
+                                  np.asarray(turn.latest_model().weights))
+    assert ahead.loss_history == turn.loss_history
+    assert ahead._batch_count == turn._batch_count == 5  # the empty one too
+    # once a micro-batch that updated the model, in order, the same model
+    assert [c[0] for c in calls_a] == [c[0] for c in calls_t] == [1, 2, 4, 5]
+    for (_, wa, la), (_, wt, lt) in zip(calls_a, calls_t):
+        np.testing.assert_array_equal(wa, wt)
+        np.testing.assert_array_equal(la, lt)
+
+
+def test_dense_host_micro_batches_go_ahead_in_blocks(monkeypatch):
+    """What the worker hands the fold: a dense host batch as blocks that no
+    device program has touched, made whole bit for bit; everything else as
+    it came."""
+    import jax
+    import jax.numpy as jnp
+    import threading
+
+    from tpu_sgd.models import streaming
+    from tpu_sgd.optimize.gradient_descent import StagedAhead
+
+    _blocked(monkeypatch, d=6)
+    (X, y), = _mixed_stream("host", batches=1)
+    began = threading.Event()
+    staged, y_out = streaming._take(iter([(X, y)]), began, True, [None])
+    assert began.is_set() and y_out is y
+    assert isinstance(staged, StagedAhead) and len(staged.blocks) == 4
+    assert [b.shape[0] for b in staged.blocks] == [1024, 1024, 1024, 100]
+    whole = staged.whole()
+    assert isinstance(whole, jax.Array) and staged.blocks is None
+    np.testing.assert_array_equal(np.asarray(whole), X)
+    # one block: no write at all
+    small = streaming._take(iter([(X[:100], y[:100])]), began, True,
+                            [None])[0]
+    assert len(small.blocks) == 1 and small.whole().shape == (100, 6)
+    # a device array, an empty batch, the stream's end
+    Xd = jnp.asarray(X)
+    assert streaming._take(iter([(Xd, y)]), began, True, [None])[0] is Xd
+    assert isinstance(streaming._take(iter([(X[:0], y[:0])]),
+                                      began, True, [None])[0],
+                      np.ndarray)
+    ended = threading.Event()
+    assert streaming._take(iter([]), ended, True, [None]) is None
+    assert streaming._take(iter([(Xd, y)]), ended, True, [None])[0] is Xd
+    assert streaming._take(iter([(X, y)]), ended, False, [None])[0] is X
+    training = [whole]  # what the fold trains: waited for, not kept
+    streaming._take(iter([(X, y)]), began, True, training)
+    assert training == []
+    assert not ended.is_set()  # nothing of those was issued
+    # a batch too large to lie beside the one in training stays on the host
+    monkeypatch.setattr(streaming.plan_mod, "device_budget",
+                        lambda *a, **k: (X.nbytes - 1, "test"))
+    assert streaming._take(iter([(X, y)]), began, True, [None])[0] is X
+
+
+def test_train_on_batch_leaves_a_device_array_on_the_device(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    (X, y), = _mixed_stream("host", batches=1)
+    alg = StreamingLinearRegressionWithSGD(step_size=0.2, num_iterations=4)
+    alg.set_initial_weights(np.zeros(6, np.float32))
+    seen = []
+    real = alg.algorithm.optimizer.optimize
+
+    def optimize(data, w0):
+        seen.append(data[0])
+        return real(data, w0)
+
+    monkeypatch.setattr(alg.algorithm.optimizer, "optimize", optimize)
+    Xd = jnp.asarray(X)
+    monkeypatch.setattr(np, "asarray", _no_fetch_of(Xd, np.asarray))
+    alg.train_on_batch(Xd, y)
+    assert seen[0] is Xd and isinstance(seen[0], jax.Array)
+
+
+def _no_fetch_of(array, real):
+    def asarray(a, *args, **kwargs):
+        assert a is not array, "the device batch was fetched to the host"
+        return real(a, *args, **kwargs)
+    return asarray
+
+
+def test_at_most_two_micro_batches_are_alive_at_once(monkeypatch):
+    """The worker takes micro-batch j only once j - 2 is trained and gone,
+    and after any fit the device holds the rows of at most two."""
+    import jax
+
+    _blocked(monkeypatch, d=6)
+    stream = _mixed_stream("host", batches=6)
+    rows = 3 * 1024 + 100
+    alg = StreamingLinearRegressionWithSGD(step_size=0.2, num_iterations=4)
+    alg.set_initial_weights(np.zeros(6, np.float32))
+    taken_at, alive = [], []
+
+    def batches():
+        for batch in stream:
+            taken_at.append(alg._batch_count)
+            yield batch
+
+    real = alg.algorithm.run_warm
+
+    def run_warm(data, model):
+        out = real(data, model)
+        alive.append(sum(a.shape[0] for a in jax.live_arrays()
+                         if a.ndim == 2 and a.shape[1] == 6))
+        return out
+
+    monkeypatch.setattr(alg.algorithm, "run_warm", run_warm)
+    alg.train_on(batches())
+    assert alg._batch_count == 6
+    for j, finished in enumerate(taken_at):
+        assert finished >= j - 1, (j, taken_at)
+    assert len(alive) == 5 and max(alive) <= 2 * rows
+
+
+def test_a_stop_between_staged_and_trained_replays_that_batch(tmp_path):
+    """The driver dies in batch 3's listener while batch 4 is already in the
+    worker's hands: the checkpoint says 3, and the resumed replay trains
+    batch 4 and reproduces the uninterrupted run."""
+    import threading
+
+    stream, _ = _replayable_stream(batches=6)
+    kwargs = dict(step_size=0.3, num_iterations=10)
+    full = StreamingLinearRegressionWithSGD(**kwargs)
+    full.set_initial_weights(np.zeros(12, np.float32))
+    full.train_on(stream)
+
+    part = StreamingLinearRegressionWithSGD(**kwargs)
+    part.set_initial_weights(np.zeros(12, np.float32))
+    part.set_checkpoint(str(tmp_path / "ck"), every=1)
+    taken = [threading.Event() for _ in stream]
+
+    def batches():
+        for event, batch in zip(taken, stream):
+            event.set()
+            yield batch
+
+    def dies(model, count):
+        if count == 3:
+            assert taken[3].wait(30)  # batch 4 has been taken ahead
+            raise KeyboardInterrupt("driver killed")
+
+    part.add_model_update_listener(dies)
+    with pytest.raises(KeyboardInterrupt):
+        part.train_on(batches())
+    assert part._batch_count == 3  # taken ahead is not consumed
+    res = StreamingLinearRegressionWithSGD.resume_from(str(tmp_path / "ck"),
+                                                       **kwargs)
+    assert res._batch_count == 3
+    res.train_on(stream)
+    np.testing.assert_array_equal(np.asarray(res.latest_model().weights),
+                                  np.asarray(full.latest_model().weights))
+    np.testing.assert_array_equal(np.asarray(res.loss_history),
+                                  np.asarray(full.loss_history))
+
+
+def test_a_stream_that_raises_leaves_the_last_finished_model():
+    stream, _ = _replayable_stream(batches=4)
+    want = StreamingLinearRegressionWithSGD(step_size=0.3, num_iterations=10)
+    want.set_initial_weights(np.zeros(12, np.float32))
+    want.train_on(stream[:2])
+
+    def batches():
+        yield from stream[:2]
+        raise OSError("the source went away")
+
+    alg = StreamingLinearRegressionWithSGD(step_size=0.3, num_iterations=10)
+    alg.set_initial_weights(np.zeros(12, np.float32))
+    with pytest.raises(OSError, match="went away"):
+        alg.train_on(batches())
+    assert alg._batch_count == 2
+    np.testing.assert_array_equal(np.asarray(alg.latest_model().weights),
+                                  np.asarray(want.latest_model().weights))
+    # and the model trains on after it
+    alg.train_on(stream[2:])
+    assert alg._batch_count == 4
+
+
+def test_the_folds_spans_tile_a_pass(tmp_path):
+    """``stream.wait`` / ``stream.batch`` (``fit.run``, ``stream.publish``)
+    on the fold's thread, ``stream.stage`` on the worker's; ``ahead`` is 0
+    for a pass's first micro-batch and 1 after it."""
+    import json
+
+    from tpu_sgd import obs
+
+    import threading
+
+    stream, _ = _replayable_stream(batches=3)
+    alg = StreamingLinearRegressionWithSGD(step_size=0.3, num_iterations=5)
+    alg.set_initial_weights(np.zeros(12, np.float32))
+    taken = [threading.Event() for _ in range(4)]
+
+    def batches():
+        for event, batch in zip(taken, stream):
+            event.set()
+            yield batch
+        taken[3].set()  # the worker came for a fourth
+
+    real = alg.algorithm.run_warm
+
+    def run_warm(data, model):  # a fit that outlasts the worker's take
+        assert taken[alg._batch_count + 1].wait(30)
+        return real(data, model)
+
+    alg.train_on(stream)  # the stream's first fit plans: then batches go ahead
+    alg.set_initial_weights(np.zeros(12, np.float32))
+    alg._batch_count = 0
+    alg.algorithm.run_warm = run_warm
+    path = tmp_path / "trace.jsonl"
+    obs.enable(str(path))
+    try:
+        alg.train_on(batches())
+    finally:
+        obs.disable()
+    spans = [json.loads(line) for line in open(path)]
+    spans = [s for s in spans if s.get("name", "").startswith(("stream.",
+                                                               "fit.run"))]
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s)
+    assert len(by["stream.wait"]) == 4 and len(by["stream.stage"]) == 4
+    assert len(by["stream.batch"]) == len(by["stream.publish"]) \
+        == len(by["fit.run"]) == 3
+    turns = sorted(by["stream.batch"], key=lambda s: s["t0_s"])
+    assert [s["index"] for s in turns] == [0, 1, 2]
+    assert [s["ahead"] for s in turns] == [0, 1, 1]
+    assert all(s["rows"] == 500 for s in turns)
+    ids = {s["span_id"] for s in turns}
+    assert all(s["parent_id"] in ids
+               for s in by["fit.run"] + by["stream.publish"])
+    (root,) = by["stream.run"]
+    assert all(s["parent_id"] == root["span_id"]
+               for s in by["stream.wait"] + turns)
+    assert root["parent_id"] == 0
+    fold = {s["thread"] for s in by["stream.wait"] + turns}
+    assert fold == {root["thread"]} and not fold & {s["thread"]
+                                          for s in by["stream.stage"]}
+    staged = [s for s in by["stream.stage"] if "blocks" in s]
+    assert len(staged) == 3 and all(s["blocks"] == 1 and
+                                    s["bytes"] == 500 * 12 * 4
+                                    for s in staged)
+
+
+def test_batches_go_ahead_only_beside_the_stock_schedule(monkeypatch):
+    """Until a stream's first fit has planned, and on any schedule that
+    sizes device state of its own (here the statistics schedule, forced),
+    the micro-batches are copied inside their fits; the fold is the same."""
+    import warnings
+
+    from tpu_sgd.models import streaming
+
+    stream, _ = _replayable_stream(batches=4)
+    made = []
+    real = streaming.StagedAhead
+
+    class Counted(real):
+        def __init__(self, X):
+            made.append(X.shape)
+            super().__init__(X)
+
+    monkeypatch.setattr(streaming, "StagedAhead", Counted)
+
+    def fold(schedule):
+        alg = StreamingLinearRegressionWithSGD(step_size=0.3,
+                                               num_iterations=10)
+        alg.set_initial_weights(np.zeros(12, np.float32))
+        alg.algorithm.set_schedule(schedule)
+        del made[:]
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")  # forced gram: a net loss here
+            alg.train_on(stream)
+        return alg, len(made)
+
+    auto, n_auto = fold("auto")
+    assert n_auto == 2  # batches 0 and 1 were taken before any plan
+    assert auto._stages_ahead()
+    off, n_off = fold("off")
+    assert n_off == 4  # nothing to wait for: the optimizer runs as it is
+    gram, n_gram = fold("resident_gram")
+    assert n_gram == 0 and gram.algorithm.optimizer.sufficient_stats
+    assert not gram._stages_ahead()
+    np.testing.assert_array_equal(np.asarray(auto.latest_model().weights),
+                                  np.asarray(off.latest_model().weights))
+    np.testing.assert_allclose(np.asarray(gram.latest_model().weights),
+                               np.asarray(auto.latest_model().weights),
+                               rtol=1e-3, atol=1e-4)

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from typing import Iterable, Optional, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -24,12 +25,28 @@ from tpu_sgd.optimize.optimizer import Optimizer
 DatasetLike = Union[Tuple, Iterable[LabeledPoint]]
 
 
+def as_features(X):
+    """Features as ``run`` takes them: BCOO passes through undensified and
+    an array that is on the device stays there (``np.asarray`` would fetch
+    all of it); anything else is a numpy array."""
+    if is_sparse(X) or isinstance(X, jax.Array):
+        return X
+    return np.asarray(X)
+
+
+def off_stock(optimizer) -> bool:
+    """Whether a schedule flag other than the stock resident schedule's is
+    set on ``optimizer``, by its user or by a plan."""
+    return bool(getattr(optimizer, "host_streaming", False)
+                or getattr(optimizer, "sufficient_stats", False)
+                or getattr(optimizer, "streamed_stats", False))
+
+
 def _as_arrays(data: DatasetLike) -> Tuple[np.ndarray, np.ndarray]:
     if isinstance(data, tuple) and len(data) == 2:
         X, y = data
-        if is_sparse(X):  # BCOO features pass through undensified
-            return X, np.asarray(y)
-        return np.asarray(X), np.asarray(y)
+        return as_features(X), y if isinstance(y, jax.Array) \
+            else np.asarray(y)
     return to_arrays(data)
 
 
@@ -205,11 +222,7 @@ class GeneralizedLinearAlgorithm:
         """``_auto_plan``'s work; True when the repeat-run ``_plan_key``
         hit skipped the probe and the plan."""
         opt = self.optimizer
-        manual = bool(
-            getattr(opt, "host_streaming", False)
-            or getattr(opt, "sufficient_stats", False)
-            or getattr(opt, "streamed_stats", False)
-        )
+        manual = off_stock(opt)
         # Flags set by a PREVIOUS plan (last_plan is not None) are the
         # planner's own and must not block re-planning for a new dataset;
         # the manual setters clear last_plan, so user-set flags — whenever

@@ -9,6 +9,20 @@ online-SGD code path.  The TPU build reuses the batch step the same way
 (config 5, BASELINE.json:11): a "DStream" is any iterator of ``(X, y)``
 micro-batches, and ``train_on`` folds the model through it.
 
+The fold holds one micro-batch AHEAD (PERF.md, PR 40): a micro-batch does
+not depend on the weights, so while batch k trains a worker thread takes
+batch k+1 from the stream and issues the host-to-device copy of its dense
+rows as blocks that need no device program
+(``gradient_descent.StagedAhead``); they land under the running fit, and the
+fold waits only for what is left of the copy.  Spans (``obs.spans``):
+``stream.run`` is all of ``train_on``; on its thread ``stream.wait`` (the
+worker's answer, then the blocks made whole), ``stream.batch`` (``index``,
+``rows``, ``ahead``: 1 where the worker was issuing this batch's copy
+before the previous batch's fit returned) around the fit's
+own spans and ``stream.publish`` (the stream position, the history's tail,
+the checkpoint, the listeners); on the worker's ``stream.stage`` (``bytes``,
+``blocks`` of a batch that went ahead).
+
 Driver recovery (SURVEY.md §5.4c): the reference rides DStream
 checkpointing — a restarted driver resumes from the latest model and
 stream position.  The analogue here is ``set_checkpoint`` (persist the
@@ -22,15 +36,56 @@ exactly, because each micro-batch update is deterministic in
 
 from __future__ import annotations
 
+import itertools
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from typing import Iterable, Iterator, Optional, Tuple
 
+import jax
 import numpy as np
 
+from tpu_sgd import plan as plan_mod
 from tpu_sgd.models.classification import LogisticRegressionWithSGD
-from tpu_sgd.models.glm import GeneralizedLinearAlgorithm, GeneralizedLinearModel
+from tpu_sgd.models.glm import (GeneralizedLinearAlgorithm,
+                                GeneralizedLinearModel, as_features,
+                                off_stock)
 from tpu_sgd.models.regression import LinearRegressionWithSGD
+from tpu_sgd.obs.spans import span
+from tpu_sgd.optimize.gradient_descent import StagedAhead
 
 Batch = Tuple[np.ndarray, np.ndarray]
+
+
+def _take(batches, began: threading.Event, stage: bool, training: list):
+    """On ``train_on``'s worker thread: the stream's next micro-batch
+    ``(X, y)``, None at its end.  Where ``stage`` allows it, dense host rows
+    that fit the device's free memory come back as a :class:`StagedAhead`,
+    their copy issued in blocks that need no device program; ``began`` is
+    set before the first block is issued.  BCOO features, device arrays, an
+    empty batch and one too large to lie beside the batch in training are
+    handed on as they are (the fit copies what it must, in turn).
+
+    ``training`` holds the features the fold is about to train (taken out
+    of it here, so that no reference outlives the wait): the first block is
+    issued only once they are whole on the device, when the blocks they were
+    made of are gone.  Transfers do not wait for the chip, so a late join
+    (its last blocks still on the wire, a stalled device) would else have a
+    third micro-batch land beside its blocks and its result (12.6 GB read
+    in one traced run; PERF.md, PR 40)."""
+    with span("stream.stage") as sp:
+        batch = next(batches, None)
+        if batch is None:
+            return None
+        X, y = batch
+        X = as_features(X)
+        if (stage and isinstance(X, np.ndarray) and X.ndim == 2
+                and X.shape[0]
+                and X.nbytes <= plan_mod.device_budget()[0]):
+            jax.block_until_ready(training.pop())
+            began.set()
+            X = StagedAhead(X)
+            sp.set(bytes=X.nbytes, blocks=len(X.blocks))
+        return X, y
 
 
 class StreamingLinearAlgorithm:
@@ -188,27 +243,34 @@ class StreamingLinearAlgorithm:
 
     def train_on_batch(self, X, y) -> GeneralizedLinearModel:
         """One micro-batch update (the body of the reference's foreachRDD);
-        accepts dense or sparse (BCOO) feature batches.  EVERY batch —
+        accepts dense or sparse (BCOO) feature batches, on the host or on
+        the device (a device array stays there).  EVERY batch —
         including an empty one, whose update is skipped like the
         reference skips empty RDDs — advances ``_batch_count``, so the
         count is the STREAM POSITION and a resumed replay's skip stays
         aligned with the consumed prefix."""
-        from tpu_sgd.ops.sparse import is_sparse
-
-        if not is_sparse(X):
-            X = np.asarray(X)
-        if X.shape[0] == 0:  # reference skips empty RDDs (no update)
-            self._batch_count += 1
-            self._maybe_checkpoint()
-            return self.model
-        self.model = self.algorithm.run_warm((X, np.asarray(y)), self.model)
-        self._batch_count += 1
-        hist = getattr(self.algorithm.optimizer, "loss_history", None)
-        if hist is not None and len(hist):
-            self.loss_history.append(float(hist[-1]))
-        self._maybe_checkpoint()
-        self.on_model_update()
+        self._publish(self._fit(as_features(X), y))
         return self.model
+
+    def _fit(self, X, y) -> bool:
+        """The batch optimizer from the latest model over one micro-batch;
+        False for an empty one (no update)."""
+        if X.shape[0] == 0:
+            return False
+        self.model = self.algorithm.run_warm((X, y), self.model)
+        return True
+
+    def _publish(self, updated: bool) -> None:
+        """The stream position, and for a batch that updated the model the
+        history's tail, the checkpoint and the listeners, in that order."""
+        with span("stream.publish"):
+            self._batch_count += 1
+            hist = getattr(self.algorithm.optimizer, "loss_history", None)
+            if updated and hist is not None and len(hist):
+                self.loss_history.append(float(hist[-1]))
+            self._maybe_checkpoint()
+            if updated:
+                self.on_model_update()
 
     def train_on(self, stream: Iterable[Batch],
                  skip: Optional[int] = None) -> GeneralizedLinearModel:
@@ -219,15 +281,75 @@ class StreamingLinearAlgorithm:
         :meth:`resume_from` (so a stream replayed from the beginning
         continues where the interrupted run stopped); pass ``0`` for a
         live stream that only yields new batches.  The resume skip is
-        consumed by the first ``train_on`` call."""
+        consumed by the first ``train_on`` call.
+
+        The fold is ``train_on_batch`` over the batches in order, and it
+        holds ONE micro-batch ahead: a micro-batch does not depend on the
+        weights, so while batch k trains a worker thread takes batch k+1
+        from the stream and issues the host-to-device copy of its dense
+        rows (``_take``), which lands under the running fit; the fold then
+        waits only for what of the copy is left.  The device holds the
+        batch in training and the one ahead, never a third; the weights,
+        the listeners' calls and the checkpoints are the in-turn fold's
+        (a batch taken ahead and not yet trained has not advanced
+        ``_batch_count``)."""
         if skip is None:
             skip = self._resume_skip
         self._resume_skip = 0
-        for i, (X, y) in enumerate(stream):
-            if i < skip:
-                continue
-            self.train_on_batch(X, y)
+        batches = itertools.islice(stream, skip, None)
+        pool = ThreadPoolExecutor(1, thread_name_prefix="tpu-sgd-stream")
+        try:
+            with span("stream.run"):
+                self._fold_ahead(pool, batches)
+        finally:
+            pool.shutdown(wait=False, cancel_futures=True)
         return self.model
+
+    def _stages_ahead(self) -> bool:
+        """Whether a micro-batch may lie on the device beside the one in
+        training: on the stock resident schedule of one device alone, and
+        once a plan (or ``set_schedule("off")``) has said that this is the
+        schedule.  The planner's other schedules size their own device
+        state (a statistics stack, streamed chunks) from the memory that
+        was free when they planned: the statistics' build over a 4.19 GB
+        micro-batch ran out of memory on the chip with a second one staged
+        beside it (PERF.md, PR 40).  Until a stream's first fit has planned,
+        its micro-batches are copied inside their fits, in turn."""
+        opt = self.algorithm.optimizer
+        if getattr(opt, "mesh", None) is not None or off_stock(opt):
+            return False
+        return (self.algorithm.schedule == "off"
+                or getattr(opt, "last_plan", None) is not None)
+
+    def _fold_ahead(self, pool, batches) -> None:
+        """``train_on``'s loop under its ``stream.run`` span, whose leaves
+        tile it: ``stream.wait`` (the worker's answer and the blocks made
+        whole), then in ``stream.batch`` the fit's own and
+        ``stream.publish``; ``stream.stage`` is the worker's."""
+        def take(training=None):
+            began = threading.Event()
+            return began, pool.submit(_take, batches, began,
+                                      self._stages_ahead(), [training])
+
+        began, ahead = take()
+        under = 0  # 1: this batch's copy began under its predecessor's fit
+        while True:
+            with span("stream.wait"):
+                taken = ahead.result()
+                if taken is None:
+                    return
+                X, y = taken
+                if isinstance(X, StagedAhead):
+                    X = X.whole()
+            began, ahead = take(X)
+            with span("stream.batch") as turn:
+                if turn.live:
+                    turn.set(index=self._batch_count, rows=X.shape[0],
+                             ahead=under)
+                updated = self._fit(X, y)
+                under = int(began.is_set())
+                del taken, X, y  # gone before the next is made whole
+                self._publish(updated)
 
     def predict_on(self, stream: Iterable[np.ndarray]) -> Iterator[np.ndarray]:
         """Lazily map prediction over a stream of feature batches, using the

@@ -130,6 +130,17 @@ def _stage_block(dest, block, offset):
         return dest, dest[offset, 0]
 
 
+def _block_rows(X) -> int:
+    """The rows of one block of a dense numpy array's hand-off; 0 where it
+    goes in one piece (no matrix, or one block holds it all)."""
+    if X.ndim != 2 or X.nbytes <= _STAGE_BLOCK_BYTES:
+        return 0
+    row_bytes = X.nbytes // X.shape[0]
+    rows = _STAGE_ROWS * max(
+        1, _STAGE_BLOCK_BYTES // row_bytes // _STAGE_ROWS)
+    return rows if rows < X.shape[0] else 0
+
+
 def _stage_dense(X, h2d):
     """Dense features on the device as ONE ``(N, d)`` array, and what the
     ``train.h2d`` span says of the copy: ``(X, blocks, block_bytes)``.
@@ -155,13 +166,10 @@ def _stage_dense(X, h2d):
         h2d.set(stalls=0, stall_ms=0.0)
     if not isinstance(X, np.ndarray):
         return jnp.asarray(X), 0, 0
-    n = rows = 0
-    if X.ndim == 2 and X.nbytes > _STAGE_BLOCK_BYTES:
-        n, row_bytes = X.shape[0], X.nbytes // X.shape[0]
-        rows = _STAGE_ROWS * max(
-            1, _STAGE_BLOCK_BYTES // row_bytes // _STAGE_ROWS)
-    if rows >= n:  # one block holds it all
+    rows = _block_rows(X)
+    if not rows:  # one block holds it all
         return jnp.asarray(X), 1, X.nbytes
+    n, row_bytes = X.shape[0], X.nbytes // X.shape[0]
     dest, writes = None, collections.deque()
     stalls, stall_s = 0, 0.0
     for a in range(0, n, rows):
@@ -181,6 +189,55 @@ def _stage_dense(X, h2d):
     if timed:
         h2d.set(stalls=stalls, stall_ms=round(stall_s * 1e3, 4))
     return dest, -(-n // rows), rows * row_bytes
+
+
+@jax.jit
+def _stage_join(*blocks):
+    """``StagedAhead``'s row blocks as the one ``(N, d)`` array: one write a
+    block and no fill (12.6 ms of the v5e's for 128 blocks of 32.8 MB).  The
+    chip's compiler leaves the scope on the last of the writes alone, so the
+    others read ``(unscoped)`` in a trace; written out as a chain of
+    ``dynamic_update_slice`` into zeros they all keep it, and the chain
+    starts with a fill of the whole array (6.7 ms more: PERF.md, PR 40)."""
+    with jax.named_scope("sgd.stage"):
+        return jnp.concatenate(blocks, axis=0)
+
+
+class StagedAhead:
+    """A dense host array on its way to the device AHEAD of the fit that
+    will train it, under a fit that is running (``StreamingLinearAlgorithm
+    .train_on``).  The device runs one program at a time, so
+    ``_stage_dense``'s in-place writes would queue behind the running
+    ``sgd_run`` and its flow control stall the host after
+    ``_STAGE_IN_FLIGHT`` blocks; here the row blocks are ``_stage_dense``'s,
+    each a device array of its own: transfers that need no device program
+    and land while the chip computes.  ``whole()`` makes them the one
+    ``(N, d)`` array once the chip is free, in ONE program (``_stage_join``:
+    13.9 ms of the host's time on the v5e, where the 128 in-place writes and
+    their destination's fill take 68: PERF.md, PR 40); the values are
+    ``_stage_dense``'s bit for bit.  The device then holds the array and,
+    until the program has run, its blocks: the batch twice for those
+    milliseconds, which is why the caller drops the batch it trained
+    first."""
+
+    def __init__(self, X):
+        rows = _block_rows(X) or X.shape[0]
+        self.nbytes, self.blocks = X.nbytes, []
+        for a in range(0, X.shape[0], rows):
+            if len(self.blocks) >= _STAGE_IN_FLIGHT:
+                # flow control: what the runtime re-tiles at once
+                self.blocks[-_STAGE_IN_FLIGHT].block_until_ready()
+            self.blocks.append(jnp.asarray(X[a:a + rows]))
+
+    def whole(self):
+        """The one device array; the blocks are given up."""
+        blocks, self.blocks = self.blocks, None
+        if len(blocks) == 1:
+            return blocks[0]
+        out = _stage_join(*blocks)
+        for block in blocks:
+            block.delete()
+        return out
 
 
 def _sample_key(key, i, axis_name, shard_index=None):
