@@ -790,6 +790,56 @@ def test_a_batch_staged_ahead_is_made_whole_in_one_program(S):
         == {by_features}
 
 
+@pytest.mark.parametrize("case", ["stream_cell", "by_rows", "f32"])
+def test_the_statistics_build_reads_x_where_it_lies(S, case):
+    """``ops.gram._stats_build`` (PR 41) at the stream cell's micro-batch
+    (2,097,152 x 1000 bf16, which the chip stores feature-major: ``X.T`` is a
+    bitcast), at a width it stores by rows, and over f32 rows: X is an
+    operand of the products as it lies, no copy, and NO temporary of X's size
+    (the build runs beside a micro-batch staged ahead: 8.4 GB of a 16 GB
+    chip are rows); every operation carries the scope."""
+    from tpu_sgd.ops import gram
+
+    n, d, dtype = {"stream_cell": (2_097_152, D, BF16),
+                   "by_rows": (2_097_152, 1024, BF16),
+                   "f32": (N, D, F32)}[case]
+    compiled = gram._stats_build.lower(S((n, d), dtype),
+                                       S((n,), F32)).compile()
+    memory = compiled.memory_analysis()
+    item = jnp.dtype(dtype).itemsize
+    assert memory.temp_size_in_bytes < 64 << 20 < n * d * item // 16
+    assert memory.output_size_in_bytes < 3 * (d * d + d + 1) * 4
+    text = compiled.as_text()
+    assert " copy(" not in text and " transpose(" not in text
+    assert text.count(" convolution(") == (2 if dtype == BF16 else 1)
+    assert "sgd.stats_build/dot_general" in text
+    by_features, by_rows = (1, 0), (0, 1)
+    assert compiled.input_formats[0][0].layout.major_to_minor == (
+        by_rows if case == "by_rows" else by_features)
+
+
+def test_a_fit_from_the_totals_holds_nothing_of_xs_size(S):
+    """``sgd_run`` over the totals' bundle at the stream cell's shape: its
+    arguments are G, b, yy, the labels and the weights (12.4 MB), so a fit
+    that runs while the next micro-batch lands holds none of the rows."""
+    from tpu_sgd.ops.gram import GramData, GramLeastSquaresGradient
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    n = 2_097_152
+    run = jax.jit(make_run(
+        GramLeastSquaresGradient(), SimpleUpdater(),
+        _cfg(step_size=0.1, num_iterations=50, mini_batch_fraction=1.0,
+             reg_param=0.0, convergence_tol=0.0)))
+    stats = GramData(None, None, None, None, S((D, D), F32), S((D,), F32),
+                     S((), F32), n, logical_shape=(n, D),
+                     logical_dtype=BF16)
+    compiled = run.lower(S((D,), F32), stats, S((n,), F32)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 4 * n + 2 * (D * D + 2 * D) * 4
+    assert memory.temp_size_in_bytes < 16 << 20
+    assert "sgd.stats_sums/dot_general" in compiled.as_text()
+
+
 # -- sparse ------------------------------------------------------------------
 
 def test_sparse_hinge_l1_step_compiles(S):

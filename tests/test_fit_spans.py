@@ -131,9 +131,13 @@ def test_under_a_mesh_the_placement_comes_before_the_selection(sink, data):
     assert (run["path"], run["shards"]) == ("mesh", 4)
 
 
-def test_the_statistics_substitution_is_inside_the_selection(sink):
-    """``_maybe_gram`` builds its statistics under ``train.select``, and the
-    fit runs the substituted gradient as it did."""
+@pytest.mark.parametrize("form", ["totals", "prefix"])
+def test_the_statistics_build_is_a_leaf_before_the_selection(sink, form):
+    """``_maybe_gram`` builds its statistics under ``train.stats`` (PR 41: a
+    leaf of its own between the hand-off and ``train.select``, which it was
+    inside), and the fit runs the substituted gradient as it did.  ``stats``
+    says whether it ran from the totals (a full batch) or, 0, from the
+    prefix form (sliced windows)."""
     rng = np.random.default_rng(3)
     X = jnp.asarray(rng.normal(size=(512, D)).astype(np.float32))
     y = X @ jnp.arange(D, dtype=jnp.float32)
@@ -141,16 +145,33 @@ def test_the_statistics_substitution_is_inside_the_selection(sink):
                                    tpu_sgd.SimpleUpdater())
            .set_step_size(0.1).set_num_iterations(5)
            .set_convergence_tol(0.0).set_sufficient_stats(True))
-    built = []
+    if form == "prefix":
+        opt.set_mini_batch_fraction(0.5).set_sampling("sliced")
+    asked = []
     real = opt._maybe_gram
-    opt._maybe_gram = lambda *a: built.append(
+    opt._maybe_gram = lambda *a: asked.append(
         obs_spans._stack()[-1].name) or real(*a)
     gradient = opt.gradient
     opt.optimize_with_history((X, y), np.zeros(D, np.float32))
-    assert built == ["train.select"] and opt.gradient is gradient
+    assert asked == ["train.run"] and opt.gradient is gradient
     run, = sink.named("train.run")
+    select, = sink.named("train.select")
+    build, = sink.named("train.stats")
     assert run["path"] == "gram"
-    _check_tiling(sink.records, OPTIMIZER_LEAVES, {"train.run"})
+    assert run["stats"] == select["stats"] == int(form == "totals")
+    assert (build["bytes"], build["rows"]) == (X.nbytes + y.nbytes, 512)
+    _check_tiling(sink.records,
+                  ["train.h2d", "train.stats", "train.select",
+                   "train.dispatch", "train.fetch"], {"train.run"})
+
+
+def test_a_fit_that_builds_no_statistics_has_no_such_leaf(sink, data):
+    X, y = data
+    _opt().optimize_with_history((jnp.asarray(X), jnp.asarray(y)),
+                                 np.zeros(D, np.float32))
+    assert not sink.named("train.stats")
+    run, = sink.named("train.run")
+    assert run["stats"] == 0 and run["path"] == "fused"
 
 
 # -- tracing off ---------------------------------------------------------------

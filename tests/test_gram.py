@@ -867,19 +867,28 @@ def test_unbound_gram_gradient_runs_stock_in_optimizers(rng):
     assert np.all(np.isfinite(np.asarray(wu))) and len(hu) >= 1
 
 
-def test_release_sufficient_stats_frees_cache(rng):
+@pytest.mark.parametrize("form", ["prefix", "totals"])
+def test_release_sufficient_stats_frees_cache(rng, form):
     """``release_sufficient_stats`` drops the identity-cached bundles (and
     gram-keyed compiled runners); the next run rebuilds and reproduces the
-    same trajectory."""
+    same trajectory.  Sliced windows cache their prefix form; a full batch
+    (PR 41) builds its totals anew every fit and caches nothing but its one
+    unbound executor's runner."""
     X, y, _ = _data(rng, n=512, d=8)
 
     opt = (GradientDescent(LeastSquaresGradient(), SimpleUpdater())
            .set_step_size(0.2).set_num_iterations(6)
            .set_convergence_tol(0.0).set_sufficient_stats(True))
+    if form == "prefix":
+        opt.set_mini_batch_fraction(0.5).set_sampling("sliced")
     w1, h1 = opt.optimize_with_history((X, y), jnp.zeros((8,)))
-    assert opt._gram_entry is not None
+    assert (opt._gram_entry is not None) == (form == "prefix")
+    assert (opt._totals_gradient is not None) == (form == "totals")
+    assert any(isinstance(part, GramLeastSquaresGradient)
+               for k in opt._run_cache for part in k)
     opt.release_sufficient_stats()
     assert opt._gram_entry is None and opt._gram_dp_entry is None
+    assert opt._totals_gradient is None
     assert not any(
         isinstance(part, GramLeastSquaresGradient)
         for k in opt._run_cache for part in k
@@ -1204,3 +1213,107 @@ def test_single_block_virtual_stats_warn_on_sliced(rng):
         _w.simplefilter("always")
         opt.optimize_with_history((g.data, y), np.zeros(8, np.float32))
     assert any("degenerate to FULL-BATCH" in str(r.message) for r in rec)
+
+
+# ---- the totals form: one read, the configuration's precision (PR 41) --------
+
+def _bf16_rows(rng, n, d):
+    X = jnp.asarray(rng.normal(size=(n, d)), jnp.bfloat16)
+    w = rng.uniform(-1, 1, size=(d,)).astype(np.float32)
+    y = jnp.asarray(np.asarray(X, np.float32) @ w
+                    + 0.1 * rng.normal(size=(n,)).astype(np.float32))
+    return X, y
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.abs(got - want).max() / np.abs(want).max()
+
+
+@pytest.mark.parametrize("n,d", [(1000, 16), (4096, 64), (777, 8)])
+def test_the_bf16_build_is_the_f32_highest_totals(rng, n, d):
+    """One bf16 pass with f32 sums over bf16 rows (products of two bf16
+    numbers are exact in f32) against ``_total_stats`` at f32 ``HIGHEST``
+    over the rows upcast, and both against the float64 values; ``y`` goes
+    in unrounded (its three bf16 parts add up to it)."""
+    from tpu_sgd.ops.gram import stats_build
+
+    X, y = _bf16_rows(rng, n, d)
+    st = stats_build(X, y)
+    assert st.X is None and st.PG is None and st.Pb is None
+    assert st.shape == (n, d) and st.dtype == jnp.bfloat16
+    assert st.G_tot.dtype == st.b_tot.dtype == st.yy_tot.dtype == jnp.float32
+    ref = GramLeastSquaresGradient._total_stats(
+        X, y, B=256, stats_dtype=jnp.float32)
+    X64, y64 = np.asarray(X, np.float64), np.asarray(y, np.float64)
+    exact = (X64.T @ X64, X64.T @ y64, y64 @ y64)
+    for got, want, true in zip((st.G_tot, st.b_tot, st.yy_tot), ref, exact):
+        assert _rel(got, true) < 1e-6
+        assert _rel(got, want) < 1e-6 + _rel(want, true)
+    # a y rounded to bf16 ONCE would be 200 times further out
+    rounded = X64.T @ np.asarray(y.astype(jnp.bfloat16), np.float64)
+    assert _rel(rounded, exact[1]) > 100 * _rel(st.b_tot, exact[1])
+
+
+def test_the_build_of_f32_rows_keeps_highest(rng):
+    from tpu_sgd.ops.gram import stats_build
+
+    X, y, _ = _data(rng, n=1000, d=16)
+    st = stats_build(X, y)
+    ref = GramLeastSquaresGradient._total_stats(
+        X, y, B=256, stats_dtype=jnp.float32)
+    for got, want in zip((st.G_tot, st.b_tot, st.yy_tot), ref):
+        assert _rel(got, want) < 1e-6
+    text = jax.jit(lambda X, y: stats_build(X, y).G_tot).lower(X, y).as_text()
+    assert "HIGHEST" in text
+
+
+def test_the_build_reads_x_where_it_lies():
+    """The program of the build at the stream cell's micro-batch: X goes
+    into two ``dot_general``s as it is, contracted along its rows; nothing
+    of X's size is converted, transposed, sliced or reshaped on the way
+    (the chip's compile of it is pinned in ``tests/test_chip_compile.py``)."""
+    from tpu_sgd.ops import gram
+
+    n, d = 2_097_152, 1000
+    jaxpr = jax.make_jaxpr(gram._stats_build)(
+        jax.ShapeDtypeStruct((n, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((n,), jnp.float32))
+    inner, = [e for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
+    eqns = inner.params["jaxpr"].eqns
+    X = inner.params["jaxpr"].jaxpr.invars[0]
+    uses = [e for e in eqns if any(v is X for v in e.invars)]
+    assert [e.primitive.name for e in uses] == ["dot_general", "dot_general"]
+    for e in uses:
+        (lhs, rhs), _ = e.params["dimension_numbers"]
+        rows = (lhs, rhs) if e.invars[0] is X else (rhs, lhs)
+        assert rows[0] == (0,) and e.params["precision"] is None
+        assert e.params["preferred_element_type"] == jnp.float32
+    made = [v.aval.size for e in eqns for v in e.outvars]
+    assert max(made) == 3 * n  # y's three bf16 parts: 12.6 MB
+
+
+def test_the_totals_form_serves_a_full_batch_and_no_window(rng):
+    from tpu_sgd.ops.gram import stats_build
+
+    X, y = _bf16_rows(rng, 512, 8)
+    w = jnp.asarray(rng.uniform(-1, 1, 8), jnp.float32)
+    st, unbound = stats_build(X, y), GramLeastSquaresGradient()
+    g, l, c = unbound.batch_sums(st, y, w)
+    g0, l0, c0 = GramLeastSquaresGradient.build(X, y, block_rows=64) \
+        .batch_sums(X, y, w)
+    np.testing.assert_allclose(g, g0, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(l, l0, rtol=1e-5)
+    assert float(c) == float(c0) == 512.0
+    with pytest.raises(NotImplementedError, match="full-batch sums only"):
+        unbound.window_sums(st, y, w, jnp.int32(0), 64)
+    with pytest.raises(ValueError, match="no prefix stack"):
+        st.save("/nonexistent")
+    opt = (GradientDescent(unbound, SimpleUpdater()).set_num_iterations(3)
+           .set_mini_batch_fraction(0.5).set_sampling("sliced"))
+    with pytest.raises(NotImplementedError, match="full-batch fits"):
+        opt.optimize_with_history((st, y), np.zeros(8, np.float32))
+    # and it is a pytree whose aux says nothing of the data's values
+    leaves, tree = jax.tree_util.tree_flatten(st)
+    assert len(leaves) == 3
+    assert tree == jax.tree_util.tree_structure(stats_build(X[::-1], y))

@@ -155,7 +155,8 @@ def test_multihost_gram_dp_matches_single_process(worker_results):
     opt = make_gd().set_sufficient_stats(True).set_gram_options(
         block_rows=4)
     w_ref, hist_ref = opt.optimize_with_history((Xg, yg), w0)
-    assert opt._gram_entry is not None  # single-device gram engaged
+    # single-device gram engaged: a full batch, so the totals form (PR 41)
+    assert opt._totals_gradient is not None
     r = worker_results[0]
     np.testing.assert_allclose(np.asarray(r["gram_w"]),
                                np.asarray(w_ref), rtol=2e-4, atol=2e-5)

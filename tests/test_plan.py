@@ -36,33 +36,51 @@ GB = 1e9
 # ---- pure decision boundaries --------------------------------------------
 
 def test_resident_gram_for_big_least_squares_full_batch():
+    """A full batch on one device reads the totals alone (PR 41): no
+    prefix stack, so no block size."""
     p = plan(3_000_000, 1000, itemsize=2, gram_able=True,
              mini_batch_fraction=1.0, num_iterations=5000,
              free_hbm=12 * GB)
     assert p.schedule == "resident_gram"
     assert not p.aligned  # exact mode is the default
-    assert p.block_rows is not None
+    assert p.estimates["stats_form"] == "totals" and p.block_rows is None
     assert p.estimates["build_amortize_iters"] < 5000
-    assert "fits" in p.reason and "B=" in p.reason
+    assert "fits" in p.reason and "totals" in p.reason
 
 
 def test_short_run_amortization_keeps_stock():
     """The one-time statistics build must pay for itself inside the run
-    (VERDICT r3 #1: warn/avoid when build_amortize_iters > iterations)."""
+    (VERDICT r3 #1: warn/avoid when build_amortize_iters > iterations).
+    Re-derived in PR 41: the totals' build over 3M x 1000 bf16 is ~40 ms
+    by the chip's own terms (a launch, one read of 6 GB and 6e12 operations
+    at the measured bf16 rate) against 16.4 ms saved an iteration, so it
+    pays from the third iteration on (it was 1.4 s and ~90 iterations by
+    the remote attachment's)."""
     p = plan(3_000_000, 1000, itemsize=2, gram_able=True,
-             mini_batch_fraction=1.0, num_iterations=50,
+             mini_batch_fraction=1.0, num_iterations=2,
              free_hbm=12 * GB)
     assert p.schedule == "resident_stock"
     assert "amortize" in p.reason
-    assert p.estimates["build_amortize_iters"] > 50
+    assert 2 < p.estimates["build_amortize_iters"] < 3
+    p = plan(3_000_000, 1000, itemsize=2, gram_able=True,
+             mini_batch_fraction=1.0, num_iterations=50,
+             free_hbm=12 * GB)
+    assert p.schedule == "resident_gram"
 
 
-def test_small_problem_keeps_stock():
-    """Tiny datasets stay on the bitwise round-2 stock path — the build
-    overhead dominates any per-iteration saving."""
-    p = plan(100_000, 100, gram_able=True, num_iterations=100,
+@pytest.mark.parametrize("n,iterations", [(100_000, 20), (10_000, 10_000)],
+                         ids=["short", "tiny"])
+def test_small_problem_keeps_stock(n, iterations):
+    """Tiny datasets stay on the bitwise round-2 stock path.  Re-derived in
+    PR 41: at 100,000 x 100 f32 the totals' build (1.4 ms: a launch, one
+    read of 40 MB, 2e9 operations at HIGHEST) pays from iteration 23 on,
+    so a run of 20 keeps stock (by the old 1.2 s no run did); at 10,000
+    rows a stock iteration reads 8 MB, under the statistics' own
+    per-iteration cost, and no length pays."""
+    p = plan(n, 100, gram_able=True, num_iterations=iterations,
              free_hbm=12 * GB)
     assert p.schedule == "resident_stock"
+    assert p.estimates["build_amortize_iters"] > iterations
 
 
 def test_non_least_squares_never_grams():
@@ -171,8 +189,9 @@ def test_huge_d_disqualifies_gram():
 def test_force_overrides_with_warning():
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
+        # PR 41: the totals' build pays from the third iteration on
         p = plan(3_000_000, 1000, itemsize=2, gram_able=True,
-                 mini_batch_fraction=1.0, num_iterations=50,
+                 mini_batch_fraction=1.0, num_iterations=2,
                  free_hbm=12 * GB, force="resident_gram")
     assert p.schedule == "resident_gram"
     assert any("NET LOSS" in str(r.message) for r in rec)
@@ -471,7 +490,10 @@ def test_gram_options_rebuild_on_change(rng):
     X = rng.normal(size=(1024, 8)).astype(np.float32)
     w = rng.uniform(-1, 1, 8).astype(np.float32)
     y = (X @ w).astype(np.float32)
+    # sliced windows: the prefix form (a full batch takes the totals, which
+    # have no block size and are built anew every fit: PR 41)
     opt = (GradientDescent().set_num_iterations(5)
+           .set_mini_batch_fraction(0.5).set_sampling("sliced")
            .set_sufficient_stats(True).set_gram_options(block_rows=128))
     opt.optimize((X, y), jnp.zeros((8,)))
     g1 = opt._gram_entry[2]
@@ -1139,3 +1161,123 @@ def test_a_batch_staged_on_the_device_keeps_the_plan(rng, monkeypatch):
     hosted = LinearRegressionWithSGD(0.2, 5)
     hosted._apply_plan(X, y)
     assert hosted.optimizer.last_plan.schedule == "host_streamed"
+
+
+# ---- the statistics' totals form, on the chip's own terms (PR 41) ---------
+
+CELL_N, CELL_D = 2_097_152, 1000  # the stream cell's micro-batch, bf16
+
+
+def _plan_for_the_cell(case):
+    """``plan_for`` of an optimizer over an array of the stream cell's
+    micro-batch shape that holds no memory: a broadcast view of one bf16
+    zero on the host, an abstract value on the device."""
+    import jax
+    import ml_dtypes
+
+    from tpu_sgd import (GradientDescent, LeastSquaresGradient,
+                         LogisticGradient, SimpleUpdater, data_mesh)
+
+    opt = (GradientDescent(LeastSquaresGradient(), SimpleUpdater())
+           .set_step_size(0.1).set_num_iterations(50)
+           .set_convergence_tol(0.0))
+    if case == "bernoulli_tenth":
+        opt.set_mini_batch_fraction(0.1)
+    elif case == "sliced_tenth":
+        opt.set_mini_batch_fraction(0.1).set_sampling("sliced")
+        opt.set_num_iterations(10_000)
+    elif case == "logistic":
+        opt.set_gradient(LogisticGradient())
+    elif case == "mesh":
+        opt.set_mesh(data_mesh(jax.devices()[:4]))
+    elif case == "two_iterations":
+        opt.set_num_iterations(2)
+    y = np.broadcast_to(np.float32(0), (CELL_N,))
+    if case == "cell_device":
+        made = []
+
+        def ask(X):
+            assert isinstance(X, jax.Array)
+            made.append(plan_for(opt, X, y))
+            return 0
+
+        jax.eval_shape(ask, jax.ShapeDtypeStruct((CELL_N, CELL_D),
+                                                 jnp.bfloat16))
+        return made[0]
+    X = np.broadcast_to(np.zeros((), ml_dtypes.bfloat16), (CELL_N, CELL_D))
+    return plan_for(opt, X, y)
+
+
+@pytest.mark.parametrize("case,schedule,form", [
+    ("cell_host", "resident_gram", "totals"),
+    ("cell_device", "resident_gram", "totals"),
+    ("bernoulli_tenth", "resident_stock", None),
+    ("logistic", "resident_stock", None),
+    ("mesh", "resident_stock", "prefix"),
+    ("two_iterations", "resident_stock", "totals"),
+    ("sliced_tenth", "resident_gram", "prefix"),
+])
+def test_the_stream_cells_micro_batch_is_planned_on_the_chips_terms(
+        case, schedule, form):
+    """A full batch of least squares on one device runs from the totals of
+    its rows where one read's build pays inside the run; a Bernoulli
+    fraction, another loss, a mesh and a run of two iterations stay stock;
+    sliced windows keep the prefix form and its terms.  A bf16 array is
+    two bytes an element on the host as on the device."""
+    p = _plan_for_the_cell(case)
+    est = p.estimates
+    assert p.schedule == schedule
+    assert est["itemsize"] == 2 and est.get("stats_form") == form
+    assert (p.block_rows is not None) == (
+        schedule == "resident_gram" and form == "prefix")
+    if form == "totals":
+        # a launch, one read of 4.19 GB and 4.4e12 operations at the
+        # measured rate: 29.2 ms for the 27.94 + 1.1 measured (PR 41)
+        assert est["gram_build_s"] == pytest.approx(0.0292, abs=3e-4)
+        assert est["build_amortize_iters"] < 10
+    if case == "mesh":  # the prefix form's build, the attachment's 1.2 s
+        assert est["n_local"] == CELL_N // 4 and est["gram_build_s"] > 1.2
+
+
+@pytest.mark.parametrize("reads", [1, 2])
+def test_the_totals_pay_whether_a_stock_step_reads_its_rows_once_or_twice(
+        reads):
+    """On a TPU the cell's stock step is the one-read kernel (5.7 ms an
+    iteration by the model, 5.56 measured): the build still pays from the
+    sixth iteration on, of 50."""
+    p = plan(CELL_N, CELL_D, itemsize=2, gram_able=True,
+             mini_batch_fraction=1.0, num_iterations=50, free_hbm=12 * GB,
+             stock_reads=reads)
+    est = p.estimates
+    assert p.schedule == "resident_gram" and est["stock_reads"] == reads
+    assert est["stock_iter_s"] == pytest.approx(
+        reads * CELL_N * CELL_D * 2 / 730e9)
+    assert est["build_amortize_iters"] < (6 if reads == 1 else 3)
+    # 12 MB of statistics beside the rows: it fits where a prefix stack
+    # (514 prefixes of 4 MB at blocks of 4,096 rows) would not
+    tight = plan(CELL_N, CELL_D, itemsize=2, gram_able=True,
+                 mini_batch_fraction=1.0, num_iterations=50,
+                 free_hbm=CELL_N * (CELL_D * 2 + 4) + 13e6,
+                 stock_reads=reads)
+    assert tight.schedule == "resident_gram"
+    from tpu_sgd.plan import _stack_bytes, _totals_bytes
+
+    assert _totals_bytes(CELL_D) < 13e6 < 2e9 < _stack_bytes(CELL_N, 4096,
+                                                             CELL_D)
+
+
+def test_stock_reads_asks_the_steps_kernel_on_a_tpu_alone(monkeypatch):
+    import jax
+
+    from tpu_sgd import GradientDescent, LeastSquaresGradient, SimpleUpdater
+    from tpu_sgd.plan import _stock_reads
+
+    opt = GradientDescent(LeastSquaresGradient(), SimpleUpdater())
+    bf16 = jnp.dtype(jnp.bfloat16)
+    assert _stock_reads(opt, CELL_N, CELL_D, bf16) == 2  # a CPU: two matvecs
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _stock_reads(opt, CELL_N, CELL_D, bf16) == 1  # the one-read kernel
+    # 1,020 features: the chip stores X by rows and no kernel reads it once
+    assert _stock_reads(opt, CELL_N, 1020, bf16) == 2
+    opt.set_mini_batch_fraction(0.1).set_sampling("indexed")
+    assert _stock_reads(opt, CELL_N, CELL_D, bf16) == 2  # a gathered batch

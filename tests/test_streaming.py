@@ -607,10 +607,14 @@ def test_the_folds_spans_tile_a_pass(tmp_path):
                                     for s in staged)
 
 
-def test_batches_go_ahead_only_beside_the_stock_schedule(monkeypatch):
+def test_batches_go_ahead_only_beside_the_stock_and_totals_schedules(
+        monkeypatch):
     """Until a stream's first fit has planned, and on any schedule that
-    sizes device state of its own (here the statistics schedule, forced),
-    the micro-batches are copied inside their fits; the fold is the same."""
+    sizes device state of its own (the statistics' PREFIX form, forced over
+    sliced windows), the micro-batches are copied inside their fits; beside
+    the stock schedule and the statistics' TOTALS form (a full batch: 12 MB
+    of device state at d = 1000 and a build with no temporary; PR 41) they go
+    ahead; the fold is the same."""
     import warnings
 
     from tpu_sgd.models import streaming
@@ -626,10 +630,11 @@ def test_batches_go_ahead_only_beside_the_stock_schedule(monkeypatch):
 
     monkeypatch.setattr(streaming, "StagedAhead", Counted)
 
-    def fold(schedule):
-        alg = StreamingLinearRegressionWithSGD(step_size=0.3,
-                                               num_iterations=10)
+    def fold(schedule, fraction=1.0):
+        alg = StreamingLinearRegressionWithSGD(
+            step_size=0.3, num_iterations=10, mini_batch_fraction=fraction)
         alg.set_initial_weights(np.zeros(12, np.float32))
+        alg.algorithm.optimizer.set_sampling("sliced")
         alg.algorithm.set_schedule(schedule)
         del made[:]
         with warnings.catch_warnings():
@@ -643,10 +648,213 @@ def test_batches_go_ahead_only_beside_the_stock_schedule(monkeypatch):
     off, n_off = fold("off")
     assert n_off == 4  # nothing to wait for: the optimizer runs as it is
     gram, n_gram = fold("resident_gram")
-    assert n_gram == 0 and gram.algorithm.optimizer.sufficient_stats
-    assert not gram._stages_ahead()
+    opt = gram.algorithm.optimizer
+    assert opt.sufficient_stats and opt.stats_in_totals()
+    assert n_gram == 2 and gram._stages_ahead()  # as the stock schedule
+    prefix, n_prefix = fold("resident_gram", fraction=0.5)
+    opt = prefix.algorithm.optimizer
+    assert opt.sufficient_stats and not opt.stats_in_totals()
+    assert n_prefix == 0 and not prefix._stages_ahead()
+    for flag in ("host_streaming", "streamed_stats"):  # beside the totals
+        setattr(gram.algorithm.optimizer, flag, True)
+        assert not gram._stages_ahead()
+        setattr(gram.algorithm.optimizer, flag, False)
     np.testing.assert_array_equal(np.asarray(auto.latest_model().weights),
                                   np.asarray(off.latest_model().weights))
     np.testing.assert_allclose(np.asarray(gram.latest_model().weights),
                                np.asarray(auto.latest_model().weights),
                                rtol=1e-3, atol=1e-4)
+
+
+# ---- the statistics schedule in its totals form (PR 41) ----------------------
+
+def _statistics_fold(stream, how="ahead", d=6, iterations=8):
+    """A fold of ``stream`` on the statistics schedule (forced: the sizes
+    are tiny) and what its listener saw."""
+    import warnings
+
+    alg = StreamingLinearRegressionWithSGD(step_size=0.2,
+                                           num_iterations=iterations)
+    alg.set_initial_weights(np.zeros(d, np.float32))
+    alg.algorithm.set_schedule("resident_gram")
+    calls = []
+    alg.add_model_update_listener(
+        lambda model, count: calls.append(
+            (count, np.asarray(model.weights).copy(),
+             np.asarray(alg.algorithm.optimizer.loss_history).copy())))
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")  # forced: a net loss at these sizes
+        if how == "ahead":
+            alg.train_on(iter(stream))
+        else:
+            for X, y in stream:
+                alg.train_on_batch(X, y)
+    return alg, calls
+
+
+@pytest.mark.parametrize("kind", ["host", "device"])
+def test_the_statistics_fold_ahead_is_the_in_turn_fold_bit_for_bit(
+        kind, monkeypatch):
+    """As ``test_train_on_ahead_is_the_in_turn_fold_bit_for_bit``, under the
+    statistics schedule: a micro-batch staged ahead is built and trained by
+    the programs that build and train one copied in its turn."""
+    _blocked(monkeypatch, d=6)
+    stream = _mixed_stream(kind)
+    (ahead, calls_a) = _statistics_fold(stream, "ahead")
+    (turn, calls_t) = _statistics_fold(stream, "in turn")
+    for alg in (ahead, turn):
+        opt = alg.algorithm.optimizer
+        assert opt.last_plan.schedule == "resident_gram"
+        assert opt._totals_gradient is not None and opt._gram_entry is None
+    np.testing.assert_array_equal(np.asarray(ahead.latest_model().weights),
+                                  np.asarray(turn.latest_model().weights))
+    assert ahead.loss_history == turn.loss_history
+    assert ahead._batch_count == turn._batch_count == 5  # the empty one too
+    assert [c[0] for c in calls_a] == [c[0] for c in calls_t] == [1, 2, 4, 5]
+    for (_, wa, la), (_, wt, lt) in zip(calls_a, calls_t):
+        np.testing.assert_array_equal(wa, wt)
+        np.testing.assert_array_equal(la, lt)
+
+
+def test_micro_batches_of_one_shape_share_one_runner_and_one_build(
+        monkeypatch):
+    """Three micro-batches of one shape on the statistics schedule: ONE
+    runner in ``_run_cache`` (its key holds the optimizer's one unbound
+    executor, nothing of a micro-batch), ONE compiled build, no compile
+    request of a fit's after the first micro-batch's (the third's is the
+    fold's: the first micro-batch to go ahead is the first to be joined),
+    and none at all in a second pass."""
+    import warnings
+
+    from jax._src import monitoring
+
+    from tpu_sgd.ops import gram
+
+    # a width no other test of the process has compiled for: the counts
+    # below are of THIS stream's programs
+    _blocked(monkeypatch, d=7)
+    stream = [b for b in _mixed_stream("host", d=7, batches=4)
+              if b[0].shape[0]]
+    assert len(stream) == 3
+    builds = gram._stats_build._cache_size()
+    requests, seen = [0], []
+
+    def count(event, duration, **kw):
+        requests[0] += event == "/jax/core/compile/backend_compile_duration"
+
+    monitoring.register_event_duration_secs_listener(count)
+    try:
+        alg = StreamingLinearRegressionWithSGD(step_size=0.2,
+                                               num_iterations=8)
+        alg.add_model_update_listener(
+            lambda model, count: seen.append(requests[0]))
+        alg.set_initial_weights(np.zeros(7, np.float32))
+        alg.algorithm.set_schedule("resident_gram")
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            alg.train_on(iter(stream))
+            alg.set_initial_weights(np.zeros(7, np.float32))
+            alg.train_on(iter(stream))  # a second pass, as the cell's
+    finally:
+        monitoring.unregister_event_duration_listener(count)
+    opt = alg.algorithm.optimizer
+    runners = [k for k in opt._run_cache if k[0] == "run"]
+    assert len(runners) == 1 and runners[0][1] is opt._totals_gradient
+    assert gram._stats_build._cache_size() == builds + 1
+    assert seen[0] > 0 and seen[1] == seen[0]
+    assert seen[2] == seen[0] + 1  # ``_stage_join``, once
+    assert seen[3:] == [seen[2]] * 3
+
+
+def test_at_most_two_micro_batches_are_alive_on_the_statistics_schedule(
+        monkeypatch):
+    """``test_at_most_two_micro_batches_are_alive_at_once`` under the
+    statistics schedule: the totals' bundle holds no rows, so after any fit
+    the device holds the rows of at most two micro-batches."""
+    import warnings
+
+    import jax
+
+    _blocked(monkeypatch, d=6)
+    stream = _mixed_stream("host", batches=6)
+    rows = 3 * 1024 + 100
+    alg = StreamingLinearRegressionWithSGD(step_size=0.2, num_iterations=4)
+    alg.set_initial_weights(np.zeros(6, np.float32))
+    alg.algorithm.set_schedule("resident_gram")
+    taken_at, alive = [], []
+
+    def batches():
+        for batch in stream:
+            taken_at.append(alg._batch_count)
+            yield batch
+
+    real = alg.algorithm.run_warm
+
+    def run_warm(data, model):
+        out = real(data, model)
+        alive.append(sum(a.shape[0] for a in jax.live_arrays()
+                         if a.ndim == 2 and a.shape[1] == 6
+                         and a.shape[0] > 6))  # rows, not the (6, 6) G
+        return out
+
+    monkeypatch.setattr(alg.algorithm, "run_warm", run_warm)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        alg.train_on(batches())
+    assert alg._batch_count == 6
+    assert alg.algorithm.optimizer._totals_gradient is not None
+    for j, finished in enumerate(taken_at):
+        assert finished >= j - 1, (j, taken_at)
+    assert len(alive) == 5 and max(alive) <= 2 * rows
+
+
+def test_the_stock_and_the_statistics_folds_agree_within_the_cells_limits():
+    """At the stream cell's ``tiny`` sizes (three micro-batches of 4,096 x 64
+    bf16, 50 steps each) the statistics fold's pass is the stock fold's
+    within the cell's three limits, which are an f32 reference's."""
+    import json
+    import os
+
+    import jax.numpy as jnp
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "bench", "configs",
+                           "dense1000-lsq-stream.json")) as f:
+        config = json.load(f)
+    m, d = config["tiny"]["micro_batch_rows"], config["tiny"]["features"]
+    rng = np.random.default_rng(11)
+    w_true = rng.uniform(-1, 1, d).astype(np.float32)
+    stream = []
+    for _ in range(3):
+        X = np.asarray(jnp.asarray(rng.normal(size=(m, d)), jnp.bfloat16))
+        y = (X.astype(np.float32) @ w_true
+             + 0.1 * rng.normal(size=m)).astype(np.float32)
+        stream.append((X, y))
+
+    def fold(schedule):
+        import warnings
+
+        alg = StreamingLinearRegressionWithSGD(
+            config["step_size"], config["num_iterations"],
+            config["mini_batch_fraction"], config["reg_param"])
+        alg.algorithm.optimizer.set_convergence_tol(0.0)
+        alg.algorithm.set_schedule(schedule)
+        alg.set_initial_weights(np.zeros(d, np.float32))
+        losses = []
+        alg.add_model_update_listener(lambda model, count: losses.append(
+            np.asarray(alg.algorithm.optimizer.loss_history)))
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            alg.train_on(iter(stream))
+        return (np.asarray(alg.latest_model().weights, np.float64),
+                np.concatenate(losses).astype(np.float64))
+
+    (w, losses), (ref_w, ref_losses) = fold("resident_gram"), fold("off")
+    assert losses.shape == ref_losses.shape == (150,)
+    change = np.linalg.norm(ref_w)
+    limits = config["limits"]
+    assert np.linalg.norm(w - ref_w) / change < limits["w_rel_gap"]
+    assert np.max(np.abs(losses - ref_losses)
+                  / np.maximum(np.abs(ref_losses), 1e-3)) \
+        < limits["loss_max_gap"]
+    assert abs(np.linalg.norm(w) - change) / change < limits["dw_norm_gap"]

@@ -307,17 +307,28 @@ class StreamingLinearAlgorithm:
 
     def _stages_ahead(self) -> bool:
         """Whether a micro-batch may lie on the device beside the one in
-        training: on the stock resident schedule of one device alone, and
-        once a plan (or ``set_schedule("off")``) has said that this is the
-        schedule.  The planner's other schedules size their own device
-        state (a statistics stack, streamed chunks) from the memory that
-        was free when they planned: the statistics' build over a 4.19 GB
-        micro-batch ran out of memory on the chip with a second one staged
-        beside it (PERF.md, PR 40).  Until a stream's first fit has planned,
-        its micro-batches are copied inside their fits, in turn."""
+        training: on one device alone, on the stock resident schedule or
+        on the statistics schedule in its TOTALS form (a full batch:
+        ``GradientDescent.stats_in_totals``), and once a plan (or
+        ``set_schedule("off")``) has said that this is the schedule.  The
+        totals' device state is 12 MB at d = 1000 and their build reads
+        the micro-batch where it lies, with no temporary of its size
+        (``ops.gram.stats_build``; PERF.md, PR 41).  The planner's other
+        schedules size their own device state (a prefix stack, streamed
+        chunks) from the memory that was free when they planned: the
+        PREFIX build over a 4.19 GB micro-batch ran out of memory on the
+        chip with a second one staged beside it (PERF.md, PR 40).  Until a
+        stream's first fit has planned, its micro-batches are copied
+        inside their fits, in turn."""
         opt = self.algorithm.optimizer
-        if getattr(opt, "mesh", None) is not None or off_stock(opt):
+        if getattr(opt, "mesh", None) is not None:
             return False
+        if off_stock(opt):
+            # of the other schedules the statistics' totals form alone
+            totals = getattr(opt, "stats_in_totals", None)
+            if (opt.host_streaming or opt.streamed_stats
+                    or totals is None or not totals()):
+                return False
         return (self.algorithm.schedule == "off"
                 or getattr(opt, "last_plan", None) is not None)
 

@@ -33,9 +33,13 @@ composable: gram for many-rows × moderate-d, feature sharding for wide d.
 Memory: the prefix stack is ``(n/block_rows + 1) · d² · 4`` bytes (f32 —
 differences of same-sign prefix accumulations would lose ~1% at bf16, so
 the stats dtype floor is f32).  For the 3M×1000 bench slab at the default
-``block_rows=8192`` that is ~1.5 GB next to the 6 GB bf16 slab.
+``block_rows=8192`` that is ~1.5 GB next to the 6 GB bf16 slab.  A FULL
+batch reads no prefix: its totals form (:func:`stats_build`) is ``G``,
+``b``, ``yy`` alone, ``(d² + d + 1) · 4`` bytes whatever the rows (4 MB at
+d = 1000), built in one read with no temporary of X's size, which is what
+lets it run beside a stream's next micro-batch (PERF.md, PR 41).
 
-Precision: this path deliberately does NOT follow the hot-path
+Precision: the PREFIX form deliberately does NOT follow the hot-path
 ``matmul_dtype`` bandwidth contract (`ops/gradients.py`).  Window results
 are *differences of whole-prefix accumulations*, so any matmul rounding is
 amplified by (prefix magnitude / window-gradient magnitude) — near
@@ -46,6 +50,20 @@ rather than bandwidth-bound, every internal matmul runs in the stats dtype
 at ``lax.Precision.HIGHEST``; the precompute walks the data block-by-block
 (``lax.map``) so the f32 upcast never materializes more than one block.
 
+The TOTALS form (:func:`stats_build`: a full batch reads ``G``, ``b``, ``yy``
+of all its rows and no prefix, so nothing is ever a difference of two large
+sums) needs that care only for what it would round.  The product of two
+bf16 numbers is exact in f32 (8 + 8 significant bits of 24), so over bf16
+rows ONE bf16 pass with f32 sums gives the same ``X^T X`` as the six passes
+of ``HIGHEST`` over the rows upcast, at a sixth of the matrix unit's time and
+with no upcast of X at all: it is the configuration's own precision ("bf16
+matmul operands, float32 sums").  ``y`` is f32 and is never rounded: it goes
+to the matrix unit as the three bf16 parts that add up to it
+(``pallas_kernels._parts_of``, as the wide kernel holds w), so ``X^T y``'s
+products are exact too.  Rows of any other type keep the upcast and
+``HIGHEST``.  The iterations on the totals (``batch_sums``) stay at
+``HIGHEST``: they are (d, d) matvecs, microseconds.
+
 Plumbing: the statistics enter compiled programs as ARGUMENTS, never as
 closure constants — tracing GB-scale captured arrays into a jit program
 embeds them in the lowered module, which chokes compilation (observed:
@@ -54,7 +72,10 @@ buffers).  :class:`GramData` is a registered pytree bundling the dense
 matrix with its statistics; pass it wherever ``X`` goes (``optimize``,
 ``make_run``) and the bound :class:`GramLeastSquaresGradient` pulls the
 statistics out of the traced argument.  The optimizer-level
-``set_sufficient_stats`` flags do this wrapping automatically.
+``set_sufficient_stats`` flags do this wrapping automatically; for a full
+batch they hand the totals form to ONE unbound executor an optimizer, so a
+new dataset of the same shape (a stream's next micro-batch) finds the
+compiled build and the compiled run it left.
 """
 
 from __future__ import annotations
@@ -122,6 +143,52 @@ def _running_sum(carry0, blocks):
     return cums
 
 
+@jax.jit
+def _stats_build(X, y):
+    """``(G, b, yy) = (X^T X, X^T y, y^T y)`` of ALL the rows, each read
+    where it lies: two ``dot_general``s that contract the rows' axis of X
+    as it is stored (at d = 1000 the chip stores X feature-major and the
+    contraction runs along the lanes: ``X.T`` is a bitcast, nothing of X's
+    size is made; ``tests/test_chip_compile.py``), static shapes, no slice
+    at a traced offset.  The precision is the module docstring's: bf16 rows
+    take one pass with f32 sums and ``y`` as three bf16 parts; any other
+    type the stats dtype at ``HIGHEST``.  The jitted function's NAME carries
+    the scope's: the persistent compile cache's key holds the one and not
+    the other (PERF.md, PR 25)."""
+    with jax.named_scope("sgd.stats_build"):
+        sd = jnp.promote_types(jnp.float32, X.dtype)
+        rows = (((0,), (0,)), ((), ()))  # contract axis 0 of both
+        if X.dtype == jnp.bfloat16:
+            from tpu_sgd.ops.pallas_kernels import _parts_of
+
+            G = jax.lax.dot_general(X, X, rows, preferred_element_type=sd)
+            parts = jnp.stack(_parts_of(y, X.dtype, 3))  # (3, n)
+            b = jax.lax.dot_general(
+                parts, X, (((1,), (0,)), ((), ())),
+                preferred_element_type=sd).sum(axis=0)
+            y = y.astype(sd)
+        else:
+            Xs, y = X.astype(sd), y.astype(sd)
+            G = jax.lax.dot_general(Xs, Xs, rows, precision=_HI,
+                                    preferred_element_type=sd)
+            b = jax.lax.dot_general(Xs, y, rows, precision=_HI,
+                                    preferred_element_type=sd)
+        return G, b, jnp.sum(y * y)
+
+
+def stats_build(X, y) -> "GramData":
+    """The TOTALS form of the statistics, for a full batch on one device:
+    ONE read of ``(X, y)`` makes ``G``, ``b``, ``yy`` (12 MB at d = 1000)
+    and no prefix stack, and the bundle that comes back holds no rows
+    (``X`` None: the iterations read 4 MB each, and the caller's X may be
+    dropped while they run).  The same program for every ``(X, y)`` of one
+    shape and type; pass the bundle as ``X`` to an UNBOUND
+    :class:`GramLeastSquaresGradient`."""
+    G, b, yy = _stats_build(X, y)
+    return GramData(None, None, None, None, G, b, yy, int(X.shape[0]),
+                    logical_shape=X.shape, logical_dtype=X.dtype)
+
+
 @jax.tree_util.register_pytree_node_class
 class GramData:
     """A dense ``(n, d)`` matrix bundled with its block-prefix Gram
@@ -133,7 +200,11 @@ class GramData:
     device (built by :meth:`GramLeastSquaresGradient.build_streamed` from
     host-resident data too large for HBM), and ``shape``/``dtype`` report
     the logical dataset.  Virtual data supports block-aligned sliced
-    windows and full-batch sums (nothing that needs to read rows)."""
+    windows and full-batch sums (nothing that needs to read rows).
+
+    ``PG``/``Pb``/``Pyy`` may be ``None`` too — the TOTALS form
+    (:func:`stats_build`): ``G_tot``, ``b_tot``, ``yy_tot`` alone, which is
+    all a full batch reads; it serves no window."""
 
     __slots__ = ("X", "PG", "Pb", "Pyy", "G_tot", "b_tot", "yy_tot",
                  "block_rows", "_logical_shape", "_logical_dtype")
@@ -208,6 +279,11 @@ class GramData:
 
         import numpy as np
 
+        if self.PG is None:
+            raise ValueError(
+                "the totals form holds no prefix stack to persist; save "
+                "GramLeastSquaresGradient.totals_only_data(G_tot, b_tot, "
+                "yy_tot, ...) of it instead")
         os.makedirs(path, exist_ok=True)
         meta = {
             "class": "GramData",
@@ -1115,13 +1191,14 @@ class GramLeastSquaresGradient(LeastSquaresGradient):
         # when the rows are virtual (st.X is None)
         cd = acc_dtype(matmul_dtype(X))
         sd = st.G_tot.dtype
-        w = weights.astype(sd)
-        Gw = _dot_hi(st.G_tot, w, sd)
-        b = st.b_tot
-        g_sum = (Gw - b).astype(cd)
-        # cancellation-safe loss dots (see _window_sums_aligned)
-        loss_sum = (0.5 * (_dot_hi(w, Gw, sd) - 2.0 * _dot_hi(w, b, sd)
-                           + st.yy_tot)).astype(cd)
+        with jax.named_scope("sgd.stats_sums"):
+            w = weights.astype(sd)
+            Gw = _dot_hi(st.G_tot, w, sd)
+            b = st.b_tot
+            g_sum = (Gw - b).astype(cd)
+            # cancellation-safe loss dots (see _window_sums_aligned)
+            loss_sum = (0.5 * (_dot_hi(w, Gw, sd) - 2.0 * _dot_hi(w, b, sd)
+                               + st.yy_tot)).astype(cd)
         return g_sum, loss_sum, jnp.asarray(X.shape[0], cd)
 
     def loss_sweep(self, X, y, W, mask=None):
@@ -1153,6 +1230,10 @@ class GramLeastSquaresGradient(LeastSquaresGradient):
                 Xd, y, weights, start, m, valid,
                 margin_axis_name=margin_axis_name,
             )
+        if st.PG is None:
+            raise NotImplementedError(
+                "the totals form of the statistics (stats_build) serves "
+                "full-batch sums only; windows need the prefix form (build)")
         cd = acc_dtype(matmul_dtype(X))
         if st.X is None or self.aligned:
             return self._window_sums_aligned(st, weights, start, m, cd)

@@ -1167,6 +1167,8 @@ class GradientDescent(Optimizer):
         self.last_plan = None
         self._plan_key = None
         self._gram_entry = None
+        #: the totals form's ONE unbound executor (``_maybe_gram``)
+        self._totals_gradient = None
         self._gram_dp_entry = None
         self._streamed_gram_entry = None
         self._streamed_gram_dp_entry = None
@@ -1303,12 +1305,14 @@ class GradientDescent(Optimizer):
         Applies when the gradient is exactly ``LeastSquaresGradient``, the
         data is dense and device-resident (no mesh, no host streaming), and
         sampling is ``sliced`` or full-batch; any other combination runs
-        unchanged.  The one-time build pass is cached per ``(X, y)`` array
-        identity — and RETAINED after ``optimize`` returns (the streaming
-        mode's repeated calls on the same arrays must not rebuild), which
+        unchanged.  Under sliced sampling the one-time build pass is cached
+        per ``(X, y)`` array identity — and RETAINED after ``optimize``
+        returns (repeated calls on the same arrays must not rebuild), which
         pins the dataset plus the ~GB-scale prefix stack in HBM until a
         different dataset is passed, the optimizer is dropped, or
-        :meth:`release_sufficient_stats` is called."""
+        :meth:`release_sufficient_stats` is called.  A FULL batch reads the
+        totals alone (``stats_in_totals``): one read builds them anew for
+        every fit, 12 MB at d = 1000, and nothing of the dataset is kept."""
         self._clear_planned_schedule()
         self.sufficient_stats = bool(flag)
         self._mark_manual_schedule()
@@ -1508,7 +1512,11 @@ class GradientDescent(Optimizer):
         for entry in (self._gram_entry, self._streamed_gram_entry):
             if entry is not None:
                 self._purge_run_cache_for(entry[2])
+        if self._totals_gradient is not None:
+            # the totals form keeps no statistics, only its executor's runner
+            self._purge_run_cache_for(self._totals_gradient)
         self._gram_entry = None
+        self._totals_gradient = None
         self._gram_dp_entry = None
         self._streamed_gram_entry = None
         self._streamed_gram_dp_entry = None
@@ -1580,6 +1588,12 @@ class GradientDescent(Optimizer):
                 raise NotImplementedError(
                     "GramData input supports sliced sampling or full "
                     f"batch (got sampling={cfg.sampling!r})"
+                )
+            if cfg.mini_batch_fraction < 1.0 and X.PG is None:
+                raise NotImplementedError(
+                    "the totals form of the statistics (stats_build) "
+                    "serves full-batch fits; sliced windows need the "
+                    "prefix form (GramLeastSquaresGradient.build)"
                 )
             if (cfg.mini_batch_fraction < 1.0 and X.X is None
                     and X.PG.shape[0] <= 2):
@@ -1762,9 +1776,10 @@ class GradientDescent(Optimizer):
     def _optimize_routed(self, X, y, w0, sparse_X, run_span):
         """Resident-data path routing (single-device / mesh / sparse /
         stepwise), after input coercion.  The fused fit's leaves tile it:
-        ``train.place`` (a 1-D mesh alone), then ``train.select`` — the
-        sufficient-stats substitution, the route, the compiled runner's
-        lookup and what ``train.run`` says of the step — up to the start of
+        ``train.place`` (a 1-D mesh alone), ``train.stats`` where the
+        sufficient-stats substitution builds (``_maybe_gram``), then
+        ``train.select`` — the route, the compiled runner's lookup and
+        what ``train.run`` says of the step — up to the start of
         ``train.dispatch``, and ``train.fetch`` to the return."""
         import numpy as np
 
@@ -1789,8 +1804,8 @@ class GradientDescent(Optimizer):
         if (not sparse_X and self.mesh is not None
                 and self._mesh_kind() == "dp"):
             placed = self._place(X, y)
-        with span("train.select") as select_span, \
-                self._substituted(X, y, sparse_X) as X:
+        with self._substituted(X, y, sparse_X) as X, \
+                span("train.select") as select_span:
             fn, args, built = self._select(X, y, w0, sparse_X, placed,
                                            run_span, select_span)
         with span("train.dispatch", built=int(built)):
@@ -1827,9 +1842,9 @@ class GradientDescent(Optimizer):
         if gram is None:
             yield X
             return
-        orig, self.gradient = self.gradient, gram
+        orig, (self.gradient, data) = self.gradient, gram
         try:
-            yield gram.data
+            yield data
         finally:
             self.gradient = orig
 
@@ -1909,13 +1924,15 @@ class GradientDescent(Optimizer):
             # (labels_prepared, row_tile, feature_blocks, mask_in_kernel,
             # by_rows): evaluated only where a span carries them
             kernel = self._step_kernel(*args) if runner else (0, 0, 1, 0, 0)
+            # stats: 1 where the fit runs from the totals of its rows
+            stats = int(isinstance(X, GramData) and X.PG is None)
             run_span.set(
                 path=path,
                 shards=1 if self.mesh is None else self.mesh.devices.size,
                 labels_prepared=kernel[0], row_tile=kernel[1],
                 feature_blocks=kernel[2], mask_in_kernel=kernel[3],
-                by_rows=kernel[4])
-            select_span.set(by_rows=kernel[4])
+                by_rows=kernel[4], stats=stats)
+            select_span.set(by_rows=kernel[4], stats=stats)
         return fn, args, len(self._run_cache) > cached
 
     def _step_kernel(self, w0, X, y, valid=None):
@@ -2109,12 +2126,37 @@ class GradientDescent(Optimizer):
         self._streamed_gram_entry = (X, y, g, opts)
         return g
 
+    def stats_in_totals(self) -> bool:
+        """Whether the sufficient-stats substitution (``_maybe_gram``)
+        takes the TOTALS form under this configuration: a full batch reads
+        ``G``, ``b``, ``yy`` of all its rows and no window, so the build is
+        one read that makes 12 MB at d = 1000 and the run holds no rows
+        (``ops.gram.stats_build``); sliced windows take the prefix form."""
+        return self.config.mini_batch_fraction >= 1.0
+
+    @staticmethod
+    def _stats_span(X, y):
+        """``train.stats``: the host's time in a statistics build (the
+        launch of the totals' one program; all of a prefix build), a leaf
+        between ``train.h2d`` and ``train.select``; ``bytes`` and ``rows``
+        are what the build reads."""
+        return span("train.stats", bytes=X.nbytes + y.nbytes,
+                    rows=X.shape[0])
+
     def _maybe_gram(self, X, y, sparse_X):
         """The sufficient-stats substitution, when it applies (see
-        ``set_sufficient_stats``); identity-cached so the streaming mode's
-        repeated ``optimize`` calls on the same arrays build once."""
+        ``set_sufficient_stats``): ``(gradient, X to train on)``, or None.
+
+        A full batch (``stats_in_totals``) takes the totals form: built
+        anew from every ``(X, y)`` in one read (nothing of a superseded
+        dataset is kept, so nothing is purged), handed to the optimizer's
+        ONE unbound executor, so that ``_runner``'s key and the build's
+        program are the same for every dataset of one shape (a stream's
+        micro-batches).  Sliced windows take the prefix form,
+        identity-cached so that repeated ``optimize`` calls on the same
+        arrays build once.  Either build runs under ``train.stats``."""
         from tpu_sgd.ops.gradients import LeastSquaresGradient as _LS
-        from tpu_sgd.ops.gram import GramLeastSquaresGradient
+        from tpu_sgd.ops.gram import GramLeastSquaresGradient, stats_build
 
         cfg = self.config
         if (sparse_X or self.mesh is not None or self.host_streaming
@@ -2126,25 +2168,32 @@ class GradientDescent(Optimizer):
                 and self.gradient.data.X is X):
             # user-built gram gradient on exactly this matrix: route its
             # GramData through so the traced program accelerates
-            return self.gradient
+            return self.gradient, self.gradient.data
         if not self.sufficient_stats or type(self.gradient) is not _LS:
             return None
+        if self.stats_in_totals():
+            if self._totals_gradient is None:
+                self._totals_gradient = GramLeastSquaresGradient()
+            with self._stats_span(X, y):
+                return self._totals_gradient, stats_build(X, y)
         entry = self._gram_entry
         opts = (self.gram_block_rows, self.gram_aligned)
         if (entry is not None and entry[0] is X and entry[1] is y
                 and entry[3:] == opts):
-            return entry[2]
+            return entry[2], entry[2].data
         if entry is not None:
             # new dataset (or new gram options): drop compiled runners
             # keyed on the superseded gram gradient so its GB-scale prefix
             # stack can be freed
             self._purge_run_cache_for(entry[2])
-        g = GramLeastSquaresGradient.build(
-            X, y, block_rows=self.gram_block_rows, aligned=self.gram_aligned
-        )
+        with self._stats_span(X, y):
+            g = GramLeastSquaresGradient.build(
+                X, y, block_rows=self.gram_block_rows,
+                aligned=self.gram_aligned
+            )
         # keep the ORIGINAL arrays in the key: build() may re-coerce
         self._gram_entry = (X, y, g) + opts
-        return g
+        return g, g.data
 
     def _maybe_gram_dp(self, X, y, Xd, yd, valid):
         """The sufficient-stats substitution over a 1-D data mesh (see
