@@ -763,6 +763,60 @@ def test_the_hand_offs_writer_writes_its_destination_in_place(S, rows):
         == [by_features, by_features]
 
 
+#: the four-chip from-host cell (``dense1000-lsq-dp4-run.from-host-sharded``):
+#: a shard of 10,000,000 x 1000 bf16 rows over four chips
+SHARD_ROWS = 2_500_000
+
+
+@pytest.mark.parametrize("program", ["fill", "full", "remainder"])
+@pytest.mark.parametrize("chip", range(4))
+def test_the_sharded_hand_offs_programs_compile_for_each_chip(
+        topo, no_persistent_cache, chip, program):
+    """Under a mesh ``_stage_dense`` has a destination a DEVICE: the fill of
+    a ``(2,500,000, 1000)`` bf16 shard and its in-place writes (152 blocks
+    of 16,384 rows and a remainder of 9,632) compile for every one of the
+    four described chips as they do for one: the destination aliased, no
+    temporary, and nothing of a shard's size made besides the write itself
+    (the fill makes the shard and nothing else)."""
+    import functools
+
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_sgd.optimize.gradient_descent import (_STAGE_BLOCK_BYTES,
+                                                   _STAGE_ROWS, _stage_block,
+                                                   _stage_dest)
+
+    here = SingleDeviceSharding(topo.devices[chip])
+    n = SHARD_ROWS
+    full = _STAGE_BLOCK_BYTES // (2 * D) // _STAGE_ROWS * _STAGE_ROWS
+    assert full == 16_384 and -(-n // full) == 153 and n % full == 9_632
+    if program == "fill":
+        compiled = jax.jit(
+            functools.partial(_stage_dest.__wrapped__, (n, D), BF16),
+            out_shardings=here).lower().compile()
+        memory = compiled.memory_analysis()
+        assert n * D * 2 <= memory.output_size_in_bytes < n * D * 2 * 1.01
+        assert memory.temp_size_in_bytes == 0
+        assert "sgd.stage" in compiled.as_text()
+        return
+    block = full if program == "full" else n % full
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=here)
+
+    compiled = _stage_block.lower(shape((n, D), BF16), shape((block, D), BF16),
+                                  shape((), I32)).compile()
+    text = compiled.as_text()
+    assert _moves_of(text, n, D) == ["dynamic-update-slice"]
+    assert "sgd.stage/dynamic_update_slice" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes == 0
+    assert memory.alias_size_in_bytes >= n * D * 2
+    by_features = (1, 0)
+    assert [f.layout.major_to_minor for f in compiled.input_formats[0][:2]] \
+        == [by_features, by_features]
+
+
 def test_a_batch_staged_ahead_is_made_whole_in_one_program(S):
     """``_stage_join`` at the stream cell's shape (a micro-batch of
     2,097,152 x 1000 bf16 as 128 blocks of 16,384 rows): one program whose

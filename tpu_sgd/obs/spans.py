@@ -147,6 +147,9 @@ class _NoopSpan:
 
 
 _NOOP = _NoopSpan()
+#: what ``span()`` returns with tracing off: the default of a callee that is
+#: handed its caller's span (``_stage_dense``, ``shard_dataset``)
+NO_SPAN = _NOOP
 
 #: the second consumer's switch: true while a ``jax.profiler`` session
 #: is active in this process (one C++ flag read, ~40-150 ns)

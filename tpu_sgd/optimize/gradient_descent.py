@@ -34,13 +34,14 @@ import collections
 import contextlib
 import functools
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from tpu_sgd.config import SGDConfig
-from tpu_sgd.obs.spans import span
+from tpu_sgd.obs.spans import NO_SPAN, span
 from tpu_sgd.obs.timeseries import observe_scalar
 from tpu_sgd.ops.gradients import (Gradient, LeastSquaresGradient,
                                    RowDraw, by_rows)
@@ -105,7 +106,13 @@ def _coerce_w0(gradient, initial_weights, n_features):
 #: ``_stage_dense``, because the wire takes 2.29 ms for the block; a faster
 #: issue would lengthen the wait, not shorten the copy (a host 1.3 times
 #: slower would make the issue the bound).  A block is far under 4 GiB,
-#: above which a host array is copied 24 times slower.
+#: above which a host array is copied 24 times slower.  Over FOUR chips
+#: (PR 42, 10,000,000 x 1000 bf16) it is the other way round, the host's
+#: issue bounds the copy: ONE thread, the devices in turn, issues 612 blocks
+#: in 0.96 to 1.07 s from one process to the next (1.57 to 1.75 ms each, 14
+#: to 18 ms of it in the wait), 1.4 wires' worth; a thread a device, as it
+#: is written, lands them in 0.82 to 0.86 s (23.3 to 24.4 GB/s), each
+#: thread 4.9 ms in its calls a block: the four contend (PERF.md section 6).
 _STAGE_BLOCK_BYTES = 32 << 20
 _STAGE_IN_FLIGHT = 16
 _STAGE_ROWS = 1024
@@ -113,7 +120,8 @@ _STAGE_ROWS = 1024
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _stage_dest(shape, dtype):
-    """The array ``_stage_dense``'s blocks are written into."""
+    """The array ``_stage_dense``'s blocks are written into, on the default
+    device (the caller sets it: a program with no operand runs there)."""
     with jax.named_scope("sgd.stage"):
         return jnp.zeros(shape, dtype)
 
@@ -123,25 +131,30 @@ def _stage_block(dest, block, offset):
     """``dest`` (donated: written in place) with ``block`` at rows
     ``offset:``, and a scalar that is ready when the write is.  The offset
     is an operand, so one program serves every full block and one more the
-    remainder."""
+    remainder (a program a device under a mesh: it runs where ``dest`` and
+    ``block`` lie)."""
     with jax.named_scope("sgd.stage"):
         dest = jax.lax.dynamic_update_slice_in_dim(dest, block, offset,
                                                    axis=0)
         return dest, dest[offset, 0]
 
 
-def _block_rows(X) -> int:
-    """The rows of one block of a dense numpy array's hand-off; 0 where it
-    goes in one piece (no matrix, or one block holds it all)."""
-    if X.ndim != 2 or X.nbytes <= _STAGE_BLOCK_BYTES:
+def _block_rows(X, n=None) -> int:
+    """The rows of one block of a dense numpy array's hand-off into a
+    destination of ``n`` of its rows (all of them: one destination); 0 where
+    a destination goes in one piece (no matrix, or one block holds it all)."""
+    n = X.shape[0] if n is None else n
+    if X.ndim != 2 or not n:
         return 0
     row_bytes = X.nbytes // X.shape[0]
+    if n * row_bytes <= _STAGE_BLOCK_BYTES:
+        return 0
     rows = _STAGE_ROWS * max(
         1, _STAGE_BLOCK_BYTES // row_bytes // _STAGE_ROWS)
-    return rows if rows < X.shape[0] else 0
+    return rows if rows < n else 0
 
 
-def _stage_dense(X, h2d):
+def _stage_dense(X, h2d=NO_SPAN, mesh=None):
     """Dense features on the device as ONE ``(N, d)`` array, and what the
     ``train.h2d`` span says of the copy: ``(X, blocks, block_bytes)``.
 
@@ -155,9 +168,21 @@ def _stage_dense(X, h2d):
     in flight, never the dataset twice.  The values are ``jnp.asarray``'s
     (each block IS one), so the fit is the single copy's bit for bit.
 
-    Where ``h2d`` (the ``train.h2d`` span) is ``live`` it is given
-    the hand-off's stall counter: ``stalls``, the times the host stood in
-    that flow-control wait, and ``stall_ms``, how long in all (0 and 0 for
+    Under ``mesh`` (1-D, over rows) a numpy array of any size has a
+    destination a DEVICE: shard ``s`` holds rows ``[s n/S, (s + 1) n/S)`` of
+    ``X`` in order, zero rows behind the last where the rows do not divide
+    (``ceil(n / S)`` rows a shard), and the ``S`` destinations are handed
+    back as the one array sharded by rows over ``mesh``: no program, no
+    copy, laid out as ``shard_dataset`` returns it.  The same loop, run by a
+    thread a device so that all of them receive at once: a block goes to
+    the device that owns its rows, the blocks in flight counted a device; a
+    shard that one block holds goes in one piece.  No device ever holds more
+    than its shard and the blocks in flight to it.
+
+    ``h2d`` is told ``shards``, the destinations the array went to (0 for a
+    device array).  Where it is ``live`` it is given the hand-off's stall
+    counter too: ``stalls``, the times the host stood in that flow-control
+    wait, and ``stall_ms``, how long in all, over the devices (0 and 0 for
     one piece or a device array).  Otherwise the loop reads no clock."""
     import numpy as np
 
@@ -165,30 +190,90 @@ def _stage_dense(X, h2d):
     if timed:
         h2d.set(stalls=0, stall_ms=0.0)
     if not isinstance(X, np.ndarray):
+        h2d.set(shards=0)
         return jnp.asarray(X), 0, 0
-    rows = _block_rows(X)
-    if not rows:  # one block holds it all
-        return jnp.asarray(X), 1, X.nbytes
-    n, row_bytes = X.shape[0], X.nbytes // X.shape[0]
-    dest, writes = None, collections.deque()
-    stalls, stall_s = 0, 0.0
-    for a in range(0, n, rows):
-        if len(writes) == _STAGE_IN_FLIGHT:
-            # flow control, not a fetch: bounds what the device holds
-            t = time.perf_counter() if timed else 0.0
-            writes.popleft().block_until_ready()
-            if timed:
-                stall_s += time.perf_counter() - t
-                stalls += 1
-        block = jnp.asarray(X[a:a + rows])
-        if dest is None:
-            dest = _stage_dest(X.shape, block.dtype)
-        dest, written = _stage_block(dest, block, a)
-        block.delete()
-        writes.append(written)
+    devices = [None] if mesh is None else list(mesh.devices.flat)
+    h2d.set(shards=len(devices))
+    n, local = X.shape[0], -(-X.shape[0] // len(devices))
+    rows = _block_rows(X, local)
+    if not rows:  # one block holds a destination's rows: one piece each
+        if mesh is None:
+            return jnp.asarray(X), 1, X.nbytes
+        dests = [jax.device_put(_rows_padded(X, s * local, local), device)
+                 for s, device in enumerate(devices)]
+        return (_sharded_by_rows(mesh, dests), len(dests),
+                dests[0].nbytes)
+
+    def send(s):
+        """Destination ``s`` written from its rows of ``X``: the array, its
+        blocks, and how often and how long its flow control stood."""
+        device, first = devices[s], s * local
+        end = min(first + local, n)  # behind it the fill's zero rows
+        dest, writes = None, collections.deque()
+        blocks, stalls, stall_s = 0, 0, 0.0
+        for lo in range(first, end, rows):
+            if len(writes) == _STAGE_IN_FLIGHT:
+                # flow control, not a fetch: bounds what a device holds
+                t = time.perf_counter() if timed else 0.0
+                writes.popleft().block_until_ready()
+                if timed:
+                    stall_s += time.perf_counter() - t
+                    stalls += 1
+            piece = X[lo:min(lo + rows, end)]
+            block = (jnp.asarray(piece) if device is None
+                     else jax.device_put(piece, device))
+            if dest is None:
+                with (contextlib.nullcontext() if device is None
+                      else jax.default_device(device)):
+                    dest = _stage_dest((local,) + X.shape[1:], block.dtype)
+            dest, written = _stage_block(dest, block, lo - first)
+            block.delete()
+            writes.append(written)
+            blocks += 1
+        return dest, blocks, stalls, stall_s
+
+    if mesh is None:
+        sent = [send(0)]
+    else:
+        # all the devices receive at once: one thread issues a block in
+        # 1.6 ms, 1.4 wires' worth, and its pace differs by a tenth from one
+        # process to the next
+        with ThreadPoolExecutor(len(devices)) as pool:
+            sent = list(pool.map(send, range(len(devices))))
+    dests, blocks, stalls, stall_s = zip(*sent)
     if timed:
-        h2d.set(stalls=stalls, stall_ms=round(stall_s * 1e3, 4))
-    return dest, -(-n // rows), rows * row_bytes
+        h2d.set(stalls=sum(stalls), stall_ms=round(sum(stall_s) * 1e3, 4))
+    block_bytes = rows * (X.nbytes // n)
+    if mesh is None:
+        return dests[0], blocks[0], block_bytes
+    # every shard holds rows of X: the zero rows are fewer than the shards,
+    # and a shard in blocks has more rows than that
+    return _sharded_by_rows(mesh, list(dests)), sum(blocks), block_bytes
+
+
+def _rows_padded(X, lo, m):
+    """Rows ``lo:lo + m`` of the host array, zero rows behind them where it
+    ends before (a piece of at most one block: ``pad_to_multiple``'s rows
+    for one shard)."""
+    import numpy as np
+
+    piece = X[lo:lo + m]
+    if piece.shape[0] == m:
+        return piece
+    return np.concatenate(
+        [piece, np.zeros((m - piece.shape[0],) + X.shape[1:], X.dtype)])
+
+
+def _sharded_by_rows(mesh, dests):
+    """The devices' ``(n / S, ...)`` arrays, in the mesh's order, as the one
+    array sharded by rows over ``mesh``: the buffers as they lie."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpu_sgd.parallel.mesh import DATA_AXIS
+
+    shape = (len(dests) * dests[0].shape[0],) + dests[0].shape[1:]
+    return jax.make_array_from_single_device_arrays(
+        shape, NamedSharding(mesh, P(DATA_AXIS)), dests)
 
 
 @jax.jit
@@ -1748,12 +1833,20 @@ class GradientDescent(Optimizer):
             return w, hist
         # host time in the calls: a large X's blocks are issued in here,
         # the last of them (any other copy whole) may still drain after it
+        valid = None
         with span("train.h2d", bytes=sum(
                 a.nbytes for a in (X, y)
                 if isinstance(a, np.ndarray))) as h2d:
-            if not sparse_X:
+            if self._hands_off_sharded(X):
+                # each row block to the device that owns its rows; y and
+                # the mask of padded rows lie sharded beside them
+                from tpu_sgd.parallel.data_parallel import shard_dataset
+
+                X, y, valid = shard_dataset(self.mesh, X, y, h2d)
+            elif not sparse_X:
                 X, blocks, block_bytes = _stage_dense(X, h2d)
                 h2d.set(blocks=blocks, block_bytes=block_bytes)
+            if not sparse_X:
                 if not jnp.issubdtype(X.dtype, jnp.inexact):
                     # int/bool features (one-hot etc.)
                     X = X.astype(jnp.float32)
@@ -1771,9 +1864,20 @@ class GradientDescent(Optimizer):
             warnings.warn(
                 "The miniBatchFraction is too small", RuntimeWarning, stacklevel=3
             )
-        return self._optimize_routed(X, y, w0, sparse_X, run_span)
+        return self._optimize_routed(X, y, w0, sparse_X, run_span, valid)
 
-    def _optimize_routed(self, X, y, w0, sparse_X, run_span):
+    def _hands_off_sharded(self, X) -> bool:
+        """Whether ``train.h2d`` sends ``X`` straight to the mesh's devices,
+        a shard each (``shard_dataset``'s host branch): a dense host matrix
+        under a 1-D data mesh of this process's devices.  Anything else is
+        staged as without a mesh and laid out by ``_place``."""
+        import numpy as np
+
+        return (isinstance(X, np.ndarray) and X.ndim == 2 and X.shape[0] > 0
+                and self.mesh is not None and self._mesh_kind() == "dp"
+                and jax.process_count() == 1)
+
+    def _optimize_routed(self, X, y, w0, sparse_X, run_span, valid=None):
         """Resident-data path routing (single-device / mesh / sparse /
         stepwise), after input coercion.  The fused fit's leaves tile it:
         ``train.place`` (a 1-D mesh alone), ``train.stats`` where the
@@ -1799,11 +1903,11 @@ class GradientDescent(Optimizer):
                         stacklevel=4,
                     )
                 run_span.set(path="stepwise")
-                return self._optimize_stepwise(X, y, w0)
+                return self._optimize_stepwise(X, y, w0, valid)
         placed = None
         if (not sparse_X and self.mesh is not None
                 and self._mesh_kind() == "dp"):
-            placed = self._place(X, y)
+            placed = self._place(X, y, valid)
         with self._substituted(X, y, sparse_X) as X, \
                 span("train.select") as select_span:
             fn, args, built = self._select(X, y, w0, sparse_X, placed,
@@ -1965,20 +2069,22 @@ class GradientDescent(Optimizer):
         return (int(rows_prepared(*args)), *blocks,
                 int(mask_in_kernel(*args)), int(blocks[0] > 0 and by_rows(X)))
 
-    def _place(self, X, y):
+    def _place(self, X, y, valid=None):
         """``shard_dataset`` for this fit's mesh under the ``train.place``
         span: ``in_place`` 1 where the dataset already lay sharded for the
-        mesh and is trained where it lies; ``bytes`` is what the placement
-        moved to lay it out (from the host or between devices), 0 in
-        place."""
+        mesh (a cached one; a host array since ``train.h2d`` sends every
+        block to the device that owns it) and is trained where it lies;
+        ``bytes`` is what the placement moved to lay it out (between
+        devices), 0 in place.  ``valid`` is the hand-off's mask of the rows
+        it padded, the mask of what it laid out."""
         from tpu_sgd.parallel.data_parallel import shard_dataset
 
         with span("train.place", shards=self.mesh.devices.size) as sp:
-            Xd, yd, valid = shard_dataset(self.mesh, X, y)
+            Xd, yd, padded = shard_dataset(self.mesh, X, y)
             in_place = Xd is X and yd is y
             sp.set(in_place=int(in_place),
                    bytes=0 if in_place else X.nbytes + y.nbytes)
-        return Xd, yd, valid
+        return Xd, yd, valid if in_place else padded
 
     def _check_streamed_stats_applies(self, sparse_X):
         """Shared guards for ``set_streamed_stats`` (single-device and
@@ -2225,7 +2331,7 @@ class GradientDescent(Optimizer):
                                self.gram_block_rows)
         return stats
 
-    def _optimize_stepwise(self, X, y, w0):
+    def _optimize_stepwise(self, X, y, w0, valid=None):
         """Observed path: jitted step per iteration with host round-trips.
 
         Used when a listener or checkpoint manager is attached.  Supports
@@ -2244,7 +2350,6 @@ class GradientDescent(Optimizer):
                 "listener/checkpoint mode supports single-device and 1-D "
                 "data meshes"
             )
-        valid = None
         sparse_shape = None
         if self.mesh is not None:
             if is_sparse(X):
@@ -2256,7 +2361,7 @@ class GradientDescent(Optimizer):
                 X = (data, idx)  # component tuple; the stepper rebuilds
                 sparse_shape = (rows_local, d_feat)
             else:
-                X, y, valid = self._place(X, y)
+                X, y, valid = self._place(X, y, valid)
         step = self._stepper(with_valid=valid is not None,
                              sparse_shape=sparse_shape)
 

@@ -24,6 +24,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpu_sgd.config import SGDConfig
+from tpu_sgd.obs.spans import NO_SPAN
 from tpu_sgd.ops.gradients import Gradient
 from tpu_sgd.ops.updaters import Updater
 from tpu_sgd.parallel.mesh import DATA_AXIS, shard_map_fn, superchunk_specs
@@ -45,13 +46,19 @@ def pad_to_multiple(
     return X, y, valid
 
 
-def shard_dataset(mesh: Mesh, X, y) -> Tuple[Array, Array, Optional[Array]]:
+def shard_dataset(mesh: Mesh, X, y,
+                  h2d=NO_SPAN) -> Tuple[Array, Array, Optional[Array]]:
     """Place ``(X, y)`` sharded over the 'data' axis of ``mesh``.
 
     Returns device arrays plus a ``valid`` mask (None when no padding was
     needed).  For host arrays this is the one host->device transfer of the
     whole run — the analogue of the reference's initial ``RDD.cache()``
-    materialization.
+    materialization — and it is the fit's own hand-off
+    (``gradient_descent._stage_dense`` with a destination a device): the
+    rows go in blocks, each to the device that owns it, so a dataset larger
+    than one device's memory arrives and no device holds more than its
+    shard and the blocks in flight to it.  ``h2d`` is the ``train.h2d`` span
+    of a fit that hands its host arrays over here, told what the copy did.
 
     Place once, fit many: what this returns is laid out for ``mesh``, and
     handed such arrays (its own result, or any ``jax.Array`` whose sharding
@@ -90,16 +97,27 @@ def shard_dataset(mesh: Mesh, X, y) -> Tuple[Array, Array, Optional[Array]]:
             valid = jax.device_put(jnp.arange(n + rem) < n, row_sharding)
         return (jax.device_put(X, x_sharding),
                 jax.device_put(y, row_sharding), valid)
+    from tpu_sgd.optimize.gradient_descent import _stage_dense
+
     Xh = np.asarray(X)
     yh = np.asarray(y)
     n = Xh.shape[0]
-    Xh, yh, validh = pad_to_multiple(Xh, yh, n_shards)
-    Xd = jax.device_put(Xh, x_sharding)
-    yd = jax.device_put(yh, row_sharding)
-    if n == Xh.shape[0]:
-        return Xd, yd, None
-    vd = jax.device_put(validh, row_sharding)
-    return Xd, yd, vd
+    if mesh.devices.size != n_shards:
+        # a model axis too: every shard lies on several devices, which the
+        # plain placement by sharding lays out
+        Xh, yh, validh = pad_to_multiple(Xh, yh, n_shards)
+        return (jax.device_put(Xh, x_sharding),
+                jax.device_put(yh, row_sharding),
+                None if n == Xh.shape[0]
+                else jax.device_put(validh, row_sharding))
+    Xd, blocks, block_bytes = _stage_dense(Xh, h2d, mesh)
+    h2d.set(blocks=blocks, block_bytes=block_bytes)
+    rem = Xd.shape[0] - n  # the zero rows behind the last shard's
+    if not rem:
+        return Xd, jax.device_put(yh, row_sharding), None
+    yh = np.concatenate([yh, np.zeros((rem,), yh.dtype)])
+    return (Xd, jax.device_put(yh, row_sharding),
+            jax.device_put(np.arange(n + rem) < n, row_sharding))
 
 
 def _shard_dataset_multihost(mesh: Mesh, Xh, yh):
