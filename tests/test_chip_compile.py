@@ -872,6 +872,52 @@ def test_the_statistics_build_reads_x_where_it_lies(S, case):
         by_rows if case == "by_rows" else by_features)
 
 
+@pytest.mark.parametrize("case", ["stream_cell", "by_rows", "f32"])
+def test_the_blocks_fold_reads_the_block_where_it_lies(S, case):
+    """``ops.gram._stats_fold`` (PR 44) at the hand-off's row block of the
+    stream cell (16,384 x 1000 bf16: ``_block_rows``), at a width the chip
+    stores by rows and over f32 rows, beside a micro-batch's 2,097,152
+    labels: the block is an operand of both products as it lies (the layout
+    the 2M-row build reads, so a block lands as the build would have it: no
+    relayout), the temporaries are under the block's size, and ``G``, ``b``,
+    ``yy`` are donated and aliased: added to in place, 12 MB a bundle
+    whatever the stream does."""
+    from tpu_sgd.ops import gram
+
+    n = 2_097_152
+    d, dtype = {"stream_cell": (D, BF16), "by_rows": (1024, BF16),
+                "f32": (D, F32)}[case]
+    item = jnp.dtype(dtype).itemsize
+    rows = (32 << 20) // (d * item) // 1024 * 1024
+    assert rows == {"stream_cell": 16_384, "by_rows": 16_384,
+                    "f32": 8_192}[case]
+    compiled = gram._stats_fold.lower(
+        S((d, d), F32), S((d,), F32), S((), F32), S((n,), F32), S((), I32),
+        S((rows, d), dtype)).compile()
+    memory = compiled.memory_analysis()
+    totals = (d * d + d + 1) * 4
+    assert memory.temp_size_in_bytes < rows * d * item // 4
+    # all three outputs but the scalar that says "done" are the arguments'
+    # own buffers (a width of 1000 is padded to the tile)
+    assert totals <= memory.alias_size_in_bytes \
+        <= memory.output_size_in_bytes < memory.alias_size_in_bytes + 4096
+    assert memory.output_size_in_bytes < 1.1 * totals
+    text = compiled.as_text()
+    assert " transpose(" not in text
+    # the compiler's own copies are of scalars (the offset, yy)
+    assert all("[]" in line.split(" copy(")[0].split("=")[1]
+               for line in text.split("\n") if " copy(" in line)
+    assert text.count(" convolution(") == (2 if dtype == BF16 else 1)
+    assert "jit(_stats_fold)/sgd.stats_build/dot_general" in text
+    by_features, by_rows = (1, 0), (0, 1)
+    block = compiled.input_formats[0][5]
+    assert block.layout.major_to_minor == (
+        by_rows if case == "by_rows" else by_features)
+    whole = gram._stats_build.lower(S((n, d), dtype), S((n,), F32)).compile()
+    assert whole.input_formats[0][0].layout.major_to_minor \
+        == block.layout.major_to_minor
+
+
 def test_a_fit_from_the_totals_holds_nothing_of_xs_size(S):
     """``sgd_run`` over the totals' bundle at the stream cell's shape: its
     arguments are G, b, yy, the labels and the weights (12.4 MB), so a fit

@@ -1317,3 +1317,50 @@ def test_the_totals_form_serves_a_full_batch_and_no_window(rng):
     leaves, tree = jax.tree_util.tree_flatten(st)
     assert len(leaves) == 3
     assert tree == jax.tree_util.tree_structure(stats_build(X[::-1], y))
+
+
+# ---- the totals folded from row blocks (PR 44) --------------------------------
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_blocks_fold_is_the_whole_build_within_f32_sums(rng, dtype):
+    """``stats_fold`` over a micro-batch's row blocks in order (four of 1,024
+    rows and a remainder) against ``stats_build`` of the whole: the same
+    products at the same precision, summed in another order of f32
+    additions, so the two agree to f32 rounding of the sums and the fold is
+    no further from the float64 totals than the whole build; ``G``, ``b``,
+    ``yy`` are given up (donated) at every call."""
+    from tpu_sgd.ops.gram import stats_build, stats_fold
+
+    n, d, rows = 4 * 1024 + 100, 16, 1024
+    X = jnp.asarray(rng.normal(size=(n, d)), dtype)
+    y = jnp.asarray(rng.normal(size=n), jnp.float32)
+    whole = stats_build(X, y)
+    totals, held = None, []
+    for a in range(0, n, rows):
+        held.append(totals)
+        totals, done = stats_fold(totals, y, a, X[a:a + rows])
+        assert done.shape == () and float(done) == float(totals[0][0, 0])
+    assert all(t[0].is_deleted() for t in held[1:])  # added to in place
+    assert [t.dtype for t in totals] == [jnp.float32] * 3
+    X64, y64 = np.asarray(X, np.float64), np.asarray(y, np.float64)
+    exact = (X64.T @ X64, X64.T @ y64, y64 @ y64)
+    eps = float(np.finfo(np.float32).eps)
+    for got, want, true in zip(
+            totals, (whole.G_tot, whole.b_tot, whole.yy_tot), exact):
+        assert got.shape == want.shape
+        assert _rel(got, want) < 16 * eps
+        assert _rel(got, true) <= 1.5 * _rel(want, true) + eps
+
+
+def test_the_blocks_fold_carries_the_builds_scope_under_its_own_name():
+    """What ``stats_build_ms`` reads (the scope) and what the compile cache
+    keys on (the jitted function's name: PERF.md, PR 25)."""
+    from tpu_sgd.ops import gram
+
+    S = jax.ShapeDtypeStruct
+    text = gram._stats_fold.lower(
+        S((8, 8), jnp.float32), S((8,), jnp.float32), S((), jnp.float32),
+        S((512,), jnp.float32), S((), jnp.int32),
+        S((256, 8), jnp.bfloat16)).as_text(debug_info=True)
+    assert "sgd.stats_build" in text and "jit(_stats_fold)" in text
+    assert "jit(_stats_build)" not in text

@@ -541,11 +541,17 @@ def test_a_stream_that_raises_leaves_the_last_finished_model():
     assert alg._batch_count == 4
 
 
-def test_the_folds_spans_tile_a_pass(tmp_path):
+@pytest.mark.parametrize("schedule,totals", [("auto", 0),
+                                             ("resident_gram", 1)])
+def test_the_folds_spans_tile_a_pass(tmp_path, schedule, totals):
     """``stream.wait`` / ``stream.batch`` (``fit.run``, ``stream.publish``)
     on the fold's thread, ``stream.stage`` on the worker's; ``ahead`` is 0
-    for a pass's first micro-batch and 1 after it."""
+    for a pass's first micro-batch and 1 after it.  On the statistics
+    schedule every block is ``folded`` under its copy, every fit runs from
+    ``totals`` made ahead and builds nothing (no ``train.stats``); on the
+    stock schedule (the planner's at these sizes) none."""
     import json
+    import warnings
 
     from tpu_sgd import obs
 
@@ -554,6 +560,8 @@ def test_the_folds_spans_tile_a_pass(tmp_path):
     stream, _ = _replayable_stream(batches=3)
     alg = StreamingLinearRegressionWithSGD(step_size=0.3, num_iterations=5)
     alg.set_initial_weights(np.zeros(12, np.float32))
+    alg.algorithm.set_schedule(schedule)
+    warnings.simplefilter("ignore")  # forced: a net loss at these sizes
     taken = [threading.Event() for _ in range(4)]
 
     def batches():
@@ -579,6 +587,9 @@ def test_the_folds_spans_tile_a_pass(tmp_path):
     finally:
         obs.disable()
     spans = [json.loads(line) for line in open(path)]
+    built = [s for s in spans if s.get("name") == "train.stats"]
+    stats = [s["stats"] for s in spans if s.get("name") == "train.select"]
+    assert built == [] and stats == [totals] * 3
     spans = [s for s in spans if s.get("name", "").startswith(("stream.",
                                                                "fit.run"))]
     by = {}
@@ -590,6 +601,7 @@ def test_the_folds_spans_tile_a_pass(tmp_path):
     turns = sorted(by["stream.batch"], key=lambda s: s["t0_s"])
     assert [s["index"] for s in turns] == [0, 1, 2]
     assert [s["ahead"] for s in turns] == [0, 1, 1]
+    assert [s["totals"] for s in turns] == [totals] * 3
     assert all(s["rows"] == 500 for s in turns)
     ids = {s["span_id"] for s in turns}
     assert all(s["parent_id"] in ids
@@ -603,7 +615,8 @@ def test_the_folds_spans_tile_a_pass(tmp_path):
                                           for s in by["stream.stage"]}
     staged = [s for s in by["stream.stage"] if "blocks" in s]
     assert len(staged) == 3 and all(s["blocks"] == 1 and
-                                    s["bytes"] == 500 * 12 * 4
+                                    s["bytes"] == 500 * 12 * 4 and
+                                    s["folded"] == totals
                                     for s in staged)
 
 
@@ -620,13 +633,13 @@ def test_batches_go_ahead_only_beside_the_stock_and_totals_schedules(
     from tpu_sgd.models import streaming
 
     stream, _ = _replayable_stream(batches=4)
-    made = []
+    made = []  # of each hand-off in blocks: whether it folded them to totals
     real = streaming.StagedAhead
 
     class Counted(real):
-        def __init__(self, X):
-            made.append(X.shape)
-            super().__init__(X)
+        def __init__(self, X, y=None, alive=None):
+            made.append(y is not None)
+            super().__init__(X, y, alive)
 
     monkeypatch.setattr(streaming, "StagedAhead", Counted)
 
@@ -640,21 +653,26 @@ def test_batches_go_ahead_only_beside_the_stock_and_totals_schedules(
         with warnings.catch_warnings():
             warnings.simplefilter("ignore")  # forced gram: a net loss here
             alg.train_on(stream)
-        return alg, len(made)
+        return alg, list(made)
 
     auto, n_auto = fold("auto")
-    assert n_auto == 2  # batches 0 and 1 were taken before any plan
-    assert auto._stages_ahead()
+    # batches 0 and 1 were taken before any plan; the stock plan keeps rows
+    assert n_auto == [False] * 2
+    assert auto._stages_ahead() and auto._totals_key() is None
     off, n_off = fold("off")
-    assert n_off == 4  # nothing to wait for: the optimizer runs as it is
+    assert n_off == [False] * 4  # nothing to wait for: it runs as it is
     gram, n_gram = fold("resident_gram")
     opt = gram.algorithm.optimizer
     assert opt.sufficient_stats and opt.stats_in_totals()
-    assert n_gram == 2 and gram._stages_ahead()  # as the stock schedule
+    # batch 0 plans in run(); batch 1, taken before that, is folded by its
+    # own fit; 2 and 3 by the worker, ahead: none is ever made whole
+    assert n_gram == [True] * 3 and gram._stages_ahead()
+    assert gram._totals_key() == ((500, 12), "float32")
     prefix, n_prefix = fold("resident_gram", fraction=0.5)
     opt = prefix.algorithm.optimizer
     assert opt.sufficient_stats and not opt.stats_in_totals()
-    assert n_prefix == 0 and not prefix._stages_ahead()
+    assert n_prefix == [] and not prefix._stages_ahead()
+    assert prefix._totals_key() is None
     for flag in ("host_streaming", "streamed_stats"):  # beside the totals
         setattr(gram.algorithm.optimizer, flag, True)
         assert not gram._stages_ahead()
@@ -668,15 +686,17 @@ def test_batches_go_ahead_only_beside_the_stock_and_totals_schedules(
 
 # ---- the statistics schedule in its totals form (PR 41) ----------------------
 
-def _statistics_fold(stream, how="ahead", d=6, iterations=8):
+def _statistics_fold(stream, how="ahead", d=6, iterations=8,
+                     schedule="resident_gram", fraction=1.0,
+                     model=StreamingLinearRegressionWithSGD):
     """A fold of ``stream`` on the statistics schedule (forced: the sizes
     are tiny) and what its listener saw."""
     import warnings
 
-    alg = StreamingLinearRegressionWithSGD(step_size=0.2,
-                                           num_iterations=iterations)
+    alg = model(step_size=0.2, num_iterations=iterations,
+                mini_batch_fraction=fraction)
     alg.set_initial_weights(np.zeros(d, np.float32))
-    alg.algorithm.set_schedule("resident_gram")
+    alg.algorithm.set_schedule(schedule)
     calls = []
     alg.add_model_update_listener(
         lambda model, count: calls.append(
@@ -692,16 +712,65 @@ def _statistics_fold(stream, how="ahead", d=6, iterations=8):
     return alg, calls
 
 
+def _count_calls(monkeypatch, cls, name):
+    """A list that gains a 1 for every call of ``cls.name``."""
+    calls, real = [], getattr(cls, name)
+
+    def counted(self, *args, **kwargs):
+        calls.append(1)
+        return real(self, *args, **kwargs)
+
+    monkeypatch.setattr(cls, name, counted)
+    return calls
+
+
+def _traced(tmp_path, fn, *args, **kwargs):
+    """``(fn(*args, **kwargs), the spans it wrote)``."""
+    import json
+
+    from tpu_sgd import obs
+
+    path = tmp_path / "trace.jsonl"
+    obs.enable(str(path))
+    try:
+        out = fn(*args, **kwargs)
+    finally:
+        obs.disable()
+    with open(path) as f:
+        spans = [json.loads(line) for line in f]
+    return out, sorted((s for s in spans if "name" in s),
+                       key=lambda s: s["t0_s"])
+
+
 @pytest.mark.parametrize("kind", ["host", "device"])
 def test_the_statistics_fold_ahead_is_the_in_turn_fold_bit_for_bit(
-        kind, monkeypatch):
+        kind, monkeypatch, tmp_path):
     """As ``test_train_on_ahead_is_the_in_turn_fold_bit_for_bit``, under the
-    statistics schedule: a micro-batch staged ahead is built and trained by
-    the programs that build and train one copied in its turn."""
-    _blocked(monkeypatch, d=6)
+    statistics schedule: a host micro-batch's totals are folded from its row
+    blocks by the same programs in the same order whether the worker issues
+    them ahead or its own fit in turn (``folded`` says the worker did); a
+    device array is built and trained where it lies, as before."""
+    gd = _blocked(monkeypatch, d=6)
+    folds = _count_calls(monkeypatch, gd.StagedAhead, "_fold")
+    joins = _count_calls(monkeypatch, gd.StagedAhead, "whole")
     stream = _mixed_stream(kind)
-    (ahead, calls_a) = _statistics_fold(stream, "ahead")
+    (ahead, calls_a), spans = _traced(tmp_path, _statistics_fold, stream,
+                                      "ahead")
+    staged = [s for s in spans if s["name"] == "stream.stage"
+              and "blocks" in s]
+    if kind == "host":
+        # batch 0 plans by rows, 1 was taken before that and is folded by
+        # its fit; 3 (under the empty 2, which has no fit) and 4 (under 3's
+        # fit) by the worker
+        assert [(s["blocks"], s["folded"]) for s in staged] \
+            == [(4, 4), (4, 4)]
+        assert folds == [1, 1, 1] and joins == []
+        assert [s["totals"] for s in spans
+                if s["name"] == "stream.batch"] == [0, 0, 0, 1, 1]
+    else:
+        assert staged == [] and folds == [] and joins == []
     (turn, calls_t) = _statistics_fold(stream, "in turn")
+    assert len(folds) == (6 if kind == "host" else 0)
     for alg in (ahead, turn):
         opt = alg.algorithm.optimizer
         assert opt.last_plan.schedule == "resident_gram"
@@ -720,10 +789,11 @@ def test_micro_batches_of_one_shape_share_one_runner_and_one_build(
         monkeypatch):
     """Three micro-batches of one shape on the statistics schedule: ONE
     runner in ``_run_cache`` (its key holds the optimizer's one unbound
-    executor, nothing of a micro-batch), ONE compiled build, no compile
-    request of a fit's after the first micro-batch's (the third's is the
-    fold's: the first micro-batch to go ahead is the first to be joined),
-    and none at all in a second pass."""
+    executor, nothing of a micro-batch), ONE compiled build (the first
+    micro-batch's, whose ``run()`` plans: the others' totals are folded from
+    their row blocks), TWO block-fold programs (a full block's and the
+    remainder's, whichever thread folds), and no compile request at all in
+    a second pass."""
     import warnings
 
     from jax._src import monitoring
@@ -737,6 +807,7 @@ def test_micro_batches_of_one_shape_share_one_runner_and_one_build(
               if b[0].shape[0]]
     assert len(stream) == 3
     builds = gram._stats_build._cache_size()
+    folds = gram._stats_fold._cache_size()
     requests, seen = [0], []
 
     def count(event, duration, **kw):
@@ -761,21 +832,23 @@ def test_micro_batches_of_one_shape_share_one_runner_and_one_build(
     runners = [k for k in opt._run_cache if k[0] == "run"]
     assert len(runners) == 1 and runners[0][1] is opt._totals_gradient
     assert gram._stats_build._cache_size() == builds + 1
-    assert seen[0] > 0 and seen[1] == seen[0]
-    assert seen[2] == seen[0] + 1  # ``_stage_join``, once
+    assert gram._stats_fold._cache_size() == folds + 2
+    assert 0 < seen[0] < seen[1] <= seen[2]  # the fold's programs, once
     assert seen[3:] == [seen[2]] * 3
 
 
 def test_at_most_two_micro_batches_are_alive_on_the_statistics_schedule(
         monkeypatch):
     """``test_at_most_two_micro_batches_are_alive_at_once`` under the
-    statistics schedule: the totals' bundle holds no rows, so after any fit
-    the device holds the rows of at most two micro-batches."""
+    statistics schedule: the worker is one micro-batch ahead as there, and
+    the totals are folded from the row blocks as they land, so after any fit
+    but the first (whose ``run()`` plans, by rows) NO live device array has a
+    micro-batch's row count: what is alive are blocks on their way."""
     import warnings
 
     import jax
 
-    _blocked(monkeypatch, d=6)
+    gd = _blocked(monkeypatch, d=6)
     stream = _mixed_stream("host", batches=6)
     rows = 3 * 1024 + 100
     alg = StreamingLinearRegressionWithSGD(step_size=0.2, num_iterations=4)
@@ -792,9 +865,9 @@ def test_at_most_two_micro_batches_are_alive_on_the_statistics_schedule(
 
     def run_warm(data, model):
         out = real(data, model)
-        alive.append(sum(a.shape[0] for a in jax.live_arrays()
-                         if a.ndim == 2 and a.shape[1] == 6
-                         and a.shape[0] > 6))  # rows, not the (6, 6) G
+        alive.append([a.shape[0] for a in jax.live_arrays()
+                      if a.ndim == 2 and a.shape[1] == 6
+                      and a.shape[0] > 6])  # rows, not the (6, 6) G
         return out
 
     monkeypatch.setattr(alg.algorithm, "run_warm", run_warm)
@@ -805,7 +878,10 @@ def test_at_most_two_micro_batches_are_alive_on_the_statistics_schedule(
     assert alg.algorithm.optimizer._totals_gradient is not None
     for j, finished in enumerate(taken_at):
         assert finished >= j - 1, (j, taken_at)
-    assert len(alive) == 5 and max(alive) <= 2 * rows
+    assert len(alive) == 5
+    for held in alive[1:]:
+        assert all(n <= 1024 for n in held), alive  # blocks, never a batch
+        assert sum(held) <= gd._STAGE_IN_FLIGHT * 1024 < rows
 
 
 def test_the_stock_and_the_statistics_folds_agree_within_the_cells_limits():
@@ -858,3 +934,172 @@ def test_the_stock_and_the_statistics_folds_agree_within_the_cells_limits():
                   / np.maximum(np.abs(ref_losses), 1e-3)) \
         < limits["loss_max_gap"]
     assert abs(np.linalg.norm(w) - change) / change < limits["dw_norm_gap"]
+
+
+# ---- the totals folded from the row blocks as they land (PR 44) --------------
+
+def _shaped_stream(shapes, d=6):
+    """Micro-batches of ``shapes`` rows each (f32, host)."""
+    w_true = np.linspace(-1, 1, d).astype(np.float32)
+    out = []
+    for i, rows in enumerate(shapes):
+        r = np.random.default_rng(70 + i)
+        X = r.normal(size=(rows, d)).astype(np.float32)
+        y = (X @ w_true + 0.05 * r.normal(size=rows)).astype(np.float32)
+        out.append((X, y))
+    return out
+
+
+def _same_fold(a, b, calls_a, calls_b):
+    np.testing.assert_array_equal(np.asarray(a.latest_model().weights),
+                                  np.asarray(b.latest_model().weights))
+    assert a.loss_history == b.loss_history
+    assert a._batch_count == b._batch_count
+    assert [c[0] for c in calls_a] == [c[0] for c in calls_b]
+    for (_, wa, la), (_, wb, lb) in zip(calls_a, calls_b):
+        np.testing.assert_array_equal(wa, wb)
+        np.testing.assert_array_equal(la, lb)
+
+
+def test_micro_batches_of_two_shapes_fold_only_where_the_plan_is_theirs(
+        monkeypatch):
+    """A stream of shapes A, B, B, A, A on the statistics schedule: a
+    micro-batch is folded to its totals where the plan in hand is for ITS
+    shape when its turn comes, in turn and ahead alike, bit for bit; one of
+    another shape than the planned one goes as rows, through ``whole()``
+    where it went ahead, and its ``run()`` plans anew.  The worker folds
+    ahead only what is SURE to be trained from totals (the fit before it
+    keeps the plan): B2, taken while the plan was A's, lands as blocks and
+    is folded where they lie once B1's fit has planned for B."""
+    gd = _blocked(monkeypatch, d=6)
+    a, b = 3 * 1024 + 100, 2 * 1024 + 7
+    stream = _shaped_stream([a, b, b, a, a])
+    folds = _count_calls(monkeypatch, gd.StagedAhead, "_fold")
+    late = _count_calls(monkeypatch, gd.StagedAhead, "fold")
+    joins = _count_calls(monkeypatch, gd.StagedAhead, "whole")
+    ahead, calls_a = _statistics_fold(stream, "ahead")
+    # B2 and the last A: folded where their blocks lay; the first A after
+    # the Bs: made whole, as before, and planned by its run()
+    assert (len(folds), len(late), len(joins)) == (2, 2, 1)
+    turn, calls_t = _statistics_fold(stream, "in turn")
+    assert (len(folds), len(late), len(joins)) == (4, 2, 1)
+    _same_fold(ahead, turn, calls_a, calls_t)
+    for alg in (ahead, turn):
+        assert alg.algorithm.optimizer.last_plan.schedule == "resident_gram"
+        assert alg._totals_key() == ((a, 6), "float32")
+        assert alg._totals_key(stream[1][0]) is None  # B: not the plan's
+
+
+@pytest.mark.parametrize("case", ["logistic", "half", "off", "intercept"])
+def test_every_other_stream_goes_through_whole_as_before(case, monkeypatch):
+    """A logistic stream, a fraction under 1 (stock: the planner's choice
+    at these sizes), the statistics set by hand under ``set_schedule("off")``
+    (no plan says what the next micro-batch's schedule is) and a harness
+    that appends an intercept column (the optimizer's matrix is not the
+    micro-batch): no block is ever folded, what went ahead is joined by
+    ``whole()``, and the fold is the in-turn fold bit for bit."""
+    from tpu_sgd.models.streaming import StreamingLogisticRegressionWithSGD
+
+    gd = _blocked(monkeypatch, d=6)
+    stream = _shaped_stream([3 * 1024 + 100] * 4)
+    model, schedule, fraction = StreamingLinearRegressionWithSGD, "auto", 1.0
+    if case == "logistic":
+        model = StreamingLogisticRegressionWithSGD
+        stream = [(X, (y > 0).astype(np.float32)) for X, y in stream]
+    elif case == "half":
+        fraction = 0.5
+    elif case == "off":
+        schedule = "off"
+    elif case == "intercept":
+        schedule = "resident_gram"
+    folds = _count_calls(monkeypatch, gd.StagedAhead, "_fold")
+    joins = _count_calls(monkeypatch, gd.StagedAhead, "whole")
+
+    def fold(how):
+        real = model.__init__
+
+        def init(self, *args, **kwargs):
+            real(self, *args, **kwargs)
+            if case == "off":
+                self.algorithm.optimizer.set_sufficient_stats(True)
+            if case == "intercept":
+                self.algorithm.set_intercept(True)
+
+        monkeypatch.setattr(model, "__init__", init)
+        try:
+            return _statistics_fold(stream, how, schedule=schedule,
+                                    fraction=fraction, model=model)
+        finally:
+            monkeypatch.setattr(model, "__init__", real)
+
+    ahead, calls_a = fold("ahead")
+    staged = len(joins)
+    assert staged == (4 if case == "off" else 2) and folds == []
+    assert ahead._totals_key() is None
+    turn, calls_t = fold("in turn")
+    assert len(joins) == staged and folds == []
+    _same_fold(ahead, turn, calls_a, calls_t)
+    if case in ("off", "intercept"):  # the totals all the same, built whole
+        assert ahead.algorithm.optimizer._totals_gradient is not None
+
+
+def test_a_stream_that_raises_between_two_folds_leaves_the_finished_model(
+        monkeypatch):
+    """``test_a_stream_that_raises_leaves_the_last_finished_model`` on the
+    totals path: the source fails while the worker is asked for the fifth
+    micro-batch, after the fourth was folded ahead; the fourth is trained,
+    the model is the four's, the plan stands and the stream trains on."""
+    gd = _blocked(monkeypatch, d=6)
+    stream = _shaped_stream([2 * 1024 + 50] * 6)
+    folds = _count_calls(monkeypatch, gd.StagedAhead, "_fold")
+    want, _ = _statistics_fold(stream[:4])
+    assert len(folds) == 3
+    alg = StreamingLinearRegressionWithSGD(step_size=0.2, num_iterations=8)
+    alg.set_initial_weights(np.zeros(6, np.float32))
+    alg.algorithm.set_schedule("resident_gram")
+
+    def batches():
+        yield from stream[:4]
+        raise OSError("the source went away")
+
+    import warnings
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        with pytest.raises(OSError, match="went away"):
+            alg.train_on(batches())
+        assert alg._batch_count == 4 and len(folds) == 6
+        np.testing.assert_array_equal(
+            np.asarray(alg.latest_model().weights),
+            np.asarray(want.latest_model().weights))
+        opt = alg.algorithm.optimizer
+        assert opt.last_plan is not None and alg._totals_key() is not None
+        alg.train_on(stream[4:])
+    assert alg._batch_count == 6 and len(folds) == 8
+
+
+def test_a_bundle_whose_optimizer_no_longer_trains_from_totals_is_refused():
+    """The rows of a folded micro-batch are gone: a fit that could only run
+    from them says so, at once."""
+    from tpu_sgd.optimize.gradient_descent import StagedAhead
+
+    (X, y), = _shaped_stream([64])
+    alg = StreamingLinearRegressionWithSGD(step_size=0.2, num_iterations=4)
+    alg.set_initial_weights(np.zeros(6, np.float32))
+    alg.algorithm.set_schedule("off")
+    staged = StagedAhead(X, y)
+    assert staged.totals.G_tot.shape == (6, 6) and staged.folded == 1
+    assert (staged.shape, staged.dtype, staged.nbytes, staged.count) \
+        == (X.shape, X.dtype, X.nbytes, 1)
+    with pytest.raises(RuntimeError, match="holds no rows"):
+        alg.algorithm.run_warm((staged, staged.y), alg.model)
+    # and the same bundle trains where the statistics are the schedule
+    alg.algorithm.optimizer.set_sufficient_stats(True)
+    model = alg.algorithm.run_warm((staged, staged.y), alg.model)
+    ref = StreamingLinearRegressionWithSGD(step_size=0.2, num_iterations=4)
+    ref.set_initial_weights(np.zeros(6, np.float32))
+    ref.algorithm.set_schedule("off")
+    ref.algorithm.optimizer.set_sufficient_stats(True)
+    ref.train_on_batch(X, y)
+    np.testing.assert_array_equal(np.asarray(model.weights),
+                                  np.asarray(ref.latest_model().weights))

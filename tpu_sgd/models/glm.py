@@ -20,16 +20,19 @@ import numpy as np
 from tpu_sgd.models.labeled_point import LabeledPoint, to_arrays
 from tpu_sgd.obs.spans import span
 from tpu_sgd.ops.sparse import append_bias_auto, is_sparse, row_matrix_bcoo
+from tpu_sgd.optimize.gradient_descent import StagedAhead
 from tpu_sgd.optimize.optimizer import Optimizer
 
 DatasetLike = Union[Tuple, Iterable[LabeledPoint]]
 
 
 def as_features(X):
-    """Features as ``run`` takes them: BCOO passes through undensified and
-    an array that is on the device stays there (``np.asarray`` would fetch
-    all of it); anything else is a numpy array."""
-    if is_sparse(X) or isinstance(X, jax.Array):
+    """Features as ``run`` takes them: BCOO passes through undensified, an
+    array that is on the device stays there (``np.asarray`` would fetch all
+    of it) and so does a stream's micro-batch that went ahead of its fit
+    (``StagedAhead``: it answers ``shape`` and ``dtype`` as its rows would,
+    and is planned as they would be); anything else is a numpy array."""
+    if is_sparse(X) or isinstance(X, (jax.Array, StagedAhead)):
         return X
     return np.asarray(X)
 

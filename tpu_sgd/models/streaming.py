@@ -12,16 +12,29 @@ micro-batches, and ``train_on`` folds the model through it.
 The fold holds one micro-batch AHEAD (PERF.md, PR 40): a micro-batch does
 not depend on the weights, so while batch k trains a worker thread takes
 batch k+1 from the stream and issues the host-to-device copy of its dense
-rows as blocks that need no device program
-(``gradient_descent.StagedAhead``); they land under the running fit, and the
-fold waits only for what is left of the copy.  Spans (``obs.spans``):
+rows in blocks (``gradient_descent.StagedAhead``), in one of two forms.
+Where the fit runs from the TOTALS of its rows (least squares on the
+planner's statistics schedule, a full batch: ``X^T X``, ``X^T y``,
+``y^T y``) each block's share is folded into them by a device program of
+its own while the next blocks are on the wire and the block is deleted: the
+rows are never one array, nothing is built after the copy, the device holds
+16 blocks and two 12 MB bundles (0.55 GB where the rows held 8.92), and the
+worker goes on to batch k+2's blocks as soon as batch k+1's are issued, so
+the wire never stands between two micro-batches (PERF.md, PR 44: a pass of
+three 4.19 GB micro-batches 0.900 s where the wire's floor is 0.880 and the
+rows form took 0.985).  Every other stream (logistic, a fraction under 1,
+the stock schedule, ``set_schedule("off")``, a shape the plan in hand is not
+for) goes as ROWS: blocks that need no device program, which land under the
+running fit and are made the one array when the chip is free; the fold
+waits only for what is left of the copy.  Spans (``obs.spans``):
 ``stream.run`` is all of ``train_on``; on its thread ``stream.wait`` (the
-worker's answer, then the blocks made whole), ``stream.batch`` (``index``,
-``rows``, ``ahead``: 1 where the worker was issuing this batch's copy
-before the previous batch's fit returned) around the fit's
-own spans and ``stream.publish`` (the stream position, the history's tail,
-the checkpoint, the listeners); on the worker's ``stream.stage`` (``bytes``,
-``blocks`` of a batch that went ahead).
+worker's answer, then in the rows form the blocks made whole),
+``stream.batch`` (``index``, ``rows``, ``ahead``: 1 where the worker was
+issuing this batch's copy before the previous batch's fit returned,
+``totals``: 1 where the fit ran from a bundle made ahead of it) around the
+fit's own spans and ``stream.publish`` (the stream position, the history's
+tail, the checkpoint, the listeners); on the worker's ``stream.stage``
+(``bytes``, ``blocks``, ``folded`` of a batch that went ahead).
 
 Driver recovery (SURVEY.md §5.4c): the reference rides DStream
 checkpointing — a restarted driver resumes from the latest model and
@@ -36,6 +49,7 @@ exactly, because each micro-batch update is deterministic in
 
 from __future__ import annotations
 
+import collections
 import itertools
 import threading
 from concurrent.futures import ThreadPoolExecutor
@@ -56,22 +70,44 @@ from tpu_sgd.optimize.gradient_descent import StagedAhead
 Batch = Tuple[np.ndarray, np.ndarray]
 
 
-def _take(batches, began: threading.Event, stage: bool, training: list):
+def _shape_and_type(X):
+    """What a plan is keyed by (``GeneralizedLinearAlgorithm._apply_plan``'s
+    ``_plan_key`` starts with it)."""
+    return np.shape(X), str(X.dtype)
+
+
+def _take(batches, began: threading.Event, stage: bool, training: list,
+          totals=None, alive=None):
     """On ``train_on``'s worker thread: the stream's next micro-batch
     ``(X, y)``, None at its end.  Where ``stage`` allows it, dense host rows
     that fit the device's free memory come back as a :class:`StagedAhead`,
-    their copy issued in blocks that need no device program; ``began`` is
-    set before the first block is issued.  BCOO features, device arrays, an
-    empty batch and one too large to lie beside the batch in training are
-    handed on as they are (the fit copies what it must, in turn).
+    their copy issued in blocks; ``began`` is set before the first block is
+    issued.  BCOO features, device arrays, an empty batch and one too large
+    to lie beside the batch in training are handed on as they are (the fit
+    copies what it must, in turn).
 
-    ``training`` holds the features the fold is about to train (taken out
-    of it here, so that no reference outlives the wait): the first block is
+    ``totals`` is the ``(shape, dtype name)`` of the micro-batches that are
+    SURE to be trained from the totals of their rows
+    (``StreamingLinearAlgorithm._totals_key``; None: none is).  Such a one
+    comes back in the TOTALS form, with its labels on the device: each
+    block's share of ``(G, b, yy)`` is folded in under the next blocks'
+    transfer and the block deleted, so nothing of it is ever whole, and its
+    first block is issued as soon as fewer than 16 blocks are alive in
+    ``alive``, the deque that counts them across the micro-batches: the wire
+    does not stand between two micro-batches (PERF.md, PR 44).
+
+    Any other goes in the ROWS form, blocks that need no device program, and
+    ``training`` holds the features the fold is about to train (taken out of
+    it here, so that no reference outlives the wait): the first block is
     issued only once they are whole on the device, when the blocks they were
     made of are gone.  Transfers do not wait for the chip, so a late join
     (its last blocks still on the wire, a stalled device) would else have a
     third micro-batch land beside its blocks and its result (12.6 GB read
-    in one traced run; PERF.md, PR 40)."""
+    in one traced run; PERF.md, PR 40).
+
+    ``stream.stage`` says ``bytes`` and ``blocks`` of a batch that went
+    ahead, and ``folded``: the blocks whose share was folded under the copy
+    (0 in the rows form)."""
     with span("stream.stage") as sp:
         batch = next(batches, None)
         if batch is None:
@@ -81,10 +117,16 @@ def _take(batches, began: threading.Event, stage: bool, training: list):
         if (stage and isinstance(X, np.ndarray) and X.ndim == 2
                 and X.shape[0]
                 and X.nbytes <= plan_mod.device_budget()[0]):
-            jax.block_until_ready(training.pop())
+            folds = _shape_and_type(X) == totals
+            before = training.pop()
+            if not folds:
+                jax.block_until_ready(before)
+            del before
             began.set()
-            X = StagedAhead(X)
-            sp.set(bytes=X.nbytes, blocks=len(X.blocks))
+            X = StagedAhead(X, y if folds else None, alive)
+            if folds:
+                y = X.y
+            sp.set(bytes=X.nbytes, blocks=X.count, folded=X.folded)
         return X, y
 
 
@@ -254,9 +296,18 @@ class StreamingLinearAlgorithm:
 
     def _fit(self, X, y) -> bool:
         """The batch optimizer from the latest model over one micro-batch;
-        False for an empty one (no update)."""
+        False for an empty one (no update).  A dense host micro-batch that
+        is trained from its totals (``_totals_key``) takes the path one
+        taken ahead takes, on this thread: its row blocks folded into
+        ``(G, b, yy)`` as they land, by the same programs in the same
+        order, so the fold ahead and the fold in turn differ in when,
+        never in what."""
         if X.shape[0] == 0:
             return False
+        if (isinstance(X, np.ndarray) and X.ndim == 2
+                and self._totals_key(X) is not None):
+            X = StagedAhead(X, y)
+            y = X.y
         self.model = self.algorithm.run_warm((X, y), self.model)
         return True
 
@@ -288,11 +339,15 @@ class StreamingLinearAlgorithm:
         weights, so while batch k trains a worker thread takes batch k+1
         from the stream and issues the host-to-device copy of its dense
         rows (``_take``), which lands under the running fit; the fold then
-        waits only for what of the copy is left.  The device holds the
-        batch in training and the one ahead, never a third; the weights,
-        the listeners' calls and the checkpoints are the in-turn fold's
-        (a batch taken ahead and not yet trained has not advanced
-        ``_batch_count``)."""
+        waits only for what of the copy is left.  In the rows form the
+        device holds the batch in training and the one ahead, never a
+        third; in the totals form (a least-squares stream on the statistics
+        schedule) no batch at all: 16 row blocks on their way and a 12 MB
+        bundle a batch.  The weights, the listeners' calls and the
+        checkpoints are the in-turn fold's, bit for bit (a batch taken
+        ahead and not yet trained has not advanced ``_batch_count``; its
+        totals are folded by the programs, in the order, that
+        ``train_on_batch`` folds them with)."""
         if skip is None:
             skip = self._resume_skip
         self._resume_skip = 0
@@ -306,14 +361,16 @@ class StreamingLinearAlgorithm:
         return self.model
 
     def _stages_ahead(self) -> bool:
-        """Whether a micro-batch may lie on the device beside the one in
+        """Whether a micro-batch may go to the device beside the one in
         training: on one device alone, on the stock resident schedule or
         on the statistics schedule in its TOTALS form (a full batch:
         ``GradientDescent.stats_in_totals``), and once a plan (or
         ``set_schedule("off")``) has said that this is the schedule.  The
-        totals' device state is 12 MB at d = 1000 and their build reads
-        the micro-batch where it lies, with no temporary of its size
-        (``ops.gram.stats_build``; PERF.md, PR 41).  The planner's other
+        totals' device state is 12 MB at d = 1000; they are folded from the
+        micro-batch's row blocks as they land where the plan in hand is for
+        its shape (``_totals_key``: no rows are kept), and else built from
+        the rows where they lie, with no temporary of their size
+        (``ops.gram.stats_build``; PERF.md, PRs 41 and 44).  The planner's other
         schedules size their own device state (a prefix stack, streamed
         chunks) from the memory that was free when they planned: the
         PREFIX build over a 4.19 GB micro-batch ran out of memory on the
@@ -332,15 +389,48 @@ class StreamingLinearAlgorithm:
         return (self.algorithm.schedule == "off"
                 or getattr(opt, "last_plan", None) is not None)
 
+    def _totals_key(self, training=None):
+        """``(shape, dtype name)`` of the dense host micro-batches that are
+        SURE to be trained from the totals of their rows, so that their row
+        blocks may be folded into ``(G, b, yy)`` as they land and no row
+        kept (``StagedAhead``'s totals form); None where none is.  They are
+        where ``_stages_ahead`` holds on the statistics schedule
+        (``GradientDescent.fits_from_totals``), the plan in hand is for
+        THIS shape and type (``_plan_key``: a micro-batch of another shape
+        is planned anew by its fit, and once its rows are folded a stock
+        fit could not be run from them), the harness hands the optimizer
+        the matrix as it is (no intercept column, no scaling), and
+        ``training``, the features whose fit comes first (None or empty:
+        none does), are of that shape and type too, so that their fit
+        leaves the plan as it is."""
+        alg, opt = self.algorithm, self.algorithm.optimizer
+        key = getattr(opt, "_plan_key", None)
+        fits = getattr(opt, "fits_from_totals", None)
+        if (key is None or getattr(opt, "last_plan", None) is None
+                or fits is None or not fits() or not self._stages_ahead()
+                or alg.add_intercept or alg.use_feature_scaling):
+            return None
+        if (training is not None and np.shape(training)[0]
+                and _shape_and_type(training) != key[:2]):
+            return None  # an empty micro-batch has no fit
+        return key[:2]
+
     def _fold_ahead(self, pool, batches) -> None:
         """``train_on``'s loop under its ``stream.run`` span, whose leaves
-        tile it: ``stream.wait`` (the worker's answer and the blocks made
-        whole), then in ``stream.batch`` the fit's own and
-        ``stream.publish``; ``stream.stage`` is the worker's."""
+        tile it: ``stream.wait`` (the worker's answer: in the totals form
+        every block issued and every fold dispatched, the fit queues behind
+        the last of them on the device; in the rows form then the blocks
+        made whole), then in ``stream.batch`` the fit's own and
+        ``stream.publish``; ``stream.stage`` is the worker's.
+        ``stream.batch`` says ``totals`` 1 where its fit ran from a bundle
+        made ahead of it."""
+        alive = collections.deque()  # the worker's blocks not yet folded
+
         def take(training=None):
             began = threading.Event()
-            return began, pool.submit(_take, batches, began,
-                                      self._stages_ahead(), [training])
+            return began, pool.submit(
+                _take, batches, began, self._stages_ahead(), [training],
+                self._totals_key(training), alive)
 
         began, ahead = take()
         under = 0  # 1: this batch's copy began under its predecessor's fit
@@ -350,13 +440,20 @@ class StreamingLinearAlgorithm:
                 if taken is None:
                     return
                 X, y = taken
-                if isinstance(X, StagedAhead):
-                    X = X.whole()
+                if isinstance(X, StagedAhead) and X.totals is None:
+                    # the rows form; its blocks are folded where they lie if
+                    # the plan has come to be for them since they were taken
+                    if self._totals_key(X) is not None:
+                        X = X.fold(y)
+                        y = X.y
+                    else:
+                        X = X.whole()
             began, ahead = take(X)
             with span("stream.batch") as turn:
                 if turn.live:
                     turn.set(index=self._batch_count, rows=X.shape[0],
-                             ahead=under)
+                             ahead=under,
+                             totals=int(isinstance(X, StagedAhead)))
                 updated = self._fit(X, y)
                 under = int(began.is_set())
                 del taken, X, y  # gone before the next is made whole

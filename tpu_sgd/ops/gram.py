@@ -143,37 +143,44 @@ def _running_sum(carry0, blocks):
     return cums
 
 
+def _stats_of(X, y):
+    """``(X^T X, X^T y, y^T y)`` of the rows ``X`` and their labels, at the
+    module docstring's precision: bf16 rows take one pass with f32 sums and
+    ``y`` as three bf16 parts; any other type the stats dtype at ``HIGHEST``.
+    Two ``dot_general``s that contract the rows' axis of X as it is stored."""
+    sd = jnp.promote_types(jnp.float32, X.dtype)
+    rows = (((0,), (0,)), ((), ()))  # contract axis 0 of both
+    if X.dtype == jnp.bfloat16:
+        from tpu_sgd.ops.pallas_kernels import _parts_of
+
+        G = jax.lax.dot_general(X, X, rows, preferred_element_type=sd)
+        parts = jnp.stack(_parts_of(y, X.dtype, 3))  # (3, n)
+        b = jax.lax.dot_general(
+            parts, X, (((1,), (0,)), ((), ())),
+            preferred_element_type=sd).sum(axis=0)
+        y = y.astype(sd)
+    else:
+        Xs, y = X.astype(sd), y.astype(sd)
+        G = jax.lax.dot_general(Xs, Xs, rows, precision=_HI,
+                                preferred_element_type=sd)
+        b = jax.lax.dot_general(Xs, y, rows, precision=_HI,
+                                preferred_element_type=sd)
+    return G, b, jnp.sum(y * y)
+
+
 @jax.jit
 def _stats_build(X, y):
     """``(G, b, yy) = (X^T X, X^T y, y^T y)`` of ALL the rows, each read
-    where it lies: two ``dot_general``s that contract the rows' axis of X
-    as it is stored (at d = 1000 the chip stores X feature-major and the
-    contraction runs along the lanes: ``X.T`` is a bitcast, nothing of X's
-    size is made; ``tests/test_chip_compile.py``), static shapes, no slice
-    at a traced offset.  The precision is the module docstring's: bf16 rows
-    take one pass with f32 sums and ``y`` as three bf16 parts; any other
-    type the stats dtype at ``HIGHEST``.  The jitted function's NAME carries
-    the scope's: the persistent compile cache's key holds the one and not
-    the other (PERF.md, PR 25)."""
+    where it lies (``_stats_of``; at d = 1000 the chip stores X feature-major
+    and the contraction runs along the lanes: ``X.T`` is a bitcast, nothing
+    of X's size is made; ``tests/test_chip_compile.py``), static shapes, no
+    slice at a traced offset.  For rows that are ALREADY one array on the
+    device; a host micro-batch's totals are folded from its row blocks as
+    they land (``_stats_fold``).  The jitted function's NAME carries the
+    scope's: the persistent compile cache's key holds the one and not the
+    other (PERF.md, PR 25)."""
     with jax.named_scope("sgd.stats_build"):
-        sd = jnp.promote_types(jnp.float32, X.dtype)
-        rows = (((0,), (0,)), ((), ()))  # contract axis 0 of both
-        if X.dtype == jnp.bfloat16:
-            from tpu_sgd.ops.pallas_kernels import _parts_of
-
-            G = jax.lax.dot_general(X, X, rows, preferred_element_type=sd)
-            parts = jnp.stack(_parts_of(y, X.dtype, 3))  # (3, n)
-            b = jax.lax.dot_general(
-                parts, X, (((1,), (0,)), ((), ())),
-                preferred_element_type=sd).sum(axis=0)
-            y = y.astype(sd)
-        else:
-            Xs, y = X.astype(sd), y.astype(sd)
-            G = jax.lax.dot_general(Xs, Xs, rows, precision=_HI,
-                                    preferred_element_type=sd)
-            b = jax.lax.dot_general(Xs, y, rows, precision=_HI,
-                                    preferred_element_type=sd)
-        return G, b, jnp.sum(y * y)
+        return _stats_of(X, y)
 
 
 def stats_build(X, y) -> "GramData":
@@ -184,9 +191,56 @@ def stats_build(X, y) -> "GramData":
     dropped while they run).  The same program for every ``(X, y)`` of one
     shape and type; pass the bundle as ``X`` to an UNBOUND
     :class:`GramLeastSquaresGradient`."""
-    G, b, yy = _stats_build(X, y)
-    return GramData(None, None, None, None, G, b, yy, int(X.shape[0]),
-                    logical_shape=X.shape, logical_dtype=X.dtype)
+    return totals_bundle(_stats_build(X, y), X.shape, X.dtype)
+
+
+def totals_bundle(totals, shape, dtype) -> "GramData":
+    """``(G, b, yy)`` of a ``shape`` matrix of ``dtype`` as the totals form
+    of :class:`GramData`: no rows, no prefix stack."""
+    return GramData(None, None, None, None, *totals, int(shape[0]),
+                    logical_shape=shape, logical_dtype=dtype)
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _stats_zero(d, dtype):
+    """The totals of no rows: what ``_stats_fold``'s first call adds to."""
+    with jax.named_scope("sgd.stats_build"):
+        return (jnp.zeros((d, d), dtype), jnp.zeros((d,), dtype),
+                jnp.zeros((), dtype))
+
+
+@partial(jax.jit, donate_argnums=(0, 1, 2))
+def _stats_fold(G, b, yy, y, offset, block):
+    """The running totals (donated: added to in place) with the share of
+    ``block`` added, the rows from ``offset`` on of a matrix whose labels
+    are ``y`` (all of them, on the device: the offset is an operand, so one
+    program serves every block of one shape); and a scalar that is ready
+    when the share is in.  The block is read where it lies at
+    ``_stats_of``'s precision, to the letter ``_stats_build``'s; the sums
+    over the blocks are f32 additions in the blocks' order.  On the v5e a
+    16,384 x 1000 bf16 block's share is 0.70 ms beside the 2.29 ms of its
+    transfer: the compiler's copy of the block into fast memory 0.14 (both
+    products read it there), ``X^T X`` 0.53 (62 TFLOP/s at this depth, a
+    third of the 2M-row product's pace), ``X^T y`` 0.03; four blocks a
+    program were no faster end to end (PERF.md, PR 44).  The scope is the
+    build's, the name the fold's own."""
+    with jax.named_scope("sgd.stats_build"):
+        share = _stats_of(block, jax.lax.dynamic_slice_in_dim(
+            y, offset, block.shape[0]))
+        G, b, yy = G + share[0], b + share[1], yy + share[2]
+        return G, b, yy, G[0, 0]
+
+
+def stats_fold(totals, y, offset, block):
+    """``(totals, done)``: ``totals`` (``(G, b, yy)``, given up; None where
+    no block has been folded yet) with the share of ``block`` added by ONE
+    device program (``_stats_fold``), and the scalar that is ready when it
+    has run."""
+    if totals is None:
+        totals = _stats_zero(
+            block.shape[1], jnp.promote_types(jnp.float32, block.dtype).name)
+    *totals, done = _stats_fold(*totals, y, offset, block)
+    return tuple(totals), done
 
 
 @jax.tree_util.register_pytree_node_class

@@ -158,8 +158,10 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
     """Dense features on the device as ONE ``(N, d)`` array, and what the
     ``train.h2d`` span says of the copy: ``(X, blocks, block_bytes)``.
 
-    A device array comes back as it is (0 blocks).  A numpy array of at
-    most one block goes in one ``jnp.asarray``.  A larger one goes in row
+    A device array comes back as it is (0 blocks), and so does a
+    ``StagedAhead`` (its blocks went ahead of the fit and were folded into
+    the totals it is trained from).  A numpy array of at most one block goes
+    in one ``jnp.asarray``.  A larger one goes in row
     blocks (views: ``X[a:b]`` copies nothing on the host) issued back to
     back, so that the runtime re-tiles the next blocks while one is on the
     wire; each is written into the destination in place and deleted, and
@@ -191,7 +193,7 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
         h2d.set(stalls=0, stall_ms=0.0)
     if not isinstance(X, np.ndarray):
         h2d.set(shards=0)
-        return jnp.asarray(X), 0, 0
+        return (X if isinstance(X, StagedAhead) else jnp.asarray(X)), 0, 0
     devices = [None] if mesh is None else list(mesh.devices.flat)
     h2d.set(shards=len(devices))
     n, local = X.shape[0], -(-X.shape[0] // len(devices))
@@ -291,24 +293,52 @@ def _stage_join(*blocks):
 class StagedAhead:
     """A dense host array on its way to the device AHEAD of the fit that
     will train it, under a fit that is running (``StreamingLinearAlgorithm
-    .train_on``).  The device runs one program at a time, so
+    .train_on``), in one of two forms; ``shape``, ``dtype`` and ``nbytes``
+    are the host array's in both, ``count`` its row blocks
+    (``_stage_dense``'s: ``_block_rows``).
+
+    The ROWS form (no ``y``).  The device runs one program at a time, so
     ``_stage_dense``'s in-place writes would queue behind the running
     ``sgd_run`` and its flow control stall the host after
-    ``_STAGE_IN_FLIGHT`` blocks; here the row blocks are ``_stage_dense``'s,
-    each a device array of its own: transfers that need no device program
-    and land while the chip computes.  ``whole()`` makes them the one
-    ``(N, d)`` array once the chip is free, in ONE program (``_stage_join``:
-    13.9 ms of the host's time on the v5e, where the 128 in-place writes and
-    their destination's fill take 68: PERF.md, PR 40); the values are
-    ``_stage_dense``'s bit for bit.  The device then holds the array and,
-    until the program has run, its blocks: the batch twice for those
-    milliseconds, which is why the caller drops the batch it trained
-    first."""
+    ``_STAGE_IN_FLIGHT`` blocks; here each row block is a device array of
+    its own: transfers that need no device program and land while the chip
+    computes.  ``whole()`` makes them the one ``(N, d)`` array once the chip
+    is free, in ONE program (``_stage_join``: 13.9 ms of the host's time on
+    the v5e, where the 128 in-place writes and their destination's fill take
+    68: PERF.md, PR 40); the values are ``_stage_dense``'s bit for bit.  The
+    device then holds the array and, until the program has run, its blocks:
+    the batch twice for those milliseconds, which is why the caller drops
+    the batch it trained first.
 
-    def __init__(self, X):
+    The TOTALS form (``y`` given: a fit that runs from ``X^T X``, ``X^T y``,
+    ``y^T y`` reads no row; PERF.md, PR 44).  The rows are never one array:
+    each block's share is added to the running ``(G, b, yy)`` by a device
+    program of its own (``ops.gram.stats_fold``: 0.70 ms of the v5e's for a
+    block whose transfer takes 2.29) while the next blocks are on the wire,
+    and the block is deleted.  ``totals`` is then the bundle the fit runs
+    from (12 MB at d = 1000), ``y`` the labels on the device, ``folded`` the
+    blocks folded; the device holds 0.55 GB in all where the rows form held
+    a micro-batch twice over, 8.92 (PERF.md, PR 44).
+    The host waits for the oldest block's fold before it issues a block
+    beyond ``_STAGE_IN_FLIGHT``, counted in ``alive`` ACROSS the arrays that
+    share it: what a stalled device can collect is those blocks (0.52 GB)
+    and a bundle an array, whatever the stream does.  The same blocks, the
+    same programs in the same order for every array of one shape, so the
+    totals are the same bit for bit whichever thread issues them and
+    whenever; ``fold`` makes them of a rows form's blocks where they lie."""
+
+    def __init__(self, X, y=None, alive=None):
         rows = _block_rows(X) or X.shape[0]
-        self.nbytes, self.blocks = X.nbytes, []
-        for a in range(0, X.shape[0], rows):
+        self.shape, self.dtype, self.nbytes = X.shape, X.dtype, X.nbytes
+        self.blocks, self.totals, self.y, self.folded = None, None, None, 0
+        starts = range(0, X.shape[0], rows)
+        self.count = len(starts)
+        if y is not None:
+            self._fold((jnp.asarray(X[a:a + rows]) for a in starts), y,
+                       collections.deque() if alive is None else alive)
+            return
+        self.blocks = []
+        for a in starts:
             if len(self.blocks) >= _STAGE_IN_FLIGHT:
                 # flow control: what the runtime re-tiles at once
                 self.blocks[-_STAGE_IN_FLIGHT].block_until_ready()
@@ -323,6 +353,34 @@ class StagedAhead:
         for block in blocks:
             block.delete()
         return out
+
+    def fold(self, y):
+        """The rows form made the totals form: its blocks, already on their
+        way, folded where they lie and given up."""
+        blocks, self.blocks = self.blocks, None
+        self._fold(iter(blocks), y, collections.deque())
+        return self
+
+    def _fold(self, blocks, y, alive):
+        """``totals`` and ``y`` from ``blocks``, an iterator that ISSUES a
+        block when it is asked for the next: asked only once fewer than
+        ``_STAGE_IN_FLIGHT`` blocks are alive."""
+        from tpu_sgd.ops.gram import stats_fold, totals_bundle
+
+        y = jnp.asarray(y)
+        if not jnp.issubdtype(y.dtype, jnp.inexact):
+            y = y.astype(jnp.float32)
+        totals, at = None, 0
+        for block in blocks:
+            totals, done = stats_fold(totals, y, at, block)
+            at, dtype = at + block.shape[0], block.dtype
+            block.delete()
+            alive.append(done)
+            if len(alive) >= _STAGE_IN_FLIGHT:
+                # flow control, not a fetch: bounds what a device holds
+                alive.popleft().block_until_ready()
+        self.y, self.folded = y, self.count
+        self.totals = totals_bundle(totals, self.shape, dtype)
 
 
 def _sample_key(key, i, axis_name, shard_index=None):
@@ -1944,6 +2002,12 @@ class GradientDescent(Optimizer):
         gram = None if isinstance(X, GramData) \
             else self._maybe_gram(X, y, sparse_X)
         if gram is None:
+            if isinstance(X, StagedAhead):
+                raise RuntimeError(
+                    "a micro-batch folded into its totals ahead of its fit "
+                    "holds no rows, and this optimizer no longer trains "
+                    "from totals (its schedule, gradient or fraction was "
+                    "changed between the fold and the fit)")
             yield X
             return
         orig, (self.gradient, data) = self.gradient, gram
@@ -2240,6 +2304,25 @@ class GradientDescent(Optimizer):
         (``ops.gram.stats_build``); sliced windows take the prefix form."""
         return self.config.mini_batch_fraction >= 1.0
 
+    def fits_from_totals(self) -> bool:
+        """Whether a dense matrix on this optimizer's one device is trained
+        from the totals of its rows: ``_maybe_gram``'s substitution in its
+        totals form.  What lets a stream fold a host micro-batch's row
+        blocks into ``(G, b, yy)`` as they land and keep no row
+        (``StagedAhead``)."""
+        return (self.sufficient_stats and self.mesh is None
+                and not self.host_streaming and not self.streamed_stats
+                and type(self.gradient) is LeastSquaresGradient
+                and self.stats_in_totals())
+
+    def _totals_executor(self):
+        """The optimizer's ONE unbound executor of the totals form."""
+        from tpu_sgd.ops.gram import GramLeastSquaresGradient
+
+        if self._totals_gradient is None:
+            self._totals_gradient = GramLeastSquaresGradient()
+        return self._totals_gradient
+
     @staticmethod
     def _stats_span(X, y):
         """``train.stats``: the host's time in a statistics build (the
@@ -2260,7 +2343,9 @@ class GradientDescent(Optimizer):
         program are the same for every dataset of one shape (a stream's
         micro-batches).  Sliced windows take the prefix form,
         identity-cached so that repeated ``optimize`` calls on the same
-        arrays build once.  Either build runs under ``train.stats``."""
+        arrays build once.  Either build runs under ``train.stats``.  A
+        ``StagedAhead`` brings the totals of its rows with it (folded from
+        its blocks under their copy) and nothing is built."""
         from tpu_sgd.ops.gradients import LeastSquaresGradient as _LS
         from tpu_sgd.ops.gram import GramLeastSquaresGradient, stats_build
 
@@ -2269,6 +2354,11 @@ class GradientDescent(Optimizer):
                 or (cfg.mini_batch_fraction < 1.0
                     and cfg.sampling != "sliced")):
             return None
+        if isinstance(X, StagedAhead):
+            # a stream's micro-batch whose totals were folded from its row
+            # blocks as they landed: nothing is built in this fit
+            return (self._totals_executor(), X.totals) \
+                if self.fits_from_totals() else None
         if (isinstance(self.gradient, GramLeastSquaresGradient)
                 and self.gradient.data is not None
                 and self.gradient.data.X is X):
@@ -2278,10 +2368,8 @@ class GradientDescent(Optimizer):
         if not self.sufficient_stats or type(self.gradient) is not _LS:
             return None
         if self.stats_in_totals():
-            if self._totals_gradient is None:
-                self._totals_gradient = GramLeastSquaresGradient()
             with self._stats_span(X, y):
-                return self._totals_gradient, stats_build(X, y)
+                return self._totals_executor(), stats_build(X, y)
         entry = self._gram_entry
         opts = (self.gram_block_rows, self.gram_aligned)
         if (entry is not None and entry[0] is X and entry[1] is y
