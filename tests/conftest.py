@@ -6,8 +6,10 @@ SURVEY.md §4: the reference tests multi-worker behavior with local threads
 before jax initializes its backends.
 """
 
+import itertools
 import os
 import sys
+import threading
 
 # the package is not pip-installed: make the repo root importable so the
 # suite runs under the bare `pytest` console script too, not only
@@ -30,3 +32,34 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+class SteppedClock:
+    """A module's ``time`` whose ``perf_counter`` advances one second a
+    reading and keeps each thread's readings."""
+
+    def __init__(self):
+        self.ticks, self.read_by = itertools.count(), {}
+
+    def perf_counter(self):
+        tick = float(next(self.ticks))
+        self.read_by.setdefault(threading.get_ident(), []).append(tick)
+        return tick
+
+
+class NoClock:
+    """A module's ``time`` for code that may read no clock."""
+
+    @staticmethod
+    def perf_counter():
+        raise AssertionError("an untraced hand-off read the clock")
+
+
+@pytest.fixture
+def stepped_clock():
+    return SteppedClock()
+
+
+@pytest.fixture
+def no_clock():
+    return NoClock

@@ -247,7 +247,9 @@ def test_train_h2d_carries_the_stall_counter(monkeypatch, sink, data):
     assert clock.calls == 0
     _small_blocks(monkeypatch, in_flight=1)
     _opt().optimize_with_history((X, y), w0)
-    assert clock.calls == 2 * 3  # four blocks, one in flight: three waits
+    # four blocks, one in flight: two readings a wait (three waits) and,
+    # since PR 46, four a block and the loop's first and last
+    assert clock.calls == 2 * 3 + 4 * 4 + 2
     _opt().optimize_with_history((jnp.asarray(X), jnp.asarray(y)), w0)
     one, many, device = sink.named("train.h2d")
     assert (many["blocks"], many["stalls"]) == (4, 3)

@@ -212,6 +212,115 @@ def test_train_h2d_says_how_many_pieces_went(monkeypatch):
         assert (span["stalls"], span["stall_ms"]) == (0, 0)
 
 
+CALLS = ("put_ms", "write_ms", "free_ms", "own_ms", "stall_ms")
+
+
+def _traced_fit(X, clock=None, monkeypatch=None):
+    """The ``train.h2d`` record of one fit of ``X`` with tracing on."""
+    y = X @ np.arange(X.shape[1], dtype=np.float32)
+    sink = Sink()
+    enable_tracing(sink)
+    try:
+        if clock is not None:
+            monkeypatch.setattr(gd, "time", clock)
+        _opt().optimize_with_history((X, y), np.zeros(X.shape[1], np.float32))
+    finally:
+        disable_tracing()
+    return sink.h2d()[0]
+
+
+def test_train_h2d_says_where_the_issuing_threads_time_went(monkeypatch):
+    """PR 46: a live span carries the four parts of a block's issue beside
+    the wait."""
+    X = _host(5 * ROWS + 9, 8, np.float32)
+    _blocks_of(monkeypatch, 32)
+    many = _traced_fit(X)
+    assert many["blocks"] == 6 and many["stalls"] == 6 - IN_FLIGHT
+    for name in CALLS:
+        assert isinstance(many[name], float) and many[name] >= 0, name
+    assert many["put_ms"] > 0 and many["write_ms"] > 0
+    # one piece and a device array time no call
+    monkeypatch.undo()
+    for record in (_traced_fit(X), _traced_fit(jnp.asarray(X))):
+        assert not set(CALLS[:4]) & set(record)
+        assert (record["stalls"], record["stall_ms"]) == (0, 0)
+
+
+def test_the_five_parts_are_the_threads_time_in_the_loop(monkeypatch,
+                                                         stepped_clock):
+    """On a clock that advances a second a reading: a put, a write's
+    dispatch, a delete and a wait are each one step long, the loop's own
+    time is the steps between them, and the five add up to the first
+    reading in ``send`` less the last."""
+    X = _host(5 * ROWS + 9, 8, np.float32)
+    _blocks_of(monkeypatch, 32)
+    clock = stepped_clock
+    record = _traced_fit(X, clock, monkeypatch)
+    readings, = clock.read_by.values()  # the fit's thread alone
+    blocks, stalls = 6, 6 - IN_FLIGHT
+    # in and out, four readings a block, two a wait
+    assert len(readings) == 2 + 4 * blocks + 2 * stalls
+    assert [record[n] for n in ("put_ms", "write_ms", "free_ms",
+                                "stall_ms")] == [
+        blocks * 1e3, blocks * 1e3, blocks * 1e3, stalls * 1e3]
+    assert sum(record[n] for n in CALLS) == pytest.approx(
+        (readings[-1] - readings[0]) * 1e3)
+    assert record["own_ms"] == (len(readings) - 1 - 3 * blocks - stalls) * 1e3
+
+
+def test_an_untraced_hand_off_reads_no_clock_and_makes_the_same_calls(
+        monkeypatch, no_clock):
+    """With ``NO_SPAN`` a clock that raises is never read, and the puts,
+    the fill, the writes and the deletes come in the order a traced
+    hand-off makes them in."""
+    X = _host(4 * ROWS + 9, 8, np.float32)
+    _blocks_of(monkeypatch, 32)
+    calls = []
+    puts, fills, writes = jnp.asarray, gd._stage_dest, gd._stage_block
+
+    class Block:
+        def __init__(self, array):
+            self.array, self.dtype = array, array.dtype
+
+        def delete(self):
+            calls.append("delete")
+            self.array.delete()
+
+    class Jnp:
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def asarray(piece):
+            calls.append(("put", piece.shape[0]))
+            return Block(puts(piece))
+
+    monkeypatch.setattr(gd, "jnp", Jnp())
+    monkeypatch.setattr(gd, "_stage_dest",
+                        lambda *a: calls.append("fill") or fills(*a))
+    monkeypatch.setattr(
+        gd, "_stage_block", lambda dest, block, offset: calls.append(
+            ("write", offset)) or writes(dest, block.array, offset))
+    sink = Sink()
+    enable_tracing(sink)
+    try:
+        with span("train.h2d") as h2d:
+            traced, _, _ = gd._stage_dense(X, h2d)
+    finally:
+        disable_tracing()
+    assert sink.h2d()[0]["put_ms"] > 0
+    as_traced, calls[:] = list(calls), []
+    monkeypatch.setattr(gd, "time", no_clock)
+    got, blocks, _ = gd._stage_dense(X, NO_SPAN)
+    assert blocks == 5
+    assert calls == as_traced == [("put", ROWS), "fill", ("write", 0),
+                                  "delete"] + [
+        step for k in range(1, 5) for step in (
+            ("put", ROWS if k < 4 else 9), ("write", k * ROWS), "delete")]
+    np.testing.assert_array_equal(np.asarray(got), X)
+    np.testing.assert_array_equal(np.asarray(traced), X)
+
+
 # -- the fit ----------------------------------------------------------------------
 
 @pytest.mark.parametrize("model,dtype", [

@@ -28,6 +28,8 @@ from tpu_sgd.parallel import shard_dataset
 
 ROWS, IN_FLIGHT, SHARDS = gd._STAGE_ROWS, 2, 4
 
+CALLS = ("put_ms", "write_ms", "free_ms", "own_ms", "stall_ms")
+
 
 @pytest.fixture(scope="module")
 def mesh():
@@ -84,10 +86,12 @@ ROW_CASES = {"under": SHARDS * (ROWS - 1), "one_block": SHARDS * ROWS,
 
 @pytest.mark.parametrize("d", [1000, 7])
 @pytest.mark.parametrize("case", sorted(ROW_CASES))
-def test_the_sharded_array_is_device_puts(monkeypatch, mesh, case, d):
+def test_the_sharded_array_is_device_puts(monkeypatch, mesh, no_clock, case,
+                                          d):
     n = ROW_CASES[case]
     X = _host(n, d, np.float32)
     _blocks_of(monkeypatch, X.strides[0])
+    monkeypatch.setattr(gd, "time", no_clock)  # no span: no clock is read
     got, blocks, block_bytes = gd._stage_dense(X, mesh=mesh)
     _same(got, X, mesh)
     local = -(-n // SHARDS)
@@ -180,7 +184,8 @@ def test_no_device_holds_more_than_its_shard_and_the_blocks_in_flight(
     assert threading.get_ident() not in {t for _, t in threads}
 
 
-def test_without_a_mesh_the_calls_are_the_calls_it_made(monkeypatch):
+def test_without_a_mesh_the_calls_are_the_calls_it_made(monkeypatch,
+                                                        no_clock):
     """One destination on the default device, ``jnp.asarray`` of each block,
     one fill and one write a block: nothing of the sharded road."""
     X = _host(3 * ROWS + 9, 8, np.float32)
@@ -192,6 +197,7 @@ def test_without_a_mesh_the_calls_are_the_calls_it_made(monkeypatch):
             jax, name, lambda *a, _n=name, **k: calls.append(_n) or 1 / 0)
     monkeypatch.setattr(gd, "ThreadPoolExecutor",
                         lambda *a: calls.append("threads") or 1 / 0)
+    monkeypatch.setattr(gd, "time", no_clock)  # no span: no clock is read
     fills, writes = gd._stage_dest, gd._stage_block
     monkeypatch.setattr(gd, "_stage_dest",
                         lambda *a: calls.append("fill") or fills(*a))
@@ -288,6 +294,71 @@ def test_train_h2d_says_the_shards_and_train_place_moves_nothing(
             1, 0, SHARDS)
     assert (places[3]["in_place"], places[3]["bytes"]) == (
         0, X.nbytes + y.nbytes)
+
+
+def _traced_hand_off(mesh, X, shards=SHARDS):
+    """``shard_dataset``'s host branch under a live ``train.h2d``: the
+    span's record."""
+    from tpu_sgd.obs.spans import span
+
+    sink = Sink()
+    enable_tracing(sink)
+    try:
+        with span("train.h2d") as h2d:
+            Xd, _, _ = shard_dataset(mesh, X, np.zeros(len(X), np.float32),
+                                     h2d)
+    finally:
+        disable_tracing()
+    if shards == SHARDS:
+        _same(Xd, X, mesh)
+    else:
+        np.testing.assert_array_equal(np.asarray(Xd), X)
+    record, = sink.spans("train.h2d")
+    return record
+
+
+def test_train_h2d_says_where_the_four_threads_time_went(monkeypatch, mesh):
+    """PR 46: the five sums are over the devices' threads."""
+    X = _host(SHARDS * (5 * ROWS + 9), 8, np.float32)
+    _blocks_of(monkeypatch, 32)
+    record = _traced_hand_off(mesh, X)
+    assert (record["shards"], record["blocks"]) == (SHARDS, SHARDS * 6)
+    assert record["stalls"] == SHARDS * (6 - IN_FLIGHT)
+    for name in CALLS:
+        assert isinstance(record[name], float) and record[name] >= 0, name
+    assert record["put_ms"] > 0 and record["write_ms"] > 0
+    # a mesh of ONE device: one thread
+    one = _traced_hand_off(tpu_sgd.data_mesh(jax.devices()[:1]),
+                           X[:5 * ROWS + 9], shards=1)
+    assert (one["shards"], one["blocks"]) == (1, 6)
+    assert one["put_ms"] > 0
+
+
+def test_the_five_parts_are_the_four_threads_time_in_their_loops(
+        monkeypatch, mesh, stepped_clock):
+    """On a clock that advances a second a reading, whichever thread reads
+    it: each thread's five parts are its last reading less its first, so the
+    span's sums are the threads' time in ``send``."""
+    X = _host(SHARDS * (5 * ROWS + 9), 8, np.float32)
+    _blocks_of(monkeypatch, 32)
+    clock = stepped_clock
+    monkeypatch.setattr(gd, "time", clock)
+    record = _traced_hand_off(mesh, X)
+    assert threading.get_ident() not in clock.read_by
+    blocks, stalls = 6, 6 - IN_FLIGHT
+    # in and out, four readings a block, two a wait: a device's loop; a
+    # pool's thread that came back early may have run two of them in turn
+    a_loop = 2 + 4 * blocks + 2 * stalls
+    loops = [readings[i:i + a_loop] for readings in clock.read_by.values()
+             for i in range(0, len(readings), a_loop)]
+    assert [len(loop) for loop in loops] == [a_loop] * SHARDS
+    in_send = sum(loop[-1] - loop[0] for loop in loops)
+    assert sum(record[n] for n in CALLS) == pytest.approx(in_send * 1e3)
+    # a part is at least its readings' one step each, more where another
+    # thread read the clock in between
+    for name, least in (("put_ms", blocks), ("write_ms", blocks),
+                        ("free_ms", blocks), ("stall_ms", stalls)):
+        assert record[name] >= SHARDS * least * 1e3, name
 
 
 # -- the fit ----------------------------------------------------------------------

@@ -112,7 +112,22 @@ def _coerce_w0(gradient, initial_weights, n_features):
 #: in 0.96 to 1.07 s from one process to the next (1.57 to 1.75 ms each, 14
 #: to 18 ms of it in the wait), 1.4 wires' worth; a thread a device, as it
 #: is written, lands them in 0.82 to 0.86 s (23.3 to 24.4 GB/s), each
-#: thread 4.9 ms in its calls a block: the four contend (PERF.md section 6).
+#: thread 4.9 ms in its calls a block: the four contend, and what for is the
+#: runtime's re-tiling of the blocks into the chip's layout (PR 46, the
+#: span's ``put_ms`` / ``write_ms`` / ``free_ms`` / ``own_ms`` and the
+#: runtime's own host events).  A put returns in 0.4 ms; the copy of a
+#: strided block into ``{0,1:T(8,128)(2,1)}`` runs behind it on the runtime's
+#: threads (``XlaLinearize``, cut into ``Transpose::ExecuteChunk`` pieces on a
+#: pool), 6.4 ms of CPU a block for one device and 18.5 where four devices'
+#: blocks are in it at once, 13.7 of the host's 30 CPUs: 24 GB/s is what that
+#: pool gives, for 2 devices as for 4, for C-ordered rows as for
+#: Fortran-ordered.  The issuing threads are held up by it wherever they next
+#: enter the runtime (the write's dispatch 3.3 ms a block for 1.1, a put 1.5
+#: for 0.4; with the write's offset kept on the device the write shrinks and
+#: the put grows by as much), not by the interpreter's lock (the delete and
+#: the loop's own statements stay at 0.03 and 0.02 ms a block).  The same
+#: bytes as FLAT 1-D blocks, which the runtime copies and does not re-tile,
+#: land at 35 GB/s (PERF.md section 7, #1(d)).
 _STAGE_BLOCK_BYTES = 32 << 20
 _STAGE_IN_FLIGHT = 16
 _STAGE_ROWS = 1024
@@ -185,10 +200,23 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
     device array).  Where it is ``live`` it is given the hand-off's stall
     counter too: ``stalls``, the times the host stood in that flow-control
     wait, and ``stall_ms``, how long in all, over the devices (0 and 0 for
-    one piece or a device array).  Otherwise the loop reads no clock."""
+    one piece or a device array).  An array that goes in blocks then says
+    where its issuing threads' time went, each sum over the devices as
+    ``stall_ms`` is, each on the issuing thread's own clock: ``put_ms`` (the
+    row block's ``jnp.asarray`` / ``jax.device_put``: the block's buffer
+    and its hand-over to the runtime, whose threads copy it into the chip's
+    layout and send it behind the call), ``write_ms`` (the dispatch of
+    ``_stage_block`` and, once a device, of the destination's fill),
+    ``free_ms`` (``block.delete()``) and ``own_ms`` (what is left of a
+    thread's time in ``send``: the slice, the deque, the loop: the
+    interpreter alone), so that ``put + write + free + own + stall`` IS the
+    threads' time in ``send``.  ``free_ms`` and ``own_ms`` are calls of
+    microseconds: a millisecond in them is a thread waiting to be let back
+    into the interpreter.  Otherwise the loop reads no clock."""
     import numpy as np
 
     timed = h2d.live
+    now = time.perf_counter if timed else _no_clock
     if timed:
         h2d.set(stalls=0, stall_ms=0.0)
     if not isinstance(X, np.ndarray):
@@ -208,31 +236,43 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
 
     def send(s):
         """Destination ``s`` written from its rows of ``X``: the array, its
-        blocks, and how often and how long its flow control stood."""
+        blocks, how often its flow control stood, and the thread's seconds
+        in the wait, in the puts, in the writes' dispatch, in the deletes
+        and beside them."""
         device, first = devices[s], s * local
         end = min(first + local, n)  # behind it the fill's zero rows
         dest, writes = None, collections.deque()
-        blocks, stalls, stall_s = 0, 0, 0.0
+        blocks, stalls = 0, 0
+        stall_s = put_s = write_s = free_s = 0.0
+        entered = now()
         for lo in range(first, end, rows):
             if len(writes) == _STAGE_IN_FLIGHT:
                 # flow control, not a fetch: bounds what a device holds
-                t = time.perf_counter() if timed else 0.0
+                t = now()
                 writes.popleft().block_until_ready()
                 if timed:
-                    stall_s += time.perf_counter() - t
+                    stall_s += now() - t
                     stalls += 1
             piece = X[lo:min(lo + rows, end)]
+            t0 = now()
             block = (jnp.asarray(piece) if device is None
                      else jax.device_put(piece, device))
+            t1 = now()
             if dest is None:
                 with (contextlib.nullcontext() if device is None
                       else jax.default_device(device)):
                     dest = _stage_dest((local,) + X.shape[1:], block.dtype)
             dest, written = _stage_block(dest, block, lo - first)
+            t2 = now()
             block.delete()
+            if timed:
+                put_s += t1 - t0
+                write_s += t2 - t1
+                free_s += now() - t2
             writes.append(written)
             blocks += 1
-        return dest, blocks, stalls, stall_s
+        own_s = now() - entered - stall_s - put_s - write_s - free_s
+        return dest, blocks, stalls, (stall_s, put_s, write_s, free_s, own_s)
 
     if mesh is None:
         sent = [send(0)]
@@ -242,15 +282,23 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
         # process to the next
         with ThreadPoolExecutor(len(devices)) as pool:
             sent = list(pool.map(send, range(len(devices))))
-    dests, blocks, stalls, stall_s = zip(*sent)
+    dests, blocks, stalls, spent = zip(*sent)
     if timed:
-        h2d.set(stalls=sum(stalls), stall_ms=round(sum(stall_s) * 1e3, 4))
+        stall, put, write, free, own = (
+            round(sum(part) * 1e3, 4) for part in zip(*spent))
+        h2d.set(stalls=sum(stalls), stall_ms=stall, put_ms=put,
+                write_ms=write, free_ms=free, own_ms=own)
     block_bytes = rows * (X.nbytes // n)
     if mesh is None:
         return dests[0], blocks[0], block_bytes
     # every shard holds rows of X: the zero rows are fewer than the shards,
     # and a shard in blocks has more rows than that
     return _sharded_by_rows(mesh, list(dests)), sum(blocks), block_bytes
+
+
+def _no_clock() -> float:
+    """``_stage_dense``'s clock where no span is live: no clock is read."""
+    return 0.0
 
 
 def _rows_padded(X, lo, m):
