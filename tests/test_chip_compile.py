@@ -1220,7 +1220,9 @@ def test_the_wide_kernels_vmem_count_admits_what_the_compiler_admits(S, case):
     n, d = 6144, RCV1_DENSE[1]  # whole tiles of 128, 256 and 384 rows
     itemsize = jnp.dtype(dtype).itemsize
     limit = PK._FM_WIDE_VMEM_LIMIT
-    own, fblock = PK.fm_wide(n, d, itemsize, masked)
+    wide = PK.one_read(n, d, itemsize, masked)
+    assert (wide.body, wide.vmem_limit) == ("wide", limit)
+    own, fblock = wide.tile, wide.fblock
     assert (own, fblock) == PK._fm_wide_plan(n, d, itemsize, masked, limit)
     _, rows = PK.wide_rows_of(dtype)
     X = S((n, d), dtype)
@@ -1280,14 +1282,15 @@ def test_the_class_kernels_vmem_count_admits_what_the_compiler_admits(
     import re
 
     from tpu_sgd.ops.pallas_kernels import (_check_fm_vmem, class_rows_of,
-                                            fm_tile)
+                                            one_read)
 
     dtype, classes, masked = CLASS_CASES[case]
     d = MNIST8M[1]
     rows = class_rows_of(classes - 1, dtype)
     X = S((KERNEL_N, d), dtype)
-    own = fm_tile(KERNEL_N, d, jnp.dtype(dtype).itemsize, masked, rows)
-    assert own is not None
+    own = one_read(KERNEL_N, d, jnp.dtype(dtype).itemsize, masked, rows)
+    assert own is not None and (own.body, own.by_rows) == ("class", False)
+    own = own.tile
     assert "tpu_custom_call" in _lower_classes(
         S, dtype, classes, masked, own).compile().as_text()
     with pytest.raises(ValueError, match=r"tile_m <= \d+") as refused:
@@ -1404,8 +1407,9 @@ def test_the_by_rows_vmem_count_admits_what_the_compiler_admits(S, case):
     itemsize = jnp.dtype(dtype).itemsize
     rows = (PK.class_rows_of(classes - 1, dtype) if classes
             else PK.wide_rows_of(dtype)[1])
-    own = PK.fm_tile(n, d, itemsize, masked, rows if classes else 0)
-    assert own is not None and PK.by_rows_form(n, d)
+    own = PK.one_read(n, d, itemsize, masked, rows if classes else 0)
+    assert own is not None and own.by_rows and PK.by_rows_form(n, d)
+    own = own.tile
     counted = PK._fm_vmem_bytes(own, d, itemsize, masked, rows, by_rows=True)
     assert counted <= PK._FM_VMEM_LIMIT
     assert "tpu_custom_call" in _lower_rows(

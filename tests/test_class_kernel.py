@@ -10,7 +10,7 @@ import pytest
 
 from tpu_sgd.ops.gradients import (LogisticGradient,
                                    MultinomialLogisticGradient,
-                                   one_read_sums)
+                                   one_read_of)
 from tpu_sgd.ops.pallas_kernels import class_rows_of, fused_class_sums
 
 
@@ -191,10 +191,13 @@ OFF = ["a_vector_of_d_weights", "more_rows_than_one_pass",
 
 
 @pytest.mark.parametrize("case", ON + OFF)
-def test_one_read_sums_says_what_it_admits_of_a_class_count(case):
+def test_one_read_of_says_what_it_admits_of_a_class_count(case):
     X, y, w, mask, K = _case(case)
-    assert one_read_sums(X, y, w, mask, classes=K) == (case in ON)
-    assert one_read_sums(X, y, w, None, classes=K) == (case in ON)
+    for m in (mask, None):
+        own = one_read_of(X, y, w, m, classes=K)
+        assert (own is not None) == (case in ON)
+        assert own is None or (own.body, own.scope, own.draws) == (
+            "class", "sgd.class_sums", False)
 
 
 def _lowered_for(platform, fn, *args):

@@ -115,26 +115,32 @@ def test_interpreter_poisons_what_lies_past_the_end():
 
 
 def test_feature_major_tile_choice():
-    """``fm_tile`` halves until the block fits the kernel's VMEM, never
+    """``one_read``'s tile halves until the block fits the kernel's VMEM, never
     passes the rows there are, takes the wide form's tile where the
     ``(d, 128)`` operands do not fit (RCV1's width) and gives up on a
     width no lane group fits in either form; an explicit tile is floored
     to whole lane groups."""
     from tpu_sgd.ops import pallas_kernels as PK
 
-    assert PK.fm_tile(4_194_304, 1000, 2) == PK.FM_TILE
-    assert PK.fm_tile(2_145_000, 1000, 4) == PK.FM_TILE  # f32: 17.4 MB
-    assert PK.fm_tile(1 << 20, 4000, 4) == PK.FM_TILE // 4
-    assert PK.fm_tile(300, 24, 4) == 384  # one block over all 300 rows
+    def tile(*shape, **kw):
+        own = PK.one_read(*shape, **kw)
+        return own and own.tile
+
+    assert tile(4_194_304, 1000, 2) == PK.FM_TILE
+    assert tile(2_145_000, 1000, 4) == PK.FM_TILE  # f32: 17.4 MB
+    assert tile(1 << 20, 4000, 4) == PK.FM_TILE // 4
+    assert tile(300, 24, 4) == 384  # one block over all 300 rows
     # RCV1's width: 96.7 MB of (d, 128) f32 operands, so the wide form:
     # 256 rows a grid step (two blocks of 24.2 MB), the width in 8 blocks
-    assert PK.fm_tile(1 << 16, 47_236, 2) == 256
-    assert PK.fm_blocks(131_072, 47_236, 2, False) == (256, 8)
-    assert PK.fm_wide(131_072, 47_236, 2, False) == (256, 6400)
-    assert PK.fm_wide(4_194_304, 1000, 2) is None  # _fm_kernel takes it
-    assert PK.fm_blocks(4_194_304, 1000, 2) == (PK.FM_TILE, 1)
-    assert PK.fm_tile(1 << 16, 400_004, 2) is None  # 128 lanes: 102 MB x 2
-    assert PK.fm_tile(1 << 16, 47_236, 2, class_rows=16) is None
+    assert tile(1 << 16, 47_236, 2) == 256
+    wide = PK.one_read(131_072, 47_236, 2, False)
+    assert (wide.tile, wide.feature_blocks) == (256, 8)
+    assert (wide.body, wide.tile, wide.fblock) == ("wide", 256, 6400)
+    narrow = PK.one_read(4_194_304, 1000, 2)
+    assert narrow.body == "scan"  # _fm_kernel takes it
+    assert (narrow.tile, narrow.feature_blocks) == (PK.FM_TILE, 1)
+    assert tile(1 << 16, 400_004, 2) is None  # 128 lanes: 102 MB x 2
+    assert tile(1 << 16, 47_236, 2, class_rows=16) is None
     assert PK._fm_round(200, 10_000) == 128
     assert PK._fm_round(64, 10_000) == 128
     assert [PK._fm_lane_chunk(t) for t in (128, 384, 1920, 2048, 4096)] \
@@ -257,7 +263,7 @@ def test_batch_sums_lowers_the_wide_kernel_for_a_tpu_at_rcv1s_width(
 
 @pytest.mark.parametrize("case", ["full_batch", "bernoulli", "sliced",
                                   "indexed", "narrow"])
-def test_step_blocks_names_the_kernels_row_tile_and_feature_blocks(case):
+def test_step_sums_names_the_kernels_row_tile_and_feature_blocks(case):
     """``train.run``'s ``row_tile`` and ``feature_blocks``, from shapes
     alone: RCV1's width under a full batch or a drawn mask is the wide
     form (256 rows a grid step, the width in 8 blocks); the window's
@@ -267,7 +273,7 @@ def test_step_blocks_names_the_kernels_row_tile_and_feature_blocks(case):
     import jax.numpy as jnp
 
     from tpu_sgd.config import SGDConfig
-    from tpu_sgd.optimize import gradient_descent as gd
+    from tpu_sgd.ops.gradients import step_sums
 
     n, d = (131_072, 47_236) if case != "narrow" else (4_194_304, 1000)
     X = jax.ShapeDtypeStruct((n, d), jnp.bfloat16)
@@ -276,11 +282,10 @@ def test_step_blocks_names_the_kernels_row_tile_and_feature_blocks(case):
     cfg = SGDConfig(
         mini_batch_fraction=1.0 if case == "full_batch" else 0.1,
         sampling=case if case in ("sliced", "indexed") else "bernoulli")
-    assert gd.step_blocks(HingeGradient(), cfg, X, y, w) == {
-        "full_batch": (256, 8), "bernoulli": (256, 8), "sliced": (0, 1),
-        "indexed": (0, 1), "narrow": (2048, 1)}[case]
-    assert gd.rows_prepared(HingeGradient(), cfg, X, y, w) == (
-        case in ("full_batch", "bernoulli", "narrow"))
+    kernel = step_sums(HingeGradient(), cfg, X, y, w).kernel
+    assert (kernel and (kernel.tile, kernel.feature_blocks)) == {
+        "full_batch": (256, 8), "bernoulli": (256, 8), "sliced": None,
+        "indexed": None, "narrow": (2048, 1)}[case]
 
 
 # -- the kernel over a window of rows -----------------------------------------
@@ -418,17 +423,18 @@ OFF = ["bcoo", "matrix_weights", "margin_axis_name", "integer_rows",
 
 @pytest.mark.parametrize("case", ["feature_major", "wide",
                                   "row_major_width"] + OFF)
-def test_one_read_sums_follows_what_the_operands_look_like(case):
-    from tpu_sgd.ops.gradients import one_read_blocks, one_read_sums
+def test_one_read_of_follows_what_the_operands_look_like(case):
+    from tpu_sgd.ops.gradients import one_read_of
 
     X, y, w, mask, axis = _selection_case(case)
     on = case in ("feature_major", "wide", "row_major_width")
-    assert one_read_sums(X, y, w, mask, axis) == on
-    assert one_read_sums(X, y, w, None, axis) == on
+    assert (one_read_of(X, y, w, mask, axis) is not None) == on
+    assert (one_read_of(X, y, w, None, axis) is not None) == on
     # the window's kernel has no wide form and no by-rows form
-    assert one_read_sums(X, y, w, None, axis, window=True) == (
-        case == "feature_major")
-    assert one_read_blocks(X, y, w, mask, axis) == {
+    assert (one_read_of(X, y, w, None, axis, window=X.shape[0] // 10)
+            is not None) == (case == "feature_major")
+    own = one_read_of(X, y, w, mask, axis)
+    assert (own and (own.tile, own.feature_blocks)) == {
         "feature_major": (1024, 1), "wide": (256, 8),
         "row_major_width": (1024, 1)}.get(case)
 
@@ -745,12 +751,13 @@ def test_a_fit_with_the_rows_prepared_is_the_fit_without_bit_for_bit(
     they on the two-read path this CPU takes, where the row rides unread."""
     import jax
 
+    from tpu_sgd.ops.gradients import step_sums
     from tpu_sgd.ops.updaters import SquaredL2Updater
     from tpu_sgd.optimize import gradient_descent as gd
 
     g, cfg, X, y, w0, valid = _run_case(case)
     n = X.shape[0]
-    assert gd.rows_prepared(g, cfg, X, y, w0, valid)
+    assert step_sums(g, cfg, X, y, w0, valid).kernel is not None
 
     def fit(prepared):
         with monkeypatch.context() as m:
@@ -777,16 +784,16 @@ def test_a_fit_with_the_rows_prepared_is_the_fit_without_bit_for_bit(
 
 @pytest.mark.parametrize("case", ["indexed", "bcoo", "row_major_width",
                                   "feature_sharded", "statistics",
-                                  "chunked_window", "classes_sliced"])
-def test_rows_prepared_is_false_where_no_kernel_reads_them(case):
+                                  "classes_sliced"])
+def test_no_rows_are_prepared_where_no_kernel_reads_them(case):
     """Where the step's sums are not the one-read kernel the step takes
     ``y`` as it is: ``prepare_rows`` makes nothing and ``labels_prepared``
     reads 0."""
     import jax.numpy as jnp
 
     from tpu_sgd.config import SGDConfig
-    from tpu_sgd.ops.gradients import (ChunkedGradient,
-                                       MultinomialLogisticGradient)
+    from tpu_sgd.ops.gradients import (MultinomialLogisticGradient,
+                                       step_sums)
     from tpu_sgd.ops.gram import GramLeastSquaresGradient
     from tpu_sgd.optimize import gradient_descent as gd
 
@@ -805,17 +812,12 @@ def test_rows_prepared_is_false_where_no_kernel_reads_them(case):
         axis = "model"
     elif case == "statistics":
         g = GramLeastSquaresGradient()
-    elif case == "chunked_window":
-        g, kw["sampling"] = ChunkedGradient(g, 256), "sliced"
     else:
         g, kw["sampling"] = MultinomialLogisticGradient(3), "sliced"
         w = jnp.zeros(2 * d)
     cfg = SGDConfig(**kw)
-    assert not gd.rows_prepared(g, cfg, X, y, w, None, axis)
+    assert step_sums(g, cfg, X, y, w, None, axis).kernel is None
     assert gd.prepare_rows(g, cfg, X, y, w, None, axis) is None
-    if case == "chunked_window":  # its batch sums are the base's: prepared
-        assert gd.rows_prepared(g, SGDConfig(mini_batch_fraction=0.1),
-                                X, y, w)
 
 
 @pytest.mark.parametrize("backend", ["cpu", "tpu"])

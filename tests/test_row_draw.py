@@ -5,7 +5,7 @@ index (``ops/pallas_kernels._row_draw``); these tests hold it to
 ``jax.random.bernoulli`` row for row (the kernel in interpret mode), the
 sums and a whole fit to the same fit handed the materialised mask bit for
 bit, the shard fold to the reference's, and the selection
-(``Gradient.draws_rows``, ``train.run``'s ``mask_in_kernel``) to what the
+(``step_sums``' ``mask_in_kernel``, ``train.run``'s ``mask_in_kernel``) to what the
 code can observe."""
 
 import functools
@@ -215,22 +215,23 @@ def test_a_fit_that_draws_in_the_kernel_is_the_fit_handed_the_mask(
         case, monkeypatch):
     """``make_run``'s fit on the path a TPU takes, the kernel in the
     interpreter: with the draw in the kernel and with ``_make_mask``'s
-    array handed to the same kernel (``draws_rows`` saying no), weights,
+    array handed to the same kernel (``counter_draws`` saying no), weights,
     loss history and count are the same bits; and the two-read fit this
     CPU takes, which draws the array, agrees to rounding."""
     import jax
 
+    from tpu_sgd.ops import gradients
     from tpu_sgd.ops.updaters import SquaredL2Updater
     from tpu_sgd.optimize import gradient_descent as gd
 
     g, cfg, X, y, w0, valid = _run_case(case)
-    assert gd.mask_in_kernel(g, cfg, X, y, w0, valid)
+    assert gradients.step_sums(g, cfg, X, y, w0, valid).mask_in_kernel
     seen = []
 
     def fit(in_kernel):
         with monkeypatch.context() as m:
             if not in_kernel:
-                m.setattr(type(g), "draws_rows", lambda *a, **k: False)
+                m.setattr(gradients, "counter_draws", lambda: False)
             run = jax.jit(gd.make_run(g, SquaredL2Updater(), cfg))
             return [np.asarray(a) for a in run(w0, X, y, valid)]
 
@@ -273,6 +274,7 @@ def test_a_meshed_fit_that_draws_in_the_kernel_is_the_fit_handed_the_mask(
     import jax
 
     from tpu_sgd.config import SGDConfig
+    from tpu_sgd.ops import gradients
     from tpu_sgd.ops.updaters import SimpleUpdater
     from tpu_sgd.parallel.data_parallel import dp_run_fn
     from tpu_sgd.parallel.mesh import data_mesh
@@ -289,7 +291,7 @@ def test_a_meshed_fit_that_draws_in_the_kernel_is_the_fit_handed_the_mask(
     def fit(in_kernel):
         with monkeypatch.context() as m:
             if not in_kernel:
-                m.setattr(type(g), "draws_rows", lambda *a, **k: False)
+                m.setattr(gradients, "counter_draws", lambda: False)
             run = dp_run_fn(g, SimpleUpdater(), cfg, mesh, with_valid=False)
             return [np.asarray(a) for a in run(w0, X, y)]
 
@@ -300,9 +302,9 @@ def test_a_meshed_fit_that_draws_in_the_kernel_is_the_fit_handed_the_mask(
 # -- the selection ------------------------------------------------------------------
 
 @pytest.mark.parametrize("case", [
-    "feature_major", "chunked", "bcoo", "row_major_width", "feature_sharded",
+    "feature_major", "bcoo", "row_major_width", "feature_sharded",
     "statistics", "classes", "wide", "flag_off", "rbg"])
-def test_draws_rows_follows_what_the_code_can_observe(case):
+def test_the_draw_follows_what_the_code_can_observe(case):
     """The kernel draws where the sums are the one-read kernel's vector
     body with all d in one feature block and the draw is a counter's;
     everywhere else ``_make_mask`` makes the array it always made."""
@@ -312,8 +314,8 @@ def test_draws_rows_follows_what_the_code_can_observe(case):
     import jax.numpy as jnp
 
     from tpu_sgd.config import SGDConfig
-    from tpu_sgd.ops.gradients import (ChunkedGradient,
-                                       MultinomialLogisticGradient, RowDraw)
+    from tpu_sgd.ops.gradients import (MultinomialLogisticGradient,
+                                       RowDraw, step_sums)
     from tpu_sgd.ops.gram import GramLeastSquaresGradient
     from tpu_sgd.optimize import gradient_descent as gd
 
@@ -322,9 +324,7 @@ def test_draws_rows_follows_what_the_code_can_observe(case):
             case, case if case in ("bcoo", "row_major_width", "wide")
             else "feature_major"))
     g, how = LogisticGradient(), contextlib.nullcontext()
-    if case == "chunked":
-        g = ChunkedGradient(g, 256)
-    elif case == "statistics":
+    if case == "statistics":
         g = GramLeastSquaresGradient()
     elif case == "classes":
         g, w = MultinomialLogisticGradient(4), jnp.zeros((3 * 1000,))
@@ -332,11 +332,15 @@ def test_draws_rows_follows_what_the_code_can_observe(case):
         how = jax.threefry_partitionable(False)
     elif case == "rbg":
         how = jax.default_prng_impl("rbg")
-    on = case in ("feature_major", "chunked")
+    on = case == "feature_major"
     cfg = SGDConfig(mini_batch_fraction=0.1)
     with how:
-        assert g.draws_rows(X, y, w, None, axis) == on
-        assert gd.mask_in_kernel(g, cfg, X, y, w, None, axis) == on
+        plan = step_sums(g, cfg, X, y, w, None, axis)
+        assert plan.mask_in_kernel == on and plan.drawn == (not on)
+        # the body that could draw is the vector's over all d, whatever
+        # the PRNG; only under a counter's draw does the step hand it on
+        assert (plan.kernel is not None and plan.kernel.draws) == (
+            case in ("feature_major", "flag_off", "rbg"))
         if case in ("wide", "bcoo"):
             return  # shapes alone, or no dense rows to draw over
         key = jax.random.PRNGKey(3)
@@ -351,7 +355,8 @@ def test_draws_rows_follows_what_the_code_can_observe(case):
     for kw in (dict(mini_batch_fraction=1.0),
                dict(mini_batch_fraction=0.1, sampling="sliced"),
                dict(mini_batch_fraction=0.1, sampling="indexed")):
-        assert not gd.mask_in_kernel(g, SGDConfig(**kw), X, y, w, None, axis)
+        assert not step_sums(g, SGDConfig(**kw), X, y, w, None,
+                             axis).mask_in_kernel
 
 
 @pytest.mark.parametrize("how", ["counter", "flag_off", "rbg"])

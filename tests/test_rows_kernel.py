@@ -12,8 +12,7 @@ import pytest
 
 from tpu_sgd.ops.gradients import (HingeGradient, LeastSquaresGradient,
                                    LogisticGradient,
-                                   MultinomialLogisticGradient, by_rows,
-                                   one_read_blocks, one_read_sums)
+                                   MultinomialLogisticGradient, one_read_of)
 from tpu_sgd.ops.pallas_kernels import (fused_class_sums, fused_rows_sums,
                                         fused_wide_sums)
 
@@ -160,6 +159,11 @@ def _shapes(n, d, dtype="bfloat16", classes=None):
     return X, y, w, jax.ShapeDtypeStruct((n,), bool)
 
 
+def _blocks(own):
+    """``(by rows, row tile, feature blocks)`` of a record, or None."""
+    return own and (own.by_rows, own.tile, own.feature_blocks)
+
+
 #: every width the issue names (embeddings, hashed spaces, 32 x 32 x 3
 #: pixels) at 2**20 rows: the row tile of a vector's / ten classes' by-rows
 #: kernel, bf16 and f32
@@ -169,24 +173,24 @@ ADMITTED = {128: (2048, 2048), 768: (2048, 2048), 1024: (2048, 2048),
 
 
 @pytest.mark.parametrize("d", sorted(ADMITTED))
-def test_one_read_blocks_admits_the_widths_the_chip_stores_by_rows(d):
+def test_one_read_of_admits_the_widths_the_chip_stores_by_rows(d):
     n = 2**20
     for dtype, tile in zip(("bfloat16", "float32"), ADMITTED[d]):
         X, y, w, mask = _shapes(n, d, dtype)
-        assert by_rows(X)
-        assert one_read_blocks(X, y, w) == (tile, 1)
+        assert _blocks(one_read_of(X, y, w)) == (True, tile, 1)
         Xc, yc, wc, _ = _shapes(n, d, dtype, classes=10)
-        assert one_read_blocks(Xc, yc, wc, classes=10) == (tile, 1)
-        assert one_read_blocks(X, y, w, mask) == (tile, 1)
+        assert _blocks(one_read_of(Xc, yc, wc, classes=10)) == (
+            True, tile, 1)
+        assert _blocks(one_read_of(X, y, w, mask)) == (True, tile, 1)
         # no window grid, no draw in the kernel
-        assert not one_read_sums(X, y, w, window=True)
-        assert not LogisticGradient().draws_rows(X, y, w)
-        assert LogisticGradient().prepares_rows(X, y, w)
-        assert LogisticGradient().kernel_blocks(X, y, w, window=n // 10) == (
-            0, 1)
+        assert one_read_of(X, y, w, window=n // 10) is None
+        g = LogisticGradient()
+        assert g.one_read(X, y, w) is not None
+        assert not g.one_read(X, y, w).draws
+        assert g.one_read(X, y, w, window=n // 10) is None
 
 
-#: what the six older cells' steps ask ``one_read_blocks`` and are answered,
+#: what the six older cells' steps ask ``one_read_of`` and are answered,
 #: on the parent and now, then the by-rows cell's and LIBSVM SVHN's shape (no
 #: cell: its rows end in a cut block): (rows a shard, d, classes, masked,
 #: window) -> blocks
@@ -209,15 +213,15 @@ BY_ROWS_CELLS = ("cifar5m-multinomial.resident-classes", "svhn-shape")
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
-def test_one_read_blocks_answers_the_cells_shapes(cell):
+def test_one_read_of_answers_the_cells_shapes(cell):
     n, d, classes, masked, window = CELLS[cell]
     X, y, w, mask = _shapes(n, d, classes=classes)
-    blocks = one_read_blocks(X, y, w, mask if masked else None,
-                             classes=classes, window=window)
-    assert blocks == {"rcv1-dense-hinge-l1.resident-wide": (256, 8),
-                      **dict.fromkeys(BY_ROWS_CELLS, (1024, 1))}.get(
-                          cell, (2048, 1))
-    assert by_rows(X) == (cell in BY_ROWS_CELLS)
+    own = one_read_of(X, y, w, mask if masked else None, classes=classes,
+                      window=n // 10 if window else None)
+    assert _blocks(own) == {
+        "rcv1-dense-hinge-l1.resident-wide": (False, 256, 8),
+        **dict.fromkeys(BY_ROWS_CELLS, (True, 1024, 1))}.get(
+            cell, (False, 2048, 1))
 
 
 #: by-rows shapes that stay two reads, and why
@@ -230,19 +234,19 @@ OFF = {
 
 
 @pytest.mark.parametrize("case", sorted(OFF))
-def test_one_read_blocks_leaves_two_reads_where_no_row_block_fits(case):
+def test_one_read_of_leaves_two_reads_where_no_row_block_fits(case):
     from tpu_sgd.ops.pallas_kernels import by_rows_form, feature_major
 
     n, d, dtype = OFF[case]
     X, y, w, mask = _shapes(n, d, dtype)
     assert not feature_major(n, d)
     assert by_rows_form(n, d) == case.startswith("overflows")
-    assert one_read_blocks(X, y, w) is None
-    assert one_read_blocks(X, y, w, mask) is None
+    assert one_read_of(X, y, w) is None
+    assert one_read_of(X, y, w, mask) is None
     Xc, yc, wc, _ = _shapes(n, d, dtype, classes=10)
-    assert one_read_blocks(Xc, yc, wc, classes=10) is None
-    assert LogisticGradient().kernel_blocks(X, y, w) == (0, 1)
-    assert not LogisticGradient().prepares_rows(X, y, w)
+    assert one_read_of(Xc, yc, wc, classes=10) is None
+    assert LogisticGradient().one_read(X, y, w) is None
+    assert LogisticGradient().one_read(X, y, w, mask) is None
 
 
 def _lowered_for(platform, fn, *args):
@@ -288,20 +292,19 @@ def test_window_sums_of_by_rows_x_keeps_the_slice_and_two_matvecs_on_a_tpu():
 
 
 def test_a_bernoulli_step_over_by_rows_x_hands_the_kernel_an_array():
-    """``draws_rows`` is False, so the step's mask is the ``(n,)`` array
-    and the by-rows kernel reads it as a row operand."""
+    """The class body draws nothing, so the step's mask is the ``(n,)``
+    array and the by-rows kernel reads it as a row operand."""
     from tpu_sgd.config import SGDConfig
-    from tpu_sgd.optimize import gradient_descent as gd
+    from tpu_sgd.ops.gradients import step_sums
 
     X, y, w, _ = _shapes(2**20, 1024)
     cfg = SGDConfig(mini_batch_fraction=0.1)
     g = LogisticGradient()
-    assert not gd.mask_in_kernel(g, cfg, X, y, w)
-    assert gd.step_blocks(g, cfg, X, y, w) == (2048, 1)
-    assert gd.rows_prepared(g, cfg, X, y, w)
+    plan = step_sums(g, cfg, X, y, w)
+    assert plan.drawn and not plan.mask_in_kernel
+    assert _blocks(plan.kernel) == (True, 2048, 1)
     sliced = SGDConfig(mini_batch_fraction=0.1, sampling="sliced")
-    assert gd.step_blocks(g, sliced, X, y, w) == (0, 1)
-    assert not gd.rows_prepared(g, sliced, X, y, w)
+    assert step_sums(g, sliced, X, y, w).kernel is None
 
 
 # -- the counter ---------------------------------------------------------------

@@ -35,11 +35,16 @@ class SGDConfig:
       sampling: mini-batch sampling strategy when ``mini_batch_fraction < 1``.
         ``"bernoulli"`` (default) is exact reference parity — a per-example
         Bernoulli mask, normalized by the realized count; it computes the
-        full-dataset matvec with masked coefficients.  ``"indexed"`` is a
-        TPU fast path: gather a fixed-size batch of ``round(frac * n)`` rows
-        sampled with replacement, touching only ``frac`` of HBM per
-        iteration — distributionally equivalent for SGD, ~1/frac less
-        memory traffic.  ``"sliced"`` is the HBM-optimal fast path: a
+        full-dataset matvec with masked coefficients.  ``"indexed"`` gathers
+        a fixed-size batch of ``round(frac * n)`` rows sampled with
+        replacement — distributionally equivalent for SGD, and sound on the
+        host-streamed path (a host gather, ``optimize/streamed.py``).  On a
+        RESIDENT X the chip read it slower than the masked scan at both
+        layouts: at d = 1000 (stored feature-major) ``X[idx]`` has all of X
+        copied first, and by rows (2,097,152 x 1,024 bf16, 209,715 drawn
+        rows) the gather and its step take 7.709 ms against the masked
+        scan's 5.831 (builder's chip runs, PR 26 and PR 39; ROADMAP
+        Design 3).  ``"sliced"`` is the HBM-optimal fast path: a
         contiguous row window of ``round(frac * n)`` rows at a per-iteration
         random offset — sequential DMA instead of a random gather (several
         times faster again), read in place: once, by the one-read kernel

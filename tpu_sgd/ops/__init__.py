@@ -1,5 +1,4 @@
 from tpu_sgd.ops.gradients import (
-    ChunkedGradient,
     Gradient,
     HingeGradient,
     LeastSquaresGradient,
@@ -26,7 +25,6 @@ from tpu_sgd.ops.updaters import (
 )
 
 __all__ = [
-    "ChunkedGradient",
     "Gradient",
     "LeastSquaresGradient",
     "LogisticGradient",

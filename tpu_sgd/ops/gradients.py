@@ -28,11 +28,15 @@ Closed forms mirrored exactly from the reference semantics (SURVEY.md §3.2):
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+import functools
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from tpu_sgd.config import SGDConfig
+from tpu_sgd.ops import pallas_kernels as pk
+from tpu_sgd.ops.pallas_kernels import OneRead
 from tpu_sgd.ops.sparse import is_sparse as _is_sparse
 
 Array = jax.Array
@@ -100,8 +104,8 @@ def grad_sum_of(coeff, X):
 class RowDraw:
     """A Bernoulli mini-batch mask that is not drawn yet: what a step hands
     ``Gradient.batch_sums`` in the mask's place where the one-read kernel
-    draws each row's bit itself (``Gradient.draws_rows``), so that no
-    array of the mask is made.  It stands for ``bernoulli(key, fraction,
+    draws each row's bit itself (:func:`step_sums`' ``mask_in_kernel``), so
+    that no array of the mask is made.  It stands for ``bernoulli(key, fraction,
     (n,)) & valid``, the same rows wherever it is drawn: :meth:`mask` IS
     that array, for every path that is no such kernel."""
 
@@ -126,31 +130,24 @@ def counter_draws() -> bool:
                 and jax.config.jax_default_prng_impl == "threefry2x32")
 
 
-def one_read_blocks(X, y, weights, mask=None, margin_axis_name=None,
-                    classes: Optional[int] = None, window: bool = False):
-    """``(row tile, feature blocks)`` of the fused one-read kernel that
-    ``batch_sums`` of these operands takes where the program is lowered
-    for a TPU (``window``: ``window_sums``), None where it takes two reads
-    — decided from what the operands look like, nothing else: dense 2-D
-    bf16 or f32 rows whose blocks the kernel can take in the order the
-    chip stores them, so that no copy of X stands in front of it (stored
-    feature-major: ``X.T`` is a bitcast; stored by rows at a width that is
-    a multiple of 128: the class body's by-rows form over X itself,
-    :func:`by_rows`), a flat
-    weight vector (``classes`` None: one entry a feature; a class count:
-    the row-major flattening of a ``(classes - 1, d)`` matrix whose rows,
-    padded to whole packed registers, are no more than one pass of the
-    matrix unit takes, ``pallas_kernels.FM_CLASS_ROWS``), one label (and
-    one mask entry) a row, whole margins on every core (a feature-sharded
-    run needs the ``psum`` between the two halves), and a block of
-    ``X.T`` that fits the kernel's VMEM beside the weights: all d along
-    128 lanes in f32 or beside the class rows (one feature block), or,
-    for a vector of weights too wide for that, in the wide form
-    (``pallas_kernels.fm_wide``: weights as rows, the width in feature
-    blocks), which the window's kernel does not have; nor has it a
-    by-rows form, and the by-rows form none in feature blocks (such
-    windows and widths stay two reads).  A mask the kernel
-    draws itself (:class:`RowDraw`) is no operand: its ``valid`` is."""
+def one_read_of(X, y, weights, mask=None, margin_axis_name=None,
+                classes: Optional[int] = None, window: Optional[int] = None
+                ) -> Optional[OneRead]:
+    """The fused one-read kernel that ``batch_sums`` of these operands
+    takes where the program is lowered for a TPU (``window``:
+    ``window_sums`` over a window of so many rows), as the record
+    ``pallas_kernels.one_read`` makes of their shape, None where the sums
+    take two reads.  Decided from what the operands look like, nothing
+    else.  HERE: dense 2-D bf16 or f32 rows, a flat weight vector
+    (``classes`` None: one entry a feature; a class count: the row-major
+    flattening of a ``(classes - 1, d)`` matrix), one label (and one mask
+    entry) a row, whole margins on every core (a feature-sharded run needs
+    the ``psum`` between the two halves), a window inside the rows.
+    THERE, from the shape: whether the kernel can take X's blocks in the
+    order the chip stores them, so that no copy of X stands in front of
+    it, which body and grid that is, and whether a block fits its VMEM
+    beside the weights.  A mask the kernel draws itself (:class:`RowDraw`)
+    is no operand: its ``valid`` is."""
     if isinstance(mask, RowDraw):
         mask = mask.valid
     if (margin_axis_name is not None or _is_sparse(X)
@@ -158,41 +155,76 @@ def one_read_blocks(X, y, weights, mask=None, margin_axis_name=None,
             or X.dtype not in (jnp.bfloat16, jnp.float32)):
         return None
     n, d = X.shape
-    if jnp.shape(y) != (n,) or (mask is not None
-                                and jnp.shape(mask) != (n,)):
+    if (jnp.shape(y) != (n,)
+            or (mask is not None and jnp.shape(mask) != (n,))
+            or (window is not None and not 0 < window <= n)):
         return None
-    from tpu_sgd.ops.pallas_kernels import (FM_CLASS_ROWS, class_rows_of,
-                                            fm_blocks)
-
     rows = 0
     if classes is not None:
-        rows = class_rows_of(classes - 1, X.dtype)
-        if rows > FM_CLASS_ROWS or jnp.shape(weights) != ((classes - 1) * d,):
+        if jnp.shape(weights) != ((classes - 1) * d,):
             return None
-    blocks = fm_blocks(n, d, X.dtype.itemsize, mask is not None, rows)
-    if blocks is None or (window and (blocks[1] > 1 or by_rows(X))):
-        return None
-    return blocks
+        rows = pk.class_rows_of(classes - 1, X.dtype)
+    return pk.one_read(n, d, X.dtype.itemsize, mask is not None, rows,
+                       window is not None)
 
 
-def by_rows(X) -> bool:
-    """Whether the one-read kernel of a dense ``X`` (where
-    :func:`one_read_blocks` gives it one) is the by-rows form: the class
-    body over row blocks of an X the chip stores by rows
-    (``pallas_kernels.by_rows_form``), for a matrix of weights and for a
-    vector alike.  From the shape alone."""
-    from tpu_sgd.ops.pallas_kernels import by_rows_form
+@dataclasses.dataclass(frozen=True)
+class StepSums:
+    """What every step of a fit hands its sums besides the labels, and the
+    kernel they take of it on a TPU: :func:`step_sums`' answer."""
 
-    return by_rows_form(*jnp.shape(X))
+    #: the row mask a step hands on as it stands (a padded shard's
+    #: ``valid``), None where there is none or the draw folds it in
+    mask: Any
+    #: rows of the window under ``sampling="sliced"``, else None
+    window: Optional[int]
+    #: the step makes its Bernoulli mask anew as an ARRAY, ``valid`` folded
+    #: into it
+    drawn: bool
+    #: the one-read kernel of every step's sums, None where they take two
+    #: reads (or the step gathers its rows, ``"indexed"``)
+    kernel: Optional[OneRead]
+    #: that kernel draws every step's Bernoulli mask itself: the step hands
+    #: it a :class:`RowDraw` (``train.run``'s attribute of the same name)
+    mask_in_kernel: bool
 
 
-def one_read_sums(X, y, weights, mask=None, margin_axis_name=None,
-                  classes: Optional[int] = None, window: bool = False
-                  ) -> bool:
-    """Whether the sums of these operands may take a fused one-read
-    kernel (:func:`one_read_blocks`)."""
-    return one_read_blocks(X, y, weights, mask, margin_axis_name, classes,
-                           window) is not None
+def window_rows(cfg: SGDConfig, n_rows: int) -> int:
+    """Rows of a sliced or indexed mini-batch over ``n_rows`` rows."""
+    return max(1, round(cfg.mini_batch_fraction * n_rows))
+
+
+def step_sums(gradient: "Gradient", cfg: SGDConfig, X, y, weights,
+              valid=None, model_axis_name=None) -> StepSums:
+    """What every step of ``make_run``'s fit over these operands (a
+    shard's, under a mesh) hands ``gradient``'s sums, and the one-read
+    kernel they take of it where the program is lowered for a TPU
+    (``Gradient.one_read``).  The ONE asker of the step: the mask
+    ``_make_mask`` makes, the rows ``prepare_rows`` lays out before the
+    loop, ``train.run``'s attributes and the planner's count of reads all
+    read this.  From shapes, types and JAX's configuration alone, so the
+    host can ask it of a fit it is about to dispatch.
+
+    A full batch hands on ``valid``; ``"sliced"`` ``valid`` and a window;
+    ``"indexed"`` gathers its rows and hands nothing on as it stands.
+    ``"bernoulli"`` hands the kernel a :class:`RowDraw` where its body
+    draws (``OneRead.draws``) and the draw is a counter's
+    (:func:`counter_draws`), ``valid`` an operand of its own; everywhere
+    else the step draws the array it always drew."""
+    sampled = cfg.mini_batch_fraction < 1.0
+    if sampled and cfg.sampling == "indexed":
+        return StepSums(None, None, False, None, False)
+    window = None
+    if sampled and cfg.sampling == "sliced":
+        window = window_rows(cfg, X.shape[0])
+    kernel = gradient.one_read(X, y, weights, valid, model_axis_name, window)
+    if not sampled or window is not None:
+        return StepSums(valid, window, False, kernel, False)
+    if kernel is not None and kernel.draws and counter_draws():
+        return StepSums(valid, None, False, kernel, True)
+    drawn = jax.ShapeDtypeStruct((X.shape[0],), bool)  # the step's draw
+    return StepSums(None, None, True, gradient.one_read(
+        X, y, weights, drawn, model_axis_name), False)
 
 
 class Gradient:
@@ -230,8 +262,8 @@ class Gradient:
         This is the XLA-compiled replacement for the reference's executor-side
         per-example seqOp loop (SURVEY.md §3.1 inner hot loop): the whole
         shard's contribution in two matvecs (two reads of X) or, where
-        :func:`one_read_sums` holds and the program is lowered for a TPU,
-        in one fused pass.  ``mask`` implements Bernoulli
+        :func:`one_read_of` names a kernel and the program is lowered for a
+        TPU, in one fused pass.  ``mask`` implements Bernoulli
         mini-batch sampling; sums are *unnormalized* so they can be combined
         across shards with ``lax.psum`` before dividing by the realized
         mini-batch count (parity with ``treeAggregate`` + ``/ miniBatchSize``).
@@ -247,17 +279,19 @@ class Gradient:
         They ride beside ``y`` and ``mask``, which the two-read path reads.
 
         A :class:`RowDraw` in the mask's place (a step hands one on where
-        :meth:`draws_rows` holds, nowhere else) is drawn by the kernel,
-        row by row, and made the array it stands for where the program is
+        :func:`step_sums` says ``mask_in_kernel``, nowhere else) is drawn by the
+        kernel, row by row, and made the array it stands for where the program is
         lowered for another platform.
         """
-        if one_read_sums(X, y, weights, mask, margin_axis_name):
+        kernel = one_read_of(X, y, weights, mask, margin_axis_name)
+        if kernel is not None:
             # both are traced; the platform the program is LOWERED for
             # picks one, so a CPU process compiling for the chip gets the
             # kernel and a CPU run the two matvecs it always had
             return jax.lax.platform_dependent(
                 X, y, weights, mask, rows,
-                tpu=self._fused_sums, default=self._two_read_default)
+                tpu=functools.partial(self._fused_sums, kernel),
+                default=self._two_read_default)
         return self._two_read_sums(X, y, weights, mask, margin_axis_name)
 
     def _two_read_default(self, X, y, weights, mask, rows):
@@ -267,45 +301,18 @@ class Gradient:
             mask = mask.mask(X.shape[0])
         return self._two_read_sums(X, y, weights, mask)
 
-    def draws_rows(self, X, y, weights, valid=None, margin_axis_name=None
-                   ) -> bool:
-        """Whether a Bernoulli-sampled step hands :meth:`batch_sums` its
-        mask as a :class:`RowDraw`, for the kernel to draw: where the sums
-        of these operands (``valid``: a padded shard's, or None) are the
-        one-read kernel's vector body with all d in one feature block if
-        the program is lowered for a TPU (:meth:`kernel_blocks`), and the
-        draw is a counter's (:func:`counter_draws`).  Everywhere else (two
-        reads, BCOO, a feature-sharded run, the wide and by-rows forms,
-        which are the class body, another PRNG)
-        the step draws the array it always drew.  From shapes, types and
-        JAX's configuration alone, so the host can ask it too."""
-        tile, feature_blocks = self.kernel_blocks(
-            X, y, weights, valid, margin_axis_name)
-        return (tile > 0 and feature_blocks == 1 and not by_rows(X)
-                and counter_draws() and jnp.shape(X)[0] < 2**31)
-
-    def kernel_blocks(self, X, y, weights, mask=None, margin_axis_name=None,
-                      window: Optional[int] = None) -> Tuple[int, int]:
-        """``(row tile, feature blocks)`` of the one-read kernel that the
-        sums of every step take where the program is lowered for a TPU
-        (``batch_sums``; ``window_sums`` over a window of ``window``
-        rows): the rows a grid step reads and the blocks its body cuts
-        the width into (:func:`one_read_blocks`); ``(0, 1)`` where the
-        step takes two reads.  Decided from shapes and types alone, so
-        the host can ask it of a fit it is about to dispatch."""
-        if window is not None and not 0 < window <= jnp.shape(X)[0]:
-            return 0, 1
-        return one_read_blocks(X, y, weights, mask, margin_axis_name,
-                               window=window is not None) or (0, 1)
-
-    def prepares_rows(self, X, y, weights, valid=None,
-                      margin_axis_name=None, window: Optional[int] = None
-                      ) -> bool:
-        """Whether :meth:`row_operands` lays these operands out: where the
-        sums of every step will be the one-read kernel if the program is
-        lowered for a TPU (:meth:`kernel_blocks`)."""
-        return self.kernel_blocks(X, y, weights, valid, margin_axis_name,
-                                  window)[0] > 0
+    def one_read(self, X, y, weights, mask=None, margin_axis_name=None,
+                 window: Optional[int] = None) -> Optional[OneRead]:
+        """The one-read kernel that the sums of every step take of these
+        operands where the program is lowered for a TPU (``batch_sums``;
+        ``window_sums`` over a window of ``window`` rows), None where the
+        step takes two reads (:func:`one_read_of`).  What a fit asks before
+        its loop (:func:`step_sums`): whether to lay the labels out for the
+        kernel, whether the kernel draws the mask, what ``train.run`` says
+        of the step.  A gradient whose steps are no such sums overrides it
+        to say so.  From shapes and types alone, so the host can ask it."""
+        return one_read_of(X, y, weights, mask, margin_axis_name,
+                           window=window)
 
     def row_operands(self, X, y, weights, valid=None,
                      margin_axis_name=None, window: Optional[int] = None):
@@ -314,44 +321,37 @@ class Gradient:
         read (``pallas_kernels.row_operand``), for the caller of a loop
         to make in front of it and hand to every step's ``batch_sums`` /
         ``window_sums`` as ``rows``; None where no kernel will read them
-        (:meth:`prepares_rows`) and the step takes ``y`` as it is.
+        (:meth:`one_read`) and the step takes ``y`` as it is.
 
         ``valid`` is a mask that is the same every step (a padded shard's);
         one drawn each step stays the step's.  Why the source and not the
         compiler moves them: ``ops/pallas_kernels.py``, "What reaches the
         kernels as a bitcast"."""
-        if not self.prepares_rows(X, y, weights, valid, margin_axis_name,
-                                  window):
+        if self.one_read(X, y, weights, valid, margin_axis_name,
+                         window) is None:
             return None
-        from tpu_sgd.ops.pallas_kernels import row_operand
-
         n = X.shape[0]
-        return (row_operand(y, n),
-                None if valid is None else row_operand(valid, n))
+        return (pk.row_operand(y, n),
+                None if valid is None else pk.row_operand(valid, n))
 
-    def _fused_sums(self, X, y, weights, mask, rows=None):
-        """One read of X: the Pallas kernel over the blocks the chip
-        already stores (``ops/pallas_kernels.py``): of ``X.T`` where it
-        stores X feature-major, in the wide form where the width asks for
-        it (``fm_wide``) under a scope of its own; of X itself where it
-        stores X by rows (:func:`by_rows`)."""
-        from tpu_sgd.ops.pallas_kernels import (fm_wide, fused_gradient_sums,
-                                                fused_rows_sums,
-                                                fused_wide_sums)
-
+    def _fused_sums(self, kernel: OneRead, X, y, weights, mask, rows=None):
+        """One read of X: the Pallas kernel ``kernel`` names
+        (``ops/pallas_kernels.py``), over the blocks the chip already
+        stores, under the record's scope (the entry finds the record's
+        tile itself: tests substitute the entries at a tile of theirs)."""
         draw = None
-        if isinstance(mask, RowDraw):  # by draws_rows the vector body's
+        if isinstance(mask, RowDraw):  # by step_sums the body that draws
             draw, mask = (mask.key, mask.fraction), mask.valid
         y, mask = _kernel_rows(y, mask, rows)
-        if by_rows(X):
-            with jax.named_scope("sgd.fused_sums"):
-                return fused_rows_sums(self.pointwise, X, y, weights, mask)
-        if fm_wide(*X.shape, X.dtype.itemsize, mask is not None) is not None:
-            with jax.named_scope("sgd.wide_sums"):
-                return fused_wide_sums(self.pointwise, X, y, weights, mask)
-        with jax.named_scope("sgd.fused_sums"):
-            return fused_gradient_sums(self.pointwise, X, y, weights, mask,
-                                       draw=draw)
+        with jax.named_scope(kernel.scope):
+            if kernel.by_rows:
+                return pk.fused_rows_sums(self.pointwise, X, y, weights,
+                                          mask)
+            if kernel.body == "wide":
+                return pk.fused_wide_sums(self.pointwise, X, y, weights,
+                                          mask)
+            return pk.fused_gradient_sums(self.pointwise, X, y, weights,
+                                          mask, draw=draw)
 
     def _two_read_sums(self, X, y, weights, mask, margin_axis_name=None):
         """Two matvecs, each a pass over all of X (or the BCOO lowering)."""
@@ -411,9 +411,10 @@ class Gradient:
         ``sampling="sliced"`` mini-batch (SURVEY.md §7 hard parts: the HBM-
         traffic-optimal sampler).  ``start`` is a traced scalar, clamped in
         bounds as ``lax.dynamic_slice`` clamps it.  Two paths, chosen as
-        :meth:`batch_sums` chooses, no option: where :func:`one_read_sums`
-        holds for ``(X, y, weights, valid)`` and the program is lowered for
-        a TPU, the one-read kernel over the window's own blocks of ``X.T``
+        :meth:`batch_sums` chooses, no option: where :func:`one_read_of`
+        names a kernel for ``(X, y, weights, valid)`` under a window of
+        ``m`` rows and the program is lowered for a TPU, the one-read
+        kernel over the window's own blocks of ``X.T``
         at a scalar-prefetched block offset (X read where it lies, the
         window once); everywhere else (a CPU, X stored by rows: the by-rows
         form has no window grid, a feature-sharded run) the slice and two
@@ -424,9 +425,8 @@ class Gradient:
         chip at 4,194,304 x 1000: an 841.5 MB temporary a step).
         ``rows`` as in :meth:`batch_sums` (``row_operands(..., window=m)``).
         """
-        if (0 < m <= jnp.shape(X)[0]
-                and one_read_sums(X, y, weights, valid, margin_axis_name,
-                                  window=True)):
+        if one_read_of(X, y, weights, valid, margin_axis_name,
+                       window=m) is not None:
             return jax.lax.platform_dependent(
                 X, y, weights, start, valid, rows,
                 tpu=lambda X, y, weights, start, valid, rows:
@@ -440,12 +440,10 @@ class Gradient:
     def _fused_window_sums(self, X, y, weights, start, valid, m, rows=None):
         """One read of the window, X read in place
         (``ops/pallas_kernels.fused_window_sums``)."""
-        from tpu_sgd.ops.pallas_kernels import fused_window_sums
-
         y, valid = _kernel_rows(y, valid, rows)
         with jax.named_scope("sgd.fused_sums"):
-            return fused_window_sums(self.pointwise, X, y, weights, start, m,
-                                     valid)
+            return pk.fused_window_sums(self.pointwise, X, y, weights, start,
+                                        m, valid)
 
 
 def _kernel_rows(y, mask, rows):
@@ -479,108 +477,6 @@ def _slice_window(X, y, valid, start, m):
         else jax.lax.dynamic_slice_in_dim(valid, start, m, 0)
     )
     return Xb, yb, mask
-
-
-class ChunkedGradient(Gradient):
-    """One-HBM-read window schedule behind the same ``Gradient`` contract.
-
-    The default :meth:`Gradient.window_sums` lowers to two full passes over
-    the window (``X @ w`` then ``Xᵀ @ coeff``) — `PROFILE_TPU.json` puts the
-    whole fused loop at that two-read bandwidth floor.  This wrapper
-    restructures the window as a ``lax.scan`` over ``chunk_rows``-row
-    blocks: each block is sliced once and immediately serves BOTH matmuls
-    while it is resident, so a scheduler that keeps the block in VMEM pays
-    ONE HBM read of X per iteration — the same traffic shape the Pallas
-    fused kernel targets (SURVEY.md §2 #11), expressed at the XLA level
-    where the MXU mapping stays the compiler's problem.  Whether the
-    read actually collapses is an empirical, per-backend question: measure
-    it against the stock path on hardware, and only a trajectory-clean
-    winner may take a headline.
-
-    Wraps any pointwise family (least-squares / logistic / hinge);
-    delegates everything except the window schedule.
-    """
-
-    def __init__(self, base: "Gradient", chunk_rows: int = 65536):
-        if chunk_rows <= 0:
-            raise ValueError(f"chunk_rows must be positive, got {chunk_rows}")
-        self.base = base
-        self.chunk_rows = int(chunk_rows)
-
-    def pointwise(self, margin, label):
-        return self.base.pointwise(margin, label)
-
-    def weight_dim(self, num_features: int) -> int:
-        return self.base.weight_dim(num_features)
-
-    def compute(self, data, label, weights):
-        return self.base.compute(data, label, weights)
-
-    def batch_sums(self, X, y, weights, mask=None, margin_axis_name=None,
-                   rows=None):
-        return self.base.batch_sums(
-            X, y, weights, mask, margin_axis_name=margin_axis_name, rows=rows
-        )
-
-    def kernel_blocks(self, X, y, weights, mask=None, margin_axis_name=None,
-                      window=None):
-        # the window schedule below slices its own blocks of the labels
-        if window is not None:
-            return 0, 1
-        return self.base.kernel_blocks(X, y, weights, mask, margin_axis_name)
-
-    def draws_rows(self, X, y, weights, valid=None, margin_axis_name=None):
-        return self.base.draws_rows(X, y, weights, valid, margin_axis_name)
-
-    def loss_sweep(self, X, y, W, mask=None):
-        return self.base.loss_sweep(X, y, W, mask)
-
-    def window_sums(
-        self, X, y, weights, start, m, valid=None, margin_axis_name=None
-    ):
-        if _is_sparse(X):
-            raise NotImplementedError(
-                "sliced sampling needs a dense row layout; use bernoulli "
-                "sampling with sparse (BCOO) features"
-            )
-        if margin_axis_name is not None:
-            # Feature-sharded margins need a psum per block; the stock
-            # two-pass path already handles that correctly — use it.
-            return self.base.window_sums(
-                X, y, weights, start, m, valid,
-                margin_axis_name=margin_axis_name,
-            )
-        c = min(self.chunk_rows, m)
-        nblk, rem = divmod(m, c)
-        # Clamp ONCE, like the stock path's whole-window dynamic_slice:
-        # per-block clamping would re-read overlapping tail rows for an
-        # out-of-range start and diverge from the base implementation.
-        start = jnp.clip(start, 0, max(X.shape[0] - m, 0))
-        # Accumulate at the same dtype batch_sums returns (>= f32; f64
-        # under jax_enable_x64 with f64 data) so the scan carry matches.
-        cd = acc_dtype(matmul_dtype(X))
-
-        def block_sums(s, rows):
-            Xb, yb, maskb = _slice_window(X, y, valid, s, rows)
-            return self.base.batch_sums(Xb, yb, weights, maskb)
-
-        def body(carry, i):
-            g, ls, cnt = carry
-            gb, lb, cb = block_sums(start + i * c, c)
-            return (g + gb.astype(cd), ls + lb.astype(cd),
-                    cnt + cb.astype(cd)), None
-
-        init = (
-            jnp.zeros(jnp.shape(weights), cd),
-            jnp.asarray(0.0, cd),
-            jnp.asarray(0.0, cd),
-        )
-        (g, ls, cnt), _ = jax.lax.scan(body, init, jnp.arange(nblk))
-        if rem:
-            gb, lb, cb = block_sums(start + nblk * c, rem)
-            g, ls, cnt = g + gb.astype(cd), ls + lb.astype(cd), \
-                cnt + cb.astype(cd)
-        return g, ls, cnt
 
 
 class LeastSquaresGradient(Gradient):
@@ -697,35 +593,28 @@ class MultinomialLogisticGradient(Gradient):
         width that is no multiple of 128 or overflows the kernel's
         VMEM)."""
         with jax.named_scope("sgd.class_sums"):
-            if one_read_sums(X, y, weights, mask, margin_axis_name,
-                             classes=self.num_classes):
+            kernel = one_read_of(X, y, weights, mask, margin_axis_name,
+                                 classes=self.num_classes)
+            if kernel is not None:
                 return jax.lax.platform_dependent(
                     X, y, weights, mask, rows,
-                    tpu=self._fused_sums, default=self._two_read_default)
+                    tpu=functools.partial(self._fused_sums, kernel),
+                    default=self._two_read_default)
             return self._two_read_sums(X, y, weights, mask, margin_axis_name)
 
-    def kernel_blocks(self, X, y, weights, mask=None, margin_axis_name=None,
-                      window=None):
-        # the class kernel has no window grid (window_sums below)
-        if window is not None:
-            return 0, 1
-        return one_read_blocks(X, y, weights, mask, margin_axis_name,
-                               classes=self.num_classes) or (0, 1)
+    def one_read(self, X, y, weights, mask=None, margin_axis_name=None,
+                 window=None):
+        # the class body: no window grid, no draw (``pallas_kernels.one_read``)
+        return one_read_of(X, y, weights, mask, margin_axis_name,
+                           classes=self.num_classes, window=window)
 
-    def draws_rows(self, X, y, weights, valid=None, margin_axis_name=None):
-        # the class kernel reads a drawn mask as an array (no cell runs it
-        # masked; the draw rides the vector body alone)
-        return False
-
-    def _fused_sums(self, X, y, weights, mask, rows=None):
-        """One read of X (``ops/pallas_kernels.fused_class_sums``, which
-        takes the blocks in the order the chip stores X)."""
-        from tpu_sgd.ops.pallas_kernels import fused_class_sums
-
+    def _fused_sums(self, kernel, X, y, weights, mask, rows=None):
+        """One read of X (``ops/pallas_kernels.fused_class_sums``, over
+        the blocks in the order the chip stores X)."""
         y, mask = _kernel_rows(y, mask, rows)
         W = weights.reshape(self.num_classes - 1, X.shape[-1])
-        grad, loss_sum, count = fused_class_sums(self.class_rule, X, y, W,
-                                                 mask)
+        grad, loss_sum, count = pk.fused_class_sums(
+            self.class_rule, X, y, W, mask, by_rows=kernel.by_rows)
         return grad.reshape(-1), loss_sum, count
 
     def _two_read_sums(self, X, y, weights, mask, margin_axis_name=None):

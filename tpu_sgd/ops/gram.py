@@ -1229,11 +1229,11 @@ class GramLeastSquaresGradient(LeastSquaresGradient):
         return X, None
 
     # -- accelerated entry points -----------------------------------------
-    def kernel_blocks(self, X, y, weights, mask=None, margin_axis_name=None,
-                      window=None):
+    def one_read(self, X, y, weights, mask=None, margin_axis_name=None,
+                 window=None):
         # the step runs from the statistics; where it falls back to the
         # stock sums they lay their row operands out themselves
-        return 0, 1
+        return None
 
     def batch_sums(self, X, y, weights, mask=None, margin_axis_name=None):
         Xd, st = self._stats_for(X, mask, margin_axis_name)

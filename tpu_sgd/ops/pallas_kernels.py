@@ -35,8 +35,10 @@ along the sublanes and lane-group adds for the gradient), X stays bf16 in
 HBM and VMEM, products and sums are f32.  On the chip it runs at one read of
 X (11.2 ms for 8.39 GB against 22.3 ms for the two matvecs, PERF.md PR 26).
 ``Gradient.batch_sums`` selects it by what the operands look like
-(``ops/gradients.py:one_read_sums``) when the program is lowered for a TPU;
-nothing else routes here (``interpret=True`` is the CPU tests' way in).
+(``ops/gradients.py:one_read_of`` -> :func:`one_read`, the ONE place where
+the order of the forms below is written) when the program is lowered for a
+TPU; nothing else routes here (``interpret=True`` is the CPU tests' way
+in).
 :func:`fused_window_sums`: the same body over the blocks that hold one
 contiguous window of rows (``sampling="sliced"``), found through a
 scalar-prefetched LANE-block offset, so X is read where it lies and the
@@ -74,7 +76,7 @@ a 24 MB row tile.  On the chip the copy sets the pace whatever the tile:
 with one and with neither, and feature blocks of 1024, 4096 or all of d;
 in a fit 16.37 ms a step, 756 GB/s, against the two matvecs' 32.86
 (PERF.md, PR 34).  ``Gradient.batch_sums`` selects it where
-:func:`fm_wide` says so, from the operands, under ``sgd.wide_sums``.
+:func:`one_read` says so, from the operands, under ``sgd.wide_sums``.
 
 **What reaches the kernels as a bitcast.**  ``X.T`` where the chip stores X
 feature-major, and every ROW operand (one entry a row of X: the labels, a
@@ -105,9 +107,10 @@ every shard.  The twenty rounds run on the rows folded over the sublanes
 (one register for 1024 rows) and hide under the block's copy: over
 4,194,304 rows the call alone reads 11.1068 ms with the draw, with eight
 rounds or with none, and 11.1067 with no mask (PERF.md, PR 36).
-``Gradient.draws_rows`` says
-where a step hands the draw on in the mask's place; the class and wide
-bodies, and every explicit mask (a padded shard's ``valid``), read a
+``OneRead.draws`` says
+which body a step hands the draw on to in the mask's place
+(``ops/gradients.step_sums``); the class and wide bodies, and every
+explicit mask (a padded shard's ``valid``), read a
 ``(1, n)`` row as before.
 
 **The by-rows form (PR 39).**  Where the chip stores X by rows, d a multiple
@@ -127,7 +130,7 @@ logistic, hinge and least squares at 1,024 or 4,096 features take it too.
 All d is one feature block under ``_FM_VMEM_LIMIT``; there is no window grid
 and no draw in the kernel (a Bernoulli mask is a row operand), and a by-rows
 X whose d is no multiple of 128 (padded lanes) or too wide for one lane
-group of rows stays two reads.  ``one_read_blocks`` selects it from the
+group of rows stays two reads.  :func:`one_read` selects it from the
 operands as it selects the others.
 
 **What the old verdict rested on.**  A second family, window kernels over
@@ -149,6 +152,7 @@ unit.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Tuple
 
@@ -187,6 +191,10 @@ _FM_WIDE_VMEM_LIMIT = 100 * 1024 * 1024
 #: of the row tile in X's type (the operand of one product, and the cut
 #: block's copy with the lanes outside replaced)
 _FM_FEATURE_BLOCK_SHARE = 32
+
+#: class rows one call of the class kernel takes at most: one pass of the
+#: matrix unit's 128 rows (more are the two-read path's; not measured)
+FM_CLASS_ROWS = 128
 
 
 def feature_major(n: int, d: int) -> bool:
@@ -318,52 +326,90 @@ def _fm_wide_plan(n: int, d: int, itemsize: int, masked: bool, limit: int
     return tile and (tile, _fm_feature_block(d, tile, itemsize, limit))
 
 
-def fm_wide(n: int, d: int, itemsize: int, masked: bool = True
-            ) -> Optional[Tuple[int, int]]:
-    """``(row tile, feature block)`` where the full scan over an ``(n, d)``
-    X under a VECTOR of weights takes the WIDE form
-    (:func:`fused_wide_sums`): where the ``(d, 128)`` f32 operands of
-    ``_fm_kernel`` do not fit its VMEM beside one lane group of ``X.T``
-    (RCV1's 47,236 features: 96.7 MB of weights and partials alone) and
-    the weights as ROWS do.  None where ``_fm_kernel`` takes the shape,
-    and where not even the wide form fits."""
-    if _fm_narrow_tile(n, d, itemsize, masked) is not None:
+@dataclasses.dataclass(frozen=True)
+class OneRead:
+    """Which one-read kernel the sums over an ``(n, d)`` X take, and how:
+    :func:`one_read`'s answer, for everyone who asks (the step's dispatch,
+    the rows a fit lays out before its loop, the mask a step draws or hands
+    on, ``train.run``'s attributes, the planner's count of reads)."""
+
+    #: ``"scan"`` / ``"window"``: ``_fm_kernel`` over the full scan / over a
+    #: window's blocks; ``"class"``: ``_fm_class_kernel`` with all d in one
+    #: feature block (a matrix of weights, or by rows a vector as rows);
+    #: ``"wide"``: the same body with the width in feature blocks
+    body: str
+    #: ``(tile, d)`` row blocks of X itself, not ``(d, tile)`` of ``X.T``
+    by_rows: bool
+    #: rows a grid step takes; features a product of the body takes, and
+    #: the blocks that cuts the width into
+    tile: int
+    fblock: int
+    feature_blocks: int
+    #: the scoped VMEM the tile was fitted under, which the call asks for
+    vmem_limit: int
+    #: the scope the call runs under (the per-layer metrics read it)
+    scope: str
+    #: the body can draw a Bernoulli mask's rows itself (``draw=`` of
+    #: :func:`fused_gradient_sums`); elsewhere a mask is a row operand
+    draws: bool
+
+
+@functools.lru_cache(maxsize=256)
+def one_read(n: int, d: int, itemsize: int, masked: bool = True,
+             class_rows: int = 0, window: bool = False
+             ) -> Optional[OneRead]:
+    """The one-read kernel of the sums over a dense ``(n, d)`` X of
+    ``itemsize`` bytes an element (``masked``: a row mask is an operand;
+    ``class_rows``: a matrix of so many padded class rows, 0 a vector;
+    ``window``: over a contiguous window of the rows), None where they take
+    two reads.  THE place where the order of the forms and what each leaves
+    out are written:
+
+    * stored by rows at a multiple of 128 (:func:`by_rows_form`): the class
+      body over row blocks of X itself, a vector riding it as one packed
+      register of rows (:func:`wide_rows_of`); no window grid;
+    * stored by rows at another width: none (a block of ``X.T`` would be
+      handed a copy of all of X);
+    * stored feature-major, a matrix of weights: the class body over
+      ``X.T``, at most ``FM_CLASS_ROWS`` rows; no window grid;
+    * a vector: ``_fm_kernel`` with all d along the lanes, over the full
+      scan (the one body that draws a mask's rows, counted in int32) or a
+      window; where its ``(d, 128)`` f32 operands do not fit
+      ``_FM_VMEM_LIMIT`` beside one lane group of ``X.T`` (RCV1's 47,236
+      features: 96.7 MB), the WIDE form under ``_FM_WIDE_VMEM_LIMIT``,
+      which the window's grid does not have.
+
+    The row tile is ``FM_TILE`` halved until the form's VMEM fits; None
+    where not even one lane group of rows does."""
+    by_rows = by_rows_form(n, d)
+    if (class_rows > FM_CLASS_ROWS or (window and (by_rows or class_rows))
+            or not (by_rows or feature_major(n, d))):
         return None
-    return _fm_wide_plan(n, d, itemsize, masked, _FM_WIDE_VMEM_LIMIT)
+    body = "class" if by_rows or class_rows else (
+        "window" if window else "scan")
+    limit, fblock = _FM_VMEM_LIMIT, d
+    tile = _fm_narrow_tile(
+        n, d, itemsize, masked,
+        class_rows or (32 // itemsize if by_rows else 0), by_rows)
+    if tile is None and body == "scan":
+        body, limit = "wide", _FM_WIDE_VMEM_LIMIT
+        tile, fblock = _fm_wide_plan(n, d, itemsize, masked, limit) or (
+            None, d)
+    if tile is None:
+        return None
+    scope = ("sgd.class_sums" if class_rows else
+             "sgd.wide_sums" if body == "wide" else "sgd.fused_sums")
+    return OneRead(body, by_rows, tile, fblock, pl.cdiv(d, fblock), limit,
+                   scope, draws=body == "scan" and n < 2**31)
 
 
 def fm_blocks(n: int, d: int, itemsize: int, masked: bool = True,
               class_rows: int = 0) -> Optional[Tuple[int, int]]:
     """``(row tile, feature blocks)`` of the full scan's kernel over an
-    ``(n, d)`` X: its own choice of row tile, ``FM_TILE`` halved until its
-    VMEM fits, and the blocks its body cuts the width into: one, or for a
-    vector of weights too wide for that (:func:`fm_wide`) the wide form's.
-    Where the chip stores X by rows the kernel is the class body over
-    ``(tile, d)`` blocks of X itself (:func:`by_rows_form`), the width in one
-    block.  None where no form fits even one lane group of rows (a wider
-    d still, or a by-rows width that is no multiple of 128: the two-read
-    path's cases)."""
-    if by_rows_form(n, d):
-        # a vector of weights rides the class body as one packed register
-        # of rows (wide_rows_of); the by-rows form has no feature blocks
-        tile = _fm_narrow_tile(n, d, itemsize, masked,
-                               class_rows or 32 // itemsize, True)
-        return tile and (tile, 1)
-    if not feature_major(n, d):
-        return None
-    tile = _fm_narrow_tile(n, d, itemsize, masked, class_rows)
-    if tile is not None:
-        return tile, 1
-    wide = None if class_rows else fm_wide(n, d, itemsize, masked)
-    return wide and (wide[0], pl.cdiv(d, wide[1]))
-
-
-def fm_tile(n: int, d: int, itemsize: int, masked: bool = True,
-            class_rows: int = 0) -> Optional[int]:
-    """The full scan's own choice of row tile for an ``(n, d)`` X
-    (:func:`fm_blocks`), None where it has none."""
-    blocks = fm_blocks(n, d, itemsize, masked, class_rows)
-    return blocks and blocks[0]
+    ``(n, d)`` X, None where it has none: a read of :func:`one_read`'s
+    record (``tests/benchmark`` holds the wide cell to it)."""
+    own = one_read(n, d, itemsize, masked, class_rows)
+    return own and (own.tile, own.feature_blocks)
 
 
 def _check_fm_vmem(tile: int, X, masked: bool, class_rows: int = 0,
@@ -768,11 +814,6 @@ def _fused_scan_sums(
     return _fold_sums(grad, loss, cnt, None if counted else n)
 
 
-#: class rows one call of the class kernel takes at most: one pass of the
-#: matrix unit's 128 rows (more are the two-read path's; not measured)
-FM_CLASS_ROWS = 128
-
-
 def class_rows_of(C: int, dtype) -> int:
     """Rows the class kernel holds ``C`` class rows of weights and
     coefficients at: padded to whole packed registers of ``dtype``."""
@@ -889,9 +930,9 @@ def fused_class_sums(
     coefficients are rounded to it) with f32 sums.
 
     ``by_rows``: the blocks are ``(tile_m, d)`` row blocks of X itself and
-    not ``(d, tile_m)`` blocks of ``X.T``; None: whichever follows the
-    order the chip stores X in (:func:`by_rows_form`), so that no copy of
-    X stands in front of the kernel.
+    not ``(d, tile_m)`` blocks of ``X.T``; None: whichever :func:`one_read`
+    says follows the order the chip stores X in, so that no copy of X
+    stands in front of the kernel.
     """
     C, d = W.shape
     rows = class_rows_of(C, X.dtype)
@@ -899,7 +940,9 @@ def fused_class_sums(
         raise ValueError(f"{C} class rows: the class kernel takes at most "
                          f"{FM_CLASS_ROWS}; use the XLA path")
     if by_rows is None:
-        by_rows = by_rows_form(*X.shape)
+        own = one_read(*X.shape, jnp.dtype(X.dtype).itemsize,
+                       mask is not None, rows)
+        by_rows = own is not None and own.by_rows
     tile = _fm_tile_of(X, tile_m, mask is not None, interpret, rows, by_rows)
     sums = _fused_rows_class_sums if by_rows else _fused_class_sums
     return sums(rule, X, y, W, mask, rows=rows, tile_m=tile,

@@ -44,7 +44,7 @@ from tpu_sgd.config import SGDConfig
 from tpu_sgd.obs.spans import NO_SPAN, span
 from tpu_sgd.obs.timeseries import observe_scalar
 from tpu_sgd.ops.gradients import (Gradient, LeastSquaresGradient,
-                                   RowDraw, by_rows)
+                                   RowDraw, step_sums, window_rows)
 from tpu_sgd.ops.gram import DEFAULT_BLOCK_ROWS
 from tpu_sgd.ops.sparse import is_sparse
 from tpu_sgd.ops.updaters import SimpleUpdater, Updater
@@ -457,98 +457,34 @@ def _make_mask(gradient, cfg: SGDConfig, key, i, X, y, weights, valid,
                axis_name, model_axis_name, shard_index=None):
     """Per-iteration Bernoulli mini-batch mask (None = take everything):
     the array, or where the gradient's kernel draws its rows itself
-    (``Gradient.draws_rows``) the ``RowDraw`` that stands for the same
+    (``step_sums``' ``mask_in_kernel``) the ``RowDraw`` that stands for the same
     array: the same rows, every step, every shard."""
     if cfg.mini_batch_fraction >= 1.0:
         return valid
     with jax.named_scope("sgd.sample"):
         k = _sample_key(key, i, axis_name, shard_index)
     draw = RowDraw(k, valid, cfg.mini_batch_fraction)
-    if gradient.draws_rows(X, y, weights, valid, model_axis_name):
+    if step_sums(gradient, cfg, X, y, weights, valid,
+                 model_axis_name).mask_in_kernel:
         return draw
     return draw.mask(X.shape[0])
-
-
-def _window_rows(cfg: SGDConfig, n_rows: int) -> int:
-    """Rows of a sliced or indexed mini-batch over ``n_rows`` rows."""
-    return max(1, round(cfg.mini_batch_fraction * n_rows))
-
-
-def mask_in_kernel(gradient, cfg: SGDConfig, X, y, weights, valid=None,
-                   model_axis_name=None) -> bool:
-    """Whether every step of a fit over these operands (a shard's, under a
-    mesh) has the one-read kernel draw its Bernoulli mask
-    (``_make_mask``): from shapes and types alone, so ``train.run``'s
-    ``mask_in_kernel`` asks it on the host."""
-    return (cfg.mini_batch_fraction < 1.0 and cfg.sampling == "bernoulli"
-            and gradient.draws_rows(X, y, weights, valid, model_axis_name))
-
-
-def _invariant_rows(gradient, cfg: SGDConfig, X, y, weights, valid,
-                    model_axis_name):
-    """``(mask, window, drawn)``: what of a fit's row operands every step's
-    sums are handed as it stands besides the labels: ``valid``, unless the
-    Bernoulli draw folds it into a mask made anew each step (``drawn``;
-    where the kernel draws, ``mask_in_kernel``, ``valid`` stays an operand
-    of its own and nothing is made), and, under ``sampling="sliced"``, a
-    window of ``window`` rows (else None).  None where the step gathers its
-    rows (``"indexed"``) and hands nothing on as it stands."""
-    if cfg.mini_batch_fraction >= 1.0:
-        return valid, None, False
-    if cfg.sampling == "sliced":
-        return valid, _window_rows(cfg, X.shape[0]), False
-    if cfg.sampling == "indexed":
-        return None
-    if mask_in_kernel(gradient, cfg, X, y, weights, valid, model_axis_name):
-        return valid, None, False
-    return None, None, True
-
-
-def rows_prepared(gradient, cfg: SGDConfig, X, y, weights, valid=None,
-                  model_axis_name=None) -> bool:
-    """Whether ``make_run``'s fit over these operands (a shard's, under a
-    mesh) lays its labels out before its loop: from shapes and types alone,
-    so ``train.run``'s ``labels_prepared`` asks it on the host."""
-    plan = _invariant_rows(gradient, cfg, X, y, weights, valid,
-                           model_axis_name)
-    return plan is not None and gradient.prepares_rows(
-        X, y, weights, plan[0], model_axis_name, plan[1])
-
-
-def step_blocks(gradient, cfg: SGDConfig, X, y, weights, valid=None,
-                model_axis_name=None):
-    """``(row tile, feature blocks)`` of the one-read kernel that every
-    step of ``make_run``'s fit over these operands takes on a TPU
-    (``Gradient.kernel_blocks`` of what the step hands its sums: the mask
-    it draws as an array, the window it slices); ``(0, 1)`` where the step
-    is no kernel.  From shapes and types alone: ``train.run``'s
-    ``row_tile`` and ``feature_blocks`` ask it on the host."""
-    plan = _invariant_rows(gradient, cfg, X, y, weights, valid,
-                           model_axis_name)
-    if plan is None:
-        return 0, 1
-    mask, window, drawn = plan
-    if drawn:
-        mask = jax.ShapeDtypeStruct((X.shape[0],), bool)  # the step's draw
-    return gradient.kernel_blocks(X, y, weights, mask, model_axis_name,
-                                  window)
 
 
 def prepare_rows(gradient, cfg: SGDConfig, X, y, weights, valid=None,
                  model_axis_name=None):
     """The step kernel's loop-invariant row operands, laid out once a fit
-    (``Gradient.row_operands``) under the scope ``sgd.prepare``; None where
-    the step takes ``y`` as it is.  For the caller of a loop over
-    ``make_step``'s step to call in FRONT of the loop and hand every step
-    as ``rows``: inside it the compiler may leave the relayout where the
-    source put it, every iteration (PERF.md, PR 33)."""
-    plan = _invariant_rows(gradient, cfg, X, y, weights, valid,
-                           model_axis_name)
-    if plan is None:
+    (``Gradient.row_operands`` of what ``step_sums`` says every step hands
+    on as it stands) under the scope ``sgd.prepare``; None where the step
+    takes ``y`` as it is.  For the caller of a loop over ``make_step``'s
+    step to call in FRONT of the loop and hand every step as ``rows``:
+    inside it the compiler may leave the relayout where the source put it,
+    every iteration (PERF.md, PR 33)."""
+    plan = step_sums(gradient, cfg, X, y, weights, valid, model_axis_name)
+    if plan.kernel is None:
         return None
     with jax.named_scope("sgd.prepare"):
-        return gradient.row_operands(X, y, weights, plan[0],
-                                     model_axis_name, plan[1])
+        return gradient.row_operands(X, y, weights, plan.mask,
+                                     model_axis_name, plan.window)
 
 
 def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
@@ -569,7 +505,7 @@ def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
         # gradient that prepares none is called as it always was
         made = {} if rows is None else {"rows": rows}
         if sliced or indexed:
-            m = _window_rows(cfg, X.shape[0])
+            m = window_rows(cfg, X.shape[0])
         if sliced:
             # HBM-optimal path: a contiguous row window at a random offset,
             # read in place instead of a random gather: once, by the
@@ -586,9 +522,10 @@ def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
                 margin_axis_name=model_axis_name, **made,
             )
         if indexed:
-            # TPU fast path: gather a fixed-size batch (with replacement)
-            # instead of masking the whole dataset — touches only ``frac``
-            # of HBM per iteration.
+            # a fixed-size batch gathered with replacement.  On a resident
+            # X slower than the masked scan at both layouts (all of X
+            # copied first at d = 1000; 7.709 against 5.831 ms by rows:
+            # ``SGDConfig.sampling``)
             with jax.named_scope("sgd.sample"):
                 k = _sample_key(key, i, axis_name, shard_index)
                 idx = jax.random.randint(k, (m,), 0, X.shape[0])
@@ -1298,6 +1235,8 @@ class GradientDescent(Optimizer):
     data-parallel shard_map body with ICI all-reduce.
     """
 
+    planned_by = "plan_for"
+
     def __init__(
         self,
         gradient: Gradient = None,
@@ -1406,9 +1345,11 @@ class GradientDescent(Optimizer):
         return self
 
     def set_sampling(self, mode: str):
-        """'bernoulli' (reference parity), 'indexed' (gathered fast path) or
-        'sliced' (contiguous-window fast path — HBM-optimal; assumes
-        exchangeable row order, see ``SGDConfig.sampling``)."""
+        """'bernoulli' (reference parity), 'indexed' (a fixed-size gather
+        with replacement: sound host-streamed, on a resident X slower than
+        the masked scan) or 'sliced' (contiguous-window fast path —
+        HBM-optimal; assumes exchangeable row order); see
+        ``SGDConfig.sampling``."""
         self.config = self.config.replace(sampling=mode)
         return self
 
@@ -2155,15 +2096,14 @@ class GradientDescent(Optimizer):
         """``train.run``'s ``(labels_prepared, row_tile, feature_blocks,
         mask_in_kernel, by_rows)`` for the fit ``_runner``'s program is about
         to make of these arguments (a shard's operands under a mesh), on a
-        TPU: ``labels_prepared`` 1 where it lays the labels out once,
-        before its loop, for the one-read kernel (``rows_prepared``),
-        ``row_tile`` the rows a grid step of the step's kernel takes and
-        ``feature_blocks`` the blocks its body cuts the width into
-        (``step_blocks``), ``mask_in_kernel`` 1 where that kernel draws
-        every step's Bernoulli mask itself (0 where the step is handed an
-        array or draws nothing), ``by_rows`` 1 where that kernel is the
-        by-rows form (row blocks of an X the chip stores by rows:
-        ``ops.gradients.by_rows``).  ``(0, 0, 1, 0, 0)`` where the step
+        TPU, read off ``ops.gradients.step_sums``' record of the step's
+        kernel: ``labels_prepared`` 1 where there is one (the fit then lays
+        the labels out once, before its loop), ``row_tile`` the rows a grid
+        step of it takes and ``feature_blocks`` the blocks its body cuts the
+        width into, ``mask_in_kernel`` 1 where it draws every step's
+        Bernoulli mask itself (0 where the step is handed an array or draws
+        nothing), ``by_rows`` 1 where it is the by-rows form (row blocks of
+        an X the chip stores by rows).  ``(0, 0, 1, 0, 0)`` where the step
         takes ``y`` as it is and is no kernel (two reads; statistics; a
         CPU, whose program drops the row nothing reads)."""
         if jax.default_backend() != "tpu":
@@ -2176,10 +2116,12 @@ class GradientDescent(Optimizer):
                     (a.shape[0] // shards,) + tuple(a.shape[1:]), a.dtype)
 
             X, y, valid = shard(X), shard(y), shard(valid)
-        args = (self.gradient, self.config, X, y, w0, valid)
-        blocks = step_blocks(*args)
-        return (int(rows_prepared(*args)), *blocks,
-                int(mask_in_kernel(*args)), int(blocks[0] > 0 and by_rows(X)))
+        plan = step_sums(self.gradient, self.config, X, y, w0, valid)
+        kernel = plan.kernel
+        if kernel is None:
+            return 0, 0, 1, 0, 0
+        return (1, kernel.tile, kernel.feature_blocks, int(plan.mask_in_kernel),
+                int(kernel.by_rows))
 
     def _place(self, X, y, valid=None):
         """``shard_dataset`` for this fit's mesh under the ``train.place``

@@ -317,6 +317,8 @@ def _two_loop(g, s_stack, y_stack, rho, k):
 class LBFGS(Optimizer):
     """Limited-memory BFGS with backtracking Armijo line search."""
 
+    planned_by = "plan_quasi_newton"
+
     def __init__(
         self,
         gradient: Gradient = None,

@@ -20,5 +20,10 @@ Dataset = Tuple[Array, Array]  # (X: (n, d), y: (n,))
 class Optimizer:
     """Anything that maps ``(data, initial_weights) -> weights``."""
 
+    #: the function of ``tpu_sgd.plan`` that plans this optimizer's
+    #: schedule, by name (the planner lies below ``optimize/`` and imports
+    #: none of it); None: nothing plans it
+    planned_by = None
+
     def optimize(self, data: Dataset, initial_weights: Array) -> Array:
         raise NotImplementedError

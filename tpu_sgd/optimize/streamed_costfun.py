@@ -46,13 +46,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_sgd.plan import default_stream_batch_rows
+
 Array = jax.Array
-
-#: default host→device chunk budget in bytes (~256 MB keeps two in-flight
-#: buffers ~0.5 GB beside the model state; the planner overrides per the
-#: probed HBM budget)
-_DEFAULT_CHUNK_BYTES = 256e6
-
 
 def mesh_spans_processes(mesh) -> bool:
     """True when ``mesh`` contains devices of OTHER processes — the
@@ -65,16 +61,6 @@ def mesh_spans_processes(mesh) -> bool:
 
     pid = jax.process_index()
     return any(d.process_index != pid for d in mesh.devices.flat)
-
-
-def default_stream_batch_rows(d: int, itemsize: int,
-                              chunk_bytes: Optional[float] = None) -> int:
-    """Rows per streamed chunk at a byte budget (default ~256 MB) —
-    THE chunk-sizing policy, shared with ``plan_quasi_newton`` so the
-    planner's estimate and the evaluator's default cannot drift."""
-    if chunk_bytes is None:
-        chunk_bytes = _DEFAULT_CHUNK_BYTES
-    return max(1024, int(chunk_bytes // max(1, d * itemsize)))
 
 
 @_lru_cache(maxsize=64)

@@ -79,6 +79,8 @@ import math
 import warnings
 from typing import Optional
 
+from tpu_sgd.ops.gradients import step_sums
+
 logger = logging.getLogger("tpu_sgd.plan")
 
 #: the five schedules `plan` chooses among (resident_gram covers both the
@@ -995,8 +997,8 @@ def plan(
       latency stay within one checkpoint interval.
     * ``stock_reads`` — how often a stock iteration reads its sampled
       rows: 1 where the step is the one-read kernel (a TPU, a layout it
-      takes: :func:`plan_for` asks ``step_blocks``), 2 where it is two
-      matvecs.
+      takes: :func:`plan_for` asks ``ops.gradients.step_sums``), 2
+      where it is two matvecs.
 
     Least squares on a full batch, resident on ONE device, is planned in
     the statistics' TOTALS form (``estimates["stats_form"]``): one read of
@@ -1392,22 +1394,37 @@ def _stock_reads(optimizer, n_local: int, d: int, dtype) -> int:
     """How often a stock iteration of ``optimizer`` over a device's
     ``(n_local, d)`` rows of ``dtype`` reads what it samples: once where its
     step is the one-read kernel (a TPU, and a layout, width and sampling
-    the kernel takes: ``step_blocks``, from shapes and types alone), twice
+    the kernel takes: ``step_sums``, from shapes and types alone), twice
     where it is two matvecs."""
     import jax
     import jax.numpy as jnp
 
     if jax.default_backend() != "tpu":
         return 2
-    from tpu_sgd.optimize.gradient_descent import step_blocks
-
     shape = jax.ShapeDtypeStruct
     gradient = optimizer.gradient
-    tile, _ = step_blocks(
+    step = step_sums(
         gradient, optimizer.config, shape((n_local, d), dtype),
         shape((n_local,), jnp.float32),
         shape((gradient.weight_dim(d),), jnp.float32))
-    return 1 if tile else 2
+    return 1 if step.kernel is not None else 2
+
+
+#: default host→device chunk budget in bytes (~256 MB keeps two in-flight
+#: buffers ~0.5 GB beside the model state; the planner overrides per the
+#: probed HBM budget)
+_DEFAULT_CHUNK_BYTES = 256e6
+
+
+def default_stream_batch_rows(d: int, itemsize: int,
+                              chunk_bytes: Optional[float] = None) -> int:
+    """Rows per streamed chunk at a byte budget (default ~256 MB) —
+    THE chunk-sizing policy, shared by ``plan_quasi_newton`` and the
+    streamed evaluator (``optimize/streamed_costfun.py``) so the planner's
+    estimate and the evaluator's default cannot drift."""
+    if chunk_bytes is None:
+        chunk_bytes = _DEFAULT_CHUNK_BYTES
+    return max(1024, int(chunk_bytes // max(1, d * itemsize)))
 
 
 #: schedules a quasi-Newton optimizer can be forced onto
@@ -1449,10 +1466,9 @@ def plan_quasi_newton(optimizer, X, y,
     from tpu_sgd.ops.gradients import LeastSquaresGradient
     from tpu_sgd.ops.gram import DEFAULT_BLOCK_ROWS, GramData
     from tpu_sgd.ops.sparse import is_sparse
-    from tpu_sgd.optimize.lbfgs import LBFGS
 
-    if (not isinstance(optimizer, LBFGS) or is_sparse(X)
-            or isinstance(X, GramData)):
+    if (getattr(optimizer, "planned_by", None) != "plan_quasi_newton"
+            or is_sparse(X) or isinstance(X, GramData)):
         return None
     if force is not None and force not in QN_SCHEDULES:
         raise ValueError(
@@ -1520,11 +1536,7 @@ def plan_quasi_newton(optimizer, X, y,
             )
         else:
             # chunk sized so two in-flight buffers use <= half the
-            # budget (the policy function is the evaluator's own)
-            from tpu_sgd.optimize.streamed_costfun import (
-                default_stream_batch_rows,
-            )
-
+            # budget (the policy function is the evaluator's own too)
             # per-DEVICE budget: the evaluator shards each chunk
             # n_devices ways, so the global chunk scales with the mesh
             batch_rows = default_stream_batch_rows(
@@ -1653,9 +1665,8 @@ def plan_for(optimizer, X, y, cost_model: Optional[CostModel] = None,
 
     from tpu_sgd.ops.gradients import LeastSquaresGradient
     from tpu_sgd.ops.sparse import is_sparse
-    from tpu_sgd.optimize.gradient_descent import GradientDescent
 
-    if not isinstance(optimizer, GradientDescent) or is_sparse(X):
+    if getattr(optimizer, "planned_by", None) != "plan_for" or is_sparse(X):
         return None
     from tpu_sgd.ops.gram import GramData
 
