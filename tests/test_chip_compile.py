@@ -1439,6 +1439,93 @@ def test_the_by_rows_entries_refuse_a_width_no_tile_fits_before_compiling(S):
                          S((n, d), BF16), S((n,), F32), S((9, d), F32))
 
 
+# -- past 128 class rows: ImageNet's thousand classes (PR 48) ------------------
+
+#: the cell's rows, width and classes (bench/configs/
+#: imagenet1k-r50-multinomial.json)
+IMAGENET = (1_281_167, 2048, 1000)
+
+
+def test_the_thousand_class_run_at_the_cells_shape_reads_x_once_where_it_lies(
+        S):
+    """``imagenet1k-r50-multinomial.resident-classes``: 1,281,167 x 2,048
+    bf16 rows under a ``(999, 2048)`` matrix of weights at fraction 1.0.
+    ONE Mosaic call a step in the by-rows form's jitted function under
+    ``sgd.class_sums``, all 1,008 padded class rows held at once (the
+    weights in X's type, the gradient's sums in f32), X handed over as the
+    parameter lies, and NO array of a row's class count in HBM: the two
+    matmuls held ``f32[1281167,999]`` margins and coefficients, 5.12 GB
+    each, between them."""
+    import re
+
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    n, d, K = IMAGENET
+    cfg = _cfg(step_size=1.0, num_iterations=100, reg_param=0.001,
+               convergence_tol=0.0, mini_batch_fraction=1.0)
+    compiled = jax.jit(make_run(MultinomialLogisticGradient(K),
+                                SquaredL2Updater(), cfg)).lower(
+        S(((K - 1) * d,), F32), S((n, d), BF16), S((n,), F32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "sgd.class_sums" in call and "_fused_rows_class_sums" in call
+    assert "operand_layout_constraints={bf16[%d,%d]{1,0}, " % (n, d) in call
+    assert "bf16[1008,%d]" % d in call and "f32[1008,%d]" % d in call
+    assert _moves_of(text, n, d) == []
+    assert "bf16[%d,%d]" % (d, n) not in text
+    rest = re.sub(r"\w+\[1,%d\]" % n, "",
+                  text.replace("bf16[%d,%d]" % (n, d), ""))
+    assert not re.search(r"\[%d,\d+\]|\[\d+,%d\]" % (n, n), rest)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < n * d * 2 // 100
+    assert memory.argument_size_in_bytes < n * d * 2 * 1.01
+
+
+#: (rows, width, by rows): the cell's shape, and a thousand classes over
+#: rows the chip stores feature-major (the north star's width)
+ROWS_1008 = {"imagenet_by_rows": (IMAGENET[0], 2048, True),
+             "feature_major_1000": (KERNEL_N, 1000, False)}
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("case", sorted(ROWS_1008))
+def test_the_vmem_count_at_1008_class_rows_admits_what_the_compiler_admits(
+        S, case, masked):
+    """``_fm_vmem_bytes`` with 1,008 class rows stays above the compiler's
+    own count in both orientations: the record's tile compiles when the
+    compiler is asked for exactly what it was counted at, and a tile the
+    count refuses under the wide form's limit is refused by the compiler
+    too."""
+    from tpu_sgd.ops import pallas_kernels as PK
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+
+    n, d, by_rows = ROWS_1008[case]
+    rows = PK.class_rows_of(999, BF16)
+    own = PK.one_read(n, d, 2, masked, rows)
+    assert (own.body, own.by_rows, own.class_rows, own.vmem_limit) == (
+        "class", by_rows, 1008, PK._FM_WIDE_VMEM_LIMIT)
+    counted = PK._fm_vmem_bytes(own.tile, d, 2, masked, rows,
+                                by_rows=by_rows)
+    assert counted <= own.vmem_limit
+    rule = MultinomialLogisticGradient(1000).class_rule
+    args = [S((n, d), BF16), S((n,), F32), S((rows, d), BF16)]
+    if masked:
+        args.append(S((n,), jnp.bool_))
+
+    def lower(tile, limit):
+        return jax.jit(lambda X, y, W, m=None: PK._class_call(
+            rule, X, y, W, m, tile, d, limit, False, by_rows)).lower(*args)
+
+    assert "tpu_custom_call" in lower(own.tile, counted).compile().as_text()
+    with pytest.raises(ValueError, match=r"tile_m <= \d+"):
+        PK._check_fm_vmem(32768, args[0], masked, rows, by_rows=by_rows)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        lower(32768, PK._FM_WIDE_VMEM_LIMIT).compile()
+
+
 def test_a_by_rows_shard_of_a_meshed_fit_takes_the_kernel_where_it_lies(
         mesh4, S):
     """A shard of a by-rows X is by rows: ``dp_run_fn`` at 4 x 524,288 x

@@ -171,7 +171,7 @@ def _case(name):
     if name == "a_vector_of_d_weights":
         return X, y, jnp.zeros((d,)), mask, K
     if name == "more_rows_than_one_pass":
-        K = 200  # 199 rows pad to 208, over the matrix unit's 128
+        K = 200  # 199 rows pad to 208, over the matrix unit's 128 (PR 48)
     elif name == "row_major_width":
         d = 1024  # stored by rows: the by-rows form's (PR 39)
         X = jnp.zeros((n, d), jnp.bfloat16)
@@ -185,9 +185,9 @@ def _case(name):
     return X, y, jnp.zeros(((K - 1) * d,)), mask, K
 
 
-ON = ["ten_classes", "two_classes", "row_major_width"]
-OFF = ["a_vector_of_d_weights", "more_rows_than_one_pass",
-       "row_major_odd_width"]
+ON = ["ten_classes", "two_classes", "row_major_width",
+      "more_rows_than_one_pass"]
+OFF = ["a_vector_of_d_weights", "row_major_odd_width"]
 
 
 @pytest.mark.parametrize("case", ON + OFF)
@@ -267,11 +267,20 @@ def test_window_sums_keeps_the_slice_and_two_matmuls_on_a_tpu():
     assert re.search(r"sgd\.class_sums/[^\"]*sgd\.margins", tpu)
 
 
-def test_the_class_kernel_refuses_more_rows_than_one_pass():
-    X, y, w, _, K = _case("more_rows_than_one_pass")
+def test_the_class_kernel_refuses_a_matrix_no_vmem_holds_with_the_count():
+    """More rows than one pass of the matrix unit are held whole under the
+    wide form's limit (PR 48; ``tests/test_class_rows.py``); what is
+    refused, before any compile, is a matrix whose weights and sums fit no
+    VMEM beside one lane group of rows: 8,000 class rows of 784 features
+    are 2 x 8,000 x 896 x 6 bytes."""
+    import jax.numpy as jnp
+
+    X, y, _, _, _ = _case("ten_classes")
+    K = 8001
     g = MultinomialLogisticGradient(K)
-    with pytest.raises(ValueError, match="at most 128"):
-        fused_class_sums(g.class_rule, X, y, w.reshape(K - 1, -1))
+    assert one_read_of(X, y, jnp.zeros(((K - 1) * 784,)), classes=K) is None
+    with pytest.raises(ValueError, match="8000 class rows of d=784 weights and sums"):
+        fused_class_sums(g.class_rule, X, y, jnp.zeros((K - 1, 784)))
 
 
 # -- through the optimizer -------------------------------------------------

@@ -4,7 +4,10 @@ over the nine cells' per-device shapes and the edge shapes that the
 predicates it replaced (three in ``ops/gradients.py``, four methods of
 ``Gradient``, four askers in ``optimize/gradient_descent.py``) each had a
 test for.  The expected rows were written by the PARENT's functions
-(c3a93dd) from these same case descriptions, not by the code under test.
+(c3a93dd) from these same case descriptions, not by the code under test;
+PR 48 took the class body past 128 class rows, so ``class_rows_over_128``
+answers a record where that parent answered None, and the tenth cell's row
+is new (``tests/test_class_rows.py`` holds the table by class rows).
 And the arrows between the layers that ask: ``ops`` <- ``plan`` <-
 ``optimize``, one way."""
 
@@ -38,6 +41,8 @@ CASES = {
         n=2_097_152, d=1000, gradient="statistics", fraction=1.0),
     "dense1000-lsq-dp4-run.padded-shard": dict(
         n=2_500_000, d=1000, gradient="least_squares", valid=True),
+    "imagenet1k-r50-multinomial.resident-classes": dict(
+        n=1_281_167, d=2048, gradient=1000, fraction=1.0),
     # the edges
     "by_rows_no_lane_multiple": dict(n=2**20, d=1020),
     "by_rows_11648_bf16": dict(n=2**16, d=11_648, fraction=1.0),
@@ -107,6 +112,9 @@ EXPECT = {
     "dense1000-lsq-dp4-run.padded-shard": (
         ("scan", False, 2048, 1000, 1, 32, "sgd.fused_sums", True),
         (1, 2048, 1, 1, 0), False),
+    "imagenet1k-r50-multinomial.resident-classes": (
+        ("class", True, 2048, 2048, 1, 100, "sgd.class_sums", False),
+        (1, 2048, 1, 0, 1), False),
     "by_rows_no_lane_multiple": (
         None,
         (0, 0, 1, 0, 0), True),
@@ -162,8 +170,8 @@ EXPECT = {
         ("class", False, 2048, 784, 1, 32, "sgd.class_sums", False),
         (1, 2048, 1, 0, 0), False),
     "class_rows_over_128": (
-        None,
-        (0, 0, 1, 0, 0), False),
+        ("class", False, 2048, 784, 1, 100, "sgd.class_sums", False),
+        (1, 2048, 1, 0, 0), False),
     "bcoo": (
         None,
         (0, 0, 1, 0, 0), True),

@@ -133,6 +133,31 @@ X whose d is no multiple of 128 (padded lanes) or too wide for one lane
 group of rows stays two reads.  :func:`one_read` selects it from the
 operands as it selects the others.
 
+**Past 128 class rows (PR 48).**  A thousand classes (ImageNet-1k's linear
+evaluation: 999 class rows pad to 1,008) turn the class kernel over: the
+two products are 1.05e13 operations a step over 1,281,167 x 2,048 rows,
+53.2 ms at the chip's 197 TFLOP/s, and the copy of X (6.4 ms) now hides
+under THEM.  The body is the one above, unchanged, with ALL class rows held
+at once: the ``(1008, 2048)`` weights in X's type (4.13 MB) and the
+gradient's sums in f32 (8.26 MB), two buffers each, under the wide form's
+VMEM limit (:func:`_fm_class_limit`); a product is issued with 1,008 rows
+against each ``128 x 128`` piece of X the matrix unit holds, where ten
+classes issue 16; and a pass of the body takes 256 lanes
+(:func:`_fm_lane_cap`), so that the ``(1008, lanes)`` f32 margins,
+exponentials and coefficients of the rule are a megabyte each and every
+class row's margin
+of a lane is there before any coefficient (the pivot softmax needs the
+sum over all of them: no class is left out and nothing is approximate).
+On the chip a call alone reads 59.28 ms at a row tile of 2,048 and 256
+lanes a pass (176.9 TFLOP/s, 89.8% of the peak), 59.33 at a tile of 1,024,
+59.75 at 512 lanes, 67.6 at 1,024 lanes, 61.4 at 128; with the lane chunks
+unrolled in place of the loop 67.8 to 77.9; with the two products and no
+softmax 55.72 (188 TFLOP/s), so the rule costs 3.6 ms of a step; in a fit
+58.89 ms a step, 90.3% of the roofline, against the two matmuls' 64.31
+(PERF.md, PR 48).  :func:`one_read` answers
+for as many class rows as fit that limit beside one lane group of rows
+(3,696 at 2,048 features); more are two reads.
+
 **What the old verdict rested on.**  A second family, window kernels over
 ``(tile, d)`` ROW blocks at a scalar-prefetched row offset, ran 3.1-3.4 ms an
 iteration against XLA's 1.64 on a 3M x 1000 bf16 window (round 2, TPU v5
@@ -192,9 +217,21 @@ _FM_WIDE_VMEM_LIMIT = 100 * 1024 * 1024
 #: block's copy with the lanes outside replaced)
 _FM_FEATURE_BLOCK_SHARE = 32
 
-#: class rows one call of the class kernel takes at most: one pass of the
-#: matrix unit's 128 rows (more are the two-read path's; not measured)
+#: class rows one pass of the matrix unit takes.  Up to here a product is
+#: issued with a fraction of the unit's rows and hides under the block's
+#: copy, and the class kernel is fitted under ``_FM_VMEM_LIMIT`` (mnist8m's
+#: and cifar5m's 16).  Past it (ImageNet's 1,008, PR 48) the products bound
+#: the step, the weights and the gradient's sums are megabytes (24.8 MB at
+#: 1,008 x 2,048 with two buffers each), and the kernel is fitted under
+#: ``_FM_WIDE_VMEM_LIMIT``
 FM_CLASS_ROWS = 128
+#: what one ``(class rows, lanes)`` f32 array of the rule between the
+#: products may take: a pass of the class body takes as many lanes as keep
+#: the margins, the exponentials and the coefficients at this each (all
+#: ``_FM_LANE_CHUNK`` up to 256 class rows; 256 lanes at 1,008, where the
+#: chip read 59.3 ms a call against 59.8 at 512 lanes, 61.4 at 128 and 67.6
+#: at 1,024: PERF.md, PR 48)
+_FM_RULE_BYTES = 1 << 20
 
 
 def feature_major(n: int, d: int) -> bool:
@@ -226,10 +263,13 @@ def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool,
     weights and gradient partials, two buffers each, and 1 MB for the
     body's temporaries.  The class kernel (``class_rows`` > 0) holds
     instead the ``(class_rows, d)`` weights in X's type and the gradient
-    in f32 (d pads to whole lane groups), two buffers each, and beside
+    in f32 (d pads to whole lane groups), two buffers each (their blocks
+    never change, and the pipeline double-buffers them all the same: 24.8
+    MB at 1,008 x 2,048), and beside
     the 1 MB one lane chunk of the block in X's type (the cut block's
     copy with the lanes outside replaced) and six ``(class_rows, chunk)``
-    f32 arrays of the rule between the products; where the body cuts the
+    f32 arrays of the rule between the products (the chunk is
+    :func:`_fm_lane_cap`'s for the class rows); where the body cuts the
     width into blocks of ``fblock`` features (the wide form), one such
     block of the lane chunk in place of all d.  ``by_rows``: the class
     kernel over ``(tile, d)`` blocks of X itself, whose d lies along the
@@ -241,7 +281,7 @@ def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool,
                 + 2 * (2 if masked else 1) * SUBLANES * 4)
     if class_rows:
         fixed = (2 * class_rows * _round_up(d, LANES) * (itemsize + 4)
-                 + _FM_LANE_CHUNK * (
+                 + _fm_lane_cap(class_rows) * (
                      _round_up(min(fblock or d, d), pad) * itemsize
                      + 6 * class_rows * 4))
     else:
@@ -266,16 +306,24 @@ def _fm_halved(n: int, need, limit: int) -> Optional[int]:
     return _fm_round(tile, n)
 
 
+def _fm_class_limit(class_rows: int) -> int:
+    """The scoped VMEM the forms that hold all d at once are fitted under
+    and ask for: ``_FM_VMEM_LIMIT``, and the wide form's for a matrix of
+    more class rows than one pass of the matrix unit (``FM_CLASS_ROWS``)."""
+    return (_FM_WIDE_VMEM_LIMIT if class_rows > FM_CLASS_ROWS
+            else _FM_VMEM_LIMIT)
+
+
 def _fm_narrow_tile(n: int, d: int, itemsize: int, masked: bool,
                     class_rows: int = 0, by_rows: bool = False
                     ) -> Optional[int]:
     """The row tile of the forms that hold all d at once under
-    ``_FM_VMEM_LIMIT``: ``_fm_kernel``'s, or the class kernel's (over
+    :func:`_fm_class_limit`: ``_fm_kernel``'s, or the class kernel's (over
     blocks of ``X.T``, or ``by_rows`` of X itself)."""
     return _fm_halved(
         n, lambda t: _fm_vmem_bytes(t, d, itemsize, masked, class_rows,
                                     by_rows=by_rows),
-        _FM_VMEM_LIMIT)
+        _fm_class_limit(class_rows))
 
 
 def by_rows_form(n: int, d: int) -> bool:
@@ -352,6 +400,9 @@ class OneRead:
     #: the body can draw a Bernoulli mask's rows itself (``draw=`` of
     #: :func:`fused_gradient_sums`); elsewhere a mask is a row operand
     draws: bool
+    #: the padded class rows both products are issued with for a MATRIX of
+    #: weights, all held at once (``train.run``'s attribute); 0 a vector
+    class_rows: int
 
 
 @functools.lru_cache(maxsize=256)
@@ -371,7 +422,12 @@ def one_read(n: int, d: int, itemsize: int, masked: bool = True,
     * stored by rows at another width: none (a block of ``X.T`` would be
       handed a copy of all of X);
     * stored feature-major, a matrix of weights: the class body over
-      ``X.T``, at most ``FM_CLASS_ROWS`` rows; no window grid;
+      ``X.T``; no window grid.  In either orientation a matrix of more
+      than ``FM_CLASS_ROWS`` padded rows (1,008 for a thousand classes)
+      is held whole all the same, fitted under ``_FM_WIDE_VMEM_LIMIT``
+      (:func:`_fm_class_limit`) at :func:`_fm_lane_cap`'s lanes a pass of
+      the body (256 at 1,008 rows): every class row's margin of a lane is
+      formed before any coefficient, as the pivot softmax needs;
     * a vector: ``_fm_kernel`` with all d along the lanes, over the full
       scan (the one body that draws a mask's rows, counted in int32) or a
       window; where its ``(d, 128)`` f32 operands do not fit
@@ -382,12 +438,12 @@ def one_read(n: int, d: int, itemsize: int, masked: bool = True,
     The row tile is ``FM_TILE`` halved until the form's VMEM fits; None
     where not even one lane group of rows does."""
     by_rows = by_rows_form(n, d)
-    if (class_rows > FM_CLASS_ROWS or (window and (by_rows or class_rows))
+    if ((window and (by_rows or class_rows))
             or not (by_rows or feature_major(n, d))):
         return None
     body = "class" if by_rows or class_rows else (
         "window" if window else "scan")
-    limit, fblock = _FM_VMEM_LIMIT, d
+    limit, fblock = _fm_class_limit(class_rows), d
     tile = _fm_narrow_tile(
         n, d, itemsize, masked,
         class_rows or (32 // itemsize if by_rows else 0), by_rows)
@@ -400,7 +456,8 @@ def one_read(n: int, d: int, itemsize: int, masked: bool = True,
     scope = ("sgd.class_sums" if class_rows else
              "sgd.wide_sums" if body == "wide" else "sgd.fused_sums")
     return OneRead(body, by_rows, tile, fblock, pl.cdiv(d, fblock), limit,
-                   scope, draws=body == "scan" and n < 2**31)
+                   scope, draws=body == "scan" and n < 2**31,
+                   class_rows=class_rows)
 
 
 def fm_blocks(n: int, d: int, itemsize: int, masked: bool = True,
@@ -414,13 +471,15 @@ def fm_blocks(n: int, d: int, itemsize: int, masked: bool = True,
 
 def _check_fm_vmem(tile: int, X, masked: bool, class_rows: int = 0,
                    fblock: Optional[int] = None,
-                   limit: int = _FM_VMEM_LIMIT, by_rows: bool = False
+                   limit: Optional[int] = None, by_rows: bool = False
                    ) -> None:
     """Reject a tile the chip's compiler would refuse with an error that
     names the largest one it admits (or says that not even one lane group
     fits), instead of a Mosaic compile-time OOM."""
     d = X.shape[1]
     itemsize = jnp.dtype(X.dtype).itemsize
+    if limit is None:
+        limit = _fm_class_limit(class_rows)
     count = functools.partial(_fm_vmem_bytes, d=d, itemsize=itemsize,
                               masked=masked, class_rows=class_rows,
                               fblock=fblock, by_rows=by_rows)
@@ -430,13 +489,17 @@ def _check_fm_vmem(tile: int, X, masked: bool, class_rows: int = 0,
     fixed = count(0)
     per_lane = count(1) - fixed
     max_tile = (limit - fixed) // per_lane // LANES * LANES
-    hint = (
-        f"use tile_m <= {max_tile}"
-        if max_tile >= LANES
-        else f"feature dim d={d} is too wide for this kernel at any "
-        "tile size; Gradient.batch_sums takes such rows in the wide form "
-        "(fused_wide_sums) or, wider still, in two reads"
-    )
+    if max_tile >= LANES:
+        hint = f"use tile_m <= {max_tile}"
+    elif class_rows > FM_CLASS_ROWS:
+        hint = (f"{class_rows} class rows of d={d} weights and sums fit "
+                "beside no tile of rows; "
+                "MultinomialLogisticGradient.batch_sums takes such a "
+                "matrix in two reads")
+    else:
+        hint = (f"feature dim d={d} is too wide for this kernel at any "
+                "tile size; Gradient.batch_sums takes such rows in the "
+                "wide form (fused_wide_sums) or, wider still, in two reads")
     raise ValueError(
         f"tile_m={tile} with d={d} {jnp.dtype(X.dtype).name} needs "
         f"~{need / 2**20:.1f} MB of scoped VMEM, over the "
@@ -445,11 +508,21 @@ def _check_fm_vmem(tile: int, X, masked: bool, class_rows: int = 0,
     )
 
 
-def _fm_lane_chunk(tile: int) -> int:
+def _fm_lane_cap(class_rows: int = 0) -> int:
+    """The most lanes one pass of a body takes: ``_FM_LANE_CHUNK``, cut to
+    the whole lane groups (one at least) at which a ``(class_rows, lanes)``
+    f32 array of the class body's rule stays within ``_FM_RULE_BYTES``."""
+    if not class_rows:
+        return _FM_LANE_CHUNK
+    lanes = _FM_RULE_BYTES // (4 * class_rows) // LANES * LANES
+    return min(_FM_LANE_CHUNK, max(LANES, lanes))
+
+
+def _fm_lane_chunk(tile: int, class_rows: int = 0) -> int:
     """Lanes one pass of the body takes: the most whole lane groups, up to
-    ``_FM_LANE_CHUNK``, that divide the tile."""
+    :func:`_fm_lane_cap`, that divide the tile."""
     groups = tile // LANES
-    most = _FM_LANE_CHUNK // LANES
+    most = _fm_lane_cap(class_rows) // LANES
     return LANES * max(j for j in range(1, most + 1) if groups % j == 0)
 
 
@@ -845,7 +918,7 @@ def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs):
     i = pl.program_id(0)
     tile, d = x_ref.shape if by_rows else x_ref.shape[::-1]
     f32 = jnp.float32
-    lw = _fm_lane_chunk(tile)
+    lw = _fm_lane_chunk(tile, w_ref.shape[0])
     blocks = [(r0, min(fblock, d - r0)) for r0 in range(0, d, fblock)]
     features = int(by_rows)  # the axis of a block that holds d
 
@@ -927,7 +1000,11 @@ def fused_class_sums(
     ``W`` past ``C`` are zero, and the rule has to give them a coefficient
     of zero.  Grid, tile and tail cut are :func:`fused_gradient_sums`';
     the products run on the matrix unit in ``X``'s type (``W`` and the
-    coefficients are rounded to it) with f32 sums.
+    coefficients are rounded to it) with f32 sums.  All class rows are
+    held at once, however many (:func:`_fm_class_limit`: past
+    ``FM_CLASS_ROWS`` under the wide form's VMEM limit); a matrix whose
+    weights and sums fit no VMEM beside one lane group of rows is refused
+    with the count (``Gradient.batch_sums`` takes two reads there).
 
     ``by_rows``: the blocks are ``(tile_m, d)`` row blocks of X itself and
     not ``(d, tile_m)`` blocks of ``X.T``; None: whichever :func:`one_read`
@@ -936,9 +1013,6 @@ def fused_class_sums(
     """
     C, d = W.shape
     rows = class_rows_of(C, X.dtype)
-    if rows > FM_CLASS_ROWS:
-        raise ValueError(f"{C} class rows: the class kernel takes at most "
-                         f"{FM_CLASS_ROWS}; use the XLA path")
     if by_rows is None:
         own = one_read(*X.shape, jnp.dtype(X.dtype).itemsize,
                        mask is not None, rows)
@@ -986,7 +1060,7 @@ def _class_sums(rule, X, y, W, mask, rows: int, tile: int, interpret: bool,
     C = W.shape[0]
     grad, loss, cnt = _class_call(
         rule, X, y, jnp.pad(W.astype(X.dtype), ((0, rows - C), (0, 0))),
-        mask, tile, d, _FM_VMEM_LIMIT, interpret, by_rows)
+        mask, tile, d, _fm_class_limit(rows), interpret, by_rows)
     count = jnp.sum(cnt) if mask is not None else jnp.asarray(
         n, jnp.float32)
     return grad[:C], jnp.sum(loss), count
