@@ -898,6 +898,70 @@ def test_the_sharded_hand_offs_programs_compile_for_each_chip(
         == [by_features, by_features]
 
 
+def _entry_instructions(text):
+    """``(opcode, result shape, op_name or None)`` of the instructions of a
+    compiled module's entry computation."""
+    import re
+
+    out = []
+    for line in text[text.index("ENTRY"):].split("\n")[1:]:
+        found = re.search(r"= (\(?\S+) ([a-z-]+)\(", line)
+        if found:
+            name = re.search(r'op_name="([^"]*)"', line)
+            out.append((found.group(2), found.group(1),
+                        name and name.group(1)))
+    return out
+
+
+@pytest.mark.parametrize("rows", ["full", "remainder"])
+@pytest.mark.parametrize("form", ["flat", "words"])
+@pytest.mark.parametrize("cell", ["from-host", "from-host-sharded"])
+def test_the_writer_makes_the_chips_layout_of_a_block_as_it_crossed(
+        S, cell, form, rows):
+    """``_stage_block`` on a block in the forms PR 49 hands the runtime, at
+    both from-host cells' destinations (2,145,000 and 2,500,000 x 1000 bf16;
+    a full block of 16,384 rows and the cell's remainder): a C-ordered
+    array's rows as ONE flat run, and a Fortran-ordered array's as
+    ``(rows / 2, 1000)`` 32-bit words, which arrive feature-major in plain
+    4-byte tiles (no 2-byte packing left for the host to do).  The chip
+    re-tiles the block and writes it in place: the donated destination IS
+    the result, the one X-sized array made is the ``dynamic-update-slice``
+    itself, no temporary of even a block's size is left in device memory,
+    and every operation that makes an array carries ``sgd.stage`` (so
+    ``stage_ms`` and ``step_unscoped_share`` read the re-tiling)."""
+    from tpu_sgd.optimize.gradient_descent import (_STAGE_BLOCK_BYTES,
+                                                   _STAGE_ROWS, _stage_block)
+
+    n = {"from-host": CELL_ROWS["from-host"],
+         "from-host-sharded": SHARD_ROWS}[cell]
+    full = _STAGE_BLOCK_BYTES // (2 * D) // _STAGE_ROWS * _STAGE_ROWS
+    block = full if rows == "full" else n % full
+    assert full == 16_384 and 0 < block <= full and not block % 2
+    crossed = (S((block * D,), BF16) if form == "flat"
+               else S((block // 2, D), jnp.uint32))
+    compiled = _stage_block.lower(S((n, D), BF16), crossed,
+                                  S((), I32)).compile()
+    text = compiled.as_text()
+    assert _moves_of(text, n, D) == ["dynamic-update-slice"]
+    assert "sgd.stage/dynamic_update_slice" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= n * D * 2  # rows pad to 128
+    assert memory.temp_size_in_bytes < block * D * 2
+    made = [(op, shape, name) for op, shape, name in _entry_instructions(text)
+            if op not in ("parameter", "constant", "tuple",
+                          "get-tuple-element") and "[]" not in shape]
+    assert made and all(name and "sgd.stage/" in name
+                        for _, _, name in made), made
+    dest, arrived = compiled.input_formats[0][:2]
+    by_features = (1, 0)
+    assert dest.layout.major_to_minor == by_features
+    if form == "words":
+        assert arrived.layout.major_to_minor == by_features
+        assert tuple(arrived.layout.tiling) == ((8, 128),)
+    else:
+        assert arrived.layout.major_to_minor == (0,)
+
+
 def test_a_batch_staged_ahead_is_made_whole_in_one_program(S):
     """``_stage_join`` at the stream cell's shape (a micro-batch of
     2,097,152 x 1000 bf16 as 128 blocks of 16,384 rows): one program whose

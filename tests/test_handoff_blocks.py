@@ -1,10 +1,13 @@
 """A dense host array reaches the device in row blocks written into ONE
-``(N, d)`` array in place (``gradient_descent._stage_dense``): the array is
-``jnp.asarray``'s value for value whatever the rows, the width, the type and
-the host array's order; what is no large numpy array takes the calls it took
-before; the ``train.h2d`` span says how many pieces went; a fit from blocks is
-the fit from one piece bit for bit.  Tiny, CPU, the block cut to a few rows'
-bytes."""
+``(N, d)`` array in place (``gradient_descent._stage_dense``), each block in
+the form that leaves the runtime least to re-tile (PR 49, ``_wire_form``: a
+C-ordered array's rows flat, a Fortran-ordered array's 2-byte items as 32-bit
+words, anything else as strided rows): the array is ``jnp.asarray``'s value
+for value whatever the rows, the width, the type and the host array's order;
+what is no large numpy array takes the calls it took before; the ``train.h2d``
+span says how many pieces went and how many of them flat; a fit from blocks
+is the fit from one piece bit for bit.  Tiny, CPU, the block cut to a few
+rows' bytes."""
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +48,21 @@ def _same(got, X):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+class Told:
+    """A span that is not live and keeps what it is told."""
+    live = False
+
+    def __init__(self):
+        self.said = {}
+
+    def set(self, **stats):
+        self.said.update(stats)
+
+
+def _ordered(X, order):
+    return np.asfortranarray(X) if order == "fortran" else X
+
+
 # -- the array ----------------------------------------------------------------------
 
 #: under one block; one block exactly; an exact multiple; a remainder of one
@@ -54,28 +72,83 @@ ROW_CASES = {"under": ROWS - 1, "one_block": ROWS, "multiple": 3 * ROWS,
              "beyond_in_flight": 5 * ROWS + 7}
 
 
+@pytest.mark.parametrize("order", ["c", "fortran"])
 @pytest.mark.parametrize("d", [1000, 128, 7])
 @pytest.mark.parametrize("case", sorted(ROW_CASES))
-def test_the_staged_array_is_jnp_asarrays(monkeypatch, case, d):
+def test_the_staged_array_is_jnp_asarrays(monkeypatch, case, d, order):
+    """A C-ordered array's blocks cross flat, a Fortran-ordered f32 array's
+    as its strided rows (its items are words already)."""
     n = ROW_CASES[case]
-    X = _host(n, d, np.float32)
-    _blocks_of(monkeypatch, X.strides[0])
-    got, blocks, block_bytes = gd._stage_dense(X, NO_SPAN)
+    X = _ordered(_host(n, d, np.float32), order)
+    _blocks_of(monkeypatch, d * 4)
+    told = Told()
+    got, blocks, block_bytes = gd._stage_dense(X, told)
     _same(got, X)
     assert blocks == max(1, -(-n // ROWS))
     assert block_bytes == (X.nbytes if blocks == 1 else ROWS * d * 4)
+    assert told.said["flat"] == (blocks if order == "c" and blocks > 1 else 0)
 
 
+@pytest.mark.parametrize("d", [1000, 128, 7])
+@pytest.mark.parametrize("case", sorted(ROW_CASES))
+def test_a_fortran_ordered_array_of_two_byte_items_crosses_as_words(
+        monkeypatch, case, d):
+    """Rows ``2k`` and ``2k + 1`` of a column in one 32-bit word, unzipped on
+    the device: ``jnp.asarray``'s bits.  A last block that ends on an odd
+    row goes as its rows; an odd number of rows has no words at all."""
+    n = ROW_CASES[case]
+    X = np.asfortranarray(_host(n, d, ml_dtypes.bfloat16))
+    _blocks_of(monkeypatch, d * 2)
+    told = Told()
+    seen = []
+    real = gd._stage_block
+    monkeypatch.setattr(gd, "_stage_block", lambda dest, block, offset: (
+        seen.append((block.dtype, block.shape)) or real(dest, block, offset)))
+    got, blocks, block_bytes = gd._stage_dense(X, told)
+    _same(got, X)
+    assert blocks == max(1, -(-n // ROWS))
+    if blocks == 1:
+        assert told.said["flat"] == 0 and seen == []
+        return
+    assert block_bytes == ROWS * d * 2
+    words = 0 if n % 2 else blocks
+    assert told.said["flat"] == words
+    assert [k for k, _ in seen] == [jnp.uint32 if words else jnp.bfloat16] \
+        * blocks
+    assert seen[0][1] == ((ROWS // 2, d) if words else (ROWS, d))
+
+
+@pytest.mark.parametrize("order", ["c", "fortran"])
 @pytest.mark.parametrize("dtype", [ml_dtypes.bfloat16, np.float32, np.float64,
-                                   np.int8, np.bool_],
+                                   np.int8, np.bool_, np.float16, np.int16],
                          ids=lambda t: np.dtype(t).name)
-def test_every_type_arrives_as_the_single_copy_brings_it(monkeypatch, dtype):
+def test_every_type_arrives_as_the_single_copy_brings_it(monkeypatch, dtype,
+                                                         order):
     """bf16 and f32 as they are, f64 as ``jnp.asarray`` narrows it, int8 and
-    bool in their own type: the cast to f32 is the caller's, after the copy."""
-    X = _host(2 * ROWS + 5, 16, dtype)
-    _blocks_of(monkeypatch, X.strides[0])
-    got, blocks, _ = gd._stage_dense(X, NO_SPAN)
+    bool in their own type: the cast to f32 is the caller's, after the copy.
+    Every 2-byte type goes through the words when Fortran-ordered."""
+    X = _ordered(_host(2 * ROWS + 6, 16, dtype), order)
+    _blocks_of(monkeypatch, 16 * X.itemsize)
+    told = Told()
+    got, blocks, _ = gd._stage_dense(X, told)
     assert blocks == 3
+    _same(got, X)
+    assert told.said["flat"] == (
+        3 if order == "c" or X.itemsize == 2 else 0)
+
+
+def test_items_off_a_word_boundary_go_as_their_rows(monkeypatch):
+    """Fortran-ordered 2-byte items whose first one is not 4-byte aligned:
+    no words, the strided rows, the right array."""
+    n, d = 4 * ROWS, 8
+    run = np.zeros(n * d + 1, ml_dtypes.bfloat16)[1:]
+    run[:] = _host(n, d, ml_dtypes.bfloat16).T.reshape(-1)
+    X = run.reshape(d, n).T
+    assert X.flags.f_contiguous and X.ctypes.data % 4 == 2
+    _blocks_of(monkeypatch, d * 2)
+    told = Told()
+    got, blocks, _ = gd._stage_dense(X, told)
+    assert blocks == 4 and told.said["flat"] == 0
     _same(got, X)
 
 
@@ -89,8 +162,33 @@ def test_a_host_array_in_another_order_arrives_right(monkeypatch, order):
          "strided_columns": base[:, ::3], "reversed": base[::-1]}[order]
     assert not X.flags.c_contiguous
     _blocks_of(monkeypatch, X.shape[1] * 4)
-    got, blocks, _ = gd._stage_dense(X, NO_SPAN)
+    told = Told()
+    got, blocks, _ = gd._stage_dense(X, told)
     assert blocks == -(-X.shape[0] // ROWS) > 1
+    _same(got, X)
+    # the fallback: neither flat nor words, the strided rows as before
+    assert told.said["flat"] == 0
+
+
+@pytest.mark.parametrize("order", ["strided_rows", "strided_columns",
+                                   "reversed", "wider"])
+def test_a_strided_view_of_two_byte_items_takes_the_fallback(monkeypatch,
+                                                             order):
+    """Neither C- nor Fortran-contiguous: the strided row blocks, ``flat``
+    0, whatever the item size."""
+    base = np.asfortranarray(_host(4 * ROWS + 6, 24, ml_dtypes.bfloat16))
+    X = {"strided_rows": base[::2], "strided_columns": base[:, ::3],
+         "reversed": base[::-1], "wider": base[2:-4]}[order]
+    assert not X.flags.c_contiguous and not X.flags.f_contiguous
+    _blocks_of(monkeypatch, X.shape[1] * 2)
+    told = Told()
+    seen = []
+    real = gd._stage_block
+    monkeypatch.setattr(gd, "_stage_block", lambda dest, block, offset: (
+        seen.append(block.shape) or real(dest, block, offset)))
+    got, blocks, _ = gd._stage_dense(X, told)
+    assert blocks == len(seen) == -(-X.shape[0] // ROWS) > 1
+    assert seen[0] == (ROWS, X.shape[1]) and told.said["flat"] == 0
     _same(got, X)
 
 
@@ -134,11 +232,18 @@ def test_what_is_no_large_numpy_array_takes_the_calls_it_took(monkeypatch):
     assert seen == [] and routed[0] is Xs
 
 
-def test_the_blocks_held_are_bounded_by_the_blocks_in_flight(monkeypatch):
+@pytest.mark.parametrize("form", ["flat", "words", "strided"])
+def test_the_blocks_held_are_bounded_by_the_blocks_in_flight(monkeypatch,
+                                                             form):
     """The host waits for the oldest write before it issues a block beyond
     the bound: at no call of the writer are more than ``_STAGE_IN_FLIGHT``
-    earlier writes not known to be done, and every block is deleted."""
-    X = _host(7 * ROWS, 8, np.float32)
+    earlier writes not known to be done, the pieces they hold are at most
+    ``_STAGE_IN_FLIGHT x _STAGE_BLOCK_BYTES`` bytes in whatever form they
+    crossed, and every block is deleted."""
+    X = {"flat": lambda: _host(7 * ROWS, 8, np.float32),
+         "words": lambda: np.asfortranarray(
+             _host(7 * ROWS, 16, ml_dtypes.bfloat16)),
+         "strided": lambda: _host(7 * ROWS, 16, np.float32)[:, ::2]}[form]()
     _blocks_of(monkeypatch, 32, in_flight=3)
     waited, blocks = [], []
     real = gd._stage_block
@@ -152,17 +257,24 @@ def test_the_blocks_held_are_bounded_by_the_blocks_in_flight(monkeypatch):
             return self.token.block_until_ready()
 
     def write(dest, block, offset):
+        held.append(block.nbytes)
         dest, token = real(dest, block, offset)
         blocks.append(block)
         pending = len(blocks) - len(waited)
         assert pending <= 3
+        # in bytes: the pieces not known to be written, this one among them
+        assert sum(held[len(waited):]) <= 3 * gd._STAGE_BLOCK_BYTES
         return dest, Written(token)
 
+    held = []
     monkeypatch.setattr(gd, "_stage_block", write)
     got, n_blocks, _ = gd._stage_dense(X, NO_SPAN)
     _same(got, X)
     assert n_blocks == len(blocks) == 7 and len(waited) == 7 - 3
     assert all(b.is_deleted() for b in blocks)
+    assert {(b.ndim, b.dtype.name) for b in blocks} == {
+        {"flat": (1, "float32"), "words": (2, "uint32"),
+         "strided": (2, "float32")}[form]}
 
 
 # -- the span ---------------------------------------------------------------------
@@ -205,6 +317,8 @@ def test_train_h2d_says_how_many_pieces_went(monkeypatch):
         X.nbytes + y.nbytes, 4, ROWS * 32)
     assert (device["bytes"], device["blocks"], device["block_bytes"]) == (
         0, 0, 0)
+    # the pieces that crossed flat (PR 49): a C-ordered array's blocks, all
+    assert (one["flat"], many["flat"], device["flat"]) == (0, 4, 0)
     # the stall counter (PR 37): 4 blocks, 2 in flight: the host stood in
     # the flow-control wait twice; one piece and a device array, never
     assert many["stalls"] == 4 - IN_FLIGHT and many["stall_ms"] >= 0
@@ -268,13 +382,23 @@ def test_the_five_parts_are_the_threads_time_in_the_loop(monkeypatch,
     assert record["own_ms"] == (len(readings) - 1 - 3 * blocks - stalls) * 1e3
 
 
+@pytest.mark.parametrize("form", ["flat", "words", "strided"])
 def test_an_untraced_hand_off_reads_no_clock_and_makes_the_same_calls(
-        monkeypatch, no_clock):
+        monkeypatch, no_clock, form):
     """With ``NO_SPAN`` a clock that raises is never read, and the puts,
     the fill, the writes and the deletes come in the order a traced
-    hand-off makes them in."""
-    X = _host(4 * ROWS + 9, 8, np.float32)
-    _blocks_of(monkeypatch, 32)
+    hand-off makes them in: a put a block, of the block in its form."""
+    d = 8
+    X = {"flat": lambda: _host(4 * ROWS + 10, d, np.float32),
+         "words": lambda: np.asfortranarray(
+             _host(4 * ROWS + 10, d, ml_dtypes.bfloat16)),
+         "strided": lambda: _host(4 * ROWS + 10, 2 * d, np.float32)[:, ::2]
+         }[form]()
+    _blocks_of(monkeypatch, d * X.itemsize)
+    # what the runtime is handed for ``rows`` rows
+    shape = {"flat": lambda rows: (rows * d,),
+             "words": lambda rows: (rows // 2, d),
+             "strided": lambda rows: (rows, d)}[form]
     calls = []
     puts, fills, writes = jnp.asarray, gd._stage_dest, gd._stage_block
 
@@ -292,7 +416,7 @@ def test_an_untraced_hand_off_reads_no_clock_and_makes_the_same_calls(
 
         @staticmethod
         def asarray(piece):
-            calls.append(("put", piece.shape[0]))
+            calls.append(("put", piece.shape))
             return Block(puts(piece))
 
     monkeypatch.setattr(gd, "jnp", Jnp())
@@ -308,15 +432,18 @@ def test_an_untraced_hand_off_reads_no_clock_and_makes_the_same_calls(
             traced, _, _ = gd._stage_dense(X, h2d)
     finally:
         disable_tracing()
-    assert sink.h2d()[0]["put_ms"] > 0
+    record, = sink.h2d()
+    assert record["put_ms"] > 0
+    assert record["flat"] == (0 if form == "strided" else 5)
     as_traced, calls[:] = list(calls), []
     monkeypatch.setattr(gd, "time", no_clock)
     got, blocks, _ = gd._stage_dense(X, NO_SPAN)
     assert blocks == 5
-    assert calls == as_traced == [("put", ROWS), "fill", ("write", 0),
+    assert calls == as_traced == [("put", shape(ROWS)), "fill", ("write", 0),
                                   "delete"] + [
         step for k in range(1, 5) for step in (
-            ("put", ROWS if k < 4 else 9), ("write", k * ROWS), "delete")]
+            ("put", shape(ROWS if k < 4 else 10)), ("write", k * ROWS),
+            "delete")]
     np.testing.assert_array_equal(np.asarray(got), X)
     np.testing.assert_array_equal(np.asarray(traced), X)
 
@@ -329,10 +456,13 @@ def test_an_untraced_hand_off_reads_no_clock_and_makes_the_same_calls(
     ("LinearRegressionWithSGD", np.float32),
     ("LinearRegressionWithSGD", np.int8)],
     ids=lambda v: v if isinstance(v, str) else np.dtype(v).name)
+@pytest.mark.parametrize("order", ["c", "fortran"])
 def test_a_fit_from_blocks_is_the_fit_from_one_piece_bit_for_bit(
-        monkeypatch, model, dtype):
+        monkeypatch, model, dtype, order):
+    """Flat pieces (C-ordered), words (Fortran-ordered bf16) and strided
+    rows (Fortran-ordered f32 and int8): the fit from one piece."""
     rng = np.random.default_rng(3)
-    X = _host(3 * ROWS + 100, 12, dtype, seed=3)
+    X = _ordered(_host(3 * ROWS + 100, 12, dtype, seed=3), order)
     margin = X.astype(np.float32) @ rng.uniform(-1, 1, 12).astype(np.float32)
     y = (margin > 0).astype(np.float32) if model.startswith("Logistic") \
         else margin
@@ -342,30 +472,40 @@ def test_a_fit_from_blocks_is_the_fit_from_one_piece_bit_for_bit(
         m = alg.run((X, y))
         return np.asarray(m.weights), np.asarray(alg.optimizer.loss_history)
 
-    staged = []
+    staged, flat = [], []
+
+    def stage(X, h2d):
+        told = Told()
+        staged.append(real(X, told))
+        flat.append(told.said["flat"])
+        return staged[-1]
+
     real = gd._stage_dense
-    monkeypatch.setattr(
-        gd, "_stage_dense",
-        lambda X, h2d: staged.append(real(X, h2d)) or staged[-1])
+    monkeypatch.setattr(gd, "_stage_dense", stage)
     w_one, loss_one = fit()
-    _blocks_of(monkeypatch, X.strides[0])
+    _blocks_of(monkeypatch, 12 * X.itemsize)
     w_blocks, loss_blocks = fit()
     assert [s[1] for s in staged] == [1, 4]
+    assert flat == [0, 4 if order == "c" or X.itemsize == 2 else 0]
     assert len(loss_one) == 10 and np.isfinite(loss_one).all()
     np.testing.assert_array_equal(w_blocks, w_one)
     np.testing.assert_array_equal(loss_blocks, loss_one)
 
 
-def test_a_second_fit_of_the_same_shape_builds_no_program(monkeypatch):
-    X = _host(2 * ROWS + 300, 10, np.float32)
-    y = (X[:, 0] > 0).astype(np.float32)
-    _blocks_of(monkeypatch, X.strides[0])
+@pytest.mark.parametrize("order,dtype", [
+    ("c", np.float32), ("fortran", ml_dtypes.bfloat16),
+    ("fortran", np.float32)], ids=["flat", "words", "strided"])
+def test_a_second_fit_of_the_same_shape_builds_no_program(monkeypatch, order,
+                                                          dtype):
+    X = _ordered(_host(2 * ROWS + 300, 10, dtype), order)
+    y = (np.asarray(X[:, 0], np.float32) > 0).astype(np.float32)
+    _blocks_of(monkeypatch, 10 * X.itemsize)
     alg = tpu_sgd.LogisticRegressionWithSGD(0.1, 4, mini_batch_fraction=0.5)
     alg.run((X, y))
     sizes = gd._stage_block._cache_size(), gd._stage_dest._cache_size()
     runners = len(alg.optimizer._run_cache)
     first = np.asarray(alg.optimizer.loss_history)
-    alg.run((X.copy(), y))
+    alg.run((X.copy(order="K"), y))
     assert (gd._stage_block._cache_size(),
             gd._stage_dest._cache_size()) == sizes
     assert len(alg.optimizer._run_cache) == runners

@@ -1,7 +1,9 @@
 """A dense host array handed to a fit over a 1-D data mesh goes to the chips
 in ``_stage_dense``'s row blocks, each block to the device that owns its rows
 (``gradient_descent._stage_dense`` with a destination a device,
-``parallel.shard_dataset``'s host branch): the sharded array is
+``parallel.shard_dataset``'s host branch) and in the form that leaves the
+runtime least to re-tile (PR 49: flat, 32-bit words, or the strided rows):
+the sharded array is
 ``jax.device_put(X, NamedSharding(mesh, P('data', None)))`` value for value
 whatever the type, the order and the rows, every shard's buffer on its own
 device; no device holds more than its shard and the blocks in flight to it;
@@ -48,6 +50,17 @@ def _host(n, d, dtype, seed=0):
     if np.issubdtype(np.dtype(dtype), np.integer):
         return rng.integers(-3, 4, (n, d)).astype(dtype)
     return rng.normal(size=(n, d)).astype(dtype)
+
+
+class Told:
+    """A span that is not live and keeps what it is told."""
+    live = False
+
+    def __init__(self):
+        self.said = {}
+
+    def set(self, **stats):
+        self.said.update(stats)
 
 
 def _padded(X, shards=SHARDS):
@@ -104,6 +117,7 @@ def test_the_sharded_array_is_device_puts(monkeypatch, mesh, no_clock, case,
         assert block_bytes == ROWS * d * 4
 
 
+@pytest.mark.parametrize("order", ["c", "fortran"])
 @pytest.mark.parametrize("dtype", [ml_dtypes.bfloat16, np.float32, np.float64,
                                    np.int8, np.bool_],
                          ids=lambda t: np.dtype(t).name)
@@ -111,11 +125,61 @@ def test_the_sharded_array_is_device_puts(monkeypatch, mesh, no_clock, case,
                                SHARDS * 8],
                          ids=["blocks", "blocks_padded", "one_piece"])
 def test_every_type_arrives_as_the_plain_placement_brings_it(
-        monkeypatch, mesh, dtype, n):
+        monkeypatch, mesh, dtype, n, order):
     X = _host(n, 16, dtype)
-    _blocks_of(monkeypatch, X.strides[0])
+    if order == "fortran":
+        X = np.asfortranarray(X)
+    _blocks_of(monkeypatch, 16 * X.itemsize)
     got, _, _ = gd._stage_dense(X, mesh=mesh)
     _same(got, X, mesh)
+
+
+#: rows a shard: whole blocks; a short last block; a last shard short of two
+#: rows (its rows end on an even row); an odd shard (shards 1 and 3 start on
+#: an odd row and have no words, the last block of shards 0 and 2 ends on
+#: one); an odd array, short of one row and of three (no words at all: a
+#: column's bytes are no whole number of words)
+WORD_CASES = {"multiple": (SHARDS * 3 * ROWS, SHARDS * 3),
+              "remainder": (SHARDS * (2 * ROWS + 1000), SHARDS * 3),
+              "two_rows_short": (SHARDS * (2 * ROWS + 8) - 2, SHARDS * 3),
+              "odd_shards": (SHARDS * (2 * ROWS + 7), 2 * 2),
+              "odd_array": (SHARDS * (2 * ROWS + 8) - 1, 0),
+              "odd_array_three_short": (SHARDS * (2 * ROWS + 8) - 3, 0)}
+
+
+@pytest.mark.parametrize("d", [1000, 128, 7])
+@pytest.mark.parametrize("case", sorted(WORD_CASES))
+def test_a_fortran_ordered_two_byte_array_crosses_as_words_to_four(
+        monkeypatch, mesh, case, d):
+    """The benchmark's four-chip array in small: bf16, Fortran-ordered.
+    Every block whose rows start and end on an even row of an even array
+    goes as 32-bit words, the others as their rows; the shards are the
+    plain placement's bit for bit."""
+    n, words = WORD_CASES[case]
+    X = np.asfortranarray(_host(n, d, ml_dtypes.bfloat16))
+    _blocks_of(monkeypatch, d * 2)
+    told = Told()
+    got, blocks, block_bytes = gd._stage_dense(X, told, mesh)
+    _same(got, X, mesh)
+    assert (blocks, block_bytes) == (SHARDS * 3, ROWS * d * 2)
+    assert (told.said["shards"], told.said["flat"]) == (SHARDS, words)
+
+
+@pytest.mark.parametrize("order", ["c", "fortran"])
+def test_one_destination_and_four_take_the_same_blocks(monkeypatch, mesh,
+                                                       order):
+    """The array that goes to four devices in ``4 x 3`` blocks goes to one
+    in 12, in the same form."""
+    X = _host(SHARDS * 3 * ROWS, 8, ml_dtypes.bfloat16)
+    if order == "fortran":
+        X = np.asfortranarray(X)
+    _blocks_of(monkeypatch, 16)
+    for on, shards in ((mesh, SHARDS), (None, 1)):
+        told = Told()
+        got, blocks, _ = gd._stage_dense(X, told, on)
+        assert (blocks, told.said["flat"], told.said["shards"]) == (
+            12, 12, shards)
+        np.testing.assert_array_equal(np.asarray(got), X)
 
 
 @pytest.mark.parametrize("order", ["c", "fortran", "strided_rows",
@@ -128,19 +192,30 @@ def test_a_host_array_in_either_order_arrives_right(monkeypatch, mesh, order,
     X = {"c": base[:n], "fortran": np.asfortranarray(base[:n]),
          "strided_rows": base[::2], "strided_columns": base[:n, ::3]}[order]
     _blocks_of(monkeypatch, X.shape[1] * 4)
-    got, blocks, _ = gd._stage_dense(X, mesh=mesh)
+    told = Told()
+    got, blocks, _ = gd._stage_dense(X, told, mesh)
     assert blocks > SHARDS
     _same(got, X, mesh)
+    # C-ordered rows cross flat; every other order of f32 as strided rows
+    assert told.said["flat"] == (blocks if order == "c" else 0)
 
 
+@pytest.mark.parametrize("form", ["flat", "words", "strided"])
 def test_no_device_holds_more_than_its_shard_and_the_blocks_in_flight(
-        monkeypatch, mesh):
+        monkeypatch, mesh, form):
     """The host waits for a device's oldest write before it issues that
-    device a block beyond the bound; every block goes to the device that
-    owns its rows, each device's in the rows' order from a thread of its
-    own, and is deleted."""
-    X = _host(SHARDS * 7 * ROWS, 8, np.float32)
+    device a block beyond the bound, so a device holds its destination and
+    at most ``_STAGE_IN_FLIGHT x _STAGE_BLOCK_BYTES`` bytes of pieces in
+    whatever form they crossed; every block goes to the device that owns
+    its rows, each device's in the rows' order from a thread of its own,
+    and is deleted."""
+    X = {"flat": lambda: _host(SHARDS * 7 * ROWS, 8, np.float32),
+         "words": lambda: np.asfortranarray(
+             _host(SHARDS * 7 * ROWS, 16, ml_dtypes.bfloat16)),
+         "strided": lambda: _host(SHARDS * 7 * ROWS, 16,
+                                  np.float32)[:, ::2]}[form]()
     _blocks_of(monkeypatch, 32, in_flight=3)
+    held = {}
     devices = list(mesh.devices.flat)
     waited = {d: 0 for d in devices}
     issued = {d: 0 for d in devices}
@@ -160,15 +235,23 @@ def test_no_device_holds_more_than_its_shard_and_the_blocks_in_flight(
         assert dest.devices() == {device}
         # the block's rows are this device's: its shard starts at s * local
         s = devices.index(device)
+        rows = X[s * 7 * ROWS + offset:s * 7 * ROWS + offset + ROWS]
+        if form == "words":  # rows 2k and 2k + 1 of a column in one word
+            rows = np.ascontiguousarray(rows.T).view(np.uint32).T
         np.testing.assert_array_equal(
-            np.asarray(block),
-            X[s * 7 * ROWS + offset:s * 7 * ROWS + offset + ROWS])
+            np.asarray(block).reshape(rows.shape), rows)
+        assert (block.ndim, block.dtype.name) == {
+            "flat": (1, "float32"), "words": (2, "uint32"),
+            "strided": (2, "float32")}[form]
+        held.setdefault(device, []).append(block.nbytes)
         dest, token = real(dest, block, offset)
         blocks.append(block)
         order.append((s, offset))
         threads.add((s, threading.get_ident()))
         issued[device] += 1
         assert issued[device] - waited[device] <= 3
+        assert sum(held[device][waited[device]:]) \
+            <= 3 * gd._STAGE_BLOCK_BYTES
         return dest, Written(token, device)
 
     monkeypatch.setattr(gd, "_stage_block", write)

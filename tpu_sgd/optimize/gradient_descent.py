@@ -94,40 +94,32 @@ def _coerce_w0(gradient, initial_weights, n_features):
 #: multiples of ``_STAGE_ROWS`` rows (a window of rows is a window of lanes
 #: where the chip stores X feature-major: the writes stay tile-aligned).
 #: Set from the sweep on the v5e in PERF.md section 6 (PR 29's step 0,
-#: 2,145,000 x 1000 bf16): the runtime re-tiles one block on one thread at
-#: 10.6 GB/s, under the wire's 14.3, so the wire is kept busy only by
-#: several blocks re-tiling at once; 16 x 32 MiB reads 0.31 s against 0.69 s
-#: for the one piece and 0.60 s for 2 x 256 MiB, and holds 0.52 GB beside
-#: the dataset.  At these sizes the WIRE bounds the copy, not the host's
-#: issue of the blocks (PR 37, ``train.h2d``'s ``stall_ms``): the fit's
-#: thread spends 1.5 to 1.8 ms in its own calls a block (``jnp.asarray`` of
-#: the row block, the write's dispatch, the delete) and then stands 0.5 to
-#: 0.8 ms in the flow-control wait, 56 to 92 ms of the 291 it is in
-#: ``_stage_dense``, because the wire takes 2.29 ms for the block; a faster
-#: issue would lengthen the wait, not shorten the copy (a host 1.3 times
-#: slower would make the issue the bound).  A block is far under 4 GiB,
-#: above which a host array is copied 24 times slower.  Over FOUR chips
-#: (PR 42, 10,000,000 x 1000 bf16) it is the other way round, the host's
-#: issue bounds the copy: ONE thread, the devices in turn, issues 612 blocks
-#: in 0.96 to 1.07 s from one process to the next (1.57 to 1.75 ms each, 14
-#: to 18 ms of it in the wait), 1.4 wires' worth; a thread a device, as it
-#: is written, lands them in 0.82 to 0.86 s (23.3 to 24.4 GB/s), each
-#: thread 4.9 ms in its calls a block: the four contend, and what for is the
-#: runtime's re-tiling of the blocks into the chip's layout (PR 46, the
-#: span's ``put_ms`` / ``write_ms`` / ``free_ms`` / ``own_ms`` and the
-#: runtime's own host events).  A put returns in 0.4 ms; the copy of a
-#: strided block into ``{0,1:T(8,128)(2,1)}`` runs behind it on the runtime's
-#: threads (``XlaLinearize``, cut into ``Transpose::ExecuteChunk`` pieces on a
-#: pool), 6.4 ms of CPU a block for one device and 18.5 where four devices'
-#: blocks are in it at once, 13.7 of the host's 30 CPUs: 24 GB/s is what that
-#: pool gives, for 2 devices as for 4, for C-ordered rows as for
-#: Fortran-ordered.  The issuing threads are held up by it wherever they next
-#: enter the runtime (the write's dispatch 3.3 ms a block for 1.1, a put 1.5
-#: for 0.4; with the write's offset kept on the device the write shrinks and
-#: the put grows by as much), not by the interpreter's lock (the delete and
-#: the loop's own statements stay at 0.03 and 0.02 ms a block).  The same
-#: bytes as FLAT 1-D blocks, which the runtime copies and does not re-tile,
-#: land at 35 GB/s (PERF.md section 7, #1(d)).
+#: 2,145,000 x 1000 bf16): the runtime makes one block ready on one thread
+#: at 10.6 GB/s, under the wire's 14.3, so the wire is kept busy only by
+#: several blocks in the runtime at once; 16 x 32 MiB reads 0.31 s against
+#: 0.69 s for the one piece and 0.60 s for 2 x 256 MiB, and holds 0.52 GB
+#: beside the dataset.  A block is far under 4 GiB, above which a host array
+#: is copied 24 times slower.  On ONE wire the wire bounds the copy (PR 37,
+#: ``train.h2d``'s ``stall_ms``), whatever form the blocks cross in: 14.5 to
+#: 14.7 GB/s in the span as strided rows, as words and flat (PR 49, by
+#: hand).  Over FOUR chips (PR 42, 10,000,000 x 1000 bf16, a thread a
+#: device) it is the HOST: strided 2-byte rows land at 24 to 25 GB/s, for 2
+#: devices as for 4, because the runtime re-tiles every block into
+#: ``{0,1:T(8,128)(2,1)}`` on its own threads (``XlaLinearize``, cut into
+#: ``Transpose::ExecuteChunk`` pieces on a pool: 6.4 ms of CPU a block for
+#: one device, 18.5 with four devices' blocks in it at once, 13.7 of the
+#: host's 30 CPUs: PR 46) and holds the issuing threads up wherever they
+#: next enter it.  What the blocks' FORM gives there (PR 49, step 0, by
+#: hand, the whole loop with its writes; ``_wire_form``): a C-ordered
+#: array's rows as flat 1-D runs, which the runtime copies and does not
+#: re-tile, 36.8 to 37.1 GB/s; a Fortran-ordered array's 2-byte items as
+#: 32-bit words (the same blocks, the re-tiling 4 bytes wide: half the work
+#: an item), 31.0 to 33.1.  A Fortran-ordered shard's OWN flat runs are
+#: single columns (5 MB at 2,500,000 rows), and a buffer costs the runtime
+#: 0.19 to 0.21 ms whatever its size, one thread after the other: 1,000
+#: runs a shard in groups of 16 land at 21.5 to 22.0 GB/s, under the
+#: strided rows' 24.7 (as 2-D slabs of 16 columns the re-tiling is back:
+#: 24.6 to 25.3), so the blocks stay blocks and a block is ONE buffer.
 _STAGE_BLOCK_BYTES = 32 << 20
 _STAGE_IN_FLIGHT = 16
 _STAGE_ROWS = 1024
@@ -143,12 +135,24 @@ def _stage_dest(shape, dtype):
 
 @functools.partial(jax.jit, donate_argnums=0)
 def _stage_block(dest, block, offset):
-    """``dest`` (donated: written in place) with ``block`` at rows
-    ``offset:``, and a scalar that is ready when the write is.  The offset
-    is an operand, so one program serves every full block and one more the
-    remainder (a program a device under a mesh: it runs where ``dest`` and
-    ``block`` lie)."""
+    """``dest`` (donated: written in place) with ``block``'s rows at rows
+    ``offset:``, and a scalar that is ready when the write is.  ``block`` is
+    the row block in the form it crossed in (``_wire_form``): the rows flat,
+    one 1-D run; ``(rows / 2, d)`` 32-bit words that hold rows ``2k`` and
+    ``2k + 1`` of a column in their low and high halves; or the ``(rows, d)``
+    rows themselves.  The chip makes the rows of it here, in its own layout.
+    The offset is an operand, so one program serves every full block and one
+    more the remainder (a program a device under a mesh: it runs where
+    ``dest`` and ``block`` lie)."""
     with jax.named_scope("sgd.stage"):
+        if block.dtype != dest.dtype:  # words: the halves are the rows
+            # a mask and a shift: ``bitcast_convert_type`` of the words had
+            # the chip's compiler lay out a block-sized array of shift
+            # counts that carries no scope (PERF.md, PR 49)
+            block = jnp.stack([jax.lax.bitcast_convert_type(
+                half.astype(jnp.uint16), dest.dtype)
+                for half in (block & 0xFFFF, block >> 16)], axis=1)
+        block = block.reshape((-1,) + dest.shape[1:])
         dest = jax.lax.dynamic_update_slice_in_dim(dest, block, offset,
                                                    axis=0)
         return dest, dest[offset, 0]
@@ -169,6 +173,34 @@ def _block_rows(X, n=None) -> int:
     return rows if rows < n else 0
 
 
+def _wire_form(X):
+    """``piece(a, b)``: rows ``a:b`` of the dense host array as they are
+    handed to the runtime, a view in every case, in the form that leaves the
+    runtime least to re-tile on the host's CPUs (PERF.md, PR 49; the chip
+    does the rest in ``_stage_block``).  Read off the array's strides and
+    item size alone:
+
+    - C-ordered: the rows are ONE contiguous run, handed over flat (1-D);
+      the runtime copies it and re-tiles nothing;
+    - Fortran-ordered with 2-byte items: the contiguous runs are single
+      columns (a shard's rows of one column: too many buffers to pay for
+      one by one), so the block stays ``(rows, d)`` and its items go as
+      32-bit WORDS, rows ``2k`` and ``2k + 1`` of a column in one: what the
+      runtime re-tiles is then 4 bytes wide, half the work an item.  A block
+      that starts or ends on an odd row goes as its rows;
+    - anything else (a strided view, a wider array's rows, Fortran order at
+      another item size) as its rows, strided: the runtime re-tiles them."""
+    import numpy as np
+
+    if X.flags.c_contiguous:
+        return lambda a, b: X[a:b].reshape(-1)
+    if (X.flags.f_contiguous and X.itemsize == 2 and not X.shape[0] % 2
+            and not X.ctypes.data % 4):
+        words = X.T.view(np.uint32).T  # (n / 2, d), strides (4, 2 n)
+        return lambda a, b: X[a:b] if (a | b) % 2 else words[a // 2:b // 2]
+    return lambda a, b: X[a:b]
+
+
 def _stage_dense(X, h2d=NO_SPAN, mesh=None):
     """Dense features on the device as ONE ``(N, d)`` array, and what the
     ``train.h2d`` span says of the copy: ``(X, blocks, block_bytes)``.
@@ -176,14 +208,16 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
     A device array comes back as it is (0 blocks), and so does a
     ``StagedAhead`` (its blocks went ahead of the fit and were folded into
     the totals it is trained from).  A numpy array of at most one block goes
-    in one ``jnp.asarray``.  A larger one goes in row
-    blocks (views: ``X[a:b]`` copies nothing on the host) issued back to
-    back, so that the runtime re-tiles the next blocks while one is on the
-    wire; each is written into the destination in place and deleted, and
+    in one ``jnp.asarray``.  A larger one goes in row blocks (views: nothing
+    is copied on the host), each in the form that leaves the runtime least
+    to re-tile (``_wire_form``: flat, 32-bit words, or the strided rows),
+    issued back to back so that the next blocks are made ready while one is
+    on the wire; each is written into the destination in place by a program
+    that makes the chip's layout of it (``_stage_block``) and deleted, and
     the host waits for the oldest write before it issues a block beyond
-    ``_STAGE_IN_FLIGHT``, so the device holds the dataset plus the blocks
-    in flight, never the dataset twice.  The values are ``jnp.asarray``'s
-    (each block IS one), so the fit is the single copy's bit for bit.
+    ``_STAGE_IN_FLIGHT``, so the device holds the dataset plus the blocks in
+    flight, never the dataset twice.  The values are ``jnp.asarray``'s bit
+    for bit whatever the form, so the fit is the single copy's.
 
     Under ``mesh`` (1-D, over rows) a numpy array of any size has a
     destination a DEVICE: shard ``s`` holds rows ``[s n/S, (s + 1) n/S)`` of
@@ -197,15 +231,18 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
     than its shard and the blocks in flight to it.
 
     ``h2d`` is told ``shards``, the destinations the array went to (0 for a
-    device array).  Where it is ``live`` it is given the hand-off's stall
+    device array), and ``flat``, the blocks that crossed flat or as words (0
+    where they went as strided rows, in one piece, or not at all).  Where it
+    is ``live`` it is given the hand-off's stall
     counter too: ``stalls``, the times the host stood in that flow-control
     wait, and ``stall_ms``, how long in all, over the devices (0 and 0 for
     one piece or a device array).  An array that goes in blocks then says
     where its issuing threads' time went, each sum over the devices as
     ``stall_ms`` is, each on the issuing thread's own clock: ``put_ms`` (the
-    row block's ``jnp.asarray`` / ``jax.device_put``: the block's buffer
-    and its hand-over to the runtime, whose threads copy it into the chip's
-    layout and send it behind the call), ``write_ms`` (the dispatch of
+    block's ``jnp.asarray`` / ``jax.device_put``: the block's buffer
+    and its hand-over to the runtime, whose threads copy it into the
+    transfer's buffers, re-tiled where its form asks for it, and send it
+    behind the call), ``write_ms`` (the dispatch of
     ``_stage_block`` and, once a device, of the destination's fill),
     ``free_ms`` (``block.delete()``) and ``own_ms`` (what is left of a
     thread's time in ``send``: the slice, the deque, the loop: the
@@ -217,6 +254,7 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
 
     timed = h2d.live
     now = time.perf_counter if timed else _no_clock
+    h2d.set(flat=0)  # until blocks have crossed flat or as words
     if timed:
         h2d.set(stalls=0, stall_ms=0.0)
     if not isinstance(X, np.ndarray):
@@ -234,15 +272,18 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
         return (_sharded_by_rows(mesh, dests), len(dests),
                 dests[0].nbytes)
 
+    piece_of = _wire_form(X)
+    dtype = jax.dtypes.canonicalize_dtype(X.dtype)  # ``jnp.asarray``'s
+
     def send(s):
         """Destination ``s`` written from its rows of ``X``: the array, its
-        blocks, how often its flow control stood, and the thread's seconds
-        in the wait, in the puts, in the writes' dispatch, in the deletes
-        and beside them."""
+        blocks and how many of them went flat or as words, how often its
+        flow control stood, and the thread's seconds in the wait, in the
+        puts, in the writes' dispatch, in the deletes and beside them."""
         device, first = devices[s], s * local
         end = min(first + local, n)  # behind it the fill's zero rows
         dest, writes = None, collections.deque()
-        blocks, stalls = 0, 0
+        blocks, flat, stalls = 0, 0, 0
         stall_s = put_s = write_s = free_s = 0.0
         entered = now()
         for lo in range(first, end, rows):
@@ -253,7 +294,8 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
                 if timed:
                     stall_s += now() - t
                     stalls += 1
-            piece = X[lo:min(lo + rows, end)]
+            piece = piece_of(lo, min(lo + rows, end))
+            flat += piece.ndim == 1 or piece.dtype != X.dtype
             t0 = now()
             block = (jnp.asarray(piece) if device is None
                      else jax.device_put(piece, device))
@@ -261,7 +303,7 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
             if dest is None:
                 with (contextlib.nullcontext() if device is None
                       else jax.default_device(device)):
-                    dest = _stage_dest((local,) + X.shape[1:], block.dtype)
+                    dest = _stage_dest((local,) + X.shape[1:], dtype)
             dest, written = _stage_block(dest, block, lo - first)
             t2 = now()
             block.delete()
@@ -272,7 +314,8 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
             writes.append(written)
             blocks += 1
         own_s = now() - entered - stall_s - put_s - write_s - free_s
-        return dest, blocks, stalls, (stall_s, put_s, write_s, free_s, own_s)
+        return (dest, blocks, flat, stalls,
+                (stall_s, put_s, write_s, free_s, own_s))
 
     if mesh is None:
         sent = [send(0)]
@@ -282,7 +325,8 @@ def _stage_dense(X, h2d=NO_SPAN, mesh=None):
         # process to the next
         with ThreadPoolExecutor(len(devices)) as pool:
             sent = list(pool.map(send, range(len(devices))))
-    dests, blocks, stalls, spent = zip(*sent)
+    dests, blocks, flat, stalls, spent = zip(*sent)
+    h2d.set(flat=int(sum(flat)))
     if timed:
         stall, put, write, free, own = (
             round(sum(part) * 1e3, 4) for part in zip(*spent))
@@ -346,11 +390,14 @@ class StagedAhead:
     (``_stage_dense``'s: ``_block_rows``).
 
     The ROWS form (no ``y``).  The device runs one program at a time, so
-    ``_stage_dense``'s in-place writes would queue behind the running
-    ``sgd_run`` and its flow control stall the host after
+    ``_stage_dense``'s in-place writes (the programs that also make the
+    chip's layout of a block that crossed flat or as words) would queue
+    behind the running ``sgd_run`` and its flow control stall the host after
     ``_STAGE_IN_FLIGHT`` blocks; here each row block is a device array of
-    its own: transfers that need no device program and land while the chip
-    computes.  ``whole()`` makes them the one ``(N, d)`` array once the chip
+    its own, handed over as its strided rows (the runtime re-tiles them on
+    the host: one wire, which that keeps busy): transfers that need no
+    device program and land while the chip computes.  ``whole()`` makes
+    them the one ``(N, d)`` array once the chip
     is free, in ONE program (``_stage_join``: 13.9 ms of the host's time on
     the v5e, where the 128 in-place writes and their destination's fill take
     68: PERF.md, PR 40); the values are ``_stage_dense``'s bit for bit.  The

@@ -55,9 +55,13 @@ def shard_dataset(mesh: Mesh, X, y,
     whole run — the analogue of the reference's initial ``RDD.cache()``
     materialization — and it is the fit's own hand-off
     (``gradient_descent._stage_dense`` with a destination a device): the
-    rows go in blocks, each to the device that owns it, so a dataset larger
-    than one device's memory arrives and no device holds more than its
-    shard and the blocks in flight to it.  ``h2d`` is the ``train.h2d`` span
+    rows go in blocks, each to the device that owns it and in the form that
+    leaves the host's runtime least to re-tile (a C-ordered array's rows
+    flat, a Fortran-ordered array's 2-byte items as 32-bit words: the chip
+    makes its own layout of them as it writes the block into the shard), so
+    a dataset larger than one device's memory arrives and no device holds
+    more than its shard and the blocks in flight to it.  ``h2d`` is the
+    ``train.h2d`` span
     of a fit that hands its host arrays over here, told what the copy did.
 
     Place once, fit many: what this returns is laid out for ``mesh``, and
