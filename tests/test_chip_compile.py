@@ -515,8 +515,22 @@ def _program_digests(fn, *shapes):
     """``(StableHLO outside the Mosaic call, the call's body without
     locations)`` of ``fn`` lowered from this CPU process for a TPU, as 16
     hex digits of sha256 each."""
-    import base64
     import hashlib
+
+    outside, kernel = _lowered_for_a_tpu(fn, *shapes)
+
+    def digest(s):
+        return hashlib.sha256(s.encode()).hexdigest()[:16]
+
+    return digest(outside), digest(kernel)
+
+
+def _lowered_for_a_tpu(fn, *shapes):
+    """``(StableHLO with the Mosaic call's body taken out, the body parsed
+    and printed without locations)`` of ``fn`` lowered from this CPU
+    process for a TPU; a program that holds no Mosaic call: an empty
+    body."""
+    import base64
     import re
 
     from jax._src.interpreters import mlir as jax_mlir
@@ -525,18 +539,14 @@ def _program_digests(fn, *shapes):
     text = fn.trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
     body = r"\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22"
     calls = re.findall(body, text)
-    kernel = ""  # a program that holds no Mosaic call: an empty body's digest
+    kernel = ""
     if calls:
         serialized, = calls
         with jax_mlir.make_ir_context() as context:
             context.allow_unregistered_dialects = True
             kernel = ir.Module.parse(base64.b64decode(serialized)) \
                 .operation.get_asm(enable_debug_info=False)
-
-    def digest(s):
-        return hashlib.sha256(s.encode()).hexdigest()[:16]
-
-    return digest(re.sub(body, '"body": ""', text)), digest(kernel)
+    return re.sub(body, '"body": ""', text), kernel
 
 
 #: the same pair for the cells whose step is no masked scan: the windowed
@@ -1546,6 +1556,39 @@ def test_the_thousand_class_run_at_the_cells_shape_reads_x_once_where_it_lies(
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < n * d * 2 // 100
     assert memory.argument_size_in_bytes < n * d * 2 * 1.01
+
+
+@pytest.mark.parametrize("rows,classes", [(1008, 1000), (16, 10)])
+def test_the_class_call_is_ahead_past_128_rows_and_its_cut_block_in_turn(
+        rows, classes):
+    """The body as lowered for a TPU at the cell's rows and width (PR 50).
+    At 1,008 class rows the full blocks take the AHEAD order, a prologue's
+    margins product, a loop whose trip holds the next chunk's margins
+    product and this chunk's gradient product, an epilogue's gradient
+    product, and the cut last block stays IN TURN, a loop of both
+    products: six products, three copies of the rule (two exponentials
+    each), two loops.  A second ahead body for the cut block would take
+    the kernel from 53,187 instruction bundles to 69,619, and on the chip
+    the call read 57.86 ms against 56.77 (PERF.md, PR 50).  At 16 class
+    rows both blocks are in turn, as ever: four products, two rules."""
+    from tpu_sgd.ops import pallas_kernels as PK
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+
+    n, d, _ = IMAGENET
+    shape = jax.ShapeDtypeStruct
+    rule = MultinomialLogisticGradient(classes).class_rule
+    own = PK.one_read(n, d, 2, False, rows)
+    assert own.ahead == (rows > PK.FM_CLASS_ROWS) and n % own.tile
+    _, kernel = _lowered_for_a_tpu(
+        jax.jit(lambda X, y, W: PK._class_call(
+            rule, X, y, W, None, own.tile, d, own.vmem_limit, False, True)),
+        shape((n, d), BF16), shape((n,), F32), shape((rows, d), BF16))
+    counts = tuple(kernel.count(op) for op in (
+        "tpu.matmul", "math.exp", "scf.for"))
+    assert counts == ((6, 6, 2) if own.ahead else (4, 4, 2))
+    # where the next chunk's margins wait: one f32 array of the rule's size
+    held = "memref<%dx%dxf32" % (rows, PK._fm_lane_chunk(own.tile, rows))
+    assert (held in kernel) == own.ahead
 
 
 #: (rows, width, by rows): the cell's shape, and a thousand classes over

@@ -6,7 +6,10 @@ float32 at ``highest``, no program code) and against the two-read path, by
 rows and feature-major, masked and not, rows that cut the last block; the
 selection's table at 16, 128, 208 and 1,008 class rows; a whole
 ``GradientDescent`` fit through the kernel against the reference's fit; and
-``class_rows`` on ``train.select`` and ``train.run``."""
+``class_rows`` on ``train.select`` and ``train.run``.  PR 50: past 128 class
+rows a block's lane chunks run AHEAD (the next chunk's margins before this
+chunk's rule), and the sums are the in-turn body's bit for bit; ``ahead``
+in the table and on the two spans."""
 
 import numpy as np
 import pytest
@@ -91,33 +94,82 @@ def test_every_class_row_is_in_every_rows_softmax():
     assert float(got[1]) > 30.0
 
 
+#: case -> (rows, row tile): at 208 class rows a pass of the body takes
+#: 1,024 lanes, so a block of 2,048 is two chunks (the prologue, one trip
+#: of the loop's body, the epilogue) and 404 rows cut a third block, which
+#: stays in turn; a block of 1,024 is ONE chunk (prologue and epilogue
+#: alone) and 300 rows cut a third; blocks of 3,072 are three chunks each
+#: and none is cut
+CHUNKS = {"two_chunks_and_a_cut_block": (4500, 2048),
+          "one_chunk_and_a_cut_block": (2348, 1024),
+          "three_chunks_no_cut": (6144, 3072)}
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("form", sorted(SHAPES))
+@pytest.mark.parametrize("case", sorted(CHUNKS))
+def test_the_ahead_body_sums_what_the_in_turn_body_sums_bit_for_bit(
+        case, form, masked):
+    """The same chunks' operations, added into the sums in the same order:
+    gradient, loss partials and count partials EQUAL, not close."""
+    import jax.numpy as jnp
+
+    (n, tile), (d, by_rows) = CHUNKS[case], SHAPES[form]
+    X, y, W = _data(n, d, seed=n + d + masked)
+    rows = PK.class_rows_of(K - 1, X.dtype)
+    assert PK._fm_ahead(rows) and not PK._fm_ahead(PK.FM_CLASS_ROWS)
+    assert tile // PK._fm_lane_chunk(tile, rows) == {
+        2048: 2, 1024: 1, 3072: 3}[tile]
+    mask = jnp.asarray(np.random.default_rng(n).uniform(size=n) < 0.4) \
+        if masked else None
+    Wp = jnp.pad(W.astype(X.dtype), ((0, rows - (K - 1)), (0, 0)))
+    rule = MultinomialLogisticGradient(K).class_rule
+
+    def sums(ahead):
+        return PK._class_call(rule, X, y, Wp, mask, tile, d,
+                              PK._fm_class_limit(rows), True, by_rows,
+                              ahead=ahead)
+
+    turn, ahead = sums(False), sums(True)
+    assert float(jnp.sum(jnp.abs(turn[0]))) > 0.0
+    for a, b in zip(ahead, turn):
+        assert a.dtype == b.dtype and bool(jnp.array_equal(a, b))
+
+
 #: (rows, width, class rows) -> (body, by_rows, tile, VMEM limit in MiB,
-#: scope, lanes a pass of the body takes): up to 128 class rows the parent's
+#: scope, lanes a pass of the body takes, chunks ahead): up to 128 class
+#: rows the parent's
 #: record under the parent's limit (the K = 10 cells' programs are pinned in
 #: tests/test_chip_compile.py); past it the wide form's limit, and the lanes
-#: that keep a (class rows, lanes) f32 array at a megabyte: 256 at 1,008
+#: that keep a (class rows, lanes) f32 array at a megabyte: 256 at 1,008,
+#: and the next chunk's margins ahead of this chunk's rule (PR 50)
 TABLE = {
     # by rows: cifar5m's and ImageNet's widths
-    (2_000_896, 3072, 16): ("class", True, 1024, 32, "sgd.class_sums", 1024),
-    (2_000_896, 3072, 128): ("class", True, 1024, 32, "sgd.class_sums", 1024),
+    (2_000_896, 3072, 16): ("class", True, 1024, 32, "sgd.class_sums", 1024,
+                            False),
+    (2_000_896, 3072, 128): ("class", True, 1024, 32, "sgd.class_sums", 1024,
+                             False),
     (2_000_896, 3072, 208): ("class", True, 2048, 100, "sgd.class_sums",
-                             1024),
-    (1_281_167, 2048, 16): ("class", True, 2048, 32, "sgd.class_sums", 1024),
-    (1_281_167, 2048, 128): ("class", True, 2048, 32, "sgd.class_sums", 1024),
+                             1024, True),
+    (1_281_167, 2048, 16): ("class", True, 2048, 32, "sgd.class_sums", 1024,
+                            False),
+    (1_281_167, 2048, 128): ("class", True, 2048, 32, "sgd.class_sums", 1024,
+                             False),
     (1_281_167, 2048, 208): ("class", True, 2048, 100, "sgd.class_sums",
-                             1024),
+                             1024, True),
     (1_281_167, 2048, 1008): ("class", True, 2048, 100, "sgd.class_sums",
-                              256),
+                              256, True),
     # feature-major: mnist8m's width and the north star's
-    (8_100_000, 784, 16): ("class", False, 2048, 32, "sgd.class_sums", 1024),
+    (8_100_000, 784, 16): ("class", False, 2048, 32, "sgd.class_sums", 1024,
+                           False),
     (8_100_000, 784, 128): ("class", False, 2048, 32, "sgd.class_sums",
-                            1024),
+                            1024, False),
     (8_100_000, 784, 208): ("class", False, 2048, 100, "sgd.class_sums",
-                            1024),
+                            1024, True),
     (8_100_000, 784, 1008): ("class", False, 2048, 100, "sgd.class_sums",
-                             256),
+                             256, True),
     (4_194_304, 1000, 1008): ("class", False, 2048, 100, "sgd.class_sums",
-                              256),
+                              256, True),
     # where not even one lane group of rows fits beside the matrix
     (1_281_167, 2048, 4096): None,
 }
@@ -134,11 +186,15 @@ def test_one_reads_table_by_class_rows(case):
             continue
         assert own.class_rows == rows and own.fblock == d
         assert (own.body, own.by_rows, own.tile, own.vmem_limit >> 20,
-                own.scope, PK._fm_lane_chunk(own.tile, rows)) == TABLE[case]
+                own.scope, PK._fm_lane_chunk(own.tile, rows),
+                own.ahead) == TABLE[case]
         assert PK._fm_vmem_bytes(own.tile, d, 2, masked, rows,
                                  by_rows=own.by_rows) <= own.vmem_limit
-    # a vector of weights carries no class rows
+    # a vector of weights carries no class rows, and no body of it runs
+    # its chunks ahead (by rows it rides the class body as 16 rows)
     assert PK.one_read(4_194_304, 1000, 2, True).class_rows == 0
+    assert not PK.one_read(4_194_304, 1000, 2, True).ahead
+    assert not PK.one_read(2_097_152, 1024, 2, True).ahead
 
 
 def _through_the_kernel(monkeypatch, tile=256):
@@ -226,12 +282,14 @@ def test_batch_sums_lowers_one_call_for_a_tpu_past_128_class_rows():
                                              "bf16[1008,2048]")
 
 
-@pytest.mark.parametrize("classes,rows", [(10, 16), (200, 208)])
+@pytest.mark.parametrize("classes,rows", [(10, 16), (200, 208),
+                                          (1000, 1008)])
 def test_train_select_and_train_run_carry_the_class_rows(monkeypatch,
                                                          classes, rows):
     """``class_rows``: the padded class rows the kernel's products are
     issued with, on both spans, where the backend is a TPU; 0 on the CPU
-    (the step is two matmuls) and for a vector of weights."""
+    (the step is two matmuls) and for a vector of weights.  ``ahead``
+    beside it: 1 past 128 class rows (208, 1,008), 0 at 16."""
     import jax
 
     import tpu_sgd
@@ -264,12 +322,16 @@ def test_train_select_and_train_run_carry_the_class_rows(monkeypatch,
     here = fit(g, (classes - 1) * d)
     assert here["train.run"]["class_rows"] == 0
     assert here["train.select"]["class_rows"] == 0
+    assert here["train.run"]["ahead"] == here["train.select"]["ahead"] == 0
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     there = fit(g, (classes - 1) * d)
     assert there["train.run"]["class_rows"] == rows
     assert there["train.select"]["class_rows"] == rows
+    assert there["train.run"]["ahead"] == there["train.select"]["ahead"] \
+        == int(rows > PK.FM_CLASS_ROWS)
     assert there["train.run"]["classes"] == classes
     assert there["train.run"]["by_rows"] == 1
     vector = fit(tpu_sgd.LogisticGradient(), d)
     assert vector["train.run"]["class_rows"] == 0
+    assert vector["train.run"]["ahead"] == 0
 

@@ -297,6 +297,9 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
                           # a matrix's padded class rows in the kernel, on
                           # a TPU alone (PR 48)
                           "class_rows": 0,
+                          # the class body's chunks ahead, past 128 class
+                          # rows on a TPU alone (PR 50)
+                          "ahead": 0,
                           # from the totals of its rows (PR 41): logistic
                           "stats": 0}
         # the leaves tile the fit in this order (PR 37: train.select
@@ -322,7 +325,7 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
     # the selection before it says which form the step's kernel is (PR 39:
     # 0 on a CPU); whether it built its runner is ``built``'s to say
     assert [s[2] for s in found["train.select"]] == [
-        {"by_rows": 0, "class_rows": 0, "stats": 0}] * 5
+        {"by_rows": 0, "class_rows": 0, "ahead": 0, "stats": 0}] * 5
     assert len(found["fit.finish"]) == 2  # run() alone has a model to make
     # device arrays at the Optimizer boundary: nothing to copy
     assert found["train.h2d"][2][2]["bytes"] == 0

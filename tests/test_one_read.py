@@ -8,6 +8,8 @@ test for.  The expected rows were written by the PARENT's functions
 PR 48 took the class body past 128 class rows, so ``class_rows_over_128``
 answers a record where that parent answered None, and the tenth cell's row
 is new (``tests/test_class_rows.py`` holds the table by class rows).
+PR 50 gave the record ``ahead`` (the class body's lane chunks a chunk ahead,
+past 128 class rows): a second table over the same cases, ``AHEAD``.
 And the arrows between the layers that ask: ``ops`` <- ``plan`` <-
 ``optimize``, one way."""
 
@@ -240,6 +242,30 @@ def test_the_selection_answers_what_the_parents_predicates_answered(case):
         run = (1, k.tile, k.feature_blocks, int(plan.mask_in_kernel),
                int(k.by_rows))
     assert (record, run, plan.drawn) == EXPECT[case]
+
+
+#: the cases whose record says the class body runs its lane chunks AHEAD
+#: (PR 50): a matrix of more than 128 padded class rows, so the tenth cell
+#: and the edge one row past the pass; every other record is in turn (a
+#: vector's bodies, ten classes, 128 class rows, a by-rows vector's 16 rows
+#: in the class body) and where there is no record nothing is asked
+AHEAD = {"imagenet1k-r50-multinomial.resident-classes",
+         "class_rows_over_128"}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_record_says_which_order_the_class_bodys_chunks_take(case):
+    from tpu_sgd.ops import pallas_kernels as PK
+    from tpu_sgd.ops.gradients import step_sums
+
+    g, cfg, X, y, w, valid, axis, how = _operands(**CASES[case])
+    with how:
+        k = step_sums(g, cfg, X, y, w, valid, axis).kernel
+    if k is None:
+        assert case not in AHEAD
+        return
+    assert k.ahead == (case in AHEAD) == PK._fm_ahead(k.class_rows)
+    assert k.ahead <= (k.body == "class")
 
 
 #: layer -> the packages it lies below and imports nothing of

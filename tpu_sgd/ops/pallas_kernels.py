@@ -158,6 +158,34 @@ softmax 55.72 (188 TFLOP/s), so the rule costs 3.6 ms of a step; in a fit
 for as many class rows as fit that limit beside one lane group of rows
 (3,696 at 2,048 features); more are two reads.
 
+**The order of a block's lane chunks (PR 50).**  Up to ``FM_CLASS_ROWS``
+padded class rows the body takes a block's chunks IN TURN, margins, rule,
+gradient product, then the next chunk: the products hide under the
+block's copy and no order has anything to hide.  Past it
+(:func:`_fm_ahead`, from the rows of ``W``: the count that already picks
+the VMEM limit and the lanes a pass) the rule stood between a chunk's two
+products with the matrix unit waiting for it, a max and two sums down
+1,008 sublanes that are chains of dependent operations, and the full
+blocks run AHEAD: chunk ``c + 1``'s margins product is issued before chunk
+``c``'s rule and its ``(1008, 256)`` f32 result waits in ONE VMEM buffer
+(written behind the rule's reads of it), so the rule runs under a product
+that does not depend on it and the chunk's gradient product follows at
+once.  The operations of every chunk and the order the chunks are added
+into the sums are the in-turn body's: the sums are equal bit for bit.
+Nothing is carried between grid steps, and the cut last block stays in
+turn, for the kernel's SIZE: the chip's compiler schedules the ahead
+loop at 16,568 cycles a chunk against 17,387 in turn (16,327 with no rule
+at all), but a kernel of more than some 65,000 instruction bundles pays
+for it at every grid step (53,187 bundles with the cut block in turn:
+56.77 ms a call against the in-turn body's 59.54; 69,619 with both blocks
+ahead: 57.86; 102,000 and more, two buffers and two chunks a trip or 512
+lanes a pass: 69.2).  A loop CARRY of the margins costs what it hides
+(252 registers copied at each end of a trip: 58.96), two chunks a trip
+with nothing carried hides nothing (the rule runs as soon as its margins
+are there, and the matrix unit's adds wait behind it: 60.14).  In a fit
+56.08 ms a step, 94.9% of the roofline, 22.79M rows/s against 21.71M
+(PERF.md, PR 50).
+
 **What the old verdict rested on.**  A second family, window kernels over
 ``(tile, d)`` ROW blocks at a scalar-prefetched row offset, ran 3.1-3.4 ms an
 iteration against XLA's 1.64 on a 3M x 1000 bf16 window (round 2, TPU v5
@@ -222,8 +250,9 @@ _FM_FEATURE_BLOCK_SHARE = 32
 #: copy, and the class kernel is fitted under ``_FM_VMEM_LIMIT`` (mnist8m's
 #: and cifar5m's 16).  Past it (ImageNet's 1,008, PR 48) the products bound
 #: the step, the weights and the gradient's sums are megabytes (24.8 MB at
-#: 1,008 x 2,048 with two buffers each), and the kernel is fitted under
-#: ``_FM_WIDE_VMEM_LIMIT``
+#: 1,008 x 2,048 with two buffers each), the kernel is fitted under
+#: ``_FM_WIDE_VMEM_LIMIT``, and the body issues a lane chunk's margins ahead
+#: of the chunk before's rule (:func:`_fm_ahead`, PR 50)
 FM_CLASS_ROWS = 128
 #: what one ``(class rows, lanes)`` f32 array of the rule between the
 #: products may take: a pass of the class body takes as many lanes as keep
@@ -269,7 +298,9 @@ def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool,
     the 1 MB one lane chunk of the block in X's type (the cut block's
     copy with the lanes outside replaced) and six ``(class_rows, chunk)``
     f32 arrays of the rule between the products (the chunk is
-    :func:`_fm_lane_cap`'s for the class rows); where the body cuts the
+    :func:`_fm_lane_cap`'s for the class rows), a seventh where the next
+    chunk's margins wait for this chunk's rule (:func:`_fm_ahead`: 1 MB at
+    1,008 x 256); where the body cuts the
     width into blocks of ``fblock`` features (the wide form), one such
     block of the lane chunk in place of all d.  ``by_rows``: the class
     kernel over ``(tile, d)`` blocks of X itself, whose d lies along the
@@ -283,7 +314,7 @@ def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool,
         fixed = (2 * class_rows * _round_up(d, LANES) * (itemsize + 4)
                  + _fm_lane_cap(class_rows) * (
                      _round_up(min(fblock or d, d), pad) * itemsize
-                     + 6 * class_rows * 4))
+                     + (6 + _fm_ahead(class_rows)) * class_rows * 4))
     else:
         fixed = 4 * _round_up(d, SUBLANES) * LANES * 4
     return per_lane * tile + fixed + (1 << 20)
@@ -312,6 +343,16 @@ def _fm_class_limit(class_rows: int) -> int:
     more class rows than one pass of the matrix unit (``FM_CLASS_ROWS``)."""
     return (_FM_WIDE_VMEM_LIMIT if class_rows > FM_CLASS_ROWS
             else _FM_VMEM_LIMIT)
+
+
+def _fm_ahead(class_rows: int) -> bool:
+    """Whether the class body issues the next lane chunk's margins AHEAD of
+    this chunk's rule: where the matrix unit bounds the body, more padded
+    class rows than one pass of it takes (``FM_CLASS_ROWS``; what
+    :func:`_fm_class_limit` and :func:`_fm_lane_cap` branch on too).  Up
+    to there the products hide under the block's copy, the order has
+    nothing to hide, and the body is traced in turn as it always was."""
+    return class_rows > FM_CLASS_ROWS
 
 
 def _fm_narrow_tile(n: int, d: int, itemsize: int, masked: bool,
@@ -403,6 +444,9 @@ class OneRead:
     #: the padded class rows both products are issued with for a MATRIX of
     #: weights, all held at once (``train.run``'s attribute); 0 a vector
     class_rows: int
+    #: the class body issues a lane chunk's margins ahead of the chunk
+    #: before's rule (:func:`_fm_ahead`; ``train.run``'s attribute)
+    ahead: bool
 
 
 @functools.lru_cache(maxsize=256)
@@ -427,7 +471,10 @@ def one_read(n: int, d: int, itemsize: int, masked: bool = True,
       is held whole all the same, fitted under ``_FM_WIDE_VMEM_LIMIT``
       (:func:`_fm_class_limit`) at :func:`_fm_lane_cap`'s lanes a pass of
       the body (256 at 1,008 rows): every class row's margin of a lane is
-      formed before any coefficient, as the pivot softmax needs;
+      formed before any coefficient, as the pivot softmax needs; there the
+      matrix unit bounds the body, and a full block's lane chunks run
+      ``ahead`` (:func:`_fm_ahead`: the next chunk's margins before this
+      chunk's rule);
     * a vector: ``_fm_kernel`` with all d along the lanes, over the full
       scan (the one body that draws a mask's rows, counted in int32) or a
       window; where its ``(d, 128)`` f32 operands do not fit
@@ -457,7 +504,7 @@ def one_read(n: int, d: int, itemsize: int, masked: bool = True,
              "sgd.wide_sums" if body == "wide" else "sgd.fused_sums")
     return OneRead(body, by_rows, tile, fblock, pl.cdiv(d, fblock), limit,
                    scope, draws=body == "scan" and n < 2**31,
-                   class_rows=class_rows)
+                   class_rows=class_rows, ahead=_fm_ahead(class_rows))
 
 
 def fm_blocks(n: int, d: int, itemsize: int, masked: bool = True,
@@ -893,7 +940,8 @@ def class_rows_of(C: int, dtype) -> int:
     return _round_up(C, 32 // jnp.dtype(dtype).itemsize)
 
 
-def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs):
+def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs,
+                     ahead=False):
     """One block of X for a ``(rows, d)`` MATRIX of weights, one row a
     class: both products go to the matrix unit with operands in X's type
     and f32 sums, with ``rule(margins, labels) -> (dloss/dmargins, loss)``
@@ -912,7 +960,24 @@ def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs):
     ``fblock`` features a product: all d, or (the wide form) the width cut
     into blocks that each product takes in turn, the margins summed over
     them before the rule and the gradient written block by block, so that
-    no operand of a product is larger than one block of the row tile."""
+    no operand of a product is larger than one block of the row tile.
+
+    The order of a block's lane chunks.  IN TURN (up to ``FM_CLASS_ROWS``
+    padded class rows, where the products hide under the block's copy, and
+    the cut last block always): a chunk's margins, its rule, its gradient
+    product, then the next chunk.  ``ahead`` (past ``FM_CLASS_ROWS``, where
+    the matrix unit bounds the body: :func:`_fm_ahead`; the last ref is then
+    a ``(rows, lanes)`` f32 VMEM buffer): chunk ``c + 1``'s margins are
+    issued BEFORE chunk ``c``'s rule and wait in the buffer, so the rule's
+    vector work (a max and two sums down 1,008 sublanes, the exponentials,
+    the one-hot, the cast) runs under a product that does not depend on it;
+    every chunk's operations and the order the chunks are added into the
+    sums are the in-turn body's, so the sums are its bit for bit.  Nothing
+    is carried from one grid step to the next: a block's first product has
+    no rule to cover and its last rule only the gradient product before it
+    (PERF.md, PR 50: 56.77 ms a call against 59.54 in turn; the module's
+    docstring, "The order of a block's lane chunks")."""
+    held, refs = (refs[-1], refs[:-1]) if ahead else (None, refs)
     m_ref = refs[0] if masked else None
     w_ref, g_ref, loss_ref, cnt_ref = refs[-4:]
     i = pl.program_id(0)
@@ -928,13 +993,15 @@ def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs):
         loss_ref[:] = jnp.zeros_like(loss_ref)
         cnt_ref[:] = jnp.zeros_like(cnt_ref)
 
-    def lanes_of(c, tail):
-        """Sums of the block's lanes ``[c * lw, (c + 1) * lw)``; in the
-        cut block every value read from a lane past row ``n`` is replaced
-        before any arithmetic, as in ``_fm_kernel`` (of a by-rows block:
-        the rows past ``n``, an iota along its first axis)."""
+    def chunk_of(c, tail):
+        """What both halves of the block's lanes ``[c * lw, (c + 1) * lw)``
+        read, ``(lanes, inside, x_of, y)``; in the cut block every value
+        read from a lane past row ``n`` is replaced before any arithmetic,
+        as in ``_fm_kernel`` (of a by-rows block: the rows past ``n``, an
+        iota along its first axis)."""
         c0 = c * lw if isinstance(c, int) else pl.multiple_of(c * lw, lw)
         lanes = pl.ds(c0, lw)
+        inside = None
         if tail:
             inside = (i * tile + c0 + jax.lax.broadcasted_iota(
                 jnp.int32, (1, lw), 1)) < n
@@ -955,12 +1022,25 @@ def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs):
         y = y_ref[:, lanes]
         if tail:
             y = jnp.where(inside, y, 0.0)
+        return lanes, inside, x_of, y
+
+    def margins_of(chunk):
+        """A chunk's first half: ``W . X_c`` (in feature blocks, their
+        sum)."""
+        x_of = chunk[2]
         margins = None
         for r0, r in blocks:
             part = jax.lax.dot_general(
                 w_ref[:, r0:r0 + r], x_of(r0, r),
                 (((1,), (features,)), ((), ())), preferred_element_type=f32)
             margins = part if margins is None else margins + part
+        return margins
+
+    def sums_of(chunk, margins):
+        """A chunk's second half: the rule, the mask, the loss and count
+        partials, the cast, and ``coeff . X_c`` added into ``g_ref``."""
+        lanes, inside, x_of, y = chunk
+        tail = inside is not None
         coeff, losses = rule(margins, y)
         if masked:
             m = m_ref[:, lanes]
@@ -978,7 +1058,31 @@ def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs):
                 coeff, x_of(r0, r), (((1,), (1 - features,)), ((), ())),
                 preferred_element_type=f32)
 
-    _fm_full_scan(functools.partial(_fm_block, lanes_of, tile, lw), n, tile)
+    def lanes_of(c, tail):
+        chunk = chunk_of(c, tail)
+        sums_of(chunk, margins_of(chunk))
+
+    def block(tail):
+        # the cut block stays in turn, for the kernel's size: a second
+        # ahead body is 16,000 instruction bundles more, and every grid
+        # step pays for them (57.86 ms a call against 56.77: the module's
+        # docstring, "The order of a block's lane chunks")
+        if not ahead or tail:
+            return _fm_block(lanes_of, tile, lw, tail)
+        last = tile // lw - 1
+        held[:] = margins_of(chunk_of(0, False))
+
+        def body(c, carry):
+            nxt = margins_of(chunk_of(c + 1, False))
+            sums_of(chunk_of(c, False), held[:])
+            held[:] = nxt
+            return carry
+
+        if last:
+            jax.lax.fori_loop(0, last, body, None)
+        sums_of(chunk_of(last, False), held[:])
+
+    _fm_full_scan(block, n, tile)
 
 
 def fused_class_sums(
@@ -1024,13 +1128,21 @@ def fused_class_sums(
 
 
 def _class_call(rule, X, y, W, mask, tile: int, fblock: int, limit: int,
-                interpret: bool, by_rows: bool = False):
+                interpret: bool, by_rows: bool = False,
+                ahead: Optional[bool] = None):
     """The class kernel's call over ``X.T`` (``by_rows``: over X as it
     lies) under the ``(rows, d)`` weights ``W`` in X's type: ``(gradient
-    (rows, d), loss and count lane partials)``, all f32."""
+    (rows, d), loss and count lane partials)``, all f32.  ``ahead``: the
+    order of a block's lane chunks, :func:`_fm_ahead`'s for the rows of
+    ``W`` when None (the tests hold the two orders against each other)."""
     n, d = X.shape
     rows = W.shape[0]
     masked = mask is not None
+    if ahead is None:
+        ahead = _fm_ahead(rows)
+    # where the next chunk's margins wait: no operand of an in-turn call
+    held = [pltpu.VMEM((rows, _fm_lane_chunk(tile, rows)), jnp.float32)
+            ] if ahead else []
     row = pl.BlockSpec((1, tile), lambda i: (0, i))
     whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))  # noqa: E731
     block = (pl.BlockSpec((tile, d), lambda i: (i, 0)) if by_rows
@@ -1040,12 +1152,14 @@ def _class_call(rule, X, y, W, mask, tile: int, fblock: int, limit: int,
         operands.append(row_operand(mask, n))
     operands.append(W)
     return pl.pallas_call(
-        functools.partial(_fm_class_kernel, rule, n, masked, fblock, by_rows),
+        functools.partial(_fm_class_kernel, rule, n, masked, fblock, by_rows,
+                          ahead=ahead),
         grid=(pl.cdiv(n, tile),),
         in_specs=[block] + [row] * (1 + masked) + [whole((rows, d))],
         out_specs=[whole((rows, d)), whole((1, LANES)), whole((1, LANES))],
         out_shape=[jax.ShapeDtypeStruct((rows, d), jnp.float32)]
         + _fm_sums_shape(d)[1:],
+        scratch_shapes=held,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=limit),
         interpret=interpret,

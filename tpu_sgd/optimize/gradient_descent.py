@@ -1273,8 +1273,8 @@ GRAFTLINT_MEMO = {
 
 #: ``GradientDescent._step_kernel``'s answer where the step is no one-read
 #: kernel: ``(labels_prepared, row_tile, feature_blocks, mask_in_kernel,
-#: by_rows, class_rows)``
-_NO_KERNEL = (0, 0, 1, 0, 0, 0)
+#: by_rows, class_rows, ahead)``
+_NO_KERNEL = (0, 0, 1, 0, 0, 0, 0)
 
 
 class GradientDescent(Optimizer):
@@ -2062,8 +2062,9 @@ class GradientDescent(Optimizer):
         each route names its compiled runner and its arguments, ONE call in
         ``train.dispatch`` runs them; ``built`` where the runner is a new
         ``_run_cache`` entry, which traces, lowers and compiles inside that
-        call.  Sets ``train.run``'s attributes (and ``by_rows`` and
-        ``class_rows`` on ``train.select`` too) where the spans keep them."""
+        call.  Sets ``train.run``'s attributes (and ``by_rows``,
+        ``class_rows`` and ``ahead`` on ``train.select`` too) where the
+        spans keep them."""
         from tpu_sgd.ops.gram import GramData
 
         cached = len(self._run_cache)
@@ -2131,7 +2132,8 @@ class GradientDescent(Optimizer):
             args = (w0, X, y)
         if run_span.live:
             # (labels_prepared, row_tile, feature_blocks, mask_in_kernel,
-            # by_rows, class_rows): evaluated only where a span carries them
+            # by_rows, class_rows, ahead): evaluated only where a span
+            # carries them
             kernel = self._step_kernel(*args) if runner else _NO_KERNEL
             # stats: 1 where the fit runs from the totals of its rows
             stats = int(isinstance(X, GramData) and X.PG is None)
@@ -2140,25 +2142,30 @@ class GradientDescent(Optimizer):
                 shards=1 if self.mesh is None else self.mesh.devices.size,
                 labels_prepared=kernel[0], row_tile=kernel[1],
                 feature_blocks=kernel[2], mask_in_kernel=kernel[3],
-                by_rows=kernel[4], class_rows=kernel[5], stats=stats)
+                by_rows=kernel[4], class_rows=kernel[5], ahead=kernel[6],
+                stats=stats)
             select_span.set(by_rows=kernel[4], class_rows=kernel[5],
-                            stats=stats)
+                            ahead=kernel[6], stats=stats)
         return fn, args, len(self._run_cache) > cached
 
     def _step_kernel(self, w0, X, y, valid=None):
         """``train.run``'s ``(labels_prepared, row_tile, feature_blocks,
-        mask_in_kernel, by_rows, class_rows)`` for the fit ``_runner``'s
-        program is about to make of these arguments (a shard's operands
-        under a mesh), on a TPU, read off ``ops.gradients.step_sums``'
-        record of the step's kernel: ``labels_prepared`` 1 where there is one (the fit then lays
-        the labels out once, before its loop), ``row_tile`` the rows a grid
+        mask_in_kernel, by_rows, class_rows, ahead)`` for the fit
+        ``_runner``'s program is about to make of these arguments (a
+        shard's operands under a mesh), on a TPU, read off
+        ``ops.gradients.step_sums``' record of the step's kernel:
+        ``labels_prepared`` 1 where there is one (the fit then lays the
+        labels out once, before its loop), ``row_tile`` the rows a grid
         step of it takes and ``feature_blocks`` the blocks its body cuts the
         width into, ``mask_in_kernel`` 1 where it draws every step's
         Bernoulli mask itself (0 where the step is handed an array or draws
         nothing), ``by_rows`` 1 where it is the by-rows form (row blocks of
         an X the chip stores by rows), ``class_rows`` the padded class rows
         its two products are issued with for a matrix of weights (16 for
-        ten classes, 1,008 for a thousand; 0 a vector).  ``_NO_KERNEL``
+        ten classes, 1,008 for a thousand; 0 a vector), ``ahead`` 1 where
+        the class body issues a lane chunk's margins ahead of the chunk
+        before's rule (past 128 class rows: the matrix unit bounds the
+        step; 0 in turn).  ``_NO_KERNEL``
         where the step takes ``y`` as it is and is no kernel (two reads;
         statistics; a CPU, whose program drops the row nothing reads)."""
         if jax.default_backend() != "tpu":
@@ -2176,7 +2183,7 @@ class GradientDescent(Optimizer):
         if kernel is None:
             return _NO_KERNEL
         return (1, kernel.tile, kernel.feature_blocks, int(plan.mask_in_kernel),
-                int(kernel.by_rows), kernel.class_rows)
+                int(kernel.by_rows), kernel.class_rows, int(kernel.ahead))
 
     def _place(self, X, y, valid=None):
         """``shard_dataset`` for this fit's mesh under the ``train.place``
