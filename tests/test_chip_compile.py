@@ -999,6 +999,101 @@ def test_a_batch_staged_ahead_is_made_whole_in_one_program(S):
         == {by_features}
 
 
+# -- a stream's micro-batch at a row capacity (PR 52) ----------------------
+
+#: the uneven logistic stream's capacity: every micro-batch of 1,048,577 to
+#: 2,097,152 rows (bench/configs/dense1000-logistic-stream.json)
+CAPACITY = 2_097_152
+
+
+def _bounded_run(S):
+    from tpu_sgd.ops.gradients import RowCount
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    cfg = _cfg(step_size=0.1, num_iterations=50, reg_param=0.0,
+               mini_batch_fraction=1.0, convergence_tol=0.0)
+    return jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg)).lower(
+        S((D,), F32), S((CAPACITY, D), BF16), S((CAPACITY,), F32),
+        RowCount(S((), I32)))
+
+
+def test_the_bounded_run_at_the_stream_cells_capacity_reads_x_in_place(S):
+    """The uneven stream's fit: ONE program for every row count up to the
+    capacity (the count is a scalar operand), the kernel in it, ``X.T`` a
+    bitcast of the parameter, no array of X's size or of a mask's made, and
+    the loop's body holds no relayout of the labels."""
+    compiled = _bounded_run(S).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "bf16[%d,%d]{1,0:T(8,128)(2,1)} bitcast(" % (D, CAPACITY) in text
+    assert _moves_of(text, CAPACITY, D) == []
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < CAPACITY * D * 2 // 100
+    comps = _computations(text)
+    body = _reach(comps, _fit_loop_body(comps))
+    assert _made_with(comps, body, CAPACITY) == []
+
+
+def test_the_bounded_kernels_grid_is_the_capacitys_and_its_count_a_scalar():
+    """Lowered from this CPU process for a TPU: the bounded fit's Mosaic
+    call takes its row count as a prefetched scalar (two of them: the count
+    and its last block), runs the full scan's grid over the capacity, and
+    no ``(n,)`` mask is an operand of it; a count in ``valid``'s place
+    under a Bernoulli fraction is made the padded shard's array."""
+    from tpu_sgd.ops.gradients import RowCount
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    shape = jax.ShapeDtypeStruct
+    args = (shape((D,), F32), shape((CAPACITY, D), BF16),
+            shape((CAPACITY,), F32), RowCount(shape((), I32)))
+    cfg = _cfg(step_size=0.1, num_iterations=50, reg_param=0.0,
+               mini_batch_fraction=1.0, convergence_tol=0.0)
+    outside, kernel = _lowered_for_a_tpu(
+        jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg)), *args)
+    assert "_fm_kernel" in kernel and "memref<2xi32" in kernel
+    assert "i1[%d]" % CAPACITY not in outside
+    assert "tensor<%dxi1>" % CAPACITY not in outside
+    sampled = _cfg(step_size=0.1, num_iterations=50, reg_param=0.0,
+                   mini_batch_fraction=0.5, convergence_tol=0.0)
+    outside, kernel = _lowered_for_a_tpu(
+        jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), sampled)),
+        *args)
+    assert "tensor<%dxi1>" % CAPACITY in outside  # arange(n) < rows
+
+
+def test_a_batch_at_a_capacity_is_made_whole_in_one_program(S):
+    """``_stage_join`` under ``sgd.whole`` at the uneven stream's capacity:
+    128 block operands whatever the micro-batch's own count (the rest the
+    one block of zeros), one write a block, the result the capacity's array in the
+    layout the fit reads, no temporary of a block's size; and the labels'
+    program beside it."""
+    from tpu_sgd.optimize.gradient_descent import _stage_join
+
+    block = 16_384
+    compiled = _stage_join.lower(
+        *[S((block, D), BF16)] * (CAPACITY // block),
+        scope="sgd.whole").compile()
+    text = compiled.as_text()
+    assert "sgd.whole/concatenate" in text and " pad(" not in text
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == CAPACITY * D * 2
+    assert memory.temp_size_in_bytes < block * D * 2
+    assert compiled.output_formats.layout.major_to_minor == (1, 0)
+    labels = _stage_join.lower(
+        *[S((block,), F32)] * (CAPACITY // block),
+        scope="sgd.whole").compile()
+    assert labels.memory_analysis().output_size_in_bytes == CAPACITY * 4
+    # every array of a stream but its first is written over the one trained
+    # before it: the result IS the operand given up, and nothing is copied
+    over = _stage_join.lower(
+        *[S((block, D), BF16)] * (CAPACITY // block), scope="sgd.whole",
+        into=S((CAPACITY, D), BF16)).compile()
+    memory = over.memory_analysis()
+    assert memory.alias_size_in_bytes == CAPACITY * D * 2
+    assert memory.temp_size_in_bytes < block * D * 2
+    assert " pad(" not in over.as_text() and " copy(" not in over.as_text()
+
+
 @pytest.mark.parametrize("case", ["stream_cell", "by_rows", "f32"])
 def test_the_statistics_build_reads_x_where_it_lies(S, case):
     """``ops.gram._stats_build`` (PR 41) at the stream cell's micro-batch

@@ -880,3 +880,103 @@ def test_train_run_says_whether_the_labels_were_prepared(backend,
     assert [r["feature_blocks"] for r in runs] == [1] * 6
     assert [r["by_rows"] for r in runs] == (
         [0, 0, 0, 0, 0, 1] if backend == "tpu" else [0] * 6)
+
+
+# -- the full scan bounded by a row count that is an operand (PR 52) ----------
+
+@pytest.mark.parametrize("g", GRADS, ids=lambda g: type(g).__name__)
+@pytest.mark.parametrize("rows", [1, 127, 128, 129, 300, 511, 512, 1000,
+                                  1024])
+def test_bounded_kernel_is_the_full_scan_over_the_real_rows(g, rows):
+    """``fused_bound_sums`` over a capacity of 1,024 rows whose tail holds
+    NaN: the sums are the full scan's over the first ``rows`` rows at the
+    same tile (bit for bit where the same bodies run over the same blocks
+    in the same order: a last block that is cut in both), the count is
+    ``rows``, and nothing past them is read into a sum."""
+    import jax.numpy as jnp
+
+    from tpu_sgd.ops.pallas_kernels import fused_bound_sums
+
+    X, y, w = _data(n=1024, d=40, seed=5, classify=True)
+    X[rows:], y[rows:] = np.nan, np.nan
+    got = fused_bound_sums(g.pointwise, jnp.asarray(X, jnp.bfloat16), y, w,
+                           jnp.int32(rows), tile_m=256, interpret=True)
+    # the full scan at the same tile (a block count the rows fill)
+    want = fused_gradient_sums(
+        g.pointwise, jnp.asarray(X[:rows], jnp.bfloat16), y[:rows], w,
+        tile_m=256, interpret=True)
+    assert float(got[2]) == float(want[2]) == rows
+    # the full scan floors its tile to the rows there are, and runs the
+    # uncut body over a last block that the rows fill
+    if rows > 256 and rows % 256:
+        for a, b in zip(got[:2], want[:2]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    else:
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                                   rtol=1e-5, atol=1e-4)
+        np.testing.assert_allclose(float(got[1]), float(want[1]), rtol=1e-5)
+
+
+def test_bounded_kernel_takes_its_count_traced_and_clamps_it():
+    """One jitted program for every count; a count of 0 or over the
+    capacity is clamped to ``[1, capacity]`` (the caller hands real
+    counts)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_sgd.ops.pallas_kernels import fused_bound_sums
+
+    g = LogisticGradient()
+    X, y, w = _data(n=512, d=16, seed=6, classify=True)
+    fn = jax.jit(lambda rows: fused_bound_sums(
+        g.pointwise, X, y, w, rows, tile_m=128, interpret=True))
+    for rows in (5, 200, 512):
+        got = fn(jnp.int32(rows))
+        want = g._two_read_sums(jnp.asarray(X[:rows]), jnp.asarray(y[:rows]),
+                                jnp.asarray(w), None)
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                                   rtol=2e-4, atol=2e-3)
+        assert float(got[2]) == rows
+    assert fn._cache_size() == 1
+    assert float(fn(jnp.int32(0))[2]) == 1
+    assert float(fn(jnp.int32(9999))[2]) == 512
+
+
+def test_a_row_count_goes_to_the_kernel_as_a_scalar_on_a_tpu_and_a_mask_here():
+    """``batch_sums`` handed a ``RowCount`` in the mask's place: lowered for
+    a TPU the bounded call (no ``(n,)`` mask is made), lowered for the CPU
+    the two matvecs under ``arange(n) < rows``; its sums here are the
+    masked ones bit for bit.  ``rows_valid`` keeps the count where the
+    step's kernel bounds its grid by it and makes the array elsewhere."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_sgd.config import SGDConfig
+    from tpu_sgd.ops.gradients import (MultinomialLogisticGradient,
+                                       RowCount, rows_valid)
+
+    g = LogisticGradient()
+    X, y, w, _, _ = _selection_case("feature_major")
+    n = X.shape[0]
+    count = RowCount(jnp.int32(n - 37))
+    tpu = _lowered_for("tpu", g.batch_sums, X, y, w, count)
+    assert "tpu_custom_call" in tpu and "stablehlo.dot_general" not in tpu
+    assert "sgd.fused_sums/jit(_fused_bound_sums)" in tpu
+    assert "tensor<%dxi1>" % n not in tpu
+    cpu = _lowered_for("cpu", g.batch_sums, X, y, w, count)
+    assert cpu.count("stablehlo.dot_general") == 2
+    mask = jnp.arange(n) < n - 37
+    for a, b in zip(g.batch_sums(X, y, w, count),
+                    g.batch_sums(X, y, w, mask)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    full = SGDConfig(mini_batch_fraction=1.0)
+    assert rows_valid(g, full, X, y, w, count) is count
+    assert rows_valid(g, full, X, y, w, None) is None
+    assert rows_valid(g, full, X, y, w, mask) is mask
+    # a sampled fit, a matrix of weights: the array a padded shard hands on
+    for grad, weights, cfg in (
+            (g, w, SGDConfig(mini_batch_fraction=0.5)),
+            (MultinomialLogisticGradient(3),
+             jnp.zeros(2 * X.shape[1], jnp.float32), full)):
+        made = rows_valid(grad, cfg, X, y, weights, count)
+        np.testing.assert_array_equal(np.asarray(made), np.asarray(mask))

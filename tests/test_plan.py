@@ -840,6 +840,10 @@ def test_device_budget_probe_shapes():
     free, source = device_budget(Dev())
     assert source == "memory_stats"
     assert free == pytest.approx(12e9 * 0.8)
+    # what it would have free with nothing on it (a caller that sizes ALL
+    # it will hold, part of which is there already)
+    assert device_budget(Dev(), empty=True) == (pytest.approx(16e9 * 0.8),
+                                                "memory_stats")
 
     class DevZeros:  # a backend that answers with zeros
         def memory_stats(self):

@@ -542,23 +542,36 @@ def test_a_stream_that_raises_leaves_the_last_finished_model():
 
 
 @pytest.mark.parametrize("schedule,totals", [("auto", 0),
-                                             ("resident_gram", 1)])
+                                             ("resident_gram", 1),
+                                             ("capacity", 0)])
 def test_the_folds_spans_tile_a_pass(tmp_path, schedule, totals):
     """``stream.wait`` / ``stream.batch`` (``fit.run``, ``stream.publish``)
     on the fold's thread, ``stream.stage`` on the worker's; ``ahead`` is 0
     for a pass's first micro-batch and 1 after it.  On the statistics
     schedule every block is ``folded`` under its copy, every fit runs from
     ``totals`` made ahead and builds nothing (no ``train.stats``); on the
-    stock schedule (the planner's at these sizes) none."""
+    stock schedule (the planner's at these sizes) none.  ``capacity``: a
+    logistic stream, whose micro-batches go at a row capacity (PR 52):
+    ``stream.whole`` is a leaf of its own inside ``stream.wait``, and
+    ``stream.stage`` and ``stream.batch`` say the real ``rows``, the
+    ``capacity`` and ``rows_read``."""
     import json
     import warnings
 
     from tpu_sgd import obs
 
     import threading
+    import time
 
     stream, _ = _replayable_stream(batches=3)
-    alg = StreamingLinearRegressionWithSGD(step_size=0.3, num_iterations=5)
+    capacity = schedule == "capacity"
+    sizes = [500, 470, 430] if capacity else [500] * 3
+    if capacity:  # sizes that do not repeat (one that does keeps its own)
+        schedule = "auto"
+        stream = [(X[:n], (y[:n] > 0).astype(np.float32))
+                  for (X, y), n in zip(stream, sizes)]
+    alg = (StreamingLogisticRegressionWithSGD if capacity else
+           StreamingLinearRegressionWithSGD)(step_size=0.3, num_iterations=5)
     alg.set_initial_weights(np.zeros(12, np.float32))
     alg.algorithm.set_schedule(schedule)
     warnings.simplefilter("ignore")  # forced: a net loss at these sizes
@@ -574,6 +587,7 @@ def test_the_folds_spans_tile_a_pass(tmp_path, schedule, totals):
 
     def run_warm(data, model):  # a fit that outlasts the worker's take
         assert taken[alg._batch_count + 1].wait(30)
+        time.sleep(0.05)  # the worker, from the stream's next() to its copy
         return real(data, model)
 
     alg.train_on(stream)  # the stream's first fit plans: then batches go ahead
@@ -595,14 +609,17 @@ def test_the_folds_spans_tile_a_pass(tmp_path, schedule, totals):
     by = {}
     for s in spans:
         by.setdefault(s["name"], []).append(s)
-    assert len(by["stream.wait"]) == 4 and len(by["stream.stage"]) == 4
+    # at a capacity TWO micro-batches go ahead (three arrays of it fit): at
+    # the stream's end the worker comes for nothing once more
+    assert len(by["stream.wait"]) == 4
+    assert len(by["stream.stage"]) == 4 + capacity
     assert len(by["stream.batch"]) == len(by["stream.publish"]) \
         == len(by["fit.run"]) == 3
     turns = sorted(by["stream.batch"], key=lambda s: s["t0_s"])
     assert [s["index"] for s in turns] == [0, 1, 2]
     assert [s["ahead"] for s in turns] == [0, 1, 1]
     assert [s["totals"] for s in turns] == [totals] * 3
-    assert all(s["rows"] == 500 for s in turns)
+    assert [s["rows"] for s in turns] == sizes
     ids = {s["span_id"] for s in turns}
     assert all(s["parent_id"] in ids
                for s in by["fit.run"] + by["stream.publish"])
@@ -614,10 +631,29 @@ def test_the_folds_spans_tile_a_pass(tmp_path, schedule, totals):
     assert fold == {root["thread"]} and not fold & {s["thread"]
                                           for s in by["stream.stage"]}
     staged = [s for s in by["stream.stage"] if "blocks" in s]
+    wire = (1024 if capacity else 500) * 12 * 4  # one piece of the capacity
     assert len(staged) == 3 and all(s["blocks"] == 1 and
-                                    s["bytes"] == 500 * 12 * 4 and
+                                    s["bytes"] == wire and
                                     s["folded"] == totals
                                     for s in staged)
+    assert sorted(s["rows"] for s in staged) == sorted(sizes)
+    whole = by.get("stream.whole", [])
+    # the rows form alone makes its blocks whole, inside the fold's wait,
+    # behind the worker's answer: the two leaves tile the wait
+    assert len(whole) == (0 if totals else 3) and len(by["stream.take"]) == 4
+    waits = {s["span_id"]: s for s in by["stream.wait"]}
+    assert all(s["parent_id"] in waits and s["blocks"] == 1 for s in whole)
+    for wait in by["stream.wait"]:
+        inner = sorted((s for s in by["stream.take"] + whole
+                        if s["parent_id"] == wait["span_id"]),
+                       key=lambda s: s["t0_s"])
+        assert inner and inner[0]["name"] == "stream.take"
+        # what is left of the wait under neither is the spans' own cost
+        assert wait["dur_s"] - sum(s["dur_s"] for s in inner) < 5e-3
+    for s in staged + turns:
+        assert ("capacity" in s) == ("rows_read" in s) == capacity
+        if capacity:  # on the CPU a step reads all of the capacity, masked
+            assert (s["capacity"], s["rows_read"]) == (1024, 1024)
 
 
 def test_batches_go_ahead_only_beside_the_stock_and_totals_schedules(
@@ -637,9 +673,9 @@ def test_batches_go_ahead_only_beside_the_stock_and_totals_schedules(
     real = streaming.StagedAhead
 
     class Counted(real):
-        def __init__(self, X, y=None, alive=None):
+        def __init__(self, X, y=None, *more):
             made.append(y is not None)
-            super().__init__(X, y, alive)
+            super().__init__(X, y, *more)
 
     monkeypatch.setattr(streaming, "StagedAhead", Counted)
 
@@ -997,7 +1033,11 @@ def test_every_other_stream_goes_through_whole_as_before(case, monkeypatch):
     (no plan says what the next micro-batch's schedule is) and a harness
     that appends an intercept column (the optimizer's matrix is not the
     micro-batch): no block is ever folded, what went ahead is joined by
-    ``whole()``, and the fold is the in-turn fold bit for bit."""
+    ``whole()``, and the fold is the in-turn fold bit for bit.  The
+    logistic stream's FIRST micro-batch goes at a row capacity (PR 52: a
+    size seen for the first time), made whole by ``whole()`` inside its
+    fit; the others REPEAT its size and keep arrays of their own rows, as
+    before: joined by ``whole()`` ahead, copied by their fits in turn."""
     from tpu_sgd.models.streaming import StreamingLogisticRegressionWithSGD
 
     gd = _blocked(monkeypatch, d=6)
@@ -1034,10 +1074,12 @@ def test_every_other_stream_goes_through_whole_as_before(case, monkeypatch):
 
     ahead, calls_a = fold("ahead")
     staged = len(joins)
-    assert staged == (4 if case == "off" else 2) and folds == []
-    assert ahead._totals_key() is None
+    assert staged == (4 if case in ("off", "logistic") else 2)
+    assert folds == [] and ahead._totals_key() is None
+    assert ahead._capacity == (4096 if case == "logistic" else 0)
     turn, calls_t = fold("in turn")
-    assert len(joins) == staged and folds == []
+    assert len(joins) == staged + (case == "logistic")
+    assert folds == []
     _same_fold(ahead, turn, calls_a, calls_t)
     if case in ("off", "intercept"):  # the totals all the same, built whole
         assert ahead.algorithm.optimizer._totals_gradient is not None
@@ -1103,3 +1145,479 @@ def test_a_bundle_whose_optimizer_no_longer_trains_from_totals_is_refused():
     ref.train_on_batch(X, y)
     np.testing.assert_array_equal(np.asarray(model.weights),
                                   np.asarray(ref.latest_model().weights))
+
+
+# ---- micro-batches of unequal sizes at a row capacity (PR 52) -----------------
+
+def _uneven_logistic(sizes, d=6, seed=90, dtype=np.float32):
+    """Logistic micro-batches of ``sizes`` rows each (host), one ``w_true``."""
+    w_true = np.linspace(-1, 1, d).astype(np.float32)
+    out = []
+    for i, rows in enumerate(sizes):
+        r = np.random.default_rng(seed + i)
+        X = r.normal(size=(rows, d)).astype(dtype)
+        y = (r.uniform(size=rows)
+             < 1 / (1 + np.exp(-(X.astype(np.float32) @ w_true))))
+        out.append((X, y.astype(np.float32)))
+    return out
+
+
+def _logistic_fold(stream, how="ahead", d=6, iterations=8, fraction=1.0):
+    return _statistics_fold(stream, how, d=d, iterations=iterations,
+                            schedule="auto", fraction=fraction,
+                            model=StreamingLogisticRegressionWithSGD)
+
+
+UNEVEN = [3 * 1024 + 100, 2 * 1024 + 7, 4096, 2049, 3333, 2 * 1024 + 7]
+
+
+def test_an_uneven_logistic_stream_is_the_unpadded_fold(monkeypatch):
+    """``train_on`` over micro-batches of unequal sizes (each in an array of
+    the stream's row capacity, its count an operand) against the fold taken
+    strictly in turn over arrays of the micro-batches' OWN rows through the
+    batch model's ``run``: the weights and every step's loss agree to
+    float32 rounding (the sums run over the capacity's rows with the
+    padding's terms exact zeros, so the order of the additions differs, the
+    terms do not), every micro-batch trained once, in order, the listener
+    called after each; and the fold ahead is the fold in turn at the
+    capacity bit for bit."""
+    from tpu_sgd import LogisticRegressionWithSGD
+
+    _blocked(monkeypatch, d=6)
+    stream = _uneven_logistic(UNEVEN)
+    ahead, calls_a = _logistic_fold(stream, "ahead")
+    turn, calls_t = _logistic_fold(stream, "in turn")
+    _same_fold(ahead, turn, calls_a, calls_t)
+    assert [c[0] for c in calls_a] == [1, 2, 3, 4, 5, 6]
+    assert ahead._capacity == turn._capacity == 4096
+    w, history = np.zeros(6, np.float32), []
+    for X, y in stream:  # the unpadded fold: a program a size
+        batch = LogisticRegressionWithSGD(0.2, 8, 0.0, 1.0)
+        w = np.asarray(batch.run((X, y), w).weights)
+        history.append(np.asarray(batch.optimizer.loss_history))
+    np.testing.assert_allclose(np.asarray(ahead.latest_model().weights), w,
+                               rtol=0, atol=2e-6)
+    for (_, _, got), want in zip(calls_a, history):
+        np.testing.assert_allclose(got, want, rtol=2e-6)
+
+
+def test_padding_rows_are_not_trained_and_not_counted(monkeypatch):
+    """A micro-batch of 2,055 rows in a capacity of 4,096: the first step's
+    loss is log 2 (every real row at zero weights: a divisor of the
+    capacity would read half of it), the fold is the one over an array of
+    the 2,055 rows, and a row appended behind them changes it."""
+    _blocked(monkeypatch, d=6)
+    (X, y), = _uneven_logistic([2055])
+    big, _ = _logistic_fold(_uneven_logistic([4000]) + [(X, y)])
+    assert big._capacity == 4096
+    history = np.asarray(big.algorithm.optimizer.loss_history)
+    fresh, calls = _logistic_fold([(X, y)])
+    assert fresh._capacity == 4096 and len(calls) == 1
+    np.testing.assert_allclose(calls[0][2][0], np.log(2.0), rtol=1e-6)
+    assert history.shape == (8,)
+    more = (np.concatenate([X, 9 * np.ones((1, 6), np.float32)]),
+            np.concatenate([y, np.zeros(1, np.float32)]))
+    other, _ = _logistic_fold([more])
+    assert not np.allclose(np.asarray(other.latest_model().weights),
+                           np.asarray(fresh.latest_model().weights),
+                           atol=1e-4)
+
+
+def _compiles():
+    """A list that gains the name of every backend compile of this
+    process from now on (``jax.monitoring``; never removed)."""
+    from jax import monitoring
+
+    seen = []
+    monitoring.register_event_duration_secs_listener(
+        lambda event, duration, **kw: seen.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    return seen
+
+
+def test_any_number_of_sizes_share_one_plan_and_one_set_of_programs(
+        monkeypatch, tmp_path):
+    """Six micro-batches of five distinct sizes, then six more of other
+    sizes under the same capacity: the second stream compiles NOTHING, the
+    first no more than a constant set (the fit, the two joins, the zero
+    blocks), the optimizer holds one runner traced once, and the probe and
+    the plan ran once (one ``fit.plan`` that planned, the others the
+    repeat-run key's hit)."""
+    gd = _blocked(monkeypatch, d=6)
+    compiles = _compiles()
+    alg = StreamingLogisticRegressionWithSGD(step_size=0.2, num_iterations=8)
+    alg.set_initial_weights(np.zeros(6, np.float32))
+    _, spans = _traced(tmp_path, alg.train_on,
+                       iter(_uneven_logistic(UNEVEN)))
+    first = len(compiles)
+    alg.set_initial_weights(np.zeros(6, np.float32))
+    alg.train_on(iter(_uneven_logistic([2500, 3999, 2051, 4001, 3100, 2222],
+                                       seed=300)))
+    assert len(compiles) == first and alg._capacity == 4096
+    assert alg._batch_count == 12
+    opt = alg.algorithm.optimizer
+    (key, runner), = [(k, fn) for k, fn in opt._run_cache.items()
+                      if k[0] == "run"]
+    assert key[-1] is True and runner._cache_size() == 1
+    # the rows' (the first array, and the one written over it), the labels'
+    assert gd._stage_join._cache_size() == 3
+    plans = [s for s in spans if s["name"] == "fit.plan"]
+    assert [s["cached"] for s in plans] == [False] + [True] * 5
+    assert {s["schedule"] for s in plans} == {"resident_stock"}
+    assert opt._plan_key[0] == (4096, 6)
+    assert not [s for s in spans if s["name"] == "stream.regrow"]
+
+
+def test_a_micro_batch_over_the_capacity_regrows_once_and_trains_whole(
+        monkeypatch, tmp_path):
+    """Sizes under 2,048, then one of 5,000 (taken ahead), then small ones
+    again: the capacity is raised once (``stream.regrow`` says from what to
+    what), the large micro-batch is trained whole in one fit, the ones
+    after it train at the raised capacity, and the fold is the unpadded
+    one."""
+    from tpu_sgd import LogisticRegressionWithSGD
+
+    _blocked(monkeypatch, d=6)
+    sizes = [1500, 2000, 1100, 5000, 1200, 1800]
+    stream = _uneven_logistic(sizes)
+    (alg, calls), spans = _traced(tmp_path, _logistic_fold, stream)
+    regrown = [s for s in spans if s["name"] == "stream.regrow"]
+    assert [(s["rows"], s["was"], s["capacity"]) for s in regrown] \
+        == [(5000, 2048, 8192)]
+    assert alg._capacity == 8192 and alg._batch_count == 6
+    turns = [s for s in spans if s["name"] == "stream.batch"]
+    assert [s["rows"] for s in turns] == sizes
+    assert [s["capacity"] for s in turns] == [2048] * 3 + [8192] * 3
+    plans = [s["cached"] for s in spans if s["name"] == "fit.plan"]
+    assert plans == [False, True, True, False, True, True]
+    w = np.zeros(6, np.float32)
+    for X, y in stream:
+        w = np.asarray(LogisticRegressionWithSGD(0.2, 8, 0.0, 1.0)
+                       .run((X, y), w).weights)
+    np.testing.assert_allclose(np.asarray(alg.latest_model().weights), w,
+                               rtol=0, atol=2e-6)
+
+
+@pytest.mark.parametrize("how", ["ahead", "in turn"])
+def test_a_size_that_repeats_keeps_an_array_of_its_own_rows(how, monkeypatch):
+    """What the stream observes is the SIZES: a micro-batch whose size is
+    the one before it's trains an array of its own rows (a stream of equal
+    micro-batches lowers, from its second one on, the programs it lowered
+    before there was a capacity), one of a size not seen just before goes
+    at the capacity again; the fold is the unpadded one either way."""
+    from tpu_sgd import LogisticRegressionWithSGD
+    from tpu_sgd.models.glm import GeneralizedLinearAlgorithm
+
+    _blocked(monkeypatch, d=6)
+    sizes = [2100, 2100, 2100, 3000, 3000, 2100]
+    stream = _uneven_logistic(sizes)
+    seen, real = [], GeneralizedLinearAlgorithm.run_warm
+
+    def run_warm(self, data, model):
+        seen.append((np.shape(data[0])[0], getattr(data[0], "capacity", 0)))
+        return real(self, data, model)
+
+    monkeypatch.setattr(GeneralizedLinearAlgorithm, "run_warm", run_warm)
+    alg, calls = _logistic_fold(stream, how)
+    assert seen == [(4096, 4096), (2100, 0), (2100, 0), (4096, 4096),
+                    (3000, 0), (4096, 4096)]
+    assert [c[0] for c in calls] == [1, 2, 3, 4, 5, 6]
+    w = np.zeros(6, np.float32)
+    for X, y in stream:
+        w = np.asarray(LogisticRegressionWithSGD(0.2, 8, 0.0, 1.0)
+                       .run((X, y), w).weights)
+    np.testing.assert_allclose(np.asarray(alg.latest_model().weights), w,
+                               rtol=0, atol=2e-6)
+
+
+@pytest.mark.parametrize("arrays,ahead", [(3, 2), (2, 1)])
+def test_two_go_ahead_where_three_arrays_of_the_capacity_fit(
+        arrays, ahead, monkeypatch):
+    """Where the memory that is free as the capacity is made holds THREE
+    arrays of it, the worker holds two micro-batches ahead of the one in
+    training (a large copy behind a small fit then has the fit before it to
+    land under too) and never a third; where it holds two, one, as before.
+    The fold is the unpadded one either way."""
+    import threading
+    import time
+
+    from tpu_sgd import LogisticRegressionWithSGD
+    from tpu_sgd.models import streaming
+    from tpu_sgd.models.glm import GeneralizedLinearAlgorithm
+
+    _blocked(monkeypatch, d=6)
+    monkeypatch.setattr(streaming.plan_mod, "device_budget",
+                        lambda *a, **k: (arrays * 4096 * 6 * 4, "test"))
+    sizes = [2100, 4000, 2300, 3900, 2049, 3000, 2500]
+    stream = _uneven_logistic(sizes)
+    pulled = [threading.Event() for _ in range(len(sizes) + ahead + 1)]
+
+    def batches():
+        for event, batch in zip(pulled, stream):
+            event.set()
+            yield batch
+        for event in pulled[len(stream):]:  # the worker came for the end
+            event.set()
+            yield from ()
+
+    held, real = [], GeneralizedLinearAlgorithm.run_warm
+
+    def run_warm(self, data, model):
+        k = len(held)
+        if k:  # the first is copied inside its fit: the takes come after it
+            assert pulled[min(k + ahead, len(sizes))].wait(30)
+            time.sleep(0.05)  # a worker that came for one more would have
+            held.append(sum(e.is_set() for e in pulled[:len(sizes)]) - k - 1)
+        else:
+            held.append(0)
+        return real(self, data, model)
+
+    monkeypatch.setattr(GeneralizedLinearAlgorithm, "run_warm", run_warm)
+    alg = StreamingLogisticRegressionWithSGD(step_size=0.2, num_iterations=8)
+    alg.set_initial_weights(np.zeros(6, np.float32))
+    alg.train_on(batches())
+    assert alg._ahead == ahead and alg._capacity == 4096
+    n = len(sizes)
+    assert held == [0] + [min(ahead, n - 1 - k) for k in range(1, n)]
+    w = np.zeros(6, np.float32)
+    for X, y in stream:
+        w = np.asarray(LogisticRegressionWithSGD(0.2, 8, 0.0, 1.0)
+                       .run((X, y), w).weights)
+    np.testing.assert_allclose(np.asarray(alg.latest_model().weights), w,
+                               rtol=0, atol=2e-6)
+
+
+def test_the_array_of_the_capacity_is_written_over_in_place(monkeypatch):
+    """A stream's array of the capacity is made once: every later
+    micro-batch's rows are written over the one trained before it (given up:
+    deleted, its memory the new array's), so that the device never frees
+    one array of the capacity to find room for the next while blocks land
+    beside them; a raised capacity's first array is a new one."""
+    gd = _blocked(monkeypatch, d=6)
+    seen, real = [], gd.StagedAhead.whole
+
+    def whole(self, into=None):
+        spent = None if into is None else into.X
+        at = spent is not None and spent.unsafe_buffer_pointer()
+        out = real(self, into)
+        if spent is not None:
+            seen.append((spent.shape[0], out.X.shape[0], spent.is_deleted(),
+                         out.X.unsafe_buffer_pointer() == at))
+        return out
+
+    monkeypatch.setattr(gd.StagedAhead, "whole", whole)
+    sizes = [2100, 4000, 2300, 5000, 2049]
+    alg, calls = _logistic_fold(_uneven_logistic(sizes))
+    assert [c[0] for c in calls] == [1, 2, 3, 4, 5]
+    assert seen == [(4096, 4096, True, True), (4096, 4096, True, True),
+                    (4096, 8192, False, False), (8192, 8192, True, True)]
+
+
+def test_nothing_small_crosses_the_wire_as_a_fit_starts(monkeypatch):
+    """A scalar or a vector of weights sent as a fit starts waits behind
+    every row block in flight on the one wire (40 ms of an idle chip a
+    micro-batch on the v5e: PERF.md, PR 52).  So a micro-batch at a capacity
+    brings its row count with it, issued in front of its labels and rows;
+    a warm start takes the last fit's weights where they lie, on the
+    device; and initial weights given on the device stay there."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_sgd.ops.gradients import RowCount
+
+    gd = _blocked(monkeypatch, d=6)
+    order, real_asarray = [], gd.jnp.asarray
+    (X, y), = _uneven_logistic([2100])
+    staged = gd.StagedAhead(X, y, capacity=4096, issue=False)
+    assert staged.valid is None
+
+    class Spy:  # the module's ``jnp`` with ``asarray`` watched
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        def asarray(self, a, *args, **kwargs):
+            order.append(np.ndim(a))
+            return real_asarray(a, *args, **kwargs)
+
+    monkeypatch.setattr(gd, "jnp", Spy())
+    staged._issue()
+    monkeypatch.undo()
+    assert order[0] == 0 and order[1] == 1 and order[-1] == 2
+    assert isinstance(staged.valid, RowCount) and int(staged.valid.rows) == 2100
+
+    _blocked(monkeypatch, d=6)
+    starts, real = [], gd.GradientDescent.optimize
+
+    def optimize(self, data, initial_weights):
+        starts.append(isinstance(initial_weights, jax.Array))
+        return real(self, data, initial_weights)
+
+    monkeypatch.setattr(gd.GradientDescent, "optimize", optimize)
+    stream = _uneven_logistic([2100, 3000, 2500])
+    alg, _ = _logistic_fold(stream)  # the model holds them on the device
+    assert starts == [True, True, True]
+    del starts[:]
+    there = StreamingLogisticRegressionWithSGD(step_size=0.2,
+                                               num_iterations=8)
+    zeros = jnp.zeros(6, jnp.float32)
+    there.set_initial_weights(zeros)
+    assert there.latest_model().weights is zeros  # not fetched and sent again
+    there.train_on(iter(stream))
+    assert starts == [True, True, True]
+    np.testing.assert_array_equal(np.asarray(there.latest_model().weights),
+                                  np.asarray(alg.latest_model().weights))
+    # anything else is made float32 on the host, as before
+    there.set_initial_weights([0] * 6)
+    assert there.latest_model().weights.dtype == np.float32
+
+
+def test_a_first_fit_that_plans_another_schedule_trains_its_own_rows():
+    """A stream's first micro-batch is wrapped at the capacity BEFORE its
+    fit plans (the plan has to be the capacity's); where that plan is
+    another schedule than the stock one (forced here by name) the fit takes
+    the rows the wrapper still holds (``StagedAhead.host``), nothing is
+    issued at a capacity, and the next micro-batches come as they are."""
+    import warnings
+
+    stream = _uneven_logistic([1500, 1300, 1700])
+
+    def fold(schedule):
+        alg = StreamingLogisticRegressionWithSGD(step_size=0.2,
+                                                 num_iterations=4)
+        alg.set_initial_weights(np.zeros(6, np.float32))
+        alg.algorithm.set_schedule(schedule)
+        seen, real = [], alg.algorithm.optimizer._optimize
+
+        def optimize(data, *more):
+            seen.append(data[0])
+            return real(data, *more)
+
+        alg.algorithm.optimizer._optimize = optimize
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")  # forced: a loss at these sizes
+            alg.train_on(iter(stream))
+        return alg, seen
+
+    alg, seen = fold("host_streamed")
+    assert alg.algorithm.optimizer.host_streaming
+    assert getattr(seen[0], "capacity", 0) == 2048 and seen[0].X is None
+    assert seen[0].blocks is None  # never issued
+    assert [getattr(X, "capacity", 0) for X in seen[1:]] == [0, 0]
+    stock, _ = fold("auto")
+    np.testing.assert_allclose(np.asarray(alg.latest_model().weights),
+                               np.asarray(stock.latest_model().weights),
+                               rtol=0, atol=2e-6)
+
+
+def test_what_crosses_the_wire_is_the_rows_and_one_blocks_remainder(
+        monkeypatch):
+    """The capacity form's hand-off: the micro-batch's own rows in blocks,
+    the last one filled to a whole block on the host, the labels in pieces
+    of 16 blocks' rows in front of them; made whole, the capacity's tail is
+    zeros made on the device; ``wire_bytes`` is within one block of the
+    rows' bytes."""
+    import jax
+
+    from tpu_sgd.optimize.gradient_descent import StagedAhead, row_capacity
+
+    _blocked(monkeypatch, d=6)
+    (X, y), = _uneven_logistic([3 * 1024 + 100])
+    assert row_capacity(X) == 4096 and row_capacity(X, 8192) == 8192
+    assert row_capacity(X[:1024]) == 1024 and row_capacity(X[:1025]) == 2048
+    staged = StagedAhead(X, y, capacity=8192)
+    assert staged.shape == (8192, 6) and staged.rows == 3172
+    assert staged.count == 4 and staged.nbytes == X.nbytes
+    assert [b.shape for b in staged.blocks] == [(1024, 6)] * 4
+    assert [b.shape for b in staged.labels] == [(2048,)] * 2  # in flight
+    assert 0 <= staged.wire_bytes - X.nbytes < 1024 * 6 * 4
+    whole = staged.whole()
+    assert whole is staged and staged.blocks is None
+    assert isinstance(staged.X, jax.Array) and staged.X.shape == (8192, 6)
+    np.testing.assert_array_equal(np.asarray(staged.X)[:3172], X)
+    np.testing.assert_array_equal(np.asarray(staged.y)[:3172], y)
+    assert not np.asarray(staged.X)[3172:].any()
+    assert not np.asarray(staged.y)[3172:].any()
+    # in turn: nothing is issued until the fit asks, then the same arrays
+    lazy = StagedAhead(X, y, capacity=8192, issue=False)
+    assert lazy.blocks is None and lazy.host()[0] is X
+    np.testing.assert_array_equal(np.asarray(lazy.whole().X),
+                                  np.asarray(staged.X))
+    with pytest.raises(RuntimeError, match="stock resident schedule"):
+        lazy.host()
+    # under one block: one piece of the capacity's rows, no program
+    small = StagedAhead(X[:700], y[:700], capacity=1024).whole()
+    assert small.count == 1 and small.X.shape == (1024, 6)
+    np.testing.assert_array_equal(np.asarray(small.X)[:700], X[:700])
+
+
+@pytest.mark.parametrize("case", ["least_squares", "intercept", "mesh",
+                                  "too_large", "device", "listener"])
+def test_everything_else_keeps_arrays_of_its_own_rows(case, monkeypatch):
+    """The capacity form is the stock one-device logistic stream's alone: a
+    least-squares stream (its plan may be the statistics schedule, whose
+    totals are keyed by the micro-batch's own shape), a harness that
+    appends an intercept, an optimizer under a mesh or with a
+    per-iteration listener, a micro-batch whose capacity does not fit the
+    device twice over, and a device array all train arrays of their own
+    rows, as before."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_sgd.models import streaming
+
+    _blocked(monkeypatch, d=6)
+    stream = _uneven_logistic([2100, 3000, 2500])
+    model = StreamingLogisticRegressionWithSGD
+    if case == "least_squares":
+        model = StreamingLinearRegressionWithSGD
+    alg = model(step_size=0.2, num_iterations=4)
+    alg.set_initial_weights(np.zeros(6, np.float32))
+    if case == "intercept":
+        alg.algorithm.set_intercept(True)
+    if case == "mesh":
+        from tpu_sgd.parallel.mesh import data_mesh
+
+        alg.algorithm.optimizer.set_mesh(data_mesh(jax.devices()[:2]))
+    if case == "listener":
+        from tpu_sgd.utils.events import CollectingListener
+
+        alg.algorithm.optimizer.set_listener(CollectingListener())
+    if case == "too_large":
+        monkeypatch.setattr(streaming.plan_mod, "device_budget",
+                            lambda *a, **k: (4096 * 24 - 1, "test"))
+    if case == "device":
+        stream = [(jnp.asarray(X), y) for X, y in stream]
+    seen, real = [], alg.algorithm.run_warm
+
+    def run_warm(data, model):
+        seen.append(data[0])
+        return real(data, model)
+
+    monkeypatch.setattr(alg.algorithm, "run_warm", run_warm)
+    alg.train_on(iter(stream))
+    assert alg._batch_count == 3
+    assert alg._capacity == 0 or case == "too_large"
+    assert [np.shape(X)[0] for X in seen] == [2100, 3000, 2500]
+    assert not any(getattr(X, "capacity", 0) for X in seen)
+
+
+def test_a_capacity_form_is_trained_by_the_stock_schedule_alone():
+    """An optimizer whose schedule left the stock one after a micro-batch
+    was wrapped for its fit in turn trains the micro-batch's own rows (the
+    wrapper still holds them); one whose blocks are on the device already
+    is refused."""
+    from tpu_sgd import LogisticRegressionWithSGD
+    from tpu_sgd.optimize.gradient_descent import StagedAhead
+
+    (X, y), = _uneven_logistic([1500])
+    alg = LogisticRegressionWithSGD(0.2, 4, 0.0, 1.0)
+    alg.set_schedule("off")
+    want = np.asarray(alg.run((X, y)).weights)
+    from tpu_sgd.utils.events import CollectingListener
+
+    alg.optimizer.set_listener(CollectingListener())  # stepwise: no count
+    assert not alg.optimizer.trains_at_capacity()
+    got = alg.run((StagedAhead(X, y, capacity=2048, issue=False), y))
+    np.testing.assert_allclose(np.asarray(got.weights), want, atol=1e-6)
+    with pytest.raises(RuntimeError, match="stock resident schedule"):
+        alg.run((StagedAhead(X, y, capacity=2048), y))

@@ -23,7 +23,7 @@ from tpu_sgd.models import __all__ as _models_all
 from tpu_sgd.ops import *  # noqa: F401,F403
 from tpu_sgd.ops import __all__ as _ops_all
 from tpu_sgd.optimize import (GradientDescent, LBFGS, NormalEquations,
-                              OWLQN, Optimizer, run_lbfgs,
+                              OWLQN, Optimizer, row_capacity, run_lbfgs,
                               run_mini_batch_sgd)
 from tpu_sgd.parallel import data_mesh, make_mesh
 # NOTE: the bare `plan` FUNCTION is deliberately not re-exported here —
@@ -44,7 +44,7 @@ __all__ = (
     + list(_models_all)
     + list(_ops_all)
     + ["GradientDescent", "LBFGS", "NormalEquations", "OWLQN", "Optimizer",
-       "run_mini_batch_sgd", "run_lbfgs",
+       "run_mini_batch_sgd", "run_lbfgs", "row_capacity",
        "data_mesh", "make_mesh",
        "CostModel", "Plan", "device_budget", "plan_for",
        "plan_quasi_newton",

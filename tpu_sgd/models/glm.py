@@ -299,21 +299,31 @@ class GeneralizedLinearAlgorithm:
         with span("fit.run") as run_span:
             with span("fit.validate") as sp:
                 X, y = _as_arrays(data)
-                sp.set(rows=X.shape[0])
+                # a stream's micro-batch at a row capacity says its own rows
+                rows = X.rows if isinstance(X, StagedAhead) else X.shape[0]
+                sp.set(rows=rows)
                 if X.shape[0] == 0:
                     raise ValueError("empty input")
                 if self.num_features < 0:
                     self.num_features = X.shape[1]
                 if self.validate_data:
                     self.validators(X, y)
-            run_span.set(rows=X.shape[0], features=X.shape[1],
+            run_span.set(rows=rows, features=X.shape[1],
                          sparse=is_sparse(X))
             if initial_weights is None:
                 initial_weights = np.zeros((self._weight_dim(),), np.float32)
-            w0 = np.asarray(initial_weights, np.float32)
+            w0 = initial_weights
+            if (self.use_feature_scaling or self.add_intercept
+                    or not isinstance(w0, jax.Array)
+                    or w0.dtype != np.float32):
+                # float32 weights that lie on the device and are handed on
+                # as they are (a stream's warm start: the last fit's) stay
+                # there: fetched and sent again they would wait, a few KB,
+                # behind whatever else is on the wire
+                w0 = np.asarray(w0, np.float32)
             scaler = None
             if self.use_feature_scaling or self.add_intercept:
-                with span("fit.prepare", rows=X.shape[0]):
+                with span("fit.prepare", rows=rows):
                     if self.use_feature_scaling:
                         X, w0, scaler = self._scale_features(X, w0)
                     if self.add_intercept:

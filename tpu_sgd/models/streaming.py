@@ -26,15 +26,50 @@ rows form took 0.985).  Every other stream (logistic, a fraction under 1,
 the stock schedule, ``set_schedule("off")``, a shape the plan in hand is not
 for) goes as ROWS: blocks that need no device program, which land under the
 running fit and are made the one array when the chip is free; the fold
-waits only for what is left of the copy.  Spans (``obs.spans``):
-``stream.run`` is all of ``train_on``; on its thread ``stream.wait`` (the
-worker's answer, then in the rows form the blocks made whole),
-``stream.batch`` (``index``, ``rows``, ``ahead``: 1 where the worker was
+waits only for what is left of the copy.
+
+A stream's micro-batches are of UNEQUAL sizes (a DStream's RDD holds what
+arrived in the batch interval), and a program compiled for a row count
+would be compiled anew for every one of them.  So where no fit of the
+stream can run from statistics (every gradient but least squares, whose
+served path is the totals form above) a micro-batch whose size is not the
+one before it's goes in the rows form at a row CAPACITY and not at its own
+count (PERF.md, PR 52; ``StagedAhead``'s capacity form; a size that REPEATS
+keeps an array of its own rows: the program compiled for it is found
+again, so a stream of equal micro-batches trains as it did before): the
+rows land in a device array of
+``gradient_descent.row_capacity`` rows (a power of two of 1,024-row units
+over the largest micro-batch so far: a function of the sizes' scale, never
+of the sizes), the labels beside them, what lies past the real rows is
+zeros made on the device, and the fit takes the real count as an OPERAND
+(``ops.gradients.RowCount``): on a TPU the step's kernel bounds its grid by
+it, so the padding is neither read nor counted; the plan, the repeat-run
+key and every program are keyed by the capacity, so a stream probes, plans
+and compiles once.  A micro-batch over the capacity in hand raises it (new
+programs; the ``stream.regrow`` span counts it) and is trained whole.
+Unequal sizes also mean that a large micro-batch's copy does not fit under
+the fit of a small one before it, so where the device holds three arrays of
+the capacity the fold holds TWO such micro-batches ahead (the second lands
+under the fit before that one, which a small copy left the wire idle in),
+and the stream's array of the capacity is made once: each micro-batch's
+rows are written over the one trained before it, in place, so the device
+never frees an array of that size to find room for the next while blocks
+are landing beside it.
+
+Spans (``obs.spans``):
+``stream.run`` is all of ``train_on``; on its thread ``stream.wait``
+(``stream.take`` inside it: the worker's answer; then in the rows form
+``stream.whole``: the blocks made whole),
+``stream.batch`` (``index``, ``rows``: the real ones, ``ahead``: 1 where
+the worker was
 issuing this batch's copy before the previous batch's fit returned,
-``totals``: 1 where the fit ran from a bundle made ahead of it) around the
+``totals``: 1 where the fit ran from a bundle made ahead of it; at a
+capacity also ``capacity`` and ``rows_read``, what one step of its fit
+reads) around the
 fit's own spans and ``stream.publish`` (the stream position, the history's
 tail, the checkpoint, the listeners); on the worker's ``stream.stage``
-(``bytes``, ``blocks``, ``folded`` of a batch that went ahead).
+(``bytes``: what crossed the wire of X, ``blocks``, ``folded`` of a batch
+that went ahead, and ``rows``, ``capacity``, ``rows_read`` as above).
 
 Driver recovery (SURVEY.md §5.4c): the reference rides DStream
 checkpointing — a restarted driver resumes from the latest model and
@@ -65,7 +100,7 @@ from tpu_sgd.models.glm import (GeneralizedLinearAlgorithm,
                                 off_stock)
 from tpu_sgd.models.regression import LinearRegressionWithSGD
 from tpu_sgd.obs.spans import span
-from tpu_sgd.optimize.gradient_descent import StagedAhead
+from tpu_sgd.optimize.gradient_descent import StagedAhead, row_capacity
 
 Batch = Tuple[np.ndarray, np.ndarray]
 
@@ -77,7 +112,7 @@ def _shape_and_type(X):
 
 
 def _take(batches, began: threading.Event, stage: bool, training: list,
-          totals=None, alive=None):
+          totals=None, alive=None, at=None):
     """On ``train_on``'s worker thread: the stream's next micro-batch
     ``(X, y)``, None at its end.  Where ``stage`` allows it, dense host rows
     that fit the device's free memory come back as a :class:`StagedAhead`,
@@ -96,9 +131,15 @@ def _take(batches, began: threading.Event, stage: bool, training: list,
     ``alive``, the deque that counts them across the micro-batches: the wire
     does not stand between two micro-batches (PERF.md, PR 44).
 
-    Any other goes in the ROWS form, blocks that need no device program, and
+    Any other goes in the ROWS form, blocks that need no device program
+    (``at``, the stream whose micro-batches go at a row capacity, None where
+    none does: in an array of ``at._capacity_of`` rows, the labels beside
+    them, the real count an operand of the fit; else in one of its own
+    rows), and
     ``training`` holds the features the fold is about to train (taken out of
-    it here, so that no reference outlives the wait): the first block is
+    it here, so that no reference outlives the wait; of a micro-batch at a
+    capacity its LABELS, made whole behind the rows, which the next one's
+    are written over): the first block is
     issued only once they are whole on the device, when the blocks they were
     made of are gone.  Transfers do not wait for the chip, so a late join
     (its last blocks still on the wire, a stalled device) would else have a
@@ -106,8 +147,10 @@ def _take(batches, began: threading.Event, stage: bool, training: list,
     in one traced run; PERF.md, PR 40).
 
     ``stream.stage`` says ``bytes`` and ``blocks`` of a batch that went
-    ahead, and ``folded``: the blocks whose share was folded under the copy
-    (0 in the rows form)."""
+    ahead, ``folded``: the blocks whose share was folded under the copy
+    (0 in the rows form), and ``rows``; at a capacity also ``capacity`` and
+    ``rows_read`` (``GradientDescent.rows_read``), and ``bytes`` is what
+    crossed the wire of X, the last block's fill included."""
     with span("stream.stage") as sp:
         batch = next(batches, None)
         if batch is None:
@@ -115,18 +158,24 @@ def _take(batches, began: threading.Event, stage: bool, training: list,
         X, y = batch
         X = as_features(X)
         if (stage and isinstance(X, np.ndarray) and X.ndim == 2
-                and X.shape[0]
-                and X.nbytes <= plan_mod.device_budget()[0]):
-            folds = _shape_and_type(X) == totals
+                and X.shape[0]):
+            capacity = 0 if at is None else at._capacity_of(X)
+            if not capacity and X.nbytes > plan_mod.device_budget()[0]:
+                return X, y
+            folds = not capacity and _shape_and_type(X) == totals
             before = training.pop()
             if not folds:
                 jax.block_until_ready(before)
             del before
             began.set()
-            X = StagedAhead(X, y if folds else None, alive)
+            X = StagedAhead(X, y if folds or capacity else None, alive,
+                            capacity)
             if folds:
                 y = X.y
-            sp.set(bytes=X.nbytes, blocks=X.count, folded=X.folded)
+            if sp.live:
+                sp.set(bytes=X.wire_bytes, blocks=X.count, folded=X.folded,
+                       rows=X.rows,
+                       **(at._capacity_says(X) if capacity else {}))
         return X, y
 
 
@@ -143,6 +192,22 @@ class StreamingLinearAlgorithm:
         self.checkpoint_history_tail = None
         self._resume_skip = 0
         self._model_update_listeners: list = []
+        #: the row capacity the stream's micro-batches are trained at (0:
+        #: none yet); the worker and the fold both raise it
+        self._capacity = 0
+        #: the rows of the micro-batch before (``_capacity_of``)
+        self._rows_before = 0
+        #: the micro-batches the worker holds ahead of the one in training
+        #: where they go at the capacity (``_capacity_of`` says when 2)
+        self._ahead = 1
+        self._capacity_lock = threading.Lock()
+
+    @property
+    def capacity(self) -> int:
+        """The row capacity this stream's micro-batches are trained at
+        (``tpu_sgd.row_capacity`` over the largest so far); 0 while none has
+        been, or where they keep arrays of their own rows."""
+        return self._capacity
 
     def latest_model(self) -> GeneralizedLinearModel:
         if self.model is None:
@@ -153,9 +218,13 @@ class StreamingLinearAlgorithm:
         return self.model
 
     def set_initial_weights(self, weights, intercept: float = 0.0):
-        self.model = self.algorithm.create_model(
-            np.asarray(weights, np.float32), intercept
-        )
+        """``weights`` as float32 on the host; float32 weights that lie on
+        the device already stay there (the first fit takes them as the later
+        ones take the model's)."""
+        if not (isinstance(weights, jax.Array)
+                and weights.dtype == np.float32):
+            weights = np.asarray(weights, np.float32)
+        self.model = self.algorithm.create_model(weights, intercept)
         return self
 
     def set_checkpoint(self, manager_or_directory, every: int = 1,
@@ -290,9 +359,89 @@ class StreamingLinearAlgorithm:
         including an empty one, whose update is skipped like the
         reference skips empty RDDs — advances ``_batch_count``, so the
         count is the STREAM POSITION and a resumed replay's skip stays
-        aligned with the consumed prefix."""
-        self._publish(self._fit(as_features(X), y))
+        aligned with the consumed prefix.  A dense host micro-batch goes at
+        a row capacity where the stream's do (``_in_capacity``), so that
+        micro-batches of unequal sizes share their programs in turn as they
+        do ahead."""
+        self._publish(self._fit(self._in_capacity(as_features(X), y), y))
         return self.model
+
+    def _by_capacity(self) -> bool:
+        """Whether this stream's dense host micro-batches may be trained in
+        arrays of a row CAPACITY, their real row count an operand of the
+        fit, so that no program and no plan is made for a row count: where
+        the optimizer takes one (``GradientDescent.trains_at_capacity``:
+        the stock resident schedule on one device, and no statistics a plan
+        could choose to run from) and the harness hands it the matrix as it
+        is (no intercept column, no scaling).  Observed, never set; which
+        micro-batches then do is ``_capacity_of``'s, from their sizes."""
+        alg, opt = self.algorithm, self.algorithm.optimizer
+        takes = getattr(opt, "trains_at_capacity", None)
+        return (takes is not None and takes()
+                and not alg.add_intercept and not alg.use_feature_scaling)
+
+    def _capacity_of(self, X, beside: int = 1) -> int:
+        """The row capacity ``X`` is trained at; 0 where it keeps an array
+        of its own rows.  It does where its size REPEATS the size of the
+        micro-batch before it: a program compiled for that row count is
+        found again (a stream of equal micro-batches lowers, from its second
+        one on, the programs it lowered before there was a capacity, and
+        pays nothing for one); a size seen for the first time would compile
+        a program that no later micro-batch and no later process uses.  And
+        where ``beside`` arrays of the capacity do not fit the device's free
+        memory (1 for a micro-batch taken ahead: the batch in training is
+        counted in use, and is gone when the blocks are made whole beside
+        themselves; 2 in turn).  The capacity is ``row_capacity`` over the
+        largest micro-batch so far: one that passes the capacity in hand
+        RAISES it (the ``stream.regrow`` span; its fit plans and compiles
+        anew) and is trained whole.  As the capacity is made or raised the
+        stream also learns how many micro-batches go ahead at it
+        (``_ahead``): two where the device, empty, holds three arrays of it,
+        else one.  Asked once a micro-batch, in stream
+        order, by the worker or by the fold for one that comes in turn (a
+        micro-batch the worker hands on as it came, too large to go ahead,
+        is asked about again: it repeats itself, and the answer is 0 as
+        before)."""
+        with self._capacity_lock:
+            repeats = X.shape[0] == self._rows_before
+            self._rows_before = X.shape[0]
+            if repeats:
+                return 0
+            capacity = row_capacity(X, self._capacity)
+            nbytes = capacity * (X.nbytes // X.shape[0])
+            if beside * nbytes > plan_mod.device_budget()[0]:
+                return 0
+            if capacity > self._capacity:
+                if self._capacity:
+                    with span("stream.regrow", rows=X.shape[0],
+                              capacity=capacity, was=self._capacity):
+                        pass  # counted: a point, in both tracers
+                self._capacity = capacity
+                # TWO go ahead where the device holds three arrays of the
+                # capacity: the one in training and the rows of two
+                # micro-batches landing beside it (what is free as each is
+                # taken decides, above, whether it goes at all)
+                self._ahead = 2 if 3 * nbytes <= plan_mod.device_budget(
+                    empty=True)[0] else 1
+            return capacity
+
+    def _capacity_says(self, X) -> dict:
+        """What ``stream.stage`` and ``stream.batch`` say of a micro-batch
+        at a capacity beside its real ``rows``."""
+        return dict(capacity=X.capacity,
+                    rows_read=self.algorithm.optimizer.rows_read(X))
+
+    def _in_capacity(self, X, y):
+        """A dense host micro-batch that comes to its fit in turn, at the
+        stream's row capacity where it has one (``_by_capacity``): the
+        capacity form, its blocks issued inside the fit (``train.h2d``) as
+        the worker would have issued them; anything else as it is."""
+        if (isinstance(X, np.ndarray) and X.ndim == 2 and X.shape[0]
+                and self._by_capacity()):
+            capacity = self._capacity_of(X, beside=2)
+            if capacity:
+                return StagedAhead(X, y, capacity=capacity, issue=False)
+        return X
 
     def _fit(self, X, y) -> bool:
         """The batch optimizer from the latest model over one micro-batch;
@@ -343,7 +492,16 @@ class StreamingLinearAlgorithm:
         device holds the batch in training and the one ahead, never a
         third; in the totals form (a least-squares stream on the statistics
         schedule) no batch at all: 16 row blocks on their way and a 12 MB
-        bundle a batch.  The weights, the listeners' calls and the
+        bundle a batch.  Where the stream goes at a row capacity
+        (``_by_capacity``: a logistic stream; ``_capacity_of``: every
+        micro-batch whose size is not the one before it's) what the device
+        holds is ONE array of the CAPACITY's rows, which each micro-batch's
+        rows are written over in turn, so that micro-batches of unequal
+        sizes share one plan and one set of programs, and beside it the row
+        blocks of the one ahead; of TWO ahead where three arrays of the
+        capacity fit the device (``_capacity_of``), since a large
+        micro-batch's copy does not fit under the fit of a small one before
+        it.  The weights, the listeners' calls and the
         checkpoints are the in-turn fold's, bit for bit (a batch taken
         ahead and not yet trained has not advanced ``_batch_count``; its
         totals are folded by the programs, in the order, that
@@ -417,46 +575,79 @@ class StreamingLinearAlgorithm:
 
     def _fold_ahead(self, pool, batches) -> None:
         """``train_on``'s loop under its ``stream.run`` span, whose leaves
-        tile it: ``stream.wait`` (the worker's answer: in the totals form
+        tile it: in ``stream.wait`` ``stream.take`` (the worker's answer,
+        the oldest of the one or two takes it has been given: in
+        the totals form
         every block issued and every fold dispatched, the fit queues behind
-        the last of them on the device; in the rows form then the blocks
+        the last of them on the device) and in the rows form then
+        ``stream.whole`` (the blocks
         made whole), then in ``stream.batch`` the fit's own and
         ``stream.publish``; ``stream.stage`` is the worker's.
         ``stream.batch`` says ``totals`` 1 where its fit ran from a bundle
         made ahead of it."""
         alive = collections.deque()  # the worker's blocks not yet folded
+        pending = collections.deque()  # the worker's takes, in stream order
 
-        def take(training=None):
-            began = threading.Event()
-            return began, pool.submit(
-                _take, batches, began, self._stages_ahead(), [training],
-                self._totals_key(training), alive)
+        def take(training=None, ahead=1):
+            """Takes submitted until ``ahead`` are the worker's; each waits
+            for ``training`` to be whole before its first block."""
+            while len(pending) < ahead:
+                began = threading.Event()
+                pending.append((began, pool.submit(
+                    _take, batches, began, self._stages_ahead(), [training],
+                    self._totals_key(training), alive,
+                    self if self._by_capacity() else None)))
 
-        began, ahead = take()
+        take()
         under = 0  # 1: this batch's copy began under its predecessor's fit
+        spent = None  # the capacity form trained last: its array is the next's
         while True:
             with span("stream.wait"):
-                taken = ahead.result()
+                with span("stream.take"):  # a leaf: the worker's answer
+                    taken = pending.popleft()[1].result()
                 if taken is None:
                     return
                 X, y = taken
                 if isinstance(X, StagedAhead) and X.totals is None:
                     # the rows form; its blocks are folded where they lie if
                     # the plan has come to be for them since they were taken
-                    if self._totals_key(X) is not None:
+                    if not X.capacity and self._totals_key(X) is not None:
                         X = X.fold(y)
                         y = X.y
                     else:
-                        X = X.whole()
-            began, ahead = take(X)
+                        with span("stream.whole", blocks=X.count):
+                            X = X.whole(spent)
+                else:
+                    X = self._in_capacity(X, y)
+                spent = None
+            staged = isinstance(X, StagedAhead)
+            at_capacity = staged and bool(X.capacity)
+            # a micro-batch at a capacity that is copied inside its fit (a
+            # stream's first, before any plan): the next is taken after it,
+            # so that the two are never on the wire, and whole, at once
+            in_turn = at_capacity and X.X is None
+            if at_capacity and not in_turn:
+                # the labels are made whole behind the rows: ready when
+                # they are, and no reference to the rows, which the next
+                # micro-batch's are written over (``whole``)
+                take(X.y, self._ahead)
+            elif not in_turn:
+                take(X)
             with span("stream.batch") as turn:
                 if turn.live:
-                    turn.set(index=self._batch_count, rows=X.shape[0],
+                    turn.set(index=self._batch_count,
+                             rows=X.rows if staged else X.shape[0],
                              ahead=under,
-                             totals=int(isinstance(X, StagedAhead)))
+                             totals=int(staged and not at_capacity),
+                             **(self._capacity_says(X) if at_capacity
+                                else {}))
                 updated = self._fit(X, y)
-                under = int(began.is_set())
-                del taken, X, y  # gone before the next is made whole
+                if in_turn:
+                    take(None, self._ahead)
+                under = int(pending[0][0].is_set())
+                if at_capacity:
+                    spent = X  # its array stays: the next is made in it
+                del taken, X, y  # any other is gone before the next is whole
                 self._publish(updated)
 
     def predict_on(self, stream: Iterable[np.ndarray]) -> Iterator[np.ndarray]:
@@ -490,6 +681,24 @@ class StreamingLinearRegressionWithSGD(StreamingLinearAlgorithm):
 
 
 class StreamingLogisticRegressionWithSGD(StreamingLinearAlgorithm):
+    """[U] mllib/classification/StreamingLogisticRegressionWithSGD.scala:
+    ``LogisticRegressionWithSGD`` (the logistic gradient under the squared-L2
+    updater) re-run over every micro-batch from the latest weights, with
+    upstream's defaults: ``stepSize`` 0.1, ``numIterations`` 50,
+    ``miniBatchFraction`` 1.0, ``regParam`` 0.0 (at which the updater's rule
+    is the plain step ``w - 0.1 / sqrt(t) * g``).
+
+    A logistic fit has no statistics to run from: every step reads the
+    micro-batch's rows, so the stream ALWAYS goes by rows
+    (``StreamingLinearAlgorithm._by_capacity``): a micro-batch is kept whole
+    on the device while it trains, beside the next one landing in blocks
+    (the next two, where the device has the room: ``_capacity_of``).
+    The rows lie in the stream's array of its row CAPACITY, their real count
+    an operand of the fit, so that micro-batches of unequal sizes (what a
+    DStream delivers) share one plan and one set of compiled programs; a
+    micro-batch of the very size of the one before it keeps an array of its
+    own rows (``_capacity_of``: its program is found again)."""
+
     def __init__(
         self,
         step_size: float = 0.1,

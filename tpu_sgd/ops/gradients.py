@@ -119,6 +119,24 @@ class RowDraw:
             return drawn if self.valid is None else drawn & self.valid
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class RowCount:
+    """The row mask of an array that holds a CAPACITY of rows, the first
+    ``rows`` of them real (a stream's micro-batch: its size is data, so that
+    no program is compiled for it): what a fit is handed as ``valid`` in the
+    place of ``arange(n) < rows``.  Where every step's sums take the
+    one-read kernel that bounds its grid by a count
+    (``pallas_kernels.OneRead.bounds``) it goes to the kernel as it is, a
+    scalar, and the rows past it are neither read nor counted;
+    :func:`rows_valid` makes it the array it stands for everywhere else."""
+
+    rows: Array  # int32 scalar
+
+    def mask(self, n: int) -> Array:
+        return jnp.arange(n, dtype=jnp.int32) < self.rows
+
+
 def counter_draws() -> bool:
     """Whether ``jax.random.bernoulli`` under a key that
     ``jax.random.PRNGKey`` makes draws entry i from i alone: threefry2x32
@@ -150,6 +168,8 @@ def one_read_of(X, y, weights, mask=None, margin_axis_name=None,
     is no operand: its ``valid`` is."""
     if isinstance(mask, RowDraw):
         mask = mask.valid
+    if isinstance(mask, RowCount):  # a scalar: no row operand
+        mask = None
     if (margin_axis_name is not None or _is_sparse(X)
             or getattr(X, "ndim", 0) != 2 or jnp.ndim(weights) != 1
             or X.dtype not in (jnp.bfloat16, jnp.float32)):
@@ -192,6 +212,24 @@ class StepSums:
 def window_rows(cfg: SGDConfig, n_rows: int) -> int:
     """Rows of a sliced or indexed mini-batch over ``n_rows`` rows."""
     return max(1, round(cfg.mini_batch_fraction * n_rows))
+
+
+def rows_valid(gradient: "Gradient", cfg: SGDConfig, X, y, weights, valid,
+               model_axis_name=None):
+    """``valid`` as a fit's steps take it.  A :class:`RowCount` stays one
+    where every step is a full batch whose sums take a one-read kernel that
+    bounds its grid by the count (on a TPU; the same program lowered for
+    another platform makes the mask in ``Gradient._two_read_default``);
+    everywhere else (a sampled fit, a matrix of weights, the wide and
+    by-rows bodies, two reads) it is made the ``(n,)`` array it stands for,
+    here, once, and the fit is the one a padded shard's ``valid`` gets."""
+    if not isinstance(valid, RowCount):
+        return valid
+    if cfg.mini_batch_fraction >= 1.0:
+        kernel = gradient.one_read(X, y, weights, valid, model_axis_name)
+        if kernel is not None and kernel.bounds:
+            return valid
+    return valid.mask(X.shape[0])
 
 
 def step_sums(gradient: "Gradient", cfg: SGDConfig, X, y, weights,
@@ -284,6 +322,9 @@ class Gradient:
         lowered for another platform.
         """
         kernel = one_read_of(X, y, weights, mask, margin_axis_name)
+        if isinstance(mask, RowCount) and not (kernel and kernel.bounds):
+            mask = mask.mask(X.shape[0])  # ``rows_valid`` comes before
+            kernel = one_read_of(X, y, weights, mask, margin_axis_name)
         if kernel is not None:
             # both are traced; the platform the program is LOWERED for
             # picks one, so a CPU process compiling for the chip gets the
@@ -297,7 +338,7 @@ class Gradient:
     def _two_read_default(self, X, y, weights, mask, rows):
         """``platform_dependent``'s other branch: the ``(n,)`` operands,
         the mask drawn as an array."""
-        if isinstance(mask, RowDraw):
+        if isinstance(mask, (RowDraw, RowCount)):
             mask = mask.mask(X.shape[0])
         return self._two_read_sums(X, y, weights, mask)
 
@@ -331,6 +372,8 @@ class Gradient:
                          window) is None:
             return None
         n = X.shape[0]
+        if isinstance(valid, RowCount):  # the kernel's grid is bounded by it
+            valid = None
         return (pk.row_operand(y, n),
                 None if valid is None else pk.row_operand(valid, n))
 
@@ -344,6 +387,9 @@ class Gradient:
             draw, mask = (mask.key, mask.fraction), mask.valid
         y, mask = _kernel_rows(y, mask, rows)
         with jax.named_scope(kernel.scope):
+            if isinstance(mask, RowCount):  # by rows_valid the full scan
+                return pk.fused_bound_sums(self.pointwise, X, y, weights,
+                                           mask.rows)
             if kernel.by_rows:
                 return pk.fused_rows_sums(self.pointwise, X, y, weights,
                                           mask)

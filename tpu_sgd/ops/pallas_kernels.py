@@ -448,6 +448,14 @@ class OneRead:
     #: before's rule (:func:`_fm_ahead`; ``train.run``'s attribute)
     ahead: bool
 
+    @property
+    def bounds(self) -> bool:
+        """The body can bound its grid by a row count that is an operand
+        (:func:`fused_bound_sums`: a stream's micro-batch in an array of a
+        row capacity): the full scan of ``_fm_kernel`` alone; elsewhere the
+        count is made a row mask."""
+        return self.body == "scan"
+
 
 @functools.lru_cache(maxsize=256)
 def one_read(n: int, d: int, itemsize: int, masked: bool = True,
@@ -658,9 +666,19 @@ def _fm_kernel(pointwise, n, masked, window, draw, *refs):
     kernel draws itself (:func:`_row_draw`), ``refs`` starts with the
     prefetched key's two words; ``masked`` is then a padded shard's
     ``valid``, which the draw is multiplied by, and the count sums their
-    product."""
-    if window is not None or draw is not None:
+    product.
+
+    ``n`` None: the full scan BOUNDED by a row count that is an operand
+    (:func:`fused_bound_sums`: X holds a capacity of rows, the first so
+    many of them real), ``refs`` starts with the prefetched scalars ``(the
+    count, its last row's block)``; the blocks before that one run the
+    uncut body, that one is cut at the count as the static tail is, a grid
+    step past it names it again (no copy) and adds nothing."""
+    bound = n is None
+    if window is not None or draw is not None or bound:
         s_ref, refs = refs[0], refs[1:]
+    if bound:
+        n = s_ref[0]
     xt_ref, y_ref = refs[:2]
     m_ref = refs[2] if masked else None
     w_ref, g_ref, loss_ref, cnt_ref = refs[-4:]
@@ -751,6 +769,9 @@ def _fm_kernel(pointwise, n, masked, window, draw, *refs):
         last = s_ref[2] - s_ref[1]  # the grid step of the window's last row
         pl.when((i == 0) | (i == last))(lambda: block(True))
         pl.when((i > 0) & (i < last))(lambda: block(False))
+    elif bound:
+        pl.when(i < s_ref[1])(lambda: block(False))
+        pl.when(i == s_ref[1])(lambda: block(True))
     else:
         _fm_full_scan(block, n, tile)
 
@@ -820,6 +841,34 @@ def fused_gradient_sums(
     return _fused_scan_sums(
         pointwise, X, y, w, mask, key, fraction=fraction, tile_m=tile,
         interpret=interpret)
+
+
+def fused_bound_sums(
+    pointwise,
+    X: Array,
+    y: Array,
+    w: Array,
+    rows: Array,
+    tile_m: Optional[int] = None,
+    interpret: bool = False,
+) -> Tuple[Array, Array, Array]:
+    """Fused ``(grad_sum, loss_sum, count)`` over the FIRST ``rows`` rows of
+    ``X`` in one read of them, ``rows`` a traced scalar: ``X`` holds a row
+    CAPACITY (a stream's micro-batch, whose size no program is compiled
+    for) and what lies past ``rows`` is never copied to VMEM, never
+    computed on and never counted.
+
+    :func:`fused_gradient_sums`' kernel and grid over all of the capacity's
+    ``(d, tile_m)`` blocks of ``X.T``, with the count and the block of its
+    last row as prefetched scalars: a grid step past that block names it
+    again, which starts no copy, and runs no body (0.35 us of the chip's
+    for an empty step, a thousandth of a step at a quarter too much
+    capacity), and that block is cut at the count as the full scan's last
+    block is cut at its static ``n``.  ``count`` is ``rows``; ``rows`` is
+    clamped to ``[1, n]``."""
+    tile = _fm_tile_of(X, tile_m, False, interpret)
+    return _fused_bound_sums(pointwise, X, y, w, rows, tile_m=tile,
+                             interpret=interpret)
 
 
 def _fm_tile_of(X, tile_m: Optional[int], masked: bool, interpret: bool,
@@ -932,6 +981,45 @@ def _fused_scan_sums(
     )(*scalars, *operands)
     counted = masked or key is not None
     return _fold_sums(grad, loss, cnt, None if counted else n)
+
+
+# a name of its own, as the window's has: the compile cache keys on it
+@functools.partial(
+    jax.jit, static_argnames=("pointwise", "tile_m", "interpret")
+)
+def _fused_bound_sums(
+    pointwise,
+    X: Array,
+    y: Array,
+    w: Array,
+    rows: Array,
+    tile_m: int = FM_TILE,
+    interpret: bool = False,
+) -> Tuple[Array, Array, Array]:
+    n, d = X.shape
+    tile = tile_m
+    rows = jnp.clip(jnp.asarray(rows, jnp.int32), 1, n)
+    # what the index maps and the kernel read before any block moves: the
+    # count of real rows and the block of the last of them
+    scalars = jnp.stack([rows, (rows - 1) // tile])
+    at = lambda i, s: (0, jnp.minimum(i, s[1]))  # noqa: E731
+    whole = lambda shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda i, s: (0, 0))
+    grad, loss, cnt = pl.pallas_call(
+        functools.partial(_fm_kernel, pointwise, None, False, None, None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(n, tile),),
+            in_specs=[pl.BlockSpec((d, tile), at),
+                      pl.BlockSpec((1, tile), at), whole((d, LANES))],
+            out_specs=[whole((d, LANES)), whole((1, LANES)),
+                       whole((1, LANES))],
+        ),
+        out_shape=_fm_sums_shape(d),
+        compiler_params=_FM_COMPILER_PARAMS,
+        interpret=interpret,
+    )(scalars, X.T, row_operand(y, n), _w_along_lanes(w, d))
+    return _fold_sums(grad, loss, cnt, rows)
 
 
 def class_rows_of(C: int, dtype) -> int:

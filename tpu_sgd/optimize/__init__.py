@@ -3,6 +3,7 @@ from tpu_sgd.optimize.gradient_descent import (
     GradientDescent,
     make_run,
     make_step,
+    row_capacity,
     run_mini_batch_sgd,
 )
 from tpu_sgd.optimize.lbfgs import LBFGS, run_lbfgs
@@ -17,6 +18,7 @@ __all__ = [
     "OWLQN",
     "make_run",
     "make_step",
+    "row_capacity",
     "run_mini_batch_sgd",
     "run_lbfgs",
 ]

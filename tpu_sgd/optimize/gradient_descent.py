@@ -44,7 +44,8 @@ from tpu_sgd.config import SGDConfig
 from tpu_sgd.obs.spans import NO_SPAN, span
 from tpu_sgd.obs.timeseries import observe_scalar
 from tpu_sgd.ops.gradients import (Gradient, LeastSquaresGradient,
-                                   RowDraw, step_sums, window_rows)
+                                   RowCount, RowDraw, rows_valid,
+                                   step_sums, window_rows)
 from tpu_sgd.ops.gram import DEFAULT_BLOCK_ROWS
 from tpu_sgd.ops.sparse import is_sparse
 from tpu_sgd.ops.updaters import SimpleUpdater, Updater
@@ -122,6 +123,15 @@ def _coerce_w0(gradient, initial_weights, n_features):
 #: 24.6 to 25.3), so the blocks stay blocks and a block is ONE buffer.
 _STAGE_BLOCK_BYTES = 32 << 20
 _STAGE_IN_FLIGHT = 16
+#: ``StagedAhead``'s capacity form, whose blocks land under a running fit:
+#: whatever small thing crosses the wire meanwhile, the fit's count and
+#: losses on their way BACK among them, waits behind every block in flight
+#: (16 x 32.8 MB: 40 ms of an idle chip wherever a fit ended while the
+#: worker was issuing, PERF.md, PR 52), and MLlib's order publishes the
+#: model before the next fit starts.  4 in flight keep the one wire as busy
+#: (a pass of four micro-batches 0.968 to 0.974 s on eight seeds, where 16
+#: read 1.02 to 1.07 by the draw of the sizes); 2 starve it (1.40 s).
+_AHEAD_IN_FLIGHT = 4
 _STAGE_ROWS = 1024
 
 
@@ -158,18 +168,24 @@ def _stage_block(dest, block, offset):
         return dest, dest[offset, 0]
 
 
-def _block_rows(X, n=None) -> int:
+def _block_rows(X, n=None, capacity: int = 0) -> int:
     """The rows of one block of a dense numpy array's hand-off into a
     destination of ``n`` of its rows (all of them: one destination); 0 where
-    a destination goes in one piece (no matrix, or one block holds it all)."""
+    a destination goes in one piece (no matrix, or one block holds it all).
+    Into an array of ``capacity`` rows (``StagedAhead``'s capacity form):
+    the most ``_STAGE_ROWS`` times a POWER OF TWO that the block's bytes
+    hold (16,384 at d = 1000 in bf16 either way), so that the blocks divide
+    every ``row_capacity`` above them; a capacity below them is one block."""
     n = X.shape[0] if n is None else n
     if X.ndim != 2 or not n:
         return 0
     row_bytes = X.nbytes // X.shape[0]
+    units = max(1, _STAGE_BLOCK_BYTES // row_bytes // _STAGE_ROWS)
+    if capacity:
+        return min(_STAGE_ROWS << (units.bit_length() - 1), capacity)
     if n * row_bytes <= _STAGE_BLOCK_BYTES:
         return 0
-    rows = _STAGE_ROWS * max(
-        1, _STAGE_BLOCK_BYTES // row_bytes // _STAGE_ROWS)
+    rows = _STAGE_ROWS * units
     return rows if rows < n else 0
 
 
@@ -348,14 +364,33 @@ def _no_clock() -> float:
 def _rows_padded(X, lo, m):
     """Rows ``lo:lo + m`` of the host array, zero rows behind them where it
     ends before (a piece of at most one block: ``pad_to_multiple``'s rows
-    for one shard)."""
+    for one shard), in the array's own order (a Fortran-ordered array's
+    rows are copied a column at a time, each one run)."""
     import numpy as np
 
     piece = X[lo:lo + m]
     if piece.shape[0] == m:
         return piece
-    return np.concatenate(
-        [piece, np.zeros((m - piece.shape[0],) + X.shape[1:], X.dtype)])
+    out = np.zeros((m,) + X.shape[1:], X.dtype,
+                   order="F" if X.ndim > 1 and X.flags.f_contiguous else "C")
+    out[:piece.shape[0]] = piece
+    return out
+
+
+def row_capacity(X, held: int = 0) -> int:
+    """The rows of the device array that a stream's micro-batch ``X`` is
+    trained in where its row count is an operand and not a shape
+    (``StagedAhead``'s capacity form): ``_STAGE_ROWS`` times the power of
+    two that holds its rows, and no less than ``held``, the capacity the
+    stream has in hand.  A function of the SCALE of the sizes seen, never of
+    the sizes: every program of the fit is keyed by it, so a stream of any
+    number of distinct sizes within a factor of two compiles one set of
+    programs, the same set in every process (2,097,152 rows for every
+    micro-batch of 1,048,577 to 2,097,152)."""
+    capacity = _STAGE_ROWS
+    while capacity < X.shape[0]:
+        capacity *= 2
+    return max(capacity, held)
 
 
 def _sharded_by_rows(mesh, dests):
@@ -370,24 +405,55 @@ def _sharded_by_rows(mesh, dests):
         shape, NamedSharding(mesh, P(DATA_AXIS)), dests)
 
 
-@jax.jit
-def _stage_join(*blocks):
+@functools.partial(jax.jit, static_argnames="scope", donate_argnames="into",
+                   keep_unused=True)
+def _stage_join(*blocks, scope="sgd.stage", into=None):
     """``StagedAhead``'s row blocks as the one ``(N, d)`` array: one write a
     block and no fill (12.6 ms of the v5e's for 128 blocks of 32.8 MB).  The
     chip's compiler leaves the scope on the last of the writes alone, so the
     others read ``(unscoped)`` in a trace; written out as a chain of
     ``dynamic_update_slice`` into zeros they all keep it, and the chain
-    starts with a fill of the whole array (6.7 ms more: PERF.md, PR 40)."""
-    with jax.named_scope("sgd.stage"):
+    starts with a fill of the whole array (6.7 ms more: PERF.md, PR 40).
+    The capacity form joins under ``sgd.whole``, over as many blocks as the
+    CAPACITY holds whatever the micro-batch's own count (the rest are one
+    block of zeros, named again and again), so ONE program serves every
+    size up to the capacity.  ``into`` is an array of the result's shape
+    that is given up to hold it (donated: the result is written over it in
+    place; nothing of it is read), so that a stream never frees one array
+    of its capacity to find room for the next while blocks are landing
+    beside them; None: the result is a new array."""
+    with jax.named_scope(scope):
         return jnp.concatenate(blocks, axis=0)
 
 
 class StagedAhead:
     """A dense host array on its way to the device AHEAD of the fit that
     will train it, under a fit that is running (``StreamingLinearAlgorithm
-    .train_on``), in one of two forms; ``shape``, ``dtype`` and ``nbytes``
-    are the host array's in both, ``count`` its row blocks
-    (``_stage_dense``'s: ``_block_rows``).
+    .train_on``), in one of three forms; ``dtype`` and ``nbytes``
+    are the host array's in all, ``rows`` its rows, ``count`` its row blocks
+    (``_stage_dense``'s: ``_block_rows``), ``shape`` the shape the fit is
+    planned for and keyed by: the host array's, but in the capacity form.
+
+    The CAPACITY form (``capacity`` rows, ``y`` the labels that travel with
+    the rows; PERF.md, PR 52): the rows form below for a fit that takes the
+    row count as an OPERAND, so that no program is compiled for it.  The
+    blocks are the array's own rows, the last one filled to a whole block on
+    the host (``_rows_padded``: under a block of zero rows crosses the
+    wire), and the labels cross in front of them, in pieces of 16 blocks'
+    rows filled likewise.  ``whole()`` makes
+    them ``X``, ``(capacity, d)``, and ``y``, ``(capacity,)``, in one
+    program each (``_stage_join`` under ``sgd.whole``) that takes as many
+    blocks as the capacity holds, the ones the micro-batch does not fill
+    being ONE block of zeros made on the device: the rows past ``rows`` are
+    zeros, and the fit is handed ``valid``, ``RowCount(rows)``, which
+    crossed in front of them.
+    ``issue=False`` holds the host array until ``whole()`` is called: a
+    micro-batch that
+    comes to its fit IN TURN (a stream's first, before any plan;
+    ``train_on_batch``) has to be wrapped before ``run_warm`` plans, so that
+    the plan is the capacity's, and is copied inside its fit's
+    ``train.h2d`` like every in-turn copy: the same blocks, the same
+    programs.
 
     The ROWS form (no ``y``).  The device runs one program at a time, so
     ``_stage_dense``'s in-place writes (the programs that also make the
@@ -422,10 +488,25 @@ class StagedAhead:
     totals are the same bit for bit whichever thread issues them and
     whenever; ``fold`` makes them of a rows form's blocks where they lie."""
 
-    def __init__(self, X, y=None, alive=None):
+    def __init__(self, X, y=None, alive=None, capacity: int = 0,
+                 issue: bool = True):
         rows = _block_rows(X) or X.shape[0]
         self.shape, self.dtype, self.nbytes = X.shape, X.dtype, X.nbytes
         self.blocks, self.totals, self.y, self.folded = None, None, None, 0
+        self.rows, self.capacity, self.X = X.shape[0], capacity, None
+        self.valid = None  # the capacity form's row count, on the device
+        #: what crosses the wire of X (the capacity form: with its last
+        #: block's fill)
+        self._host, self.wire_bytes = None, X.nbytes
+        if capacity:
+            rows = _block_rows(X, capacity=capacity)
+            self.shape = (capacity,) + X.shape[1:]
+            self.count = -(-X.shape[0] // rows)
+            self.wire_bytes = self.count * rows * (X.nbytes // X.shape[0])
+            self._host = (X, y, rows)
+            if issue:
+                self._issue()
+            return
         starts = range(0, X.shape[0], rows)
         self.count = len(starts)
         if y is not None:
@@ -439,8 +520,89 @@ class StagedAhead:
                 self.blocks[-_STAGE_IN_FLIGHT].block_until_ready()
             self.blocks.append(jnp.asarray(X[a:a + rows]))
 
-    def whole(self):
-        """The one device array; the blocks are given up."""
+    def _issue(self):
+        """The capacity form's blocks issued in order: the row count first
+        (``valid``, the fit's operand: a scalar issued as the fit starts
+        would wait behind every block in flight on the one wire, 40 ms of
+        an idle chip a micro-batch: PERF.md, PR 52), then the labels, in
+        pieces of ``_STAGE_IN_FLIGHT`` blocks' rows (a buffer costs the
+        runtime the same whatever its size, and one a block beside the
+        rows' own slowed a micro-batch's hand-off by a third on the chip:
+        PERF.md, PR 52), then the rows.  The last block's fill is a copy of
+        up to a block on the host (13 to 30 ms for strided 2-byte rows), made
+        on a thread of its own while the blocks in front of it are issued."""
+        import numpy as np
+
+        (X, y, rows), self._host = self._host, None
+        y = np.asarray(y)
+        if not np.issubdtype(y.dtype, np.inexact):
+            y = y.astype(np.float32)
+        self.valid = RowCount(jnp.asarray(self.rows, jnp.int32))
+        pieces = min(self.capacity, rows * _STAGE_IN_FLIGHT)
+        self.labels = [jnp.asarray(_rows_padded(y, a, pieces))
+                       for a in range(0, y.shape[0], pieces)]
+        starts = range(0, X.shape[0], rows)
+        with ThreadPoolExecutor(1) as pool:
+            last = pool.submit(_rows_padded, X, starts[-1], rows)
+            self.blocks = []
+            for a in starts[:-1]:
+                if len(self.blocks) >= _AHEAD_IN_FLIGHT:
+                    # flow control: what the runtime re-tiles at once
+                    self.blocks[-_AHEAD_IN_FLIGHT].block_until_ready()
+                self.blocks.append(jnp.asarray(X[a:a + rows]))
+            self.blocks.append(jnp.asarray(last.result()))
+
+    def host(self):
+        """``(X, y)`` of a capacity form that holds its host array still (a
+        micro-batch wrapped for its fit in turn), for a fit whose OWN plan,
+        made after the wrapping, chose another schedule than the stock one
+        (a stream's first micro-batch under ``set_schedule`` of a schedule's
+        name, or where the planner finds the rows too many to keep)."""
+        if self._host is None:
+            raise RuntimeError(
+                "a micro-batch staged at a row capacity ahead of its fit "
+                "can be trained by the stock resident schedule alone, and "
+                "this optimizer's schedule was changed since it was staged")
+        return self._host[:2]
+
+    def whole(self, into=None):
+        """The one device array; the blocks are given up.  The capacity
+        form: itself, with ``X`` and ``y`` made (once).  ``into`` is a
+        capacity form that has been trained: its ``X`` is given up before
+        anything is made, and where it is of this one's shape and type this
+        one's rows are written over it in place (``_stage_join``'s
+        ``into``), so the stream's array of the capacity stays where it lies
+        from one micro-batch to the next."""
+        spent = None
+        if into is not None:
+            spent, into.X = into.X, None
+        if self.capacity:
+            if self.X is None:
+                if self._host is not None:
+                    self._issue()
+                if spent is not None and (
+                        spent.shape != self.shape
+                        or spent.dtype != self.blocks[0].dtype):
+                    spent = None  # another capacity's: gone, not written over
+                made = []
+                for blocks, dest in ((self.blocks, spent),
+                                     (self.labels, None)):
+                    slots = self.capacity // blocks[0].shape[0]
+                    if slots > 1:
+                        fill = slots - len(blocks)
+                        zeros = fill and _stage_dest(blocks[0].shape,
+                                                     blocks[0].dtype)
+                        made.append(_stage_join(*blocks, *[zeros] * fill,
+                                                scope="sgd.whole",
+                                                into=dest))
+                        for block in blocks:
+                            block.delete()
+                    else:
+                        made.append(blocks[0])
+                self.blocks = self.labels = None
+                self.X, self.y = made
+            return self
+        del spent
         blocks, self.blocks = self.blocks, None
         if len(blocks) == 1:
             return blocks[0]
@@ -687,6 +849,9 @@ def make_run(
             # a warm-started 2-D run records a block-local iteration-1 loss
             reg_val0 = jax.lax.psum(reg_val0, model_axis_name)
         losses0 = jnp.full((cfg.num_iterations,), jnp.nan, jnp.float32)
+        # a row count in ``valid``'s place stays one where the step's kernel
+        # bounds its grid by it, and is made its mask here, once, elsewhere
+        valid = rows_valid(gradient, cfg, X, y, w0, valid, model_axis_name)
         # once a fit: what the step's kernel reads of the labels (and of a
         # padded shard's ``valid``) in the layout it reads them in, so that
         # the loop's body holds the kernel and no relayout of an operand
@@ -1738,7 +1903,8 @@ class GradientDescent(Optimizer):
 
         # classes: K for a (K-1, d) matrix of weights, 2 for a vector
         with span("train.run", iterations=self.config.num_iterations,
-                  rows=np.shape(data[0])[0],
+                  rows=data[0].rows if isinstance(data[0], StagedAhead)
+                  else np.shape(data[0])[0],
                   classes=getattr(self.gradient, "num_classes", 2)
                   ) as run_span:
             return self._optimize(data, initial_weights, run_span)
@@ -1751,6 +1917,9 @@ class GradientDescent(Optimizer):
         X, y = data
         from tpu_sgd.ops.gram import GramData, GramLeastSquaresGradient
 
+        if (isinstance(X, StagedAhead) and X.capacity
+                and not self.trains_at_capacity()):
+            X, y = X.host()  # planned otherwise since: its rows as they are
         if isinstance(X, GramData):
             # Statistics-first input (build/build_streamed): the rows may
             # be virtual (beyond-HBM datasets), so coerce only y/w0 and
@@ -1935,8 +2104,18 @@ class GradientDescent(Optimizer):
         valid = None
         with span("train.h2d", bytes=sum(
                 a.nbytes for a in (X, y)
-                if isinstance(a, np.ndarray))) as h2d:
-            if self._hands_off_sharded(X):
+                if isinstance(a, np.ndarray)
+                or getattr(a, "_host", None) is not None)) as h2d:
+            if isinstance(X, StagedAhead) and X.capacity:
+                # a stream's micro-batch at a row capacity: its blocks were
+                # issued ahead of this fit, or are now (in turn: the same
+                # blocks, the same programs); its row count is an operand
+                h2d.set(blocks=X.count if X.X is None else 0,
+                        block_bytes=X.wire_bytes // X.count, shards=1,
+                        flat=0)
+                at = X if X.X is not None else X.whole()
+                X, y, valid = at.X, at.y, at.valid
+            elif self._hands_off_sharded(X):
                 # each row block to the device that owns its rows; y and
                 # the mask of padded rows lie sharded beside them
                 from tpu_sgd.parallel.data_parallel import shard_dataset
@@ -2010,7 +2189,7 @@ class GradientDescent(Optimizer):
         with self._substituted(X, y, sparse_X) as X, \
                 span("train.select") as select_span:
             fn, args, built = self._select(X, y, w0, sparse_X, placed,
-                                           run_span, select_span)
+                                           run_span, select_span, valid)
         with span("train.dispatch", built=int(built)):
             w, losses, n_rec = fn(*args)
             # the copies to the host ride behind the program, so the fit
@@ -2057,7 +2236,8 @@ class GradientDescent(Optimizer):
         finally:
             self.gradient = orig
 
-    def _select(self, X, y, w0, sparse_X, placed, run_span, select_span):
+    def _select(self, X, y, w0, sparse_X, placed, run_span, select_span,
+                valid=None):
         """``train.select``'s work for a fused fit: ``(fn, args, built)`` —
         each route names its compiled runner and its arguments, ONE call in
         ``train.dispatch`` runs them; ``built`` where the runner is a new
@@ -2127,9 +2307,10 @@ class GradientDescent(Optimizer):
                 args = (w0, Xd, yd) if valid is None else (w0, Xd, yd, valid)
             path = "mesh"
         else:
-            fn, runner = self._runner(with_valid=False), True
+            # ``valid`` on one device: a stream's row count (``RowCount``)
+            fn, runner = self._runner(with_valid=valid is not None), True
             path = "gram" if isinstance(X, GramData) else "fused"
-            args = (w0, X, y)
+            args = (w0, X, y) if valid is None else (w0, X, y, valid)
         if run_span.live:
             # (labels_prepared, row_tile, feature_blocks, mask_in_kernel,
             # by_rows, class_rows, ahead): evaluated only where a span
@@ -2347,6 +2528,41 @@ class GradientDescent(Optimizer):
         )
         self._streamed_gram_entry = (X, y, g, opts)
         return g
+
+    def trains_at_capacity(self) -> bool:
+        """Whether a dense matrix may reach this optimizer's fit in an array
+        of MORE rows than its own, its row count an operand
+        (``StagedAhead``'s capacity form, ``RowCount``): on the stock
+        resident schedule of one device alone, fused, under a gradient with
+        no statistics that a plan could choose to run from (every one but
+        exactly ``LeastSquaresGradient``, the planner's ``gram_able``,
+        whose totals are keyed by the matrix's own shape).
+        The other schedules size their state, their chunks or their
+        statistics from the rows; the observed per-iteration path counts
+        them on the host."""
+        return (self.mesh is None and not self.host_streaming
+                and not self.sufficient_stats and not self.streamed_stats
+                and type(self.gradient) is not LeastSquaresGradient
+                and self.listener is None
+                and self.checkpoint_manager is None)
+
+    def rows_read(self, staged) -> int:
+        """The rows one step of the fit of ``staged`` (a ``StagedAhead`` in
+        its capacity form) reads: its real rows rounded up to the row tile
+        where the step's kernel bounds its grid by them (a TPU, a vector of
+        weights over rows stored feature-major), else all of the
+        capacity's, masked."""
+        struct = jax.ShapeDtypeStruct
+        X = struct(staged.shape, jax.dtypes.canonicalize_dtype(staged.dtype))
+        y = struct(staged.shape[:1], jnp.float32)
+        w = struct((self.gradient.weight_dim(staged.shape[1]),), jnp.float32)
+        valid = RowCount(struct((), jnp.int32))
+        if jax.default_backend() == "tpu" and isinstance(
+                rows_valid(self.gradient, self.config, X, y, w, valid),
+                RowCount):
+            tile = self.gradient.one_read(X, y, w, valid).tile
+            return min(staged.capacity, -(-staged.rows // tile) * tile)
+        return staged.capacity
 
     def stats_in_totals(self) -> bool:
         """Whether the sufficient-stats substitution (``_maybe_gram``)

@@ -312,17 +312,21 @@ class CostModel:
 DEFAULT_COST_MODEL = CostModel()
 
 
-def device_budget(device=None, cost_model: CostModel = DEFAULT_COST_MODEL):
+def device_budget(device=None, cost_model: CostModel = DEFAULT_COST_MODEL,
+                  empty: bool = False):
     """``(free_bytes, source)`` for the target device — probed from
     ``device.memory_stats()`` when the backend reports it (TPU does),
-    otherwise the cost model's fallback.  ``source`` says which."""
+    otherwise the cost model's fallback.  ``source`` says which.
+    ``empty``: what the device would have free with nothing on it (for a
+    caller that sizes ALL it will hold, part of which is there already)."""
     import jax
 
     if device is None:
         device = jax.devices()[0]  # a backend that cannot start raises
     stats = device.memory_stats()  # None where the backend reports none
     if stats and stats.get("bytes_limit"):
-        free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+        free = stats["bytes_limit"] - (
+            0 if empty else stats.get("bytes_in_use", 0))
         return max(0.0, free * cost_model.hbm_safety), "memory_stats"
     return cost_model.hbm_bytes * cost_model.hbm_safety, "fallback"
 
