@@ -1,6 +1,8 @@
-"""One run of one cell: set-up (data from the seed, the entry point's object
-built once, the first fit), the window of back-to-back fits, and — outside
-both — the plain reference's fit that decides ``correct``.
+"""One run of one cell: the data from the seed (the harness's own work, timed
+apart as ``data_s``), the program's set-up (the entry point's object built
+once and the first fit: ``setup_s``, with the program's import), the window
+of back-to-back fits, and — outside all three — the plain reference's fit
+that decides ``correct``.
 
 ``run_cell`` does not look for a chip: ``bench/run.py`` does, before it calls
 this.  The tests call it tiny on the CPU."""
@@ -75,19 +77,29 @@ def _timed(fit):
 
 def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float,
              counter: CompileCounter, peaks=None, trace_dir=None,
-             log=print) -> dict:
+             log=print, import_s: float = 0.0) -> dict:
     """Returns the run's record; ``bench/run.py`` makes the last line of it.
-    ``t0`` is the ``time.perf_counter()`` the process started at."""
+    ``t0`` is the ``time.perf_counter()`` the process started at and
+    ``import_s`` the seconds ``import tpu_sgd`` took there, JAX already in.
+
+    ``setup_s`` is what the PROGRAM costs a job before its first timed row:
+    ``import_s`` plus the stretch from ``place``'s return (the generator's
+    arrays are where the job puts them) to the end of the first fit as every
+    later run of the checkout takes it.  The interpreter's and the runtime's
+    start, the cell's files, the generator and ``place`` are the harness's
+    and the machine's: ``data_s`` ends where they do, and ``process_s`` is
+    the whole stretch from ``t0`` (what ``setup_s`` read before PR 53)."""
     import jax
 
     config = cell.config
     iterations = int(config["num_iterations"])
     data_seed, sgd_seed = data_seed_of(seed), int(config["sampling_seed"])
     X, y = place(cell, *cell.generator.make(config, cell.rows, data_seed))
-    t_data = time.perf_counter() - t0
+    placed = time.perf_counter()
 
     # the first fit of the process at the cell's config, compile cache warm
     fit = cell.entry.prepare(config, X, y, sgd_seed)
+    prepared = time.perf_counter()
     misses = counter.misses
     first, first_fit_s = _timed(fit)
     cold_fit_s = None
@@ -99,7 +111,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float,
         jax.clear_caches()
         fit = cell.entry.prepare(config, X, y, sgd_seed)
         first, first_fit_s = _timed(fit)
-    setup_s = time.perf_counter() - t0
+    ready = time.perf_counter()
+    # warm: import + prepare + the first fit are all of set-up
+    prepare_s = prepared - placed if cold_fit_s is None else None
 
     # the window: that same object, fits back to back
     requests = counter.requests
@@ -142,9 +156,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float,
     run = {
         "workload": cell.name, "seed": seed, "rows": cell.rows,
         "iterations": iterations, "batch_rows": batch, "fits": timed,
-        "window_s": elapsed, "fit_s": fit_s, "data_s": t_data,
-        "first_fit_s": first_fit_s, "cold_first_fit_s": cold_fit_s,
-        "setup_s": setup_s,
+        "window_s": elapsed, "fit_s": fit_s, "data_s": placed - t0,
+        "import_s": import_s, "prepare_s": prepare_s,
+        "first_fit_s": first_fit_s,
+        "cold_first_fit_s": cold_fit_s,
+        "setup_s": import_s + (ready - placed), "process_s": ready - t0,
         "reference_s": reference_s, "compiles_in_window": compiles,
         "memory_peak_bytes": memory_peak, "checks": worst,
         "limits": config["limits"], "attempted": len(fits), "failed": failed,

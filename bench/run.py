@@ -12,6 +12,17 @@ one JSON object: ``correct``, ``attempted``, ``failed`` (fits), ``metrics``
 ``--trace 1``), ``device``, with ``--trace 1`` also ``breakdown``; ``run``
 holds the rest of the record and is for people.
 
+``setup_s`` is the PROGRAM's set-up, what a job that trains once pays before
+its first timed row: ``import_s`` (the seconds of ``import tpu_sgd``, taken
+below with JAX already imported) plus the stretch from the moment the
+generator's arrays are where the job puts them to the end of the first fit
+(``prepare``, the fit; in a checkout's first run also the fit that compiled,
+``jax.clear_caches()`` and both again).  The interpreter's and JAX's start,
+``jax.devices()``, the cell's files and the harness's generator are not the
+program's and are not in it: ``run`` keeps them as ``data_s`` (process start
+to the arrays placed) and ``process_s`` (process start to the end of the
+first fit: what ``setup_s`` read before PR 53).
+
 Compile cache: ``JAX_COMPILATION_CACHE_DIR`` if it is set (JAX reads it, no
 directory is set in code), else the fixed ``<checkout>/.jax_cache``; every
 program is kept, however short its compile.  Traces go to
@@ -57,8 +68,11 @@ def main(argv=None) -> int:
     parser.add_argument("--trace", type=int, choices=(0, 1), required=True)
     args = parser.parse_args(argv)
 
-    import tpu_sgd  # noqa: F401  (the system under test; absent -> no run)
     import jax
+
+    t = time.perf_counter()
+    import tpu_sgd  # noqa: F401  (the system under test; absent -> no run)
+    import_s = time.perf_counter() - t
 
     from bench import cells, harness, spans
 
@@ -78,7 +92,8 @@ def main(argv=None) -> int:
     run = harness.run_cell(
         cell, args.seed, args.seconds, bool(args.trace), T0,
         harness.CompileCounter(), peaks=peaks[device["kind"]],
-        trace_dir=os.path.join(REPO, ".bench_trace", cell.name))
+        trace_dir=os.path.join(REPO, ".bench_trace", cell.name),
+        import_s=import_s)
     run["compile_cache_dir"] = cache_dir
     device["memory_peak_bytes"] = run["memory_peak_bytes"]
     line = {"correct": run["failed"] == 0, "attempted": run["attempted"],

@@ -70,9 +70,87 @@ def test_rows_per_s_is_fits_times_iterations_times_nominal_batch(timed):
 
 
 def test_first_fit_is_inside_setup_and_the_reference_is_not(timed):
+    """``setup_s`` is the program's: the generator's ``data_s`` lies in front
+    of it and the reference's fit behind the window, both outside."""
     _, run, _ = timed
-    assert 0 < run["first_fit_s"] < run["setup_s"]
-    assert run["data_s"] < run["setup_s"] and run["reference_s"] > 0
+    assert 0 < run["first_fit_s"] <= run["setup_s"] < run["process_s"]
+    assert run["data_s"] > 0 and run["reference_s"] > 0
+    assert run["process_s"] == pytest.approx(
+        run["data_s"] + run["setup_s"] - run["import_s"])
+    if run["cold_first_fit_s"] is None:  # nothing compiled: no second fit
+        assert run["setup_s"] == pytest.approx(
+            run["import_s"] + run["prepare_s"] + run["first_fit_s"],
+            abs=5e-3)
+
+
+# -- setup_s's clock ------------------------------------------------------------
+
+class _Clock:
+    """``time`` for the harness: every reading costs a millisecond and
+    nothing else passes but what a test's ``sleep`` adds, so two runs of one
+    cell read the same ``setup_s`` to rounding."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def perf_counter(self):
+        self.now += 1e-3
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += seconds
+
+
+def _clocked(monkeypatch, counter, slow=None, import_s=0.0):
+    """One run of the windowed cell on a ``_Clock``; ``slow`` names the part
+    (``generator`` or ``entry``) that sleeps five seconds of it."""
+    clock = _Clock()
+    monkeypatch.setattr(harness, "time", clock)
+    name = "dense1000-logistic-sliced.resident"
+    cell = cells.Cell(name, ALL, _tiny(name))
+    real = {"generator": cell.generator.make, "entry": cell.entry.prepare}
+
+    class Slow:
+        @staticmethod
+        def make(*args):
+            clock.sleep(5.0)
+            return real["generator"](*args)
+
+        @staticmethod
+        def prepare(*args):
+            clock.sleep(5.0)
+            return real["entry"](*args)
+
+    if slow is not None:
+        setattr(cell, slow, Slow)
+    run = harness.run_cell(cell, 2**31 + 11, 0.01, False,
+                           clock.perf_counter(), counter,
+                           log=lambda line: None, import_s=import_s)
+    assert run["failed"] == 0 and run["cold_first_fit_s"] is None
+    return run
+
+
+CLOCKS = ("data_s", "import_s", "prepare_s", "first_fit_s", "setup_s",
+          "process_s")
+
+
+@pytest.mark.parametrize("slow, import_s, moved", [
+    ("generator", 0.0, {"data_s": 5.0, "process_s": 5.0}),
+    ("entry", 0.0, {"prepare_s": 5.0, "setup_s": 5.0, "process_s": 5.0}),
+    (None, 0.75, {"import_s": 0.75, "setup_s": 0.75}),
+], ids=["a_slow_generator_is_not_in_setup_s", "a_slow_prepare_is",
+        "the_programs_import_is"])
+def test_setup_s_moves_with_the_programs_work_alone(
+        slow, import_s, moved, monkeypatch, counter):
+    base = _clocked(monkeypatch, counter)
+    run = _clocked(monkeypatch, counter, slow, import_s)
+    for key in CLOCKS:
+        assert run[key] - base[key] == pytest.approx(moved.get(key, 0.0),
+                                                     abs=1e-9), key
+    assert run["rows_per_s"] == pytest.approx(base["rows_per_s"])
+    metrics = harness.metrics_of(cells.Cell(run["workload"], ALL), run,
+                                 trace=False)
+    assert metrics["setup_s"] == {"value": run["setup_s"], "unit": "s"}
 
 
 def test_every_number_compared_is_printed_beside_its_limit(timed):
@@ -154,6 +232,9 @@ def test_a_cold_compile_cache_takes_the_first_fit_again(counter, tmp_path):
         compilation_cache.reset_cache()
     assert run["cold_first_fit_s"] is not None and len(prepared) == 2
     assert run["failed"] == 0 and run["compiles_in_window"] == 0
+    # both fits, the one that compiled too, are inside set-up
+    assert run["cold_first_fit_s"] + run["first_fit_s"] < run["setup_s"]
+    assert run["setup_s"] < run["process_s"] and run["prepare_s"] is None
 
 
 # -- the timed path broken underneath ----------------------------------------

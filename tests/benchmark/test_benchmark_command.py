@@ -96,3 +96,72 @@ def test_every_argument_is_required(flag):
     del args[i:i + 2]
     done = _run(cells.REPO, {"JAX_PLATFORMS": "cpu"}, args)
     assert done.returncode == 2 and _results(done.stdout) == []
+
+
+STUBBED = '''
+import builtins, importlib.util, json, sys, time
+
+sys.path.insert(0, ".")
+spec = importlib.util.spec_from_file_location("bench_run", "bench/run.py")
+run = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(run)
+assert "jax" not in sys.modules and "tpu_sgd" not in sys.modules
+
+seen, real_import = {}, builtins.__import__
+
+
+def timed_import(name, *args, **kw):
+    if name != "tpu_sgd" or "began" in seen:
+        return real_import(name, *args, **kw)
+    seen.update(began=time.perf_counter(), jax_was_in="jax" in sys.modules)
+    try:
+        return real_import(name, *args, **kw)
+    finally:
+        seen["took"] = time.perf_counter() - seen["began"]
+
+
+class Device:
+    platform, device_kind = "tpu", "TPU v5 lite"
+
+
+def no_cache():  # called just before main() reads the devices
+    import jax
+    jax.devices = lambda: [Device()]
+    return "none"
+
+
+def run_cell(cell, seed, seconds, trace, t0, counter, **kw):
+    seen.update(import_s=kw["import_s"], t0_is_the_processes=t0 == run.T0)
+    return {"failed": 0, "attempted": 1, "memory_peak_bytes": 1,
+            "rows_per_s": 1.0, "setup_s": kw["import_s"] + 2.0,
+            "data_s": 1.0, "process_s": 3.0, "import_s": kw["import_s"]}
+
+
+builtins.__import__ = timed_import
+run.configure_compile_cache = no_cache
+from bench import harness
+harness.run_cell = run_cell
+code = run.main(sys.argv[1:])
+builtins.__import__ = real_import
+print(json.dumps(seen))
+sys.exit(code)
+'''
+
+
+def test_import_s_is_the_packages_import_with_jax_already_in():
+    """``bench/run.py`` itself, its devices and ``run_cell`` stubbed: the
+    ``import_s`` it hands on is the time of ``import tpu_sgd`` and of nothing
+    in front of it, and it is in the last line's ``setup_s``."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run([sys.executable, "-c", STUBBED] + ARGS,
+                          cwd=cells.REPO, env=env, capture_output=True,
+                          text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
+    line, seen = map(json.loads, done.stdout.strip().splitlines()[-2:])
+    assert seen["jax_was_in"] and seen["t0_is_the_processes"]
+    assert 0 < seen["import_s"] - seen["took"] < 0.02
+    assert line["metrics"]["setup_s"] == {"value": seen["import_s"] + 2.0,
+                                          "unit": "s"}
+    for key in ("data_s", "import_s", "process_s"):
+        assert key in line["run"], key

@@ -79,20 +79,27 @@ def paste(root: str) -> dict:
         "name": CONFIG, "source": "BASELINE.json config 4, uncut",
         "file": f"bench/configs/{CONFIG}.json", "reduced": ["data_parallel"],
         "why": "the north star's own sentence: an all-reduce across cores"})
+    # four chips where the copy's quota has room for one more such cell
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    room = four + 1 <= (len(bench["workloads"]) + 1) // 4
     bench["workloads"].append({
-        "name": CELL, "config": CONFIG, "traffic": JOB, "chips": 4,
+        "name": CELL, "config": CONFIG, "traffic": JOB,
+        "chips": 4 if room else 1,
         "why": "10M x 1000 bf16 over four chips through run(); the psum"})
     bench["per_layer"].append({
         "name": METRIC, "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "step", "moves": "rows_per_s",
         "workloads": [CELL]})
-    # and a prepared cell is promoted by pasting its entries as they stand
+    # and a prepared cell is promoted by pasting its entries as they stand;
+    # one that a PR has pasted already is in the copy and is not added twice
     prepared = os.path.join(bench_dir, "prepared")
     for name in sorted(os.listdir(prepared)):
         with open(os.path.join(prepared, name)) as f:
             more = json.load(f)
         for kind in ("configs", "workloads", "per_layer"):
-            bench[kind] += more.get(kind, [])
+            have = {entry["name"] for entry in bench[kind]}
+            bench[kind] += [entry for entry in more.get(kind, [])
+                            if entry["name"] not in have]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return bench

@@ -4,13 +4,12 @@ and NEW entries only, the prepared cells promoted, and the copy's contract
 and span tests run against it.
 
 The older rehearsal pastes a FOUR-chip cell (the shape of the cell that
-``dense1000-lsq-dp4.resident-sharded`` now is).  With that cell in, its copy
-holds two four-chip cells of six, over the quota its own contract test holds
-a benchmark to (one in four, rounded down, and one always), so it stays red
-until a ``benchmark`` PR edits it; a ``model_config`` PR may not.  What it
-checks — that the tests follow ``BENCHMARK.json`` and ``bench/``, so that a
-cell or a metric is added with new files and appended entries alone — is
-checked here at one chip, by its own functions."""
+``dense1000-lsq-dp4.resident-sharded`` now is) where the copy's quota of
+four-chip cells has room for it (one in four, rounded down) and a one-chip
+cell where it has not.  What both check, that the tests follow
+``BENCHMARK.json`` and ``bench/``, so that a cell or a metric is added with
+new files and appended entries alone, is checked here at one chip always, by
+the older file's own functions."""
 
 import json
 import os

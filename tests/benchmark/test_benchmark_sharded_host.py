@@ -95,24 +95,33 @@ def test_the_configuration_is_dp4s_value_for_value_but_for_the_path():
 
 
 def test_the_benchmark_grew_by_appended_entries_alone():
+    """The ORDER of the cell's own entries, by ``index``: later PRs append
+    behind them, so neither the end nor the length of a list is pinned."""
     bench = cells.benchmark()
-    assert bench["configs"][-1]["name"] == "dense1000-lsq-dp4-run"
-    assert bench["configs"][-1]["reduced"] == ["data_parallel"]
-    assert bench["workloads"][-1] == {
+    configs = [c["name"] for c in bench["configs"]]
+    at = configs.index("dense1000-lsq-dp4-run")
+    assert at > configs.index("dense1000-lsq-dp4")
+    assert bench["configs"][at]["reduced"] == ["data_parallel"]
+    workloads = [w["name"] for w in bench["workloads"]]
+    at = workloads.index(NAME)
+    assert at > workloads.index(RESIDENT)
+    assert bench["workloads"][at] == {
         "name": NAME, "config": "dense1000-lsq-dp4-run",
         "traffic": "from-host-sharded", "chips": 4,
-        "why": bench["workloads"][-1]["why"]}
-    assert [m["name"] for m in bench["per_layer"][-4:]] == METRICS
+        "why": bench["workloads"][at]["why"]}
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(METRICS[0])
+    assert names[first:first + 4] == METRICS
     for entry, unit, better, source, layer in zip(
-            bench["per_layer"][-4:], ("count", "GB/s", "ms", "ms"),
+            bench["per_layer"][first:first + 4], ("count", "GB/s", "ms", "ms"),
             ("higher", "higher", "lower", "lower"),
             ("program_span",) * 3 + ("device_trace",),
             ("model harness",) * 3 + ("step",)):
         assert entry == {"name": entry["name"], "unit": unit,
                          "better": better, "source": source, "layer": layer,
                          "moves": "rows_per_s", "workloads": [NAME]}
-    # no list of an accepted metric was touched for the cell
-    for entry in bench["per_layer"][:-4]:
+    # no list of a metric accepted before the cell was touched for it
+    for entry in bench["per_layer"][:first]:
         assert NAME not in entry.get("workloads", [])
     reported = {m["name"] for m in cells.Cell(NAME).metrics["per_layer"]}
     assert set(METRICS) <= reported and "step_roofline" in reported
@@ -120,12 +129,12 @@ def test_the_benchmark_grew_by_appended_entries_alone():
 
 
 def test_two_of_the_cells_take_four_chips_and_the_quota_holds():
+    """The quota as a share of however many cells there are: one in four,
+    rounded down, and one always, or the two the accepted benchmark has."""
     workloads = cells.benchmark()["workloads"]
     four = [w["name"] for w in workloads if w["chips"] == 4]
-    assert four == [RESIDENT, NAME] and len(workloads) == 9
-    assert len(four) <= max(1, len(workloads) // 4)
-    # spent: a third would need twelve cells
-    assert len(four) + 1 > max(1, (len(workloads) + 2) // 4)
+    assert four[:2] == [RESIDENT, NAME]
+    assert len(four) <= max(2, len(workloads) // 4)
 
 
 # -- the generator ------------------------------------------------------------------

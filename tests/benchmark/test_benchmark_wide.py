@@ -277,8 +277,10 @@ def test_the_two_metrics_are_the_wide_cells_and_move_rows_per_s():
         "name": "row_tile", "unit": "count", "better": "higher",
         "source": "program_span", "layer": "step", "moves": "rows_per_s",
         "workloads": [NAME]}
-    assert [m["name"] for m in bench["per_layer"]][-2:] == ["wide_sums_ms",
-                                                            "row_tile"]
+    # their order by index: later PRs append behind them
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names.index("row_tile") == names.index("wide_sums_ms") + 1
+    assert names.index("wide_sums_ms") > names.index("class_sums_ms")
     cell = cells.Cell(NAME)
     reported = {m["name"] for m in cell.metrics["per_layer"]}
     assert {"wide_sums_ms", "row_tile", "step_ms", "step_roofline"} <= reported
