@@ -34,6 +34,7 @@ and NormalEquations need dense row layouts and raise clear errors.
 
 from __future__ import annotations
 
+import sys
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
@@ -41,10 +42,9 @@ import numpy as np
 
 
 def is_sparse(X) -> bool:
-    """True when ``X`` is a sparse (BCOO) feature matrix."""
-    from jax.experimental.sparse import BCOO
-
-    return isinstance(X, BCOO)
+    """True when ``X`` is a sparse (BCOO) feature matrix; imports nothing."""
+    sparse = sys.modules.get("jax.experimental.sparse")  # not loaded: no BCOO
+    return isinstance(X, getattr(sparse, "BCOO", ()))
 
 
 def row_matrix_bcoo(x):
