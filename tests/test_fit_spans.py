@@ -24,12 +24,17 @@ OPTIMIZER_LEAVES = ["train.h2d", "train.select", "train.dispatch",
 
 
 class Sink:
+    """The fit's own spans; what a first fit BUILT (``build.*``, PR 55:
+    records under the root that lie over ``train.dispatch`` and the eager
+    programs around it, no part of the tiling) is kept apart."""
+
     def __init__(self):
-        self.records = []
+        self.records, self.builds = [], []
 
     def emit(self, kind, payload):
         if kind == "trace_span":
-            self.records.append(dict(payload))
+            (self.builds if payload["name"].startswith("build.")
+             else self.records).append(dict(payload))
 
     def named(self, name):
         return [p for p in self.records if p["name"] == name]
@@ -103,11 +108,13 @@ def test_a_fit_through_run_emits_each_leaf_once_in_order(sink, data):
     fit, = sink.named("fit.run")
     assert finish["parent_id"] == fit["span_id"]
     assert run["t0_s"] + run["dur_s"] <= finish["t0_s"]
+    # what the first fit built hangs under the OUTERMOST root, fit.run
+    assert {b["parent_id"] for b in sink.builds} == {fit["span_id"]}
     # the second fit of the object finds its runner where the first left it
-    del sink.records[:]
+    del sink.records[:], sink.builds[:]
     alg.run(data)
     _check_tiling(sink.records, RUN_LEAVES, {"fit.run", "train.run"})
-    assert sink.named("train.dispatch")[0]["built"] == 0
+    assert sink.named("train.dispatch")[0]["built"] == 0 and not sink.builds
 
 
 def test_a_fit_at_the_optimizer_boundary_emits_each_leaf_once_in_order(

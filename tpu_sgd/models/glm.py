@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_sgd.models.labeled_point import LabeledPoint, to_arrays
+from tpu_sgd.obs.builds import root
 from tpu_sgd.obs.spans import span
 from tpu_sgd.ops.sparse import append_bias_auto, is_sparse, row_matrix_bcoo
 from tpu_sgd.optimize.gradient_descent import StagedAhead
@@ -296,7 +297,7 @@ class GeneralizedLinearAlgorithm:
         initial_weights=None,
         initial_intercept: float = 0.0,
     ) -> GeneralizedLinearModel:
-        with span("fit.run") as run_span:
+        with span("fit.run") as run_span, root("fit.run", run_span):
             with span("fit.validate") as sp:
                 X, y = _as_arrays(data)
                 # a stream's micro-batch at a row capacity says its own rows

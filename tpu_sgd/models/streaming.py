@@ -99,6 +99,7 @@ from tpu_sgd.models.glm import (GeneralizedLinearAlgorithm,
                                 GeneralizedLinearModel, as_features,
                                 off_stock)
 from tpu_sgd.models.regression import LinearRegressionWithSGD
+from tpu_sgd.obs.builds import root
 from tpu_sgd.obs.spans import span
 from tpu_sgd.optimize.gradient_descent import StagedAhead, row_capacity
 
@@ -512,7 +513,8 @@ class StreamingLinearAlgorithm:
         batches = itertools.islice(stream, skip, None)
         pool = ThreadPoolExecutor(1, thread_name_prefix="tpu-sgd-stream")
         try:
-            with span("stream.run"):
+            with span("stream.run") as run_span, \
+                    root("stream.run", run_span):
                 self._fold_ahead(pool, batches)
         finally:
             pool.shutdown(wait=False, cancel_futures=True)

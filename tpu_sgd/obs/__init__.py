@@ -1,6 +1,6 @@
 """tpu_sgd.obs: the unified observability layer.
 
-Six pieces, one opt-in switch (ROADMAP items 1 and 3 both presuppose
+Seven pieces, one opt-in switch (ROADMAP items 1 and 3 both presuppose
 this surface: straggler detection for async replicas needs per-stage
 timings that run in production, and the closed production loop needs
 SLO assertions evaluated over a trace):
@@ -12,6 +12,11 @@ SLO assertions evaluated over a trace):
   reloads, checkpoint save/restore, retry/breaker/failpoint incidents),
   emitted as ``trace_*`` JSONL records on the shared
   ``JsonLinesEventLog`` contract;
+* **what a fit builds** (:mod:`tpu_sgd.obs.builds`) — the one piece that
+  is on without the switch: every trace, lowering and compile-or-cache-read
+  JAX makes under a fit, kept in memory as ``build.*`` spans of that fit
+  (``build_roots()``: which fit recompiled, which program, what it cost)
+  and, with tracing on, written under the fit's root span;
 * **runtime counters** (:mod:`tpu_sgd.obs.counters`) — the
   test-twin monkeypatch machinery (``tpu_sgd.analysis.runtime``)
   promoted to an always-on accounting layer: program dispatches,
@@ -67,11 +72,13 @@ from __future__ import annotations
 
 from typing import Optional
 
+from tpu_sgd.obs import builds
 from tpu_sgd.obs import counters
 from tpu_sgd.obs import detect
 from tpu_sgd.obs import flightrec
 from tpu_sgd.obs import spans
 from tpu_sgd.obs import timeseries
+from tpu_sgd.obs.builds import build_roots
 from tpu_sgd.obs.counters import RuntimeCounters, deltas, inc, snapshot
 from tpu_sgd.obs.spans import (current_subsystem, disable_tracing,
                                enable_tracing, event, span)
@@ -81,8 +88,8 @@ __all__ = [
     "span", "event", "inc", "snapshot", "deltas", "RuntimeCounters",
     "enable", "disable", "flush_counters", "flush_windows", "is_enabled",
     "enable_tracing", "disable_tracing", "current_subsystem",
-    "observe_scalar", "windows_snapshot", "detector_engine",
-    "spans", "counters", "timeseries", "detect", "flightrec",
+    "observe_scalar", "windows_snapshot", "detector_engine", "build_roots",
+    "spans", "builds", "counters", "timeseries", "detect", "flightrec",
 ]
 
 #: graftlint lock-discipline declaration (tpu_sgd/analysis): EMPTY on

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_sgd.config import SGDConfig
+from tpu_sgd.obs.builds import root
 from tpu_sgd.obs.spans import NO_SPAN, span
 from tpu_sgd.obs.timeseries import observe_scalar
 from tpu_sgd.ops.gradients import (Gradient, LeastSquaresGradient,
@@ -1906,7 +1907,7 @@ class GradientDescent(Optimizer):
                   rows=data[0].rows if isinstance(data[0], StagedAhead)
                   else np.shape(data[0])[0],
                   classes=getattr(self.gradient, "num_classes", 2)
-                  ) as run_span:
+                  ) as run_span, root("train.run", run_span):
             return self._optimize(data, initial_weights, run_span)
 
     def _optimize(self, data: Dataset, initial_weights: Array, run_span):
