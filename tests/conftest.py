@@ -63,3 +63,22 @@ def stepped_clock():
 @pytest.fixture
 def no_clock():
     return NoClock
+
+
+@pytest.fixture
+def compile_cache(tmp_path):
+    """An empty persistent compile cache directory for the length of a test
+    (every program kept, however short its compile): its path."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    wanted = {"jax_compilation_cache_dir": str(tmp_path / "cache"),
+              "jax_persistent_cache_min_compile_time_secs": 0.0,
+              "jax_persistent_cache_min_entry_size_bytes": -1}
+    before = {key: getattr(jax.config, key) for key in wanted}
+    for key, value in wanted.items():
+        jax.config.update(key, value)
+    compilation_cache.reset_cache()
+    yield str(tmp_path / "cache")
+    for key, value in before.items():
+        jax.config.update(key, value)
+    compilation_cache.reset_cache()

@@ -823,6 +823,42 @@ def test_dp_whole_run_at_the_four_chip_cells_shape_trains_each_shard_in_place(
     assert memory.temp_size_in_bytes < shard // 100
 
 
+def test_the_four_chip_cells_run_restored_from_its_export_is_the_traced_one(
+        mesh4, S):
+    """What the store of exported runners (``optimize/run_store.py``) hands
+    XLA for the four-chip cell: ``dp_run_fn``'s program exported with its
+    shardings, serialized, read back and called under a ``jax.jit``.  It
+    compiles for the four described chips to what the traced program does:
+    one kernel a shard over the shard as it is stored, one all-reduce, the
+    same arguments and temporaries, the ``sgd.*`` scopes in place."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpu_sgd.parallel.data_parallel import dp_run_fn
+    from tpu_sgd.parallel.mesh import DATA_AXIS
+
+    n = 10_000_000
+    fn = dp_run_fn(LeastSquaresGradient(), SimpleUpdater(),
+                   _cfg(step_size=1.0, num_iterations=100, reg_param=0.0,
+                        convergence_tol=0.0), mesh4, with_valid=False)
+    shapes = (S((D,), F32, NamedSharding(mesh4, P())),
+              S((n, D), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
+              S((n,), F32, NamedSharding(mesh4, P(DATA_AXIS))))
+    exported = jax.export.export(fn, platforms=("tpu",))(*shapes)
+    assert exported.nr_devices == 4
+    restored = jax.export.deserialize(exported.serialize())
+    compiled = jax.jit(lambda *a: restored.call(*a)).lower(*shapes).compile()
+    traced = fn.lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert text.count(" all-reduce(") == 1 and "all-gather" not in text
+    assert "sgd.allreduce" in text and "sgd.fused_sums" in text
+    assert _moves_of(text, n // 4, D) == [] and _moves_of(text, n, D) == []
+    mine, theirs = compiled.memory_analysis(), traced.memory_analysis()
+    assert mine.argument_size_in_bytes == theirs.argument_size_in_bytes
+    assert mine.temp_size_in_bytes == theirs.temp_size_in_bytes
+    assert mine.output_size_in_bytes == theirs.output_size_in_bytes
+
+
 # -- the hand-off -------------------------------------------------------------
 
 @pytest.mark.parametrize("rows", ["full", "remainder"])

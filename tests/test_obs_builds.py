@@ -1,7 +1,9 @@
 """The record of what a fit BUILDS (``tpu_sgd/obs/builds.py``): every trace,
 lowering and compile-or-cache-read JAX makes under ``fit.run``, ``train.run``
 or ``stream.run`` is kept as a ``build.*`` span of that root, tracing or not;
-a fit that builds nothing leaves nothing and costs nothing.
+a fit that builds nothing leaves nothing and costs nothing.  Beside them
+stands ``build.restore``: the first call of ``_runner``'s program through the
+store of exported runners (``tpu_sgd/optimize/run_store.py``).
 
 The ``jax.monitoring`` names the record depends on are the six in
 ``builds._KINDS``, ``_HIT``, ``_MISS`` and ``_READ``: a JAX that renames one
@@ -25,6 +27,7 @@ from tpu_sgd.obs import builds, spans as obs_spans
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KINDS = ("build.trace", "build.lower", "build.compile")
+RESTORE = "build.restore"
 
 
 @pytest.fixture(autouse=True)
@@ -113,7 +116,8 @@ def test_a_first_fit_leaves_one_root_with_sgd_runs_trace_lowering_and_compile(
     assert root["name"] == "train.run" and root["span_id"] == 0
     assert _named(root, kind), [s["fun"] for s in root["spans"]]
     for s in root["spans"]:
-        assert s["name"] in KINDS and s["thread"] == "MainThread"
+        assert s["name"] in KINDS + (RESTORE,) \
+            and s["thread"] == "MainThread"
         # on the root's clock and inside it
         assert root["start"] <= s["start"] <= s["end"] \
             <= root["start"] + root["dur_s"]
@@ -159,20 +163,8 @@ def test_a_new_step_size_builds_again_under_a_second_root():
 
 
 @pytest.fixture
-def cache_dir(tmp_path):
-    from jax.experimental.compilation_cache import compilation_cache
-
-    wanted = {"jax_compilation_cache_dir": str(tmp_path / "cache"),
-              "jax_persistent_cache_min_compile_time_secs": 0.0,
-              "jax_persistent_cache_min_entry_size_bytes": -1}
-    before = {key: getattr(jax.config, key) for key in wanted}
-    for key, value in wanted.items():
-        jax.config.update(key, value)
-    compilation_cache.reset_cache()
-    yield
-    for key, value in before.items():
-        jax.config.update(key, value)
-    compilation_cache.reset_cache()
+def cache_dir(compile_cache):
+    return compile_cache
 
 
 def test_the_cache_read_is_told_by_program(cache_dir):
@@ -188,6 +180,48 @@ def test_the_cache_read_is_told_by_program(cache_dir):
     # the directory was empty: nothing of the first fit was read from it
     assert {s["cache_hit"] for s in cold["spans"]
             if s["name"] == "build.compile"} == {0}
+
+
+def test_the_store_is_told_by_a_restore_span_under_the_root(cache_dir):
+    """``hit`` 0 where the runner was exported and stored (its trace and
+    lowering fire inside the span), 1 where it was read back: then nothing
+    of the package is traced under the root."""
+    X, y = _data(features=40)
+    _fit(_optimizer(), X, y)
+    jax.clear_caches()
+    _fit(_optimizer(), X, y)
+    cold, warm = obs.build_roots()
+    (stored,), (restored,) = (_named(r, RESTORE) for r in (cold, warm))
+    assert stored["hit"] == 0 and restored["hit"] == 1
+    assert "reason" not in stored and "reason" not in restored
+    for s, root in ((stored, cold), (restored, warm)):
+        assert s["fun"] == "sgd_run" and s["thread"] == "MainThread"
+        assert s["ms"] == pytest.approx((s["end"] - s["start"]) * 1e3)
+        assert root["start"] <= s["start"] <= s["end"] \
+            <= root["start"] + root["dur_s"]
+    inside = [t for t in _named(cold, "build.trace")
+              if stored["start"] <= t["start"] and t["end"] <= stored["end"]]
+    assert inside and restored["ms"] < stored["ms"]
+    assert not [t for t in warm["spans"] if t["name"] == "build.trace"
+                and t["end"] - t["start"] > 0.02]
+    assert _named(warm, "build.compile")[0]["cache_hit"] == 1
+
+
+def test_a_bypass_of_the_store_says_why():
+    if jax.config.jax_compilation_cache_dir:
+        pytest.skip("this process has a persistent cache")
+    sink = _Sink()
+    obs.enable_tracing(sink)
+    X, y = _data()
+    _fit(_optimizer(), X, y)
+    obs.disable_tracing()
+    (root,) = obs.build_roots()
+    (bypass,) = _named(root, RESTORE)
+    assert bypass["hit"] is None \
+        and bypass["reason"] == "no compile cache directory"
+    (record,) = sink.spans(RESTORE)
+    assert record["reason"] == bypass["reason"] and record["hit"] is None \
+        and record["fun"] == "sgd_run"
 
 
 def test_without_a_persistent_cache_the_hit_is_none():

@@ -1258,7 +1258,10 @@ def test_any_number_of_sizes_share_one_plan_and_one_set_of_programs(
     opt = alg.algorithm.optimizer
     (key, runner), = [(k, fn) for k, fn in opt._run_cache.items()
                       if k[0] == "run"]
-    assert key[-1] is True and runner._cache_size() == 1
+    # one signature resolved by the store's wrapper (no cache directory
+    # here: the jitted runner itself), traced once
+    assert key[-1] is True and len(runner._fns) == 1 \
+        and runner.fresh._cache_size() == 1
     # the rows' (the first array, and the one written over it), the labels'
     assert gd._stage_join._cache_size() == 3
     plans = [s for s in spans if s["name"] == "fit.plan"]

@@ -13,13 +13,20 @@ span               ``jax.monitoring`` event                            attrs
 ``build.trace``    ``/jax/core/compile/jaxpr_trace_duration``          ``fun``, ``thread``
 ``build.lower``    ``/jax/core/compile/jaxpr_to_mlir_module_duration`` ``fun``, ``thread``
 ``build.compile``  ``/jax/core/compile/backend_compile_duration``      ``fun``, ``thread``, ``cache_hit``, ``cache_read_ms``
+``build.restore``  none: ``optimize/run_store.py`` tells :func:`restored`  ``fun``, ``thread``, ``hit``, ``ms``, ``reason``
 =================  ==================================================  =====
 
 ``cache_hit`` is 1 where the persistent cache gave the executable
 (``/jax/compilation_cache/cache_hits``), 0 where it was compiled and written
 there (``.../cache_misses``: what ``bench/harness.py`` takes a cold first fit
 by) and None where no persistent cache took part; ``cache_read_ms`` is the
-retrieval (``.../cache_retrieval_time_sec``) where there was one.
+retrieval (``.../cache_retrieval_time_sec``) where there was one.  A
+``build.restore`` is the first call of ``GradientDescent._runner``'s program
+in the process, from the store's key to the program in hand: ``hit`` 1 where
+the stored export was read back (no trace and no lowering of the package's
+code follow, only the restored call's own), 0 where the runner was exported
+and stored (its trace and lowering fire inside the span), None with the
+``reason`` of a bypass, after which the runner builds as it always did.
 
 A ROOT is the outermost of ``fit.run``, ``train.run`` and ``stream.run`` open
 in the process (``root()``, entered beside the span).  The spans that fire
@@ -98,6 +105,15 @@ def _on_span(event, start, end, fun_name="", **_):
         return
     _BUILT.append((root, kind, start, end, fun_name,
                    threading.current_thread().name, hit, read_ms))
+
+
+def restored(fun: str, hit, reason, start: float, end: float) -> None:
+    """The store resolved ``fun`` between ``start`` and ``end`` (on
+    ``time.time()``): kept under the open root, dropped outside any."""
+    root = _OPEN
+    if root is not None:
+        _BUILT.append((root, "build.restore", start, end, fun,
+                       threading.current_thread().name, hit, reason))
 
 
 def _on_cache(event, seconds=0.0, **_):
@@ -190,6 +206,10 @@ def _attrs(b) -> dict:
     out = {"name": b[0], "fun": b[3], "thread": b[4]}
     if b[0] == "build.compile":
         out.update(cache_hit=b[5], cache_read_ms=b[6])
+    elif b[0] == "build.restore":
+        out.update(hit=b[5], ms=(b[2] - b[1]) * 1e3)
+        if b[6] is not None:
+            out["reason"] = b[6]
     return out
 
 
@@ -198,7 +218,8 @@ def build_roots() -> list:
     dict of ``name``, ``start`` (``time.time()``), ``dur_s``, ``span_id`` (0
     with tracing off), ``spans`` (dicts of ``name``, ``fun``, ``thread``,
     ``start``, ``end``; a ``build.compile`` also ``cache_hit`` and
-    ``cache_read_ms``) and ``short_traces`` / ``short_trace_s``."""
+    ``cache_read_ms``, a ``build.restore`` ``hit``, ``ms`` and on a bypass
+    ``reason``) and ``short_traces`` / ``short_trace_s``."""
     return [{"name": name, "start": start, "dur_s": dur_s,
              "span_id": span_id,
              "spans": [dict(_attrs(b), start=b[1], end=b[2]) for b in kept],

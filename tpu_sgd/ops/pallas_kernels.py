@@ -211,10 +211,30 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
+
+
+class _AtFirstBuild:
+    """``jax.experimental.pallas`` (1.4 s on the chip's machine: PERF.md,
+    PR 54) is imported where the first kernel is BUILT, not with the
+    package: a process whose first fit restores its program from the store
+    of exported runners (``optimize/run_store.py``) builds none.  The first
+    attribute read puts the module itself under the global's name."""
+
+    def __init__(self, alias: str, module: str):
+        self._alias, self._module = alias, module
+
+    def __getattr__(self, name: str):
+        import importlib
+
+        module = globals()[self._alias] = importlib.import_module(
+            self._module)
+        return getattr(module, name)
+
+
+pl = _AtFirstBuild("pl", "jax.experimental.pallas")
+pltpu = _AtFirstBuild("pltpu", "jax.experimental.pallas.tpu")
 
 SUBLANES = 8  # f32 sublane count
 
@@ -510,7 +530,7 @@ def one_read(n: int, d: int, itemsize: int, masked: bool = True,
         return None
     scope = ("sgd.class_sums" if class_rows else
              "sgd.wide_sums" if body == "wide" else "sgd.fused_sums")
-    return OneRead(body, by_rows, tile, fblock, pl.cdiv(d, fblock), limit,
+    return OneRead(body, by_rows, tile, fblock, -(-d // fblock), limit,
                    scope, draws=body == "scan" and n < 2**31,
                    class_rows=class_rows, ahead=_fm_ahead(class_rows))
 
@@ -898,8 +918,9 @@ def _fm_sums_shape(d: int):
             jax.ShapeDtypeStruct((1, LANES), f32)]
 
 
-_FM_COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("arbitrary",), vmem_limit_bytes=_FM_VMEM_LIMIT)
+def _fm_compiler_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",), vmem_limit_bytes=_FM_VMEM_LIMIT)
 
 
 def row_operand(a, n: int):
@@ -976,7 +997,7 @@ def _fused_scan_sums(
                        whole((1, LANES))],
         ),
         out_shape=_fm_sums_shape(d),
-        compiler_params=_FM_COMPILER_PARAMS,
+        compiler_params=_fm_compiler_params(),
         interpret=interpret,
     )(*scalars, *operands)
     counted = masked or key is not None
@@ -1016,7 +1037,7 @@ def _fused_bound_sums(
                        whole((1, LANES))],
         ),
         out_shape=_fm_sums_shape(d),
-        compiler_params=_FM_COMPILER_PARAMS,
+        compiler_params=_fm_compiler_params(),
         interpret=interpret,
     )(scalars, X.T, row_operand(y, n), _w_along_lanes(w, d))
     return _fold_sums(grad, loss, cnt, rows)
@@ -1536,7 +1557,7 @@ def _fused_lane_window_sums(
                        whole((1, LANES))],
         ),
         out_shape=_fm_sums_shape(d),
-        compiler_params=_FM_COMPILER_PARAMS,
+        compiler_params=_fm_compiler_params(),
         interpret=interpret,
     )(scalars, *operands)
     return _fold_sums(grad, loss, cnt, None if masked else m)

@@ -51,6 +51,7 @@ from tpu_sgd.ops.gram import DEFAULT_BLOCK_ROWS
 from tpu_sgd.ops.sparse import is_sparse
 from tpu_sgd.ops.updaters import SimpleUpdater, Updater
 from tpu_sgd.optimize.optimizer import Dataset, Optimizer
+from tpu_sgd.optimize.run_store import StoredRun
 
 Array = jax.Array
 
@@ -3062,7 +3063,10 @@ class GradientDescent(Optimizer):
         Rebuilt only when the plugin pair, config, or mesh changes —
         repeated ``optimize`` calls (the streaming mode's per-micro-batch
         pattern, SURVEY.md §3.3) hit XLA's compile cache instead of
-        retracing; measured ~3000x faster on repeat calls.
+        retracing; measured ~3000x faster on repeat calls.  Its first call
+        in a process goes through the store of exported runners beside the
+        compile cache (``optimize/run_store.py``): where the program is
+        there it is restored, not traced.
         """
         key = ("run", self.gradient, self.updater, self.config,
                self.mesh, with_valid)
@@ -3075,6 +3079,8 @@ class GradientDescent(Optimizer):
                                self.mesh, with_valid)
             else:
                 fn = jax.jit(make_run(self.gradient, self.updater, self.config))
+            fn = StoredRun(fn, self.gradient, self.updater, self.config,
+                           self.mesh, with_valid)
             self._run_cache[key] = fn
         return fn
 
