@@ -1790,3 +1790,108 @@ def test_a_by_rows_shard_of_a_meshed_fit_takes_the_kernel_where_it_lies(
     assert "bf16[%d,%d]{1,0}" % (n // 4, d) in call
     assert text.count(" all-reduce(") == 1
     assert _moves_of(text, n // 4, d) == [] and _moves_of(text, n, d) == []
+
+
+# -- rows kept in 8 bits: CIFAR-5m's pixels as they are published (PR 57) ------
+
+#: the cell's rows, width and classes (bench/configs/
+#: cifar5m-int8-multinomial.json): four of the source's six parts in the
+#: bytes that hold two as bfloat16
+CIFAR5M_INT8 = (4_001_792, 3072, 10)
+I8 = jnp.int8
+
+
+@pytest.mark.parametrize("case", ["the_cell", "vector_as_rows",
+                                  "padded_shard"])
+def test_the_int8_run_at_the_cells_shape_reads_the_bytes_once_where_they_lie(
+        S, case):
+    """``cifar5m-int8-multinomial.resident-classes`` (4,001,792 x 3,072 int8
+    rows, a ``(9, 3072)`` matrix of weights, fraction 1.0), a vector of
+    weights over int8 rows at 2,097,152 x 1,024, and the cell's shape under
+    a row mask (compile only): ONE Mosaic call a step in the by-rows form's
+    own jitted function, handed X AS THE ``s8`` PARAMETER LIES, ``{1,0}``;
+    no convert, copy, transpose or fusion outside it makes an array of X's
+    size in ANY type (a bf16 copy would be 24.6 GB, an f32 one 49.2: the
+    parent's, which could not run); the weights go in as 16 bf16 rows (the
+    operands' type, not X's), the gradient comes out as 16 f32 rows;
+    temporaries are under 1% of X and the arguments are X, one byte a
+    feature."""
+    import re
+
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+    from tpu_sgd.optimize.gradient_descent import make_run
+
+    n, d, K = CIFAR5M_INT8
+    grad, wd, scope, fn = (MultinomialLogisticGradient(K), (K - 1) * d,
+                           "sgd.class_sums", "_fused_rows_class_sums")
+    if case == "vector_as_rows":
+        n, d = ROWS_VECTOR
+        grad, wd, scope, fn = (LogisticGradient(), d, "sgd.fused_sums",
+                               "_fused_rows_sums")
+    cfg = _cfg(step_size=2.0 ** -12, num_iterations=100, reg_param=4.096,
+               convergence_tol=0.0, mini_batch_fraction=1.0)
+    args = [S((wd,), F32), S((n, d), I8), S((n,), F32)]
+    if case == "padded_shard":
+        args.append(S((n,), jnp.bool_))
+    compiled = jax.jit(make_run(grad, SquaredL2Updater(), cfg)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert scope in call and fn in call
+    assert "operand_layout_constraints={s8[%d,%d]{1,0}, " % (n, d) in call
+    assert compiled.input_formats[0][1].layout.major_to_minor == (0, 1)
+    # nothing but the parameter and its hand-ons has X's size, in any type
+    made = re.findall(
+        r"= (\w+)\[(?:%d,%d|%d,%d)\]\S* ([a-z-]+)\(" % (n, d, d, n), text)
+    assert {t for t, _ in made} == {"s8"}
+    assert [op for _, op in made if op not in _NO_MOVE] == []
+    assert "convert" not in {op for _, op in made}
+    # the weights in the OPERANDS' type, 16 rows; the gradient 16 f32 rows
+    assert "bf16[16,%d]" % d in call and "f32[16,%d]" % d in call
+    assert "s8[16,%d]" % d not in text
+    rest = re.sub(r"\w+\[1,%d\]" % n, "",  # the labels' row, a mask's
+                  text.replace("s8[%d,%d]" % (n, d), ""))
+    assert not re.search(r"\[%d,\d+\]|\[\d+,%d\]" % (n, n), rest)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < n * d // 100
+    assert n * d <= memory.argument_size_in_bytes < n * d * 1.01
+
+
+def test_the_int8_kernels_vmem_count_admits_what_the_compiler_admits(S):
+    """``_fm_vmem_bytes`` at one byte a feature (the block in int8, the
+    weights and the lane chunk's widened copy in bf16) stays above the
+    compiler's own count: the form's own tile, 2,048 rows where a bf16 block
+    takes 1,024, compiles when the compiler is asked for exactly what the
+    tile was counted at; a tile the count refuses is refused by the compiler
+    too; the largest tile the count's hint names compiles."""
+    import re
+
+    from tpu_sgd.ops import pallas_kernels as PK
+    from tpu_sgd.ops.gradients import MultinomialLogisticGradient
+
+    n, d, K = KERNEL_N, 3072, 10
+    rows = PK.class_rows_of(K - 1, I8)
+    rule = MultinomialLogisticGradient(K).class_rule
+
+    def lower(tile, limit=PK._FM_VMEM_LIMIT):
+        return jax.jit(lambda X, y, W: PK._class_call(
+            rule, X, y, W, None, tile, d, limit, False, True)).lower(
+                S((n, d), I8), S((n,), F32), S((rows, d), BF16))
+
+    own = PK.one_read(n, d, 1, False, rows)
+    assert own.by_rows and (own.tile, own.item_bytes, rows) == (2048, 1, 16)
+    assert PK.one_read(n, d, 2, False, rows).tile == 1024
+    counted = PK._fm_vmem_bytes(own.tile, d, 1, False, rows, by_rows=True)
+    assert counted <= PK._FM_VMEM_LIMIT
+    assert "tpu_custom_call" in lower(own.tile, counted).compile().as_text()
+    X = S((n, d), I8)
+    with pytest.raises(ValueError, match=r"tile_m <= \d+") as refused:
+        PK._check_fm_vmem(16384, X, False, rows, by_rows=True)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        lower(16384).compile()
+    tile = int(re.search(r"tile_m <= (\d+)", str(refused.value)).group(1))
+    assert own.tile <= tile < 16384
+    PK._check_fm_vmem(tile, X, False, rows, by_rows=True)
+    assert "tpu_custom_call" in lower(tile).compile().as_text()

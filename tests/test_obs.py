@@ -300,6 +300,9 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
                           # the class body's chunks ahead, past 128 class
                           # rows on a TPU alone (PR 50)
                           "ahead": 0,
+                          # a feature's bytes as the step reads it and the
+                          # products' operand type, on both paths (PR 57)
+                          "row_item_bytes": 4, "operand": "float32",
                           # from the totals of its rows (PR 41): logistic
                           "stats": 0}
         # the leaves tile the fit in this order (PR 37: train.select

@@ -10,6 +10,10 @@ answers a record where that parent answered None, and the tenth cell's row
 is new (``tests/test_class_rows.py`` holds the table by class rows).
 PR 50 gave the record ``ahead`` (the class body's lane chunks a chunk ahead,
 past 128 class rows): a second table over the same cases, ``AHEAD``.
+PR 57 admitted int8 rows where the chip stores them by rows (a block read as
+the bytes it is, widened in VMEM): the twelfth cell's row and the ``int8_*``
+edges are new, ``integer_rows`` (a feature-major width) keeps its None, and a
+third table, ``ROW_BYTES``, holds the record's ``item_bytes`` and ``operand``.
 And the arrows between the layers that ask: ``ops`` <- ``plan`` <-
 ``optimize``, one way."""
 
@@ -45,6 +49,9 @@ CASES = {
         n=2_500_000, d=1000, gradient="least_squares", valid=True),
     "imagenet1k-r50-multinomial.resident-classes": dict(
         n=1_281_167, d=2048, gradient=1000, fraction=1.0),
+    # PR 57: int8 rows, admitted where the chip stores them by rows
+    "cifar5m-int8-multinomial.resident-classes": dict(
+        n=4_001_792, d=3072, dtype="int8", gradient=10, fraction=1.0),
     # the edges
     "by_rows_no_lane_multiple": dict(n=2**20, d=1020),
     "by_rows_11648_bf16": dict(n=2**16, d=11_648, fraction=1.0),
@@ -74,6 +81,18 @@ CASES = {
     "bcoo": dict(n=64, d=1000, sparse=True),
     "feature_sharded": dict(n=2**20, d=1000, axis="model"),
     "integer_rows": dict(n=2**20, d=1000, dtype="int8"),
+    "int8_by_rows_vector_full_batch": dict(n=2_097_152, d=1024, dtype="int8",
+                                           fraction=1.0),
+    "int8_by_rows_vector_masked": dict(n=2_097_152, d=1024, dtype="int8"),
+    "int8_by_rows_padded_shard": dict(n=1_000_448, d=3072, dtype="int8",
+                                      gradient=10, fraction=1.0, valid=True),
+    "int8_window_by_rows": dict(n=2_097_152, d=1024, dtype="int8",
+                                sampling="sliced"),
+    "int8_feature_major_classes": dict(n=8_100_000, d=784, dtype="int8",
+                                       gradient=10, fraction=1.0),
+    "int8_by_rows_11648": dict(n=2**16, d=11_648, dtype="int8",
+                               fraction=1.0),
+    "uint8_by_rows": dict(n=2_097_152, d=1024, dtype="uint8", fraction=1.0),
     "indexed": dict(n=4_194_304, d=1000, sampling="indexed"),
     "rbg": dict(n=4_194_304, d=1000, prng="rbg"),
     "partitionable_flag_off": dict(n=4_194_304, d=1000, prng="flag_off"),
@@ -116,6 +135,9 @@ EXPECT = {
         (1, 2048, 1, 1, 0), False),
     "imagenet1k-r50-multinomial.resident-classes": (
         ("class", True, 2048, 2048, 1, 100, "sgd.class_sums", False),
+        (1, 2048, 1, 0, 1), False),
+    "cifar5m-int8-multinomial.resident-classes": (
+        ("class", True, 2048, 3072, 1, 32, "sgd.class_sums", False),
         (1, 2048, 1, 0, 1), False),
     "by_rows_no_lane_multiple": (
         None,
@@ -183,6 +205,27 @@ EXPECT = {
     "integer_rows": (
         None,
         (0, 0, 1, 0, 0), True),
+    "int8_by_rows_vector_full_batch": (
+        ("class", True, 2048, 1024, 1, 32, "sgd.fused_sums", False),
+        (1, 2048, 1, 0, 1), False),
+    "int8_by_rows_vector_masked": (
+        ("class", True, 2048, 1024, 1, 32, "sgd.fused_sums", False),
+        (1, 2048, 1, 0, 1), True),
+    "int8_by_rows_padded_shard": (
+        ("class", True, 2048, 3072, 1, 32, "sgd.class_sums", False),
+        (1, 2048, 1, 0, 1), False),
+    "int8_window_by_rows": (
+        None,
+        (0, 0, 1, 0, 0), False),
+    "int8_feature_major_classes": (
+        None,
+        (0, 0, 1, 0, 0), False),
+    "int8_by_rows_11648": (
+        ("class", True, 256, 11648, 1, 32, "sgd.fused_sums", False),
+        (1, 256, 1, 0, 1), False),
+    "uint8_by_rows": (
+        None,
+        (0, 0, 1, 0, 0), False),
     "indexed": (
         None,
         (0, 0, 1, 0, 0), False),
@@ -266,6 +309,36 @@ def test_the_record_says_which_order_the_class_bodys_chunks_take(case):
         return
     assert k.ahead == (case in AHEAD) == PK._fm_ahead(k.class_rows)
     assert k.ahead <= (k.body == "class")
+
+
+#: the cases whose record reads ONE byte a feature from HBM (int8 rows,
+#: widened to bf16 operands in VMEM: PR 57); float32 rows read four and
+#: keep float32 operands; every other record two, bf16
+ROW_BYTES = {**{case: (1, "bfloat16") for case in (
+    "cifar5m-int8-multinomial.resident-classes",
+    "int8_by_rows_vector_full_batch", "int8_by_rows_vector_masked",
+    "int8_by_rows_padded_shard", "int8_by_rows_11648")},
+    **{case: (4, "float32") for case in (
+        "by_rows_f32_embeddings", "f32_masked", "f32_4000_features",
+        "one_cut_block")}}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_record_says_what_a_feature_costs_and_the_operands_type(case):
+    import jax.numpy as jnp
+
+    from tpu_sgd.ops.gradients import matmul_dtype, step_sums
+
+    g, cfg, X, y, w, valid, axis, how = _operands(**CASES[case])
+    with how:
+        k = step_sums(g, cfg, X, y, w, valid, axis).kernel
+    if k is None:
+        assert case not in ROW_BYTES
+        return
+    assert (k.item_bytes, k.operand) == ROW_BYTES.get(case, (2, "bfloat16"))
+    # the record and the two-read contract name one operand type
+    assert k.item_bytes == X.dtype.itemsize
+    assert k.operand == jnp.dtype(matmul_dtype(X)).name
 
 
 #: layer -> the packages it lies below and imports nothing of

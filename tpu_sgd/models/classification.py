@@ -400,7 +400,7 @@ class LogisticRegressionWithLBFGS(GeneralizedLinearAlgorithm):
             # runs auto-plan, set_schedule forces or raises — exactly as
             # the harness path does
             self._auto_plan(X, np.asarray(y))
-            weights = self.optimizer.optimize((X, np.asarray(y)), w0)
+            weights = self._optimize(X, np.asarray(y), w0)
             if scaler is not None:
                 W = np.array(weights, np.float32).reshape(K - 1, d + 1)
                 W[:, :d] = W[:, :d] * np.asarray(scaler.factor)[None, :]

@@ -21,7 +21,7 @@ from tpu_sgd.models.labeled_point import LabeledPoint, to_arrays
 from tpu_sgd.obs.builds import root
 from tpu_sgd.obs.spans import span
 from tpu_sgd.ops.sparse import append_bias_auto, is_sparse, row_matrix_bcoo
-from tpu_sgd.optimize.gradient_descent import StagedAhead
+from tpu_sgd.optimize.gradient_descent import GradientDescent, StagedAhead
 from tpu_sgd.optimize.optimizer import Optimizer
 
 DatasetLike = Union[Tuple, Iterable[LabeledPoint]]
@@ -291,6 +291,19 @@ class GeneralizedLinearAlgorithm:
         """Input validation hook; classifier subclasses check label sets."""
 
     # -- training ----------------------------------------------------------
+    def _optimize(self, X, y, w0):
+        """``run``'s call into its optimizer, the one place it is made.
+        Under ``run`` integer input is trained in float32, cast after the
+        copy.  ``GradientDescent``, which at its own boundary trains 8-bit
+        integer rows as the integers they are, is told so with the call;
+        ``LBFGS``, ``OWLQN`` and ``NormalEquations`` compute integer rows
+        of every width in float32 themselves; any other optimizer is
+        handed them as they came, as ever."""
+        if (isinstance(self.optimizer, GradientDescent) and not is_sparse(X)
+                and not jnp.issubdtype(X.dtype, jnp.inexact)):
+            return self.optimizer._fit((X, y), w0, integers_f32=True)[0]
+        return self.optimizer.optimize((X, y), w0)
+
     def run(
         self,
         data: DatasetLike,
@@ -335,7 +348,7 @@ class GeneralizedLinearAlgorithm:
                         w0 = np.concatenate(
                             [w0, np.asarray([initial_intercept], np.float32)])
             self._auto_plan(X, y)
-            weights = self.optimizer.optimize((X, y), w0)
+            weights = self._optimize(X, y, w0)
             with span("fit.finish"):
                 intercept = 0.0
                 if self.add_intercept:

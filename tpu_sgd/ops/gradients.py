@@ -48,12 +48,12 @@ SWEEP_BUDGET_ELEMS = 64_000_000
 
 
 def matmul_dtype(X: Array):
-    """The shared mixed-precision contract for every hot-path matmul: run in
-    the data's dtype with f32 accumulation (bf16 data keeps both MXU passes
-    bf16, halving HBM traffic; a plain ``X @ w`` would silently promote the
-    whole X read to f32), while int/bool features (one-hot paths that skip
-    the harness cast) compute in f32 so weights are never truncated."""
-    return X.dtype if jnp.issubdtype(X.dtype, jnp.inexact) else jnp.float32
+    """The shared mixed-precision contract for every hot-path matmul:
+    ``pallas_kernels.operand_dtype`` of the rows' type, its one home (the
+    one-read kernels read it there).  Float data in its own dtype with f32
+    accumulation, 8-bit integer rows as the bf16 values they exactly are,
+    ``bool`` and wider integers in f32."""
+    return pk.operand_dtype(X.dtype)
 
 
 def acc_dtype(mm_dtype):
@@ -156,7 +156,8 @@ def one_read_of(X, y, weights, mask=None, margin_axis_name=None,
     ``window_sums`` over a window of so many rows), as the record
     ``pallas_kernels.one_read`` makes of their shape, None where the sums
     take two reads.  Decided from what the operands look like, nothing
-    else.  HERE: dense 2-D bf16 or f32 rows, a flat weight vector
+    else.  HERE: dense 2-D bf16, f32 or int8 rows (int8 where the chip
+    stores them by rows: THERE), a flat weight vector
     (``classes`` None: one entry a feature; a class count: the row-major
     flattening of a ``(classes - 1, d)`` matrix), one label (and one mask
     entry) a row, whole margins on every core (a feature-sharded run needs
@@ -172,7 +173,7 @@ def one_read_of(X, y, weights, mask=None, margin_axis_name=None,
         mask = None
     if (margin_axis_name is not None or _is_sparse(X)
             or getattr(X, "ndim", 0) != 2 or jnp.ndim(weights) != 1
-            or X.dtype not in (jnp.bfloat16, jnp.float32)):
+            or X.dtype not in (jnp.bfloat16, jnp.float32, jnp.int8)):
         return None
     n, d = X.shape
     if (jnp.shape(y) != (n,)

@@ -186,6 +186,25 @@ are there, and the matrix unit's adds wait behind it: 60.14).  In a fit
 56.08 ms a step, 94.9% of the roofline, 22.79M rows/s against 21.71M
 (PERF.md, PR 50).
 
+**Rows kept in 8 bits (PR 57).**  An ``int8`` X is trained as the integers
+it holds (:func:`operand_dtype`: every int8 is exact in bfloat16, so both
+products take bf16 operands, the rows widened exactly, the weights and the
+coefficients rounded as for bf16 rows).  The by-rows form alone admits them
+(:func:`one_read`): a ``(tile, d)`` int8 block is one contiguous piece of
+``tile * d`` BYTES (an int8 array is tiled ``(32, 128)``, and every row tile
+is a multiple of 128), read from HBM once; the body widens each lane chunk
+of it to bf16 in VMEM, once, and both products read that copy
+(``_fm_class_kernel``'s ``x_of``), so no bf16 or f32 copy of X exists in
+HBM.  A block is half a bf16 block's bytes, so the row tile is 2,048 at
+3,072 features where bf16 rows take 1,024; full blocks are taken in lane
+chunks of 1,024 rows in order whatever the tile, so the sums are the bf16
+body's on the same values BIT FOR BIT.  The widening hides under the copy:
+over 4,001,792 x 3,072 int8 rows a call alone reads 16.39 ms at tiles of
+1,024, 2,048 and 4,096, widened once a chunk, once a product, or through
+f32 (16.36 to 16.43; 17.05 at a tile of 512): 750 GB/s of int8, the bf16
+body's pace at twice the rows (PERF.md, PR 57).  Feature-major int8 rows
+(d = 784, 1000) take two reads under the same contract.
+
 **What the old verdict rested on.**  A second family, window kernels over
 ``(tile, d)`` ROW blocks at a scalar-prefetched row offset, ran 3.1-3.4 ms an
 iteration against XLA's 1.64 on a 3M x 1000 bf16 window (round 2, TPU v5
@@ -244,6 +263,37 @@ LANES = 128
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def operand_dtype(dtype):
+    """The type both products' operands take for rows of ``dtype``: THE
+    mixed-precision contract, on every path (``ops/gradients.matmul_dtype``
+    is this of ``X.dtype``; the one-read kernels, the two matmuls and the
+    CPU lowering of the same program all compute the same thing).  Float
+    rows in their own type with f32 sums (bf16 rows keep both products
+    bf16, halving the bytes read; a plain ``X @ w`` would promote the whole
+    read of X to f32).  8-bit integer rows are trained AS THE INTEGERS THEY
+    ARE: every one of them is exact in bfloat16, so their products run as a
+    bf16 dataset's of the same values do, the rows widened exactly, the
+    weights and the coefficients rounded to bf16 as for bf16 rows.  ``bool``
+    and wider integers (one-hot paths that skip the harness's cast) compute
+    in f32, so that the weights are never truncated for them."""
+    dtype = jnp.dtype(dtype)
+    if jnp.issubdtype(dtype, jnp.inexact):
+        return dtype
+    if dtype in (jnp.int8, jnp.uint8):
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(jnp.float32)
+
+
+def _operand_bytes(itemsize: int) -> int:
+    """Bytes of a product's operand where the rows are ``itemsize`` bytes a
+    feature in HBM: 1-byte rows (int8) are widened to bf16 in VMEM."""
+    return max(itemsize, 2)
+
+
+#: the operands' type by its bytes, for the rows the kernels admit
+_OPERAND_NAME = {2: "bfloat16", 4: "float32"}
 
 
 #: rows of X (lanes of ``X.T``) per grid step of the feature-major kernel
@@ -325,15 +375,19 @@ def _fm_vmem_bytes(tile: int, d: int, itemsize: int, masked: bool,
     block of the lane chunk in place of all d.  ``by_rows``: the class
     kernel over ``(tile, d)`` blocks of X itself, whose d lies along the
     lanes and pads to whole lane groups, in the block and in the chunk's
-    copy.  Above the compiler's own count at every shape tried
+    copy.  1-byte rows (int8) are counted at their own byte a feature in
+    the block, and in the bf16 the body widens them to
+    (:func:`_operand_bytes`) in the weights and the lane chunk's copy.
+    Above the compiler's own count at every shape tried
     (tests/test_chip_compile.py), so a tile it admits compiles."""
     pad = LANES if by_rows else 32 // itemsize  # what d pads to in a block
+    operand = _operand_bytes(itemsize)
     per_lane = (2 * _round_up(d, pad) * itemsize
                 + 2 * (2 if masked else 1) * SUBLANES * 4)
     if class_rows:
-        fixed = (2 * class_rows * _round_up(d, LANES) * (itemsize + 4)
+        fixed = (2 * class_rows * _round_up(d, LANES) * (operand + 4)
                  + _fm_lane_cap(class_rows) * (
-                     _round_up(min(fblock or d, d), pad) * itemsize
+                     _round_up(min(fblock or d, d), pad) * operand
                      + (6 + _fm_ahead(class_rows)) * class_rows * 4))
     else:
         fixed = 4 * _round_up(d, SUBLANES) * LANES * 4
@@ -467,6 +521,12 @@ class OneRead:
     #: the class body issues a lane chunk's margins ahead of the chunk
     #: before's rule (:func:`_fm_ahead`; ``train.run``'s attribute)
     ahead: bool
+    #: bytes of one feature as the body reads it from HBM (X's own: 1 for
+    #: int8 rows, 2 and 4 elsewhere) and the name of the type both products'
+    #: operands are in (:func:`operand_dtype`: int8 rows are widened to
+    #: bf16 in VMEM); ``train.run``'s ``row_item_bytes`` and ``operand``
+    item_bytes: int
+    operand: str
 
     @property
     def bounds(self) -> bool:
@@ -493,6 +553,10 @@ def one_read(n: int, d: int, itemsize: int, masked: bool = True,
       register of rows (:func:`wide_rows_of`); no window grid;
     * stored by rows at another width: none (a block of ``X.T`` would be
       handed a copy of all of X);
+    * 1-byte rows (int8; an int8 array is tiled ``(32, 128)``): the by-rows
+      form alone, each ``(tile, d)`` block read from HBM as the bytes it
+      is and widened to bf16 in VMEM for both products
+      (:func:`operand_dtype`); feature-major they take two reads;
     * stored feature-major, a matrix of weights: the class body over
       ``X.T``; no window grid.  In either orientation a matrix of more
       than ``FM_CLASS_ROWS`` padded rows (1,008 for a thousand classes)
@@ -514,14 +578,16 @@ def one_read(n: int, d: int, itemsize: int, masked: bool = True,
     where not even one lane group of rows does."""
     by_rows = by_rows_form(n, d)
     if ((window and (by_rows or class_rows))
-            or not (by_rows or feature_major(n, d))):
+            or not (by_rows or feature_major(n, d))
+            or (itemsize == 1 and not by_rows)):
         return None
     body = "class" if by_rows or class_rows else (
         "window" if window else "scan")
     limit, fblock = _fm_class_limit(class_rows), d
     tile = _fm_narrow_tile(
         n, d, itemsize, masked,
-        class_rows or (32 // itemsize if by_rows else 0), by_rows)
+        class_rows or (32 // _operand_bytes(itemsize) if by_rows else 0),
+        by_rows)
     if tile is None and body == "scan":
         body, limit = "wide", _FM_WIDE_VMEM_LIMIT
         tile, fblock = _fm_wide_plan(n, d, itemsize, masked, limit) or (
@@ -532,7 +598,9 @@ def one_read(n: int, d: int, itemsize: int, masked: bool = True,
              "sgd.wide_sums" if body == "wide" else "sgd.fused_sums")
     return OneRead(body, by_rows, tile, fblock, -(-d // fblock), limit,
                    scope, draws=body == "scan" and n < 2**31,
-                   class_rows=class_rows, ahead=_fm_ahead(class_rows))
+                   class_rows=class_rows, ahead=_fm_ahead(class_rows),
+                   item_bytes=itemsize,
+                   operand=_OPERAND_NAME[_operand_bytes(itemsize)])
 
 
 def fm_blocks(n: int, d: int, itemsize: int, masked: bool = True,
@@ -1045,15 +1113,19 @@ def _fused_bound_sums(
 
 def class_rows_of(C: int, dtype) -> int:
     """Rows the class kernel holds ``C`` class rows of weights and
-    coefficients at: padded to whole packed registers of ``dtype``."""
-    return _round_up(C, 32 // jnp.dtype(dtype).itemsize)
+    coefficients at, for rows of X in ``dtype``: padded to whole packed
+    registers of the type the products' operands are in
+    (:func:`operand_dtype`: 16 for int8 rows as for bf16 ones)."""
+    return _round_up(C, 32 // operand_dtype(dtype).itemsize)
 
 
 def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs,
                      ahead=False):
     """One block of X for a ``(rows, d)`` MATRIX of weights, one row a
-    class: both products go to the matrix unit with operands in X's type
-    and f32 sums, with ``rule(margins, labels) -> (dloss/dmargins, loss)``
+    class: both products go to the matrix unit with operands in the
+    weights' type (X's own; for int8 rows the bf16 their block is widened
+    to here, in VMEM, exactly: :func:`operand_dtype`) and f32 sums, with
+    ``rule(margins, labels) -> (dloss/dmargins, loss)``
     between them on ``(rows, lanes)`` arrays in VMEM.  The block is a
     ``(d, tile)`` block of ``X.T`` (features on sublanes, rows on lanes:
     ``(rows, d) @ (d, lanes)`` for the margins, ``(rows, lanes) @ (lanes,
@@ -1123,6 +1195,8 @@ def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs,
                 return whole
             x = (x_ref[lanes, r0:r0 + r] if by_rows
                  else x_ref[r0:r0 + r, lanes])
+            if x.dtype != w_ref.dtype:  # int8 rows: widened once a chunk
+                x = x.astype(w_ref.dtype)
             return jnp.where(x_inside, x, jnp.zeros_like(x)) if tail else x
 
         whole = None
@@ -1161,7 +1235,7 @@ def _fm_class_kernel(rule, n, masked, fblock, by_rows, x_ref, y_ref, *refs,
             coeff = jnp.where(inside, coeff, 0.0)
             losses = jnp.where(inside, losses, 0.0)
         loss_ref[:] += _lane_fold(losses)
-        coeff = coeff.astype(x_ref.dtype)
+        coeff = coeff.astype(w_ref.dtype)
         for r0, r in blocks:
             g_ref[:, r0:r0 + r] += jax.lax.dot_general(
                 coeff, x_of(r0, r), (((1,), (1 - features,)), ((), ())),
@@ -1212,7 +1286,8 @@ def fused_class_sums(
     rows PADDED to whole packed registers (``class_rows_of``): the rows of
     ``W`` past ``C`` are zero, and the rule has to give them a coefficient
     of zero.  Grid, tile and tail cut are :func:`fused_gradient_sums`';
-    the products run on the matrix unit in ``X``'s type (``W`` and the
+    the products run on the matrix unit in the operands' type
+    (:func:`operand_dtype`: ``X``'s own, bf16 for int8 rows; ``W`` and the
     coefficients are rounded to it) with f32 sums.  All class rows are
     held at once, however many (:func:`_fm_class_limit`: past
     ``FM_CLASS_ROWS`` under the wide form's VMEM limit); a matrix whose
@@ -1240,7 +1315,8 @@ def _class_call(rule, X, y, W, mask, tile: int, fblock: int, limit: int,
                 interpret: bool, by_rows: bool = False,
                 ahead: Optional[bool] = None):
     """The class kernel's call over ``X.T`` (``by_rows``: over X as it
-    lies) under the ``(rows, d)`` weights ``W`` in X's type: ``(gradient
+    lies) under the ``(rows, d)`` weights ``W`` in the operands' type:
+    ``(gradient
     (rows, d), loss and count lane partials)``, all f32.  ``ahead``: the
     order of a block's lane chunks, :func:`_fm_ahead`'s for the rows of
     ``W`` when None (the tests hold the two orders against each other)."""
@@ -1278,11 +1354,13 @@ def _class_call(rule, X, y, W, mask, tile: int, fblock: int, limit: int,
 def _class_sums(rule, X, y, W, mask, rows: int, tile: int, interpret: bool,
                 by_rows: bool):
     """The class kernel's call with the ``(C, d)`` weights ``W`` cast to
-    X's type and padded to ``rows``, all d in one feature block."""
+    the operands' type and padded to ``rows``, all d in one feature
+    block."""
     n, d = X.shape
     C = W.shape[0]
+    operand = operand_dtype(X.dtype)
     grad, loss, cnt = _class_call(
-        rule, X, y, jnp.pad(W.astype(X.dtype), ((0, rows - C), (0, 0))),
+        rule, X, y, jnp.pad(W.astype(operand), ((0, rows - C), (0, 0))),
         mask, tile, d, _fm_class_limit(rows), interpret, by_rows)
     count = jnp.sum(cnt) if mask is not None else jnp.asarray(
         n, jnp.float32)
@@ -1406,13 +1484,14 @@ def fused_wide_sums(
 def _vector_sums(pointwise, X, y, w, mask, tile: int, fblock: int,
                  limit: int, interpret: bool, by_rows: bool):
     """The class kernel's call with the vector ``w`` as rows of a matrix
-    in X's type and ``_vector_rule`` between the products."""
+    in the operands' type and ``_vector_rule`` between the products."""
     n, d = X.shape
-    parts, rows = wide_rows_of(X.dtype)
-    W = jnp.pad(jnp.stack(_parts_of(w, X.dtype, parts)),
+    operand = operand_dtype(X.dtype)
+    parts, rows = wide_rows_of(operand)
+    W = jnp.pad(jnp.stack(_parts_of(w, operand, parts)),
                 ((0, rows - parts), (0, 0)))
     grad, loss, cnt = _class_call(
-        functools.partial(_vector_rule, pointwise, parts, X.dtype),
+        functools.partial(_vector_rule, pointwise, parts, operand),
         X, y, W, mask, tile, fblock, limit, interpret, by_rows)
     count = jnp.sum(cnt) if mask is not None else jnp.asarray(
         n, jnp.float32)
@@ -1457,7 +1536,7 @@ def fused_rows_sums(
     between the two products, as in :func:`fused_wide_sums`; all d is one
     feature block under ``_FM_VMEM_LIMIT``.  Over a feature-major X it
     is right and slow: the compiler puts a copy of all of X in front."""
-    _, rows = wide_rows_of(X.dtype)
+    _, rows = wide_rows_of(operand_dtype(X.dtype))
     tile = _fm_tile_of(X, tile_m, mask is not None, interpret, rows, True)
     return _fused_rows_sums(pointwise, X, y, w, mask, tile_m=tile,
                             interpret=interpret)

@@ -45,8 +45,8 @@ from tpu_sgd.obs.builds import root
 from tpu_sgd.obs.spans import NO_SPAN, span
 from tpu_sgd.obs.timeseries import observe_scalar
 from tpu_sgd.ops.gradients import (Gradient, LeastSquaresGradient,
-                                   RowCount, RowDraw, rows_valid,
-                                   step_sums, window_rows)
+                                   RowCount, RowDraw, matmul_dtype,
+                                   rows_valid, step_sums, window_rows)
 from tpu_sgd.ops.gram import DEFAULT_BLOCK_ROWS
 from tpu_sgd.ops.sparse import is_sparse
 from tpu_sgd.ops.updaters import SimpleUpdater, Updater
@@ -1440,8 +1440,30 @@ GRAFTLINT_MEMO = {
 
 #: ``GradientDescent._step_kernel``'s answer where the step is no one-read
 #: kernel: ``(labels_prepared, row_tile, feature_blocks, mask_in_kernel,
-#: by_rows, class_rows, ahead)``
+#: by_rows, class_rows, ahead)``, then :func:`_rows_as_read`'s two
 _NO_KERNEL = (0, 0, 1, 0, 0, 0, 0)
+
+
+def _rows_as_read(X):
+    """``train.run``'s ``(row_item_bytes, operand)`` where the step is no
+    one-read kernel (whose record says both): the bytes of one feature as
+    the step's products read it from HBM, X's own item, and the name of
+    the type their operands are in (``ops/gradients.matmul_dtype``: the
+    contract every path computes under; BCOO rows compute at the
+    accumulation type)."""
+    operand = matmul_dtype(X)
+    if is_sparse(X):
+        operand = jnp.promote_types(operand, jnp.float32)
+    return jnp.dtype(X.dtype).itemsize, jnp.dtype(operand).name
+
+
+def _stays_integer(X) -> bool:
+    """Whether dense rows of ``X``'s type are trained as the integers they
+    are, in the bytes they are (``matmul_dtype``: 8-bit ones, whose
+    products' operands are the bf16 values they exactly are), and not cast
+    to float32 after the copy as ``bool`` and wider integers are."""
+    return (not jnp.issubdtype(X.dtype, jnp.inexact)
+            and matmul_dtype(X) != jnp.float32)
 
 
 class GradientDescent(Optimizer):
@@ -1901,6 +1923,15 @@ class GradientDescent(Optimizer):
         return w
 
     def optimize_with_history(self, data: Dataset, initial_weights: Array):
+        return self._fit(data, initial_weights)
+
+    def _fit(self, data: Dataset, initial_weights: Array,
+             integers_f32: bool = False):
+        """``optimize_with_history``, which trains 8-bit integer rows as
+        the integers they are (``matmul_dtype``: bf16 operands, exact).
+        ``integers_f32``, said with the call by a caller whose own contract
+        is float32 for every integer input: those rows too are cast to
+        float32 after the copy, as ``bool`` and wider integers always are."""
         import numpy as np
 
         # classes: K for a (K-1, d) matrix of weights, 2 for a vector
@@ -1909,11 +1940,13 @@ class GradientDescent(Optimizer):
                   else np.shape(data[0])[0],
                   classes=getattr(self.gradient, "num_classes", 2)
                   ) as run_span, root("train.run", run_span):
-            return self._optimize(data, initial_weights, run_span)
+            return self._optimize(data, initial_weights, run_span,
+                                  integers_f32)
 
-    def _optimize(self, data: Dataset, initial_weights: Array, run_span):
-        """``optimize_with_history`` under its ``train.run`` span, whose
-        ``path`` is set where the route is decided."""
+    def _optimize(self, data: Dataset, initial_weights: Array, run_span,
+                  integers_f32: bool = False):
+        """``_fit`` under its ``train.run`` span, whose ``path`` is set
+        where the route is decided."""
         import numpy as np
 
         X, y = data
@@ -2054,7 +2087,7 @@ class GradientDescent(Optimizer):
                 n_logical = gram.data.shape[0]
                 return self._optimize(
                     (gram.data, np.asarray(y)[:n_logical]), initial_weights,
-                    run_span
+                    run_span, integers_f32
                 )
             finally:
                 self.gradient = orig
@@ -2070,6 +2103,9 @@ class GradientDescent(Optimizer):
                 )
             run_span.set(path="streamed")
             Xh = np.asarray(X)
+            if integers_f32 and _stays_integer(Xh):
+                # no cast follows the copy of a chunk: on the host, then
+                Xh = Xh.astype(np.float32)
             # same weight validation/coercion as the resident paths — a
             # wrong-length w0 must raise the clear ValueError here, not
             # an opaque XLA dot-shape error inside the streamed step
@@ -2127,8 +2163,11 @@ class GradientDescent(Optimizer):
                 X, blocks, block_bytes = _stage_dense(X, h2d)
                 h2d.set(blocks=blocks, block_bytes=block_bytes)
             if not sparse_X:
-                if not jnp.issubdtype(X.dtype, jnp.inexact):
-                    # int/bool features (one-hot etc.)
+                if (not jnp.issubdtype(X.dtype, jnp.inexact)
+                        and (integers_f32 or not _stays_integer(X))):
+                    # bool and wider integer features (one-hot etc.), and
+                    # every integer type where the call says so; else
+                    # 8-bit integer rows stay the bytes they are
                     X = X.astype(jnp.float32)
             y = jnp.asarray(y)
             if not jnp.issubdtype(y.dtype, jnp.inexact):
@@ -2315,9 +2354,10 @@ class GradientDescent(Optimizer):
             args = (w0, X, y) if valid is None else (w0, X, y, valid)
         if run_span.live:
             # (labels_prepared, row_tile, feature_blocks, mask_in_kernel,
-            # by_rows, class_rows, ahead): evaluated only where a span
-            # carries them
-            kernel = self._step_kernel(*args) if runner else _NO_KERNEL
+            # by_rows, class_rows, ahead, row_item_bytes, operand):
+            # evaluated only where a span carries them
+            kernel = (self._step_kernel(*args) if runner
+                      else _NO_KERNEL + _rows_as_read(X))
             # stats: 1 where the fit runs from the totals of its rows
             stats = int(isinstance(X, GramData) and X.PG is None)
             run_span.set(
@@ -2326,14 +2366,15 @@ class GradientDescent(Optimizer):
                 labels_prepared=kernel[0], row_tile=kernel[1],
                 feature_blocks=kernel[2], mask_in_kernel=kernel[3],
                 by_rows=kernel[4], class_rows=kernel[5], ahead=kernel[6],
-                stats=stats)
+                row_item_bytes=kernel[7], operand=kernel[8], stats=stats)
             select_span.set(by_rows=kernel[4], class_rows=kernel[5],
                             ahead=kernel[6], stats=stats)
         return fn, args, len(self._run_cache) > cached
 
     def _step_kernel(self, w0, X, y, valid=None):
         """``train.run``'s ``(labels_prepared, row_tile, feature_blocks,
-        mask_in_kernel, by_rows, class_rows, ahead)`` for the fit
+        mask_in_kernel, by_rows, class_rows, ahead, row_item_bytes,
+        operand)`` for the fit
         ``_runner``'s program is about to make of these arguments (a
         shard's operands under a mesh), on a TPU, read off
         ``ops.gradients.step_sums``' record of the step's kernel:
@@ -2348,25 +2389,31 @@ class GradientDescent(Optimizer):
         ten classes, 1,008 for a thousand; 0 a vector), ``ahead`` 1 where
         the class body issues a lane chunk's margins ahead of the chunk
         before's rule (past 128 class rows: the matrix unit bounds the
-        step; 0 in turn).  ``_NO_KERNEL``
+        step; 0 in turn), ``row_item_bytes`` the bytes of one feature as
+        the step reads it from HBM (1 for int8 rows, which the kernel
+        widens in VMEM; 2 and 4 elsewhere) and ``operand`` the name of the
+        type both products' operands are in.  ``_NO_KERNEL`` and
+        :func:`_rows_as_read`'s two
         where the step takes ``y`` as it is and is no kernel (two reads;
         statistics; a CPU, whose program drops the row nothing reads)."""
-        if jax.default_backend() != "tpu":
-            return _NO_KERNEL
-        if self.mesh is not None:
-            shards = self.mesh.devices.size
+        kernel = None
+        if jax.default_backend() == "tpu":
+            if self.mesh is not None:
+                shards = self.mesh.devices.size
 
-            def shard(a):
-                return None if a is None else jax.ShapeDtypeStruct(
-                    (a.shape[0] // shards,) + tuple(a.shape[1:]), a.dtype)
+                def shard(a):
+                    return None if a is None else jax.ShapeDtypeStruct(
+                        (a.shape[0] // shards,) + tuple(a.shape[1:]),
+                        a.dtype)
 
-            X, y, valid = shard(X), shard(y), shard(valid)
-        plan = step_sums(self.gradient, self.config, X, y, w0, valid)
-        kernel = plan.kernel
+                X, y, valid = shard(X), shard(y), shard(valid)
+            plan = step_sums(self.gradient, self.config, X, y, w0, valid)
+            kernel = plan.kernel
         if kernel is None:
-            return _NO_KERNEL
+            return _NO_KERNEL + _rows_as_read(X)
         return (1, kernel.tile, kernel.feature_blocks, int(plan.mask_in_kernel),
-                int(kernel.by_rows), kernel.class_rows, int(kernel.ahead))
+                int(kernel.by_rows), kernel.class_rows, int(kernel.ahead),
+                kernel.item_bytes, kernel.operand)
 
     def _place(self, X, y, valid=None):
         """``shard_dataset`` for this fit's mesh under the ``train.place``

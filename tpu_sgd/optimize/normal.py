@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_sgd.ops.gradients import acc_dtype, matmul_dtype
+from tpu_sgd.ops.gradients import acc_dtype
 from tpu_sgd.optimize.optimizer import Dataset, Optimizer
 
 Array = jax.Array
@@ -38,8 +38,11 @@ Array = jax.Array
 
 def _gram_sums(X: Array, y: Array) -> Tuple[Array, Array, Array, Array]:
     """One pass: ``(XᵀX, Xᵀy, yᵀy, n)`` with f32 accumulation (bf16 data
-    runs the Gram matmul on the MXU in bf16)."""
-    mm_dtype = matmul_dtype(X)
+    runs the Gram matmul on the MXU in bf16).  Integer rows of EVERY width
+    in f32, not ``matmul_dtype``'s bf16 for 8-bit ones: it would round
+    ``y`` to bf16 in ``Xᵀy``, three digits, in a solver that is exact."""
+    mm_dtype = (X.dtype if jnp.issubdtype(X.dtype, jnp.inexact)
+                else jnp.float32)
     acc = acc_dtype(mm_dtype)
     Xc = X.astype(mm_dtype)
     A = jnp.dot(Xc.T, Xc, preferred_element_type=acc)
