@@ -24,23 +24,20 @@ A launch belongs to the fit whose ``bench.fit`` holds its midpoint, as
 picks a launch, it is in no reading.  Of a fit's launches the LONGEST is
 taken, not the latest: the whole-run program outlasts every block write of
 the hand-off (from host 133 launches a fit), so a short launch of the next
-fit that the clocks' offset books to this one changes nothing.  ``bench/spans.load`` keeps the ``XLA
-Ops`` line alone and ``trace["fits"]`` holds clipped times, so the launches
-are read from the run's file through ``bench/trace.load``, once a process.
+fit that the clocks' offset books to this one changes nothing.
+``trace["fits"]`` holds clipped times, so the launches are taken whole from
+what ``bench/spans.py`` keeps of the run's file (read once a process).
 
 Times in nanoseconds, as in ``bench/trace.py``."""
 
-import functools
-
-from bench import trace as trace_mod
+from bench import spans, trace as trace_mod
 
 
-@functools.lru_cache(maxsize=None)
 def launches(path: str) -> list:
     """Per chip the ``(start_ns, duration_ns)`` of its program launches."""
     return [[(start, dur) for _, start, dur
              in trace_mod._events(plane, trace_mod.MODULES_LINE)]
-            for plane in trace_mod.load(path)
+            for plane in spans._reduced(path)["planes"]
             if trace_mod.DEVICE_PLANE.match(plane["name"])]
 
 

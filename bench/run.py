@@ -105,6 +105,9 @@ def main(argv=None) -> int:
         device["busy_s"] = reduced["busy_ns"] / 1e9
         device["window_s"] = reduced["window_ns"] / 1e9
         line["breakdown"] = spans.breakdown(reduced, run)
+        # the shift that put each chip's lines on the host's clock before a
+        # gap was named: the record's, not the driver's
+        run["clock"] = line["breakdown"].pop("clock")
         run["traced_fits"] = reduced["fits"]
     line["run"] = run
     print(json.dumps(line), flush=True)

@@ -1,9 +1,10 @@
 """Reduce a profiler trace (``.xplane.pb``) to what the per-layer metrics
-read: per traced fit its span, the device's busy time inside it, the programs
-launched and the first operation's start; over all traced fits the busy time,
-the operations that took most time and the longest idle gaps, each named by
-its place in the fit (``bench/spans.py``'s ``breakdown`` puts the step's scope
-in front of an operation and the program's span in front of a gap).
+read: per traced fit its span, the device's busy time inside it and the
+programs launched; over all traced fits the busy time, the operations that
+took most time and the longest idle gaps, each named by its place in the fit
+(``bench/spans.py``'s ``breakdown`` puts the step's scope in front of an
+operation and, the device's lines first shifted onto the host's clock, the
+program's span in front of a gap).
 
 ``load`` turns the file into plain dicts with nothing but JAX
 (``jax.profiler.ProfileData``); ``reduce`` works on those dicts, so a test
@@ -14,7 +15,13 @@ What the trace looks like (TPU v5 lite, JAX 0.9.0): one plane per chip,
 launched and whose line ``XLA Ops`` has one per operation, a ``while`` and
 the operations of its body nested inside it on the same line; the host's
 plane ``/host:CPU`` has one line per thread, and a ``TraceAnnotation`` is an
-event on the line of the thread that entered it.  All on one clock."""
+event on the line of the thread that entered it.  All in one file, on TWO
+clocks: the device's lines sit a constant of 1 to 2 ms off the host's, and
+the constant follows the order of the process's profiler sessions (the
+ledger's PR 57 lines: the parent's side, traced first, against the change's
+on one program).  So a fit's share of the device's lines is cut at the
+host's bounds to within that constant, and nothing here reports a device
+time less a host time."""
 
 import re
 
@@ -112,8 +119,8 @@ def reduce(planes: list) -> dict:
             if spans else (0.0, 0.0)
     out["window_ns"] = hi - lo
     own, gaps, busy_total = {}, [], 0.0
-    per_fit = [{"start_ns": s, "end_ns": e, "busy_ns": 0.0, "programs": 0.0,
-                "first_op_ns": None} for s, e in fits]
+    per_fit = [{"start_ns": s, "end_ns": e, "busy_ns": 0.0, "programs": 0.0}
+               for s, e in fits]
     for plane in devices:
         tag = "" if len(devices) == 1 else plane["name"] + " "
         ops = _events(plane, OPS_LINE)
@@ -128,18 +135,16 @@ def reduce(planes: list) -> dict:
             inside = _clip(merged, fs, fe)
             per_fit[i]["busy_ns"] += sum(e - s for s, e in inside) \
                 / len(devices)
-            # by midpoint: the device's clock and the host's agree to some
-            # tens of microseconds only, so a launch can start "before" it
+            # by midpoint: the device's clock is a millisecond or two off
+            # the host's (the module's docstring), so a launch can start
+            # "before" the fit that made it
             per_fit[i]["programs"] += sum(
                 fs <= s + d / 2 < fe for _, s, d in modules) / len(devices)
             if not inside:
                 gaps.append((f"{tag}fit {i}: no operation", fs, fe))
                 continue
-            first = inside[0][0]
-            if per_fit[i]["first_op_ns"] is None \
-                    or first < per_fit[i]["first_op_ns"]:
-                per_fit[i]["first_op_ns"] = first
-            gaps.append((f"{tag}fit {i}: before first operation", fs, first))
+            gaps.append((f"{tag}fit {i}: before first operation", fs,
+                         inside[0][0]))
             gaps.append((f"{tag}fit {i}: after last operation",
                          inside[-1][1], fe))
             for (_, e0), (s1, _) in zip(inside, inside[1:]):

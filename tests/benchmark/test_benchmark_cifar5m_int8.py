@@ -5,10 +5,11 @@ bfloat16.  Its entries, appended; the configuration's contract (what is
 published, what is cut, what is assumed, the arithmetic of the cut); the
 generator; the program through the cell's entry at the tiny sizes against
 the configuration's own reference, the ``float8_e4m3fn`` control failing
-every limit; the work at one byte a feature; the reader that waits
-prepared (``row_item_bytes``) and the accepted readers of the class kernel,
-which find the cell's kernel as they find the twin's, on traces written by
-hand; and the prepared file held to the contract."""
+every limit; the work at one byte a feature; the cell's own reader
+(``row_item_bytes``: its entry waited prepared until PR 58 pasted it) and
+the accepted readers of the class kernel, which find the cell's kernel as
+they find the twin's, on traces written by hand and, since PR 58, in the
+cell's own traced runs."""
 
 import importlib.util
 import json
@@ -32,7 +33,8 @@ TWIN = "cifar5m-multinomial"
 NAME = CONFIG + ".resident-classes"
 BENCH = cells.benchmark()
 READERS = ("row_item_bytes",)
-PREPARED = os.path.join(cells.BENCH, "prepared", CONFIG + ".json")
+#: the class kernel's readers, which list the cell since PR 58
+SHARED = ("class_kernel_ms", "class_sums_ms", "class_rows", "row_tile")
 with open(os.path.join(cells.BENCH, "peaks.json")) as _f:
     PEAKS = json.load(_f)["TPU v5 lite"]
 _cell = cells.Cell(NAME)  # before any fixture moves ``cells.REPO``
@@ -72,22 +74,28 @@ def test_the_entries_are_appended_behind_what_the_benchmark_had():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "resident-classes", 1)
     assert "4,001,792 x 3,072 int8" in cell["why"] and len(cell["why"]) <= 200
-    # the four-chip quota is what it was: two cells
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 2
+    # the cell took none of the four-chip quota: up to it the two cells
+    # that had it (by index: a later PR's four-chip cell stands behind)
+    assert sum(w["chips"] == 4 for w in BENCH["workloads"][
+        :_index("workloads", NAME) + 1]) == 2
 
 
-def test_no_per_layer_entry_is_appended_to_the_benchmark_itself():
-    """The three wait prepared (``test_benchmark_first_fit.py`` pins the end
-    of ``per_layer``): the cell is guarded by what every cell reports."""
+def test_the_cells_own_entry_is_appended_and_the_kernels_readers_list_it():
+    """``row_item_bytes`` behind everything the benchmark had when the cell
+    came (by ``index``: what a later PR appends is none of this test's), and
+    the cell on the lists of the readers that find its kernel."""
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert not set(READERS) & set(names)
+    assert names.index("row_item_bytes") > names.index("first_fit_rest_ms")
     reported = [m["name"] for m in cells.Cell(NAME).metrics["per_layer"]]
     for metric in ("step_ms", "step_roofline", "peak_hbm_gb",
                    "device_idle_share", "programs_per_fit",
-                   "compiles_in_window", "first_fit_ms"):
+                   "compiles_in_window", "first_fit_ms") + READERS + SHARED:
         assert metric in reported, metric
-    assert not [m for m in BENCH["per_layer"]
-                if NAME in m.get("workloads", [])]
+    listed = sorted(m["name"] for m in BENCH["per_layer"]
+                    if NAME in m.get("workloads", []))
+    assert listed == sorted(READERS + SHARED)
+    assert not os.path.exists(
+        os.path.join(cells.BENCH, "prepared", CONFIG + ".json"))
 
 
 # -- the configuration -----------------------------------------------------------
@@ -329,8 +337,8 @@ def test_the_accepted_readers_of_the_class_kernel_find_the_int8_one(
         checkout, metric, reads):
     """No reader of the cell's own repeats them: the int8 kernel is the
     class kernel under its scope and its name, and ``train.run`` says
-    ``class_rows`` and ``row_tile`` of it as of the twin's; what waits is
-    this cell on those metrics' ``workloads`` (PERF.md section 7)."""
+    ``class_rows`` and ``row_tile`` of it as of the twin's; the cell is on
+    those metrics' ``workloads`` since PR 58."""
     int8 = _host(row_item_bytes=1, operand="bfloat16", by_rows=1,
                  class_rows=16, row_tile=2048)
     reduced, run = checkout(H._text(host=int8, ops=OPS, tf_ops=ONE_READ))
@@ -342,32 +350,17 @@ def test_the_accepted_readers_of_the_class_kernel_find_the_int8_one(
     assert _read(metric, reduced, run) == pytest.approx(reads, rel=1e-4)
 
 
-# -- the prepared file -------------------------------------------------------------
-
-def test_the_prepared_file_holds_the_cells_entries_as_the_benchmark_has_them():
-    with open(PREPARED) as f:
-        prepared = json.load(f)
-    assert set(prepared) == {"what", "configs", "workloads", "per_layer"}
-    assert prepared["configs"] == [
-        BENCH["configs"][_index("configs", CONFIG)]]
-    assert prepared["workloads"] == [
-        BENCH["workloads"][_index("workloads", NAME)]]
-    assert "test_benchmark_first_fit.py" in prepared["what"]
-    assert [m["name"] for m in prepared["per_layer"]] == list(READERS)
-
+# -- the entry ---------------------------------------------------------------------
 
 @pytest.mark.parametrize("metric,unit,better,source", [
     ("row_item_bytes", "count", "lower", "program_span")])
-def test_a_prepared_metric_is_the_cells_alone_and_has_its_reader(
+def test_the_cells_own_metric_is_the_cells_alone_and_has_its_reader(
         metric, unit, better, source):
-    with open(PREPARED) as f:
-        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[metric]
+    entry = {m["name"]: m for m in BENCH["per_layer"]}[metric]
     assert entry == {"name": metric, "unit": unit, "better": better,
                      "source": source, "layer": "step",
                      "moves": "rows_per_s", "workloads": [NAME]}
-    pasted = cells.benchmark(with_prepared=True)
-    assert metric in cells.Cell(NAME, pasted).readers
-    assert metric not in cells.Cell(TWIN + ".resident-classes",
-                                    pasted).readers
-    assert metric not in cells.Cell(NAME).readers  # no run reads it yet
+    assert metric in cells.Cell(NAME).readers
+    for other in (w["name"] for w in BENCH["workloads"] if w["name"] != NAME):
+        assert metric not in cells.Cell(other).readers
     assert cells.load_module("layers", metric).__doc__.startswith("Step")

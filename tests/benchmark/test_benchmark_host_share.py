@@ -5,8 +5,11 @@ their readers on a trace written by hand (two fits, one chip and four, a
 worker thread beside the fit's), and their entries, appended.
 
 The point of them: every device event moved by +2 ms and by -2 ms, the
-session's clock offset, leaves all six where they were, while ``handoff_ms``
-and ``fetch_ms`` of the same trace move by those 2 ms."""
+session's clock offset, leaves all six where they were, and since PR 58
+nothing else of a traced run moves with it either: the three metrics that
+read the offset are retired, and ``spans.breakdown`` names the same gaps,
+of the same lengths, by the same leaves (it puts the device's lines on the
+host's clock by what causality allows before it looks for a leaf)."""
 
 import importlib.util
 import os
@@ -128,12 +131,9 @@ def _text(host=HOST, worker=WORKER, chips=ONE, shift=0.0):
 
 @pytest.fixture
 def traced(checkout):
-    """``checkout``, and the launches are read anew with every file."""
-    def write(text):
-        host_share.launches.cache_clear()
-        return checkout(text)
-
-    return write
+    """``checkout``: the launches are read with the file, anew with every
+    one (they are ``bench/spans.py``'s reading of it)."""
+    return checkout
 
 
 # -- the readers ---------------------------------------------------------------
@@ -145,24 +145,75 @@ def test_reader(traced, metric, chips):
     assert got == pytest.approx(EXPECTED[metric], abs=1e-9)
 
 
+SHIFTS = (0.0, 2.0, -2.0)
+
+
 @pytest.mark.parametrize("chips", [ONE, FOUR], ids=["one_chip", "four_chips"])
-def test_a_clock_offset_moves_none_of_the_six_and_both_of_the_old(traced,
-                                                                  chips):
-    """Every device event 2 ms later and 2 ms earlier: the six new metrics
-    stand to 1e-9; ``handoff_ms`` and ``fetch_ms`` read the offset."""
+def test_a_clock_offset_moves_none_of_the_six(traced, chips):
+    """Every device event 2 ms later and 2 ms earlier: the six stand to
+    1e-9."""
     at = {}
-    for shift in (0.0, 2.0, -2.0):
+    for shift in SHIFTS:
         reduced, run = traced(_text(chips=chips, shift=shift))
-        at[shift] = {m: H._read(m, reduced, run)
-                     for m in NEW + ["handoff_ms", "fetch_ms"]}
-    for shift in (2.0, -2.0):
+        at[shift] = {m: H._read(m, reduced, run) for m in NEW}
+    for shift in SHIFTS[1:]:
         for metric in NEW:
             assert at[shift][metric] == pytest.approx(
                 at[0.0][metric], rel=0, abs=1e-9), (metric, shift)
-        assert at[shift]["handoff_ms"] == pytest.approx(
-            at[0.0]["handoff_ms"] + shift)
-        assert at[shift]["fetch_ms"] == pytest.approx(
-            at[0.0]["fetch_ms"] - shift)
+
+
+@pytest.mark.parametrize("chips", [ONE, FOUR], ids=["one_chip", "four_chips"])
+def test_a_clock_offset_renames_no_gap_of_the_breakdown(traced, chips):
+    """The same gaps, as long, under the same leaves, whatever the session's
+    offset: the shift ``breakdown`` finds takes the offset back out, and the
+    record says which shift that was."""
+    at = {}
+    for shift in SHIFTS:
+        got = spans.breakdown(*traced(_text(chips=chips, shift=shift)))
+        at[shift] = got
+        assert sorted(got["clock"]) == sorted(chips)
+    for shift in SHIFTS[1:]:
+        assert [n for n, _ in at[shift]["idle_gaps"]] \
+            == [n for n, _ in at[0.0]["idle_gaps"]]
+        assert [s for _, s in at[shift]["idle_gaps"]] == pytest.approx(
+            [s for _, s in at[0.0]["idle_gaps"]], rel=0, abs=1e-9)
+        for chip, clock in at[shift]["clock"].items():
+            assert clock["pairs"] == 2
+            assert clock["shift_ms"] == pytest.approx(
+                at[0.0]["clock"][chip]["shift_ms"] - shift)
+    # one chip: the calls are [17, 95) and [102.5, 198), the launches
+    # [22, 92) and [106, 196): brackets [-5, 3] and [-3.5, 2], so the
+    # chip's lines go (-3.5 + 2) / 2 = -0.75 ms.  Fit 0's first write then
+    # starts at 7.25 (fit.validate has 3 ms of the wait, more than any) and
+    # its program ends at 91.25: train.fetch has 3.75 ms of what is left
+    # of the fit, fit.finish 1 and nobody 4
+    if chips is ONE:
+        assert at[0.0]["clock"]["/device:TPU:0"] == {
+            "shift_ms": pytest.approx(-0.75), "pairs": 2,
+            "bracket_ms": pytest.approx([-3.5, 2.0])}
+        gaps = dict((n, s) for n, s in at[2.0]["idle_gaps"])
+        assert gaps["fit.validate: fit 0: before first operation"] \
+            == pytest.approx(7.25e-3)
+        assert gaps["(unspanned): fit 0: after last operation"] \
+            == pytest.approx(8.75e-3)
+        assert gaps["train.fetch: fit 1: after last operation"] \
+            == pytest.approx(4.75e-3)
+
+
+def test_calls_that_exclude_each_other_bracket_nothing(traced):
+    """Two calls whose brackets do not meet (a launch booked to the wrong
+    call): no shift is believed, the lines stand and the record says so."""
+    def clock(second):
+        chips = {"/device:TPU:0": (OPS, LAUNCHES[:2] + [
+            ("jit_sgd_run(7)", 18, 70), ("jit_sgd_run(7)", second, 90)])}
+        return spans.breakdown(*traced(_text(chips=chips)))["clock"][
+            "/device:TPU:0"]
+
+    # [17 - 18, 95 - 88] = [-1, 7] and [102.5 - 107, 198 - 197] = [-4.5, 1]
+    assert clock(107) == {"shift_ms": 0.0, "pairs": 2,
+                          "bracket_ms": pytest.approx([-1.0, 1.0])}
+    # [102.5 - 95, 198 - 185] = [7.5, 13] is past the first call's 7
+    assert clock(95) == {"shift_ms": None, "bracket_ms": None, "pairs": 0}
 
 
 @pytest.mark.parametrize("chips", [ONE, FOUR], ids=["one_chip", "four_chips"])
@@ -286,13 +337,13 @@ def test_each_cell_reports_the_five_and_from_host_the_stall(cell):
 
 def test_no_reader_takes_a_device_time_from_a_host_time():
     """The rule, as far as a test can hold it: the new readers and what they
-    share read no device timestamp (``first_op_ns``, ``last_op_end_ns``,
-    the clipped ``busy``/``idle`` of the reductions) and clip nothing."""
+    share read no device timestamp (the clipped ``busy_ns`` of the
+    reduction, a launch's start) and clip nothing."""
     files = [os.path.join(cells.BENCH, "host_share.py")] + [
         os.path.join(cells.BENCH, "layers", name + ".py") for name in NEW]
     for file in files:
         with open(file) as f:
             code = f.read().split('"""', 2)[2]  # past the docstring
-        for banned in ("first_op_ns", "last_op_end_ns", "_clip", '"idle"',
-                       "before_first_op", "busy_ns"):
+        for banned in ("_clip", "busy_ns", "clock_bracket_ns",
+                       "on_host_clock"):
             assert banned not in code, (file, banned)

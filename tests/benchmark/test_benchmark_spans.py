@@ -23,8 +23,7 @@ SPAN_METRICS = sorted(
 #: the device's lines too.  A later metric states either and is not listed
 SOURCE = {**dict.fromkeys(("validate_ms", "plan_ms", "h2d_ms", "dispatch_ms"),
                           "program_span"),
-          **dict.fromkeys(("fetch_ms", "idle_unspanned_share", "margins_ms",
-                           "gradient_ms", "step_unscoped_share",
+          **dict.fromkeys(("margins_ms", "gradient_ms", "step_unscoped_share",
                            "fused_sums_ms"), "device_trace")}
 
 #: fit 0 is [0, 100) ms, fit 1 [100, 200) ms; (name, start ms, length ms,
@@ -166,6 +165,12 @@ def test_load_keeps_the_programs_spans_and_the_operations_op_names(checkout):
     assert ("train.h2d", 6 * MS, 10 * MS, {"bytes": 4096}) \
         in host["lines"][0]["events"]
     assert [line["name"] for line in device["lines"]] == ["XLA Ops"]
+    # and the launches where the chip has any; the async line never (it
+    # would count busy twice)
+    checkout(_text(modules=LAUNCHES))
+    device = spans.load(spans.find({"workload": WORKLOAD}))[1]
+    assert [line["name"] for line in device["lines"]] \
+        == ["XLA Modules", "XLA Ops"]
     assert device["op_names"] == TF_OPS  # by value and by reference
     assert spans.scope_of(TF_OPS[G]) == "sgd.gradient"  # the innermost
     assert spans.scope_of(TF_OPS[WHILE]) == spans.scope_of(None) \
@@ -186,26 +191,15 @@ def test_spans_are_a_tree_by_containment(checkout):
             if s["name"] == "train.dispatch"] == [1]
 
 
-def test_the_hand_off_is_cut_by_the_leaf_span_that_covers_it(checkout):
-    reduced, run = checkout(_text())
-    fit0, fit1 = spans.of(reduced, run)["fits"]
-    # [0, 30) ms before the first operation
-    assert fit0["before_first_op"] == {
-        "fit.validate": 4 * MS, "fit.plan": 1 * MS, "train.h2d": 10 * MS,
-        "train.dispatch": 2 * MS, "train.fetch": 10 * MS,
-        spans.UNSPANNED: 3 * MS}  # [0, 1) and [16, 18): fit.run is no leaf
-    assert sum(fit0["before_first_op"].values()) == pytest.approx(
-        reduced["fits"][0]["first_op_ns"] - reduced["fits"][0]["start_ns"])
-    # and [90, 100) after the last: fetch to 97, then nobody
-    assert fit0["idle"]["train.fetch"] == 17 * MS
-    assert fit0["idle"][spans.UNSPANNED] == 6 * MS
-    assert fit0["last_op_end_ns"] == 90 * MS
-    # fit 1: [100, 110), [130, 140), [160, 200)
-    assert fit1["before_first_op"] == {
-        "fit.validate": 2 * MS, "fit.plan": 3 * MS, "train.h2d": 2 * MS,
-        "train.dispatch": 1 * MS, "train.fetch": 2 * MS}
-    assert fit1["idle"]["train.fetch"] == (2 + 10 + 38) * MS
-    assert fit1["idle"][spans.UNSPANNED] == 2 * MS
+def test_a_fit_holds_host_times_alone(checkout):
+    """Spans, their tree and the leaves: nothing of a fit is a time on the
+    device's clock, so no reader can take one from a time on the host's."""
+    fit0, fit1 = spans.of(*checkout(_text()))["fits"]
+    assert set(fit0) == {"start_ns", "end_ns", "spans", "leaves"}
+    assert [s["name"] for s in fit0["leaves"]] == [
+        "fit.validate", "fit.plan", "train.h2d", "train.dispatch",
+        "train.fetch"]  # fit.run and train.run hold spans: no leaves
+    assert (fit1["start_ns"], fit1["end_ns"]) == (100 * MS, 200 * MS)
 
 
 def test_operations_own_time_is_keyed_by_scope(checkout):
@@ -226,8 +220,6 @@ def test_overlapping_leaves_of_two_threads_share_a_gap_once():
 @pytest.mark.parametrize("metric,expected", [
     ("validate_ms", (4 + 2) / 2), ("plan_ms", (1 + 3) / 2),
     ("h2d_ms", (10 + 2) / 2), ("dispatch_ms", (2 + 1) / 2),
-    ("fetch_ms", ((97 - 90) + (198 - 160)) / 2),
-    ("idle_unspanned_share", 100 * (6 + 2) / (40 + 60)),
     ("margins_ms", 60 / 2 / 10), ("gradient_ms", 15 / 2 / 10),
     ("step_unscoped_share", 100 * 25 / 100)])
 def test_reader(checkout, metric, expected):
@@ -279,7 +271,11 @@ def test_the_breakdown_names_operations_by_scope_and_gaps_by_span(checkout):
     got = spans.breakdown(reduced, run)
     assert [n for n, _ in got["device_ops"]] == [
         f"sgd.margins: {M}", f"(unscoped): {COPY}", f"sgd.gradient: {G}",
-        f"(unscoped): {WHILE}"]
+        f"(unscoped) sgd_run: {WHILE}"]
+    # no launch in the file: nothing brackets the clocks, the device's lines
+    # stand as they are and the record says so
+    assert got["clock"] == {"/device:TPU:0": {
+        "shift_ms": None, "bracket_ms": None, "pairs": 0}}
     # fetch covers [20, 97) of fit 0 and [108, 198) of fit 1; nobody [0, 1)
     gaps = dict((n, s) for n, s in got["idle_gaps"])
     assert gaps["train.fetch: fit 1: after last operation"] \
@@ -302,7 +298,7 @@ def test_a_breakdown_that_resolves_nothing_keeps_the_traces_names(checkout):
     long = "%fusion.9 = " + "f32[4194304]{0:T(1024)} " * 20
     other = dict(reduced, device_ops=[[long, 1.0]])
     got = spans.breakdown(other, dict(run, workload="some.other"))
-    assert got["idle_gaps"] == reduced["idle_gaps"]
+    assert got["idle_gaps"] == reduced["idle_gaps"] and got["clock"] is None
     assert got["device_ops"] == [[long[:trace.NAME_CHARS], 1.0]]
     # a gap that no leaf covers, and an operation the file does not name
     bare = [e for e in HOST if e[0] == "bench.fit"]
@@ -335,7 +331,6 @@ def test_four_device_planes_reduce_to_per_device_means(checkout):
     f0, f1 = reduced["fits"]
     assert f0["busy_ns"] == pytest.approx(50 * MS) and f0["programs"] == 1
     assert f1["busy_ns"] == pytest.approx(21.5 * MS) and f1["programs"] == 1
-    assert f0["first_op_ns"] == 30 * MS and f1["first_op_ns"] == 110 * MS
     # own time, a chip: margins 34 + the mean of fit 1's 21.5 under
     # sgd.gradient, the all-reduce's mean 16, nothing left to the while
     resolved = spans.of(reduced, run)
@@ -343,28 +338,29 @@ def test_four_device_planes_reduce_to_per_device_means(checkout):
         "sgd.margins": 34 * MS, "sgd.allreduce": 16 * MS,
         "sgd.gradient": 21.5 * MS, spans.UNSCOPED: 0.0})
     assert resolved["op_scopes"][ALLREDUCE] == "sgd.allreduce"
-    # the device is idle only while no chip is busy: fit 1's last operation
-    # ends with chip 3's, at 133 ms
-    assert [f["last_op_end_ns"] for f in resolved["fits"]] \
-        == [80 * MS, 133 * MS]
+    # and by the jitted function of the program each ran in
+    assert resolved["functions"] == pytest.approx({
+        "sgd_run": (34 + 16 + 21.5) * MS})
     assert _read("step_ms", reduced, run) == pytest.approx(71.5 / 2 / 10)
     assert _read("programs_per_fit", reduced, run) == 1
     assert _read("device_idle_share", reduced, run) \
         == pytest.approx(100 * (1 - 71.5 / 200))
-    assert _read("handoff_ms", reduced, run) == pytest.approx((30 + 10) / 2)
     assert _read("margins_ms", reduced, run) == pytest.approx(34 / 2 / 10)
     assert _read("gradient_ms", reduced, run) == pytest.approx(21.5 / 2 / 10)
     assert _read("step_unscoped_share", reduced, run) == 0.0
-    assert _read("fetch_ms", reduced, run) \
-        == pytest.approx(((97 - 80) + (198 - 133)) / 2)
-    # idle inside the fits: [0, 30) + [80, 100), [100, 110) + [133, 200); of
-    # it no leaf covers [0, 1), [16, 18), [97, 100) and [198, 200)
-    assert _read("idle_unspanned_share", reduced, run) \
-        == pytest.approx(100 * (1 + 2 + 3 + 2) / (50 + 77))
-    # each chip's gaps are its own, named by the chip
-    names = [n for n, _ in spans.breakdown(reduced, run)["idle_gaps"]]
-    assert any(n.startswith("train.fetch: /device:TPU:0 fit 1: after last")
-               for n in names)
+    # each chip's gaps are its own, named by the chip, on a clock of its own:
+    # the calls are [18, 97) and [107, 198), every chip's launches [30, 80)
+    # and [110, 135).  Fit 0's launch fills its call and brackets the clocks,
+    # [18 - 30, 97 - 80]; fit 1's is under half of its call and is not
+    # believed.  The chip's lines go (-12 + 17) / 2 = 2.5 ms later
+    got = spans.breakdown(reduced, run)
+    assert got["clock"] == {chip: {
+        "shift_ms": pytest.approx(2.5), "pairs": 1,
+        "bracket_ms": pytest.approx([-12, 17])} for chip in CHIPS}
+    gaps = dict((n, s) for n, s in got["idle_gaps"])
+    # chip 0 is done at 130 + 2.5 ms; train.fetch covers [132.5, 198) of it
+    assert gaps["train.fetch: /device:TPU:0 fit 1: after last operation"] \
+        == pytest.approx(0.0675)
 
 
 # -- BENCHMARK.json ------------------------------------------------------------

@@ -90,7 +90,10 @@ def test_the_three_metrics_are_this_cells_and_move_rows_per_s():
     names = [m["name"] for m in bench["per_layer"]]
     for metric, unit, better in zip(METRICS, ("ms", "count", "ms"),
                                     ("lower", "higher", "lower")):
-        assert entries[metric] == {
+        # the cell the metric came with stands first on its list; a later
+        # stream cell whose passes carry the span is appended behind it
+        assert {**entries[metric],
+                "workloads": entries[metric]["workloads"][:1]} == {
             "name": metric, "unit": unit, "better": better,
             "source": "program_span", "layer": "stream fold",
             "moves": "rows_per_s", "workloads": [NAME]}

@@ -273,7 +273,10 @@ def test_the_two_metrics_are_the_wide_cells_and_move_rows_per_s():
         "name": "wide_sums_ms", "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "step", "moves": "rows_per_s",
         "workloads": [NAME]}
-    assert entries["row_tile"] == {
+    # the cell the metric came with stands first; a later cell whose
+    # ``train.run`` says its kernel's row tile is appended behind it
+    assert {**entries["row_tile"],
+            "workloads": entries["row_tile"]["workloads"][:1]} == {
         "name": "row_tile", "unit": "count", "better": "higher",
         "source": "program_span", "layer": "step", "moves": "rows_per_s",
         "workloads": [NAME]}
