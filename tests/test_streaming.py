@@ -552,7 +552,10 @@ def test_the_folds_spans_tile_a_pass(tmp_path, schedule, totals):
     ``totals`` made ahead and builds nothing (no ``train.stats``); on the
     stock schedule (the planner's at these sizes) none.  ``capacity``: a
     logistic stream, whose micro-batches go at a row capacity (PR 52):
-    ``stream.whole`` is a leaf of its own inside ``stream.wait``, and
+    ``stream.whole`` is a leaf of its own, inside ``stream.wait`` in turn
+    (``ahead`` 0: the pass's first) and inside the fit BEFORE its own where
+    the take was done as that fit was dispatched (``ahead`` 1, PR 60: the
+    ``stream.wait`` in front of its fit is then empty), and
     ``stream.stage`` and ``stream.batch`` say the real ``rows``, the
     ``capacity`` and ``rows_read``."""
     import json
@@ -604,6 +607,7 @@ def test_the_folds_spans_tile_a_pass(tmp_path, schedule, totals):
     built = [s for s in spans if s.get("name") == "train.stats"]
     stats = [s["stats"] for s in spans if s.get("name") == "train.select"]
     assert built == [] and stats == [totals] * 3
+    fits = {s["span_id"] for s in spans if s.get("name") == "train.run"}
     spans = [s for s in spans if s.get("name", "").startswith(("stream.",
                                                                "fit.run"))]
     by = {}
@@ -637,19 +641,29 @@ def test_the_folds_spans_tile_a_pass(tmp_path, schedule, totals):
                                     s["folded"] == totals
                                     for s in staged)
     assert sorted(s["rows"] for s in staged) == sorted(sizes)
-    whole = by.get("stream.whole", [])
-    # the rows form alone makes its blocks whole, inside the fold's wait,
-    # behind the worker's answer: the two leaves tile the wait
-    assert len(whole) == (0 if totals else 3) and len(by["stream.take"]) == 4
+    whole = sorted(by.get("stream.whole", []), key=lambda s: s["t0_s"])
+    # the rows form alone makes its blocks whole: inside the fold's wait,
+    # behind the worker's answer (the two leaves tile the wait), or at a
+    # capacity behind the fit that is running, which the worker's take was
+    # done for (``run_warm`` above): no take and no leaf in the next wait
+    behind = 2 * capacity
+    assert len(whole) == (0 if totals else 3)
+    assert len(by["stream.take"]) == 4 - behind
+    assert [s["ahead"] for s in whole] == [0, 1, 1][:len(whole)] \
+        if capacity else not any(s["ahead"] for s in whole)
     waits = {s["span_id"]: s for s in by["stream.wait"]}
-    assert all(s["parent_id"] in waits and s["blocks"] == 1 for s in whole)
+    assert all(s["blocks"] == 1 and s["parent_id"] in (
+        fits if s["ahead"] else waits) for s in whole)
+    empty = 0
     for wait in by["stream.wait"]:
         inner = sorted((s for s in by["stream.take"] + whole
                         if s["parent_id"] == wait["span_id"]),
                        key=lambda s: s["t0_s"])
-        assert inner and inner[0]["name"] == "stream.take"
+        empty += not inner
+        assert not inner or inner[0]["name"] == "stream.take"
         # what is left of the wait under neither is the spans' own cost
         assert wait["dur_s"] - sum(s["dur_s"] for s in inner) < 5e-3
+    assert empty == behind
     for s in staged + turns:
         assert ("capacity" in s) == ("rows_read" in s) == capacity
         if capacity:  # on the CPU a step reads all of the capacity, masked
@@ -724,15 +738,18 @@ def test_batches_go_ahead_only_beside_the_stock_and_totals_schedules(
 
 def _statistics_fold(stream, how="ahead", d=6, iterations=8,
                      schedule="resident_gram", fraction=1.0,
-                     model=StreamingLinearRegressionWithSGD):
+                     model=StreamingLinearRegressionWithSGD, manager=None):
     """A fold of ``stream`` on the statistics schedule (forced: the sizes
-    are tiny) and what its listener saw."""
+    are tiny) and what its listener saw; ``manager`` is given every
+    checkpoint."""
     import warnings
 
     alg = model(step_size=0.2, num_iterations=iterations,
                 mini_batch_fraction=fraction)
     alg.set_initial_weights(np.zeros(d, np.float32))
     alg.algorithm.set_schedule(schedule)
+    if manager is not None:
+        alg.set_checkpoint(manager)
     calls = []
     alg.add_model_update_listener(
         lambda model, count: calls.append(
@@ -1162,16 +1179,104 @@ def _uneven_logistic(sizes, d=6, seed=90, dtype=np.float32):
     return out
 
 
-def _logistic_fold(stream, how="ahead", d=6, iterations=8, fraction=1.0):
+def _logistic_fold(stream, how="ahead", d=6, iterations=8, fraction=1.0,
+                   manager=None):
     return _statistics_fold(stream, how, d=d, iterations=iterations,
                             schedule="auto", fraction=fraction,
-                            model=StreamingLogisticRegressionWithSGD)
+                            model=StreamingLogisticRegressionWithSGD,
+                            manager=manager)
 
 
 UNEVEN = [3 * 1024 + 100, 2 * 1024 + 7, 4096, 2049, 3333, 2 * 1024 + 7]
 
+#: when the worker's take of micro-batch k+1 is done against fit k
+#: (``_paced``): left to the threads; before the fit is dispatched, or under
+#: the running fit (the join then goes BEHIND that fit: PR 60); or not before
+#: the fit has ended (every join in turn)
+PACES = ["as it comes", "done", "during", "late"]
+BEHIND = ("done", "during")
 
-def test_an_uneven_logistic_stream_is_the_unpadded_fold(monkeypatch):
+
+def _paced(monkeypatch, pace):
+    """``batches(stream)``, the iterator to hand ``train_on``, and the pace
+    set.  ``"done"`` has every fit at a capacity wait, as its program is
+    queued, until the worker has finished every take it was given (they wait
+    for nothing the host has still to do), so the next micro-batch is made
+    whole behind the running fit wherever the fold allows it.  ``"during"``
+    lets the stream yield micro-batch k only once k fits have been queued
+    and tells the fold that its fit is still running for as long as it asks
+    (on the CPU a fit is over in milliseconds): the take is waited for under
+    the fit and joined behind it.  ``"late"`` lets the stream yield
+    micro-batch k only once the fold has LEFT the k-th fit's queue hook,
+    which it does when that fit has ended: no take is done while a fit runs
+    and every join goes in turn."""
+    import concurrent.futures
+    import threading
+
+    import tpu_sgd.optimize.gradient_descent as gd
+    from tpu_sgd.models import streaming
+
+    if pace == "as it comes":
+        return iter
+    takes, turn = [], threading.Condition()
+    fits = {"entered": 0, "left": 0}  # of the queue hook, this stream
+
+    class Pool(streaming.ThreadPoolExecutor):
+        def submit(self, *args, **kwargs):
+            takes.append(super().submit(*args, **kwargs))
+            return takes[-1]
+
+    real = gd.StagedAhead.queued
+
+    def passed(mark):
+        with turn:
+            fits[mark] += 1
+            turn.notify_all()
+
+    def queued(self, done):
+        if pace == "done":
+            _, still = concurrent.futures.wait(
+                [t for t in takes if not t.cancelled()], timeout=30)
+            assert not still
+        passed("entered")
+        real(self, (lambda: False) if pace == "during" else done)
+        passed("left")
+
+    monkeypatch.setattr(streaming, "ThreadPoolExecutor", Pool)
+    monkeypatch.setattr(gd.StagedAhead, "queued", queued)
+
+    def batches(stream):
+        fits.update(entered=0, left=0)  # the first take: before any fit
+        mark = {"during": "entered", "late": "left"}.get(pace)
+        for k, batch in enumerate(stream):
+            if mark:
+                with turn:
+                    assert turn.wait_for(lambda: fits[mark] >= k, 30)
+            yield batch
+
+    return batches
+
+
+class _Checkpoints:
+    """A ``CheckpointManager`` that keeps what it is given."""
+
+    def __init__(self):
+        self.saved = []
+
+    def save(self, iteration, weights, intercept, history, **more):
+        self.saved.append((iteration, np.array(weights), np.array(history),
+                           more["config_key"],
+                           float(more["extras"]["intercept"])))
+
+
+def _joins(spans):
+    """``ahead`` of a fold's ``stream.whole`` spans, in time order."""
+    return [s["ahead"] for s in spans if s["name"] == "stream.whole"]
+
+
+@pytest.mark.parametrize("pace", PACES)
+def test_an_uneven_logistic_stream_is_the_unpadded_fold(pace, monkeypatch,
+                                                        tmp_path):
     """``train_on`` over micro-batches of unequal sizes (each in an array of
     the stream's row capacity, its count an operand) against the fold taken
     strictly in turn over arrays of the micro-batches' OWN rows through the
@@ -1180,16 +1285,31 @@ def test_an_uneven_logistic_stream_is_the_unpadded_fold(monkeypatch):
     padding's terms exact zeros, so the order of the additions differs, the
     terms do not), every micro-batch trained once, in order, the listener
     called after each; and the fold ahead is the fold in turn at the
-    capacity bit for bit."""
+    capacity bit for bit, weights, losses, listener calls and checkpoints,
+    whether a micro-batch was made whole behind the fit before it (``done``
+    and ``during``: every one from the third on, the first two come in
+    turn) or in turn (``late``: all of them)."""
     from tpu_sgd import LogisticRegressionWithSGD
 
     _blocked(monkeypatch, d=6)
     stream = _uneven_logistic(UNEVEN)
-    ahead, calls_a = _logistic_fold(stream, "ahead")
-    turn, calls_t = _logistic_fold(stream, "in turn")
+    kept_a, kept_t = _Checkpoints(), _Checkpoints()
+    (ahead, calls_a), spans = _traced(
+        tmp_path, lambda: _logistic_fold(_paced(monkeypatch, pace)(stream),
+                                         "ahead", manager=kept_a))
+    turn, calls_t = _logistic_fold(stream, "in turn", manager=kept_t)
     _same_fold(ahead, turn, calls_a, calls_t)
     assert [c[0] for c in calls_a] == [1, 2, 3, 4, 5, 6]
     assert ahead._capacity == turn._capacity == 4096
+    assert [c[0] for c in kept_a.saved] == [1, 2, 3, 4, 5, 6]
+    for a, t in zip(kept_a.saved, kept_t.saved):
+        assert a[0] == t[0] and a[3:] == t[3:]
+        np.testing.assert_array_equal(a[1], t[1])
+        np.testing.assert_array_equal(a[2], t[2])
+    # the first is copied inside its fit, the second is taken after it
+    if pace != "as it comes":
+        assert _joins(spans) == [0] + [int(pace in BEHIND)] * 4
+    assert len(_joins(spans)) == 5 and _joins(spans)[0] == 0
     w, history = np.zeros(6, np.float32), []
     for X, y in stream:  # the unpadded fold: a program a size
         batch = LogisticRegressionWithSGD(0.2, 8, 0.0, 1.0)
@@ -1390,14 +1510,31 @@ def test_two_go_ahead_where_three_arrays_of_the_capacity_fit(
                                rtol=0, atol=2e-6)
 
 
-def test_the_array_of_the_capacity_is_written_over_in_place(monkeypatch):
+@pytest.mark.parametrize("pace", PACES)
+def test_the_array_of_the_capacity_is_written_over_in_place(pace, monkeypatch,
+                                                            tmp_path):
     """A stream's array of the capacity is made once: every later
     micro-batch's rows are written over the one trained before it (given up:
     deleted, its memory the new array's), so that the device never frees
     one array of the capacity to find room for the next while blocks land
-    beside them; a raised capacity's first array is a new one."""
+    beside them; a raised capacity's first array is a new one.  Behind a
+    running fit (``done``, ``during``) as in turn: the array the fit is reading is the
+    one given up, and the device holds ONE array of a capacity's shape
+    whenever a join has been dispatched; a capacity that regrows is made in
+    turn, in an array of its own."""
+    import gc
+
+    import jax
+
+    def buffers(shape=None):
+        """The device buffers of live arrays (of ``shape``; None: any)."""
+        return {a.unsafe_buffer_pointer() for a in jax.live_arrays()
+                if shape in (None, a.shape)}
+
     gd = _blocked(monkeypatch, d=6)
-    seen, real = [], gd.StagedAhead.whole
+    seen, alive, real = [], [], gd.StagedAhead.whole
+    gc.collect()
+    before = buffers()  # what earlier tests of this process left alive
 
     def whole(self, into=None):
         spent = None if into is None else into.X
@@ -1406,14 +1543,21 @@ def test_the_array_of_the_capacity_is_written_over_in_place(monkeypatch):
         if spent is not None:
             seen.append((spent.shape[0], out.X.shape[0], spent.is_deleted(),
                          out.X.unsafe_buffer_pointer() == at))
+            del spent
+            alive.append(len(buffers(out.X.shape) - before))
         return out
 
     monkeypatch.setattr(gd.StagedAhead, "whole", whole)
     sizes = [2100, 4000, 2300, 5000, 2049]
-    alg, calls = _logistic_fold(_uneven_logistic(sizes))
+    stream = _paced(monkeypatch, pace)(_uneven_logistic(sizes))
+    (alg, calls), spans = _traced(tmp_path, _logistic_fold, stream)
     assert [c[0] for c in calls] == [1, 2, 3, 4, 5]
     assert seen == [(4096, 4096, True, True), (4096, 4096, True, True),
                     (4096, 8192, False, False), (8192, 8192, True, True)]
+    assert alive == [1, 1, 1, 1]
+    if pace != "as it comes":
+        behind = int(pace in BEHIND)  # the third's, and the fifth's
+        assert _joins(spans) == [0, behind, 0, behind]
 
 
 def test_nothing_small_crosses_the_wire_as_a_fit_starts(monkeypatch):
@@ -1624,3 +1768,201 @@ def test_a_capacity_form_is_trained_by_the_stock_schedule_alone():
     np.testing.assert_allclose(np.asarray(got.weights), want, atol=1e-6)
     with pytest.raises(RuntimeError, match="stock resident schedule"):
         alg.run((StagedAhead(X, y, capacity=2048), y))
+
+
+# ---- the next micro-batch made whole BEHIND the running fit (PR 60) ------------
+
+def _children(spans, parent, name):
+    return [s for s in spans
+            if s["parent_id"] == parent["span_id"] and s["name"] == name]
+
+
+@pytest.mark.parametrize("pace", BEHIND)
+def test_the_next_join_is_queued_between_a_fits_dispatch_and_its_fetch(
+        pace, monkeypatch, tmp_path):
+    """The ORDER on the fold's thread where the worker's take is done as fit
+    k is dispatched, or while it runs: micro-batch k+1's ``stream.whole``
+    (``ahead`` 1) lies inside fit k, behind its ``train.dispatch`` and in
+    front of its ``train.fetch``, so the join is in the device's queue
+    before the host begins to wait (``during``: behind a ``stream.take``
+    leaf, ``behind`` 1, the wait for the worker under the running fit);
+    ``stream.publish`` of k (the listeners) follows the fetch as before, and
+    fit k+1 is selected and dispatched only after it has returned."""
+    _blocked(monkeypatch, d=6)
+    stream = _paced(monkeypatch, pace)(_uneven_logistic(UNEVEN))
+    (alg, calls), spans = _traced(tmp_path, _logistic_fold, stream)
+    assert [c[0] for c in calls] == [1, 2, 3, 4, 5, 6]
+
+    def end(s):
+        return s["t0_s"] + s["dur_s"]
+
+    fits = [s for s in spans if s["name"] == "train.run"]
+    turns = [s for s in spans if s["name"] == "stream.batch"]
+    published = [s for s in spans if s["name"] == "stream.publish"]
+    assert len(fits) == len(turns) == len(published) == 6
+    behind, waits = {}, 0
+    for k, fit in enumerate(fits):
+        (called,), (answered,) = (_children(spans, fit, name) for name
+                                  in ("train.dispatch", "train.fetch"))
+        for whole in _children(spans, fit, "stream.whole"):
+            behind[k] = whole
+            assert whole["ahead"] == 1 and whole["thread"] == fit["thread"]
+            assert end(called) <= whole["t0_s"]
+            assert end(whole) <= answered["t0_s"] < end(answered)
+            waited = _children(spans, fit, "stream.take")
+            assert all(s["behind"] == 1 and end(called) <= s["t0_s"]
+                       and end(s) <= whole["t0_s"] for s in waited)
+            waits += len(waited)
+        assert end(answered) <= published[k]["t0_s"]
+        if k:  # from the model that stands once k - 1's listeners are back
+            assert end(published[k - 1]) <= called["t0_s"]
+    # the first is copied inside its fit and the second taken after it:
+    # fits 2 to 5 (of 1 to 6) have the next one queued behind them
+    assert sorted(behind) == [1, 2, 3, 4]
+    assert waits <= 4 and (waits if pace == "during" else not waits)
+    assert _joins(spans) == [0, 1, 1, 1, 1]
+    assert [s["ahead"] for s in turns] == [0, 0, 1, 1, 1, 1]
+
+
+@pytest.mark.parametrize("pace", PACES)
+def test_weights_a_listener_sets_are_where_the_next_micro_batch_starts(
+        pace, monkeypatch, tmp_path):
+    """Why fit k+1 is NOT dispatched behind fit k with the join: a
+    model-update listener may set the weights the next micro-batch starts
+    from (``bench/entries/stream_train_on_uneven.py`` does, at each pass's
+    end).  Every micro-batch trains from what the listener of the one
+    before it set, ahead as in turn, bit for bit."""
+    from tpu_sgd import LogisticRegressionWithSGD
+
+    _blocked(monkeypatch, d=6)
+    stream = _uneven_logistic(UNEVEN)
+    starts = [np.linspace(k, -k, 6).astype(np.float32) / 4 for k in range(7)]
+
+    def fold(batches):
+        alg = StreamingLogisticRegressionWithSGD(step_size=0.2,
+                                                 num_iterations=8)
+        alg.set_initial_weights(starts[0])
+        seen = []
+
+        def listener(model, count):
+            seen.append(np.asarray(model.weights).copy())
+            alg.set_initial_weights(starts[count])
+
+        alg.add_model_update_listener(listener)
+        if batches is None:
+            for X, y in stream:
+                alg.train_on_batch(X, y)
+        else:
+            alg.train_on(batches)
+        return seen
+
+    ahead, spans = _traced(tmp_path, fold, _paced(monkeypatch, pace)(stream))
+    turn = fold(None)
+    if pace != "as it comes":
+        assert _joins(spans) == [0] + [int(pace in BEHIND)] * 4
+    assert len(ahead) == len(turn) == 6
+    for k, ((X, y), got, same) in enumerate(zip(stream, ahead, turn)):
+        np.testing.assert_array_equal(got, same)
+        want = LogisticRegressionWithSGD(0.2, 8, 0.0, 1.0).run(
+            (X, y), starts[k]).weights
+        np.testing.assert_allclose(got, np.asarray(want), rtol=0, atol=2e-6)
+
+
+@pytest.mark.parametrize("form", ["rows", "totals"])
+def test_the_rows_and_the_totals_forms_are_never_joined_behind_a_fit(
+        form, monkeypatch, tmp_path):
+    """Behind a running fit goes the join that writes the capacity's array
+    over in place, and no other.  A micro-batch in the ROWS form (a size
+    that repeats: its join makes an array of its own, and the device would
+    hold it beside the one in training) and one in the TOTALS form (nothing
+    to join) come in turn, whatever the worker has done; a capacity form
+    that follows a rows form finds no array to be written over and is made
+    in turn too; the fold is the in-turn fold bit for bit."""
+    _blocked(monkeypatch, d=6)
+    if form == "rows":
+        sizes = [2100, 3000, 3000, 2500, 2600, 2600, 2700]
+        stream, fold = _uneven_logistic(sizes), _logistic_fold
+    else:
+        stream, fold = _shaped_stream([3 * 1024 + 100] * 5), _statistics_fold
+    (ahead, calls_a), spans = _traced(
+        tmp_path, fold, _paced(monkeypatch, "done")(stream))
+    turn, calls_t = fold(stream, "in turn")
+    _same_fold(ahead, turn, calls_a, calls_t)
+    turns = [s for s in spans if s["name"] == "stream.batch"]
+    if form == "totals":
+        assert _joins(spans) == [] and ahead._capacity == 0
+        # the first two were taken before the stream's first fit had planned
+        assert [s["totals"] for s in turns] == [0, 0, 1, 1, 1]
+        return
+    # at the capacity: 1 (in its fit), 2, 4, 5 and 7; own rows: 3 and 6.
+    # Behind a fit: 5 behind 4 and no other (2 follows the first, which is
+    # copied in its fit; 4 and 7 follow rows forms: no array to write over)
+    assert [s.get("capacity", 0) for s in turns] \
+        == [4096, 4096, 0, 4096, 4096, 0, 4096]
+    assert _joins(spans) == [0, 0, 0, 1, 0, 0]
+
+
+def test_a_join_queued_behind_a_running_fit_leaves_its_result_unchanged(
+        monkeypatch):
+    """What the early join rests on: the array a dispatched fit reads may be
+    DONATED to a program dispatched behind it.  The device's queue runs the
+    join after the fit and the runtime keeps the buffer until then: the
+    fit's weights and losses are the ones it has with no join behind it, bit
+    for bit, though the join writes other rows over every row it reads; the
+    join's result lies where the trained array lay and holds the next
+    micro-batch's rows, zeros behind them."""
+    from tpu_sgd import LogisticRegressionWithSGD
+
+    gd = _blocked(monkeypatch, d=16)
+    (Xa, ya), (Xb, yb) = _uneven_logistic([60000, 50000], d=16)
+    Xb = Xb + 9  # rows that would show in any sum they were read into
+
+    def fit(behind):
+        a = gd.StagedAhead(Xa, ya, capacity=65536).whole()
+        b = gd.StagedAhead(Xb, yb, capacity=65536)
+        assert b.lands_in(a) and not a.lands_in(b)
+        lay = a.X.unsafe_buffer_pointer()
+        if behind:  # dispatched once the fit's program is queued
+            a.behind = lambda training, done: b.whole(training)
+        opt = LogisticRegressionWithSGD(0.2, 30, 0.0, 1.0).optimizer
+        w, losses = opt.optimize_with_history((a, a.y),
+                                              np.zeros(16, np.float32))
+        assert (a.X is None) == behind
+        if not behind:
+            b.whole(a)
+        return np.asarray(w), np.asarray(losses), b, lay
+
+    w, losses, b, lay = fit(behind=True)
+    w_turn, losses_turn, b_turn, _ = fit(behind=False)
+    np.testing.assert_array_equal(w, w_turn)
+    np.testing.assert_array_equal(losses, losses_turn)
+    assert losses.shape == (30,) and np.isfinite(losses).all()
+    assert b.X.unsafe_buffer_pointer() == lay  # in place, as in turn
+    for made in (b, b_turn):
+        np.testing.assert_array_equal(np.asarray(made.X[:50000]), Xb)
+        assert not np.asarray(made.X[50000:]).any()
+        np.testing.assert_array_equal(np.asarray(made.y[:50000]), yb)
+
+
+def test_a_fit_that_fails_at_its_fetch_ends_the_stream_there(monkeypatch):
+    """An error fit k raises once its program has run (a non-finite loss
+    under ``check_numerics``) ends the stream at k as in turn: k is not
+    published, the model is k - 1's, and the join of k+1 that was queued
+    behind the fit is harmless (its micro-batch is not consumed: a replay
+    trains it)."""
+    _blocked(monkeypatch, d=6)
+    stream = _uneven_logistic(UNEVEN)
+    bad = (stream[3][0] * np.float32("inf"), stream[3][1])
+    want, _ = _logistic_fold(stream[:3])
+
+    alg = StreamingLogisticRegressionWithSGD(step_size=0.2, num_iterations=8)
+    alg.set_initial_weights(np.zeros(6, np.float32))
+    alg.algorithm.optimizer.set_check_numerics(True)
+    batches = _paced(monkeypatch, "done")(stream[:3] + [bad] + stream[4:])
+    with pytest.raises(FloatingPointError):
+        alg.train_on(batches)
+    assert alg._batch_count == 3
+    np.testing.assert_array_equal(np.asarray(alg.latest_model().weights),
+                                  np.asarray(want.latest_model().weights))
+    alg.train_on(iter(stream[4:]))  # and the model trains on after it
+    assert alg._batch_count == 5

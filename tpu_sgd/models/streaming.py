@@ -54,12 +54,23 @@ under the fit before that one, which a small copy left the wire idle in),
 and the stream's array of the capacity is made once: each micro-batch's
 rows are written over the one trained before it, in place, so the device
 never frees an array of that size to find room for the next while blocks
-are landing beside it.
+are landing beside it.  That write needs nothing the fit before it
+produces, only the array once the fit has read it, which the device's queue
+orders by itself: where the worker's take is done while fit k runs (the
+fold waits for it under the fit, never past the fit's end), micro-batch
+k+1's join is dispatched BEHIND fit k, before the host waits for the fit,
+runs the moment the fit ends, and the host's turn-around (the fetch, the
+publish with its listeners, the next fit's select and dispatch) passes
+under it (PERF.md, PR 60).  Fit k+1 is still dispatched only once the
+listeners of k have returned: they may set the weights it starts from.
 
 Spans (``obs.spans``):
 ``stream.run`` is all of ``train_on``; on its thread ``stream.wait``
 (``stream.take`` inside it: the worker's answer; then in the rows form
-``stream.whole``: the blocks made whole),
+``stream.whole``: the blocks made whole, ``ahead`` 0; a micro-batch made
+whole behind the fit before it has its ``stream.whole``, ``ahead`` 1, inside
+THAT fit, between ``train.dispatch`` and ``train.fetch``, and its own
+``stream.wait`` holds neither),
 ``stream.batch`` (``index``, ``rows``: the real ones, ``ahead``: 1 where
 the worker was
 issuing this batch's copy before the previous batch's fit returned,
@@ -85,6 +96,7 @@ exactly, because each micro-batch update is deterministic in
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import itertools
 import threading
 from concurrent.futures import ThreadPoolExecutor
@@ -142,7 +154,10 @@ def _take(batches, began: threading.Event, stage: bool, training: list,
     capacity its LABELS, made whole behind the rows, which the next one's
     are written over): the first block is
     issued only once they are whole on the device, when the blocks they were
-    made of are gone.  Transfers do not wait for the chip, so a late join
+    made of are gone (a join queued behind a running fit is whole when that
+    fit has ended and the join has run: a take submitted with it waits as
+    long as one submitted after the fit would have).  Transfers do not wait
+    for the chip, so a late join
     (its last blocks still on the wire, a stalled device) would else have a
     third micro-batch land beside its blocks and its result (12.6 GB read
     in one traced run; PERF.md, PR 40).
@@ -502,11 +517,23 @@ class StreamingLinearAlgorithm:
         blocks of the one ahead; of TWO ahead where three arrays of the
         capacity fit the device (``_capacity_of``), since a large
         micro-batch's copy does not fit under the fit of a small one before
-        it.  The weights, the listeners' calls and the
+        it.  There the write itself goes ahead too: where the worker's
+        take of micro-batch k+1 is done while fit k runs, its rows'
+        join is queued on the device BEHIND fit k, before the host waits
+        for the fit (``_fold_ahead``), and runs as the fit ends, so the
+        host's turn-around between two micro-batches (the fetch, the
+        publish, the next fit's dispatch) passes under it and not under an
+        idle chip; a take that is not done when the fit has ended (the
+        model is published without waiting for it), a regrown capacity and
+        every other form go in turn, as observed, with no setting.  Fit k+1
+        itself is dispatched only after k's checkpoint and listeners, from
+        the model that stands then (a listener may set it).  The weights,
+        the listeners' calls and the
         checkpoints are the in-turn fold's, bit for bit (a batch taken
         ahead and not yet trained has not advanced ``_batch_count``; its
         totals are folded by the programs, in the order, that
-        ``train_on_batch`` folds them with)."""
+        ``train_on_batch`` folds them with; a join is the same program over
+        the same operands whenever it is dispatched)."""
         if skip is None:
             skip = self._resume_skip
         self._resume_skip = 0
@@ -583,12 +610,22 @@ class StreamingLinearAlgorithm:
         every block issued and every fold dispatched, the fit queues behind
         the last of them on the device) and in the rows form then
         ``stream.whole`` (the blocks
-        made whole), then in ``stream.batch`` the fit's own and
+        made whole; ``ahead`` 0), then in ``stream.batch`` the fit's own and
         ``stream.publish``; ``stream.stage`` is the worker's.
         ``stream.batch`` says ``totals`` 1 where its fit ran from a bundle
-        made ahead of it."""
+        made ahead of it.
+
+        At a capacity micro-batch k+1 is made whole BEHIND the running fit
+        of micro-batch k where the fold can (``whole_behind``; PERF.md, PR
+        60): its ``stream.whole`` (``ahead`` 1) then lies inside fit k,
+        between ``train.dispatch`` and ``train.fetch``, the join runs the
+        moment the fit ends, the host's turn-around passes under it, and
+        the ``stream.wait`` in front of fit k+1 holds no leaf.  Fit k+1
+        itself is never dispatched ahead: a listener of k may set the
+        weights it starts from."""
         alive = collections.deque()  # the worker's blocks not yet folded
         pending = collections.deque()  # the worker's takes, in stream order
+        made = []  # the micro-batch made whole behind the running fit
 
         def take(training=None, ahead=1):
             """Takes submitted until ``ahead`` are the worker's; each waits
@@ -600,27 +637,71 @@ class StreamingLinearAlgorithm:
                     self._totals_key(training), alive,
                     self if self._by_capacity() else None)))
 
+        def whole_behind(training, fit_done):
+            """``StagedAhead.behind`` of the capacity form in training: its
+            fit's program is in the device's queue and the host has not
+            begun to wait for it.  Once the worker's oldest take is DONE
+            while the fit still runs, and is a capacity form that ``whole``
+            writes over ``training``'s array in place (``lands_in``: no
+            second array of the capacity), its join is dispatched, behind
+            the fit, and the next takes are submitted as they are in turn.
+            A take still on its way is waited for under the running fit (a
+            ``stream.take`` leaf inside the fit: where the wire is the
+            slower the worker answers late in it) and no longer than the
+            fit: once ``fit_done()`` holds the chip has nothing to run
+            until the take is there, nothing is left to hide, and the model
+            is published first, in turn.  Anything else (a take that
+            raised, the stream's end, host rows, the rows or the totals
+            form, another capacity's) is left to its turn too: what the
+            fold observes decides, nothing is set."""
+            answer = pending[0][1]  # a fit at a capacity has its takes out
+            if not answer.done():
+                with span("stream.take", behind=1):
+                    # the interval moves nothing: a fit that ends inside it
+                    # ends on a chip that has to wait for this take anyway
+                    while not (answer.done() or fit_done()):
+                        concurrent.futures.wait([answer], timeout=1e-3)
+            if not answer.done() or answer.exception() is not None:
+                return
+            taken = answer.result()
+            if taken is None or not (isinstance(taken[0], StagedAhead)
+                                     and taken[0].lands_in(training)):
+                return
+            X, y = taken
+            pending.popleft()
+            with span("stream.whole", blocks=X.count, ahead=1):
+                X.whole(training)
+            take(X.y, self._ahead)
+            made.append((X, y))
+
         take()
         under = 0  # 1: this batch's copy began under its predecessor's fit
         spent = None  # the capacity form trained last: its array is the next's
         while True:
             with span("stream.wait"):
-                with span("stream.take"):  # a leaf: the worker's answer
-                    taken = pending.popleft()[1].result()
-                if taken is None:
-                    return
-                X, y = taken
-                if isinstance(X, StagedAhead) and X.totals is None:
-                    # the rows form; its blocks are folded where they lie if
-                    # the plan has come to be for them since they were taken
-                    if not X.capacity and self._totals_key(X) is not None:
-                        X = X.fold(y)
-                        y = X.y
-                    else:
-                        with span("stream.whole", blocks=X.count):
-                            X = X.whole(spent)
+                if made:
+                    taken = made.pop()  # whole already (``whole_behind``)
+                    X, y = taken
                 else:
-                    X = self._in_capacity(X, y)
+                    with span("stream.take"):  # a leaf: the worker's answer
+                        taken = pending.popleft()[1].result()
+                    if taken is None:
+                        return
+                    X, y = taken
+                    if isinstance(X, StagedAhead) and X.totals is None:
+                        # the rows form; its blocks are folded where they
+                        # lie if the plan has come to be for them since
+                        # they were taken
+                        if (not X.capacity
+                                and self._totals_key(X) is not None):
+                            X = X.fold(y)
+                            y = X.y
+                        else:
+                            with span("stream.whole", blocks=X.count,
+                                      ahead=0):
+                                X = X.whole(spent)
+                    else:
+                        X = self._in_capacity(X, y)
                 spent = None
             staged = isinstance(X, StagedAhead)
             at_capacity = staged and bool(X.capacity)
@@ -633,6 +714,7 @@ class StreamingLinearAlgorithm:
                 # they are, and no reference to the rows, which the next
                 # micro-batch's are written over (``whole``)
                 take(X.y, self._ahead)
+                X.behind = whole_behind
             elif not in_turn:
                 take(X)
             with span("stream.batch") as turn:
@@ -646,7 +728,9 @@ class StreamingLinearAlgorithm:
                 updated = self._fit(X, y)
                 if in_turn:
                     take(None, self._ahead)
-                under = int(pending[0][0].is_set())
+                # one made whole behind this fit was in the worker's hands
+                # before the fit returned
+                under = int(bool(made) or pending[0][0].is_set())
                 if at_capacity:
                     spent = X  # its array stays: the next is made in it
                 del taken, X, y  # any other is gone before the next is whole

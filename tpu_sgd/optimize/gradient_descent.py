@@ -497,6 +497,10 @@ class StagedAhead:
         self.blocks, self.totals, self.y, self.folded = None, None, None, 0
         self.rows, self.capacity, self.X = X.shape[0], capacity, None
         self.valid = None  # the capacity form's row count, on the device
+        #: the capacity form's: ``behind(self, done)`` is called by the fit
+        #: that trains it once its program is in the device's queue
+        #: (``queued``)
+        self.behind = None
         #: what crosses the wire of X (the capacity form: with its last
         #: block's fill)
         self._host, self.wire_bytes = None, X.nbytes
@@ -567,25 +571,50 @@ class StagedAhead:
                 "this optimizer's schedule was changed since it was staged")
         return self._host[:2]
 
+    def lands_in(self, into) -> bool:
+        """Whether ``whole(into)`` writes this one's rows over the array of
+        ``into`` (a capacity form) in place and makes no array of its own: a
+        capacity form that is not whole yet, and ``into`` holds an array of
+        this one's shape and type."""
+        return bool(
+            self.capacity and self.X is None and into.X is not None
+            and into.X.shape == self.shape
+            and into.X.dtype == jax.dtypes.canonicalize_dtype(self.dtype))
+
+    def queued(self, done):
+        """The fit's word that the program which trains this capacity form
+        is in the device's queue and the host has not begun to wait for it:
+        what ``behind`` dispatches before ``done()`` holds (the fit's answer
+        without a wait: whether the program has run) runs the moment the fit
+        ends, under the host's turn-around
+        (``StreamingLinearAlgorithm._fold_ahead`` queues the next
+        micro-batch's join there).  Nothing where no one asked."""
+        if self.behind is not None:
+            self.behind(self, done)
+
     def whole(self, into=None):
         """The one device array; the blocks are given up.  The capacity
         form: itself, with ``X`` and ``y`` made (once).  ``into`` is a
-        capacity form that has been trained: its ``X`` is given up before
-        anything is made, and where it is of this one's shape and type this
-        one's rows are written over it in place (``_stage_join``'s
-        ``into``), so the stream's array of the capacity stays where it lies
-        from one micro-batch to the next."""
+        capacity form that has been trained, or one whose fit is RUNNING
+        (``queued``): its ``X`` is given up before anything is made, and
+        where it is of this one's shape and type (``lands_in``) this one's
+        rows are written over it in place (``_stage_join``'s ``into``), so
+        the stream's array of the capacity stays where it lies from one
+        micro-batch to the next.  Under a running fit the device's queue
+        orders the two: the join runs once every program dispatched before
+        it has read the array, and the runtime holds the buffer until then
+        (``tests/test_streaming.py`` pins that the fit's result is the one
+        it has without the join behind it)."""
         spent = None
         if into is not None:
+            over = self.lands_in(into)
             spent, into.X = into.X, None
+            if not over:
+                spent = None  # another capacity's: gone, not written over
         if self.capacity:
             if self.X is None:
                 if self._host is not None:
                     self._issue()
-                if spent is not None and (
-                        spent.shape != self.shape
-                        or spent.dtype != self.blocks[0].dtype):
-                    spent = None  # another capacity's: gone, not written over
                 made = []
                 for blocks, dest in ((self.blocks, spent),
                                      (self.labels, None)):
@@ -2139,7 +2168,7 @@ class GradientDescent(Optimizer):
             return w, hist
         # host time in the calls: a large X's blocks are issued in here,
         # the last of them (any other copy whole) may still drain after it
-        valid = None
+        valid = queued = None
         with span("train.h2d", bytes=sum(
                 a.nbytes for a in (X, y)
                 if isinstance(a, np.ndarray)
@@ -2152,7 +2181,7 @@ class GradientDescent(Optimizer):
                         block_bytes=X.wire_bytes // X.count, shards=1,
                         flat=0)
                 at = X if X.X is not None else X.whole()
-                X, y, valid = at.X, at.y, at.valid
+                X, y, valid, queued = at.X, at.y, at.valid, at.queued
             elif self._hands_off_sharded(X):
                 # each row block to the device that owns its rows; y and
                 # the mask of padded rows lie sharded beside them
@@ -2183,7 +2212,8 @@ class GradientDescent(Optimizer):
             warnings.warn(
                 "The miniBatchFraction is too small", RuntimeWarning, stacklevel=3
             )
-        return self._optimize_routed(X, y, w0, sparse_X, run_span, valid)
+        return self._optimize_routed(X, y, w0, sparse_X, run_span, valid,
+                                     queued)
 
     def _hands_off_sharded(self, X) -> bool:
         """Whether ``train.h2d`` sends ``X`` straight to the mesh's devices,
@@ -2196,14 +2226,19 @@ class GradientDescent(Optimizer):
                 and self.mesh is not None and self._mesh_kind() == "dp"
                 and jax.process_count() == 1)
 
-    def _optimize_routed(self, X, y, w0, sparse_X, run_span, valid=None):
+    def _optimize_routed(self, X, y, w0, sparse_X, run_span, valid=None,
+                         queued=None):
         """Resident-data path routing (single-device / mesh / sparse /
         stepwise), after input coercion.  The fused fit's leaves tile it:
         ``train.place`` (a 1-D mesh alone), ``train.stats`` where the
         sufficient-stats substitution builds (``_maybe_gram``), then
         ``train.select`` — the route, the compiled runner's lookup and
         what ``train.run`` says of the step — up to the start of
-        ``train.dispatch``, and ``train.fetch`` to the return."""
+        ``train.dispatch``, and ``train.fetch`` to the return.  ``queued``
+        (a stream's micro-batch at a row capacity alone:
+        ``StagedAhead.queued``; None for every other fit) is called between
+        the two, once the fused program is in the device's queue, with the
+        program's own answer to "has it run" (no wait)."""
         import numpy as np
 
         if self.listener is not None or self.checkpoint_manager is not None:
@@ -2243,6 +2278,8 @@ class GradientDescent(Optimizer):
             n_rec.copy_to_host_async()
             losses.copy_to_host_async()
             w.copy_to_host_async()
+        if queued is not None:
+            queued(n_rec.is_ready)
         with span("train.fetch") as sp:
             recorded = int(n_rec)
             self._loss_history = np.asarray(losses)[:recorded]
