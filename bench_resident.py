@@ -234,25 +234,27 @@ def main():
     Xd, yd = jnp.asarray(X), jnp.asarray(y)
     vd = jnp.ones((ROWS,), bool)
 
-    def step_fn(w_, i_, rv_, Xr, yr, vr):
-        return step(w_, Xr, yr, i_, rv_, vr)
+    hyper = jax.device_put(cfg.hyper())  # the updater's two operands
+
+    def step_fn(w_, i_, rv_, hy, Xr, yr, vr):
+        return step(w_, Xr, yr, i_, rv_, hy, vr)
 
     w0d = jnp.asarray(np.zeros(DIM, np.float32))  # outside the regions
     loop_one = ResidentLoop(step_fn, cfg, K, C)
     hooks = ResidentBookkeeper(cfg, K, C, losses=[], reg_val=0.0,
                                start_iter=1)
-    loop_one.run(w0d, 0.0, 1, (Xd, yd, vd), hooks)  # warm
+    loop_one.run(w0d, 0.0, 1, (hyper, Xd, yd, vd), hooks)  # warm
     with assert_dispatch_count(1):
-        loop_one.run(w0d, 0.0, 1, (Xd, yd, vd),
+        loop_one.run(w0d, 0.0, 1, (hyper, Xd, yd, vd),
                      ResidentBookkeeper(cfg, K, C, losses=[],
                                         reg_val=0.0, start_iter=1))
     cfg_full = cfg.replace(num_iterations=ITERS)
     loop_full = ResidentLoop(step_fn, cfg_full, K, C)
-    loop_full.run(w0d, 0.0, 1, (Xd, yd, vd),
+    loop_full.run(w0d, 0.0, 1, (hyper, Xd, yd, vd),
                   ResidentBookkeeper(cfg_full, K, C, losses=[],
                                      reg_val=0.0, start_iter=1))  # warm
     with count_dispatches() as full_count:
-        loop_full.run(w0d, 0.0, 1, (Xd, yd, vd),
+        loop_full.run(w0d, 0.0, 1, (hyper, Xd, yd, vd),
                       ResidentBookkeeper(cfg_full, K, C, losses=[],
                                          reg_val=0.0, start_iter=1))
     assert full_count["n"] == 1, full_count
@@ -333,7 +335,7 @@ def main():
             i0 = 1
             while i0 <= iters:
                 steps = min(K, iters - i0 + 1)
-                w, ys = fused(w, jnp.asarray(rv, jnp.float32),
+                w, ys = fused(w, jnp.asarray(rv, jnp.float32), hyper,
                               jnp.asarray(i0, jnp.int32), Xd, yd, vd)
                 ys_h = tuple(np.asarray(a) for a in ys)
                 _, rv, _ = _replay_fused_steps(ys_h, i0, steps, losses,
@@ -350,15 +352,15 @@ def main():
         step_i = make_step(LeastSquaresGradient(), SimpleUpdater(),
                            rcfg)
         loop = ResidentLoop(
-            lambda w_, i_, rv_, Xr, yr, vr: step_i(w_, Xr, yr, i_, rv_,
-                                                   vr),
+            lambda w_, i_, rv_, hy, Xr, yr, vr: step_i(w_, Xr, yr, i_,
+                                                       rv_, hy, vr),
             rcfg, K, C)
 
         def once():
             hooks = ResidentBookkeeper(rcfg, K, C, losses=[],
                                        reg_val=0.0, start_iter=1)
             t0 = time.perf_counter()
-            loop.run(w0d, 0.0, 1, (Xd, yd, vd), hooks)
+            loop.run(w0d, 0.0, 1, (hyper, Xd, yd, vd), hooks)
             return time.perf_counter() - t0
 
         once()  # warm the compile

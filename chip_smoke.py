@@ -452,8 +452,8 @@ def phase_data_parallel(n: int, d: int, iters: int, seed: int, devices,
 
     opt = alg.optimizer  # the same plugins and config on both meshes
     text = dp_run_fn(opt.gradient, opt.updater, opt.config, mesh,
-                     False).lower(np.zeros((d,), np.float32), Xd,
-                                  yd).compile().as_text()
+                     False).lower(np.zeros((d,), np.float32), Xd, yd,
+                                  opt.config.hyper()).compile().as_text()
     _require("all-reduce" in text, "no all-reduce in the compiled "
              "data-parallel run program")
     return {

@@ -65,6 +65,17 @@ def no_clock():
     return NoClock
 
 
+@pytest.fixture(autouse=True)
+def _no_live_runners():
+    """Every test starts as a new process does: no runner of an earlier
+    test's optimizer is found live (``optimize/run_store.py``: ``_LIVE``), so
+    what a test counts of traces, compiles and store files is its own."""
+    from tpu_sgd.optimize import run_store
+
+    run_store._LIVE.clear()
+    yield
+
+
 @pytest.fixture
 def compile_cache(tmp_path):
     """An empty persistent compile cache directory for the length of a test

@@ -98,9 +98,20 @@ def _cfg(**kw):
     return SGDConfig(**base)
 
 
+def _hyper(like=None):
+    """The step size and the regulariser as a fit hands them to its program
+    (``config.Hyper``): two weakly typed float32 scalars, placed as ``like``
+    (a spec of the same lowering: the one chip, or replicated on the mesh)."""
+    from tpu_sgd.config import Hyper
+
+    one = jax.ShapeDtypeStruct((), F32, weak_type=True,
+                               sharding=getattr(like, "sharding", None))
+    return Hyper(one, one)
+
+
 def _dense_args(S):
     return (S((D,), F32), S((N, D), BF16), S((N,), F32), S((), I32),
-            S((), F32))
+            S((), F32), _hyper(S((), F32)))
 
 
 # -- the dense trainer's programs ------------------------------------------
@@ -130,7 +141,8 @@ def test_whole_run_program_compiles(S):
 
     run = make_run(LeastSquaresGradient(), SimpleUpdater(), _cfg())
     compiled = jax.jit(run).lower(
-        S((D,), F32), S((N, D), BF16), S((N,), F32)).compile()
+        S((D,), F32), S((N, D), BF16), S((N,), F32),
+        _hyper(S((), F32))).compile()
     assert "while" in compiled.as_text()
 
 
@@ -151,7 +163,8 @@ def test_whole_run_fusions_keep_the_step_scopes(S, d):
 
     run = make_run(LogisticGradient(), SquaredL2Updater(), _cfg())
     text = jax.jit(run).lower(
-        S((d,), F32), S((N, d), BF16), S((N,), F32)).compile().as_text()
+        S((d,), F32), S((N, d), BF16), S((N,), F32),
+        _hyper(S((), F32))).compile().as_text()
 
     def scopes(opcode):
         return {m.group(1) for m in re.finditer(
@@ -199,7 +212,7 @@ def test_whole_run_at_the_cells_shapes_reads_x_in_place(S, cell):
                convergence_tol=0.0)
     compiled = jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg)
                        ).lower(S((D,), F32), S((n, D), BF16),
-                               S((n,), F32)).compile()
+                               S((n,), F32), _hyper(S((), F32))).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "bf16[%d,%d]{1,0:T(8,128)(2,1)} bitcast(" % (D, n) in text
@@ -227,7 +240,8 @@ def test_sliced_run_at_the_cells_shape_reads_the_window_once_in_place(
     if shards == 1:
         compiled = jax.jit(
             make_run(LogisticGradient(), SquaredL2Updater(), cfg)).lower(
-                S((D,), F32), S((n, D), BF16), S((n,), F32)).compile()
+                S((D,), F32), S((n, D), BF16), S((n,), F32),
+                _hyper(S((), F32))).compile()
     else:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -239,7 +253,9 @@ def test_sliced_run_at_the_cells_shape_reads_the_window_once_in_place(
                              mesh4, with_valid=True).lower(
             S((D,), F32, NamedSharding(mesh4, P())),
             S((n, D), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
-            S((n,), F32, rows), S((n,), jnp.bool_, rows)).compile()
+            S((n,), F32, rows),
+            _hyper(S((), F32, NamedSharding(mesh4, P()))),
+            S((n,), jnp.bool_, rows)).compile()
     text = compiled.as_text()
     local, m = n // shards, round(0.1 * (n // shards))
     assert text.count('custom_call_target="tpu_custom_call"') == 1
@@ -268,7 +284,8 @@ def _classes_run(S):
                reg_param=0.001, convergence_tol=0.0)
     return jax.jit(make_run(MultinomialLogisticGradient(K),
                             SquaredL2Updater(), cfg)).lower(
-        S(((K - 1) * d,), F32), S((n, d), BF16), S((n,), F32)).compile()
+        S(((K - 1) * d,), F32), S((n, d), BF16), S((n,), F32),
+        _hyper(S((), F32))).compile()
 
 
 def test_classes_run_at_the_cells_shape_reads_x_once_in_place(S):
@@ -437,13 +454,14 @@ def test_the_masked_fits_loop_makes_no_array_of_the_masks_size(
             S((D,), F32, NamedSharding(mesh4, P())),
             S((4 * n, D), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
             S((4 * n,), F32, NamedSharding(mesh4, P(DATA_AXIS))),
+            _hyper(S((), F32, NamedSharding(mesh4, P()))),
         ).compile().as_text()
     else:
         cfg = _cfg(step_size=5.0, num_iterations=100, reg_param=0.001,
                    convergence_tol=0.0)
         text = jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg)
-                       ).lower(S((D,), F32), S((n, D), BF16),
-                               S((n,), F32)).compile().as_text()
+                       ).lower(S((D,), F32), S((n, D), BF16), S((n,), F32),
+                               _hyper(S((), F32))).compile().as_text()
     comps = _computations(text)
     body = _reach(comps, _fit_loop_body(comps))
     assert _made_with(comps, body, n) == []
@@ -477,11 +495,15 @@ def test_the_masked_fits_loop_makes_no_array_of_the_masks_size(
 #: 31 put the window's grid beside the masked one on the same body and left
 #: them as they were) and the StableHLO PR 33's (the labels' ``reshape`` in
 #: front of the ``while``, ``sgd.prepare``).
+#: PR 62 moved the FIRST digest of every pair here and no second: the step
+#: size and the regulariser are operands of the program (``config.Hyper``:
+#: two scalar parameters more, their constants gone from the update), the
+#: kernels' bodies are as they were.
 #: A PR that means to change the masked step changes them here, and says so.
 MASKED_PROGRAMS = {
-    "resident": ("b0d759422ecb7c46", "ebe1ae5e828b7d7c"),
-    "from-host": ("6b2dd818dd5e44d0", "a1e7fb6617a1c983"),
-    "resident-sharded": ("b39c1a803f76616e", "03e9de77b6558f08"),
+    "resident": ("cdd4654faf10badf", "ebe1ae5e828b7d7c"),
+    "from-host": ("e4fad55c2050a9d3", "a1e7fb6617a1c983"),
+    "resident-sharded": ("687231a842a079b4", "03e9de77b6558f08"),
 }
 
 
@@ -508,7 +530,8 @@ def test_the_masked_cells_lowered_program_is_the_pinned_one(cell):
             _cfg(step_size=5.0, num_iterations=100, reg_param=0.001,
                  convergence_tol=0.0)))
     assert _program_digests(fn, shape((D,), F32), shape((n, D), BF16),
-                            shape((n,), F32)) == MASKED_PROGRAMS[cell]
+                            shape((n,), F32), _hyper()
+                            ) == MASKED_PROGRAMS[cell]
 
 
 def _program_digests(fn, *shapes):
@@ -555,12 +578,16 @@ def _lowered_for_a_tpu(fn, *shapes):
 #: PR 37 left them and as PR 39 found and kept them while it gave the class
 #: body its by-rows orientation; and the CIFAR-5m cell's by-rows program,
 #: which is PR 39's.
+#: PR 62 moved the FIRST digest of every pair here and no second: the step
+#: size and the regulariser are operands of the program (``config.Hyper``:
+#: two scalar parameters more, their constants gone from the update), the
+#: kernels' bodies are as they were.
 #: A PR that means to change one of these steps changes its pair here.
 OTHER_PROGRAMS = {
-    "sliced": ("e3280a2b1fc6b199", "40c8672db62c8475"),
-    "classes": ("13db878507830ea7", "29123780d775e1cd"),
-    "wide": ("093108cc0f17cb39", "e267abd75882cd0a"),
-    "cifar5m": ("222dd9de2b854bcb", "20c7220b35aa989c"),
+    "sliced": ("bafa874aaf596ab7", "40c8672db62c8475"),
+    "classes": ("23aaccb6d1ca7888", "29123780d775e1cd"),
+    "wide": ("5a9a6b3024e69e5a", "e267abd75882cd0a"),
+    "cifar5m": ("a31e82e867ab897f", "20c7220b35aa989c"),
 }
 
 
@@ -590,7 +617,8 @@ def test_the_other_cells_lowered_program_is_the_pinned_one(cell):
     shape = jax.ShapeDtypeStruct
     assert _program_digests(
         jax.jit(make_run(grad, upd, cfg)), shape((wd,), F32),
-        shape((n, d), BF16), shape((n,), F32)) == OTHER_PROGRAMS[cell]
+        shape((n, d), BF16), shape((n,), F32), _hyper()
+    ) == OTHER_PROGRAMS[cell]
 
 
 #: the same pair for the step programs no pair above holds, PR 47's, taken
@@ -602,16 +630,20 @@ def test_the_other_cells_lowered_program_is_the_pinned_one(cell):
 #: selection's exclusions at the cells' own widths: a Bernoulli mask under
 #: the by-rows and the wide form (an array operand, no draw in the class
 #: body), a window at the wide width and by rows (two matvecs, no call).
+#: PR 62 moved the FIRST digest of every pair here but the fold's and no second: the step
+#: size and the regulariser are operands of the program (``config.Hyper``:
+#: two scalar parameters more, their constants gone from the update), the
+#: kernels' bodies are as they were.
 #: A PR that means to change one of these programs changes its pair here.
 SELECTED_PROGRAMS = {
-    "stream_totals": ("ae4d46a5fe20d31d", "e3b0c44298fc1c14"),
+    "stream_totals": ("2c8059dc84f194bd", "e3b0c44298fc1c14"),
     "stream_fold": ("9a66ecd2b3100030", "e3b0c44298fc1c14"),
-    "stream_first": ("a53cab35497cb88d", "0485639c28be3e02"),
-    "padded_shard": ("ae8e6427eca5f23b", "f17d32ed29ba13f4"),
-    "rows_masked": ("08bdf0aed5ec544a", "cca4706f4d6f187f"),
-    "wide_masked": ("896c1e160f3cf342", "426f73b1e6508f0d"),
-    "wide_window": ("d872a177738e8bea", "e3b0c44298fc1c14"),
-    "rows_window": ("fdeb22b5b29cd72b", "e3b0c44298fc1c14"),
+    "stream_first": ("7b86996e009a0fb8", "0485639c28be3e02"),
+    "padded_shard": ("e728ef480b1b1fdc", "f17d32ed29ba13f4"),
+    "rows_masked": ("437b33a5f8685047", "cca4706f4d6f187f"),
+    "wide_masked": ("af8544c54b1a2058", "426f73b1e6508f0d"),
+    "wide_window": ("a2cdc76c502bb7fd", "e3b0c44298fc1c14"),
+    "rows_window": ("a3feef3f74493697", "e3b0c44298fc1c14"),
 }
 
 
@@ -635,7 +667,7 @@ def test_the_selected_step_programs_are_the_pinned_ones(cell):
         stats = gram.GramData(
             None, None, None, None, shape((D, D), F32), shape((D,), F32),
             shape((), F32), n, logical_shape=(n, D), logical_dtype=BF16)
-        shapes = (shape((D,), F32), stats, shape((n,), F32))
+        shapes = (shape((D,), F32), stats, shape((n,), F32), _hyper())
     elif cell == "padded_shard":
         from tpu_sgd.parallel.data_parallel import dp_run_fn
         from tpu_sgd.parallel.mesh import data_mesh
@@ -645,7 +677,7 @@ def test_the_selected_step_programs_are_the_pinned_ones(cell):
                        _cfg(step_size=1.0, reg_param=0.0, **base),
                        data_mesh(jax.devices()[:4]), True)
         shapes = (shape((D,), F32), shape((n, D), BF16), shape((n,), F32),
-                  shape((n,), jnp.bool_))
+                  _hyper(), shape((n,), jnp.bool_))
     else:
         grad, upd, cfg, n, d = {
             "stream_first": (
@@ -667,7 +699,8 @@ def test_the_selected_step_programs_are_the_pinned_ones(cell):
                             131_072, 47_236),
         }[cell]
         fn = jax.jit(make_run(grad, upd, cfg))
-        shapes = (shape((d,), F32), shape((n, d), BF16), shape((n,), F32))
+        shapes = (shape((d,), F32), shape((n, d), BF16), shape((n,), F32),
+                  _hyper())
     assert _program_digests(fn, *shapes) == SELECTED_PROGRAMS[cell]
 
 
@@ -725,7 +758,7 @@ def test_superstep_k8_compiles(S):
     sstep = make_superstep(LeastSquaresGradient(), SimpleUpdater(),
                            _cfg(mini_batch_fraction=1.0))
     text = jax.jit(sstep).lower(
-        S((D,), F32), S((), F32), S((), I32),
+        S((D,), F32), S((), F32), _hyper(S((), F32)), S((), I32),
         S((K, m, D), BF16), S((K, m), F32), S((K, m), jnp.bool_),
     ).compile().as_text()
     assert "tpu_custom_call" in text  # each step's sums: the one-read kernel
@@ -740,9 +773,9 @@ def test_resident_while_loop_compiles(S):
     cfg = _cfg(num_iterations=64)
     step = make_step(LeastSquaresGradient(), SimpleUpdater(), cfg)
     loop = ResidentLoop(
-        lambda w, i, rv, X, y: step(w, X, y, i, rv, None), cfg, 8, 4)
+        lambda w, i, rv, hyper, X, y: step(w, X, y, i, rv, hyper), cfg, 8, 4)
     compiled = loop._fn.lower(
-        S((D,), F32), S((), F32), S((), I32),
+        S((D,), F32), S((), F32), S((), I32), _hyper(S((), F32)),
         S((N, D), BF16), S((N,), F32)).compile()
     assert "while" in compiled.as_text()
 
@@ -761,6 +794,7 @@ def test_dp_step_4_devices_has_all_reduce(mesh4, S):
         S((D,), F32, rep),
         S((N, D), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
         S((N,), F32, rows), S((), I32, rep), S((), F32, rep),
+        _hyper(S((), F32, rep)),
     ).compile()
     assert "all-reduce" in compiled.as_text()
     # rows are sharded: each device holds a quarter of X
@@ -783,6 +817,7 @@ def test_dp_whole_run_4_devices_has_all_reduce(mesh4, S):
         S((D,), F32, NamedSharding(mesh4, P())),
         S((N, D), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
         S((N,), F32, NamedSharding(mesh4, P(DATA_AXIS))),
+        _hyper(S((), F32, NamedSharding(mesh4, P()))),
     ).compile().as_text()
     assert "all-reduce" in text and "while" in text
     # each shard's block is stored as the whole is: the kernel, no copy
@@ -811,6 +846,7 @@ def test_dp_whole_run_at_the_four_chip_cells_shape_trains_each_shard_in_place(
         S((D,), F32, NamedSharding(mesh4, P())),
         S((n, D), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
         S((n,), F32, NamedSharding(mesh4, P(DATA_AXIS))),
+        _hyper(S((), F32, NamedSharding(mesh4, P()))),
     ).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
@@ -842,11 +878,19 @@ def test_the_four_chip_cells_run_restored_from_its_export_is_the_traced_one(
                         convergence_tol=0.0), mesh4, with_valid=False)
     shapes = (S((D,), F32, NamedSharding(mesh4, P())),
               S((n, D), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
-              S((n,), F32, NamedSharding(mesh4, P(DATA_AXIS))))
-    exported = jax.export.export(fn, platforms=("tpu",))(*shapes)
+              S((n,), F32, NamedSharding(mesh4, P(DATA_AXIS))),
+              _hyper(S((), F32, NamedSharding(mesh4, P()))))
+    # over the arguments' flat leaves, as the store exports it
+    # (``run_store.export``: the operands' ``config.Hyper`` is a node of the
+    # tree, in the key, and needs no serialization registry)
+    leaves, tree = jax.tree_util.tree_flatten(shapes)
+    exported = jax.export.export(
+        jax.jit(lambda *flat: fn(*jax.tree_util.tree_unflatten(tree, flat))),
+        platforms=("tpu",))(*leaves)
     assert exported.nr_devices == 4
     restored = jax.export.deserialize(exported.serialize())
-    compiled = jax.jit(lambda *a: restored.call(*a)).lower(*shapes).compile()
+    compiled = jax.jit(lambda *a: restored.call(
+        *jax.tree_util.tree_leaves(a))).lower(*shapes).compile()
     traced = fn.lower(*shapes).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
@@ -854,7 +898,10 @@ def test_the_four_chip_cells_run_restored_from_its_export_is_the_traced_one(
     assert "sgd.allreduce" in text and "sgd.fused_sums" in text
     assert _moves_of(text, n // 4, D) == [] and _moves_of(text, n, D) == []
     mine, theirs = compiled.memory_analysis(), traced.memory_analysis()
-    assert mine.argument_size_in_bytes == theirs.argument_size_in_bytes
+    # (to the operands' two scalars: the restored call holds them as
+    # arguments of its own, 256 bytes each)
+    assert abs(mine.argument_size_in_bytes
+               - theirs.argument_size_in_bytes) <= 1024
     assert mine.temp_size_in_bytes == theirs.temp_size_in_bytes
     assert mine.output_size_in_bytes == theirs.output_size_in_bytes
 
@@ -1050,7 +1097,7 @@ def _bounded_run(S):
                mini_batch_fraction=1.0, convergence_tol=0.0)
     return jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg)).lower(
         S((D,), F32), S((CAPACITY, D), BF16), S((CAPACITY,), F32),
-        RowCount(S((), I32)))
+        _hyper(S((), F32)), RowCount(S((), I32)))
 
 
 def test_the_bounded_run_at_the_stream_cells_capacity_reads_x_in_place(S):
@@ -1081,7 +1128,7 @@ def test_the_bounded_kernels_grid_is_the_capacitys_and_its_count_a_scalar():
 
     shape = jax.ShapeDtypeStruct
     args = (shape((D,), F32), shape((CAPACITY, D), BF16),
-            shape((CAPACITY,), F32), RowCount(shape((), I32)))
+            shape((CAPACITY,), F32), _hyper(), RowCount(shape((), I32)))
     cfg = _cfg(step_size=0.1, num_iterations=50, reg_param=0.0,
                mini_batch_fraction=1.0, convergence_tol=0.0)
     outside, kernel = _lowered_for_a_tpu(
@@ -1219,7 +1266,8 @@ def test_a_fit_from_the_totals_holds_nothing_of_xs_size(S):
     stats = GramData(None, None, None, None, S((D, D), F32), S((D,), F32),
                      S((), F32), n, logical_shape=(n, D),
                      logical_dtype=BF16)
-    compiled = run.lower(S((D,), F32), stats, S((n,), F32)).compile()
+    compiled = run.lower(S((D,), F32), stats, S((n,), F32),
+                         _hyper(S((), F32))).compile()
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes < 4 * n + 2 * (D * D + 2 * D) * 4
     assert memory.temp_size_in_bytes < 16 << 20
@@ -1237,14 +1285,15 @@ def test_sparse_hinge_l1_step_compiles(S):
     step = make_step(HingeGradient(), L1Updater(), _cfg())
     nse = SPARSE_N * SPARSE_NNZ
 
-    def sparse_step(w, data, idx, y, i, rv):
+    def sparse_step(w, data, idx, y, i, rv, hyper):
         X = BCOO((data, idx), shape=(SPARSE_N, SPARSE_D),
                  indices_sorted=True, unique_indices=True)
-        return step(w, X, y, i, rv, None)
+        return step(w, X, y, i, rv, hyper)
 
     compiled = jax.jit(sparse_step).lower(
         S((SPARSE_D,), F32), S((nse,), F32), S((nse, 2), I32),
-        S((SPARSE_N,), F32), S((), I32), S((), F32)).compile()
+        S((SPARSE_N,), F32), S((), I32), S((), F32),
+        _hyper(S((), F32))).compile()
     mem = compiled.memory_analysis()
     # a densified (200000, 47236) f32 would be 37.8 GB
     assert mem.temp_size_in_bytes < 4 * 2**30
@@ -1261,14 +1310,14 @@ def test_sparse_whole_run_program_compiles(S):
                    _cfg(mini_batch_fraction=1.0))
     nse = SPARSE_N * SPARSE_NNZ
 
-    def sparse_run(w, data, idx, y):
+    def sparse_run(w, data, idx, y, hyper):
         X = BCOO((data, idx), shape=(SPARSE_N, SPARSE_D),
                  indices_sorted=True, unique_indices=True)
-        return run(w, X, y)
+        return run(w, X, y, hyper)
 
     jax.jit(sparse_run).lower(
         S((SPARSE_D,), F32), S((nse,), F32), S((nse, 2), I32),
-        S((SPARSE_N,), F32)).compile()
+        S((SPARSE_N,), F32), _hyper(S((), F32))).compile()
 
 
 # -- the smoke's own device-side generators ----------------------------------
@@ -1387,7 +1436,8 @@ def test_wide_run_at_the_cells_shape_reads_x_once_in_place(S):
     cfg = _cfg(step_size=100.0, num_iterations=100, mini_batch_fraction=1.0,
                reg_param=1e-5, convergence_tol=0.0)
     compiled = jax.jit(make_run(HingeGradient(), L1Updater(), cfg)).lower(
-        S((d,), F32), S((n, d), BF16), S((n,), F32)).compile()
+        S((d,), F32), S((n, d), BF16), S((n,), F32),
+        _hyper(S((), F32))).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     call = next(line for line in text.splitlines()
@@ -1546,7 +1596,8 @@ def test_by_rows_run_at_the_cells_shape_reads_x_once_where_it_lies(S, case):
                convergence_tol=0.0, mini_batch_fraction=0.1
                if case == "vector_bernoulli" else 1.0)
     compiled = jax.jit(make_run(grad, SquaredL2Updater(), cfg)).lower(
-        S((wd,), F32), S((n, d), BF16), S((n,), F32)).compile()
+        S((wd,), F32), S((n, d), BF16), S((n,), F32),
+        _hyper(S((), F32))).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     call = next(line for line in text.splitlines()
@@ -1671,7 +1722,8 @@ def test_the_thousand_class_run_at_the_cells_shape_reads_x_once_where_it_lies(
                convergence_tol=0.0, mini_batch_fraction=1.0)
     compiled = jax.jit(make_run(MultinomialLogisticGradient(K),
                                 SquaredL2Updater(), cfg)).lower(
-        S(((K - 1) * d,), F32), S((n, d), BF16), S((n,), F32)).compile()
+        S(((K - 1) * d,), F32), S((n, d), BF16), S((n,), F32),
+        _hyper(S((), F32))).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     call = next(line for line in text.splitlines()
@@ -1782,6 +1834,7 @@ def test_a_by_rows_shard_of_a_meshed_fit_takes_the_kernel_where_it_lies(
         S((d,), F32, NamedSharding(mesh4, P())),
         S((n, d), BF16, NamedSharding(mesh4, P(DATA_AXIS, None))),
         S((n,), F32, NamedSharding(mesh4, P(DATA_AXIS))),
+        _hyper(S((), F32, NamedSharding(mesh4, P()))),
     ).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     call = next(line for line in text.splitlines()
@@ -1830,7 +1883,7 @@ def test_the_int8_run_at_the_cells_shape_reads_the_bytes_once_where_they_lie(
                                "_fused_rows_sums")
     cfg = _cfg(step_size=2.0 ** -12, num_iterations=100, reg_param=4.096,
                convergence_tol=0.0, mini_batch_fraction=1.0)
-    args = [S((wd,), F32), S((n, d), I8), S((n,), F32)]
+    args = [S((wd,), F32), S((n, d), I8), S((n,), F32), _hyper(S((), F32))]
     if case == "padded_shard":
         args.append(S((n,), jnp.bool_))
     compiled = jax.jit(make_run(grad, SquaredL2Updater(), cfg)).lower(

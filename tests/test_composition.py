@@ -324,8 +324,8 @@ def test_grid_resident_compressed_one_dispatch_one_program():
     comp = make_compressed_step(LeastSquaresGradient(), SimpleUpdater(),
                                 cfg, 0.25)
 
-    def _step(w, e, i, rv, Xr, yr):
-        return comp(w, e, Xr, yr, i, rv, None)
+    def _step(w, e, i, rv, hyper, Xr, yr):
+        return comp(w, e, Xr, yr, i, rv, hyper, None)
 
     loop = ResidentLoop(_step, cfg, 4, 3, with_extra=True)
     Xd, yd = jnp.asarray(X), jnp.asarray(y)
@@ -334,8 +334,8 @@ def test_grid_resident_compressed_one_dispatch_one_program():
     def run():
         hooks = ResidentBookkeeper(cfg, 4, 3, losses=[], reg_val=0.0,
                                    start_iter=1)
-        return loop.run(jnp.asarray(w0), 0.0, 1, (Xd, yd), hooks,
-                        extra0=ef0)
+        return loop.run(jnp.asarray(w0), 0.0, 1, (cfg.hyper(), Xd, yd),
+                        hooks, extra0=ef0)
 
     run()  # warm the compile
     assert loop.compile_cache_size() == 1

@@ -573,7 +573,7 @@ def test_a_fused_fit_ends_in_one_wait_with_the_runners_own_results(
     finally:
         disable_tracing()
     w_ref, losses_ref, n_ref = opt._runner(with_valid=False)(
-        jnp.asarray(w0), X, y)
+        jnp.asarray(w0), X, y, opt._hyper())
     recorded = int(n_ref)
     assert (recorded < 40) == (tol > 0)
     np.testing.assert_array_equal(np.asarray(w), np.asarray(w_ref))
@@ -676,12 +676,12 @@ def test_lowered_step_carries_the_named_scopes(case):
         X, y = jnp.ones((64, 8), jnp.bfloat16), jnp.ones(64, jnp.float32)
         fn = jax.jit(make_run(LogisticGradient(), SquaredL2Updater(), cfg))
         assert fn.__name__ == "sgd_run"  # part of the compile cache's key
-        found = _scopes_in(fn.lower(w, X, y))
+        found = _scopes_in(fn.lower(w, X, y, cfg.hyper()))
         assert found == STEP_SCOPES | {"sgd.converge", "sgd.prepare"}
     elif case == "bcoo_hinge":
         X, y, _ = sparse_data(64, 8, nnz_per_row=3, kind="svm")
         fn = jax.jit(make_run(HingeGradient(), L1Updater(), cfg))
-        found = _scopes_in(fn.lower(w, X, jnp.asarray(y)))
+        found = _scopes_in(fn.lower(w, X, jnp.asarray(y), cfg.hyper()))
         assert found == STEP_SCOPES | {"sgd.converge"}
     else:
         from tpu_sgd.parallel.data_parallel import dp_step_fn
@@ -691,5 +691,6 @@ def test_lowered_step_carries_the_named_scopes(case):
         fn = dp_step_fn(LeastSquaresGradient(), SimpleUpdater(), cfg,
                         data_mesh(jax.devices()[:4]), with_valid=False)
         found = _scopes_in(fn.lower(w, X, y, jnp.asarray(1, jnp.int32),
-                                    jnp.asarray(0.0, jnp.float32)))
+                                    jnp.asarray(0.0, jnp.float32),
+                                    cfg.hyper()))
         assert found == STEP_SCOPES | {"sgd.allreduce"}

@@ -290,9 +290,9 @@ def test_gramdata_argument_path_matches_plain(rng):
                     mini_batch_fraction=0.25, convergence_tol=0.0,
                     sampling="sliced")
     run = jax.jit(make_run(gram, SimpleUpdater(), cfg))
-    w1, h1, nr1 = run(jnp.zeros((16,)), gram.data, y)
+    w1, h1, nr1 = run(jnp.zeros((16,)), gram.data, y, cfg.hyper())
     run0 = jax.jit(make_run(LeastSquaresGradient(), SimpleUpdater(), cfg))
-    w0, h0, nr0 = run0(jnp.zeros((16,)), X, y)
+    w0, h0, nr0 = run0(jnp.zeros((16,)), X, y, cfg.hyper())
     np.testing.assert_allclose(np.asarray(h1)[:int(nr1)],
                                np.asarray(h0)[:int(nr0)],
                                rtol=5e-4, atol=5e-4)

@@ -488,7 +488,7 @@ def test_a_meshed_run_from_host_arrays_is_the_fit_on_pre_sharded_arrays(
         Xd = Xd.astype(jnp.float32)
     yd = jax.device_put(yp, NamedSharding(mesh, P("data")))
     opt = alg.optimizer
-    args = (jnp.zeros(12, jnp.float32), Xd, yd)
+    args = (jnp.zeros(12, jnp.float32), Xd, yd, opt.config.hyper())
     if n % SHARDS:
         args += (jax.device_put(validp, NamedSharding(mesh, P("data"))),)
     fn = dp_run_fn(opt.gradient, opt.updater, opt.config, mesh,

@@ -267,7 +267,7 @@ def _fit(X, y, K=10, iterations=12):
     g = MultinomialLogisticGradient(K)
     run = jax.jit(gd.make_run(g, SquaredL2Updater(), cfg))
     w0 = np.zeros(g.weight_dim(X.shape[1]), np.float32)
-    return [np.asarray(a) for a in run(w0, X, y)]
+    return [np.asarray(a) for a in run(w0, X, y, cfg.hyper())]
 
 
 def test_the_int8_fit_is_the_bf16_fit_bit_for_bit_on_both_paths(monkeypatch):

@@ -326,9 +326,12 @@ def test_fit_path_spans_round_trip_through_the_profiler(profiler_session,
     assert [d[2]["built"] for d in found["train.dispatch"]] == [1, 0, 1, 1,
                                                                 0]
     # the selection before it says which form the step's kernel is (PR 39:
-    # 0 on a CPU); whether it built its runner is ``built``'s to say
+    # 0 on a CPU) and where ``_runner``'s program came from (no compile
+    # cache directory here: the runner as it was); whether the optimizer
+    # made a new entry for it is ``built``'s to say
     assert [s[2] for s in found["train.select"]] == [
-        {"by_rows": 0, "class_rows": 0, "ahead": 0, "stats": 0}] * 5
+        {"runner": "as_was", "by_rows": 0, "class_rows": 0, "ahead": 0,
+         "stats": 0}] * 5
     assert len(found["fit.finish"]) == 2  # run() alone has a model to make
     # device arrays at the Optimizer boundary: nothing to copy
     assert found["train.h2d"][2][2]["bytes"] == 0
@@ -653,7 +656,7 @@ def test_enabled_obs_resident_driver_pins_one_dispatch_windows_syncs(rng):
     iters, k, c = 64, 4, 2
     o = _opt(iters=iters, k=k, c=c)
     o.optimize_with_history((X, y), w0)  # warm the one compiled program
-    key = ("resident", o.gradient, o.updater, o.config, k, c)
+    key = ("resident", o.gradient, o.updater, o.config.structure(), k, c)
     loop = o._run_cache[key]
     windows = iters // (k * c)
 
@@ -664,7 +667,7 @@ def test_enabled_obs_resident_driver_pins_one_dispatch_windows_syncs(rng):
         hooks = ResidentBookkeeper(o.config, k, c, losses=[], reg_val=0.0,
                                    start_iter=1)
         loop.run(jnp.asarray(w0), 0.0, 1,
-                 (jnp.asarray(X), jnp.asarray(y)), hooks)
+                 (o._hyper(), jnp.asarray(X), jnp.asarray(y)), hooks)
         snap = obs_counters.snapshot()
     finally:
         obs.disable()

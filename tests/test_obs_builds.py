@@ -150,16 +150,21 @@ def test_steady_fits_leave_nothing_and_fire_no_listener_call():
     assert obs.build_roots() == kept and builds._BUILT == []
 
 
-def test_a_new_step_size_builds_again_under_a_second_root():
-    """Today's behaviour (ROADMAP Speed 4(a)): the configuration is a
-    constant of ``sgd_run``, so a changed step size is a new program.  The
-    record states it; it does not mend it."""
+def test_a_new_step_size_builds_nothing():
+    """Mended (ROADMAP Speed 4(a), PR 62): the step size and the regulariser
+    are operands of ``sgd_run`` (``config.Hyper``), so a changed value of
+    either runs the program the first fit built: no second root, no second
+    ``_run_cache`` entry, no listener call."""
     X, y = _data()
     opt = _optimizer()
     _fit(opt, X, y)
-    _fit(opt.set_step_size(0.25), X, y)
-    first, second = obs.build_roots()
-    assert _named(second, "build.compile") and second["start"] > first["start"]
+    kept = obs.build_roots()
+    with _Listeners() as heard:
+        _fit(opt.set_step_size(0.25), X, y)
+        _fit(opt.set_reg_param(0.5), X, y)
+    assert heard.calls == 0
+    assert obs.build_roots() == kept and len(kept) == 1
+    assert len(opt._run_cache) == 1
 
 
 @pytest.fixture

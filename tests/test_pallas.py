@@ -764,7 +764,8 @@ def test_a_fit_with_the_rows_prepared_is_the_fit_without_bit_for_bit(
             if not prepared:
                 m.setattr(gd, "prepare_rows", lambda *a, **k: None)
             run = jax.jit(gd.make_run(g, SquaredL2Updater(), cfg))
-            return [np.asarray(a) for a in run(w0, X, y, valid)]
+            return [np.asarray(a)
+                    for a in run(w0, X, y, cfg.hyper(), valid)]
 
     here = fit(True)
     for a, b in zip(here, fit(False)):

@@ -234,13 +234,13 @@ def test_resident_run_is_one_dispatch(rng):
 
     o = _opt("sliced", iters=32, k=4, c=2)
     o.optimize_with_history((X, y), w0)  # warm the compile
-    key = ("resident", o.gradient, o.updater, o.config, 4, 2)
+    key = ("resident", o.gradient, o.updater, o.config.structure(), 4, 2)
     loop = o._run_cache[key]
     Xd, yd = jnp.asarray(X), jnp.asarray(y)
     hooks = ResidentBookkeeper(o.config, 4, 2, losses=[], reg_val=0.0,
                                start_iter=1)
     with assert_dispatch_count(1):
-        loop.run(jnp.asarray(w0), 0.0, 1, (Xd, yd), hooks)
+        loop.run(jnp.asarray(w0), 0.0, 1, (o._hyper(), Xd, yd), hooks)
     assert len(hooks.losses) == 32 and hooks.windows_fired == 4
 
 
@@ -276,7 +276,7 @@ def test_resident_loop_compiles_one_program(rng):
     w0 = np.zeros(6, np.float32)
     o = _opt("bernoulli", iters=23, k=4, c=2)
     o.optimize_with_history((X, y), w0)
-    key = ("resident", o.gradient, o.updater, o.config, 4, 2)
+    key = ("resident", o.gradient, o.updater, o.config.structure(), 4, 2)
     loop = o._run_cache[key]
     assert loop.compile_cache_size() == 1
     with assert_compile_count(0, of=loop.compile_cache_size):
@@ -302,14 +302,14 @@ def test_resident_warmed_window_no_host_sync(rng):
     def run_counted(iters):
         o = _opt("sliced", iters=iters, k=4, c=2)
         o.optimize_with_history((X, y), w0)  # warm the compile
-        key = ("resident", o.gradient, o.updater, o.config, 4, 2)
+        key = ("resident", o.gradient, o.updater, o.config.structure(), 4, 2)
         loop = o._run_cache[key]
         hooks = ResidentBookkeeper(o.config, 4, 2, losses=[],
                                    reg_val=0.0, start_iter=1)
         windows = iters // (4 * 2)
         with assert_no_host_sync(allow=windows + 3) as counter:
             loop.run(jnp.asarray(w0), 0.0, 1,
-                     (jnp.asarray(X), jnp.asarray(y)), hooks)
+                     (o._hyper(), jnp.asarray(X), jnp.asarray(y)), hooks)
         assert counter["n"] == windows + 3
         assert all(shape == () for shape, _ in counter["shapes"])
         return counter["n"]
@@ -336,7 +336,7 @@ def test_resident_warmed_sync_pin_holds_with_tracing_on(rng):
     iters, windows = 64, 64 // (4 * 2)
     o = _opt("sliced", iters=iters, k=4, c=2)
     o.optimize_with_history((X, y), w0)  # warm the compile
-    key = ("resident", o.gradient, o.updater, o.config, 4, 2)
+    key = ("resident", o.gradient, o.updater, o.config.structure(), 4, 2)
     loop = o._run_cache[key]
 
     class Sink:
@@ -353,7 +353,7 @@ def test_resident_warmed_sync_pin_holds_with_tracing_on(rng):
     try:
         with assert_no_host_sync(allow=windows + 3) as counter:
             loop.run(jnp.asarray(w0), 0.0, 1,
-                     (jnp.asarray(X), jnp.asarray(y)), hooks)
+                     (o._hyper(), jnp.asarray(X), jnp.asarray(y)), hooks)
     finally:
         disable_tracing()
     assert counter["n"] == windows + 3

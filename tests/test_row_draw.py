@@ -233,7 +233,8 @@ def test_a_fit_that_draws_in_the_kernel_is_the_fit_handed_the_mask(
             if not in_kernel:
                 m.setattr(gradients, "counter_draws", lambda: False)
             run = jax.jit(gd.make_run(g, SquaredL2Updater(), cfg))
-            return [np.asarray(a) for a in run(w0, X, y, valid)]
+            return [np.asarray(a)
+                    for a in run(w0, X, y, cfg.hyper(), valid)]
 
     here = fit(True)
     for a, b in zip(here, fit(False)):  # on the CPU both draw the array
@@ -293,7 +294,7 @@ def test_a_meshed_fit_that_draws_in_the_kernel_is_the_fit_handed_the_mask(
             if not in_kernel:
                 m.setattr(gradients, "counter_draws", lambda: False)
             run = dp_run_fn(g, SimpleUpdater(), cfg, mesh, with_valid=False)
-            return [np.asarray(a) for a in run(w0, X, y)]
+            return [np.asarray(a) for a in run(w0, X, y, cfg.hyper())]
 
     for a, b in zip(fit(True), fit(False)):
         np.testing.assert_array_equal(a, b)

@@ -3,7 +3,8 @@
 ``GradientDescent._runner``'s program restores it from
 ``<jax_compilation_cache_dir>/tpu_sgd_runs/<key>`` where it is there, exports
 and stores it where it is not, and runs the RESTORED form either way; anything
-the key cannot hold bypasses the store and trains as before.  Tiny, on the
+the key cannot hold bypasses the store and trains as before; a second
+optimizer of the process finds the program LIVE.  Tiny, on the
 CPU: the restored program against the traced one bit for bit for every step
 family ``_runner`` selects, what the key holds, every bypass, a fresh
 interpreter on a warm store, and what a steady fit pays."""
@@ -42,7 +43,19 @@ def cache_dir(compile_cache):
 def _no_roots():
     builds._ROOTS.clear()
     del builds._BUILT[:]
+    _another_process()
     yield
+
+
+def _another_process():
+    """What another process has of this one's runners: none live."""
+    run_store._LIVE.clear()
+
+
+def _args(opt, w0, X, y, *valid):
+    """``_runner``'s arguments as a fit hands them over: the step size and
+    the regulariser ride as operands behind the labels."""
+    return (w0, X, y, opt._hyper(), *valid)
 
 
 def _rows(n, d, dtype=jnp.float32, classes=2, seed=0):
@@ -63,48 +76,52 @@ def _optimizer(gradient=None, updater=None, **config):
 
 def _masked_vector():
     X, y = _rows(256, 16, jnp.bfloat16)
-    return _optimizer(), (jnp.zeros((16,)), X, y)
+    opt = _optimizer()
+    return opt, _args(opt, jnp.zeros((16,)), X, y)
 
 
 def _windowed():
     X, y = _rows(256, 16, jnp.bfloat16)
-    return _optimizer(sampling="sliced", mini_batch_fraction=0.25), (
-        jnp.zeros((16,)), X, y)
+    opt = _optimizer(sampling="sliced", mini_batch_fraction=0.25)
+    return opt, _args(opt, jnp.zeros((16,)), X, y)
 
 
 def _bound():
     """A stream's micro-batch in an array of a row capacity, its row count
     an operand in ``valid``'s place."""
     X, y = _rows(256, 16, jnp.bfloat16)
-    return _optimizer(), (jnp.zeros((16,)), X, y,
-                          RowCount(jnp.asarray(200, jnp.int32)))
+    opt = _optimizer()
+    return opt, _args(opt, jnp.zeros((16,)), X, y,
+                      RowCount(jnp.asarray(200, jnp.int32)))
 
 
 def _class_feature_major():
     X, y = _rows(256, 24, jnp.bfloat16, classes=4)
-    return _optimizer(tpu_sgd.MultinomialLogisticGradient(4),
-                      mini_batch_fraction=1.0), (jnp.zeros((3 * 24,)), X, y)
+    opt = _optimizer(tpu_sgd.MultinomialLogisticGradient(4),
+                     mini_batch_fraction=1.0)
+    return opt, _args(opt, jnp.zeros((3 * 24,)), X, y)
 
 
 def _class_by_rows():
     X, y = _rows(128, 128, jnp.bfloat16, classes=4)
-    return _optimizer(tpu_sgd.MultinomialLogisticGradient(4),
-                      mini_batch_fraction=1.0), (jnp.zeros((3 * 128,)), X, y)
+    opt = _optimizer(tpu_sgd.MultinomialLogisticGradient(4),
+                     mini_batch_fraction=1.0)
+    return opt, _args(opt, jnp.zeros((3 * 128,)), X, y)
 
 
 def _wide():
     X, y = _rows(64, 2048, jnp.bfloat16)
-    return _optimizer(tpu_sgd.HingeGradient(), tpu_sgd.L1Updater(),
-                      mini_batch_fraction=1.0, step_size=0.05), (
-        jnp.zeros((2048,)), X, y)
+    opt = _optimizer(tpu_sgd.HingeGradient(), tpu_sgd.L1Updater(),
+                     mini_batch_fraction=1.0, step_size=0.05)
+    return opt, _args(opt, jnp.zeros((2048,)), X, y)
 
 
 def _gram_totals():
     X, y = _rows(256, 16)
-    return _optimizer(gram.GramLeastSquaresGradient(),
-                      tpu_sgd.SimpleUpdater(), mini_batch_fraction=1.0,
-                      step_size=0.05), (
-        jnp.zeros((16,)), gram.stats_build(X, y), y)
+    opt = _optimizer(gram.GramLeastSquaresGradient(),
+                     tpu_sgd.SimpleUpdater(), mini_batch_fraction=1.0,
+                     step_size=0.05)
+    return opt, _args(opt, jnp.zeros((16,)), gram.stats_build(X, y), y)
 
 
 FAMILIES = {"masked_vector": _masked_vector, "windowed": _windowed,
@@ -147,13 +164,14 @@ def _call(stored, args):
 def test_the_restored_program_is_the_traced_one_bit_for_bit(cache_dir,
                                                             family):
     opt, args = FAMILIES[family]()
-    with_valid = len(args) == 4
+    with_valid = len(args) == 5
     first = opt._runner(with_valid)
     assert isinstance(first, run_store.StoredRun)
     stored_out, stored = _call(first, args)
     assert stored["hit"] == 0 and "reason" not in stored, stored
     (name,) = os.listdir(cache_dir)
     # another process's view: a new optimizer, nothing traced yet
+    _another_process()
     again = FAMILIES[family]()[0]._runner(with_valid)
     restored_out, restored = _call(again, args)
     assert restored["hit"] == 1 and restored["fun"] == first.name
@@ -173,7 +191,11 @@ def test_the_restored_program_is_the_traced_one_bit_for_bit(cache_dir,
         bytearray(run_store._read(os.path.join(cache_dir, name))))
     fresh = run_store.export(first.fresh, first.name, tree, leaves)
     assert _text(kept) == _text(fresh) and "stablehlo.while" in _text(kept)
-    assert kept.in_avals == fresh.in_avals and kept.fun_name == "sgd_run"
+    # (a weak type does not survive the serialization: the operands' two
+    # scalars come back plain float32, their arithmetic already lowered)
+    assert [(a.shape, a.dtype) for a in kept.in_avals] \
+        == [(a.shape, a.dtype) for a in fresh.in_avals]
+    assert kept.fun_name == "sgd_run"
 
 
 def test_outputs_are_committed_only_where_an_argument_is(cache_dir):
@@ -181,10 +203,11 @@ def test_outputs_are_committed_only_where_an_argument_is(cache_dir):
     one; the store hands them back as the runner would have: committed to
     the device of a committed argument, free otherwise, so that a stream
     feeding its weights back meets ONE program."""
-    opt, (w0, X, y) = _masked_vector()
+    opt, (w0, X, y, hyper) = _masked_vector()
     pinned = jax.device_put(X, jax.devices()[1])
     runner = opt._runner(False)
-    for args, committed in (((w0, X, y), False), ((w0, pinned, y), True)):
+    for args, committed in (((w0, X, y, hyper), False),
+                            ((w0, pinned, y, hyper), True)):
         for _ in range(2):  # the call that stores, then the steady one
             out = runner(*args)
             traced = runner.fresh(*args)
@@ -195,8 +218,8 @@ def test_outputs_are_committed_only_where_an_argument_is(cache_dir):
             assert _same(out, traced)
     assert len(runner._fns) == 2
     # fed back, the free weights are the first call's signature again
-    w, _, _ = runner(w0, X, y)
-    runner(w, X, y)
+    w, _, _ = runner(w0, X, y, hyper)
+    runner(w, X, y, hyper)
     assert len(runner._fns) == 2
 
 
@@ -211,11 +234,12 @@ def test_the_mesh_runner_goes_through_the_store(cache_dir):
                           if s["name"] == RESTORE]
 
     _, (w_stored, l_stored), (stored,) = fit()
+    _another_process()
     opt, (w, losses), (restored,) = fit()
     assert (stored["hit"], restored["hit"]) == (0, 1)
     (runner,) = opt._run_cache.values()
     placed = opt._place(X, y)
-    traced = runner.fresh(jnp.asarray(w0), *placed[:2])
+    traced = runner.fresh(jnp.asarray(w0), *placed[:2], opt._hyper())
     assert _same((w_stored, w), (traced[0], traced[0]))
     assert _same((l_stored, losses), (np.asarray(traced[1]),) * 2)
     assert len(os.listdir(cache_dir)) == 1
@@ -243,8 +267,8 @@ def test_the_cold_and_the_warm_process_hand_xla_the_same_module(cache_dir):
 
 def _key(opt, args, with_valid=False, mesh=None):
     leaves, tree = jax.tree_util.tree_flatten(args)
-    plugins = tuple(run_store.plugin_state(p)
-                    for p in (opt.gradient, opt.updater, opt.config))
+    plugins = tuple(run_store.plugin_state(p) for p in (
+        opt.gradient, opt.updater, opt.config.structure()))
     assert None not in plugins
     return run_store.key_of(plugins, mesh, with_valid, tree, leaves)
 
@@ -259,11 +283,16 @@ def test_every_field_of_the_config_has_another_value_here():
 
 
 @pytest.mark.parametrize("field", OTHER_CONFIG)
-def test_the_key_changes_with_each_field_of_the_config(field):
+def test_the_key_changes_with_each_field_of_the_configs_structure(field):
+    """And with no operand's value: the step size and the regulariser are
+    two of the program's arguments (``config.Hyper``)."""
+    from tpu_sgd.config import Hyper
+
     opt, args = _masked_vector()
     other = _optimizer(**{field: OTHER_CONFIG[field]})
     assert getattr(other.config, field) != getattr(opt.config, field)
-    assert _key(other, args) != _key(opt, args)
+    assert (_key(other, args) == _key(opt, args)) \
+        == (field in Hyper._fields)
     assert _key(_masked_vector()[0], args) == _key(opt, args)
 
 
@@ -291,20 +320,19 @@ OTHERS = {
     "the mesh's shape": lambda o, a: (
         o, a, False, tpu_sgd.data_mesh(jax.devices()[:2])),
     "a leaf's shape": lambda o, a: (
-        o, (a[0], a[1][:128], a[2][:128]), False, None),
+        o, (a[0], a[1][:128], a[2][:128], a[3]), False, None),
     "a leaf's dtype": lambda o, a: (
-        o, (a[0], a[1].astype(jnp.float32), a[2]), False, None),
+        o, (a[0], a[1].astype(jnp.float32), a[2], a[3]), False, None),
     "a leaf's weak type": lambda o, a: (
-        o, (jax.lax.full((16,), 0.0), a[1], a[2]), False, None),
+        o, (jax.lax.full((16,), 0.0), a[1], a[2], a[3]), False, None),
     "a leaf's device": lambda o, a: (
-        o, (a[0], _other_device(a[1]), a[2]), False, None),
+        o, (a[0], _other_device(a[1]), a[2], a[3]), False, None),
     "a leaf's sharding": lambda o, a: (
-        o, (a[0], _sharded(a[1]), a[2]), False, None),
+        o, (a[0], _sharded(a[1]), a[2], a[3]), False, None),
     "the tree's structure": lambda o, a: (
-        o, (a[0], a[1], a[2], jnp.ones((256,), bool)), False, None),
+        o, (*a, jnp.ones((256,), bool)), False, None),
     "a row count for a mask": lambda o, a: (
-        o, (a[0], a[1], a[2], RowCount(jnp.asarray(256, jnp.int32))), False,
-        None),
+        o, (*a, RowCount(jnp.asarray(256, jnp.int32))), False, None),
 }
 
 
@@ -422,6 +450,7 @@ def _a_truncated_file(cache_dir):
         whole = f.read()
     with open(path, "wb") as f:
         f.write(whole[:len(whole) // 2])
+    _another_process()
     return _optimizer(), "a stored file that does not read back"
 
 
@@ -458,8 +487,8 @@ def test_a_bypass_trains_as_before_and_says_why(cache_dir, case):
     assert left["hit"] is None and left["reason"] == reason, left
     assert _same(out, expected)
     # the runner as it was: the parent's jitted function itself
-    (fn,) = runner._fns.values()
-    assert fn is runner.fresh
+    ((fn, origin),) = runner._fns.values()
+    assert fn is runner.fresh and origin == "as_was"
     if case == "a_truncated_file":  # gone, so the next first fit stores anew
         assert os.listdir(cache_dir) == []
         _, healed = _call(_optimizer()._runner(False), args)
@@ -473,13 +502,13 @@ def test_a_runner_jax_export_refuses_runs_as_it_is(cache_dir):
 
     seen = []
 
-    def observed(w, X, y):
+    def observed(w, X, y, hyper):
         io_callback(lambda v: seen.append(float(v)), None, w.sum(),
                     ordered=True)
         return w + X.sum(0).astype(w.dtype), y[:3], jnp.asarray(3)
 
     opt, args = _masked_vector()
-    runner = run_store.StoredRun(jax.jit(observed), opt.gradient,
+    runner = run_store.StoredRun(lambda: jax.jit(observed), opt.gradient,
                                  opt.updater, opt.config, None, False)
     out, left = _call(runner, args)
     assert left["hit"] is None \
@@ -634,16 +663,17 @@ def test_a_steady_fit_makes_no_store_call_and_no_key(cache_dir, monkeypatch):
     assert calls == [] and obs.build_roots() == kept
     # arguments of another shape are another first call
     opt.optimize_with_history((X[:128], y[:128]), w0)
-    assert calls[0] == "folder" and calls[-1] == "restored"
+    assert calls[0] == "plugin_state" and calls[-1] == "restored"
 
 
 @pytest.mark.parametrize("family", ["masked_vector", "bound", "gram_totals"])
 def test_a_steady_call_costs_microseconds(family):
     opt, args = FAMILIES[family]()
-    runner = opt._runner(len(args) == 4)
+    runner = opt._runner(len(args) == 5)
     runner(*args)
     (signature,) = runner._fns
-    runner._fns[signature] = lambda *a: None  # the wrapper's own cost alone
+    # the wrapper's own cost alone
+    runner._fns[signature] = (lambda *a: None, "as_was")
     best = min(_per_call(runner, args) for _ in range(5))
     # 3 to 5 microseconds (15 with a GramData's seven leaves) on the
     # sandbox's CPU; the bound leaves room for a loaded test machine

@@ -421,14 +421,14 @@ def test_superstep_builder_compiles_one_program(rng):
         Xs, Ys, Vs = stack_superchunk([p[0] for p in full],
                                       [p[1] for p in full],
                                       [p[2] for p in full])
-        w, ys = fused(w, jnp.asarray(0.0, jnp.float32),
+        w, ys = fused(w, jnp.asarray(0.0, jnp.float32), cfg.hyper(),
                       jnp.asarray(1, jnp.int32), Xs, Ys, Vs)
         # tail superstep: 2 real batches padded to K=4 — same shapes,
         # same program
         Xs, Ys, Vs = stack_superchunk([p[0] for p in full[:2]],
                                       [p[1] for p in full[:2]],
                                       [p[2] for p in full[:2]], k=4)
-        w, ys = fused(w, jnp.asarray(0.0, jnp.float32),
+        w, ys = fused(w, jnp.asarray(0.0, jnp.float32), cfg.hyper(),
                       jnp.asarray(5, jnp.int32), Xs, Ys, Vs)
         jax.block_until_ready(w)
 
@@ -444,7 +444,8 @@ def test_stepwise_fused_run_compiles_one_program(rng):
          .set_convergence_tol(0.0).set_seed(3)
          .set_listener(SGDListener()).set_superstep(4))
     o.optimize_with_history((X, y), np.zeros(6, np.float32))
-    key = ("superstep", o.gradient, o.updater, o.config, 4, None, False)
+    key = ("superstep", o.gradient, o.updater, o.config.structure(), 4,
+           None, False)
     fn = o._run_cache[key]
     assert fn._cache_size() == 1
 

@@ -15,6 +15,18 @@ the class constructor; see SURVEY.md §2 #2, §5.6).
 from __future__ import annotations
 
 import dataclasses
+import functools
+from typing import Any, NamedTuple
+
+
+class Hyper(NamedTuple):
+    """The two hyper-parameters every compiled SGD program takes as
+    OPERANDS (one small pytree, two scalars): a new value of either runs the
+    program that is already built.  Everything else of :class:`SGDConfig` is
+    the program's STRUCTURE (:meth:`SGDConfig.structure`)."""
+
+    step_size: Any
+    reg_param: Any
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,6 +110,24 @@ class SGDConfig:
 
     def replace(self, **kwargs) -> "SGDConfig":
         return dataclasses.replace(self, **kwargs)
+
+    def hyper(self) -> Hyper:
+        """The operands' values, as the Python floats the updaters were
+        always handed: a jitted program takes them as weakly typed scalars,
+        so the update's arithmetic is what a closed-over float gave."""
+        return Hyper(*(float(getattr(self, name)) for name in Hyper._fields))
+
+    def structure(self) -> "SGDConfig":
+        """This config with every operand at its default: equal for two
+        configs that one compiled program serves."""
+        return _structure_of(self)
+
+
+@functools.lru_cache(maxsize=64)
+def _structure_of(config: SGDConfig) -> SGDConfig:
+    # asked for on every fit (the memo keys): one dictionary lookup
+    return dataclasses.replace(config, **{
+        name: getattr(SGDConfig, name) for name in Hyper._fields})
 
 
 @dataclasses.dataclass(frozen=True)

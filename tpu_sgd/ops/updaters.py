@@ -10,6 +10,18 @@ later — see SURVEY.md §5.5 loss-history contract).
 All updaters are pure jnp functions, safe under ``jit`` and inside
 ``shard_map`` (they run replicated on every core; deterministic replication
 replaces the reference's TorrentBroadcast, SURVEY.md §5.8).
+
+``step_size`` and ``reg_param`` may be TRACED scalars, as ``iter_num`` always
+was: every compiled program of the optimizer (``make_step`` and what is built
+from it) takes the two as operands (``config.Hyper``), so that one program
+serves every step size and regulariser of a tuning grid, and hands ``compute``
+its tracers.  ``compute`` therefore does ``jax.numpy`` arithmetic on them and
+nothing else: no ``float(step_size)``, no ``if reg_param > 0`` (``jnp.where``),
+no numpy call.  An updater that needs a concrete value fails at trace with a
+``TypeError`` that names this contract (``gradient_descent._update``); there
+is no option that closes a program over the values instead.  Called outside a
+program (a test, the observed driver's probe of the initial ``reg_val``) the
+same ``compute`` is handed Python floats and behaves as it always did.
 """
 
 from __future__ import annotations
@@ -29,9 +41,9 @@ class Updater:
         self,
         weights_old: Array,
         gradient: Array,
-        step_size: float,
+        step_size,  # a float or a traced scalar
         iter_num: Array,
-        reg_param: float,
+        reg_param,  # a float or a traced scalar
     ) -> Tuple[Array, Array]:
         raise NotImplementedError
 

@@ -782,6 +782,31 @@ def _make_local_sums(gradient, cfg, key, axis_name, model_axis_name,
     return local_sums
 
 
+#: what JAX raises where a traced value is asked for a concrete one
+_NEEDS_CONCRETE = (jax.errors.ConcretizationTypeError,
+                   jax.errors.TracerArrayConversionError,
+                   jax.errors.TracerIntegerConversionError)
+
+
+def _update(updater, weights, gradient, step_size, i, reg_param):
+    """``updater.compute`` as every compiled program calls it: with
+    ``step_size`` and ``reg_param`` the program's operands, traced scalars.
+    An updater that needs either as a concrete number (``float(...)``, an
+    ``if`` on it, a numpy call) fails here, at trace, by the contract's
+    name (``ops/updaters.py``), never by training at a stale value."""
+    try:
+        return updater.compute(weights, gradient, step_size, i, reg_param)
+    except _NEEDS_CONCRETE as e:
+        raise TypeError(
+            f"{type(updater).__name__}.compute asked a traced value for a "
+            "concrete one.  The Updater contract (tpu_sgd/ops/updaters.py): "
+            "step_size and reg_param, like iter_num, may be TRACED scalars "
+            "-- they are operands of the compiled program, so that one "
+            "program serves every step size and regulariser -- and compute "
+            "must stay jax.numpy arithmetic on them (jnp.where, not `if`; "
+            "no float(), no numpy).") from e
+
+
 def make_step(
     gradient: Gradient,
     updater: Updater,
@@ -791,13 +816,18 @@ def make_step(
 ):
     """Build one SGD iteration as a pure function.
 
-    ``step(weights, X, y, i, reg_val, valid) ->
+    ``step(weights, X, y, i, reg_val, hyper, valid) ->
     (new_weights, loss_i, new_reg_val, count)`` — the unit the streaming mode
     and the fused driver both build on.  ``loss_i`` already includes the
     previous iteration's ``reg_val`` per the reference's loss-history contract.
-    A seventh argument, ``rows``, is what :func:`prepare_rows` made of the
-    same ``X``, ``y`` and ``valid`` in front of the caller's loop (None: the
-    step lays its kernel's row operands out itself, every call).
+    ``hyper`` is the step size and the regulariser (``config.Hyper``, two
+    scalars): OPERANDS of the step and of every program built from it, never
+    read from ``config`` here, so one compiled program serves every pair
+    (``SGDConfig`` says which fields are the program's structure).  This is
+    the step's one signature.  An eighth argument, ``rows``, is what
+    :func:`prepare_rows` made of the same ``X``, ``y`` and ``valid`` in front
+    of the caller's loop (None: the step lays its kernel's row operands out
+    itself, every call).
 
     ``axis_name`` shards the example axis (data parallelism — the reference's
     only strategy); ``model_axis_name`` additionally shards the FEATURE axis
@@ -811,7 +841,7 @@ def make_step(
     local_sums = _make_local_sums(gradient, cfg, key, axis_name,
                                   model_axis_name)
 
-    def step(weights, X, y, i, reg_val, valid=None, rows=None):
+    def step(weights, X, y, i, reg_val, hyper, valid=None, rows=None):
         g, l, c = local_sums(weights, X, y, i, valid, rows)
         if axis_name is not None:
             with jax.named_scope("sgd.allreduce"):
@@ -820,9 +850,9 @@ def make_step(
             has_batch = c > 0
             safe_c = jnp.maximum(c, 1.0)
             loss_i = l / safe_c + reg_val
-            new_w, new_reg = updater.compute(
-                weights, g / safe_c, cfg.step_size, i, cfg.reg_param
-            )
+            new_w, new_reg = _update(
+                updater, weights, g / safe_c, hyper.step_size, i,
+                hyper.reg_param)
             if model_axis_name is not None:
                 # reg value is a sum over features -> combine the local
                 # blocks
@@ -845,11 +875,13 @@ def make_run(
 ):
     """Build the full optimization loop as one traceable function.
 
-    ``run(initial_weights, X, y, valid) -> (weights, loss_history, n_recorded)``
-    where ``loss_history`` has static length ``config.num_iterations`` padded
-    with NaN beyond ``n_recorded`` (the while_loop may exit early on the
-    convergence tolerance).  Runs unchanged inside ``shard_map`` when
-    ``axis_name`` (and optionally ``model_axis_name``) is given.
+    ``run(initial_weights, X, y, hyper, valid) -> (weights, loss_history,
+    n_recorded)`` where ``loss_history`` has static length
+    ``config.num_iterations`` padded with NaN beyond ``n_recorded`` (the
+    while_loop may exit early on the convergence tolerance) and ``hyper`` is
+    :func:`make_step`'s (the step size and the regulariser, operands).  Runs
+    unchanged inside ``shard_map`` when ``axis_name`` (and optionally
+    ``model_axis_name``) is given.
     """
     cfg = config
     check_conv = cfg.convergence_tol > 0.0
@@ -867,13 +899,13 @@ def make_run(
     # leaves metadata out of it), so under the old name a cache warmed
     # before the scopes existed hands back an executable without them —
     # and the profiler names operations from the executable it runs.
-    def sgd_run(initial_weights, X, y, valid=None):
+    def sgd_run(initial_weights, X, y, hyper, valid=None):
         w0 = initial_weights
         # Initial regVal from a zero-gradient probe update, exactly as the
         # reference initializes it before the loop (SURVEY.md §5.5).
-        _, reg_val0 = updater.compute(
-            w0, jnp.zeros_like(w0), 0.0, jnp.asarray(1, jnp.int32), cfg.reg_param
-        )
+        _, reg_val0 = _update(
+            updater, w0, jnp.zeros_like(w0), 0.0, jnp.asarray(1, jnp.int32),
+            hyper.reg_param)
         if model_axis_name is not None:
             # the reg value sums over FEATURES, and each model shard holds
             # only its block of w0 — combine like make_step's new_reg, or
@@ -896,8 +928,8 @@ def make_run(
 
         def body(carry):
             i, w, reg_val, losses, n_rec, _ = carry
-            new_w, loss_i, new_reg, c = step(w, X, y, i, reg_val, valid,
-                                             rows)
+            new_w, loss_i, new_reg, c = step(w, X, y, i, reg_val, hyper,
+                                             valid, rows)
             has_batch = c > 0
             losses = jnp.where(
                 has_batch, losses.at[n_rec].set(loss_i.astype(jnp.float32)), losses
@@ -1076,8 +1108,10 @@ def make_superstep(
     """Fuse K consecutive SGD iterations over PER-STEP batches into ONE
     compiled program (``lax.scan`` over the superchunk's leading axis).
 
-    ``superstep(weights, reg_val, i0, Xs, ys, valids) ->
-    (carry_weights, ys_out)``: ``Xs``/``ys``/``valids`` stack K
+    ``superstep(weights, reg_val, hyper, i0, Xs, ys, valids) ->
+    (carry_weights, ys_out)`` (``hyper``: :func:`make_step`'s operands,
+    here as in every superstep behind ``reg_val``):
+    ``Xs``/``ys``/``valids`` stack K
     per-iteration batches on axis 0 — the host-assembled *superchunk*
     (``tpu_sgd.io.stack_superchunk``) that replaces K ``device_put`` +
     dispatch round-trips with one of each.  The scan body is EXACTLY
@@ -1113,13 +1147,13 @@ def make_superstep(
     """
     step = make_step(gradient, updater, config, axis_name, model_axis_name)
 
-    def superstep(weights, reg_val, i0, Xs, ys, valids):
+    def superstep(weights, reg_val, hyper, i0, Xs, ys, valids):
         idx = i0 + jnp.arange(Xs.shape[0], dtype=jnp.int32)
 
         def body(carry, xs):
             w, rv = carry
             i, Xb, yb, vb = xs
-            new_w, loss_i, new_rv, c = step(w, Xb, yb, i, rv, vb)
+            new_w, loss_i, new_rv, c = step(w, Xb, yb, i, rv, hyper, vb)
             # per-step norms ride the ys so the host-side convergence
             # check stays EXACTLY the legacy per-iteration rule
             return (new_w, new_rv), pack_step_ys(w, new_w, loss_i,
@@ -1156,12 +1190,12 @@ def make_shared_batch_superstep(
     step = make_step(gradient, updater, config, axis_name, model_axis_name)
     K = int(k)
 
-    def superstep(weights, reg_val, i0, X, y, valid=None):
+    def superstep(weights, reg_val, hyper, i0, X, y, valid=None):
         idx = i0 + jnp.arange(K, dtype=jnp.int32)
 
         def body(carry, i):
             w, rv = carry
-            new_w, loss_i, new_rv, c = step(w, X, y, i, rv, valid)
+            new_w, loss_i, new_rv, c = step(w, X, y, i, rv, hyper, valid)
             return (new_w, new_rv), pack_step_ys(w, new_w, loss_i,
                                                  new_rv, c)
 
@@ -1183,8 +1217,8 @@ def make_resident_window_superstep(
     the transferred superchunk batch, selected per step by a flag the
     host packs alongside the superchunk.
 
-    ``superstep(weights, reg_val, i0, Xres, yres, starts, flags, Xs,
-    ys, valids) -> (carry_weights, ys_out)`` with the same ys contract
+    ``superstep(weights, reg_val, hyper, i0, Xres, yres, starts, flags,
+    Xs, ys, valids) -> (carry_weights, ys_out)`` with the same ys contract
     as :func:`make_superstep`.  ``starts``/``flags`` are ``(K,)``
     per-step window starts and residency flags; resident steps ride
     zero rows in ``Xs`` (the fixed superchunk shape is the price of
@@ -1200,7 +1234,7 @@ def make_resident_window_superstep(
     step = make_step(gradient, updater, config)
     m = int(window_rows)
 
-    def superstep(weights, reg_val, i0, Xres, yres, starts, flags,
+    def superstep(weights, reg_val, hyper, i0, Xres, yres, starts, flags,
                   Xs, ys, valids):
         idx = i0 + jnp.arange(Xs.shape[0], dtype=jnp.int32)
 
@@ -1212,7 +1246,7 @@ def make_resident_window_superstep(
                 lambda: (jax.lax.dynamic_slice_in_dim(Xres, s0, m, 0),
                          jax.lax.dynamic_slice_in_dim(yres, s0, m, 0)),
                 lambda: (Xb, yb))
-            new_w, loss_i, new_rv, c = step(w, Xw, yw, i, rv, vb)
+            new_w, loss_i, new_rv, c = step(w, Xw, yw, i, rv, hyper, vb)
             return (new_w, new_rv), pack_step_ys(w, new_w, loss_i,
                                                  new_rv, c)
 
@@ -1234,8 +1268,9 @@ def make_compressed_step(
     error feedback (``wire_compress="topk:<frac>"``; README "Compressed
     wire", SparCML arXiv:1802.08021).
 
-    ``step(weights, ef, X, y, i, reg_val, valid) -> (new_w, new_ef,
-    loss_i, new_reg_val, count)``.  Sampling and the local batch sums
+    ``step(weights, ef, X, y, i, reg_val, hyper, valid) -> (new_w, new_ef,
+    loss_i, new_reg_val, count)`` (``hyper``: :func:`make_step`'s
+    operands, in the same place).  Sampling and the local batch sums
     are EXACTLY :func:`make_step`'s (one shared ``_make_local_sums``);
     what changes is the combine: each shard folds its normalized
     gradient contribution into a persistent per-shard error-feedback
@@ -1271,7 +1306,7 @@ def make_compressed_step(
     frac = float(topk_frac)
     local_sums = _make_local_sums(gradient, cfg, key, axis_name, None)
 
-    def step(weights, ef, X, y, i, reg_val, valid=None):
+    def step(weights, ef, X, y, i, reg_val, hyper, valid=None):
         g, l, c = local_sums(weights, X, y, i, valid)
         if axis_name is not None:
             l, c = jax.lax.psum((l, c), axis_name)
@@ -1293,10 +1328,9 @@ def make_compressed_step(
                 idx_all.reshape(-1)].add(vals_all.reshape(-1))
         else:
             ghat = jnp.zeros((dim,), acc.dtype).at[idx].add(vals)
-        new_w, new_reg = updater.compute(
-            weights, ghat.astype(weights.dtype), cfg.step_size, i,
-            cfg.reg_param
-        )
+        new_w, new_reg = _update(
+            updater, weights, ghat.astype(weights.dtype), hyper.step_size,
+            i, hyper.reg_param)
         # empty sampled batch: skip the update AND keep the accumulator
         # (the extracted mass must not vanish on a skipped step)
         new_w = jnp.where(has_batch, new_w, weights)
@@ -1320,7 +1354,7 @@ def make_compressed_superstep(
     seventh leaf — checkpoints taken mid-superstep need iteration-exact
     EF state just as they need iteration-exact weights.
 
-    ``superstep(weights, ef, reg_val, i0, Xs, ys, valids) ->
+    ``superstep(weights, ef, reg_val, hyper, i0, Xs, ys, valids) ->
     (carry_weights, carry_ef, ys_out)`` with ``ys_out = (*pack_step_ys,
     efs)``.  Same one-program / tail-padding contract as
     :func:`make_superstep` (a padded no-op step passes ``ef`` through
@@ -1328,14 +1362,14 @@ def make_compressed_superstep(
     step = make_compressed_step(gradient, updater, config, topk_frac,
                                 axis_name)
 
-    def superstep(weights, ef, reg_val, i0, Xs, ys, valids):
+    def superstep(weights, ef, reg_val, hyper, i0, Xs, ys, valids):
         idx = i0 + jnp.arange(Xs.shape[0], dtype=jnp.int32)
 
         def body(carry, xs):
             w, e, rv = carry
             i, Xb, yb, vb = xs
             new_w, new_e, loss_i, new_rv, c = step(w, e, Xb, yb, i, rv,
-                                                   vb)
+                                                   hyper, vb)
             return (new_w, new_e, new_rv), pack_step_ys(
                 w, new_w, loss_i, new_rv, c) + (new_e,)
 
@@ -1362,13 +1396,13 @@ def make_compressed_shared_superstep(
                                 axis_name)
     K = int(k)
 
-    def superstep(weights, ef, reg_val, i0, X, y, valid=None):
+    def superstep(weights, ef, reg_val, hyper, i0, X, y, valid=None):
         idx = i0 + jnp.arange(K, dtype=jnp.int32)
 
         def body(carry, i):
             w, e, rv = carry
             new_w, new_e, loss_i, new_rv, c = step(w, e, X, y, i, rv,
-                                                   valid)
+                                                   hyper, valid)
             return (new_w, new_e, new_rv), pack_step_ys(
                 w, new_w, loss_i, new_rv, c) + (new_e,)
 
@@ -1574,6 +1608,8 @@ class GradientDescent(Optimizer):
         self._streamed_gram_dp_entry = None
         self._loss_history = None
         self._run_cache = {}
+        #: ``_hyper``'s last answer and what it was made from
+        self._hyper_made = None
 
     # -- fluent config (returns self, like the reference's setters) --------
     def set_gradient(self, g: Gradient):
@@ -2320,9 +2356,13 @@ class GradientDescent(Optimizer):
         each route names its compiled runner and its arguments, ONE call in
         ``train.dispatch`` runs them; ``built`` where the runner is a new
         ``_run_cache`` entry, which traces, lowers and compiles inside that
-        call.  Sets ``train.run``'s attributes (and ``by_rows``,
-        ``class_rows`` and ``ahead`` on ``train.select`` too) where the
-        spans keep them."""
+        call.  ``_runner``'s program is resolved HERE for its arguments
+        (``StoredRun.resolve``: this optimizer's own from the second fit on,
+        else one the process already runs, else the store's) and
+        ``train.select`` says where it came from (``runner``: ``live``,
+        ``restored``, ``stored``, ``as_was``).  Sets ``train.run``'s
+        attributes (and ``by_rows``, ``class_rows`` and ``ahead`` on
+        ``train.select`` too) where the spans keep them."""
         from tpu_sgd.ops.gram import GramData
 
         cached = len(self._run_cache)
@@ -2338,15 +2378,16 @@ class GradientDescent(Optimizer):
 
             data, idx, yd, valid, rows_local, d = shard_bcoo(self.mesh, X, y)
             with_valid = valid is not None
-            key = ("sparse_run", self.gradient, self.updater, self.config,
+            key = ("sparse_run", self.gradient, self.updater,
+                   self.config.structure(),
                    self.mesh, rows_local, d, with_valid)
             fn = self._run_cache.get(key)
             if fn is None:
                 fn = sparse_dp_run_fn(self.gradient, self.updater,
-                                      self.config, self.mesh, rows_local, d,
-                                      with_valid)
+                                      self.config.structure(), self.mesh,
+                                      rows_local, d, with_valid)
                 self._run_cache[key] = fn
-            path, args = "sparse_mesh", (w0, data, idx, yd)
+            path, args = "sparse_mesh", (w0, data, idx, yd, self._hyper())
             if with_valid:
                 args += (valid,)
         elif self.mesh is not None and placed is None:
@@ -2367,28 +2408,38 @@ class GradientDescent(Optimizer):
             stats = self._maybe_gram_dp(X, y, Xd, yd, valid)
             if stats is not None:
                 stats_leaves, block_rows = stats
-                key = ("gram_dp_run", self.updater, self.config,
-                       self.mesh, block_rows, self.gram_aligned)
+                key = ("gram_dp_run", self.updater,
+                       self.config.structure(), self.mesh, block_rows,
+                       self.gram_aligned)
                 fn = self._run_cache.get(key)
                 if fn is None:
                     from tpu_sgd.parallel.gram_parallel import (
                         dp_gram_run_fn,
                     )
 
-                    fn = dp_gram_run_fn(self.updater, self.config,
+                    fn = dp_gram_run_fn(self.updater,
+                                        self.config.structure(),
                                         self.mesh, block_rows,
                                         aligned=self.gram_aligned)
                     self._run_cache[key] = fn
-                args = (w0, Xd, yd, *stats_leaves)
+                args = (w0, Xd, yd, self._hyper(), *stats_leaves)
             else:
                 fn, runner = self._runner(with_valid=valid is not None), True
-                args = (w0, Xd, yd) if valid is None else (w0, Xd, yd, valid)
+                args = (w0, Xd, yd, self._hyper())
+                if valid is not None:
+                    args += (valid,)
             path = "mesh"
         else:
             # ``valid`` on one device: a stream's row count (``RowCount``)
             fn, runner = self._runner(with_valid=valid is not None), True
             path = "gram" if isinstance(X, GramData) else "fused"
-            args = (w0, X, y) if valid is None else (w0, X, y, valid)
+            args = (w0, X, y, self._hyper())
+            if valid is not None:
+                args += (valid,)
+        call = fn  # what ``train.dispatch`` calls with ``args``
+        if runner:
+            call, origin = fn.resolve(*args)
+            select_span.set(runner=origin)
         if run_span.live:
             # (labels_prepared, row_tile, feature_blocks, mask_in_kernel,
             # by_rows, class_rows, ahead, row_item_bytes, operand):
@@ -2406,9 +2457,9 @@ class GradientDescent(Optimizer):
                 row_item_bytes=kernel[7], operand=kernel[8], stats=stats)
             select_span.set(by_rows=kernel[4], class_rows=kernel[5],
                             ahead=kernel[6], stats=stats)
-        return fn, args, len(self._run_cache) > cached
+        return call, args, len(self._run_cache) > cached
 
-    def _step_kernel(self, w0, X, y, valid=None):
+    def _step_kernel(self, w0, X, y, hyper=None, valid=None):  # as ``args``
         """``train.run``'s ``(labels_prepared, row_tile, feature_blocks,
         mask_in_kernel, by_rows, class_rows, ahead, row_item_bytes,
         operand)`` for the fit
@@ -2568,14 +2619,15 @@ class GradientDescent(Optimizer):
         dtype_name = str(np.dtype(Xh.dtype)
                          if np.issubdtype(Xh.dtype, np.inexact)
                          else np.dtype(np.float32))
-        key = ("virtual_gram_dp_run", self.updater, self.config, self.mesh,
-               B, n_used, d, dtype_name)
+        key = ("virtual_gram_dp_run", self.updater,
+               self.config.structure(), self.mesh, B, n_used, d, dtype_name)
         fn = self._run_cache.get(key)
         if fn is None:
-            fn = dp_virtual_gram_run_fn(self.updater, self.config,
+            fn = dp_virtual_gram_run_fn(self.updater,
+                                        self.config.structure(),
                                         self.mesh, B, n_used, d, dtype_name)
             self._run_cache[key] = fn
-        w, losses, n_rec = fn(w0, yd, *stats)
+        w, losses, n_rec = fn(w0, yd, self._hyper(), *stats)
         n_rec = int(n_rec)
         self._loss_history = np.asarray(losses)[:n_rec]
         if self.check_numerics:
@@ -2806,6 +2858,7 @@ class GradientDescent(Optimizer):
                 X, y, valid = self._place(X, y, valid)
         step = self._stepper(with_valid=valid is not None,
                              sparse_shape=sparse_shape)
+        hyper = self._hyper()
 
         # regVal probe init (same as the fused path)
         _, reg_val = self.updater.compute(
@@ -2902,7 +2955,8 @@ class GradientDescent(Optimizer):
                 check_numerics=self.check_numerics)
             if start_iter <= cfg.num_iterations:
                 w_np, converged_early = loop.run(
-                    jnp.asarray(w0), reg_val, start_iter, (X, y), hooks)
+                    jnp.asarray(w0), reg_val, start_iter, (hyper, X, y),
+                    hooks)
                 w = jnp.asarray(w_np)
                 reg_val = hooks.reg_val
         elif fused_k > 1:
@@ -2933,12 +2987,12 @@ class GradientDescent(Optimizer):
                 with span("train.superstep", i0=i0, steps=steps):
                     if valid is not None:
                         w_dev, ys = fused(
-                            w, jnp.asarray(reg_val, jnp.float32),
+                            w, jnp.asarray(reg_val, jnp.float32), hyper,
                             jnp.asarray(i0, jnp.int32), X, y, valid,
                         )
                     else:
                         w_dev, ys = fused(
-                            w, jnp.asarray(reg_val, jnp.float32),
+                            w, jnp.asarray(reg_val, jnp.float32), hyper,
                             jnp.asarray(i0, jnp.int32), X, y,
                         )
                     ys_host = tuple(np.asarray(a) for a in ys)  # blocks
@@ -2985,12 +3039,12 @@ class GradientDescent(Optimizer):
                 if valid is not None:
                     new_w, loss_i, new_reg, c = step(
                         w, X, y, jnp.asarray(i, jnp.int32),
-                        jnp.asarray(reg_val), valid
+                        jnp.asarray(reg_val), hyper, valid
                     )
                 else:
                     new_w, loss_i, new_reg, c = step(
                         w, X, y, jnp.asarray(i, jnp.int32),
-                        jnp.asarray(reg_val)
+                        jnp.asarray(reg_val), hyper
                     )
                 # the observed stepwise driver's host hop IS the
                 # contract: per-iteration listener scalars and
@@ -3072,21 +3126,22 @@ class GradientDescent(Optimizer):
         (including the tail) reuses the one compiled scan program.
         Single device runs the plain scan; a 1-D data mesh runs the
         same scan under shard_map (``dp_shared_superstep_fn``)."""
-        key = ("superstep", self.gradient, self.updater, self.config,
-               int(k), self.mesh, with_valid)
+        key = ("superstep", self.gradient, self.updater,
+               self.config.structure(), int(k), self.mesh, with_valid)
         fn = self._run_cache.get(key)
         if fn is None:
             if self.mesh is None:
                 fn = jax.jit(make_shared_batch_superstep(
-                    self.gradient, self.updater, self.config, int(k)))
+                    self.gradient, self.updater, self.config.structure(),
+                    int(k)))
             else:
                 from tpu_sgd.parallel.data_parallel import (
                     dp_shared_superstep_fn,
                 )
 
                 fn = dp_shared_superstep_fn(
-                    self.gradient, self.updater, self.config, int(k),
-                    self.mesh, with_valid)
+                    self.gradient, self.updater, self.config.structure(),
+                    int(k), self.mesh, with_valid)
             self._run_cache[key] = fn
         return fn
 
@@ -3095,16 +3150,18 @@ class GradientDescent(Optimizer):
         (``set_residency``; ``optimize/resident_driver.py``) — one
         compiled while_loop per (plugin pair, config, K, C); repeated
         runs and resumes re-dispatch the same program."""
-        key = ("resident", self.gradient, self.updater, self.config,
-               int(k), int(cadence))
+        key = ("resident", self.gradient, self.updater,
+               self.config.structure(), int(k), int(cadence))
         loop = self._run_cache.get(key)
         if loop is None:
             from tpu_sgd.optimize.resident_driver import ResidentLoop
 
-            step = make_step(self.gradient, self.updater, self.config)
+            step = make_step(self.gradient, self.updater,
+                             self.config.structure())
+            # ``hyper`` rides in front of the loop's data: an operand
             loop = ResidentLoop(
-                lambda w, i, rv, X, y: step(w, X, y, i, rv, None),
-                self.config, int(k), int(cadence))
+                lambda w, i, rv, hyper, X, y: step(w, X, y, i, rv, hyper),
+                self.config.structure(), int(k), int(cadence))
             self._run_cache[key] = loop
         return loop
 
@@ -3115,26 +3172,45 @@ class GradientDescent(Optimizer):
         # Key on the objects themselves (identity hash, strong ref): an
         # id()-based key could alias a new gradient/mesh to a stale compiled
         # fn after GC id reuse.
-        key = ("step", self.gradient, self.updater, self.config,
-               self.mesh, with_valid, sparse_shape)
+        key = ("step", self.gradient, self.updater,
+               self.config.structure(), self.mesh, with_valid, sparse_shape)
         fn = self._run_cache.get(key)
         if fn is None:
             if self.mesh is None:
-                fn = jax.jit(make_step(self.gradient, self.updater, self.config))
+                fn = jax.jit(make_step(self.gradient, self.updater,
+                                       self.config.structure()))
             elif sparse_shape is not None:
                 from tpu_sgd.parallel.sparse_parallel import sparse_dp_step_fn
 
                 fn = sparse_dp_step_fn(
-                    self.gradient, self.updater, self.config, self.mesh,
-                    sparse_shape[0], sparse_shape[1], with_valid,
+                    self.gradient, self.updater, self.config.structure(),
+                    self.mesh, sparse_shape[0], sparse_shape[1], with_valid,
                 )
             else:
                 from tpu_sgd.parallel.data_parallel import dp_step_fn
 
-                fn = dp_step_fn(self.gradient, self.updater, self.config,
-                                self.mesh, with_valid)
+                fn = dp_step_fn(self.gradient, self.updater,
+                                self.config.structure(), self.mesh,
+                                with_valid)
             self._run_cache[key] = fn
         return fn
+
+    def _hyper(self):
+        """This fit's step size and regulariser as the compiled programs'
+        operands (``config.Hyper`` of two weakly typed scalars on the
+        device, replicated over the mesh where there is one): made anew
+        only where a value or the mesh changed, so a steady fit sends
+        nothing."""
+        cfg = self.config
+        made = (cfg.step_size, cfg.reg_param, self.mesh)
+        if self._hyper_made is None or self._hyper_made[0] != made:
+            to = None
+            if self.mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                to = NamedSharding(self.mesh, PartitionSpec())
+            self._hyper_made = (made, jax.device_put(cfg.hyper(), to))
+        return self._hyper_made[1]
 
     def _mesh_kind(self) -> str:
         from tpu_sgd.parallel.mesh import has_model_axis
@@ -3144,29 +3220,39 @@ class GradientDescent(Optimizer):
     def _runner(self, with_valid: bool):
         """Memoized jitted runner.
 
-        Rebuilt only when the plugin pair, config, or mesh changes —
-        repeated ``optimize`` calls (the streaming mode's per-micro-batch
-        pattern, SURVEY.md §3.3) hit XLA's compile cache instead of
-        retracing; measured ~3000x faster on repeat calls.  Its first call
-        in a process goes through the store of exported runners beside the
-        compile cache (``optimize/run_store.py``): where the program is
-        there it is restored, not traced.
+        Rebuilt only when the plugin pair, the config's STRUCTURE
+        (``SGDConfig.structure``: a new step size or regulariser is a new
+        operand of the same program) or the mesh changes — repeated
+        ``optimize`` calls (the streaming mode's per-micro-batch pattern,
+        SURVEY.md §3.3) hit XLA's compile cache instead of retracing;
+        measured ~3000x faster on repeat calls.  Its first call on this
+        object goes through the store of exported runners beside the
+        compile cache (``optimize/run_store.py``): a runner another
+        optimizer of the process already runs under the same key is taken
+        LIVE, one the store holds is restored, not traced.
         """
-        key = ("run", self.gradient, self.updater, self.config,
-               self.mesh, with_valid)
+        key = ("run", self.gradient, self.updater,
+               self.config.structure(), self.mesh, with_valid)
         fn = self._run_cache.get(key)
         if fn is None:
-            if self.mesh is not None:
-                from tpu_sgd.parallel.data_parallel import dp_run_fn
-
-                fn = dp_run_fn(self.gradient, self.updater, self.config,
-                               self.mesh, with_valid)
-            else:
-                fn = jax.jit(make_run(self.gradient, self.updater, self.config))
-            fn = StoredRun(fn, self.gradient, self.updater, self.config,
-                           self.mesh, with_valid)
+            fn = StoredRun(
+                functools.partial(_make_runner, self.gradient, self.updater,
+                                  self.config.structure(), self.mesh,
+                                  with_valid),
+                self.gradient, self.updater, self.config.structure(),
+                self.mesh, with_valid)
             self._run_cache[key] = fn
         return fn
+
+
+def _make_runner(gradient, updater, config, mesh, with_valid: bool):
+    """``_runner``'s jitted program, traced by nobody yet: what ``StoredRun``
+    builds where it finds the program neither live nor stored."""
+    if mesh is None:
+        return jax.jit(make_run(gradient, updater, config))
+    from tpu_sgd.parallel.data_parallel import dp_run_fn
+
+    return dp_run_fn(gradient, updater, config, mesh, with_valid)
 
 
 def run_mini_batch_sgd(

@@ -5,10 +5,21 @@ tracing and lowering ``sgd_run``, a program whose COMPILED form is already on
 disk (PERF.md section 6, PRs 55 and 56): JAX's cache is keyed by the lowered
 module, so every process traces the Pallas kernel body and lowers it again
 only to find the key.  This module keeps the module itself: where
-``GradientDescent._runner``'s program is first called in a process,
+``GradientDescent._runner``'s program is first asked for by an optimizer,
 :class:`StoredRun` computes a key WITHOUT tracing and looks for
-``<jax_compilation_cache_dir>/tpu_sgd_runs/<key>``.
+``<jax_compilation_cache_dir>/tpu_sgd_runs/<key>``: first among the
+runners the PROCESS already runs, then on disk.
 
+* live: another optimizer of this process resolved the same file's name
+  (a tuning loop builds a new ``GradientDescent`` a grid point:
+  ``run_mini_batch_sgd``) and its program still holds its executable: that
+  program is run, nothing is read, traced, lowered or looked up in the
+  compile cache, and no ``build.*`` span is left.  The table (``_LIVE``)
+  holds the last ``LIVE_KEPT`` programs by the store's directory (another
+  directory is another table), the key and whether an argument was
+  committed; an entry whose executable is gone (``jax.clear_caches()``)
+  is dropped and the file is read.  It needs no store: with no compile
+  cache directory the runner as it was is what the next optimizer finds;
 * a hit: the file is read, ``jax.export.deserialize``d (a millisecond) and
   called under a ``jax.jit`` named as the runner is; nothing of the package
   is traced, and ``jax.experimental.pallas`` is never imported
@@ -18,14 +29,16 @@ only to find the key.  This module keeps the module itself: where
   RESTORED form is run, so that the process that stores and every process
   that restores hand XLA the same module and the executable the first caches
   is the one the others read;
-* no compile cache directory: no store, the runner as it was.  That is the
-  only switch.
+* no compile cache directory: no store, the runner as it was (and live for
+  the process's next optimizer).  That is the only switch.
 
 The key holds everything the trace reads: a digest of every ``.py`` file of
 this package, the versions of ``jax``, ``jaxlib`` and the backend, the device
-kind, the mesh's shape and axis names, the gradient's, the updater's and the
-config's class and state, ``with_valid``, the arguments' tree structure, each
-leaf's shape, dtype, weak type, sharding and device layout, and the
+kind, the mesh's shape and axis names, the gradient's and the updater's class
+and state, the config's STRUCTURE (``SGDConfig.structure``: the step size and
+the regulariser are operands of the program, two of its arguments' leaves, and
+their values are in no key), ``with_valid``, the arguments' tree structure,
+each leaf's shape, dtype, weak type, sharding and device layout, and the
 ``jax.config`` values a trace depends on (``_TRACE_CONFIG``).  Eligibility is
 OBSERVED: a plugin whose class is defined outside the package (its code is
 not in the digest) or whose state is not plain scalars, strings and tuples of
@@ -37,15 +50,17 @@ was and never raises.  Custom pytree arguments (``GramData``, ``RowCount``)
 are exported over their flat leaves, the tree structure in the key.  Every
 first call leaves a ``build.restore`` span under the fit's root
 (``obs/builds.py``): ``hit`` 1 restored, 0 exported and stored, None with the
-``reason`` of a bypass.  A steady fit makes no store call and no key: a
-flatten of the arguments, one dictionary lookup and, where no argument is
-committed to a device, the outputs handed back uncommitted as the runner's
-own are (``_jit_restored``).
+``reason`` of a bypass; a live one leaves none.  ``train.select`` says which
+(``runner``: ``live``, ``restored``, ``stored``, ``as_was``).  A steady fit of
+one optimizer makes no store call and no key: a flatten of the arguments, one
+dictionary lookup and, where no argument is committed to a device, the outputs
+handed back uncommitted as the runner's own are (``_jit_restored``).
 
 Deleting the directory is always safe: the next first fit stores again."""
 
 from __future__ import annotations
 
+import collections
 import functools
 import hashlib
 import os
@@ -61,6 +76,8 @@ from tpu_sgd.obs import builds
 
 FOLDER = "tpu_sgd_runs"
 KEPT = 64  # files in the folder, the newest by modification time
+LIVE_KEPT = 32  # programs in ``_LIVE``, the last used
+_HIT = {"restored": 1, "stored": 0}  # ``build.restore``'s ``hit``; else None
 _MAGIC = b"tpu_sgd run 1\n"  # then the payload's sha256, then the payload
 
 #: the ``jax.config`` values a trace of the runner depends on
@@ -75,6 +92,34 @@ _TRACE_CONFIG = (
 _DEBUG_CONFIG = ("jax_disable_jit", "jax_debug_nans", "jax_debug_infs")
 
 _PACKAGE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: ``(the store's directory or None, the key, an argument committed) -> (the
+#: program as it is called, its ``jax.jit``)`` of the programs this process
+#: runs: the restored ones and, with no store, the runners as they were
+_LIVE: collections.OrderedDict = collections.OrderedDict()
+_LIVE_LOCK = threading.Lock()
+
+
+def _live(at):
+    """The program resolved under ``at`` earlier in the process, None where
+    there is none or its executable is no longer loaded."""
+    with _LIVE_LOCK:
+        found = _LIVE.get(at)
+        if found is None:
+            return None
+        if not found[1]._cache_size():
+            del _LIVE[at]
+            return None
+        _LIVE.move_to_end(at)
+        return found[0]
+
+
+def _keep_live(at, fn, jitted) -> None:
+    with _LIVE_LOCK:
+        _LIVE[at] = (fn, jitted)
+        _LIVE.move_to_end(at)
+        while len(_LIVE) > LIVE_KEPT:
+            _LIVE.popitem(last=False)
 
 
 def folder() -> Optional[str]:
@@ -207,9 +252,9 @@ def export(fresh, name: str, tree, leaves):
 
 
 def _jit_restored(exported, name: str, committed: bool):
-    """The exported program under a ``jax.jit`` named as the runner is (the
-    profiler's module name, ``build.*``'s ``fun``), taking the runner's own
-    arguments.  ``jax.jit`` COMMITS every output of a program that calls an
+    """``(fn, its jax.jit)``: the exported program under a ``jax.jit`` named
+    as the runner is (the profiler's module name, ``build.*``'s ``fun``),
+    taking the runner's own arguments.  ``jax.jit`` COMMITS every output of a program that calls an
     exported one to its device (``pxla`` counts the call's outputs among the
     program's memory transfers), where the runner's own outputs are committed
     only if an argument is: with no argument ``committed`` they are handed
@@ -222,107 +267,145 @@ def _jit_restored(exported, name: str, committed: bool):
     run.__name__ = run.__qualname__ = name
     restored = jax.jit(run)
     if committed:
-        return restored
+        return restored, restored
     from jax._src.array import ArrayImpl  # no public constructor takes it
 
+    @functools.wraps(run)
     def uncommitted(*args):
         # a program's own outputs: the constructor's checks are made
         return tuple(ArrayImpl(out.aval, out.sharding, out._arrays,
                                committed=False, _skip_checks=True)
                      for out in restored(*args))
 
-    return uncommitted
+    return uncommitted, restored
 
 
 class StoredRun:
     """``GradientDescent._runner``'s program behind the store: called as the
     jitted runner is.  The first call with arguments of a new signature
-    resolves it (restore, export and store, or bypass); every later one is a
-    dictionary lookup and the call."""
+    resolves it (live, restore, export and store, or bypass); every later one
+    is a dictionary lookup and the call.  ``make`` builds the jitted runner
+    (``fresh``) and is called only where it is needed: on a miss, which
+    traces it, and on a bypass; an optimizer that finds its program live or
+    in the store never builds one (``make_step`` draws its key on the
+    device: two programs a tuning loop's every call would launch for
+    nothing).  ``config`` is the config's structure
+    (``SGDConfig.structure``)."""
 
-    __slots__ = ("fresh", "name", "gradient", "updater", "config", "mesh",
+    __slots__ = ("_make", "_fresh", "gradient", "updater", "config", "mesh",
                  "with_valid", "_fns", "_lock")
 
-    def __init__(self, fresh, gradient, updater, config, mesh,
+    def __init__(self, make, gradient, updater, config, mesh,
                  with_valid: bool):
-        self.fresh = fresh  # the jitted runner, traced only on a miss
-        self.name = getattr(fresh, "__name__", "sgd_run")
+        self._make, self._fresh = make, None
         self.gradient, self.updater, self.config = gradient, updater, config
         self.mesh, self.with_valid = mesh, with_valid
         self._fns = {}
         self._lock = threading.Lock()
 
+    @property
+    def fresh(self):
+        """The jitted runner as it was, built at the first asking."""
+        if self._fresh is None:
+            self._fresh = self._make()
+        return self._fresh
+
+    @property
+    def name(self) -> str:
+        return getattr(self.fresh, "__name__", "sgd_run")
+
     def __call__(self, *args):
+        return self.resolve(*args)[0](*args)
+
+    def resolve(self, *args):
+        """``(fn, origin)`` for these arguments: the program to call with
+        them and where this optimizer got it: ``"live"`` (the process ran it
+        already), ``"restored"``, ``"stored"`` or ``"as_was"`` (a bypass)."""
         leaves, tree = jax.tree_util.tree_flatten(args)
         signature = (tree, *[
             (getattr(x, "shape", None), getattr(x, "dtype", None),
              getattr(x, "weak_type", None), getattr(x, "sharding", None),
              getattr(x, "committed", None))
             for x in leaves])
-        fn = self._fns.get(signature)
-        if fn is None:
+        found = self._fns.get(signature)
+        if found is None:
             with self._lock:
-                fn = self._fns.get(signature)
-                if fn is None:
-                    fn = self._fns[signature] = self._resolve(tree, leaves)
-        return fn(*args)
+                found = self._fns.get(signature)
+                if found is None:
+                    found = self._fns[signature] = self._resolve(tree, leaves)
+        return found
 
     def _resolve(self, tree, leaves):
         start = time.time()
         try:
-            fn, hit, reason = self._stored(tree, leaves)
+            fn, origin, reason = self._stored(tree, leaves)
         except Exception as e:  # the store must never fail a fit
-            fn, hit, reason = self.fresh, None, f"error: {type(e).__name__}"
-        builds.restored(self.name, hit, reason, start, time.time())
-        return fn
+            fn, origin, reason = (self.fresh, "as_was",
+                                  f"error: {type(e).__name__}")
+        if origin != "live":  # a live one was built by nobody here
+            builds.restored(getattr(fn, "__name__", "sgd_run"),
+                            _HIT.get(origin), reason, start, time.time())
+        return fn, origin
 
     def _stored(self, tree, leaves):
-        """``(fn, hit, reason)``: the restored program with ``hit`` 1 or 0,
-        or the runner as it was with the reason of the bypass."""
-        directory = folder()
-        if directory is None:
-            return self.fresh, None, "no compile cache directory"
+        """``(fn, origin, reason)``: the program the process already runs
+        (``"live"``), the restored program (``"restored"`` from the file,
+        named as the file's export is; ``"stored"`` where this call wrote
+        it), or the runner as it was (``"as_was"``) with the reason of the
+        bypass."""
         plugins = tuple(plugin_state(p) for p in
                         (self.gradient, self.updater, self.config))
         if None in plugins:
-            return self.fresh, None, "a plugin from outside the package"
+            return self.fresh, "as_was", "a plugin from outside the package"
         if not all(isinstance(x, jax.Array) for x in leaves):
-            return self.fresh, None, "an argument that is no device array"
+            return self.fresh, "as_was", "an argument that is no device array"
         if any(getattr(jax.config, name) for name in _DEBUG_CONFIG):
-            return self.fresh, None, "a debugging mode of jax.jit"
-        path = os.path.join(directory, key_of(
-            plugins, self.mesh, self.with_valid, tree, leaves))
+            return self.fresh, "as_was", "a debugging mode of jax.jit"
+        directory = folder()
+        key = key_of(plugins, self.mesh, self.with_valid, tree, leaves)
+        at = (directory, key, any(x.committed for x in leaves))
+        fn = _live(at)
+        if fn is not None:
+            return fn, "live", None
+        if directory is None:
+            # no store: the runner as it was, kept for the next optimizer
+            _keep_live(at, self.fresh, self.fresh)
+            return self.fresh, "as_was", "no compile cache directory"
+        path = os.path.join(directory, key)
         try:
             payload = _read(path)
             if payload is not None:
                 exported = jax.export.deserialize(bytearray(payload))
         except Exception:
             _remove(path)  # the next first fit stores it anew
-            return self.fresh, None, "a stored file that does not read back"
+            return (self.fresh, "as_was",
+                    "a stored file that does not read back")
         if payload is not None:
             try:
                 os.utime(path)  # the newest files are the ones kept
             except OSError:
                 pass
-            return self._restored(exported, leaves), 1, None
+            return self._restored(exported, at), "restored", None
         try:
             os.makedirs(directory, exist_ok=True)
         except OSError:
             pass
         if not (os.path.isdir(directory) and os.access(directory, os.W_OK)):
-            return self.fresh, None, "a directory that cannot be written"
+            return self.fresh, "as_was", "a directory that cannot be written"
         try:
             payload = bytes(
                 export(self.fresh, self.name, tree, leaves).serialize())
         except Exception as e:
-            return self.fresh, None, f"jax.export refused: {type(e).__name__}"
+            return (self.fresh, "as_was",
+                    f"jax.export refused: {type(e).__name__}")
         try:
             _write(directory, path, payload)
         except OSError:
-            return self.fresh, None, "a directory that cannot be written"
+            return self.fresh, "as_was", "a directory that cannot be written"
         exported = jax.export.deserialize(bytearray(payload))
-        return self._restored(exported, leaves), 0, None
+        return self._restored(exported, at), "stored", None
 
-    def _restored(self, exported, leaves):
-        return _jit_restored(exported, self.name,
-                             any(x.committed for x in leaves))
+    def _restored(self, exported, at):
+        fn, jitted = _jit_restored(exported, exported.fun_name, at[2])
+        _keep_live(at, fn, jitted)
+        return fn

@@ -328,6 +328,9 @@ def optimize_host_streamed(
         super_mask_sharding = NamedSharding(mesh, spec_ys)
         ef_sharding = NamedSharding(mesh, P(DATA_AXIS, None))
     w = jax.device_put(w, w_sharding)
+    # the step size and the regulariser: operands of every step program
+    # below (``make_step``), placed once beside the weights
+    hyper = jax.device_put(cfg.hyper(), w_sharding)
 
     _, reg_val = updater.compute(
         w, jnp.zeros_like(w), 0.0, jnp.asarray(1, jnp.int32), cfg.reg_param
@@ -361,10 +364,10 @@ def optimize_host_streamed(
         ones_mask = jnp.ones((m_fixed,), bool)
 
         @jax.jit
-        def resident_step(w, Xr, yr, start, i, reg_val):
+        def resident_step(w, Xr, yr, start, i, reg_val, hyper):
             Xb = jax.lax.dynamic_slice_in_dim(Xr, start, m_fixed, 0)
             yb = jax.lax.dynamic_slice_in_dim(yr, start, m_fixed, 0)
-            return base_step(w, Xb, yb, i, reg_val, ones_mask)
+            return base_step(w, Xb, yb, i, reg_val, hyper, ones_mask)
 
         # Prewarm BOTH compiled programs (dummy on-device inputs, no host
         # transfer): the window sequence decides per iteration which
@@ -377,12 +380,12 @@ def optimize_host_streamed(
             i0 = jnp.asarray(1, jnp.int32)
             r0 = jnp.zeros((), jnp.float32)
             jax.block_until_ready(resident_step(
-                w, Xres, yres, jnp.asarray(0, jnp.int32), i0, r0
+                w, Xres, yres, jnp.asarray(0, jnp.int32), i0, r0, hyper
             ))
             Xb0 = jnp.zeros((m_fixed,) + X.shape[1:], Xres.dtype)
             yb0 = jnp.zeros((m_fixed,), yres.dtype)
             v0 = jnp.ones((m_fixed,), bool)
-            jax.block_until_ready(step(w, Xb0, yb0, i0, r0, v0))
+            jax.block_until_ready(step(w, Xb0, yb0, i0, r0, hyper, v0))
             del Xb0, yb0, v0
 
     _gather = lambda A, idx: A[idx]
@@ -711,16 +714,18 @@ def optimize_host_streamed(
                 comp_step = (make_compressed_step(
                     gradient, updater, step_cfg, comp_frac)
                     if comp_frac is not None else None)
+                # ``hyper`` rides in front of the loop's data: an operand
+                # of the one program, like the rows
                 if shared_full_batch:
-                    res_data = _full_batch_transfer()
+                    res_data = (hyper,) + tuple(_full_batch_transfer())
 
                     if comp_frac is not None:
-                        def _res_step(w_, e_, i_, rv_, Xr, yr, vr):
+                        def _res_step(w_, e_, i_, rv_, hy, Xr, yr, vr):
                             return comp_step(w_, e_, Xr, yr, i_, rv_,
-                                             vr)
+                                             hy, vr)
                     else:
-                        def _res_step(w_, i_, rv_, Xr, yr, vr):
-                            return base_step(w_, Xr, yr, i_, rv_, vr)
+                        def _res_step(w_, i_, rv_, hy, Xr, yr, vr):
+                            return base_step(w_, Xr, yr, i_, rv_, hy, vr)
                 else:
                     # fully-resident sliced slab: the window sequence
                     # is deterministic in (seed, i) — replay THE host
@@ -736,26 +741,26 @@ def optimize_host_streamed(
                         assert tag == "resident", tag
                         starts_np[it - 1] = start
                     starts_d = jax.device_put(starts_np, device)
-                    res_data = (Xres, yres, starts_d)
+                    res_data = (hyper, Xres, yres, starts_d)
 
                     if comp_frac is not None:
-                        def _res_step(w_, e_, i_, rv_, Xr, yr, st):
+                        def _res_step(w_, e_, i_, rv_, hy, Xr, yr, st):
                             s0 = st[i_ - 1]
                             Xb = jax.lax.dynamic_slice_in_dim(
                                 Xr, s0, m_fixed, 0)
                             yb = jax.lax.dynamic_slice_in_dim(
                                 yr, s0, m_fixed, 0)
                             return comp_step(w_, e_, Xb, yb, i_, rv_,
-                                             ones_mask)
+                                             hy, ones_mask)
                     else:
-                        def _res_step(w_, i_, rv_, Xr, yr, st):
+                        def _res_step(w_, i_, rv_, hy, Xr, yr, st):
                             s0 = st[i_ - 1]
                             Xb = jax.lax.dynamic_slice_in_dim(
                                 Xr, s0, m_fixed, 0)
                             yb = jax.lax.dynamic_slice_in_dim(
                                 yr, s0, m_fixed, 0)
                             return base_step(w_, Xb, yb, i_, rv_,
-                                             ones_mask)
+                                             hy, ones_mask)
 
                 # the loop's program depends only on (step math, cfg,
                 # K, C, wire) and the feed shape family — memo hit =
@@ -891,14 +896,16 @@ def optimize_host_streamed(
                         if comp_frac is not None:
                             w_dev, ef, ys = fused(
                                 w, ef, jnp.asarray(reg_val, jnp.float32),
+                                hyper,
                                 jnp.asarray(i0, jnp.int32), Xd, yd, vd)
                         else:
                             w_dev, ys = fused(
                                 w, jnp.asarray(reg_val, jnp.float32),
+                                hyper,
                                 jnp.asarray(i0, jnp.int32), Xd, yd, vd)
                     elif window_resident:
                         w_dev, ys = fused(
-                            w, jnp.asarray(reg_val, jnp.float32),
+                            w, jnp.asarray(reg_val, jnp.float32), hyper,
                             jnp.asarray(i0, jnp.int32), Xres, yres,
                             *nxt)
                         if i0 + K <= cfg.num_iterations:
@@ -908,10 +915,12 @@ def optimize_host_streamed(
                         if comp_frac is not None:
                             w_dev, ef, ys = fused(
                                 w, ef, jnp.asarray(reg_val, jnp.float32),
+                                hyper,
                                 jnp.asarray(i0, jnp.int32), Xs, Ys, Vs)
                         else:
                             w_dev, ys = fused(
                                 w, jnp.asarray(reg_val, jnp.float32),
+                                hyper,
                                 jnp.asarray(i0, jnp.int32), Xs, Ys, Vs)
                         if i0 + K <= cfg.num_iterations:
                             nxt = next(prefetch)
@@ -1002,7 +1011,7 @@ def optimize_host_streamed(
                     new_w, loss_i, new_reg, c = resident_step(
                         w, Xres, yres, jnp.asarray(payload, jnp.int32),
                         jnp.asarray(i, jnp.int32),
-                        jnp.asarray(reg_val, jnp.float32),
+                        jnp.asarray(reg_val, jnp.float32), hyper,
                     )
                 elif comp_frac is not None:
                     # compressed wire: the EF accumulator is carried
@@ -1011,14 +1020,14 @@ def optimize_host_streamed(
                     Xb, yb, valid = payload
                     new_w, ef, loss_i, new_reg, c = step(
                         w, ef, Xb, yb, jnp.asarray(i, jnp.int32),
-                        jnp.asarray(reg_val, jnp.float32),
+                        jnp.asarray(reg_val, jnp.float32), hyper,
                         valid,
                     )
                 else:
                     Xb, yb, valid = payload
                     new_w, loss_i, new_reg, c = step(
                         w, Xb, yb, jnp.asarray(i, jnp.int32),
-                        jnp.asarray(reg_val, jnp.float32),
+                        jnp.asarray(reg_val, jnp.float32), hyper,
                         valid,
                     )
                 if i < cfg.num_iterations:

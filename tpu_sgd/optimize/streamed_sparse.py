@@ -87,8 +87,8 @@ def _sparse_step_fn(gradient, updater, step_cfg, rows: int, d: int):
 
     base = make_step(gradient, updater, step_cfg)
 
-    def fn(w, data, idx, yb, i, rv, valid):
-        return base(w, _bcoo(data, idx, rows, d), yb, i, rv, valid)
+    def fn(w, data, idx, yb, i, rv, hyper, valid):
+        return base(w, _bcoo(data, idx, rows, d), yb, i, rv, hyper, valid)
 
     return jax.jit(fn)
 
@@ -101,14 +101,14 @@ def _sparse_superstep_fn(gradient, updater, step_cfg, rows: int, d: int):
 
     step = make_step(gradient, updater, step_cfg)
 
-    def fn(w, rv, i0, Ds, Is, Ys, Vs):
+    def fn(w, rv, hyper, i0, Ds, Is, Ys, Vs):
         idxs = i0 + jnp.arange(Ds.shape[0], dtype=jnp.int32)
 
         def body(carry, xs):
             cw, crv = carry
             i, dt, it, yt, vt = xs
             new_w, loss_i, new_rv, c = step(
-                cw, _bcoo(dt, it, rows, d), yt, i, crv, vt)
+                cw, _bcoo(dt, it, rows, d), yt, i, crv, hyper, vt)
             return (new_w, new_rv), pack_step_ys(cw, new_w, loss_i,
                                                  new_rv, c)
 
@@ -130,8 +130,8 @@ def _sparse_resident_step_fn(gradient, updater, step_cfg, rows: int,
 
     base = make_step(gradient, updater, step_cfg)
 
-    def fn(w, i, rv, data, idx, yb, valid):
-        return base(w, _bcoo(data, idx, rows, d), yb, i, rv, valid)
+    def fn(w, i, rv, hyper, data, idx, yb, valid):
+        return base(w, _bcoo(data, idx, rows, d), yb, i, rv, hyper, valid)
 
     return fn
 
@@ -146,13 +146,13 @@ def _sparse_shared_superstep_fn(gradient, updater, step_cfg, rows: int,
     step = make_step(gradient, updater, step_cfg)
     K = int(k)
 
-    def fn(w, rv, i0, data, idx, yb, valid):
+    def fn(w, rv, hyper, i0, data, idx, yb, valid):
         idxs = i0 + jnp.arange(K, dtype=jnp.int32)
 
         def body(carry, i):
             cw, crv = carry
             new_w, loss_i, new_rv, c = step(
-                cw, _bcoo(data, idx, rows, d), yb, i, crv, valid)
+                cw, _bcoo(data, idx, rows, d), yb, i, crv, hyper, valid)
             return (new_w, new_rv), pack_step_ys(cw, new_w, loss_i,
                                                  new_rv, c)
 
@@ -306,6 +306,9 @@ def optimize_host_streamed_sparse(
         w, jnp.zeros_like(w), 0.0, jnp.asarray(1, jnp.int32),
         cfg.reg_param
     )
+    # the step size and the regulariser: operands of every program below
+    # (``make_step``), placed once
+    hyper = jax.device_put(cfg.hyper(), device)
 
     def stage(i: int):
         """One batch's host assembly: CSR row gather + fixed-shape pad
@@ -462,8 +465,8 @@ def optimize_host_streamed_sparse(
                 save_every=checkpoint_every,
                 stop_signal=stop_signal,
                 retry_policy=retry_policy)
-            w_np, converged = prog.run(w, reg_val, start_iter, shared,
-                                       hooks)
+            w_np, converged = prog.run(w, reg_val, start_iter,
+                                       (hyper,) + tuple(shared), hooks)
             w = jax.device_put(jnp.asarray(w_np), device)
             reg_val = hooks.reg_val
         _end()
@@ -495,12 +498,12 @@ def optimize_host_streamed_sparse(
                 with span("train.superstep", i0=i0, steps=steps):
                     if full_batch:
                         w_dev, ys = prog(
-                            w, jnp.asarray(reg_val, jnp.float32),
+                            w, jnp.asarray(reg_val, jnp.float32), hyper,
                             jnp.asarray(i0, jnp.int32), *shared)
                     else:
                         Ds, Is, Ys, Vs = nxt
                         w_dev, ys = prog(
-                            w, jnp.asarray(reg_val, jnp.float32),
+                            w, jnp.asarray(reg_val, jnp.float32), hyper,
                             jnp.asarray(i0, jnp.int32), Ds, Is, Ys, Vs)
                         if i0 + K <= cfg.num_iterations:
                             nxt = next(prefetch)
@@ -560,7 +563,7 @@ def optimize_host_streamed_sparse(
                 data, idx, yb, valid = shared if full_batch else nxt
                 new_w, loss_i, new_reg, c = prog(
                     w, data, idx, yb, jnp.asarray(i, jnp.int32),
-                    jnp.asarray(reg_val, jnp.float32), valid)
+                    jnp.asarray(reg_val, jnp.float32), hyper, valid)
                 if prefetch is not None and i < cfg.num_iterations:
                     nxt = next(prefetch)
                 # the observed sparse streamed driver shares the dense

@@ -182,13 +182,15 @@ def dp_step_fn(
     from tpu_sgd.optimize.gradient_descent import make_step
 
     step = make_step(gradient, updater, config, axis_name=DATA_AXIS)
+    # ``hyper`` (the step size and the regulariser: operands, replicated)
+    # behind ``reg_val``, as in ``make_step``
     if with_valid:
         body = step
-        in_specs = (P(), P(DATA_AXIS, None), P(DATA_AXIS), P(), P(),
+        in_specs = (P(), P(DATA_AXIS, None), P(DATA_AXIS), P(), P(), P(),
                     P(DATA_AXIS))
     else:
-        body = lambda w, X, y, i, r: step(w, X, y, i, r, None)
-        in_specs = (P(), P(DATA_AXIS, None), P(DATA_AXIS), P(), P())
+        body = lambda w, X, y, i, r, hyper: step(w, X, y, i, r, hyper)
+        in_specs = (P(), P(DATA_AXIS, None), P(DATA_AXIS), P(), P(), P())
     return jax.jit(
         shard_map_fn(mesh, body, in_specs, (P(), P(), P(), P()))
     )
@@ -220,7 +222,7 @@ def dp_superstep_fn(
     from tpu_sgd.optimize.gradient_descent import make_superstep
 
     sstep = make_superstep(gradient, updater, config, axis_name=DATA_AXIS)
-    in_specs = (P(), P(), P()) + superchunk_specs()
+    in_specs = (P(), P(), P(), P()) + superchunk_specs()
     return jax.jit(shard_map_fn(
         mesh, sstep, in_specs, (P(), _SUPERSTEP_YS_SPECS)))
 
@@ -247,11 +249,11 @@ def dp_shared_superstep_fn(
                                         axis_name=DATA_AXIS)
     if with_valid:
         body = sstep
-        in_specs = (P(), P(), P(), P(DATA_AXIS, None), P(DATA_AXIS),
+        in_specs = (P(), P(), P(), P(), P(DATA_AXIS, None), P(DATA_AXIS),
                     P(DATA_AXIS))
     else:
-        body = lambda w, rv, i0, X, y: sstep(w, rv, i0, X, y, None)
-        in_specs = (P(), P(), P(), P(DATA_AXIS, None), P(DATA_AXIS))
+        body = lambda w, rv, hyper, i0, X, y: sstep(w, rv, hyper, i0, X, y)
+        in_specs = (P(), P(), P(), P(), P(DATA_AXIS, None), P(DATA_AXIS))
     return jax.jit(shard_map_fn(
         mesh, body, in_specs, (P(), _SUPERSTEP_YS_SPECS)))
 
@@ -275,7 +277,7 @@ def dp_compressed_step_fn(
     wire (``make_compressed_step`` with the 'data' axis): the gradient
     all-reduce ships top-k ``(values, indices)`` segments with per-shard
     error-feedback state instead of a dense ``(d,)`` psum — README
-    "Compressed wire".  Signature: ``fn(w, ef, X, y, i, reg_val[,
+    "Compressed wire".  Signature: ``fn(w, ef, X, y, i, reg_val, hyper[,
     valid]) -> (new_w, new_ef, loss, new_reg, count)`` where ``ef`` is
     the ``(n_shards, dim)`` sharded accumulator."""
     from tpu_sgd.optimize.gradient_descent import make_compressed_step
@@ -283,13 +285,13 @@ def dp_compressed_step_fn(
     step = make_compressed_step(gradient, updater, config, topk_frac,
                                 axis_name=DATA_AXIS)
 
-    def body(w, ef, X, y, i, rv, valid=None):
+    def body(w, ef, X, y, i, rv, hyper, valid=None):
         new_w, new_ef, loss, new_rv, c = step(w, ef[0], X, y, i, rv,
-                                              valid)
+                                              hyper, valid)
         return new_w, new_ef[None], loss, new_rv, c
 
     in_specs = (P(), _EF_SPEC, P(DATA_AXIS, None), P(DATA_AXIS), P(),
-                P())
+                P(), P())
     if with_valid:
         in_specs = in_specs + (P(DATA_AXIS),)
     return jax.jit(shard_map_fn(
@@ -307,7 +309,7 @@ def dp_compressed_superstep_fn(
     compressed steps per dispatch, the per-shard EF accumulator carried
     in the scan and the per-step post-update accumulators returned as a
     ``(K, n_shards, dim)`` ys leaf (iteration-exact EF for
-    mid-superstep checkpoints).  ``fn(w, ef, reg_val, i0, Xs, ys,
+    mid-superstep checkpoints).  ``fn(w, ef, reg_val, hyper, i0, Xs, ys,
     valids) -> (w, ef, (*step_ys, efs))``."""
     from tpu_sgd.optimize.gradient_descent import (
         make_compressed_superstep,
@@ -316,11 +318,11 @@ def dp_compressed_superstep_fn(
     sstep = make_compressed_superstep(gradient, updater, config,
                                       topk_frac, axis_name=DATA_AXIS)
 
-    def body(w, ef, rv, i0, Xs, ys, valids):
-        new_w, new_ef, out = sstep(w, ef[0], rv, i0, Xs, ys, valids)
+    def body(w, ef, rv, hyper, i0, Xs, ys, valids):
+        new_w, new_ef, out = sstep(w, ef[0], rv, hyper, i0, Xs, ys, valids)
         return new_w, new_ef[None], out[:6] + (out[6][:, None, :],)
 
-    in_specs = (P(), _EF_SPEC, P(), P()) + superchunk_specs()
+    in_specs = (P(), _EF_SPEC, P(), P(), P()) + superchunk_specs()
     out_specs = (P(), _EF_SPEC,
                  _SUPERSTEP_YS_SPECS + (P(None, DATA_AXIS, None),))
     return jax.jit(shard_map_fn(mesh, body, in_specs, out_specs))
@@ -345,11 +347,11 @@ def dp_compressed_shared_superstep_fn(
     sstep = make_compressed_shared_superstep(
         gradient, updater, config, topk_frac, k, axis_name=DATA_AXIS)
 
-    def body(w, ef, rv, i0, X, y, valid=None):
-        new_w, new_ef, out = sstep(w, ef[0], rv, i0, X, y, valid)
+    def body(w, ef, rv, hyper, i0, X, y, valid=None):
+        new_w, new_ef, out = sstep(w, ef[0], rv, hyper, i0, X, y, valid)
         return new_w, new_ef[None], out[:6] + (out[6][:, None, :],)
 
-    in_specs = (P(), _EF_SPEC, P(), P(), P(DATA_AXIS, None),
+    in_specs = (P(), _EF_SPEC, P(), P(), P(), P(DATA_AXIS, None),
                 P(DATA_AXIS))
     if with_valid:
         in_specs = in_specs + (P(DATA_AXIS),)
@@ -370,16 +372,20 @@ def dp_run_fn(
     The inner body is *the same* ``make_run`` used single-device, with
     ``axis_name='data'`` turning its combines into ICI all-reduces — one
     compiled XLA program for the entire optimization across all cores.
+    ``fn(w0, X, y, hyper[, valid])``: ``hyper`` (``make_run``'s: the step
+    size and the regulariser) is an operand here too, replicated, so a new
+    value of either runs the program the mesh already holds.
     """
     from tpu_sgd.optimize.gradient_descent import make_run
 
     run = make_run(gradient, updater, config, axis_name=DATA_AXIS)
     if with_valid:
-        body = lambda w, X, y, v: run(w, X, y, v)
-        in_specs = (P(), P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS))
+        body = lambda w, X, y, hyper, v: run(w, X, y, hyper, v)
+        in_specs = (P(), P(DATA_AXIS, None), P(DATA_AXIS), P(),
+                    P(DATA_AXIS))
     else:
-        body = lambda w, X, y: run(w, X, y, None)
-        in_specs = (P(), P(DATA_AXIS, None), P(DATA_AXIS))
+        body = lambda w, X, y, hyper: run(w, X, y, hyper)
+        in_specs = (P(), P(DATA_AXIS, None), P(DATA_AXIS), P())
     out_specs = (P(), P(), P())
     return jax.jit(shard_map_fn(mesh, body, in_specs, out_specs))
 
@@ -397,6 +403,7 @@ def dp_optimize(
     Xd, yd, valid = shard_dataset(mesh, X, y)
     w0 = jnp.asarray(initial_weights)
     fn = dp_run_fn(gradient, updater, config, mesh, valid is not None)
+    hyper = config.hyper()
     if valid is not None:
-        return fn(w0, Xd, yd, valid)
-    return fn(w0, Xd, yd)
+        return fn(w0, Xd, yd, hyper, valid)
+    return fn(w0, Xd, yd, hyper)

@@ -106,12 +106,12 @@ def dp_gram_run_fn(
     run = make_run(GramLeastSquaresGradient(aligned=aligned), updater,
                    config, axis_name=DATA_AXIS)
 
-    def body(w, Xl, yl, PG, Pb, Pyy, Gt, bt, yyt):
+    def body(w, Xl, yl, hyper, PG, Pb, Pyy, Gt, bt, yyt):
         gd = GramData(Xl, PG[0], Pb[0], Pyy[0], Gt[0], bt[0], yyt[0],
                       block_rows)
-        return run(w, gd, yl, None)
+        return run(w, gd, yl, hyper)
 
-    in_specs = (P(), P(DATA_AXIS, None), P(DATA_AXIS)) + _STATS_SPECS
+    in_specs = (P(), P(DATA_AXIS, None), P(DATA_AXIS), P()) + _STATS_SPECS
     out_specs = (P(), P(), P())
     return jax.jit(shard_map_fn(mesh, body, in_specs, out_specs))
 
@@ -219,7 +219,7 @@ def dp_virtual_gram_run_fn(
     ``GramData`` carrying its logical ``(n_local, d)`` shape, so windows
     run block-aligned from the prefix stacks and only the (grad, loss,
     count) psums ride the ICI.  Signature:
-    ``fn(w0, yd, *stats_leaves) -> (w, losses, n_rec)`` (``yd`` is the
+    ``fn(w0, yd, hyper, *stats_leaves) -> (w, losses, n_rec)`` (``yd`` is the
     tiny label vector, sharded for shape parity — the virtual window path
     never reads it)."""
     from tpu_sgd.optimize.gradient_descent import make_run
@@ -227,13 +227,13 @@ def dp_virtual_gram_run_fn(
     run = make_run(GramLeastSquaresGradient(), updater, config,
                    axis_name=DATA_AXIS)
 
-    def body(w, yl, PG, Pb, Pyy, Gt, bt, yyt):
+    def body(w, yl, hyper, PG, Pb, Pyy, Gt, bt, yyt):
         gd = GramData(None, PG[0], Pb[0], Pyy[0], Gt[0], bt[0], yyt[0],
                       block_rows, logical_shape=(n_local, d),
                       logical_dtype=data_dtype_name)
-        return run(w, gd, yl, None)
+        return run(w, gd, yl, hyper)
 
-    in_specs = (P(), P(DATA_AXIS)) + _STATS_SPECS
+    in_specs = (P(), P(DATA_AXIS), P()) + _STATS_SPECS
     out_specs = (P(), P(), P())
     return jax.jit(shard_map_fn(mesh, body, in_specs, out_specs))
 

@@ -48,12 +48,13 @@ def dp_mp_run_fn(
         axis_name=DATA_AXIS, model_axis_name=MODEL_AXIS,
     )
     if with_valid:
-        body = lambda w, X, y, v: run(w, X, y, v)
+        body = lambda w, X, y, hyper, v: run(w, X, y, hyper, v)
         in_specs = (P(MODEL_AXIS), P(DATA_AXIS, MODEL_AXIS), P(DATA_AXIS),
-                    P(DATA_AXIS))
+                    P(), P(DATA_AXIS))
     else:
-        body = lambda w, X, y: run(w, X, y, None)
-        in_specs = (P(MODEL_AXIS), P(DATA_AXIS, MODEL_AXIS), P(DATA_AXIS))
+        body = lambda w, X, y, hyper: run(w, X, y, hyper)
+        in_specs = (P(MODEL_AXIS), P(DATA_AXIS, MODEL_AXIS), P(DATA_AXIS),
+                    P())
     out_specs = (P(MODEL_AXIS), P(), P())
     return jax.jit(shard_map_fn(mesh, body, in_specs, out_specs))
 
@@ -84,9 +85,10 @@ def dp_mp_optimize(
     yd = jax.device_put(yh, NamedSharding(mesh, P(DATA_AXIS)))
     wd = jax.device_put(w0h, NamedSharding(mesh, P(MODEL_AXIS)))
     fn = dp_mp_run_fn(gradient, updater, config, mesh, need_valid)
+    hyper = config.hyper()
     if need_valid:
         vd = jax.device_put(validh, NamedSharding(mesh, P(DATA_AXIS)))
-        w, losses, n_rec = fn(wd, Xd, yd, vd)
+        w, losses, n_rec = fn(wd, Xd, yd, hyper, vd)
     else:
-        w, losses, n_rec = fn(wd, Xd, yd)
+        w, losses, n_rec = fn(wd, Xd, yd, hyper)
     return w[:orig_dim], losses, n_rec

@@ -197,15 +197,15 @@ def sparse_dp_step_fn(
 
     step = make_step(gradient, updater, config, axis_name=DATA_AXIS)
 
-    def local(w, X, y, i, reg_val, valid=None):
+    def local(w, X, y, i, reg_val, hyper, valid=None):
         return step(w, local_bcoo(X[0], X[1], rows_local, d), y, i, reg_val,
-                    valid)
+                    hyper, valid)
 
     # X arrives as the (data, idx) component tuple, matching the stepwise
     # caller's ``step(w, X, y, ...)`` signature for dense X
     # ``local`` defaults valid=None, so it serves both arities directly
     x_spec = (P(DATA_AXIS), P(DATA_AXIS, None))
-    in_specs = (P(), x_spec, P(DATA_AXIS), P(), P())
+    in_specs = (P(), x_spec, P(DATA_AXIS), P(), P(), P())
     if with_valid:
         in_specs = in_specs + (P(DATA_AXIS),)
     return jax.jit(
@@ -228,11 +228,11 @@ def sparse_dp_run_fn(
 
     run = make_run(gradient, updater, config, axis_name=DATA_AXIS)
 
-    def local(w, data, idx, y, valid=None):
-        return run(w, local_bcoo(data, idx, rows_local, d), y, valid)
+    def local(w, data, idx, y, hyper, valid=None):
+        return run(w, local_bcoo(data, idx, rows_local, d), y, hyper, valid)
 
     # ``local`` defaults valid=None, so it serves both arities directly
-    in_specs = (P(), P(DATA_AXIS), P(DATA_AXIS, None), P(DATA_AXIS))
+    in_specs = (P(), P(DATA_AXIS), P(DATA_AXIS, None), P(DATA_AXIS), P())
     if with_valid:
         in_specs = in_specs + (P(DATA_AXIS),)
     return jax.jit(shard_map_fn(mesh, local, in_specs, (P(), P(), P())))
